@@ -35,6 +35,9 @@ def run(group, world_size: int, steps: int) -> None:
 
 
 def main():
+    from tpu_sandbox.runtime.bootstrap import configure_compile_cache
+
+    configure_compile_cache()
     parser = argparse.ArgumentParser()
     parser.add_argument("--backend", type=str, default="xla",
                         help="Accepted for reference parity; XLA picks the fabric.")

@@ -302,6 +302,9 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main():
+    from tpu_sandbox.runtime.bootstrap import configure_compile_cache
+
+    configure_compile_cache()
     train(build_parser().parse_args())
 
 

@@ -10,8 +10,9 @@ every 100 steps, wall-clock total. Its multi-node flags never actually
 worked (hardcoded localhost master + fresh random port per invocation).
 
 TPU-native shape: no spawning — ranks are devices of one process
-(``-g`` = number of local devices; CPU-virtualized when the chip count is
-smaller). The DDP engine is ``tpu_sandbox.parallel.DataParallel``: one jit'd
+(``-g`` = number of local devices: the host's chips, or virtual CPU devices
+under ``--force-cpu``; asking for more chips than there are is an error).
+The DDP engine is ``tpu_sandbox.parallel.DataParallel``: one jit'd
 shard_map step with pmean'd grads, replicated params, per-replica BN stats.
 Real multi-host runs initialize via tpu_sandbox.runtime.bootstrap
 (jax.distributed) instead of the reference's broken localhost rendezvous.
@@ -68,20 +69,21 @@ def make_optimizer(args):
     return optax.adamw(learning_rate=1e-4)
 
 
-def train(args, world_size):
+def build(args, world_size):
+    """Everything ``train`` runs, built but not run: (DataParallel engine,
+    unsharded initial state, sharded batch loader) over ``world_size``
+    devices of this process. ``chip_smoke.py`` drives these same objects."""
     import jax
     import jax.numpy as jnp
 
     from tpu_sandbox.data import ShardedBatchLoader
     from tpu_sandbox.models import pick_convnet
     from tpu_sandbox.parallel import DataParallel
-    from tpu_sandbox.runtime import bootstrap
     from tpu_sandbox.runtime.mesh import make_mesh
-    from tpu_sandbox.train import Trainer, TrainState
+    from tpu_sandbox.train import TrainState
     from tpu_sandbox.utils.cli import ensure_devices
 
     devices = ensure_devices(world_size, force_cpu=args.force_cpu)
-    bootstrap.init()
     mesh = make_mesh({"data": world_size}, devices=devices)
 
     rng = jax.random.key(0)  # parity: torch.manual_seed(0), reference :51
@@ -100,17 +102,26 @@ def train(args, world_size):
     )
 
     state = TrainState.create(model, rng, jnp.zeros([1, *image_shape, 1], dtype), tx)
+    dp = DataParallel(model, tx, mesh, image_size=tuple(image_shape),
+                      zero=args.zero, grad_compress=args.grad_compress,
+                      error_feedback=not args.no_error_feedback,
+                      overlap_grad_sync=args.overlap_grad_sync,
+                      bucket_mb=args.bucket_mb)
+    return dp, state, loader
+
+
+def train(args, world_size):
+    from tpu_sandbox.runtime import bootstrap
+    from tpu_sandbox.train import Trainer
+
+    dp, state, loader = build(args, world_size)
+    bootstrap.init()
     if args.ckpt_dir and args.resume:
         from tpu_sandbox.train import checkpoint as ckpt
 
         if ckpt.latest_step(args.ckpt_dir) is not None:
             state = ckpt.restore(args.ckpt_dir, state)
             print(f"resumed from step {int(state.step)}")
-    dp = DataParallel(model, tx, mesh, image_size=tuple(image_shape),
-                      zero=args.zero, grad_compress=args.grad_compress,
-                      error_feedback=not args.no_error_feedback,
-                      overlap_grad_sync=args.overlap_grad_sync,
-                      bucket_mb=args.bucket_mb)
     dstate = dp.shard_state(state)
 
     def step(s, images_np, labels_np):
@@ -746,7 +757,7 @@ def spawn_multiprocess(args, world_size):
     kv_server.stop()
 
 
-def main():
+def build_parser():
     parser = argparse.ArgumentParser()
     parser.add_argument("-n", "--nodes", type=int, default=1, metavar="N",
                         help="number of hosts (parity flag; >1 uses jax.distributed)")
@@ -798,7 +809,14 @@ def main():
     parser.add_argument("--port", type=str, default="", help=argparse.SUPPRESS)
     parser.add_argument("--kv-port", type=str, default="",
                         help=argparse.SUPPRESS)
-    args = parser.parse_args()
+    return parser
+
+
+def main():
+    from tpu_sandbox.runtime.bootstrap import configure_compile_cache
+
+    configure_compile_cache()
+    args = build_parser().parse_args()
     world_size = args.gpus * args.nodes  # reference :123
     if args.worker:
         train_multiprocess_worker(args, world_size)

@@ -19,8 +19,18 @@ import argparse
 IMAGE_SHAPE = [3000, 3000]
 
 
-def train(device_index, args):
+def build(args):
+    """Everything ``train`` runs, built but not run: (model, initial state,
+    jitted train step, batch loader) for ``args``. ``chip_smoke.py`` drives
+    these same objects, so the smoke and the script cannot drift apart."""
     import jax
+    import jax.numpy as jnp
+    import optax
+
+    from tpu_sandbox.data import BatchLoader, load_mnist, synthetic_mnist
+    from tpu_sandbox.data.mnist import normalize
+    from tpu_sandbox.models import pick_convnet
+    from tpu_sandbox.train import TrainState, make_train_step
 
     if args.accum_steps < 1:
         raise SystemExit(f"--accum-steps must be >= 1, got {args.accum_steps}")
@@ -29,18 +39,6 @@ def train(device_index, args):
             f"--batch-size {args.batch_size} must be divisible by "
             f"--accum-steps {args.accum_steps}"
         )
-    if args.force_cpu:
-        from tpu_sandbox.utils.cli import ensure_devices
-
-        ensure_devices(1, force_cpu=True)
-    import jax.numpy as jnp
-    import optax
-
-    from tpu_sandbox.data import BatchLoader, load_mnist, synthetic_mnist
-    from tpu_sandbox.data.mnist import normalize
-    from tpu_sandbox.models import pick_convnet
-    from tpu_sandbox.train import Trainer, TrainState, make_train_step
-
     rng = jax.random.key(0)  # parity: torch.manual_seed(0), reference :35
     image_shape = [args.image_size, args.image_size]
     dtype = jnp.bfloat16 if args.dtype == "bf16" else jnp.float32
@@ -78,14 +76,26 @@ def train(device_index, args):
     state = TrainState.create(
         model, rng, jnp.zeros([1, *image_shape, 1], dtype), tx
     )
+    step = make_train_step(model, tx, image_size=tuple(image_shape),
+                           accum_steps=args.accum_steps)
+    return model, state, step, loader
+
+
+def train(device_index, args):
+    if args.force_cpu:
+        from tpu_sandbox.utils.cli import ensure_devices
+
+        ensure_devices(1, force_cpu=True)
+    from tpu_sandbox.train import Trainer
+
+    image_shape = [args.image_size, args.image_size]
+    model, state, step, loader = build(args)
     if args.ckpt_dir and args.resume:
         from tpu_sandbox.train import checkpoint as ckpt
 
         if ckpt.latest_step(args.ckpt_dir) is not None:
             state = ckpt.restore(args.ckpt_dir, state)
             print(f"resumed from step {int(state.step)}")
-    step = make_train_step(model, tx, image_size=tuple(image_shape),
-                           accum_steps=args.accum_steps)
     trainer = Trainer(step, log_every=args.log_every,
                       ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every)
     import contextlib
@@ -101,6 +111,8 @@ def train(device_index, args):
     if args.profile:
         print(f"profiler trace written to {args.profile}")
     if args.eval:
+        from tpu_sandbox.data import load_mnist, synthetic_mnist
+        from tpu_sandbox.data.mnist import normalize
         from tpu_sandbox.train.trainer import make_eval_step
 
         try:
@@ -131,7 +143,7 @@ def train(device_index, args):
         print(f"saved checkpoint at step {ckpt.save(args.ckpt_dir, state)}")
 
 
-def main():
+def build_parser():
     parser = argparse.ArgumentParser()
     parser.add_argument("--epochs", type=int, default=2, help="number of epochs")
     parser.add_argument("--batch-size", type=int, default=5,
@@ -176,8 +188,14 @@ def main():
                         help="restore the latest checkpoint from --ckpt-dir first")
     parser.add_argument("--force-cpu", action="store_true",
                         help="run on the CPU backend even if an accelerator is present")
-    args = parser.parse_args()
-    train(0, args)
+    return parser
+
+
+def main():
+    from tpu_sandbox.runtime.bootstrap import configure_compile_cache
+
+    configure_compile_cache()
+    train(0, build_parser().parse_args())
 
 
 if __name__ == "__main__":

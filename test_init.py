@@ -106,6 +106,9 @@ def test_setup(world_size: int, multiprocess: bool,
 
 
 def main():
+    from tpu_sandbox.runtime.bootstrap import configure_compile_cache
+
+    configure_compile_cache()
     parser = argparse.ArgumentParser()
     parser.add_argument("--world-size", type=int, default=4)
     parser.add_argument("--multiprocess", action="store_true",

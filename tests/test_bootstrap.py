@@ -79,3 +79,30 @@ def test_init_twice_is_idempotent():
     topo = bootstrap.init()
     assert topo.num_processes == 1
     bootstrap.cleanup()
+
+
+def test_compile_cache_honours_the_environment(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR places the cache from outside: JAX reads it
+    by itself, and the helper must set no other directory in code."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/from/outside")
+    before = jax.config.jax_compilation_cache_dir
+    assert bootstrap.configure_compile_cache() == "/placed/from/outside"
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_to_a_fixed_path_in_the_checkout(
+        monkeypatch, tmp_path):
+    """Unset, the cache is <checkout>/.jax_cache whatever the working
+    directory: the path is part of the cache key, so it may depend on
+    nothing that changes between runs."""
+    from pathlib import Path
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.chdir(tmp_path)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        want = str(Path(__file__).resolve().parents[1] / ".jax_cache")
+        assert bootstrap.configure_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

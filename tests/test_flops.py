@@ -65,7 +65,11 @@ def test_convnet_flops_agrees_with_xla_cost_analysis():
 def test_peak_table_and_mfu_verdicts():
     assert device_peak_tflops("TPU v5 lite") == 197.0
     assert device_peak_tflops("TPU v4") == 275.0
-    assert device_peak_tflops("cpu") is None
+    # exact kinds only: no published peak is an error on a measuring path,
+    # never a default — and "TPU v5" is jax's name for a v5p, not a v5e
+    for unknown in ("cpu", "TPU v5", "TPU v5 lite (simulated)"):
+        with pytest.raises(ValueError, match="no published bf16 peak"):
+            device_peak_tflops(unknown)
 
     # a sane measurement: 1 TFLOP in 10 ms on a v5e -> 100 TFLOP/s, ~51%
     r = mfu(1e12, 0.010, "TPU v5 lite")
@@ -77,9 +81,9 @@ def test_peak_table_and_mfu_verdicts():
     r = mfu(1e12, 0.0005, "TPU v5 lite")
     assert r["mfu"] > 1 and not r["plausible"]
 
-    # unknown chip: no peak, no verdict — but not declared implausible
-    r = mfu(1e12, 0.010, "cpu")
-    assert r["mfu"] is None and r["plausible"]
+    # unknown chip: no utilization at all
+    with pytest.raises(ValueError, match="no published bf16 peak"):
+        mfu(1e12, 0.010, "cpu")
 
     # multi-chip peak scales
     r = mfu(1e12, 0.010, "TPU v5 lite", n_devices=4)
@@ -137,6 +141,40 @@ def test_s2d_custom_call_flops_counts_pallas_calls_only():
     c3 = s2d_custom_call_flops(hlo, 16, 3000, plan="ConvNetS2DT",
                                sparse_conv1=False)
     assert c3["per_class"]["conv1"] == base * 9 * 16 * 256
+
+
+def test_s2d_custom_call_flops_knows_every_kernel_of_the_production_step():
+    """The r05 step's 11 Pallas calls by op_name, as the v5e compile
+    prints them: the conv1+tail composite (2 forward, 2 backward), conv2
+    (fwd, dgrad, wgrad), the bn2 tail (fwd, reduce, apply) and the fc
+    input-grad. None may be 'unmatched', or the composed cross-check is
+    withheld."""
+    from tpu_sandbox.utils.flops import s2d_custom_call_flops
+
+    def call(path):
+        return (f'  %k = bf16[1] custom-call(%a), custom_call_target='
+                f'"tpu_custom_call", metadata={{op_name="jit(train_step)/'
+                f'{path}/pallas_call"}}')
+
+    fwd, bwd = "jvp(ConvNetS2DT)", "transpose(jvp(ConvNetS2DT))"
+    hlo = "\n".join(
+        [call(f"{fwd}/bn1.fused_conv1")] * 2 + [call(f"{fwd}/conv2")]
+        + [call(f"{fwd}/ConvNetS2DT._tail/bn2.fused")]
+        + [call(f"{bwd}/fc")]
+        + [call(f"{bwd}/ConvNetS2DT._tail/bn2.fused")] * 2
+        + [call(f"{bwd}/conv2")] * 2 + [call(f"{bwd}/bn1.fused_conv1")] * 2
+    )
+    c = s2d_custom_call_flops(hlo, 5, 3000, plan="ConvNetS2DT",
+                              sparse_conv1=True)
+    assert c["custom_calls_counted"] == 11
+    assert c["unmatched_pallas_calls"] == 0
+    base = 2.0 * 5 * 750 * 750
+    # composite: conv + tail forward, reduce pass, then ONE backward
+    # kernel running both the selection matmul and the conv1 wgrad dot
+    assert c["per_class"]["bn1.fused_conv1"] == base * 64 * 256 * 5
+    assert c["per_class"]["fc"] == base * 10 * 32
+    assert c["per_class"]["conv2"] == base * 9 * 64 * 128 * 3
+    assert c["per_class"]["bn2.fused"] == base * 128 * 32 * 3
 
 
 def test_model_runs_sparse_conv1_tracks_field_and_env(monkeypatch):

@@ -118,7 +118,6 @@ def test_int8_error_feedback_telescopes(mesh8):
     sum of exact means, to fp32 roundoff: the residual carries exactly
     what the quantizer dropped, so the error telescopes instead of
     accumulating — the whole reason error feedback exists."""
-    from tpu_sandbox.utils.compat import shard_map
 
     policy = CompressedAllReduce(mode="int8", block=128)
     rng = np.random.default_rng(1)
@@ -128,7 +127,7 @@ def test_int8_error_feedback_telescopes(mesh8):
     def body(v, res):
         return policy.pmean(v[0], "data", WORLD, res[0])
 
-    run = shard_map(
+    run = jax.shard_map(
         lambda v, r: tuple(x[None] for x in body(v, r)),
         mesh=mesh8, in_specs=(P("data"), P("data")),
         out_specs=(P("data"), P("data")), check_vma=False)

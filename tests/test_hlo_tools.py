@@ -65,6 +65,31 @@ def test_collective_bytes_per_opcode():
     assert "all-gather-done" not in out["by_opcode"]
 
 
+def test_collective_bytes_resolves_operands_printed_by_name():
+    """The installed XLA prints operands without shapes
+    (``all-reduce(%fusion.3)``): bytes come from the result shape of the
+    instruction that defined each name — also across computations, and for
+    a tuple all-reduce over several operands."""
+    out = collective_bytes("""\
+HloModule mod, is_scheduled=true
+
+%body (p: f32[64,128]) -> f32[64,128] {
+  %p = f32[64,128]{1,0} parameter(0)
+  ROOT %ag = f32[256,128]{1,0} all-gather(%p), dimensions={0}
+}
+
+ENTRY %main.1 (p0: f32[256,128], p1: bf16[10]) -> f32[256,128] {
+  %p0 = f32[256,128]{1,0} parameter(0)
+  %p1 = bf16[10]{0} parameter(1)
+  %fusion.3 = f32[256,128]{1,0} fusion(%p0), kind=kLoop, calls=%f
+  %ar = (f32[256,128]{1,0}, bf16[10]{0}) all-reduce(%fusion.3, %p1), to_apply=%add
+  ROOT %gte = f32[256,128]{1,0} get-tuple-element(%ar), index=0
+}
+""")
+    assert out["by_opcode"]["all-reduce"] == 256 * 128 * 4 + 10 * 2
+    assert out["by_opcode"]["all-gather"] == 64 * 128 * 4
+
+
 def test_collective_bytes_ignores_non_collectives():
     assert collective_bytes("""\
 ENTRY %m (p0: f32[8]) -> f32[8] {
@@ -121,6 +146,12 @@ def test_schedule_report_monolithic_baseline():
     assert rep["comm_bytes_overlapped"] == 0
     assert rep["exposed_comm_fraction"] == 1.0
     assert rep["last_bwd_compute_op"] == "fusion.bwd"
+
+
+def test_schedule_report_resolves_operands_printed_by_name():
+    rep = schedule_report(_MONO_HLO.replace("f32[256,128] %", "%")
+                          .replace("f32[] %", "%"))
+    assert rep["comm_bytes_exposed"] == 256 * 128 * 4
 
 
 def test_schedule_report_overlapped_schedule():

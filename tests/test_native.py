@@ -26,6 +26,34 @@ needs_native = pytest.mark.skipif(not HAVE_NATIVE, reason="native build unavaila
 
 
 @needs_native
+def test_library_is_named_by_the_hash_of_its_source(tmp_path, monkeypatch):
+    """lib/ travels with any copy of the tree as it stands on disk, so a
+    binary may only be picked up if it was built from exactly the source
+    beside it: the name carries the source's hash, and a source edit means
+    a different file — a stale build is never loaded, whatever its mtime."""
+    import hashlib
+    import shutil
+
+    from tpu_sandbox.native import build
+
+    src = build._SRC / "dataloader.cpp"
+    out = build.build_library("dataloader")
+    sha = hashlib.sha256(src.read_bytes()).hexdigest()[:16]
+    assert out.name == f"dataloader-{sha}.so"
+
+    # same tree with an edited source: the old binary is not what we get
+    monkeypatch.setattr(build, "_SRC", tmp_path / "src")
+    monkeypatch.setattr(build, "_LIB", tmp_path / "lib")
+    (tmp_path / "src").mkdir()
+    (tmp_path / "lib").mkdir()
+    shutil.copy(out, tmp_path / "lib" / out.name)
+    (tmp_path / "src" / "dataloader.cpp").write_text(
+        src.read_text() + "\n// edited\n")
+    rebuilt = build.build_library("dataloader")
+    assert rebuilt.name != out.name and rebuilt.exists()
+
+
+@needs_native
 def test_native_loader_matches_python_loader():
     from tpu_sandbox.data.native_loader import NativeBatchLoader
 

@@ -1,8 +1,7 @@
-"""utils/profiling.py coverage: the trace() wrapper (including the
-newer-jax ``start_trace`` signature fallback), region annotation, the
-fetch-synced host_sync primitive, StepTimer, and the differential
-per-step measurement — all on CPU with stubbed profilers where the real
-one would write trace directories."""
+"""utils/profiling.py coverage: the trace() wrapper against the installed
+``jax.profiler.start_trace`` signature (stubbed, and once for real),
+region annotation, the fetch-synced host_sync primitive, StepTimer, and
+the differential per-step measurement — all on CPU."""
 
 import math
 import time
@@ -14,48 +13,43 @@ from tpu_sandbox.utils import profiling
 
 
 class _StubProfiler:
-    """Records start/stop calls; optionally rejects the tracer-options
-    kwarg the way newer jax releases do."""
+    """Records start/stop calls behind the installed ``start_trace``
+    signature; ``ProfileOptions`` is the real class."""
 
-    def __init__(self, accepts_options: bool):
-        self.accepts_options = accepts_options
+    ProfileOptions = profiling.jax.profiler.ProfileOptions
+
+    def __init__(self):
         self.calls = []
 
-    def start_trace(self, logdir, **kwargs):
-        if kwargs and not self.accepts_options:
-            raise TypeError(
-                "start_trace() got an unexpected keyword argument "
-                f"{next(iter(kwargs))!r}")
-        self.calls.append(("start", logdir, dict(kwargs)))
+    def start_trace(self, log_dir, create_perfetto_link=False,
+                    create_perfetto_trace=False, profiler_options=None):
+        self.calls.append(("start", log_dir, profiler_options))
 
     def stop_trace(self):
         self.calls.append(("stop",))
 
 
-def test_trace_passes_tracer_options_when_supported(monkeypatch, tmp_path):
-    stub = _StubProfiler(accepts_options=True)
+def test_trace_passes_host_tracer_level_in_profile_options(
+        monkeypatch, tmp_path):
+    stub = _StubProfiler()
     monkeypatch.setattr(profiling.jax, "profiler", stub)
     with profiling.trace(str(tmp_path), host_tracer_level=3):
         pass
-    assert stub.calls == [
-        ("start", str(tmp_path), {"host_tracer_level": 3}),
-        ("stop",),
-    ]
+    (start, logdir, options), stop = stub.calls
+    assert (start, logdir, stop) == ("start", str(tmp_path), ("stop",))
+    assert options.host_tracer_level == 3
 
 
-def test_trace_falls_back_when_start_trace_rejects_options(
-        monkeypatch, tmp_path):
-    # newer jax moved tracer options off start_trace: the first attempt
-    # raises TypeError and trace() must retry bare, not propagate
-    stub = _StubProfiler(accepts_options=False)
-    monkeypatch.setattr(profiling.jax, "profiler", stub)
+def test_trace_writes_an_xplane_with_the_installed_profiler(tmp_path):
+    # no stub: the call must be one the installed jax accepts (the old
+    # ``host_tracer_level=`` keyword was always rejected by it)
     with profiling.trace(str(tmp_path)):
-        pass
-    assert stub.calls == [("start", str(tmp_path), {}), ("stop",)]
+        jnp.ones((8, 8)).sum().block_until_ready()
+    assert list(tmp_path.rglob("*.xplane.pb"))
 
 
 def test_trace_stops_profiler_on_body_exception(monkeypatch, tmp_path):
-    stub = _StubProfiler(accepts_options=True)
+    stub = _StubProfiler()
     monkeypatch.setattr(profiling.jax, "profiler", stub)
     with pytest.raises(RuntimeError, match="boom"):
         with profiling.trace(str(tmp_path)):

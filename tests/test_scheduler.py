@@ -325,7 +325,9 @@ def test_cogroup_preempts_room_for_whole_group(agent_script):
             agent_argv=_agent_argv(agent_script, "preemptible")))
         assert _tick_until(sched, lambda: (
             sched.kv.try_get(k_state("occupant")) == b"running"
-            and sched.kv.keys("job/occupant/test/ran/")))
+            # BOTH agents' handlers in place: a SIGTERM that beats one of
+            # them kills it outright and the preemption never completes
+            and len(sched.kv.keys("job/occupant/test/ran/")) == 2))
         for s in (0, 1):
             sched.submit(JobSpec(job_id=f"stage{s}", hosts=1, world_size=1,
                                  priority=5, cogroup="pipe0",
@@ -675,7 +677,8 @@ def test_autoscaler_preempts_training_and_returns_slots(agent_script):
             agent_argv=_agent_argv(agent_script, "preemptible")))
         assert _tick_until(sched, lambda: (
             sched.kv.try_get(k_state("train")) == b"running"
-            and sched.kv.keys("job/train/test/ran/")))
+            # both agents' SIGTERM handlers in place (see the cogroup test)
+            and len(sched.kv.keys("job/train/test/ran/")) == 2))
         asc = ReplicaAutoscaler(
             sched.kv, _agent_argv(agent_script, "work", 60.0),
             cfg=AutoscaleConfig(min_replicas=0, max_replicas=2,

@@ -26,7 +26,7 @@ _HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, _HERE)
 sys.path.insert(0, os.path.dirname(_HERE))
 
-from aot_v5e import HBM_BW, make_topology, unwrap_cost  # noqa: E402
+from aot_v5e import HBM_BW, make_topology  # noqa: E402
 
 
 def compile_lm_step(topo, batch: int, seq: int = 2048):
@@ -102,7 +102,7 @@ def main() -> None:
                      (op.group(1) if op else "")))
     rows.sort(reverse=True)
 
-    ca = unwrap_cost(compiled)
+    ca = compiled.cost_analysis()
     doc = {
         "what": ("per-op estimated_cycles (940 MHz -> ms) of the"
                  " non-Pallas entry ops in the AOT-compiled LM train"

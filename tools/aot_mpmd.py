@@ -27,7 +27,7 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from tools.aot_v5e import make_topology, unwrap_cost  # noqa: E402
+from tools.aot_v5e import make_topology  # noqa: E402
 
 
 def mpmd_aot_report(*, n_stages: int = 4, microbatches: int = 4,
@@ -99,7 +99,7 @@ def mpmd_aot_report(*, n_stages: int = 4, microbatches: int = 4,
         for name, low in lowered.items():
             compiled = low.compile()
             ma = compiled.memory_analysis()
-            ca = unwrap_cost(compiled)
+            ca = compiled.cost_analysis()
             programs[name] = {
                 "argument_bytes": ma.argument_size_in_bytes,
                 "output_bytes": ma.output_size_in_bytes,

@@ -25,7 +25,7 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from tools.aot_v5e import make_topology, unwrap_cost  # noqa: E402
+from tools.aot_v5e import make_topology  # noqa: E402
 
 
 def compile_decode(topo, *, num_blocks: int, block_size: int,
@@ -71,7 +71,7 @@ def compile_decode(topo, *, num_blocks: int, block_size: int,
 
 def analyze(compiled, cache_bytes: int, args) -> dict:
     ma = compiled.memory_analysis()
-    ca = unwrap_cost(compiled)
+    ca = compiled.cost_analysis()
     alias = ma.alias_size_in_bytes
     return {
         "metric": "serve_aot_donation",

@@ -44,7 +44,13 @@ _HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, _HERE)            # import aot_v5e / hlo_traffic as siblings
 sys.path.insert(0, os.path.dirname(_HERE))  # import tpu_sandbox from the repo
 
-from hlo_traffic import _COLLECTIVES, shape_bytes  # noqa: E402
+from hlo_traffic import (  # noqa: E402
+    _COLLECTIVES,
+    _INST,
+    operand_bytes,
+    operand_tokens,
+    shape_bytes,
+)
 
 # aot_v5e (and with it libtpu topologies) stays lazy in the driver below:
 # schedule_report() must be importable on CPU-only boxes — the tier-1
@@ -55,9 +61,6 @@ from hlo_traffic import _COLLECTIVES, shape_bytes  # noqa: E402
 #: convolutions are the backward work itself; custom-call catches Mosaic.
 _COMPUTE = ("dot", "convolution", "fusion", "custom-call")
 
-_INST = re.compile(
-    r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.+?)\s+([a-z][\w\-]*)\((.*)$"
-)
 _BWD = re.compile(r'op_name="[^"]*transpose\(')
 
 #: Latency-hiding / async-collective knobs for TPU AOT compiles (the
@@ -72,33 +75,6 @@ TPU_OVERLAP_COMPILER_OPTIONS = {
 }
 
 
-def _operand_region(rest: str) -> str:
-    """The operand list of one instruction: everything up to the first ')'
-    that is outside layout braces and balanced parens. TPU layouts carry
-    parens INSIDE braces (``{0:T(8,128)S(1)}``), so a bare split on ')'
-    truncates mid-layout; tuple-shaped operands open parens of their own.
-    """
-    brace = paren = 0
-    for i, ch in enumerate(rest):
-        if ch == "{":
-            brace += 1
-        elif ch == "}":
-            brace -= 1
-        elif brace == 0 and ch == "(":
-            paren += 1
-        elif brace == 0 and ch == ")":
-            if paren == 0:
-                return rest[:i]
-            paren -= 1
-    return rest
-
-
-def _operand_tokens(rest: str) -> list[str]:
-    """Candidate operand names, '%' sigil optional (dumps come both ways).
-    Shape/dtype tokens ride along; callers filter by known names."""
-    return re.findall(r"%?([\w.\-]+)", _operand_region(rest))
-
-
 def schedule_report(hlo_text: str) -> dict:
     """Schedule-structure report of an optimized (scheduled) HLO module.
 
@@ -110,6 +86,7 @@ def schedule_report(hlo_text: str) -> dict:
     collectives = []    # per-collective detail rows, all computations
     issue_count = 0     # all-reduce issue points before last bwd compute
     last_bwd_op = None
+    result_bytes: dict[str, int] = {}  # instruction name -> result bytes
 
     def flush(ops):
         """Process one computation's ordered instruction list."""
@@ -133,13 +110,13 @@ def schedule_report(hlo_text: str) -> dict:
             if base not in _COLLECTIVES:
                 continue
             before_bwd = last_bwd is not None and i < last_bwd
-            nbytes = shape_bytes(_operand_region(rest))
+            nbytes = operand_bytes(rest, result_bytes)
             if opcode.endswith("-start"):
                 starts[name] = (i, base, nbytes)
                 if base == "all-reduce" and before_bwd:
                     issue_count += 1
             elif opcode.endswith("-done"):
-                for tok in _operand_tokens(rest):
+                for tok in operand_tokens(rest):
                     if tok in starts:
                         s_i, s_base, s_bytes = starts.pop(tok)
                         between = sum(1 for c in compute_idx if s_i < c < i)
@@ -186,7 +163,8 @@ def schedule_report(hlo_text: str) -> dict:
             continue
         m = _INST.match(line)
         if m:
-            name, _shape, opcode, rest = m.groups()
+            name, shape, opcode, rest = m.groups()
+            result_bytes[name] = shape_bytes(shape)
             ops.append((name, opcode, rest, line))
     flush(ops)
 

@@ -83,8 +83,47 @@ _COLLECTIVES = (
 )
 
 _INST = re.compile(
-    r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(.+?)\s+([a-z][\w\-]*)\((.*)$"
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.+?)\s+([a-z][\w\-]*)\((.*)$"
 )
+
+
+def operand_region(rest: str) -> str:
+    """The operand list of one instruction: everything up to the first ')'
+    that is outside layout braces and balanced parens. TPU layouts carry
+    parens INSIDE braces (``{0:T(8,128)S(1)}``), so a bare split on ')'
+    truncates mid-layout; tuple-shaped operands open parens of their own.
+    """
+    brace = paren = 0
+    for i, ch in enumerate(rest):
+        if ch == "{":
+            brace += 1
+        elif ch == "}":
+            brace -= 1
+        elif brace == 0 and ch == "(":
+            paren += 1
+        elif brace == 0 and ch == ")":
+            if paren == 0:
+                return rest[:i]
+            paren -= 1
+    return rest
+
+
+def operand_tokens(rest: str) -> list[str]:
+    """Candidate operand names, '%' sigil optional (dumps come both ways).
+    Shape/dtype tokens ride along; callers filter by known names."""
+    return re.findall(r"%?([\w.\-]+)", operand_region(rest))
+
+
+def operand_bytes(rest: str, result_bytes: dict[str, int]) -> int:
+    """Padded bytes of one instruction's operands. The installed XLA prints
+    operands by name only (``all-reduce(%fusion.3)``), so names resolve
+    through ``result_bytes``: the result shapes of the instructions seen so
+    far (names are unique within a module). A dump printed with operand
+    shapes carries them inline, and those win."""
+    inline = shape_bytes(operand_region(rest))
+    if inline:
+        return inline
+    return sum(result_bytes.get(tok, 0) for tok in operand_tokens(rest))
 
 
 def collective_bytes(hlo_text: str) -> dict:
@@ -101,16 +140,17 @@ def collective_bytes(hlo_text: str) -> dict:
     Returns ``{"total": int, "by_opcode": {opcode: int}}``.
     """
     by_opcode: dict[str, int] = collections.defaultdict(int)
+    result_bytes: dict[str, int] = {}
     for line in hlo_text.splitlines():
         m = _INST.match(line)
         if not m:
             continue
-        _shape, opcode, rest = m.groups()
+        name, shape, opcode, rest = m.groups()
+        result_bytes[name] = shape_bytes(shape)
         base = opcode[:-6] if opcode.endswith("-start") else opcode
         if base not in _COLLECTIVES or opcode.endswith("-done"):
             continue
-        # operand list ends at the first ')' (shapes carry no parens)
-        by_opcode[base] += shape_bytes(rest.split(")")[0])
+        by_opcode[base] += operand_bytes(rest, result_bytes)
     return {"total": sum(by_opcode.values()), "by_opcode": dict(by_opcode)}
 
 
@@ -184,11 +224,9 @@ def main():
         if opcode in ("parameter", "constant", "get-tuple-element", "tuple",
                       "bitcast"):
             continue
-        # operand list ends at the first ')'; tokens are matched with an
-        # OPTIONAL '%' sigil (HLO dumps come both ways) and filtered
-        # through the name table, so comment/keyword tokens count as 0
-        operand_names = re.findall(r"%?([\w.\-]+)", rest.split(")")[0])
-        in_b = sum(shapes.get(o, 0) for o in operand_names)
+        # tokens are filtered through the name table, so shape, comment
+        # and keyword tokens count as 0
+        in_b = sum(shapes.get(o, 0) for o in operand_tokens(rest))
         rows.append({
             "op": name, "class": classify(opcode, m.group(0), out_b),
             "opcode": opcode, "write_mb": out_b / 1e6, "read_mb": in_b / 1e6,

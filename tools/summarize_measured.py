@@ -3,9 +3,8 @@
 Reads every ``measured/*_r{N}*.json[l]`` artifact plus the current-round
 err files and prints a compact table: headline images/sec lines (with
 plan, loss flag, fallbacks), capacity, kernel micro rows (min + spread),
-lm/seq rows, and which rungs never produced output. Run after the
-recovery ladder (tools/rerun_on_recovery.sh) finishes — or any time, to
-see what is still missing.
+lm/seq rows, and which rungs never produced output. Run any time, to see
+what is still missing.
 
 Usage: python tools/summarize_measured.py [--round 4]
 """

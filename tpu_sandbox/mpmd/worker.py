@@ -81,8 +81,6 @@ def main(argv=None) -> int:
     p.add_argument("--get-timeout", type=float, default=120.0)
     args = p.parse_args(argv)
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
     import jax
     import numpy as np
 

@@ -4,11 +4,17 @@ The reference's native layer ships precompiled inside torch wheels; here
 the sources live in tpu_sandbox/native/src/ and compile once per machine
 into native/lib/ (g++ -O3 -shared -fPIC). No pybind11 — plain C ABIs
 loaded with ctypes.
+
+The output is named by a hash of its source (``lib/<name>-<sha>.so``):
+lib/ is git-ignored but travels with any copy of the tree as it stands on
+disk, so a binary is only ever loaded if it was built from exactly the
+source file beside it.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import tempfile
@@ -24,13 +30,15 @@ class NativeBuildError(RuntimeError):
 
 
 def build_library(name: str, *, force: bool = False) -> Path:
-    """Compile src/<name>.cpp -> lib/<name>.so if missing/stale; return path."""
+    """Compile src/<name>.cpp -> lib/<name>-<sha>.so unless that exact
+    build exists; return its path."""
     src = _SRC / f"{name}.cpp"
     if not src.exists():
         raise NativeBuildError(f"no such native source: {src}")
     _LIB.mkdir(exist_ok=True)
-    out = _LIB / f"{name}.so"
-    if not force and out.exists() and out.stat().st_mtime >= src.stat().st_mtime:
+    digest = hashlib.sha256(src.read_bytes()).hexdigest()[:16]
+    out = _LIB / f"{name}-{digest}.so"
+    if not force and out.exists():
         return out
     # build to a temp file then atomic-rename: concurrent builders race safely
     with tempfile.NamedTemporaryFile(

@@ -36,8 +36,6 @@ import functools
 import math
 
 import jax
-
-from tpu_sandbox.utils.compat import tpu_compiler_params
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
@@ -187,7 +185,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, kv_len,
             pltpu.VMEM((block_q, _LANE), jnp.float32),  # running sum l
             pltpu.VMEM((block_q, d), jnp.float32),      # output accumulator
         ],
-        compiler_params=tpu_compiler_params(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
@@ -354,7 +352,7 @@ def _flash_bwd(q, k, v, delta, lse, g, scale, causal, block_q, block_k,
 
     params = dict(scale=scale, causal=causal, block_q=block_q,
                   block_k=block_k, kv_len=kv_len)
-    compiler = tpu_compiler_params(pltpu)(
+    compiler = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
     )
 

@@ -41,8 +41,6 @@ from __future__ import annotations
 import functools
 
 import jax
-
-from tpu_sandbox.utils.compat import tpu_compiler_params
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
@@ -310,7 +308,7 @@ def _vjp_bwd(co, blk, eps, interpret, res, cts):
             pltpu.VMEM((1, c), jnp.float32),
             pltpu.VMEM((1, c), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interp,

@@ -27,8 +27,6 @@ from __future__ import annotations
 import functools
 
 import jax
-
-from tpu_sandbox.utils.compat import tpu_compiler_params
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
@@ -274,7 +272,7 @@ def bwd_reduce(y, g, co, blk, a_col, b_col, mu, inv, interpret):
             pltpu.VMEM((c, 1), jnp.float32),
             pltpu.VMEM((c, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interp,

@@ -46,8 +46,6 @@ from __future__ import annotations
 import functools
 
 import jax
-
-from tpu_sandbox.utils.compat import tpu_compiler_params
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -224,7 +222,7 @@ def _conv_call(x, w, bias, out_dtype, interpret, stats=False):
         ],
         out_specs=out_specs,
         scratch_shapes=scratch,
-        compiler_params=tpu_compiler_params(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=default_interpret(interpret),
@@ -274,7 +272,7 @@ def _conv_vjp_bwd(interpret, res, g):
             pltpu.VMEM((9 * c, co), jnp.float32),
             pltpu.VMEM((1, co), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=default_interpret(interpret),

@@ -42,8 +42,6 @@ from __future__ import annotations
 import functools
 
 import jax
-
-from tpu_sandbox.utils.compat import tpu_compiler_params
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -177,7 +175,7 @@ def _fused_wgrad(x, y1, g_pool, a_col, b_col, sel, mu_col, inv_col,
             pltpu.VMEM(dw_shape, jnp.float32),
             pltpu.VMEM((cbig, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT,
         ),

@@ -42,8 +42,6 @@ from __future__ import annotations
 import functools
 
 import jax
-
-from tpu_sandbox.utils.compat import tpu_compiler_params
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
@@ -237,7 +235,7 @@ def _conv_call(x, w1, bias_g, out_dtype, interpret, stats=False):
         ],
         out_specs=out_specs,
         scratch_shapes=scratch,
-        compiler_params=tpu_compiler_params(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT,
         ),
@@ -332,7 +330,7 @@ def conv1_s2d_t_wgrad(x, g, interpret=None, restage=None):
             pltpu.VMEM(dw_shape, jnp.float32),
             pltpu.VMEM((co, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT,
         ),

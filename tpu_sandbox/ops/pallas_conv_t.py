@@ -41,8 +41,6 @@ from __future__ import annotations
 import functools
 
 import jax
-
-from tpu_sandbox.utils.compat import tpu_compiler_params
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -250,7 +248,7 @@ def _conv_call(x, w, bias, out_dtype, interpret, stats=False):
         ],
         out_specs=out_specs,
         scratch_shapes=scratch,
-        compiler_params=tpu_compiler_params(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT,
         ),
@@ -317,7 +315,7 @@ def conv3x3_t_wgrad(x, g, interpret=None, restage=None):
             pltpu.VMEM(dw_shape, jnp.float32),
             pltpu.VMEM((co, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT,
         ),

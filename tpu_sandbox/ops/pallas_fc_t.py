@@ -38,8 +38,6 @@ from __future__ import annotations
 import functools
 
 import jax
-
-from tpu_sandbox.utils.compat import tpu_compiler_params
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -85,7 +83,7 @@ def fc_dgrad_t(g, wt, out_dtype, interpret=None):
             pl.BlockSpec((k, bh, c, w), lambda i: (0, i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((n, bh, c, w), lambda i: (0, i, 0, 0)),
-        compiler_params=tpu_compiler_params(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_VMEM_LIMIT,
         ),

@@ -27,8 +27,6 @@ from functools import partial
 from typing import Callable
 
 import jax
-
-from tpu_sandbox.utils.compat import shard_map
 import jax.numpy as jnp
 import numpy as np
 import optax
@@ -489,7 +487,7 @@ class DataParallel:
 
     def _compile_for(self, state: TrainState) -> Callable:
         specs = self._specs(state)
-        smapped = shard_map(
+        smapped = jax.shard_map(
             self._shard_body,
             mesh=self.mesh,
             in_specs=(specs, P(self.axis), P(self.axis)),

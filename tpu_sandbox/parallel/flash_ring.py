@@ -31,8 +31,6 @@ from __future__ import annotations
 from functools import partial
 
 import jax
-
-from tpu_sandbox.utils.compat import axis_size, shard_map
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
@@ -54,7 +52,7 @@ def _merge(o, lse, o_b, lse_b):
 
 
 def _ring_fwd_pass(q, k, v, axis_name, causal, block_q, block_k, interpret):
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     b, s_loc, h, d = q.shape
     q_off = idx * s_loc
@@ -99,7 +97,7 @@ def _fr_fwd(q, k, v, axis_name, causal, block_q, block_k, interpret):
 
 def _fr_bwd(axis_name, causal, block_q, block_k, interpret, res, g):
     q, k, v, out, lse = res
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     s_loc = q.shape[1]
     q_off = idx * s_loc
@@ -150,7 +148,7 @@ def make_flash_ring_attention(
         return flash_ring_attention(q, k, v, axis, causal, block_q, block_k,
                                     interpret)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(None, axis), P(None, axis), P(None, axis)),

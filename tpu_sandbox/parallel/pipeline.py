@@ -73,8 +73,6 @@ import functools
 from typing import Callable
 
 import jax
-
-from tpu_sandbox.utils.compat import shard_map
 import jax.numpy as jnp
 import numpy as np
 import optax
@@ -570,7 +568,7 @@ class PipelineParallel:
         specs = self._state_specs(state)
         bspec = (P(self.data_axis, self.seq_axis) if self.seq_axis
                  else P(self.data_axis))
-        smapped = shard_map(
+        smapped = jax.shard_map(
             self._body,
             mesh=self.mesh,
             in_specs=(specs, bspec, bspec),

@@ -344,8 +344,6 @@ class PjitEngine:
                 )
             from jax import lax
 
-            from tpu_sandbox.utils.compat import shard_map
-
             axis = self.batch_axis
             size = self.mesh.shape[axis]
 
@@ -364,7 +362,7 @@ class PjitEngine:
                     grads, _ = compress.pmean_tree(grads, axis, size, None)
                 return lax.pmean(loss, axis), grads
 
-            grads_fn = shard_map(
+            grads_fn = jax.shard_map(
                 grads_body,
                 mesh=self.mesh,
                 in_specs=(P(), P(axis), P(axis)),

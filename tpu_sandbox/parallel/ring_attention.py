@@ -26,8 +26,6 @@ from __future__ import annotations
 from functools import partial
 
 import jax
-
-from tpu_sandbox.utils.compat import axis_size, shard_map
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
@@ -81,7 +79,7 @@ def ring_attention(
     causal: bool = True,
 ) -> jnp.ndarray:
     """q,k,v: local shards [B, S/n, H, D] (inside shard_map). -> [B, S/n, H, D]."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     b, s_local, h, d = q.shape
     q_offset = idx * s_local
@@ -117,7 +115,7 @@ def make_ring_attention(mesh: Mesh, axis: str, *, causal: bool = True):
     sharded on dim 1."""
     if axis not in mesh.axis_names:
         raise ValueError(f"axis {axis!r} not in mesh axes {mesh.axis_names}")
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(ring_attention, axis_name=axis, causal=causal),
         mesh=mesh,
         in_specs=(P(None, axis), P(None, axis), P(None, axis)),

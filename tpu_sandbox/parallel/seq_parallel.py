@@ -21,8 +21,6 @@ from functools import partial
 from typing import Callable
 
 import jax
-
-from tpu_sandbox.utils.compat import shard_map
 import jax.numpy as jnp
 import numpy as np
 import optax
@@ -128,7 +126,7 @@ class SeqParallel:
 
         batch_spec = P(daxis, saxis)
         state_spec = TrainState(step=P(), params=P(), batch_stats=P(), opt_state=P())
-        smapped = shard_map(
+        smapped = jax.shard_map(
             body,
             mesh=self.mesh,
             in_specs=(state_spec, batch_spec, batch_spec, batch_spec),

@@ -34,10 +34,32 @@ from __future__ import annotations
 import os
 import socket
 from dataclasses import dataclass
+from pathlib import Path
 
 import jax
 
 SERIAL_RANK = -1
+
+#: Where compiled programs persist when nobody placed the cache from
+#: outside: a fixed path inside the checkout. The directory is part of the
+#: cache key, so it must never come from tempfile, a pid or the clock.
+DEFAULT_COMPILE_CACHE = Path(__file__).resolve().parents[2] / ".jax_cache"
+
+
+def configure_compile_cache() -> str:
+    """Give JAX a persistent compilation cache; return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR`` wins: JAX reads it by itself, and no code
+    may point the cache anywhere else (a machine that comes with the
+    variable set keeps the cache across runs only if the program writes
+    there). Unset, the cache lives at ``DEFAULT_COMPILE_CACHE``. Entry
+    scripts call this first, before anything compiles.
+    """
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    jax.config.update("jax_compilation_cache_dir", str(DEFAULT_COMPILE_CACHE))
+    return str(DEFAULT_COMPILE_CACHE)
 
 # Module state: records what init() decided, so entry scripts and tests can
 # query topology without re-deriving it.

@@ -716,7 +716,6 @@ def _build_engine(cfg: dict):
 
 
 def main(argv: Sequence[str] | None = None) -> int:
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     p = argparse.ArgumentParser()
     p.add_argument("--config", required=True,
                    help="JSON: model/cache/max_batch/buckets/param_seed/"

@@ -1,18 +1,14 @@
 """Entry-script device bootstrapping.
 
 The reference scripts fork one process per GPU rank (``mp.spawn``); here
-"ranks" are devices of one process. When the user asks for more ranks than
-the accelerator has (the common case on a 1-chip dev box), we fall back to
-N virtual CPU devices — the same trick the reference pulls with
-gloo-on-localhost (SURVEY §4), minus the processes. JAX keeps the CPU
-client alongside the accelerator client, so no platform flip is needed;
-``jax_num_cpu_devices`` just has to be set before any backend initializes,
-which is why entry scripts call this first.
+"ranks" are devices of one process: the host's real chips, or — only when
+the user passes ``--force-cpu`` — N virtual CPU devices (the same trick the
+reference pulls with gloo-on-localhost, SURVEY §4, minus the processes).
+``jax_num_cpu_devices`` has to be set before any backend initializes,
+which is why entry scripts call ``ensure_devices`` first.
 """
 
 from __future__ import annotations
-
-import os
 
 import jax
 
@@ -150,27 +146,6 @@ def add_elastic_cli(parser) -> None:
                              "secret)")
 
 
-def _request_cpu_devices(n: int) -> None:
-    """Ask for ``n`` virtual CPU devices, whatever this jax calls the knob.
-
-    Newer jax exposes the ``jax_num_cpu_devices`` config; older releases
-    only honor the XLA_FLAGS env var, which likewise must be set before
-    the CPU backend initializes.
-    """
-    try:
-        jax.config.update("jax_num_cpu_devices", n)
-        return
-    except AttributeError:
-        pass
-    flag = "--xla_force_host_platform_device_count"
-    flags = [
-        f for f in os.environ.get("XLA_FLAGS", "").split()
-        if not f.startswith(flag + "=")
-    ]
-    flags.append(f"{flag}={n}")
-    os.environ["XLA_FLAGS"] = " ".join(flags)
-
-
 def configure_worker_cpu(n: int = 1) -> None:
     """Per-rank worker processes: exactly ``n`` (usually 1) CPU device(s),
     regardless of any XLA_FLAGS the parent process exported (tests run
@@ -178,42 +153,42 @@ def configure_worker_cpu(n: int = 1) -> None:
     of ``world_size`` processes x 8 devices each is not the topology).
     Must run before the first device query."""
     jax.config.update("jax_platforms", "cpu")
-    try:
-        # cross-process CPU collectives run over gloo; without this the CPU
-        # backend refuses multiprocess computations outright
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):
-        pass  # jax versions that dropped/renamed the knob enable it themselves
-    _request_cpu_devices(n)
+    # cross-process CPU collectives run over gloo; without this the CPU
+    # backend refuses multiprocess computations outright
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
+    jax.config.update("jax_num_cpu_devices", n)
 
 
 def ensure_devices(n: int, force_cpu: bool = False) -> list:
-    """Return ``n`` devices to act as ranks, virtualizing on CPU if needed.
+    """Return ``n`` devices to act as ranks: the default backend's real
+    devices, or — only with ``force_cpu`` — ``n`` virtual CPU devices.
 
-    Preference order: real accelerator devices if there are enough of them;
-    otherwise ``n`` virtual CPU devices. ``force_cpu`` skips the accelerator
-    (useful for deterministic multi-rank demos on a 1-chip box).
+    Asking for more ranks than the backend has is an error that names
+    ``--force-cpu``: a run must never land on the CPU while its logs say
+    otherwise. ``force_cpu`` must be requested before any backend
+    initializes (entry scripts call this first).
     """
     if n < 1:
         raise ValueError(f"need at least 1 device, asked for {n}")
+    if not force_cpu:
+        if jax.device_count() < n:
+            raise RuntimeError(
+                f"wanted {n} ranks but the {jax.default_backend()} backend "
+                f"has {jax.device_count()} device(s); ask for at most that "
+                "many, or pass --force-cpu to run on virtual CPU devices"
+            )
+        return jax.devices()[:n]
     try:
-        # Pre-size the CPU client before any backend initializes so the
-        # fallback exists. Harmless if real devices suffice.
-        _request_cpu_devices(min(max(n, 1), _MAX_VIRTUAL))
-        if force_cpu:
-            # Exclude the accelerator platform entirely: initializing it just
-            # to ignore it can hang (and wastes its memory grant).
-            jax.config.update("jax_platforms", "cpu")
+        # Exclude the accelerator platform entirely: initializing it just to
+        # ignore it takes its memory grant (and, on a TPU host, the chip).
+        jax.config.update("jax_platforms", "cpu")
+        jax.config.update("jax_num_cpu_devices", min(n, _MAX_VIRTUAL))
     except RuntimeError:
         pass  # backends already up; the current CPU client size is fixed
-    if not force_cpu:
-        if jax.device_count() >= n:
-            return jax.devices()[:n]
     cpu = jax.devices("cpu")
     if len(cpu) < n:
         raise RuntimeError(
-            f"wanted {n} ranks; have {jax.device_count()} "
-            f"{jax.default_backend()} device(s) and {len(cpu)} CPU device(s), "
-            "and the CPU client size is already fixed for this process"
+            f"wanted {n} virtual CPU ranks but the CPU client already holds "
+            f"{len(cpu)} device(s) and its size is fixed for this process"
         )
     return cpu[:n]

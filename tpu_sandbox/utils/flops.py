@@ -25,29 +25,35 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
-# bf16 peak matmul TFLOP/s per chip, keyed by substrings of
-# jax.Device.device_kind. Public figures (cloud.google.com/tpu docs):
-#   v2 46, v3 123, v4 275, v5e 197, v5p 459, v6e (Trillium) 918.
-# 'TPU v5 lite' is what jax reports for v5e; 'TPU v6 lite' for v6e.
+#: bf16 peak matmul TFLOP/s per chip, keyed by the EXACT
+#: ``jax.Device.device_kind`` string (spellings as in the installed jax's
+#: own table, jax/_src/pallas/mosaic/tpu_info.py). Figures: Google Cloud
+#: TPU documentation, the "System architecture" page of each generation
+#: (cloud.google.com/tpu/docs/v5e: 197 TFLOP/s bf16 per v5e chip).
+#: A kind that is not listed has no peak here: it is an error, never a
+#: guess ("TPU v5" is a v5p to jax, not a v5e).
 PEAK_BF16_TFLOPS: dict[str, float] = {
-    "TPU v6 lite": 918.0,
-    "TPU v6": 918.0,
+    "TPU v6 lite": 918.0,  # v6e
     "TPU v5p": 459.0,
-    "TPU v5 lite": 197.0,
-    "TPU v5": 197.0,
+    "TPU v5 lite": 197.0,  # v5e
     "TPU v4": 275.0,
     "TPU v3": 123.0,
     "TPU v2": 46.0,
 }
 
 
-def device_peak_tflops(device_kind: str) -> float | None:
-    """bf16 peak for a device_kind string, or None if unknown (e.g. 'cpu' —
-    no published peak, MFU is then not computed rather than faked)."""
-    for key in sorted(PEAK_BF16_TFLOPS, key=len, reverse=True):
-        if key.lower() in device_kind.lower():
-            return PEAK_BF16_TFLOPS[key]
-    return None
+def device_peak_tflops(device_kind: str) -> float:
+    """bf16 peak for an exact ``device_kind``. An unlisted kind (``cpu``
+    included) raises: a utilization against an assumed peak is not a
+    measurement."""
+    try:
+        return PEAK_BF16_TFLOPS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published bf16 peak for device_kind {device_kind!r}; "
+            f"known kinds: {sorted(PEAK_BF16_TFLOPS)}. Add it to "
+            "PEAK_BF16_TFLOPS with its source before measuring on it."
+        ) from None
 
 
 def conv2d_flops(h: int, w: int, c_in: int, c_out: int, k: int) -> float:
@@ -93,16 +99,46 @@ def convnet_flops(image_size: int, num_classes: int = 10) -> ConvNetFlops:
 #: scattered 3x3 at the s2d channel widths (conv1: 16 in -> 256 out;
 #: conv2: 64 in -> 128 out); the bn tails' matmuls are the pool
 #: compaction/scatter selections (bn1: [64,256] sel; bn2: [32,128]).
+#: ``bn1.fused_conv1`` is the scope of the conv1+tail composite
+#: (ops/pallas_conv1_tail_t.py). Forward: the sparse conv1 (64 tap rows
+#: x 256) and the tail (256 x 64 selection) — the same depth. ``fc`` is
+#: the head's Pallas input-grad (ops/pallas_fc_t.py: 10 classes x f2 = 32
+#: channels per output position; its forward and wgrad are XLA dots).
 _S2D_KERNEL_K = {
+    "/bn1.fused_conv1/": 64 * 256,
     "/conv1/": 9 * 16 * 256,   # in 16 (s2d image), out blk^2*f1 = 256
     "/conv2/": 9 * 64 * 128,   # in 4*f1 = 64 (pool1), out blk^2*f2 = 128
     "/bn1.fused/": 256 * 64,   # pool compaction/scatter selection matmuls
     "/bn2.fused/": 128 * 32,
+    "/fc/": 10 * 32,
 }
+
+#: backward calls whose depth differs from the class's forward: the
+#: composite's backward is the tail's reduce pass (one selection matmul)
+#: plus ONE kernel doing the selection matmul AND the conv1 wgrad dot —
+#: three 64x256-deep contractions over two calls, so 1.5 per call.
+_S2D_KERNEL_K_BWD = {"/bn1.fused_conv1/": 1.5 * 64 * 256}
 
 #: the transposed plan's conv1 runs the sparse-tap union-tile kernel
 #: since r04 (ops/pallas_conv5_t.py): K = 64 tap rows, not 9C = 144
 _S2DT_OVERRIDES = {"/conv1/": 64 * 256}
+
+
+def pallas_call_paths(hlo_text: str) -> list[str]:
+    """The ``op_name`` path of every Pallas kernel instruction in an HLO
+    module: ``%name = <shape> custom-call(...)`` lines whose metadata path
+    ends in .../pallas_call (plain XLA gather/scatter ops under the same
+    module paths, and non-Pallas custom calls, are not kernels)."""
+    import re
+
+    paths = []
+    for line in hlo_text.splitlines():
+        if not re.search(r"= [^=]*custom-call\(", line):
+            continue
+        m = re.search(r'op_name="([^"]*)"', line)
+        if m and "/pallas_call" in m.group(1):
+            paths.append(m.group(1))
+    return paths
 
 
 def model_runs_sparse_conv1(model) -> bool:
@@ -140,8 +176,6 @@ def s2d_custom_call_flops(hlo_text: str, batch: int, image_size: int,
     (ADVICE r04 medium). Callers that know the model should pass
     ``model_runs_sparse_conv1(model)``; None falls back to the plan-name
     heuristic for HLO-only callers."""
-    import re
-
     h = w = image_size // 4
     base = 2.0 * batch * h * w
     table = dict(_S2D_KERNEL_K)
@@ -151,18 +185,11 @@ def s2d_custom_call_flops(hlo_text: str, batch: int, image_size: int,
         table.update(_S2DT_OVERRIDES)
     per_class: dict[str, float] = {}
     count = unmatched = 0
-    for line in hlo_text.splitlines():
-        # a Pallas kernel instruction: `%name = <shape> custom-call(...)`
-        # whose metadata path ends in .../pallas_call (plain XLA
-        # gather/scatter ops under the same module paths must not count)
-        if not re.search(r"= [^=]*custom-call\(", line):
-            continue
-        m = re.search(r'op_name="([^"]*)"', line)
-        path = m.group(1) if m else ""
-        if "/pallas_call" not in path:
-            continue
+    for path in pallas_call_paths(hlo_text):
         for tag, k in table.items():
             if tag in path:
+                if "transpose(" in path:
+                    k = _S2D_KERNEL_K_BWD.get(tag, k)
                 key = tag.strip("/")
                 per_class[key] = per_class.get(key, 0.0) + base * k
                 count += 1
@@ -196,17 +223,16 @@ def mfu(flops_per_step: float, sec_per_step: float, device_kind: str,
         n_devices: int = 1) -> dict:
     """Achieved TFLOP/s + model-FLOPs utilization, with a sanity verdict.
 
-    Returns achieved_tflops, peak_tflops (None if unknown chip), mfu (None
-    if peak unknown), and plausible=False when mfu > 1 — the r01 failure
-    mode this module exists to catch.
+    Returns achieved_tflops, peak_tflops_bf16, mfu, and plausible=False
+    when mfu > 1 — the r01 failure mode this module exists to catch.
+    Raises ``ValueError`` for a ``device_kind`` with no published peak.
     """
     achieved = flops_per_step / sec_per_step / 1e12
-    peak = device_peak_tflops(device_kind)
-    total_peak = peak * n_devices if peak is not None else None
-    util = achieved / total_peak if total_peak else None
+    total_peak = device_peak_tflops(device_kind) * n_devices
+    util = achieved / total_peak
     return {
         "achieved_tflops": achieved,
         "peak_tflops_bf16": total_peak,
         "mfu": util,
-        "plausible": util is None or 0.0 < util <= 1.0,
+        "plausible": 0.0 < util <= 1.0,
     }
