@@ -69,6 +69,7 @@ def train(args):
     from tpu_sandbox.runtime import bootstrap
     from tpu_sandbox.runtime.mesh import make_mesh
     from tpu_sandbox.train import TrainState
+    from tpu_sandbox.train.trainer import LoopSpans
 
     bootstrap.init()
     n = len(devices)
@@ -229,15 +230,21 @@ def train(args):
     state = eng.shard_state(state)
     start = datetime.datetime.now()
     losses = []
-    for step, (tokens, targets) in enumerate(
-        make_batches(args.vocab, args.batch, args.seq_len, args.steps, 0), 1
+    spans = LoopSpans()  # the same four spans as Trainer's loop
+    for step, (tokens, targets) in enumerate(spans.batches(
+        make_batches(args.vocab, args.batch, args.seq_len, args.steps, 0)), 1
     ):
-        state, loss = eng.train_step(state, *eng.shard_batch(tokens, targets))
+        with spans.dispatch():
+            state, loss = eng.train_step(
+                state, *eng.shard_batch(tokens, targets))
+        spans.returned(step)
         if step % args.log_every == 0 or step == args.steps:
-            loss_v = float(np.ravel(np.asarray(loss))[0])
+            with spans.sync("log"):
+                loss_v = float(np.ravel(np.asarray(loss))[0])
             losses.append(loss_v)
             print(f"[{p}] Step [{step}/{args.steps}], Loss: {loss_v:.4f}",
                   flush=True)
+    spans.rec.flush()
     print(f"Training complete in: {datetime.datetime.now() - start}")
     if len(losses) >= 2 and not losses[-1] < losses[0]:
         raise SystemExit(f"loss did not decrease: {losses[0]} -> {losses[-1]}")

@@ -181,7 +181,11 @@ def build_parser():
                         help="save a checkpoint here after training")
     parser.add_argument("--profile", type=str, default=None, metavar="DIR",
                         help="capture a jax.profiler trace of training into "
-                             "DIR (view in TensorBoard/Perfetto)")
+                             "DIR (view in TensorBoard/Perfetto): device ops "
+                             "on the /device:TPU:N planes and, on the same "
+                             "clock, the program's own spans (train:next_batch"
+                             ", train:dispatch, train:sync, setup:*) on "
+                             "/host:CPU")
     parser.add_argument("--eval", action="store_true",
                         help="report test-set accuracy after training")
     parser.add_argument("--resume", action="store_true",

@@ -4,7 +4,7 @@ Static ``snake.dotted`` literals; bounded dimensions ride ``labels=``
 instead of being baked into the name.
 """
 
-from tpu_sandbox.obs import get_registry
+from tpu_sandbox.obs import get_recorder, get_registry
 
 
 def static_names(tenant):
@@ -21,3 +21,12 @@ def keyword_name():
 def non_registry_receiver(index):
     # instrument-shaped calls on non-registry objects are out of scope
     index.counter(f"dynamic.{index}").inc()
+
+
+def span_histogram(t0):
+    rec = get_recorder()
+    with rec.span("train:dispatch", hist="train.dispatch_s", loop=True):
+        pass
+    rec.complete("train:step", t0, hist="train.step_s", loop=True)
+    with rec.span("admit", hist=None):
+        pass

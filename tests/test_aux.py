@@ -11,7 +11,6 @@ from tpu_sandbox.models import ConvNet
 from tpu_sandbox.train import TrainState, make_train_step
 from tpu_sandbox.train import checkpoint as ckpt
 from tpu_sandbox.utils.metrics import MetricsWriter, read_metrics
-from tpu_sandbox.utils.profiling import StepTimer
 
 
 def small_state(lr=0.05):
@@ -60,18 +59,6 @@ def test_metrics_writer_roundtrip(tmp_path):
     records = read_metrics(path)
     assert [r["step"] for r in records] == [1, 2]
     assert records[1]["loss"] == 0.75
-
-
-def test_step_timer():
-    import time
-
-    t = StepTimer(warmup=1)
-    t.start()
-    for _ in range(4):
-        time.sleep(0.01)
-        t.tick(n_items=10)
-    assert 0.005 < t.seconds_per_step < 0.1
-    assert t.items_per_second > 50
 
 
 def test_pallas_ce_matches_reference():

@@ -220,11 +220,12 @@ def test_bad_metrics_fixture_fires_gl_o402():
     by_rule = {}
     for f in findings:
         by_rule.setdefault(f.rule, []).append(f)
-    # trips ONLY the metric-name rule — three spellings: f-string,
-    # concatenation, flat (undotted) literal
+    # trips ONLY the metric-name rule — four spellings: f-string,
+    # concatenation, flat (undotted) literal, and a span's dynamic hist=
     assert set(by_rule) == {"GL-O402"}
-    assert len(by_rule["GL-O402"]) == 3
+    assert len(by_rule["GL-O402"]) == 4
     msgs = "\n".join(f.message for f in by_rule["GL-O402"])
+    assert "span() hist=" in msgs
     assert "counter()" in msgs
     assert "gauge()" in msgs
     assert "histogram()" in msgs

@@ -1,7 +1,8 @@
 """utils/profiling.py coverage: the trace() wrapper against the installed
-``jax.profiler.start_trace`` signature (stubbed, and once for real),
-region annotation, the fetch-synced host_sync primitive, StepTimer, and
-the differential per-step measurement — all on CPU."""
+``jax.profiler.start_trace`` signature (stubbed, and once for real), the
+fetch-synced host_sync primitive and the differential per-step measurement
+— all on CPU. (Regions are annotated by ``obs.record.Recorder.span``:
+tests/test_program_spans.py.)"""
 
 import math
 import time
@@ -57,42 +58,10 @@ def test_trace_stops_profiler_on_body_exception(monkeypatch, tmp_path):
     assert stub.calls[-1] == ("stop",)
 
 
-def test_annotate_names_a_region():
-    # the real TraceAnnotation is a cheap no-op off-profiler; the context
-    # must simply nest without error
-    with profiling.annotate("outer"):
-        with profiling.annotate("inner"):
-            pass
-
-
 def test_host_sync_fetches_a_data_dependent_scalar():
     x = jnp.arange(8, dtype=jnp.float32) + 1.0
     assert profiling.host_sync(x) == 1.0
     assert profiling.host_sync(jnp.zeros((2, 3))) == 0.0
-
-
-def test_step_timer_warmup_and_rates():
-    t = profiling.StepTimer(warmup=1)
-    t.start()
-    for _ in range(3):
-        time.sleep(0.002)
-        t.tick(n_items=4)
-    # warmup discards the first step: two measured
-    assert len(t.step_times) == 2
-    assert t.seconds_per_step >= 0.002
-    assert t.items_per_second == pytest.approx(
-        8 / sum(t.step_times))
-
-
-def test_step_timer_tick_before_start_only_arms():
-    t = profiling.StepTimer(warmup=0)
-    t.tick(n_items=4)  # no start(): arms the clock, measures nothing
-    assert t.step_times == []
-    assert math.isnan(t.seconds_per_step)
-    assert math.isnan(t.items_per_second)
-    time.sleep(0.001)
-    t.tick(n_items=4)
-    assert len(t.step_times) == 1
 
 
 def test_measure_per_step_cancels_fixed_costs():

@@ -1,0 +1,530 @@
+"""The program's one span primitive (``obs.record.Recorder.span``) and where
+it is applied: every span lands on the profiler's timeline, in the always-on
+registry and (when enabled) in the JSONL; a span named for device work ends
+after that work; the compiled steps carry ``loss`` / ``grad_sync`` /
+``optimizer`` scopes. All on the CPU: counts and orderings, never a time
+that stands for a device's."""
+
+import json
+import os
+import subprocess
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from tpu_sandbox.obs import get_recorder, get_registry, reset_recorder
+from tpu_sandbox.obs.record import Recorder
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture
+def registry():
+    reg = get_registry()
+    reg.reset()
+    yield reg
+    reg.reset()
+
+
+@pytest.fixture
+def dark(monkeypatch):
+    """The process recorder, disabled (no ``TPU_SANDBOX_TRACE_DIR``)."""
+    monkeypatch.delenv("TPU_SANDBOX_TRACE_DIR", raising=False)
+    reset_recorder()
+    yield get_recorder()
+    reset_recorder()
+
+
+def _mesh2():
+    from tpu_sandbox.runtime.mesh import make_mesh
+
+    return make_mesh({"data": 2}, devices=jax.devices()[:2])
+
+
+def _hist(reg, name):
+    return reg.snapshot()["histograms"].get(name)
+
+
+def _state():
+    from tpu_sandbox.train import TrainState
+
+    return TrainState(step=jnp.zeros((), jnp.int32), params=jnp.zeros(()),
+                      batch_stats={}, opt_state={})
+
+
+@jax.jit
+def _work(state, images, labels=None):
+    """A train step that is one reduction: the loop is what is under test,
+    not a model."""
+    loss = jnp.sum(images)
+    return state.replace(step=state.step + 1, params=state.params + loss), loss
+
+
+def _batches(steps):
+    return [(np.ones((2, 4), np.float32), np.zeros((2,), np.int32))
+            for _ in range(steps)]
+
+
+def _tiny_fit(steps, step_fn=_work, **trainer_kw):
+    from tpu_sandbox.train import Trainer
+
+    trainer = Trainer(step_fn, verbose=False, **trainer_kw)
+    return trainer.fit(_state(), _batches(steps), 1)
+
+
+# -- the primitive ------------------------------------------------------------
+
+
+def test_span_feeds_the_registry_with_the_recorder_disabled(registry, dark):
+    assert not dark.enabled
+    with dark.span("train:dispatch", hist="train.dispatch_s", loop=True):
+        time.sleep(0.002)
+    h = _hist(registry, "train.dispatch_s")
+    assert h["count"] == 1 and h["sum"] >= 0.002
+    # a span with no hist= observes nothing, and complete() takes hist= too
+    with dark.span("admit"):
+        pass
+    dark.complete("train:step", time.monotonic() - 0.5, hist="train.step_s",
+                  loop=True)
+    snap = registry.snapshot()["histograms"]
+    assert set(snap) == {"train.dispatch_s", "train.step_s"}
+    assert snap["train.step_s"]["sum"] >= 0.5
+
+
+def test_span_writes_jsonl_only_when_enabled_and_buffers_until_flush(
+        registry, tmp_path):
+    path = str(tmp_path / "spans.jsonl")
+    rec = Recorder(path, proc="unit")
+    size0 = os.path.getsize(path)  # the preamble is flushed at once
+    for _ in range(100):  # 25 train steps' worth: no write in the hot path
+        with rec.span("train:dispatch", hist="train.dispatch_s", loop=True):
+            pass
+    assert os.path.getsize(path) == size0
+    rec.close()
+    spans = [json.loads(line) for line in open(path)][1:]
+    assert len(spans) == 100
+    assert {s["name"] for s in spans} == {"train:dispatch"}
+    # a loop span starts no trace: the collector's request chains skip it
+    assert all(s["trace"] is None and s["span"] is None for s in spans)
+    assert _hist(registry, "train.dispatch_s")["count"] == 100
+
+
+def test_a_root_span_still_starts_a_trace_and_children_chain(tmp_path):
+    rec = Recorder(str(tmp_path / "t.jsonl"), proc="unit", flush_every=1)
+    with rec.span("submit") as root:
+        with rec.span("admit", parent=root.ctx) as child:
+            pass
+    rec.close()
+    assert root.ctx.trace_id == child.ctx.trace_id
+    assert child.parent.span_id == root.ctx.span_id
+
+
+def test_a_process_without_jax_can_open_and_close_a_span():
+    # the package's __init__ imports jax (runtime.bootstrap); a gateway /
+    # scheduler / KV process that loads obs/ alone must not pay that import
+    code = """
+import os, sys, types
+pkg = types.ModuleType("tpu_sandbox")
+pkg.__path__ = [os.path.join(os.getcwd(), "tpu_sandbox")]
+sys.modules["tpu_sandbox"] = pkg
+from tpu_sandbox.obs.record import get_recorder
+from tpu_sandbox.obs.metrics import get_registry
+with get_recorder().span("gateway:route", hist="gateway.route_s"):
+    pass
+assert get_registry().histogram("gateway.route_s").count == 1
+assert "jax" not in sys.modules, "obs/record imported jax"
+print("ok")
+"""
+    env = {k: v for k, v in os.environ.items()
+           if k != "TPU_SANDBOX_TRACE_DIR"}
+    proc = subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=env,
+                          capture_output=True, text=True, timeout=60)
+    assert proc.returncode == 0 and proc.stdout.strip() == "ok", proc.stderr
+
+
+def test_the_only_trace_annotation_of_the_program_is_the_recorders():
+    hits = []
+    for d, _, files in os.walk(os.path.join(ROOT, "tpu_sandbox")):
+        for f in files:
+            if f.endswith(".py"):
+                text = open(os.path.join(d, f), encoding="utf-8").read()
+                if "TraceAnnotation(" in text or "import TraceAnnotation" in text:
+                    hits.append(os.path.relpath(os.path.join(d, f), ROOT))
+    assert hits == ["tpu_sandbox/obs/record.py"]
+
+
+# -- the training loop ----------------------------------------------------------
+
+
+def test_fit_fills_the_registry_with_the_recorder_disabled(registry, dark):
+    steps = 7
+    _tiny_fit(steps, log_every=3)
+    snap = registry.snapshot()
+    assert snap["counters"]["train.steps"] == steps
+    hist = snap["histograms"]
+    # the first iteration of a fit has no predecessor
+    assert hist["train.step_s"]["count"] == steps - 1
+    assert hist["train.dispatch_s"]["count"] == steps
+    # one draw a batch, and the one that found the loader empty
+    assert hist["train.next_batch_s"]["count"] == steps + 1
+    # two log lines (steps 3 and 6) and fit's final block_until_ready
+    assert hist["train.sync_s"]["count"] == 2 + 1
+
+
+def test_fit_under_the_profiler_puts_its_spans_on_the_host_plane(
+        registry, dark, tmp_path):
+    from jax.profiler import ProfileData
+
+    from tpu_sandbox.utils.profiling import trace
+
+    with trace(str(tmp_path)):
+        _tiny_fit(5, log_every=2)
+    found = [os.path.join(d, f) for d, _, fs in os.walk(tmp_path)
+             for f in fs if f.endswith(".xplane.pb")]
+    assert len(found) == 1
+    host = [p for p in ProfileData.from_file(found[0]).planes
+            if p.name == "/host:CPU"]
+    assert len(host) == 1
+    names = [e.name for line in host[0].lines for e in line.events]
+    assert names.count("train:dispatch") == 5
+    assert names.count("train:next_batch") == 6
+    assert names.count("train:sync") == 2 + 1
+    # train:step is an interval between two calls: no annotation of its own
+    assert "train:step" not in names
+
+
+def test_train_step_spans_the_device_work_and_dispatch_only_the_enqueue(
+        registry, dark):
+    """``train:step`` of a step that waits 20 ms for its result reads
+    >= 20 ms; ``train:dispatch`` of a step that only enqueues does not: the
+    span named for the step no longer measures the enqueue."""
+    def waiting_step(state, images, labels):
+        out = jax.block_until_ready(_work(state, images))
+        time.sleep(0.020)  # stands for the device finishing 20 ms later
+        return out
+
+    _tiny_fit(4, step_fn=waiting_step)
+    h = _hist(registry, "train.step_s")
+    assert h["count"] == 3 and h["min"] >= 0.020
+
+    registry.reset()
+
+    # asynchronous dispatch: the call returns at once, and what paces the
+    # loop (here a loader that takes 20 ms a batch) lies outside it
+    class SlowLoader(list):
+        def __iter__(self):
+            for b in list.__iter__(self):
+                time.sleep(0.020)
+                yield b
+
+    from tpu_sandbox.train import Trainer
+
+    Trainer(_work, verbose=False).fit(_state(), SlowLoader(_batches(4)), 1)
+    hist = registry.snapshot()["histograms"]
+    assert hist["train.dispatch_s"]["p50"] < 0.010
+    assert hist["train.step_s"]["min"] >= 0.020
+    assert hist["train.next_batch_s"]["p50"] >= 0.020
+
+
+def test_lm_train_loop_carries_the_same_spans(registry, dark):
+    import lm_train
+
+    # no --force-cpu: it would resize this process's CPU client for every
+    # later test; the conftest's virtual CPU devices are the backend here
+    args = lm_train.build_parser().parse_args(
+        ["--steps", "4", "--log-every", "2", "--n-layers", "1", "--d-model",
+         "16", "--n-heads", "2", "--d-ff", "32", "--seq-len", "16",
+         "--batch", "2", "--lr", "1e-2"])
+    lm_train.train(args)
+    snap = registry.snapshot()
+    assert snap["counters"]["train.steps"] == 4
+    hist = snap["histograms"]
+    assert hist["train.step_s"]["count"] == 3
+    assert hist["train.dispatch_s"]["count"] == 4
+    assert hist["train.sync_s"]["count"] == 2
+    assert hist["place.batch_s"]["count"] == 4
+    assert hist["place.state_s"]["count"] == 1
+    assert hist["setup.model_init_s"]["count"] == 1
+    assert hist["setup.opt_init_s"]["count"] == 1
+    assert snap["counters"]["place.batch_bytes"] == 4 * 2 * (2 * 16 * 4)
+
+
+# -- placement, set-up, the compile cache ----------------------------------------
+
+
+def test_placement_and_setup_spans_of_data_parallel(registry, dark):
+    from tpu_sandbox.models.convnet import ConvNet
+    from tpu_sandbox.parallel import DataParallel
+    from tpu_sandbox.train import TrainState
+
+    model, tx = ConvNet(), optax.sgd(1e-3)
+    state = TrainState.create(model, jax.random.key(0),
+                              jnp.zeros((1, 28, 28, 1)), tx)
+    hist = registry.snapshot()["histograms"]
+    assert hist["setup.model_init_s"]["count"] == 1
+    assert hist["setup.opt_init_s"]["count"] == 1
+    assert hist["setup.model_init_s"]["sum"] > hist["setup.opt_init_s"]["sum"]
+    dp = DataParallel(model, tx, _mesh2())
+    dp.shard_state(state)
+    images = np.zeros((4, 28, 28, 1), np.float32)
+    labels = np.zeros((4,), np.int32)
+    dp.shard_batch(images, labels)
+    dp.shard_batch(images, labels)
+    snap = registry.snapshot()
+    assert snap["histograms"]["place.state_s"]["count"] == 1
+    assert snap["histograms"]["place.batch_s"]["count"] == 2
+    assert snap["counters"]["place.batch_bytes"] == \
+        2 * (images.nbytes + labels.nbytes)
+
+
+def test_configure_compile_cache_counts_hits_and_misses(
+        registry, tmp_path, monkeypatch):
+    from tpu_sandbox.runtime import bootstrap
+
+    assert "compile.cache_misses" not in registry.snapshot()["counters"]
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    bootstrap.configure_compile_cache()
+    bootstrap.configure_compile_cache()  # registers its listener once
+    # both exist from the call on: a warm run reads 0, not nothing
+    assert registry.snapshot()["counters"] == {
+        "compile.cache_hits": 0, "compile.cache_misses": 0}
+    # what jax reports through jax.monitoring lands in the counters (the
+    # names are jax's own: _src/compiler.py, _src/compilation_cache.py)
+    from jax._src import monitoring
+
+    monitoring.record_event("/jax/compilation_cache/cache_misses")
+    monitoring.record_event("/jax/compilation_cache/cache_hits")
+    monitoring.record_event("/jax/compilation_cache/cache_hits")
+    monitoring.record_event("/jax/compilation_cache/tasks_using_cache")
+    assert registry.snapshot()["counters"] == {
+        "compile.cache_hits": 2, "compile.cache_misses": 1}
+
+
+# -- scopes inside the compiled steps ----------------------------------------------
+
+
+def _op_names(lowered):
+    import re
+
+    text = lowered.as_text(debug_info=True)
+    return set(re.findall(r'loc\("([^"]+)"', text))
+
+
+def _has_scope(names, scope):
+    """``scope`` as one level of an op_name: ``jit(step)/optimizer/add``,
+    wrapped by a transform as ``jit(step)/transpose(jvp(loss))/mul``, or
+    relative inside a ``shard_map`` body as ``grad_sync/psum``."""
+    import re
+
+    level = re.compile(rf"(?:^|[/(]){scope}(?:[/)]|$)")
+    return any(level.search(n) for n in names)
+
+
+def _convnet_state():
+    from tpu_sandbox.models.convnet import ConvNet
+    from tpu_sandbox.train import TrainState
+
+    model, tx = ConvNet(), optax.sgd(1e-3)
+    state = TrainState.create(model, jax.random.key(0),
+                              jnp.zeros((1, 28, 28, 1)), tx)
+    return model, tx, state
+
+
+@pytest.mark.parametrize("engine", ["one_chip", "data_parallel", "pjit_lm",
+                                    "pjit_lm_compressed"])
+def test_compiled_steps_carry_loss_grad_sync_and_optimizer_scopes(
+        engine, registry, dark):
+    images = jnp.zeros((4, 28, 28, 1))
+    labels = jnp.zeros((4,), jnp.int32)
+    if engine == "one_chip":
+        from tpu_sandbox.train import make_train_step
+
+        model, tx, state = _convnet_state()
+        lowered = make_train_step(model, tx).lower(state, images, labels)
+        want = {"loss", "optimizer"}
+    elif engine == "data_parallel":
+        from tpu_sandbox.parallel import DataParallel
+
+        model, tx, state = _convnet_state()
+        dp = DataParallel(model, tx, _mesh2())
+        lowered = dp.lower_step(dp.shard_state(state),
+                                *dp.shard_batch(images, labels))
+        want = {"loss", "grad_sync", "optimizer"}
+    else:
+        from tpu_sandbox.models.transformer import (TransformerConfig,
+                                                    TransformerLM)
+        from tpu_sandbox.parallel import PjitEngine
+        from tpu_sandbox.train import TrainState
+
+        cfg = TransformerConfig(vocab_size=32, d_model=16, n_heads=2,
+                                n_layers=1, d_ff=32, max_len=8)
+        model, tx = TransformerLM(cfg), optax.adam(1e-3)
+        tokens = jnp.zeros((2, 8), jnp.int32)
+        state = TrainState.create(model, jax.random.key(0), tokens[:1], tx)
+        compressed = engine == "pjit_lm_compressed"
+        eng = PjitEngine(model, tx, _mesh2(), task="lm",
+                         grad_compress="bf16" if compressed else "none")
+        lowered = eng.lower_step(eng.shard_state(state),
+                                 *eng.shard_batch(tokens, tokens))
+        # without compression XLA inserts the gradient all-reduce itself:
+        # there is no gradient mean in the program to scope
+        want = {"loss", "optimizer"} | ({"grad_sync"} if compressed else set())
+    names = _op_names(lowered)
+    for scope in want:
+        assert _has_scope(names, scope), (engine, scope)
+    if "grad_sync" not in want:
+        assert not _has_scope(names, "grad_sync")
+    # the loss scope goes around the loss, not the forward pass: the model's
+    # own modules stay at the top of their op_names, where the benchmark
+    # looks for its kernel scopes
+    assert not any("loss)/conv1" in n or "loss)/block0" in n
+                   or "loss/conv1" in n or "loss/block0" in n for n in names)
+
+
+def test_serve_programs_have_stable_names_and_scopes():
+    from tpu_sandbox.models.transformer import TransformerConfig
+    from tpu_sandbox.serve.cache import CacheConfig
+    from tpu_sandbox.serve.decode import (make_decode_fn, make_prefill_fn,
+                                          page_shapes)
+
+    cfg = TransformerConfig(vocab_size=32, d_model=16, n_heads=2, n_layers=2,
+                            d_ff=32, max_len=32)
+    cache = CacheConfig(num_blocks=8, block_size=4, max_blocks_per_seq=4)
+    from tpu_sandbox.models.transformer import TransformerLM
+
+    params = jax.eval_shape(lambda: TransformerLM(cfg).init(
+        jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    kd, vd = page_shapes(cfg, cache, jnp.float32)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+    prefill = make_prefill_fn(cfg, cache).lower(
+        params, kd, vd, i32(1, 8), i32(8), i32())
+    decode = make_decode_fn(cfg, cache, 2).lower(
+        params, kd, vd, i32(2, 1), i32(2), i32(2, 4))
+    assert "jit_serve_prefill" in prefill.as_text()
+    assert "jit_serve_decode" in decode.as_text()
+    assert _has_scope(_op_names(prefill), "write_kv")
+    names = _op_names(decode)
+    for scope in ("write_kv", "gather_ctx"):
+        assert _has_scope(names, scope)
+    # flax names the modules; the two scopes sit inside each block's attn
+    assert any("block1/attn/gather_ctx" in n for n in names)
+    assert any("block1/mlp" in n for n in names)
+
+
+# -- the serving engine ----------------------------------------------------------
+
+
+class _CountingSteps:
+    """The engine's compiled programs, with every call and every fetch of a
+    result counted: the logits come back as objects that note when
+    ``np.asarray`` reads them."""
+
+    class _Logits:
+        def __init__(self, owner, array):
+            self.owner, self.array = owner, array
+
+        def __array__(self, dtype=None, copy=None):
+            self.owner.events.append("fetch")
+            time.sleep(0.005)  # the wait for the device
+            return np.asarray(self.array, dtype)
+
+    def __init__(self, real):
+        self.real = real
+        self.events: list[str] = []
+        self.prefill = {b: self._wrap("prefill", f)
+                        for b, f in real.prefill.items()}
+        self.decode = self._wrap("decode", real.decode)
+        self.pick_bucket = real.pick_bucket
+
+    def _wrap(self, kind, fn):
+        def call(*args):
+            self.events.append(kind)
+            logits, k, v = fn(*args)
+            return self._Logits(self, logits), k, v
+        return call
+
+
+def _tiny_engine(tmp_path=None):
+    from tpu_sandbox.models.transformer import TransformerConfig, TransformerLM
+    from tpu_sandbox.serve.cache import CacheConfig
+    from tpu_sandbox.serve.engine import ContinuousEngine, ServeConfig
+
+    cfg = TransformerConfig(vocab_size=32, d_model=16, n_heads=2, n_layers=1,
+                            d_ff=32, max_len=32)
+    serve = ServeConfig(model=cfg, cache=CacheConfig(
+        num_blocks=16, block_size=4, max_blocks_per_seq=8),
+        max_batch=2, buckets=(8,))
+    params = TransformerLM(cfg).init(
+        jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    eng = ContinuousEngine(params, serve)
+    eng.step_fns = _CountingSteps(eng.step_fns)
+    return eng
+
+
+def test_engine_spans_end_after_the_logits_are_fetched(registry, tmp_path,
+                                                       monkeypatch):
+    from tpu_sandbox.serve.engine import Request
+
+    monkeypatch.setenv("TPU_SANDBOX_TRACE_DIR", str(tmp_path))
+    reset_recorder()
+    try:
+        eng = _tiny_engine()
+        for n in range(3):
+            eng.submit(Request(rid=f"r{n}", prompt=[1 + n, 2, 3],
+                               max_new_tokens=4))
+        eng.run_until_idle()
+        get_recorder().flush()
+    finally:
+        reset_recorder()
+    events = eng.step_fns.events
+    decode_calls = events.count("decode")
+    assert decode_calls >= 3 and events.count("prefill") == 3
+    # every program call is followed by the fetch of its logits
+    assert events.count("fetch") == len(events) // 2
+    snap = registry.snapshot()
+    hist = snap["histograms"]
+    assert hist["engine.decode_call_s"]["count"] == decode_calls
+    assert hist["engine.decode_call_s"]["min"] >= 0.005  # holds the fetch
+    assert hist["engine.prefill_s"]["count"] == 3
+    assert hist["engine.prefill_s"]["min"] >= 0.005
+    assert hist["engine.step_s"]["count"] == eng.steps
+    assert hist["engine.sample_s"]["count"] == decode_calls
+    assert hist["engine.occupancy"]["count"] == eng.steps
+    assert 0.0 < hist["engine.occupancy"]["max"] <= 1.0
+    assert snap["counters"]["engine.tokens"] == 3 * 4
+    # the JSONL: a request's prefill span holds the fetch too, inside admit
+    (log,) = [f for f in os.listdir(tmp_path) if f.endswith(".jsonl")]
+    spans = [json.loads(line) for line in open(tmp_path / log)]
+    prefills = [s for s in spans if s.get("name") == "prefill"]
+    admits = {s["span"]: s for s in spans if s.get("name") == "admit"}
+    assert len(prefills) == 3
+    for p in prefills:
+        assert p["dur"] >= 0.005
+        a = admits[p["parent"]]
+        assert a["ts"] <= p["ts"] and \
+            p["ts"] + p["dur"] <= a["ts"] + a["dur"] + 1e-6
+    steps = [s for s in spans if s.get("name") == "engine:step"]
+    assert len(steps) == eng.steps and all(s["trace"] is None for s in steps)
+
+
+def test_buffered_spans_are_written_at_a_clean_exit(tmp_path):
+    # fewer spans than flush_every, no explicit flush: the process's exit
+    # writes them (the LM cell's place:batch spans were lost without it)
+    code = """
+from tpu_sandbox.obs import get_recorder
+for _ in range(5):
+    with get_recorder().span("place:batch", hist="place.batch_s", loop=True):
+        pass
+"""
+    env = dict(os.environ, TPU_SANDBOX_TRACE_DIR=str(tmp_path),
+               JAX_PLATFORMS="cpu")
+    subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=env,
+                   check=True, timeout=120)
+    (log,) = os.listdir(tmp_path)
+    names = [json.loads(line).get("name") for line in open(tmp_path / log)]
+    assert names.count("place:batch") == 5

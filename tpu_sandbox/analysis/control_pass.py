@@ -54,7 +54,9 @@ incident history:
   string literal. A dynamic name (f-string, concatenation, variable)
   mints one series per distinct value: unbounded cardinality in every
   snapshot, scrape, and tsdb flush, and nothing stable for alert rules
-  to key on. Bounded dimensions belong in ``labels=``.
+  to key on. Bounded dimensions belong in ``labels=``. The ``hist=`` of
+  a recorder's ``span()``/``begin_span()``/``complete()`` names a
+  registry histogram and is held to the same shape at the call site.
 - **GL-O403** — a ``span()``/``begin_span()``/``complete()``/
   ``instant()`` call on a recorder whose name argument is minted at
   runtime (``%``, ``.format()``, concatenation, a bare variable, or an
@@ -553,6 +555,27 @@ class _FnLinter:
                 f"{node.func.attr}() name is not a static snake.dotted "
                 f"literal — a dynamic name mints one series per distinct "
                 f"value (put bounded dimensions in labels=)",
+            )
+        # a span's ``hist=`` is a histogram name the recorder relays to
+        # the registry: the literal lives at the span's call site
+        for node in ast.walk(fn):
+            if not (isinstance(node, ast.Call)
+                    and isinstance(node.func, ast.Attribute)
+                    and node.func.attr in SPAN_EMITTERS
+                    and self._is_recorder_receiver(node.func.value)):
+                continue
+            hist = next((kw.value for kw in node.keywords
+                         if kw.arg == "hist"), None)
+            if hist is None or (isinstance(hist, ast.Constant) and (
+                    hist.value is None or (
+                        isinstance(hist.value, str)
+                        and METRIC_NAME_RE.match(hist.value)))):
+                continue
+            self._emit(
+                "GL-O402", node,
+                f"{node.func.attr}() hist= is not a static snake.dotted "
+                f"literal — the recorder observes it as a registry "
+                f"histogram, one series per distinct value",
             )
 
     # -- GL-O403 -------------------------------------------------------------

@@ -3,10 +3,13 @@ merged timelines, durable time series, and alerting wired into control.
 
 Five pieces, deliberately decoupled:
 
-- :mod:`tpu_sandbox.obs.record` — the in-process recorder. Append-only
-  per-process JSONL, monotonic timestamps, propagated trace context.
-  Off by default; exporting ``TPU_SANDBOX_TRACE_DIR`` turns it on for
-  every process that inherits the env (agents, replicas, the gateway).
+- :mod:`tpu_sandbox.obs.record` — the in-process recorder and the
+  program's one span primitive: ``Recorder.span`` puts a span on the
+  profiler's timeline (``jax.profiler.TraceAnnotation``), into a registry
+  histogram (always) and into the append-only per-process JSONL
+  (monotonic timestamps, propagated trace context). The JSONL is off by
+  default; exporting ``TPU_SANDBOX_TRACE_DIR`` turns it on for every
+  process that inherits the env (agents, replicas, the gateway).
 - :mod:`tpu_sandbox.obs.metrics` — counters / gauges / streaming-quantile
   histograms. Always on (an increment is nanoseconds); scraped live via
   the gateway's METRICS wire op. Bounded dimensions ride ``labels=``;

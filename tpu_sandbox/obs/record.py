@@ -3,8 +3,10 @@ to leave on.
 
 One :class:`Recorder` per process, obtained via :func:`get_recorder`. It
 is **disabled unless** ``TPU_SANDBOX_TRACE_DIR`` is set in the
-environment — every emit on a disabled recorder is a couple of attribute
-reads, so instrumentation stays in the hot paths unconditionally.
+environment — on a disabled recorder a span costs its histogram observe
+and a profiler flag test (about a microsecond; PERF.md gives the measured
+cost), every other emit a couple of attribute reads, so instrumentation
+stays in the hot paths unconditionally.
 
 Record forms (one JSON object per line, all timestamps are THIS
 process's ``time.monotonic()`` seconds — never wall clock, never another
@@ -33,6 +35,18 @@ process boundary (gateway wire frames, ``serve/req/<rid>`` bodies). A
 disabled recorder *passes contexts through* unchanged, so one dark
 process does not sever the chain between two instrumented ones.
 
+One span, three sinks. ``with rec.span(name, hist="layer.what_s")`` (a)
+enters a ``jax.profiler.TraceAnnotation(name)`` — the profiler's clock,
+the one the device planes are on, so under ``--profile DIR`` the program's
+spans lie over the device ops; outside a profiler session that is a flag
+test, and a process that never imported ``jax`` (gateway, scheduler, KV)
+skips it and stays jax-free; (b) observes the duration in seconds into the
+always-on registry histogram ``hist`` (a static literal at the call site,
+GL-O402), recorder enabled or not; (c) writes the JSONL ``X`` record, only
+when enabled. A span names work and ends when that work has ended: a span
+around a device dispatch measures the enqueue and must say so in its name
+(``train:dispatch``); one named for device work ends after a wait.
+
 Span discipline: ``with rec.span(name) as sp`` is the sanctioned form;
 ``begin_span`` exists for the rare span that cannot be a ``with`` block
 and MUST be closed in a ``try/finally`` (graftlint GL-O401 polices
@@ -44,12 +58,15 @@ which emits retrospectively and cannot leak.
 
 from __future__ import annotations
 
-import contextlib
+import atexit
 import json
 import os
+import sys
 import threading
 import time
 from dataclasses import dataclass
+
+from tpu_sandbox.obs.metrics import get_registry
 
 ENV_TRACE_DIR = "TPU_SANDBOX_TRACE_DIR"
 ENV_PROC_NAME = "TPU_SANDBOX_OBS_PROC"
@@ -86,31 +103,68 @@ class TraceContext:
         return None
 
 
-class Span:
-    """A live span handle. ``ctx`` is the context CHILDREN of this span
-    should carry; on a disabled recorder it passes the parent through."""
+_ANNOTATION = None
 
-    __slots__ = ("_rec", "name", "ctx", "parent", "args", "_t0", "_closed")
+
+def _annotation(name: str):
+    """An entered ``jax.profiler.TraceAnnotation``, or None in a process
+    that has not imported ``jax``: this module never pays that import."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        if "jax" not in sys.modules:
+            return None
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    ann = _ANNOTATION(name)
+    ann.__enter__()
+    return ann
+
+
+def _observe(hist: str | None, seconds: float) -> None:
+    if hist is not None:
+        get_registry().histogram(hist).observe(seconds)
+
+
+class Span:
+    """A live span handle, and the ``with`` form itself. ``ctx`` is the
+    context CHILDREN of this span should carry; on a disabled recorder it
+    passes the parent through."""
+
+    __slots__ = ("_rec", "name", "ctx", "parent", "args", "_t0", "_closed",
+                 "_hist", "_ann")
 
     def __init__(self, rec: "Recorder", name: str,
                  ctx: TraceContext | None, parent: TraceContext | None,
-                 args: dict | None, t0: float | None):
+                 args: dict | None, hist: str | None):
         self._rec = rec
         self.name = name
         self.ctx = ctx
         self.parent = parent
         self.args = args if args is not None else {}
-        self._t0 = t0
-        self._closed = t0 is None  # disabled spans have nothing to emit
+        self._hist = hist
+        self._closed = False
+        self._ann = _annotation(name)
+        self._t0 = time.monotonic()
+
+    def __enter__(self) -> "Span":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.close()
 
     def close(self) -> None:
         if self._closed:
             return
         self._closed = True
-        now = time.monotonic()
+        dur = time.monotonic() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        _observe(self._hist, dur)
+        if not self._rec.enabled:
+            return
         self._rec._emit({
-            "ph": "X", "name": self.name, "ts": self._t0,
-            "dur": now - self._t0,
+            "ph": "X", "name": self.name, "ts": self._t0, "dur": dur,
             "trace": None if self.ctx is None else self.ctx.trace_id,
             "span": None if self.ctx is None else self.ctx.span_id,
             "parent": None if self.parent is None else self.parent.span_id,
@@ -123,7 +177,11 @@ class Recorder:
 
     ``flush_every`` > 0 flushes the buffer to disk whenever it reaches
     that many records (and on every instant — instants mark faults and
-    verdicts, which must survive an immediate SIGKILL). ``flush_every``
+    verdicts, which must survive an immediate SIGKILL). Between flushes a
+    span costs no file I/O; the default is wide enough that a training
+    loop of four spans a step writes once in 256 steps, while the device
+    still holds queued work, and ``Trainer.fit`` flushes at its end.
+    ``flush_every``
     == 0 means fully manual flushing, which is how the backpressure path
     is exercised: once the buffer holds ``max_buffered`` records, new
     ones are DROPPED and counted — the recorder prefers losing its own
@@ -132,7 +190,7 @@ class Recorder:
     recorder is visible, not invisible)."""
 
     def __init__(self, path: str | None, *, proc: str | None = None,
-                 flush_every: int = 64, max_buffered: int = 4096):
+                 flush_every: int = 1024, max_buffered: int = 4096):
         self.path = path
         self.enabled = path is not None
         self.pid = os.getpid()
@@ -212,39 +270,52 @@ class Recorder:
 
     # -- spans / events ------------------------------------------------------
 
-    def begin_span(self, name: str, parent=None,
-                   args: dict | None = None) -> Span:
+    def _span_ctx(self, parent: TraceContext | None,
+                  loop: bool) -> TraceContext | None:
+        if not self.enabled or (loop and parent is None):
+            return parent
+        return self._child_ctx(parent)
+
+    def begin_span(self, name: str, parent=None, args: dict | None = None,
+                   hist: str | None = None, loop: bool = False) -> Span:
         """Open a span the caller MUST close in a try/finally (GL-O401).
         Prefer ``with rec.span(...)``; use this only when the span's
         lifetime cannot be a lexical block."""
         parent = TraceContext.from_wire(parent)
-        if not self.enabled:
-            return Span(self, name, parent, parent, args, None)
-        ctx = self._child_ctx(parent)
-        return Span(self, name, ctx, parent, args, time.monotonic())
+        return Span(self, name, self._span_ctx(parent, loop), parent, args,
+                    hist)
 
-    @contextlib.contextmanager
-    def span(self, name: str, parent=None, args: dict | None = None):
-        """The sanctioned span form: closes on every path."""
-        sp = self.begin_span(name, parent=parent, args=args)
-        try:
-            yield sp
-        finally:
-            sp.close()
+    def span(self, name: str, parent=None, args: dict | None = None,
+             hist: str | None = None, loop: bool = False) -> Span:
+        """The sanctioned span form, ``with rec.span(...)``: closes on
+        every path, and feeds all three sinks (module docstring).
+
+        A span without a parent starts a new trace — a request's root.
+        ``loop=True`` marks what belongs to no request: an iteration of a
+        training or serving loop, a set-up phase. Such a span carries no
+        trace id, so the collector's request chains leave it out, and it
+        sits on the merged timeline by its time alone."""
+        return self.begin_span(name, parent=parent, args=args, hist=hist,
+                               loop=loop)
 
     def complete(self, name: str, start_mono: float, parent=None,
-                 args: dict | None = None) -> TraceContext | None:
+                 args: dict | None = None, hist: str | None = None,
+                 loop: bool = False) -> TraceContext | None:
         """Emit a span retrospectively: started at ``start_mono`` (this
         process's monotonic clock), ended now. Returns the context
-        children should parent to (parent pass-through when disabled)."""
+        children should parent to (parent pass-through when disabled).
+        Feeds the registry histogram ``hist`` and the JSONL, not the
+        profiler's timeline: an annotation cannot start in the past."""
+        dur = time.monotonic() - start_mono
+        _observe(hist, dur)
         parent = TraceContext.from_wire(parent)
+        ctx = self._span_ctx(parent, loop)
         if not self.enabled:
-            return parent
-        ctx = self._child_ctx(parent)
+            return ctx
         self._emit({
-            "ph": "X", "name": name, "ts": start_mono,
-            "dur": time.monotonic() - start_mono,
-            "trace": ctx.trace_id, "span": ctx.span_id,
+            "ph": "X", "name": name, "ts": start_mono, "dur": dur,
+            "trace": None if ctx is None else ctx.trace_id,
+            "span": None if ctx is None else ctx.span_id,
             "parent": None if parent is None else parent.span_id,
             "args": args or {},
         })
@@ -324,6 +395,9 @@ def get_recorder() -> Recorder:
                     or f"proc-{os.getpid()}"
                 path = os.path.join(trace_dir, f"{proc}-{os.getpid()}.jsonl")
                 _RECORDER = Recorder(path, proc=proc)
+                # spans are buffered: what a clean exit still holds is
+                # written then (a SIGKILL loses it, instants excepted)
+                atexit.register(_RECORDER.flush)
             else:
                 _RECORDER = Recorder(None)
         return _RECORDER

@@ -33,6 +33,7 @@ import optax
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from tpu_sandbox.obs import get_recorder, get_registry
 from tpu_sandbox.ops.losses import cross_entropy_loss
 from tpu_sandbox.parallel.collectives import CompressedAllReduce
 from tpu_sandbox.train.state import TrainState
@@ -198,6 +199,15 @@ class DataParallel:
         reference's implicit contract), and each process materializes only
         its addressable shards via ``make_array_from_callback``.
         """
+        # the span ends when the state is on the mesh, not when the copies
+        # are enqueued: set-up, and the next consumer waits for it anyway
+        with get_recorder().span("place:state",
+                                 hist="place.state_s", loop=True):
+            return jax.block_until_ready(
+                self._place_state(state, stats_expanded))
+
+    def _place_state(self, state: TrainState,
+                     stats_expanded: bool) -> TrainState:
         if self.compress.needs_residual and state.grad_residual is None:
             # first placement of a compression-naive state: start the
             # error-feedback residual at zero (its mathematical identity)
@@ -359,9 +369,14 @@ class DataParallel:
         the slice DistributedSampler would have given rank i (see
         ShardedBatchLoader, which lays the global batch out that way)."""
         sh = NamedSharding(self.mesh, P(self.axis))
-        return jax.device_put(jnp.asarray(images), sh), jax.device_put(
-            jnp.asarray(labels), sh
-        )
+        # what the loop pays to hand a batch over; the copy itself may
+        # still be in flight when this returns (no wait is added per step)
+        with get_recorder().span("place:batch",
+                                 hist="place.batch_s", loop=True):
+            images, labels = jnp.asarray(images), jnp.asarray(labels)
+            get_registry().counter("place.batch_bytes").inc(
+                images.nbytes + labels.nbytes)
+            return jax.device_put(images, sh), jax.device_put(labels, sh)
 
     # -- the engine ---------------------------------------------------------
 
@@ -379,10 +394,9 @@ class DataParallel:
             logits, mutated = model.apply(
                 variables, images, train=True, mutable=["batch_stats"]
             )
-            return (
-                cross_entropy_loss(logits, labels),
-                mutated.get("batch_stats", {}),
-            )
+            with jax.named_scope("loss"):
+                loss = cross_entropy_loss(logits, labels)
+            return loss, mutated.get("batch_stats", {})
 
         def shard_body(state: TrainState, images, labels):
             # Per-rank block: images [B/size, ...]; BN stats [1, ...] -> local.
@@ -404,17 +418,18 @@ class DataParallel:
                     if compress.needs_residual
                     else None
                 )
-                if overlap:
-                    from tpu_sandbox.parallel.buckets import sync_buckets
+                with jax.named_scope("grad_sync"):
+                    if overlap:
+                        from tpu_sandbox.parallel.buckets import sync_buckets
 
-                    grads, new_res = sync_buckets(
-                        grads, axis, size, compress, residuals=local_res,
-                        bucket_bytes=bucket_bytes,
-                    )
-                else:
-                    grads, new_res = compress.pmean_tree(
-                        grads, axis, size, local_res
-                    )
+                        grads, new_res = sync_buckets(
+                            grads, axis, size, compress, residuals=local_res,
+                            bucket_bytes=bucket_bytes,
+                        )
+                    else:
+                        grads, new_res = compress.pmean_tree(
+                            grads, axis, size, local_res
+                        )
                 if compress.needs_residual:
                     new_residual = jax.tree.map(lambda x: x[None], new_res)
             if zero:
@@ -442,18 +457,20 @@ class DataParallel:
                         lambda g, s: blk(g) if s else g, grads, sharded
                     )
                 else:
-                    grads_blk = jax.tree.map(
-                        lambda g, s: (
-                            lax.psum_scatter(g, axis, scatter_dimension=0,
-                                             tiled=True) / size
-                            if s else lax.pmean(g, axis)
-                        ),
-                        grads, sharded,
+                    with jax.named_scope("grad_sync"):
+                        grads_blk = jax.tree.map(
+                            lambda g, s: (
+                                lax.psum_scatter(g, axis, scatter_dimension=0,
+                                                 tiled=True) / size
+                                if s else lax.pmean(g, axis)
+                            ),
+                            grads, sharded,
+                        )
+                with jax.named_scope("optimizer"):
+                    updates, new_opt = tx.update(
+                        grads_blk, state.opt_state, params_blk
                     )
-                updates, new_opt = tx.update(
-                    grads_blk, state.opt_state, params_blk
-                )
-                new_blk = optax.apply_updates(params_blk, updates)
+                    new_blk = optax.apply_updates(params_blk, updates)
                 new_params = jax.tree.map(
                     lambda p, s: (
                         lax.all_gather(p, axis, axis=0, tiled=True) if s else p
@@ -463,11 +480,13 @@ class DataParallel:
             else:
                 if not overlap and compress.mode == "none":
                     # THE data-parallel step: mean grads across ranks.
-                    grads = lax.pmean(grads, axis)
-                updates, new_opt = tx.update(
-                    grads, state.opt_state, state.params
-                )
-                new_params = optax.apply_updates(state.params, updates)
+                    with jax.named_scope("grad_sync"):
+                        grads = lax.pmean(grads, axis)
+                with jax.named_scope("optimizer"):
+                    updates, new_opt = tx.update(
+                        grads, state.opt_state, state.params
+                    )
+                    new_params = optax.apply_updates(state.params, updates)
             if average_loss:
                 loss = lax.pmean(loss, axis)  # the reference's dead AVG reduce
             new_state = state.replace(

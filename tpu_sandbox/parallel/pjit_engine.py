@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from tpu_sandbox.obs import get_recorder, get_registry
 from tpu_sandbox.ops.losses import cross_entropy_loss
 from tpu_sandbox.parallel.collectives import (
     CompressedAllReduce,
@@ -290,16 +291,27 @@ class PjitEngine:
         return NamedSharding(self.mesh, spec)
 
     def shard_state(self, state: TrainState) -> TrainState:
-        specs = self._state_specs(state)
-        return jax.tree.map(
-            lambda x, s: jax.device_put(x, self._sharding(s)), state, specs
-        )
+        # the span ends when the state is on the mesh, not when the copies
+        # are enqueued: set-up, and the next consumer waits for it anyway
+        with get_recorder().span("place:state",
+                                 hist="place.state_s", loop=True):
+            specs = self._state_specs(state)
+            return jax.block_until_ready(jax.tree.map(
+                lambda x, s: jax.device_put(x, self._sharding(s)), state, specs
+            ))
 
     def shard_batch(self, images, labels):
-        return (
-            jax.device_put(jnp.asarray(images), self._sharding(self.input_spec)),
-            jax.device_put(jnp.asarray(labels), self._sharding(P(self.batch_axis))),
-        )
+        # what the loop pays to hand a batch over; the copy itself may
+        # still be in flight when this returns (no wait is added per step)
+        with get_recorder().span("place:batch",
+                                 hist="place.batch_s", loop=True):
+            images, labels = jnp.asarray(images), jnp.asarray(labels)
+            get_registry().counter("place.batch_bytes").inc(
+                images.nbytes + labels.nbytes)
+            return (
+                jax.device_put(images, self._sharding(self.input_spec)),
+                jax.device_put(labels, self._sharding(P(self.batch_axis))),
+            )
 
     def _build(self, state: TrainState) -> Callable:
         model, tx, image_size = self.model, self.tx, self.image_size
@@ -311,12 +323,14 @@ class PjitEngine:
                 logits, sown = model.apply(
                     {"params": params}, tokens, mutable=["aux_loss"]
                 )
-                loss = cross_entropy_loss(
-                    logits.reshape(-1, logits.shape[-1]), targets.reshape(-1)
-                )
-                aux = jax.tree.leaves(sown.get("aux_loss", {}))
-                if aux:  # mean over layers: alpha independent of depth
-                    loss = loss + aux_weight * sum(aux) / len(aux)
+                with jax.named_scope("loss"):
+                    loss = cross_entropy_loss(
+                        logits.reshape(-1, logits.shape[-1]),
+                        targets.reshape(-1)
+                    )
+                    aux = jax.tree.leaves(sown.get("aux_loss", {}))
+                    if aux:  # mean over layers: alpha independent of depth
+                        loss = loss + aux_weight * sum(aux) / len(aux)
                 return loss, batch_stats
 
         else:
@@ -328,9 +342,9 @@ class PjitEngine:
                 logits, mutated = model.apply(
                     variables, images, train=True, mutable=["batch_stats"]
                 )
-                return cross_entropy_loss(logits, labels), mutated.get(
-                    "batch_stats", {}
-                )
+                with jax.named_scope("loss"):
+                    loss = cross_entropy_loss(logits, labels)
+                return loss, mutated.get("batch_stats", {})
 
         compress = self.grad_compress
         overlap, bucket_bytes = self.overlap, self.bucket_bytes
@@ -351,15 +365,17 @@ class PjitEngine:
                 (loss, _), grads = jax.value_and_grad(
                     loss_fn, has_aux=True
                 )(params, {}, images, labels)
-                if overlap:
-                    from tpu_sandbox.parallel.buckets import sync_buckets
+                with jax.named_scope("grad_sync"):
+                    if overlap:
+                        from tpu_sandbox.parallel.buckets import sync_buckets
 
-                    grads, _ = sync_buckets(
-                        grads, axis, size, compress,
-                        bucket_bytes=bucket_bytes,
-                    )
-                else:
-                    grads, _ = compress.pmean_tree(grads, axis, size, None)
+                        grads, _ = sync_buckets(
+                            grads, axis, size, compress,
+                            bucket_bytes=bucket_bytes,
+                        )
+                    else:
+                        grads, _ = compress.pmean_tree(
+                            grads, axis, size, None)
                 return lax.pmean(loss, axis), grads
 
             grads_fn = jax.shard_map(
@@ -376,13 +392,15 @@ class PjitEngine:
                     from tpu_sandbox.train import prepare_inputs
                     images = prepare_inputs(model, images, image_size)
                 loss, grads = grads_fn(state.params, images, labels)
-                updates, new_opt = tx.update(
-                    grads, state.opt_state, state.params
-                )
+                with jax.named_scope("optimizer"):
+                    updates, new_opt = tx.update(
+                        grads, state.opt_state, state.params
+                    )
+                    new_params = optax.apply_updates(state.params, updates)
                 return (
                     state.replace(
                         step=state.step + 1,
-                        params=optax.apply_updates(state.params, updates),
+                        params=new_params,
                         opt_state=new_opt,
                     ),
                     loss,
@@ -397,11 +415,14 @@ class PjitEngine:
                 (loss, new_stats), grads = jax.value_and_grad(
                     loss_fn, has_aux=True
                 )(state.params, state.batch_stats, images, labels)
-                updates, new_opt = tx.update(grads, state.opt_state, state.params)
+                with jax.named_scope("optimizer"):
+                    updates, new_opt = tx.update(
+                        grads, state.opt_state, state.params)
+                    new_params = optax.apply_updates(state.params, updates)
                 return (
                     state.replace(
                         step=state.step + 1,
-                        params=optax.apply_updates(state.params, updates),
+                        params=new_params,
                         batch_stats=new_stats,
                         opt_state=new_opt,
                     ),

@@ -54,12 +54,46 @@ def configure_compile_cache() -> str:
     variable set keeps the cache across runs only if the program writes
     there). Unset, the cache lives at ``DEFAULT_COMPILE_CACHE``. Entry
     scripts call this first, before anything compiles.
+
+    Also counts what the cache does into the always-on registry:
+    ``compile.cache_hits`` (a program loaded instead of compiled) and
+    ``compile.cache_misses`` (a program compiled and written: jax counts a
+    miss only where it writes the entry, i.e. a compile above its
+    ``jax_persistent_cache_min_compile_time_secs``). Both exist from this
+    call on, so a warm run reads a true 0 misses.
     """
+    _count_cache_events()
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed
     jax.config.update("jax_compilation_cache_dir", str(DEFAULT_COMPILE_CACHE))
     return str(DEFAULT_COMPILE_CACHE)
+
+
+_cache_listener_on = False
+
+
+def _count_cache_events() -> None:
+    """Create the two compile-cache counters (so that they read 0, not
+    nothing) and register, once, the ``jax.monitoring`` listener behind
+    them."""
+    global _cache_listener_on
+    from tpu_sandbox.obs import get_registry
+
+    get_registry().counter("compile.cache_hits")
+    get_registry().counter("compile.cache_misses")
+    if _cache_listener_on:
+        return
+    _cache_listener_on = True
+
+    def on_event(event: str, **kwargs) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            get_registry().counter("compile.cache_hits").inc()
+        elif event == "/jax/compilation_cache/cache_misses":
+            get_registry().counter("compile.cache_misses").inc()
+
+    jax.monitoring.register_event_listener(on_event)
+
 
 # Module state: records what init() decided, so entry scripts and tests can
 # query topology without re-deriving it.

@@ -125,20 +125,25 @@ def make_prefill_fn(model_cfg: TransformerConfig, cache_cfg: CacheConfig,
     """
     model = TransformerLM(model_cfg)
 
-    def prefill(params, k_pages, v_pages, tokens, dest_idx, last_pos):
+    # the function's name is the program's name in a device trace
+    # (``jit_serve_prefill``); flax scopes the model's own ops
+    # (``block7/attn``, ``block7/mlp``, ``lm_head``), ``write_kv`` the
+    # scatter into the pages
+    def serve_prefill(params, k_pages, v_pages, tokens, dest_idx, last_pos):
         logits, taps = model.apply(
             {"params": params}, tokens, mutable=["kv_cache"])
         fk, fv = _flat(k_pages), _flat(v_pages)
-        for i in range(model_cfg.n_layers):
-            k, v = taps["kv_cache"][f"block{i}"]["attn"]["kv"]
-            fk = fk.at[i, dest_idx].set(k[0].astype(cache_dtype))
-            fv = fv.at[i, dest_idx].set(v[0].astype(cache_dtype))
+        with jax.named_scope("write_kv"):
+            for i in range(model_cfg.n_layers):
+                k, v = taps["kv_cache"][f"block{i}"]["attn"]["kv"]
+                fk = fk.at[i, dest_idx].set(k[0].astype(cache_dtype))
+                fv = fv.at[i, dest_idx].set(v[0].astype(cache_dtype))
         next_logits = jax.lax.dynamic_index_in_dim(
             logits[0], last_pos, axis=0, keepdims=False)
         return (next_logits,
                 fk.reshape(k_pages.shape), fv.reshape(v_pages.shape))
 
-    return jax.jit(prefill, donate_argnums=(1, 2))
+    return jax.jit(serve_prefill, donate_argnums=(1, 2))
 
 
 def make_decode_fn(model_cfg: TransformerConfig, cache_cfg: CacheConfig,
@@ -157,7 +162,10 @@ def make_decode_fn(model_cfg: TransformerConfig, cache_cfg: CacheConfig,
     max_ctx = cache_cfg.max_context
     scale = jnp.sqrt(jnp.asarray(head_dim, jnp.float32))
 
-    def decode(params, k_pages, v_pages, tokens, lengths, block_tables):
+    # ``jit_serve_decode`` in a device trace; inside each ``block{i}/attn``
+    # the page write and the context gather carry scopes of their own
+    # (``write_kv``, ``gather_ctx``), the rest is the attention math
+    def serve_decode(params, k_pages, v_pages, tokens, lengths, block_tables):
         fk, fv = _flat(k_pages), _flat(v_pages)
         pos = jnp.maximum(lengths - 1, 0)                      # [B]
         dest = (jnp.take_along_axis(
@@ -175,10 +183,12 @@ def make_decode_fn(model_cfg: TransformerConfig, cache_cfg: CacheConfig,
             # q/k/v: [B, 1, H, D] — the new token at position lengths-1
             nonlocal fk, fv
             i = next(layer)
-            fk = fk.at[i, dest].set(k[:, 0].astype(cache_dtype))
-            fv = fv.at[i, dest].set(v[:, 0].astype(cache_dtype))
-            kc = fk[i][ctx_idx].astype(q.dtype)                # [B, ctx, H, D]
-            vc = fv[i][ctx_idx].astype(v.dtype)
+            with jax.named_scope("write_kv"):
+                fk = fk.at[i, dest].set(k[:, 0].astype(cache_dtype))
+                fv = fv.at[i, dest].set(v[:, 0].astype(cache_dtype))
+            with jax.named_scope("gather_ctx"):
+                kc = fk[i][ctx_idx].astype(q.dtype)            # [B, ctx, H, D]
+                vc = fv[i][ctx_idx].astype(v.dtype)
             # query padded to q=2: XLA's q=1 matvec kernel accumulates the
             # output contraction in a different order than the full
             # forward's gemm (~1 ulp); at q>=2 the gemm kernel matches
@@ -197,7 +207,7 @@ def make_decode_fn(model_cfg: TransformerConfig, cache_cfg: CacheConfig,
         return (logits[:, 0],
                 fk.reshape(k_pages.shape), fv.reshape(v_pages.shape))
 
-    return jax.jit(decode, donate_argnums=(1, 2))
+    return jax.jit(serve_decode, donate_argnums=(1, 2))
 
 
 def build_decode_step(model_cfg: TransformerConfig, cache_cfg: CacheConfig,

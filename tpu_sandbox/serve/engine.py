@@ -395,44 +395,47 @@ class _EngineBase:
         return True
 
     def _prefill(self, request: Request, alloc: SeqAlloc, slot_idx: int):
-        cfg = self.config
-        t_admit = time.monotonic()
+        rec = get_recorder()
         ver = self.version if request.ver is None else int(request.ver)
         request.ver = ver  # pin sticks to the request: preempt-to-requeue
                            # and drain replay on these weights, swap or not
-        params = self._params_for(ver)
-        if params is _MISSING:
-            raise KeyError(
-                f"request {request.rid} pinned to version {ver} but no such "
-                f"params are resident (admit through the queue, which sheds "
-                f"stale pins, or provide a loader)")
-        plen = len(request.prompt)
-        bucket = self.step_fns.pick_bucket(plen)
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, :plen] = request.prompt
-        dest = self.cache.dest_indices(alloc, bucket).astype(np.int32)
-        t_prefill = time.monotonic()
-        next_logits, self.k_pages, self.v_pages = self.step_fns.prefill[bucket](
-            params, self.k_pages, self.v_pages,
-            jnp.asarray(toks), jnp.asarray(dest),
-            jnp.asarray(plen - 1, jnp.int32))
-        alloc.length = plen
-        self.cache.commit_prefix(alloc)
-        slot = _Slot(request=request, alloc=alloc, tokens=list(request.prompt),
-                     preemptions=request.preemptions, ver=ver)
         # the admit span covers admission bookkeeping plus the prefill
         # compute; the prefill child span carves the compute out so the
-        # critpath analyzer can tell "slow admission" from "big prompt".
-        # The decode span that follows is emitted retrospectively at
-        # retire time, anchored here
-        ctx = get_recorder().complete("admit", t_admit, parent=request.tc,
-                                      args={"rid": request.rid})
-        get_recorder().complete("prefill", t_prefill, parent=ctx,
-                                args={"rid": request.rid, "plen": plen})
-        slot.tc = None if ctx is None else ctx.to_wire()
+        # critpath analyzer can tell "slow admission" from "big prompt",
+        # and ends when the logits are on the host — the device has
+        # finished — not when the program was enqueued. The decode span
+        # that follows is emitted retrospectively at retire time,
+        # anchored at the admit span's end
+        with rec.span("admit", parent=request.tc,
+                      args={"rid": request.rid}) as admit:
+            params = self._params_for(ver)
+            if params is _MISSING:
+                raise KeyError(
+                    f"request {request.rid} pinned to version {ver} but no "
+                    f"such params are resident (admit through the queue, "
+                    f"which sheds stale pins, or provide a loader)")
+            plen = len(request.prompt)
+            bucket = self.step_fns.pick_bucket(plen)
+            toks = np.zeros((1, bucket), np.int32)
+            toks[0, :plen] = request.prompt
+            dest = self.cache.dest_indices(alloc, bucket).astype(np.int32)
+            with rec.span("prefill", parent=admit.ctx,
+                          args={"rid": request.rid, "plen": plen},
+                          hist="engine.prefill_s"):
+                next_logits, self.k_pages, self.v_pages = \
+                    self.step_fns.prefill[bucket](
+                        params, self.k_pages, self.v_pages,
+                        jnp.asarray(toks), jnp.asarray(dest),
+                        jnp.asarray(plen - 1, jnp.int32))
+                row = np.asarray(next_logits).reshape(-1)
+            alloc.length = plen
+            self.cache.commit_prefix(alloc)
+            slot = _Slot(request=request, alloc=alloc,
+                         tokens=list(request.prompt),
+                         preemptions=request.preemptions, ver=ver)
+        slot.tc = None if admit.ctx is None else admit.ctx.to_wire()
         slot.admitted_mono = time.monotonic()
         self.slots[slot_idx] = slot
-        row = np.asarray(next_logits).reshape(-1)
         token = self._pick_token(slot, row)
         slot.logprob_sum += _token_logprob(row, token)
         self._emit_token(slot, token)
@@ -453,6 +456,7 @@ class _EngineBase:
                             temperature=req.temperature, top_k=req.top_k)
 
     def _emit_token(self, slot: _Slot, token: int) -> None:
+        get_registry().counter("engine.tokens").inc()
         now = self.clock()
         if slot.first_token_at is None:
             slot.first_token_at = now
@@ -534,6 +538,7 @@ class _EngineBase:
         the weights it pinned at admit, never a blend."""
         B = self.config.max_batch
         cfg = self.config.cache
+        rec = get_recorder()
         # resolve capacity for every slot first: growing one slot may
         # preempt another that was already swept, so the batch is built
         # only from the survivors
@@ -544,6 +549,8 @@ class _EngineBase:
         for i, slot in enumerate(self.slots):
             if slot is not None:
                 by_ver.setdefault(slot.ver, []).append(i)
+        get_registry().histogram("engine.occupancy").observe(
+            self.active_requests / B)
         if not by_ver:
             return
         rows: dict[int, np.ndarray] = {}
@@ -557,23 +564,35 @@ class _EngineBase:
                 tokens[i, 0] = slot.tokens[-1]
                 lengths[i] = len(slot.tokens)
                 tables[i] = self.cache.block_table(slot.alloc)
-            logits, self.k_pages, self.v_pages = self.step_fns.decode(
-                self._params_by_ver[ver], self.k_pages, self.v_pages,
-                jnp.asarray(tokens), jnp.asarray(lengths),
-                jnp.asarray(tables))
-            logits = np.asarray(logits)
+            # dispatch to logits on the host: device time plus the D2H
+            with rec.span("engine:decode_call",
+                          hist="engine.decode_call_s", loop=True):
+                logits, self.k_pages, self.v_pages = self.step_fns.decode(
+                    self._params_by_ver[ver], self.k_pages, self.v_pages,
+                    jnp.asarray(tokens), jnp.asarray(lengths),
+                    jnp.asarray(tables))
+                logits = np.asarray(logits)
             for i in members:
                 rows[i] = logits[i]
         self.steps += 1
         self.last_step_at = self.clock()
-        for i in sorted(rows):
-            slot = self.slots[i]
-            slot.alloc.length = len(slot.tokens)
-            token = self._pick_token(slot, rows[i])
-            slot.logprob_sum += _token_logprob(rows[i], token)
-            self._emit_token(slot, token)
-            if self._finished(slot):
-                self._retire(i)
+        with rec.span("engine:sample", hist="engine.sample_s", loop=True):
+            for i in sorted(rows):
+                slot = self.slots[i]
+                slot.alloc.length = len(slot.tokens)
+                token = self._pick_token(slot, rows[i])
+                slot.logprob_sum += _token_logprob(rows[i], token)
+                self._emit_token(slot, token)
+                if self._finished(slot):
+                    self._retire(i)
+
+    def _admit_waiting(self) -> None:
+        """Fill free slots from the queue head until it blocks."""
+        with get_recorder().span("engine:admit",
+                                 hist="engine.admit_s", loop=True):
+            while self.waiting:
+                if not self._admit_from_waiting():
+                    break
 
 
 class ContinuousEngine(_EngineBase):
@@ -581,11 +600,11 @@ class ContinuousEngine(_EngineBase):
     the next step, nothing waits for a batch to finish."""
 
     def step(self) -> None:
-        self.shed_expired()
-        while self.waiting:
-            if not self._admit_from_waiting():
-                break
-        self._decode_active()
+        with get_recorder().span("engine:step",
+                                 hist="engine.step_s", loop=True):
+            self.shed_expired()
+            self._admit_waiting()
+            self._decode_active()
 
 
 class StaticEngine(_EngineBase):
@@ -593,9 +612,9 @@ class StaticEngine(_EngineBase):
     member finishes before admitting again."""
 
     def step(self) -> None:
-        self.shed_expired()
-        if self.active_requests == 0:
-            while self.waiting:
-                if not self._admit_from_waiting():
-                    break
-        self._decode_active()
+        with get_recorder().span("engine:step",
+                                 hist="engine.step_s", loop=True):
+            self.shed_expired()
+            if self.active_requests == 0:
+                self._admit_waiting()
+            self._decode_active()

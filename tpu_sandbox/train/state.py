@@ -15,6 +15,8 @@ import numpy as np
 import optax
 from flax import struct
 
+from tpu_sandbox.obs import get_recorder
+
 
 @struct.dataclass
 class TrainState:
@@ -31,17 +33,27 @@ class TrainState:
     @classmethod
     def create(cls, model, rng, sample_input, tx: optax.GradientTransformation):
         """Init by tracing (gives the reference's LazyLinear sizing without
-        its CPU dummy-forward dance, mnist_onegpu.py:39)."""
-        try:
-            variables = model.init(rng, sample_input, train=False)
-        except TypeError:  # model without a train-mode switch (e.g. the LM)
-            variables = model.init(rng, sample_input)
+        its CPU dummy-forward dance, mnist_onegpu.py:39).
+
+        The two set-up spans end when the device has finished, not when the
+        (eager, op-by-op) init has been enqueued: every caller waits for the
+        state before it can do anything else."""
+        rec = get_recorder()
+        with rec.span("setup:model_init",
+                      hist="setup.model_init_s", loop=True):
+            try:
+                variables = model.init(rng, sample_input, train=False)
+            except TypeError:  # model without a train-mode switch (the LM)
+                variables = model.init(rng, sample_input)
+            jax.block_until_ready(variables)
         params = variables["params"]
+        with rec.span("setup:opt_init", hist="setup.opt_init_s", loop=True):
+            opt_state = jax.block_until_ready(tx.init(params))
         return cls(
             step=jax.numpy.zeros((), jax.numpy.int32),
             params=params,
             batch_stats=variables.get("batch_stats", {}),
-            opt_state=tx.init(params),
+            opt_state=opt_state,
         )
 
     def variables(self) -> dict:

@@ -34,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
-from tpu_sandbox.obs import get_recorder
+from tpu_sandbox.obs import get_recorder, get_registry
 from tpu_sandbox.ops.losses import cross_entropy_loss
 from tpu_sandbox.train.state import TrainState
 from tpu_sandbox.utils.metrics import MetricsWriter
@@ -147,7 +147,9 @@ def make_train_step(
         logits, mutated = model.apply(
             variables, images, train=True, mutable=["batch_stats"]
         )
-        return cross_entropy_loss(logits, labels), mutated.get("batch_stats", {})
+        with jax.named_scope("loss"):
+            loss = cross_entropy_loss(logits, labels)
+        return loss, mutated.get("batch_stats", {})
 
     # ``remat``: recompute the whole forward during backward instead of
     # saving activations (jax.checkpoint over the loss). The capacity
@@ -191,8 +193,9 @@ def make_train_step(
             )
             grads = jax.tree.map(lambda g: g / accum_steps, grads)
             loss = loss / accum_steps
-        updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = tx.update(grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
         return (
             state.replace(
                 step=state.step + 1,
@@ -219,6 +222,53 @@ def make_eval_step(model, *, image_size: tuple[int, int] | None = None) -> Calla
         return correct, loss
 
     return eval_step
+
+
+class LoopSpans:
+    """The spans of a training loop (``obs/record.py``: the profiler's
+    timeline, the registry, and the JSONL when enabled), in one place for
+    ``Trainer`` and ``lm_train.train``. ``train:next_batch``,
+    ``train:dispatch`` and ``train:sync`` tile an iteration; ``train:step``
+    is the interval from one return of the step call to the next — the
+    loop's cadence, which the device paces once its queue is full (a loop's
+    first step has no predecessor and records none). None of them waits for
+    the device where the loop did not already."""
+
+    def __init__(self):
+        self.rec = get_recorder()
+        self.steps = get_registry().counter("train.steps")
+        self._returned: float | None = None
+
+    def batches(self, loader):
+        """``loader``'s batches, each ``next()`` under ``train:next_batch``
+        (the draw that finds the loader empty included)."""
+        done = object()
+        it = iter(loader)
+        while True:
+            with self.rec.span("train:next_batch",
+                               hist="train.next_batch_s", loop=True):
+                batch = next(it, done)
+            if batch is done:
+                return
+            yield batch
+
+    def dispatch(self):
+        return self.rec.span("train:dispatch", hist="train.dispatch_s",
+                             loop=True)
+
+    def returned(self, step: int) -> None:
+        """The step call has returned: close the interval since the last."""
+        if self._returned is not None:
+            self.rec.complete("train:step", self._returned,
+                              args={"step": step}, hist="train.step_s",
+                              loop=True)
+        self._returned = time.monotonic()
+        self.steps.inc()
+
+    def sync(self, why: str):
+        """Around every place the loop does wait for the device."""
+        return self.rec.span("train:sync", hist="train.sync_s", loop=True,
+                             args={"why": why})
 
 
 class Trainer:
@@ -260,7 +310,10 @@ class Trainer:
             from tpu_sandbox.train.checkpoint import AsyncSaver
 
             self._saver = AsyncSaver(self.ckpt_dir)
-        if self._saver.save(self.state_for_checkpoint(state), opt_step):
+        with self._spans.sync("checkpoint"):
+            saved = self._saver.save(self.state_for_checkpoint(state),
+                                     opt_step)
+        if saved:
             if self.verbose:
                 print(f"checkpoint saved at step {opt_step}")
         elif self.verbose:
@@ -289,6 +342,7 @@ class Trainer:
         start = time.monotonic()
         total_step = len(loader)
         opt_step = int(jax.numpy.ravel(state.step)[0])  # resume-safe seed
+        self._spans = LoopSpans()  # a fit's first step has no predecessor
         try:
             with (MetricsWriter(metrics_path) if metrics_path
                   else contextlib.nullcontext()) as mw:
@@ -301,7 +355,9 @@ class Trainer:
                 # a lost crash-recovery checkpoint
                 self._saver.close()
                 self._saver = None
-        jax.block_until_ready(state)
+        with self._spans.sync("fit_end"):
+            jax.block_until_ready(state)
+        self._spans.rec.flush()  # off the hot path: the loop has ended
         self.elapsed = timedelta(seconds=time.monotonic() - start)
         if self.verbose:
             print("Training complete in: " + str(self.elapsed))
@@ -309,14 +365,14 @@ class Trainer:
 
     def _run_epochs(self, state, loader, epochs, set_epoch, total_step,
                     opt_step, mw=None):
+        spans = self._spans
         for epoch in range(epochs):
             if set_epoch:
                 loader.set_epoch(epoch)
-            for i, (images, labels) in enumerate(loader):
-                t_step = time.monotonic()
-                state, loss = self.train_step(state, images, labels)
-                get_recorder().complete("train:step", t_step,
-                                        args={"step": opt_step + 1})
+            for i, (images, labels) in enumerate(spans.batches(loader)):
+                with spans.dispatch():
+                    state, loss = self.train_step(state, images, labels)
+                spans.returned(opt_step + 1)
                 opt_step += 1
                 self._maybe_checkpoint(state, opt_step)
                 if (i + 1) % self.log_every == 0:
@@ -331,7 +387,8 @@ class Trainer:
                         loss_host = loss.addressable_shards[0].data
                     else:
                         loss_host = loss
-                    loss_val = float(jax.numpy.ravel(loss_host)[0])
+                    with spans.sync("log"):
+                        loss_val = float(jax.numpy.ravel(loss_host)[0])
                     self.losses.append(loss_val)
                     if mw is not None:
                         mw.write(opt_step, loss=loss_val, epoch=epoch + 1)

@@ -4,22 +4,28 @@ The reference's only instrumentation is a wall-clock print
 (``datetime.now() - start``, mnist_onegpu.py:61,83-84 — kept verbatim by
 train.Trainer). SURVEY §5 calls a real profiler "a free idiomatic add" on
 TPU, so: ``trace()`` wraps ``jax.profiler`` (XLA/TPU timeline viewable in
-TensorBoard/Perfetto) and ``StepTimer`` turns step wall-times into the
-images/sec numbers BASELINE.md wants.
+TensorBoard/Perfetto). Regions are named by the program's one span
+primitive (``obs.record.Recorder.span``), which annotates the profiler's
+timeline itself; there is no annotation helper here.
 """
 
 from __future__ import annotations
 
 import contextlib
 import time
-from dataclasses import dataclass, field
 
 import jax
 
 
 @contextlib.contextmanager
 def trace(logdir: str, *, host_tracer_level: int = 2):
-    """Capture an XLA profiler trace for the enclosed block."""
+    """Capture an XLA profiler trace for the enclosed block
+    (``mnist_onegpu.py --profile DIR``). The program's own spans
+    (``train:next_batch`` / ``train:dispatch`` / ``train:sync``,
+    ``place:*``, ``setup:*``, ``engine:*``) are ``TraceAnnotation``s on
+    the trace's ``/host:CPU`` plane, on the same clock as the device ops
+    of the ``/device:TPU:N`` planes: this is where an operator sees what
+    the host was doing over each device gap."""
     options = jax.profiler.ProfileOptions()
     options.host_tracer_level = host_tracer_level
     jax.profiler.start_trace(logdir, profiler_options=options)
@@ -27,13 +33,6 @@ def trace(logdir: str, *, host_tracer_level: int = 2):
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-@contextlib.contextmanager
-def annotate(name: str):
-    """Name a region so it shows up on the trace timeline."""
-    with jax.profiler.TraceAnnotation(name):
-        yield
 
 
 def host_sync(x) -> float:
@@ -107,39 +106,3 @@ def measure_per_step_repeated(run_steps, n: int, repeats: int = 3) -> dict:
     if bad:
         out["nonpositive_samples"] = bad
     return out
-
-
-@dataclass
-class StepTimer:
-    """Throughput measurement: call start() once, tick(n_items) per step."""
-
-    warmup: int = 1
-    _steps: int = 0
-    _items: int = 0
-    _t0: float | None = None
-    step_times: list = field(default_factory=list)
-
-    def start(self) -> None:
-        self._t0 = time.perf_counter()
-
-    def tick(self, n_items: int = 0) -> None:
-        now = time.perf_counter()
-        if self._t0 is None:
-            self._t0 = now
-            return
-        self._steps += 1
-        if self._steps > self.warmup:
-            self.step_times.append(now - self._t0)
-            self._items += n_items
-        self._t0 = now
-
-    @property
-    def seconds_per_step(self) -> float:
-        if not self.step_times:
-            return float("nan")
-        return sum(self.step_times) / len(self.step_times)
-
-    @property
-    def items_per_second(self) -> float:
-        total = sum(self.step_times)
-        return self._items / total if total > 0 else float("nan")
