@@ -38,13 +38,14 @@ KILL_SWITCHES = (
 )
 
 #: Pallas scopes the compiled step must hold as ``tpu_custom_call``s, by the
-#: differentiation direction of their ``op_name`` (the fc head's forward is
-#: an XLA dot; only its input-grad is a kernel).
+#: differentiation direction of their ``op_name`` (the fc head's forward
+#: kernel flattens the activation, its backward one is the input-grad; the
+#: contractions between them are XLA dots).
 KERNEL_SCOPES = {
     "/bn1.fused_conv1/": ("forward", "backward"),
     "/conv2/": ("forward", "backward"),
     "/bn2.fused/": ("forward", "backward"),
-    "/fc/": ("backward",),
+    "/fc/": ("forward", "backward"),
 }
 
 #: First-step loss band around ln(10): ten classes, lecun-normal head over

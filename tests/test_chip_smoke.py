@@ -49,7 +49,8 @@ def test_check_kernels_wants_every_scope_in_every_direction():
     paths = [f"{fwd}/bn1.fused_conv1", f"{bwd}/bn1.fused_conv1",
              f"{fwd}/conv2", f"{bwd}/conv2",
              f"{fwd}/ConvNetS2DT._tail/bn2.fused",
-             f"{bwd}/ConvNetS2DT._tail/bn2.fused", f"{bwd}/fc"]
+             f"{bwd}/ConvNetS2DT._tail/bn2.fused", f"{fwd}/fc",
+             f"{bwd}/fc"]
     assert chip_smoke.check_kernels(
         "\n".join(_call(p) for p in paths), "t") == len(paths)
     # the fc input-grad fell back to an XLA dot: a failure, not a slower pass

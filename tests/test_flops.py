@@ -144,10 +144,11 @@ def test_s2d_custom_call_flops_counts_pallas_calls_only():
 
 
 def test_s2d_custom_call_flops_knows_every_kernel_of_the_production_step():
-    """The r05 step's 11 Pallas calls by op_name, as the v5e compile
-    prints them: the conv1+tail composite (2 forward, 2 backward), conv2
-    (fwd, dgrad, wgrad), the bn2 tail (fwd, reduce, apply) and the fc
-    input-grad. None may be 'unmatched', or the composed cross-check is
+    """The step's 12 Pallas calls by op_name, as the v5e compile prints
+    them: the conv1+tail composite (2 forward, 2 backward), conv2 (fwd,
+    dgrad, wgrad), the bn2 tail (fwd, reduce, apply), the fc input-grad
+    and — since PR 24 — the fc forward's flatten, a copy that counts no
+    flops. None may be 'unmatched', or the composed cross-check is
     withheld."""
     from tpu_sandbox.utils.flops import s2d_custom_call_flops
 
@@ -160,13 +161,13 @@ def test_s2d_custom_call_flops_knows_every_kernel_of_the_production_step():
     hlo = "\n".join(
         [call(f"{fwd}/bn1.fused_conv1")] * 2 + [call(f"{fwd}/conv2")]
         + [call(f"{fwd}/ConvNetS2DT._tail/bn2.fused")]
-        + [call(f"{bwd}/fc")]
+        + [call(f"{fwd}/fc"), call(f"{bwd}/fc")]
         + [call(f"{bwd}/ConvNetS2DT._tail/bn2.fused")] * 2
         + [call(f"{bwd}/conv2")] * 2 + [call(f"{bwd}/bn1.fused_conv1")] * 2
     )
     c = s2d_custom_call_flops(hlo, 5, 3000, plan="ConvNetS2DT",
                               sparse_conv1=True)
-    assert c["custom_calls_counted"] == 11
+    assert c["custom_calls_counted"] == 12
     assert c["unmatched_pallas_calls"] == 0
     base = 2.0 * 5 * 750 * 750
     # composite: conv + tail forward, reduce pass, then ONE backward

@@ -16,7 +16,12 @@ sys.path.insert(0, os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "tools"))
 
 from hlo_schedule import schedule_report  # noqa: E402
-from hlo_traffic import collective_bytes, shape_bytes  # noqa: E402
+from hlo_traffic import (  # noqa: E402
+    collective_bytes,
+    shape_bytes,
+    traffic_rows,
+    while_loops,
+)
 
 # ---------------------------------------------------------------------------
 # shape_bytes: TPU tiling padding
@@ -32,6 +37,80 @@ def test_shape_bytes_unpadded_and_padded():
     assert shape_bytes("bf16[4,100]{1,0:T(8,128)(2,1)}") == 16 * 128 * 2
     # tuple shapes sum element-wise; unknown dtypes (token) are skipped
     assert shape_bytes("(f32[16], s32[4])") == 16 * 4 + 4 * 4
+    # the tile's own numbers decide: one row a tile pads the lanes only
+    assert shape_bytes("f32[1,1,300]{2,1,0:T(1,128)}") == 384 * 4
+    assert shape_bytes("bf16[5,300]{1,0:T(2,128)(2,1)}") == 8 * 384 * 2
+    assert shape_bytes("f32[1000]{0:T(1024)}") == 1024 * 4
+
+
+# ---------------------------------------------------------------------------
+# while_loops / traffic_rows: loops are seen, and counted by trip count
+# ---------------------------------------------------------------------------
+
+_LOOP_HLO = """\
+HloModule mod, is_scheduled=true
+
+%fused_slice (param_0.1: f32[10,4096], param_1.1: u32[]) -> f32[4096] {
+  %param_0.1 = f32[10,4096]{1,0:T(8,128)} parameter(0)
+  %param_1.1 = u32[] parameter(1)
+  %zero = u32[] constant(0)
+  %dynamic-slice.1 = f32[1,4096]{1,0:T(1,128)} dynamic-slice(%param_0.1, %param_1.1, %zero), dynamic_slice_sizes={1,4096}
+  ROOT %reshape.1 = f32[4096]{0:T(1024)} reshape(%dynamic-slice.1)
+}
+
+%body (wide.param: (u32[], f32[10,4096], f32[40960])) -> (u32[], f32[10,4096], f32[40960]) {
+  %wide.param = (u32[]{:T(128)}, f32[10,4096]{1,0:T(8,128)}, /*index=2*/f32[40960]{0:T(1024)}) parameter(0)
+  %i = u32[] get-tuple-element(%wide.param), index=0
+  %one = u32[] constant(1)
+  %next = u32[] add(%i, %one)
+  %w = f32[10,4096]{1,0:T(8,128)} get-tuple-element(%wide.param), index=1
+  %flat = f32[40960]{0:T(1024)} get-tuple-element(%wide.param), index=2
+  %row.1 = f32[4096]{0:T(1024)} fusion(%w, %i), kind=kLoop, calls=%fused_slice
+  %dynamic-update-slice.1 = f32[40960]{0:T(1024)} dynamic-update-slice(%flat, %row.1, %i)
+  ROOT %tuple.1 = (u32[]{:T(128)}, f32[10,4096]{1,0:T(8,128)}, /*index=2*/f32[40960]{0:T(1024)}) tuple(%next, %w, %dynamic-update-slice.1)
+}
+
+%cond (wide.param.1: (u32[], f32[10,4096], f32[40960])) -> pred[] {
+  %wide.param.1 = (u32[]{:T(128)}, f32[10,4096]{1,0:T(8,128)}, /*index=2*/f32[40960]{0:T(1024)}) parameter(0)
+  %j = u32[] get-tuple-element(%wide.param.1), index=0
+  %ten = u32[] constant(10)
+  ROOT %lt = pred[] compare(%j, %ten), direction=LT
+}
+
+ENTRY %main.1 (p0: f32[10,4096]) -> f32[40960] {
+  %p0 = f32[10,4096]{1,0:T(8,128)} parameter(0)
+  %c0 = u32[] constant(0)
+  %buf = f32[40960]{0:T(1024)} broadcast(%c0), dimensions={}
+  %tuple.0 = (u32[]{:T(128)}, f32[10,4096]{1,0:T(8,128)}, /*index=2*/f32[40960]{0:T(1024)}) tuple(%c0, %p0, %buf)
+  %while.1 = (u32[]{:T(128)}, f32[10,4096]{1,0:T(8,128)}, /*index=2*/f32[40960]{0:T(1024)}) while(%tuple.0), condition=%cond, body=%body
+  ROOT %out = f32[40960]{0:T(1024)} get-tuple-element(%while.1), index=2
+}
+"""
+
+
+def test_while_loops_are_reported_with_shapes_and_trips():
+    (lp,) = while_loops(_LOOP_HLO)
+    assert (lp["while"], lp["in"], lp["body"]) == ("while.1", "main.1", "body")
+    assert lp["trip_count"] == 10
+    # the weight pads 10 -> 16 sublanes; the flat buffer does not
+    assert lp["carried_max_bytes"] == 16 * 4096 * 4
+
+
+def test_loop_bodies_count_times_their_trip_count():
+    rows, loops = traffic_rows(_LOOP_HLO)
+    assert len(loops) == 1
+    by_op = {r["op"]: r for r in rows}
+    # ENTRY: the zero-fill, once; the while itself moves nothing
+    assert by_op["buf"]["times"] == 1 and "while.1" not in by_op
+    # the slicing fusion reads one 4096-float row an iteration, not the
+    # whole weight; ten iterations
+    row = by_op["row.1"]
+    assert (row["in"], row["times"]) == ("ENTRY/while.1", 10)
+    assert row["read_mb"] == 10 * (4096 * 4 + 4) / 1e6
+    assert row["write_mb"] == 10 * 4096 * 4 / 1e6
+    # the in-place update writes (and reads) the slice, not the buffer
+    dus = by_op["dynamic-update-slice.1"]
+    assert dus["write_mb"] == dus["read_mb"] == 10 * 4096 * 4 / 1e6
 
 
 # ---------------------------------------------------------------------------

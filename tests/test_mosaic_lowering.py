@@ -295,9 +295,10 @@ def test_sparse_tap_conv1_lowers_for_tpu(restage, monkeypatch):
 
 
 def test_pallas_fc_dgrad_lowers_for_tpu():
-    """The r05 fc input-grad kernel (ops/pallas_fc_t.py) at production
-    geometry: K=10 classes, C=32, W=750, bs=16 — the scalar-FMA
-    accumulation with g in SMEM, under real Mosaic."""
+    """The fc head's two kernels (ops/pallas_fc_t.py: the flatten and the
+    input-grad with its in-VMEM un-flatten) at production geometry: K=10
+    classes, C=32, W=750 (rows stored at lane offsets that are no
+    multiple of 128), bs=16, under real Mosaic."""
     from tpu_sandbox.ops.pallas_fc_t import fc_t
 
     rng = np.random.default_rng(12)

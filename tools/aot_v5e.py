@@ -94,6 +94,43 @@ def compile_step(topo, plan: str, batch: int, image_size: int = 3000,
     return step.trace(state, imgs, labs).lower().compile()
 
 
+def compile_head(topo, batch: int, image_size: int = 3000):
+    """Only the s2dt plan's fc head: ``fc_t`` forward, its three gradients
+    and the SGD add, on shapes alone — the 18,000,000 x 10 weight against
+    the [N, H/4, 32, W/4] map. Ten seconds where the whole step takes 35,
+    and the program tests/test_pallas_fc_t.py holds free of weight-sized
+    loops."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from tpu_sandbox.ops.pallas_fc_t import fc_t
+
+    sh = NamedSharding(Mesh(np.array(topo.devices), ("data",)), P())
+    dtype = jnp.bfloat16  # the production compute dtype
+    h = w = image_size // 4
+    c, k = 32, 10
+
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+
+    def train_step(y, kernel, bias, labels):
+        def loss(y, kernel, bias):
+            logits = fc_t(y, kernel, bias, dtype).astype(jnp.float32)
+            picked = jnp.take_along_axis(
+                jax.nn.log_softmax(logits), labels[:, None], 1)
+            return -jnp.mean(picked)
+
+        dy, dkernel, dbias = jax.grad(loss, argnums=(0, 1, 2))(
+            y, kernel, bias)
+        return dy, kernel - 1e-4 * dkernel, bias - 1e-4 * dbias
+
+    return jax.jit(train_step, donate_argnums=(1, 2)).lower(
+        spec((batch, h, c, w), dtype), spec((h * c * w, k), jnp.float32),
+        spec((k,), jnp.float32), spec((batch,), jnp.int32)).compile()
+
+
 def analyze(compiled, plan: str, batch: int, remat: bool = False) -> dict:
     ma = compiled.memory_analysis()
     ca = compiled.cost_analysis()

@@ -7,17 +7,26 @@ computation materializes its output once and reads its operands, so
 (padded output bytes + padded operand bytes) per instruction is the
 traffic model — the same accounting XLA's own `bytes accessed` uses,
 but attributable to individual ops and op classes (conv fwd / dgrad /
-wgrad, packed-form copies, Mosaic kernels, fusions).
+wgrad, packed-form copies, Mosaic kernels, fusions). A ``while`` is
+counted as its body's instructions times its trip count, and every
+``while`` is printed with the shapes it carries: up to PR 24 this tool
+read ENTRY alone and said "while/cond absent from this step", which is
+how two per-class loops over the fc weight (36 ms a step on the chip)
+went unseen (PERF.md section 6, PR 24).
 
-Padded bytes honor the TPU tiling in the dump: layout T(8,128) pads the
-two minor physical dims to (8·(32/bits), 128) — the [.,.,.,16]-lane
-pathology this repo's s2d plan exists to kill shows up directly here.
+Padded bytes honor the TPU tiling in the dump: layout T(8,128)(2,1) pads
+the two minor physical dims to (8·(32/bits), 128), T(a,b) to (a, b) — the
+[.,.,.,16]-lane pathology this repo's s2d plan exists to kill shows up
+directly here.
 
 Chipless (uses the local libtpu via jax.experimental.topologies, like
 tools/aot_v5e.py — single-process: do not run two AOT tools at once).
 Estimates, not measurements; the bench owns measured truth.
 
 Usage: python tools/hlo_traffic.py [--plan s2d] [--batch 16] [--top 25]
+       python tools/hlo_traffic.py --head --batch 5   (the fc head alone)
+Exit code 3: the TPU topology could not be described (no libtpu here, or
+another process holds it).
 """
 
 from __future__ import annotations
@@ -62,12 +71,16 @@ def shape_bytes(text: str) -> int:
             phys = [dims[i] for i in reversed(perm)]
         else:
             phys = list(dims)
-        if "T(" in (layout or "") and len(phys) >= 2:
-            sub = 8 * (32 // bits)  # bf16: (16,128) second-level tiling
+        tile = re.search(r"T\((\d+)(?:,(\d+))?\)(\(\d+,1\))?", layout or "")
+        if tile and tile.group(2) and len(phys) >= 2:
+            # T(8,128)(2,1): the second-level tiling packs 32/bits rows
+            # into a sublane, so a bf16 tile is 16 rows; T(1,128) pads
+            # the lanes only
+            sub = int(tile.group(1)) * (32 // bits if tile.group(3) else 1)
             phys[-2] = -(-phys[-2] // sub) * sub
-            phys[-1] = -(-phys[-1] // 128) * 128
-        elif "T(" in (layout or "") and len(phys) == 1:
-            phys[-1] = -(-phys[-1] // 128) * 128
+            phys[-1] = -(-phys[-1] // int(tile.group(2))) * int(tile.group(2))
+        elif tile:
+            phys[-1] = -(-phys[-1] // int(tile.group(1))) * int(tile.group(1))
         n = 1
         for d in phys:
             n *= d
@@ -154,6 +167,155 @@ def collective_bytes(hlo_text: str) -> dict:
     return {"total": sum(by_opcode.values()), "by_opcode": dict(by_opcode)}
 
 
+_COMPUTATION = re.compile(
+    r"^(ENTRY\s+)?%?([\w.\-]+)\s+\(.*?\)\s*->\s*.*?\{\s*$", re.M)
+_INST_HEAD = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*", re.M)
+_OPCODE = re.compile(r"\s*([\w\-]+)\(")
+
+
+def instructions(comp_text: str):
+    """(name, result shape, opcode, text after ``opcode(``, whole line) of
+    every instruction of one computation. A tuple shape is taken to its
+    balancing paren: it may hold ``/*index=5*/`` comments and layouts
+    with parens of their own."""
+    for line in comp_text.splitlines():
+        m = _INST_HEAD.match(line)
+        if not m:
+            continue
+        tail = line[m.end():]
+        if tail.startswith("("):
+            shape_s = "(" + operand_region(tail[1:]) + ")"
+        else:
+            shape_s = tail.split(" ", 1)[0]
+        op = _OPCODE.match(tail[len(shape_s):])
+        if op:
+            yield (m.group(1), shape_s, op.group(1),
+                   tail[len(shape_s) + op.end():], line)
+
+
+_NO_TRAFFIC = ("parameter", "constant", "get-tuple-element", "tuple",
+               "bitcast", "while")
+
+
+def computations(hlo_text: str) -> tuple[dict[str, str], str | None]:
+    """Split an HLO module into ``{computation name: body text}``; also
+    returns the ENTRY computation's name."""
+    heads = list(_COMPUTATION.finditer(hlo_text))
+    comps, entry = {}, None
+    for m, nxt in zip(heads, heads[1:] + [None]):
+        end = nxt.start() if nxt else len(hlo_text)
+        comps[m.group(2)] = hlo_text[m.end():end]
+        if m.group(1):
+            entry = m.group(2)
+    return comps, entry
+
+
+def _trip_count(cond_text: str) -> int | None:
+    """Trip count of the canonical counted loop XLA emits: the condition
+    compares the induction variable (from 0, step 1) ``LT`` against one
+    integer constant. Anything else: unknown."""
+    consts = re.findall(r"=\s*[su]\d+\[\][^ ]*\s+constant\((\d+)\)", cond_text)
+    if len(consts) == 1 and "direction=LT" in cond_text:
+        return int(consts[0])
+    return None
+
+
+def while_loops(hlo_text: str) -> list[dict]:
+    """Every ``while`` of the module, wherever it sits: its name, the
+    computation holding it, body and condition, trip count (None when
+    the condition is not a counted loop) and the shapes it carries with
+    their padded bytes, largest first."""
+    comps, _ = computations(hlo_text)
+    loops = []
+    for comp, body_text in comps.items():
+        for name, shape_s, opcode, rest, _ in instructions(body_text):
+            if opcode != "while":
+                continue
+            body = re.search(r"body=%?([\w.\-]+)", rest)
+            cond = re.search(r"condition=%?([\w.\-]+)", rest)
+            carried = sorted(
+                ((f"{dt}[{dims}]", shape_bytes(f"{dt}[{dims}]{{{lay}}}"))
+                 for dt, dims, lay in _SHAPE.findall(shape_s)),
+                key=lambda kv: -kv[1])
+            loops.append({
+                "while": name, "in": comp,
+                "body": body.group(1) if body else None,
+                "trip_count": _trip_count(
+                    comps.get(cond.group(1), "") if cond else ""),
+                "carried": [{"shape": sh, "mb": round(b / 1e6, 1)}
+                            for sh, b in carried if b >= 1 << 20],
+                "carried_max_bytes": carried[0][1] if carried else 0,
+            })
+    return loops
+
+
+def _sliced_params(fused_text: str) -> dict[int, int]:
+    """Parameters of a fused computation that only ``dynamic-slice`` ops
+    read -> the bytes of those slices (what the fusion really loads)."""
+    insts = [i[:4] for i in instructions(fused_text)]
+    params = {}
+    for name, _, opcode, rest in insts:
+        if opcode == "parameter":
+            params[name] = int(re.match(r"\s*(\d+)", rest).group(1))
+    out = {}
+    for pname, idx in params.items():
+        users = [(op, shape_s, rest) for _, shape_s, op, rest in insts
+                 if pname in operand_tokens(rest) and op != "parameter"]
+        if users and all(op == "dynamic-slice"
+                         and operand_tokens(rest)[0] == pname
+                         for op, _, rest in users):
+            out[idx] = sum(shape_bytes(sh) for _, sh, _ in users)
+    return out
+
+
+def traffic_rows(hlo_text: str) -> tuple[list[dict], list[dict]]:
+    """(rows, loops): one row per instruction that moves bytes — the
+    ENTRY computation's, and each ``while`` body's with its traffic
+    multiplied by the trip count (nested loops multiply; an unknown
+    trip count counts once and says so). A ``dynamic-slice`` reads, and
+    a ``dynamic-update-slice`` reads and writes, the slice and not the
+    buffer; a fusion that only slices an operand reads the slices."""
+    comps, entry = computations(hlo_text)
+    loops = while_loops(hlo_text)
+    by_holder = collections.defaultdict(list)
+    for lp in loops:
+        by_holder[lp["in"]].append(lp)
+    rows = []
+
+    def walk(comp: str, times: int, where: str) -> None:
+        shapes: dict[str, int] = {}
+        for name, shape_s, opcode, rest, line in instructions(
+                comps.get(comp, "")):
+            out_b = shape_bytes(shape_s)
+            shapes[name] = out_b
+            if opcode in _NO_TRAFFIC:
+                continue
+            # tokens are filtered through the name table, so shape,
+            # comment and keyword tokens count as 0
+            reads = [shapes[o] for o in operand_tokens(rest) if o in shapes]
+            if opcode == "dynamic-slice":
+                reads = [out_b]
+            elif opcode == "dynamic-update-slice" and len(reads) > 1:
+                out_b, reads = reads[1], [reads[1]]
+            elif opcode == "fusion":
+                called = re.search(r"calls=%?([\w.\-]+)", rest)
+                sliced = _sliced_params(
+                    comps.get(called.group(1), "")) if called else {}
+                reads = [sliced.get(i, b) for i, b in enumerate(reads)]
+            rows.append({
+                "op": name, "class": classify(opcode, line, out_b),
+                "opcode": opcode, "in": where, "times": times,
+                "write_mb": out_b * times / 1e6,
+                "read_mb": sum(reads) * times / 1e6,
+            })
+        for lp in by_holder.get(comp, []):
+            walk(lp["body"], times * (lp["trip_count"] or 1),
+                 f"{where}/{lp['while']}")
+
+    walk(entry, 1, "ENTRY")
+    return rows, loops
+
+
 _OPNAME = re.compile(r'op_name="jit\(train_step\)/([^"]*)"')
 
 
@@ -190,6 +352,9 @@ def main():
     p.add_argument("--batch", type=int, default=16)
     p.add_argument("--image-size", type=int, default=3000)
     p.add_argument("--top", type=int, default=25)
+    p.add_argument("--head", action="store_true",
+                   help="compile the s2dt fc head alone (fc_t forward, "
+                        "gradients, SGD add: ~10 s) instead of the step")
     p.add_argument("--hlo-file", default=None,
                    help="re-analyze an existing optimized-HLO dump instead "
                         "of recompiling (~5 min saved per iteration)")
@@ -200,37 +365,25 @@ def main():
     if args.hlo_file:
         text = open(args.hlo_file).read()
     else:
-        from aot_v5e import compile_step, make_topology
+        from aot_v5e import compile_head, compile_step, make_topology
 
-        topo = make_topology()
-        compiled = compile_step(topo, args.plan, args.batch, args.image_size)
+        try:
+            topo = make_topology()
+        except Exception as e:  # no libtpu, or another process holds it
+            print(f"no TPU topology: {e}", file=sys.stderr)
+            sys.exit(3)
+        if args.head:
+            compiled = compile_head(topo, args.batch, args.image_size)
+        else:
+            compiled = compile_step(topo, args.plan, args.batch,
+                                    args.image_size)
         text = compiled.as_text()
         if args.dump_hlo:
             open(args.dump_hlo, "w").write(text)
 
-    # ENTRY computation only: fusions count once (their internals stay in
-    # registers/VMEM); while/cond absent from this step.
-    entry = text[text.index("ENTRY "):]
-    shapes: dict[str, int] = {}
-    rows = []
-    inst = re.compile(
-        r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\(?[^=]+?\)?)\s+([\w\-]+)\((.*)",
-        re.M,
-    )
-    for m in inst.finditer(entry):
-        name, shape_s, opcode, rest = m.groups()
-        out_b = shape_bytes(shape_s)
-        shapes[name] = out_b
-        if opcode in ("parameter", "constant", "get-tuple-element", "tuple",
-                      "bitcast"):
-            continue
-        # tokens are filtered through the name table, so shape, comment
-        # and keyword tokens count as 0
-        in_b = sum(shapes.get(o, 0) for o in operand_tokens(rest))
-        rows.append({
-            "op": name, "class": classify(opcode, m.group(0), out_b),
-            "opcode": opcode, "write_mb": out_b / 1e6, "read_mb": in_b / 1e6,
-        })
+    # fusions count once (their internals stay in registers/VMEM); a
+    # while counts its body times its trip count
+    rows, loops = traffic_rows(text)
 
     per_img = args.batch
     by_class = collections.defaultdict(float)
@@ -243,9 +396,12 @@ def main():
         "gb_per_img": round(total / 1e3 / per_img, 3),
         "by_class_gb": {k: round(v / 1e3, 2) for k, v in sorted(
             by_class.items(), key=lambda kv: -kv[1])},
+        "while_loops": len(loops),
         "source": "optimized-HLO padded-buffer accounting "
                   "(chipless AOT estimate, not a measurement)",
     }))
+    for lp in loops:
+        print(json.dumps(lp))
     for r in sorted(rows, key=lambda r: -(r["write_mb"] + r["read_mb"]))[
             : args.top]:
         r["write_mb"] = round(r["write_mb"], 1)

@@ -227,19 +227,20 @@ class _GroupedBNT(nn.Module):
 
 
 class _DenseT(nn.Module):
-    """nn.Dense over the transposed feature map WITHOUT materializing the
-    (h, w, c) activation transpose. The kernel variable stays
+    """nn.Dense over the transposed feature map. The kernel variable stays
     [h*w*c, k] with rows flattened in canonical (h, c, w) order — the
     parameter tree is bit-identical to ConvNet's fc: same init path (so
     the same values under the same key), rows in the framework-canonical
     (h, c, w) order that all three plans share — see models/convnet.py
     (the torch reference's own NCHW flatten is (c, h, w); utils/parity.py
-    re-blocks between the conventions). The contraction reads y in
-    its native [N, h, c, w] layout against the kernel viewed as
-    [h, c, w, k]: contraction order aligned on both sides, so neither
-    the 2.3 GB activation nor the 180M-param weight is ever relayouted
-    (the r03 step spent ~40 ms/step at bs=16 on exactly those copies —
-    measured/hlo_cycles_s2dt_b16_r04.json)."""
+    re-blocks between the conventions). On the v5e the [F, k] parameter
+    is stored k-major ([k, F], classes on sublanes), and ops/pallas_fc_t
+    contracts against exactly that: the activation is flattened to
+    [N, F] (and its gradient un-flattened) inside two small Pallas
+    kernels, the weight and its gradient never change layout. The
+    [k, h, c, w] view this class took before PR 24 was a copy of the
+    whole weight each way, 36 ms of an 89.7 ms step on the chip (PERF.md
+    section 6, PR 24); the kill-switch branch below still takes it."""
 
     features: int
     dtype: jnp.dtype
@@ -254,11 +255,10 @@ class _DenseT(nn.Module):
         bias = self.param(
             "bias", nn.initializers.zeros, (self.features,), jnp.float32
         )
-        # r05: the input-grad of this contraction runs a Pallas kernel
-        # that emits dy in the native [N,h,C,W] layout (ops/pallas_fc_t
-        # — kills the ~540 MB dgrad relayout the XLA einsum paid; fwd
-        # and weight-grad stay the same XLA dots). Env kill switch reads
-        # at trace time like TPU_SANDBOX_NO_SPARSE_CONV1.
+        # ops/pallas_fc_t: Pallas flatten + input-grad kernels around
+        # XLA contractions against kernel.T. The env kill switch (the
+        # plain einsum, the tests' oracle) reads at trace time like
+        # TPU_SANDBOX_NO_SPARSE_CONV1.
         if os.environ.get("TPU_SANDBOX_NO_PALLAS_FC") != "1":
             from tpu_sandbox.ops.pallas_fc_t import fc_t
 

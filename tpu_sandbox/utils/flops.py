@@ -103,7 +103,8 @@ def convnet_flops(image_size: int, num_classes: int = 10) -> ConvNetFlops:
 #: (ops/pallas_conv1_tail_t.py). Forward: the sparse conv1 (64 tap rows
 #: x 256) and the tail (256 x 64 selection) — the same depth. ``fc`` is
 #: the head's Pallas input-grad (ops/pallas_fc_t.py: 10 classes x f2 = 32
-#: channels per output position; its forward and wgrad are XLA dots).
+#: channels per output position; its forward and wgrad are XLA dots, and
+#: its forward Pallas call is the flatten, a copy: _S2D_KERNEL_K_FWD).
 _S2D_KERNEL_K = {
     "/bn1.fused_conv1/": 64 * 256,
     "/conv1/": 9 * 16 * 256,   # in 16 (s2d image), out blk^2*f1 = 256
@@ -118,6 +119,10 @@ _S2D_KERNEL_K = {
 #: plus ONE kernel doing the selection matmul AND the conv1 wgrad dot —
 #: three 64x256-deep contractions over two calls, so 1.5 per call.
 _S2D_KERNEL_K_BWD = {"/bn1.fused_conv1/": 1.5 * 64 * 256}
+
+#: forward calls whose depth differs from the class's: the head's forward
+#: kernel only flattens the activation
+_S2D_KERNEL_K_FWD = {"/fc/": 0}
 
 #: the transposed plan's conv1 runs the sparse-tap union-tile kernel
 #: since r04 (ops/pallas_conv5_t.py): K = 64 tap rows, not 9C = 144
@@ -190,6 +195,8 @@ def s2d_custom_call_flops(hlo_text: str, batch: int, image_size: int,
             if tag in path:
                 if "transpose(" in path:
                     k = _S2D_KERNEL_K_BWD.get(tag, k)
+                else:
+                    k = _S2D_KERNEL_K_FWD.get(tag, k)
                 key = tag.strip("/")
                 per_class[key] = per_class.get(key, 0.0) + base * k
                 count += 1
