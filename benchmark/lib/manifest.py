@@ -13,7 +13,8 @@ A cell is one entry of ``workloads``. Its files:
 Runner kinds (``benchmark/runners/<kind>.py``), references
 (``benchmark/reference/<name>.py``) and per-layer metrics
 (``benchmark/layer_metrics/<name>.py``) are modules found by the same rule.
-Nothing here lists names: a later PR adds files and entries.
+Nothing here lists names: a later PR adds files and entries
+(``benchmark/sweeps/NOTES.md`` lists which).
 """
 
 from __future__ import annotations

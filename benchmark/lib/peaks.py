@@ -59,6 +59,24 @@ def transformer_train_flops_per_token(n_layers: int, d_model: int, d_ff: int,
     return 3.0 * (n_layers * per_layer + 2.0 * d_model * vocab)
 
 
+def causal_attention_train_flops(batch: int, heads: int, seq: int,
+                                 head_dim: int, layers: int) -> float:
+    """FLOPs of the attention proper (scores and values, no projections)
+    that one training step NEEDS: the causal half of the two ``seq x seq``
+    matmuls a head, forward once (2 matmuls) and backward 2.5 times that (5
+    matmuls: scores again inside the kernel, dV, dP, dQ, dK) -- 3.5 x
+    (2 x 2 x B x H x S^2 x D / 2) a layer. What a kernel does beyond that
+    does not count: the masked half of a block it does not skip, the
+    forward pass repeated under ``remat``. So the share of the peak this
+    gives stays under 100 % whatever a later kernel skips or repeats. At
+    S 1024, D 64 the FLOPs bind, not the bytes: a layer's 60 GFLOP want
+    0.31 ms of the bf16 peak where its q, k, v, o and their gradients (0.2
+    GB) want 0.25 ms of HBM; a configuration where the bytes bind needs
+    their count too."""
+    forward = 2.0 * 2.0 * batch * heads * seq * seq * head_dim / 2.0
+    return 3.5 * forward * layers
+
+
 def mfu_pct(flops_per_step: float, step_s: float, device_kind: str,
             chips: int) -> float:
     """Model FLOP/s utilisation, in percent of ``chips`` x the bf16 peak."""

@@ -4,6 +4,8 @@ the harness leaves the metric out of the line."""
 
 from __future__ import annotations
 
+import re
+
 from benchmark.lib import stats
 from benchmark.lib.observe import Observations
 
@@ -47,3 +49,42 @@ def module_ms(obs: Observations, pattern: str, q: float | None = None) -> float 
               for ns in runs]
     value = stats.median(values) if q is None else stats.percentile(values, q)
     return None if value is None else value / 1e6
+
+
+def _scope_sum(obs: Observations, pattern: str, column: int) -> float | None:
+    """Per step and per chip, the nanoseconds (``column`` 1) or the calls
+    (``column`` 2) of the trace's operations whose scope matches ``pattern``.
+    An untraced run has nothing to read; a traced one whose runner noted no
+    program, or whose programs hold no such scope, says which."""
+    from benchmark.lib.trace_reduce import joined
+
+    if obs.trace is None or not obs.attempted:
+        return None
+    if not obs.scopes:
+        obs.problem(f"scope {pattern!r}: the runner noted no compiled "
+                    "program (Observations.note_program)")
+        return None
+    match = re.compile(pattern).search
+    rows = [row for row in joined(obs.trace["devices"], obs.scopes)
+            if row[0] and match(row[0])]
+    if not rows:
+        obs.problem(f"scope {pattern!r}: no operation of the trace runs "
+                    "under a scope it matches")
+        return None
+    return (sum(row[column] for row in rows) / len(obs.trace["devices"])
+            / obs.attempted)
+
+
+def scope_ms(obs: Observations, pattern: str) -> float | None:
+    """Device milliseconds a step in the operations whose ``op_name`` (the
+    compiled program's, joined by ``trace_reduce.joined``) the regular
+    expression ``pattern`` is found in: forward, recomputation and backward
+    of a module together, averaged over the chips, as ``pallas_ms``. A
+    fusion counts under its root's scope (``trace_reduce``'s docstring)."""
+    ns = _scope_sum(obs, pattern, 1)
+    return None if ns is None else ns / 1e6
+
+
+def scope_calls(obs: Observations, pattern: str) -> float | None:
+    """How many such operations a step runs on a chip."""
+    return _scope_sum(obs, pattern, 2)

@@ -16,10 +16,31 @@ copies live on a line of their own (``Async XLA Ops``) and are not counted
 as busy. Host threads are lines of the plane ``/host:CPU``; the
 ``TraceAnnotation`` s are on its line ``python3``, on the same clock. All
 times are nanoseconds.
+
+**Time by scope** (PR 25). The trace has no name-scope line and an ``XLA
+Ops`` event carries no ``op_name``, only the instruction's text. So time
+by scope is a join: the event's instruction name, looked up in the
+``op_name`` s of the compiled program it ran in (``observe.
+instruction_scopes`` of ``compiled.as_text()``, kept by program name in
+``Observations.scopes``). The program is the ``XLA Modules`` event that
+holds the operation's start, its name cut before ``(<id>)``; instruction
+names are unique within a module, not across modules, so a window that
+runs two programs is joined program by program, and two programs of one
+name (a prefill program per bucket) are told apart only where their
+instruction names differ: an instruction they scope differently counts as
+``observe.AMBIGUOUS``. What the join is: exact for custom calls (a Pallas
+kernel is one instruction under one module) and for collectives. What it
+is not: a fusion is one event under the ``op_name`` of its *root*, so a
+fusion that spans two modules is all under one of them (the ConvNet
+head's weight gradient fused into the SGD update counts under ``fc``, not
+under ``optimizer``); an instruction the compiler inserted (a copy, an
+async pair) has no ``op_name`` and counts as ``(no scope)``; an operation
+of a program nobody noted counts as ``(other program)``.
 """
 
 from __future__ import annotations
 
+import bisect
 import re
 from dataclasses import dataclass, field
 from pathlib import Path
@@ -39,6 +60,17 @@ COLLECTIVE = re.compile(rf"^{_COLLECTIVE_OPS}|\s{_COLLECTIVE_OPS}\(")
 #: instructions that only wrap others: their time is their children's
 WRAPPERS = re.compile(r"^(while|conditional|call)([.\d]|$)")
 
+#: scope segments that are jax's own (checkpoint, shard_map), not the model's
+_JAX_MARKERS = {"checkpoint", "rematted_computation", "shard_map"}
+_TRANSFORMED = re.compile(r"^\w+\((.*)\)$")
+_NUMBERED = re.compile(r"^(.*?)(\d+)$")
+NO_SCOPE = "(no scope)"
+OTHER_PROGRAM = "(other program)"
+#: rows of ``device_scopes`` name at most this many scopes from the top
+SCOPE_DEPTH = 4
+#: more numbered siblings than this (block0 .. block23) are one row
+MAX_SIBLINGS = 4
+
 Event = tuple[str, int, int]  # name, start_ns, duration_ns
 
 
@@ -55,6 +87,86 @@ class Plane:
 def instruction_name(text: str) -> str:
     """``%fusion.12 = f32[8]{0} fusion(...)`` -> ``fusion.12``."""
     return text.split(" = ", 1)[0].strip().lstrip("%")
+
+
+def program_of(module_event: str) -> str:
+    """``jit_train_step(2742204733650636828)`` -> ``jit_train_step``."""
+    return module_event.split("(", 1)[0]
+
+
+def scope_path(op_name: str | None) -> str:
+    """The module path of an ``op_name``, forward and backward together:
+    ``jit(step)/transpose(jvp(TransformerLM))/jvp(TransformerLM)/checkpoint/
+    rematted_computation/block3/attn/qkv/add`` -> ``TransformerLM/block3/
+    attn/qkv``. The program's own ``jit(...)`` and the primitive at the end
+    go; a scope under a transformation (``jvp(loss)``) is that scope;
+    jax's own markers (``checkpoint``, ``shard_map``) and repeats go; an
+    inner ``jit(...)`` (a
+    library function: ``jit(_take)``) ends the path; at most
+    ``SCOPE_DEPTH`` segments stay. Of several ``op_name`` s joined by ``;``
+    the first counts."""
+    if not op_name:
+        return NO_SCOPE
+    if op_name.startswith("("):  # OTHER_PROGRAM, observe.AMBIGUOUS
+        return op_name
+    parts = op_name.split(";", 1)[0].split("/")
+    if parts and parts[0].startswith(("jit(", "pjit(")):
+        parts = parts[1:]
+    path: list[str] = []
+    for part in parts[:-1]:
+        if part.startswith(("jit(", "pjit(")):
+            break
+        while (m := _TRANSFORMED.match(part)):
+            part = m.group(1)
+        if part in _JAX_MARKERS or (path and path[-1] == part):
+            continue
+        path.append(part)
+    return "/".join(path[:SCOPE_DEPTH]) or NO_SCOPE
+
+
+def fold_siblings(table: dict[str, int]) -> dict[str, int]:
+    """Rows that differ only in the number at the end of one segment
+    (``block0/attn`` .. ``block23/attn``) as one row ``block*/attn``, where
+    there are more than ``MAX_SIBLINGS`` of them; ``conv1`` and ``conv2``
+    stay two rows."""
+    def split(path: str, at: int):
+        """``(family, folded path)`` of a path whose segment ``at`` ends in
+        a number; the family is the path up to that segment."""
+        parts = path.split("/")
+        m = _NUMBERED.match(parts[at]) if at < len(parts) else None
+        if not m:
+            return None, path
+        head = parts[:at] + [m.group(1) + "*"]
+        return "/".join(head), "/".join(head + parts[at + 1:])
+
+    for at in range(SCOPE_DEPTH):
+        members: dict[str, set[str]] = {}
+        for path in table:
+            family, _ = split(path, at)
+            if family is not None:
+                members.setdefault(family, set()).add(path.split("/")[at])
+        out: dict[str, int] = {}
+        for path, ns in table.items():
+            family, folded = split(path, at)
+            if family is None or len(members[family]) <= MAX_SIBLINGS:
+                folded = path
+            out[folded] = out.get(folded, 0) + ns
+        table = out
+    return table
+
+
+def joined(devices: list[dict], scopes: dict[str, dict[str, str]]):
+    """``(op_name | None, ns, calls)`` of every operation of the reduced
+    ``devices``, wrappers left out (their bodies' operations are there),
+    each joined through the program it ran in (module docstring)."""
+    for d in devices:
+        for program, ops in d["by_program"].items():
+            names = scopes.get(program)
+            for op, (ns, calls) in ops.items():
+                if WRAPPERS.match(op):
+                    continue
+                yield (OTHER_PROGRAM if names is None else names.get(op),
+                       ns, calls)
 
 
 def find_xplane(trace_dir) -> Path:
@@ -217,8 +329,19 @@ def reduce_device(plane: Plane, window) -> dict:
     compute = merge((s, s + d) for n, s, d in ops
                     if n not in moves and not WRAPPERS.match(n))
     by_op: dict[str, int] = {}
-    for n, _, d in ops:
+    # program -> op -> [ns, calls]: an operation belongs to the program
+    # whose execution (unclipped) holds its start; "" where none does
+    by_program: dict[str, dict[str, list[int]]] = {}
+    runs = sorted((s, s + d, program_of(n))
+                  for n, s, d in plane.lines.get(MODULES_LINE, []))
+    starts = [r[0] for r in runs]
+    for n, s, d in ops:
         by_op[n] = by_op.get(n, 0) + d
+        i = bisect.bisect_right(starts, s) - 1
+        program = runs[i][2] if i >= 0 and s < runs[i][1] else ""
+        cell = by_program.setdefault(program, {}).setdefault(n, [0, 0])
+        cell[0] += d
+        cell[1] += 1
     modules: dict[str, list[int]] = {}
     for n, s, d in plane.lines.get(MODULES_LINE, []):
         # whole executions only: a clipped one is not a step time
@@ -232,6 +355,7 @@ def reduce_device(plane: Plane, window) -> dict:
         "collective_ns": total(coll),
         "collective_exposed_ns": total(subtract(coll, compute)),
         "by_op_ns": by_op,
+        "by_program": by_program,
         "custom_calls": sorted(plane.custom_calls),
         "modules_ns": modules,
         "gaps": subtract([(w0, w1)], busy),
@@ -239,10 +363,24 @@ def reduce_device(plane: Plane, window) -> dict:
     }
 
 
+def cut(rows: list, top: int, rest: str | None = None) -> list:
+    """The first ``top`` rows of a ranked table; with ``rest``, the last of
+    them is the sum of all that was cut, under that name, so that the
+    table's sum stays."""
+    if rest is None or len(rows) <= top:
+        return rows[:top]
+    return rows[:top - 1] + [[rest, sum(v for _, v in rows[top - 1:])]]
+
+
 def reduce(planes, *, marker: str = "bench:window", prefix: str = "bench:",
-           top: int = 10) -> dict | None:
+           scopes: dict[str, dict[str, str]] | None = None,
+           top: int = 20) -> dict | None:
     """The whole trace as one summary; ``None`` when no operation ran on a
-    device (a reader that finds nothing to read returns nothing)."""
+    device (a reader that finds nothing to read returns nothing).
+    ``device_scopes`` is ``device_ops`` regrouped by ``scope_path`` through
+    ``scopes`` (``Observations.scopes``; empty without them): the same
+    operations and the same seconds, so both tables have one sum; what does
+    not fit ``top`` rows is its last row, ``(other)``."""
     window = window_of(planes, marker)
     devices = [reduce_device(p, window) for p in planes
                if DEVICE_PLANE.match(p.name)]
@@ -264,9 +402,15 @@ def reduce(planes, *, marker: str = "bench:window", prefix: str = "bench:",
         label = name_gap(gap, annotations)
         gap_ns[label] = gap_ns.get(label, 0) + (gap[1] - gap[0])
 
-    def ranked(table, scale=1.0):
-        return [[n, ns * scale / 1e9] for n, ns in
-                sorted(table.items(), key=lambda kv: -kv[1])[:top]]
+    by_scope: dict[str, int] = {}
+    for scope, ns, _ in joined(devices, scopes or {}):
+        path = scope_path(scope)
+        by_scope[path] = by_scope.get(path, 0) + ns
+    by_scope = fold_siblings(by_scope)
+
+    def ranked(table, scale=1.0, rest=None):
+        rows = sorted(table.items(), key=lambda kv: -kv[1])
+        return cut([[n, ns * scale / 1e9] for n, ns in rows], top, rest)
 
     return {
         "devices": devices,
@@ -276,5 +420,7 @@ def reduce(planes, *, marker: str = "bench:window", prefix: str = "bench:",
         "idle_pct_worst": 100.0 * max(
             1.0 - d["busy_ns"] / d["window_ns"] for d in devices),
         "device_ops": ranked(by_op, 1.0 / n_dev),
+        "device_scopes": (ranked(by_scope, 1.0 / n_dev, rest="(other)")
+                          if scopes else []),
         "idle_gaps": ranked(gap_ns),
     }

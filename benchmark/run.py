@@ -37,6 +37,9 @@ KILL_SWITCHES = (
 #: per-layer numbers need a steady stretch, not the whole window.
 TRACE_SECONDS = 10.0
 TRACE_DIR = ROOT / ".bench_trace"
+#: the result line's contract: a list of ``breakdown`` holds at most ten
+#: rows; the reduction's twenty go into ``notes``, which the driver ignores
+LINE_ROWS = 10
 
 
 def die(msg: str) -> None:
@@ -98,7 +101,7 @@ def measure(runner, obs, session, seconds: float) -> None:
     path = trace_reduce.find_xplane(TRACE_DIR)
     obs.trace = trace_reduce.reduce(
         trace_reduce.load(path, host_prefix=PREFIX), marker=WINDOW,
-        prefix=PREFIX)
+        prefix=PREFIX, scopes=obs.scopes)
     shutil.rmtree(TRACE_DIR, ignore_errors=True)
     if obs.trace is None:
         obs.problem("the traced window holds no device operation")
@@ -186,8 +189,16 @@ def main() -> None:
         if obs.trace is not None:
             device["busy_s"] = obs.trace["busy_s"]
             device["window_s"] = obs.trace["window_s"]
-            breakdown = {"device_ops": obs.trace["device_ops"],
-                         "idle_gaps": obs.trace["idle_gaps"]}
+            from benchmark.lib.trace_reduce import cut
+
+            breakdown = {
+                "device_ops": cut(obs.trace["device_ops"], LINE_ROWS),
+                "idle_gaps": cut(obs.trace["idle_gaps"], LINE_ROWS)}
+            if obs.trace["device_scopes"]:
+                breakdown["device_scopes"] = cut(
+                    obs.trace["device_scopes"], LINE_ROWS, "(other)")
+            obs.notes["breakdown_top20"] = {
+                key: obs.trace[key] for key in ("device_ops", "device_scopes")}
     else:
         values = dict(runner.end_to_end(obs), setup_s=setup_s)
         for m in cell["end_to_end"]:

@@ -15,7 +15,7 @@ from typing import Any
 import numpy as np
 
 from benchmark.lib import manifest, peaks, traffic
-from benchmark.lib.observe import Observations, pallas_instructions
+from benchmark.lib.observe import Observations
 from benchmark.lib.train_window import (BoundedLoader, Window,
                                         compile_clocked, train_step_ms)
 
@@ -53,8 +53,8 @@ def _compile_train_step(obs: Observations, lower):
     as a compiled kernel in each direction (a kernel that gave way to a
     reference is a failure, not a slower pass)."""
     compiled = compile_clocked(obs, lower)
-    kernels = pallas_instructions(compiled.as_text())
-    obs.op_scopes.update(kernels)
+    obs.note_program(compiled.as_text())
+    kernels = obs.op_scopes
     obs.facts["pallas_calls"] = len(kernels)
     for scope, directions in obs.cell["config"].get("kernel_scopes", {}).items():
         for direction in directions:

@@ -201,6 +201,10 @@ def setup(obs: Observations) -> Session:
     cell = obs.cell
     eng, params = build(cell["config"], cell["deployment"], obs.seed,
                         obs.facts)
+    # jit_serve_prefill (one program a bucket) and jit_serve_decode: their
+    # scopes, so that write_kv / gather_ctx have a device time
+    for program in (*eng.step_fns.prefill.values(), eng.step_fns.decode):
+        obs.note_program(program.as_text())
     t0 = time.perf_counter()
     check_against_reference(obs, eng, params)
     obs.facts["reference_check_s"] = time.perf_counter() - t0

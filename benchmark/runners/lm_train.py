@@ -13,7 +13,7 @@ from typing import Any
 
 
 from benchmark.lib import manifest, peaks, traffic
-from benchmark.lib.observe import Observations, pallas_instructions
+from benchmark.lib.observe import Observations
 from benchmark.lib.train_window import (Window, compile_clocked,
                                         train_step_ms)
 
@@ -108,7 +108,7 @@ def setup(obs: Observations) -> Session:
     batches = traffic.token_batches(spec, obs.seed, config["vocab_size"])
     first = eng.shard_batch(*next(batches))
     compiled = compile_clocked(obs, lambda: eng.lower_step(state, *first))
-    obs.op_scopes.update(pallas_instructions(compiled.as_text()))
+    obs.note_program(compiled.as_text())
     obs.facts["pallas_calls"] = len(obs.op_scopes)
     if dep["flash"] and not any("/attn/" in s for s in obs.op_scopes.values()):
         obs.problem("no Pallas attention kernel in the compiled step")
@@ -129,6 +129,9 @@ def setup(obs: Observations) -> Session:
         peaks.transformer_train_flops_per_token(
             config["n_layer"], config["n_embd"], config["n_inner"], seq_len,
             config["vocab_size"]))
+    obs.facts["attn_flops_per_step"] = peaks.causal_attention_train_flops(
+        batch, config["n_head"], seq_len, config["n_embd"] // config["n_head"],
+        config["n_layer"])
     return Session(eng, state, compiled, batches)
 
 

@@ -83,15 +83,58 @@ def test_median_and_spread():
     assert stats.quartile_spread([1, 2, 3, 4, 5]) == pytest.approx(2 / 3)
 
 
-def test_manifest_is_valid():
-    assert manifest.validate(ROOT) == []
-    m = manifest.load(ROOT)
-    four = [w for w in m["workloads"] if w["chips"] == 4]
-    assert len(four) == 1 and len(m["workloads"]) == 3
+#: What must stay true of ``BENCHMARK.json`` however many cells, metrics and
+#: configurations later PRs add (they add files and entries and edit
+#: nothing, this file included): PR 22's cells with their chips and
+#: configurations, the two bounds, and the cells each per-layer metric of
+#: PRs 22 to 25 is read in. A later cell appends itself to such a list.
+ONE, DP4, LM = ("convnet3000_1chip_bs5", "convnet3000_dp4_bs5",
+                "gpt2m_train_s1024")
+CELLS = {ONE: (1, "convnet3000"), DP4: (4, "convnet3000"),
+         LM: (1, "gpt2-medium")}
+BOUNDS = {"train_step_ms": 0.01, "setup_s": 0.1}
+READ_IN = {
+    "loader_wait_ms": [ONE, DP4, LM], "device_step_ms": [ONE, DP4, LM],
+    "pallas_ms": [ONE, DP4, LM], "mfu_pct": [ONE, DP4, LM],
+    "device_idle_pct": [ONE, DP4, LM],
+    "allreduce_ms": [DP4], "allreduce_exposed_ms": [DP4],
+    "loop_step_ms": [ONE, DP4], "loop_loader_wait_ms": [ONE, DP4],
+    "place_batch_ms": [DP4, LM], "state_place_s": [DP4, LM],
+    "model_init_s": [ONE, DP4, LM], "opt_init_s": [ONE, DP4, LM],
+    "compile_cache_misses": [ONE, DP4, LM],
+    "attn_ms": [LM], "flash_attn_roofline": [LM], "head_ms": [ONE, DP4],
+    "optimizer_ms": [DP4],
+}
+#: what a one-chip training cell built through ``TrainState.create`` and an
+#: engine's ``shard_state`` reports besides its own metrics
+ONE_CHIP_TRAINING = ("model_init_s", "opt_init_s", "compile_cache_misses",
+                     "place_batch_ms", "state_place_s", "device_step_ms",
+                     "pallas_ms", "mfu_pct", "loader_wait_ms",
+                     "device_idle_pct")
+
+
+def assert_benchmark_invariants(root: Path) -> None:
+    assert manifest.validate(root) == []
+    m = manifest.load(root)
     assert m["run_seconds"] == 10
+    assert len(json.dumps(m)) < 64 * 1024
+    cells = {w["name"]: (w["chips"], w["config"]) for w in m["workloads"]}
+    assert CELLS.items() <= cells.items()
+    assert {c["name"] for c in m["configs"]} == {c for _, c in cells.values()}
+    end_to_end = {x["name"]: x for x in m["end_to_end"]}
+    for name, bound in BOUNDS.items():
+        assert end_to_end[name]["bound"] == bound
     for metric in m["end_to_end"]:
         assert 0 < metric["bound"] <= 0.1
-    assert len(json.dumps(m)) < 64 * 1024
+    assert set(CELLS) <= set(end_to_end["train_step_ms"]["workloads"])
+    per_layer = {x["name"]: x for x in m["per_layer"]}
+    for name, want in READ_IN.items():
+        assert set(want) <= set(per_layer[name]["workloads"]), name
+        assert (manifest.home(root) / "layer_metrics" / f"{name}.py").is_file()
+
+
+def test_manifest_is_valid():
+    assert_benchmark_invariants(ROOT)
 
 
 def copy_benchmark(tmp_path: Path) -> Path:
@@ -108,7 +151,7 @@ def copy_benchmark(tmp_path: Path) -> Path:
     (lambda m: m["end_to_end"][0].update(unit="tokens per second"), "unit"),
     (lambda m: m["end_to_end"][0].update(bound=0.5), "bound"),
     (lambda m: m["end_to_end"][0].update(source="program_span"), "read from the program"),
-    (lambda m: m["workloads"][0].update(chips=4), "four-chip"),
+    (lambda m: [w.update(chips=4) for w in m["workloads"]], "four-chip"),
     (lambda m: m["workloads"][0].update(traffic="no_such_mix"), "no traffic file"),
     (lambda m: m["per_layer"][0].update(name="no_reader"), "no reader"),
     (lambda m: m["per_layer"][0].update(moves="nothing"), "moves unknown"),
@@ -169,12 +212,14 @@ def test_the_kept_serving_cell_needs_entries_only(tmp_path):
     assert manifest.validate(root) == []
     cell = manifest.cell(SERVE_CELL, root)
     assert cell["runner"] == "lm_serve"
-    assert {m["name"] for m in cell["end_to_end"]} == {
+    assert {m["name"] for m in cell["end_to_end"]} >= {
         "serve_tok_per_s", "ttft_p90_ms", "itl_p99_ms", "setup_s"}
-    # every serving reader under layer_metrics/ is one of those entries
+    # with the serving entries every entry has its reader (a reader kept
+    # for a later cell may be there without an entry)
     listed = {x["name"] for x in manifest.load(root)["per_layer"]}
     on_disk = {p.stem for p in (root / "benchmark/layer_metrics").glob("*.py")}
-    assert on_disk - {"__init__"} == listed
+    assert {name for name, *_ in SERVE_PER_LAYER} <= listed <= on_disk
+    assert_benchmark_invariants(root)
 
 
 def test_a_later_pr_adds_files_and_entries_only(tmp_path):
@@ -209,14 +254,20 @@ def test_a_later_pr_adds_files_and_entries_only(tmp_path):
                            "better": "higher", "source": "program_counter",
                            "layer": "toy", "moves": "setup_s",
                            "workloads": ["toy_cell"]})
-    m["end_to_end"][0]["workloads"].append("toy_cell")  # train_step_ms
+    # a one-chip training cell: it appends itself to train_step_ms and to
+    # every per-layer metric such a cell reports
+    for x in m["end_to_end"] + m["per_layer"]:
+        if x["name"] in ("train_step_ms", *ONE_CHIP_TRAINING):
+            x["workloads"].append("toy_cell")
     (root / "BENCHMARK.json").write_text(json.dumps(m))
 
-    assert manifest.validate(root) == []
+    assert_benchmark_invariants(root)
     cell = manifest.cell("toy_cell", root)
+    assert {x["name"] for x in cell["per_layer"]} >= {
+        "init_s", "trace_lower_s", "compile_s", "toy_metric",
+        *ONE_CHIP_TRAINING}
     assert cell["runner"] == "toy_runner" and cell["reference"] == "toy_ref"
     assert cell["deployment"] == {"dtype": "bf16", "max_batch": 2}
-    assert [x["name"] for x in cell["per_layer"]][-1] == "toy_metric"
     assert manifest.module("runners", "toy_runner", root).setup(None) == "session"
     assert manifest.module("reference", "toy_ref", root).TOLERANCE == {}
 

@@ -4,6 +4,8 @@ the metric readers. The numbers these runs produce are counts and
 correctness only; a time from the CPU is never a device metric."""
 
 import copy
+import functools
+import re
 import sys
 from pathlib import Path
 
@@ -44,9 +46,11 @@ TINY_GPT2 = {"n_layer": 2, "n_embd": 32, "n_head": 4, "n_inner": 64,
              "vocab_size": 97, "n_positions": 64}
 
 
-@pytest.mark.parametrize("name,chips", [("convnet3000_1chip_bs5", 1),
-                                        ("convnet3000_dp4_bs5", 4)])
-def test_convnet_train_runner_tiny(name, chips):
+CONVNET_CELLS = [("convnet3000_1chip_bs5", 1), ("convnet3000_dp4_bs5", 4)]
+
+
+@functools.cache
+def tiny_convnet(name: str, chips: int) -> Observations:
     cell = tiny_cell(
         name, chips=chips,
         # plan "auto" is the interpretable NHWC plan on the CPU; the s2dt
@@ -54,7 +58,21 @@ def test_convnet_train_runner_tiny(name, chips):
         config={"kernel_scopes": {}},
         deployment={"image_size": 32, "plan": "auto"},
         traffic={"batch_per_chip": 2, "n_images": 64, "steps_per_chunk": 2})
-    obs = drive(cell, seconds=1.5)
+    return drive(cell, seconds=1.5)
+
+
+@functools.cache
+def tiny_lm() -> Observations:
+    cell = tiny_cell(
+        "gpt2m_train_s1024", config=TINY_GPT2,
+        deployment={"flash": False, "dtype": "fp32", "remat": False},
+        traffic={"batch": 2, "seq_len": 16, "steps_per_chunk": 2})
+    return drive(cell, seconds=1.5)
+
+
+@pytest.mark.parametrize("name,chips", CONVNET_CELLS)
+def test_convnet_train_runner_tiny(name, chips):
+    obs = tiny_convnet(name, chips)
     assert obs.problems == []
     assert obs.attempted >= 2 and obs.failed == 0
     assert obs.end_to_end["train_step_ms"] > 0
@@ -65,16 +83,14 @@ def test_convnet_train_runner_tiny(name, chips):
     for metric in ("init_s", "trace_lower_s", "compile_s", "loader_wait_ms"):
         assert manifest.module("layer_metrics", metric).read(obs) is not None
     # no trace was taken: trace readers return nothing, they do not guess
-    for metric in ("device_step_ms", "pallas_ms", "device_idle_pct"):
+    for metric in ("device_step_ms", "pallas_ms", "device_idle_pct",
+                   "head_ms", "optimizer_ms"):
         assert manifest.module("layer_metrics", metric).read(obs) is None
+    assert obs.problems == []
 
 
 def test_lm_train_runner_tiny():
-    cell = tiny_cell(
-        "gpt2m_train_s1024", config=TINY_GPT2,
-        deployment={"flash": False, "dtype": "fp32", "remat": False},
-        traffic={"batch": 2, "seq_len": 16, "steps_per_chunk": 2})
-    obs = drive(cell, seconds=1.5)
+    obs = tiny_lm()
     # 32 tokens a step: one tiny batch's loss is noise, and how many steps
     # fit the window depends on the box, so the rule that training lowers
     # the loss is tested on its own below, not here
@@ -82,6 +98,35 @@ def test_lm_train_runner_tiny():
     assert obs.attempted >= 2 and obs.failed == 0
     assert obs.notes["reference_deviation"]["logit_rms_rel"] < 1e-4
     assert obs.notes["reference_deviation"]["loss_abs"] < 1e-4
+    # 3.5 x (2 x 2 x B x H x S^2 x D / 2) x layers, B 2, H 4, S 16, D 8, 2 layers
+    assert obs.facts["attn_flops_per_step"] == 3.5 * 2 * 2 * 2 * 4 * 256 * 8 / 2 * 2
+    for metric in ("attn_ms", "flash_attn_roofline", "optimizer_ms"):
+        assert manifest.module("layer_metrics", metric).read(obs) is None
+
+
+#: the scopes the scope readers look for, in the step each runner compiles
+#: and notes: flax's module paths and the train step's named scopes
+STEP_SCOPES = (
+    [(tiny_convnet, cell, pattern) for cell in CONVNET_CELLS
+     for pattern in (r"/fc(/|$)", r"(^|/)optimizer(/|$)", r"loss",
+                     r"/conv2(/|$)")]
+    + [(tiny_lm, (), pattern)
+       for pattern in (r"/attn/", r"(^|/)optimizer(/|$)", r"loss",
+                       r"/block1/mlp/")])
+
+
+@pytest.mark.parametrize("driven,args,pattern", STEP_SCOPES)
+def test_the_compiled_step_carries_the_scopes_the_readers_match(
+        driven, args, pattern):
+    obs = driven(*args)
+    assert len(obs.scopes) == 1  # one program runs in the window
+    (program, scopes), = obs.scopes.items()
+    assert program.startswith("jit_")
+    assert any(re.search(pattern, s) for s in scopes.values())
+    # the Pallas kernels the runner noted are among them (none on the CPU,
+    # where kernels are interpreted; the chip's are in the hand-written text
+    # of test_benchmark_trace_reduce)
+    assert obs.op_scopes.items() <= scopes.items()
 
 
 def test_lm_train_holds_training_to_a_falling_loss():
@@ -132,6 +177,12 @@ def test_lm_serve_runner_tiny(serving_root):
     dev = obs.notes["reference_deviation"]
     assert dev["chosen_logprob_abs"] < 1e-4 and dev["chosen_gap_rel"] < 1e-4
     assert obs.attempted > 5 and obs.failed == 0
+    # both programs are noted by the names the trace gives them; the three
+    # prefill buckets share one, and what they scope differently is marked
+    assert set(obs.scopes) == {"jit_serve_prefill", "jit_serve_decode"}
+    decode = obs.scopes["jit_serve_decode"].values()
+    assert any("/attn/gather_ctx/" in s for s in decode)
+    assert any("/attn/write_kv/" in s for s in decode)
     e2e = obs.end_to_end
     assert e2e["serve_tok_per_s"] > 0 and e2e["itl_p99_ms"] > 0
     assert e2e["ttft_p90_ms"] > 0 and obs.notes["backlog"] == 0
