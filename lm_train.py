@@ -45,16 +45,18 @@ def make_batches(vocab: int, batch: int, seq_len: int, steps: int, seed: int):
         yield tokens, targets
 
 
-def train(args):
-    from tpu_sandbox.utils.cli import ensure_devices
-
-    devices = ensure_devices(args.devices, force_cpu=args.force_cpu)
-
-    import datetime
-
+def build(args, devices):
+    """Model, optimizer, state (on the mesh) and engine for ``args`` (a
+    namespace of ``build_parser``) over ``devices``: everything ``train``
+    needs before its loop, and what the benchmark's runner drives, so that
+    a cell measures this construction and not a copy of it. ``--model
+    xing4 --config <json>`` builds ``models.xing4.Xing4LM`` from the
+    published keys of the file (dp only); the size flags then do not apply.
+    A caller that has read the file already sets ``args.config`` to its
+    content.
+    """
     import jax
     import jax.numpy as jnp
-    import numpy as np
     import optax
     from jax.sharding import PartitionSpec as P
 
@@ -69,7 +71,6 @@ def train(args):
     from tpu_sandbox.runtime import bootstrap
     from tpu_sandbox.runtime.mesh import make_mesh
     from tpu_sandbox.train import TrainState
-    from tpu_sandbox.train.trainer import LoopSpans
 
     bootstrap.init()
     n = len(devices)
@@ -127,7 +128,7 @@ def train(args):
                 "(per-stage norms would diverge); clip under dp/tp/sp/ep"
             )
         tx = optax.chain(optax.clip_by_global_norm(args.clip_norm), tx)
-    rng = jax.random.key(0)
+    rng = jax.random.key(args.seed)
     sample = jnp.zeros((1, args.seq_len), jnp.int32)
 
     p = args.parallelism
@@ -139,7 +140,33 @@ def train(args):
             f"--parallelism dp (got {p!r}): other plans let XLA place the "
             "grad collectives"
         )
-    if p == "dp":
+    model = None  # the sp / pp engines build theirs from ``cfg``
+    if args.model == "xing4":
+        if p != "dp" or not args.config:
+            raise SystemExit("--model xing4 needs --config <json> and "
+                             "--parallelism dp")
+        import json
+        import types
+
+        from tpu_sandbox.models import xing4
+
+        config = args.config
+        if not isinstance(config, dict):
+            with open(config) as f:
+                config = json.load(f)
+        model = xing4.Xing4LM(xing4.Xing4Config.from_dict(
+            config, tokens_per_step=args.batch * args.seq_len, dtype=dtype,
+            remat=args.remat, flash=args.flash))
+        mesh = make_mesh({"data": n}, devices=devices)
+        # ``create`` runs ``init`` op by op, and this model has hundreds of
+        # distinct operations: hand it the init as one compiled program.
+        # Parameter shapes do not depend on the length: a short sample.
+        state = TrainState.create(
+            types.SimpleNamespace(init=jax.jit(model.init)), rng,
+            sample[:, :min(args.seq_len, 128)], tx)
+        eng = PjitEngine(model, tx, mesh, task="lm",
+                         mtp_weight=xing4.MTP_LOSS_WEIGHT)
+    elif p == "dp":
         mesh = make_mesh({"data": n}, devices=devices)
         model = TransformerLM(cfg, attention_fn=attention_fn)
         state = TrainState.create(model, rng, sample, tx)
@@ -227,12 +254,29 @@ def train(args):
     else:
         raise SystemExit(f"unknown parallelism {p!r}")
 
-    state = eng.shard_state(state)
+    return model, tx, eng.shard_state(state), eng
+
+
+def train(args):
+    from tpu_sandbox.utils.cli import ensure_devices
+
+    devices = ensure_devices(args.devices, force_cpu=args.force_cpu)
+
+    import datetime
+
+    import numpy as np
+
+    from tpu_sandbox.runtime import bootstrap
+    from tpu_sandbox.train.trainer import LoopSpans
+
+    model, _, state, eng = build(args, devices)
+    p = args.parallelism
+    vocab = model.config.vocab_size if args.model == "xing4" else args.vocab
     start = datetime.datetime.now()
     losses = []
     spans = LoopSpans()  # the same four spans as Trainer's loop
     for step, (tokens, targets) in enumerate(spans.batches(
-        make_batches(args.vocab, args.batch, args.seq_len, args.steps, 0)), 1
+        make_batches(vocab, args.batch, args.seq_len, args.steps, 0)), 1
     ):
         with spans.dispatch():
             state, loss = eng.train_step(
@@ -258,6 +302,14 @@ def build_parser() -> argparse.ArgumentParser:
     parser.add_argument("--parallelism",
                         choices=["dp", "tp", "sp", "pp", "pp_sp", "ep", "3d"],
                         default="dp")
+    parser.add_argument("--model", choices=["gpt2", "xing4"], default="gpt2",
+                        help="gpt2: TransformerLM from the size flags; "
+                             "xing4: models.xing4 from --config")
+    parser.add_argument("--config", default=None,
+                        help="xing4 only: the published config.json keys "
+                             "(benchmark/configs/xing4.0-29b-a4b.json)")
+    parser.add_argument("--seed", type=int, default=0,
+                        help="key of the weights' init")
     parser.add_argument("--dp", type=int, default=1,
                         help="tp only: data-parallel axis size composed "
                              "with model parallelism (devices = dp x tp)")

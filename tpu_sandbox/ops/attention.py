@@ -22,17 +22,22 @@ def causal_attention(
     v: jnp.ndarray,
     *,
     causal: bool = True,
+    scale: float | None = None,
     q_offset: int | jnp.ndarray = 0,
     kv_offset: int | jnp.ndarray = 0,
 ) -> jnp.ndarray:
-    """q,k,v: [B, S, H, D] -> [B, S, H, D].
+    """q,k: [B, S, H, D], v: [B, S, H, Dv] -> [B, S, H, Dv]. ``scale``
+    multiplies q.k before the softmax; None is ``D ** -0.5``.
 
     ``q_offset``/``kv_offset`` are the global positions of the first local
     query/key — the hooks sequence-sharded callers use to mask correctly.
     """
     d = q.shape[-1]
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
-    scores = scores / jnp.sqrt(jnp.asarray(d, jnp.float32))
+    if scale is None:
+        scores = scores / jnp.sqrt(jnp.asarray(d, jnp.float32))
+    else:
+        scores = scores * scale
     if causal:
         q_pos = q_offset + jnp.arange(q.shape[1])
         kv_pos = kv_offset + jnp.arange(k.shape[1])

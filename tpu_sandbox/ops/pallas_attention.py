@@ -12,7 +12,9 @@ accumulation.
 Shapes and grid:
 - inputs [B, H, S, D] (callers with [B, S, H, D] use ``flash_attention_fn``,
   which transposes, pads S to the q/k block and D to the 128-lane tile, and
-  undoes both on the way out);
+  undoes both on the way out); q and k share one head size and v (with the
+  output) may have another (latent attention: 192 for q.k, 128 for v), each
+  padded to its own lane multiple, and the softmax scale is an argument;
 - grid (B, H, S/block_q, S/block_k), kv innermost ("arbitrary" — it carries
   the softmax state); m/l/acc live in VMEM scratch across kv steps and the
   output + logsumexp are written on the last kv step.
@@ -136,8 +138,9 @@ def _fwd_kernel(q_off_ref, kv_off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, kv_len,
                q_offset=0, kv_offset=0, out_dtype=None):
-    """q,k,v [B,H,S,D] (S multiple of blocks, D lane-aligned; ``kv_len`` is
-    the true pre-padding length) -> (out [B,H,S,D], lse [B,H,S]).
+    """q,k [B,H,S,D], v [B,H,S,Dv] (S multiple of blocks, D and Dv
+    lane-aligned; ``kv_len`` is the true pre-padding length) ->
+    (out [B,H,S,Dv], lse [B,H,S]).
 
     ``q_offset``/``kv_offset`` are *global* positions of the first local
     query/key (python ints or traced scalars — ring attention passes the
@@ -150,7 +153,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, kv_len,
 
     interpret = default_interpret(interpret)
     b, h, s, d = q.shape
-    sk = k.shape[2]
+    sk, dv = k.shape[2], v.shape[3]
     grid = (b, h, s // block_q, sk // block_k)
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal,
@@ -164,7 +167,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, kv_len,
     out, lse = pl.pallas_call(
         kernel,
         out_shape=(
-            jax.ShapeDtypeStruct((b, h, s, d), out_dtype or q.dtype),
+            jax.ShapeDtypeStruct((b, h, s, dv), out_dtype or q.dtype),
             jax.ShapeDtypeStruct((b, h, s, _LANE), jnp.float32),
         ),
         grid=grid,
@@ -173,17 +176,17 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, kv_len,
             smem(),
             pl.BlockSpec((1, 1, block_q, d), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, block_k, d), lambda b, h, i, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda b, h, i, j: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, block_k, dv), lambda b, h, i, j: (b, h, j, 0)),
         ],
         out_specs=(
-            pl.BlockSpec((1, 1, block_q, d), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, block_q, dv), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, block_q, _LANE),
                          lambda b, h, i, j: (b, h, i, 0)),
         ),
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANE), jnp.float32),  # running max m
             pltpu.VMEM((block_q, _LANE), jnp.float32),  # running sum l
-            pltpu.VMEM((block_q, d), jnp.float32),      # output accumulator
+            pltpu.VMEM((block_q, dv), jnp.float32),     # output accumulator
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
@@ -313,7 +316,7 @@ def _flash_bwd(q, k, v, delta, lse, g, scale, causal, block_q, block_k,
                interpret, kv_len, q_offset=0, kv_offset=0, out_dtype=None):
     """Pallas backward: (dq, dk, dv), peak memory O(block) per core.
 
-    q,k,v,g [B,H,S,D] (block-padded, lane-aligned), lse [B,H,S] fp32,
+    q,k [B,H,S,D], v,g [B,H,S,Dv] (block-padded, lane-aligned), lse [B,H,S] fp32,
     delta = rowsum(g ⊙ out) [B,H,S] precomputed by the caller (once — ring
     callers reuse it across hops). ``out_dtype`` overrides the gradient
     dtype (ring callers pass fp32 so per-hop partials accumulate unrounded).
@@ -322,7 +325,7 @@ def _flash_bwd(q, k, v, delta, lse, g, scale, causal, block_q, block_k,
 
     interpret = default_interpret(interpret)
     b, h, s, d = q.shape
-    sk = k.shape[2]
+    sk, dv = k.shape[2], v.shape[3]
     # row stats enter lane-broadcast ([B,H,S] -> [B,H,S,LANE]) for the same
     # Mosaic block-alignment reason the forward emits lse that way
     lse = jnp.broadcast_to(lse[..., None], (*lse.shape, _LANE))
@@ -333,15 +336,17 @@ def _flash_bwd(q, k, v, delta, lse, g, scale, causal, block_q, block_k,
                              lambda b, h, x, y: (0, 0),
                              memory_space=pltpu.SMEM)
 
-    def spec(blk, pos):  # [*, *, blk, d] tensors indexed by grid dim `pos`
+    def spec(blk, width, pos):  # [*, *, blk, width], indexed by grid dim `pos`
         return pl.BlockSpec(
-            (1, 1, blk, d),
+            (1, 1, blk, width),
             (lambda b, h, x, y: (b, h, x, 0)) if pos == 2
             else (lambda b, h, x, y: (b, h, y, 0)),
         )
 
-    qspec = functools.partial(spec, block_q)
-    kspec = functools.partial(spec, block_k)
+    qspec = functools.partial(spec, block_q, d)    # q, dq
+    kspec = functools.partial(spec, block_k, d)    # k, dk
+    vspec = functools.partial(spec, block_k, dv)   # v, dv
+    gspec = functools.partial(spec, block_q, dv)   # the output's cotangent
 
     def rowspec(pos):  # lse/delta [B, H, S, LANE] lane-broadcast blocks
         return pl.BlockSpec(
@@ -363,12 +368,12 @@ def _flash_bwd(q, k, v, delta, lse, g, scale, causal, block_q, block_k,
             jax.ShapeDtypeStruct(v.shape, out_dtype or v.dtype),
         ),
         grid=(b, h, sk // block_k, s // block_q),
-        in_specs=[smem(), smem(), qspec(3), kspec(2), kspec(2), qspec(3),
+        in_specs=[smem(), smem(), qspec(3), kspec(2), vspec(2), gspec(3),
                   rowspec(3), rowspec(3)],
-        out_specs=(kspec(2), kspec(2)),
+        out_specs=(kspec(2), vspec(2)),
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32),
         ],
         compiler_params=compiler,
         interpret=interpret,
@@ -378,7 +383,7 @@ def _flash_bwd(q, k, v, delta, lse, g, scale, causal, block_q, block_k,
         functools.partial(_bwd_dq_kernel, **params),
         out_shape=jax.ShapeDtypeStruct(q.shape, out_dtype or q.dtype),
         grid=(b, h, s // block_q, sk // block_k),
-        in_specs=[smem(), smem(), qspec(2), kspec(3), kspec(3), qspec(2),
+        in_specs=[smem(), smem(), qspec(2), kspec(3), vspec(3), gspec(2),
                   rowspec(2), rowspec(2)],
         out_specs=qspec(2),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
@@ -464,28 +469,34 @@ def flash_attention(
     v: jnp.ndarray,
     *,
     causal: bool = True,
+    scale: float | None = None,
     block_q: int = 128,
     block_k: int = 128,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
-    """Flash attention over [B, S, H, D] inputs (the layout used by
-    models.transformer.SelfAttention and ops.attention.causal_attention,
-    which this matches numerically — tested).
+    """Flash attention over q, k [B, S, H, D] and v [B, S, H, Dv] (the
+    layout used by models.transformer.SelfAttention and
+    ops.attention.causal_attention, which this matches numerically —
+    tested) -> [B, S, H, Dv]. ``scale`` multiplies q.k before the softmax;
+    None is ``D ** -0.5``.
 
-    Pads S up to the block size and D up to the 128-lane tile (zero-padded
-    keys are masked inside the kernel; zero-padded value lanes produce
-    zero output lanes, sliced off).
+    Pads S up to the block size and D, Dv each up to the 128-lane tile
+    (zero-padded keys are masked inside the kernel; zero-padded q/k lanes
+    add nothing to a score; zero-padded value lanes produce zero output
+    lanes, sliced off).
     """
     b, s, h, d = q.shape
-    scale = 1.0 / float(d) ** 0.5
+    dv = v.shape[-1]
+    if scale is None:
+        scale = 1.0 / float(d) ** 0.5
     lcm = math.lcm(block_q, block_k)
     sp = _round_up(max(s, lcm), lcm)
-    dp = _round_up(d, _LANE)
+    dp, dvp = _round_up(d, _LANE), _round_up(dv, _LANE)
     out = _flash_core(
-        _to_bhsd(q, sp, dp), _to_bhsd(k, sp, dp), _to_bhsd(v, sp, dp),
-        scale, causal, block_q, block_k, interpret, s,
+        _to_bhsd(q, sp, dp), _to_bhsd(k, sp, dp), _to_bhsd(v, sp, dvp),
+        float(scale), causal, block_q, block_k, interpret, s,
     )
-    return _from_bhsd(out, s, d)
+    return _from_bhsd(out, s, dv)
 
 
 def flash_attention_lse(
@@ -568,15 +579,16 @@ def make_flash_bwd_lse(
 
 
 def flash_attention_fn(
-    *, causal: bool = True, block_q: int = 128, block_k: int = 128,
-    interpret: bool | None = None,
+    *, causal: bool = True, scale: float | None = None, block_q: int = 128,
+    block_k: int = 128, interpret: bool | None = None,
 ):
-    """An ``attention_fn`` drop-in for models.transformer.TransformerLM."""
+    """An ``attention_fn`` drop-in for models.transformer.TransformerLM and
+    for models.xing4's latent attention (which passes its own ``scale``)."""
 
     def fn(q, k, v):
         return flash_attention(
-            q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-            interpret=interpret,
+            q, k, v, causal=causal, scale=scale, block_q=block_q,
+            block_k=block_k, interpret=interpret,
         )
 
     return fn

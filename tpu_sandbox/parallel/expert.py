@@ -26,6 +26,9 @@ objective with ``aux_weight``.
 
 from __future__ import annotations
 
+import functools
+from typing import Any
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -102,3 +105,229 @@ class MoeMlp(nn.Module):
         out = jnp.einsum("ebcf,efd->ebcd", h, w_down)  # [E,B,C,D]
         y = jnp.einsum("bsec,ebcd->bsd", combine.astype(cfg.dtype), out)
         return y
+
+
+# --- a share of the experts, with device work that follows shapes alone ---
+
+def router_scores(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+    """Sigmoid router scores ``[T, E]`` in float32 at full matmul precision
+    (on a TPU a float32 product is otherwise rounded to bf16 passes)."""
+    return jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+
+
+def _pick(table: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """``table[idx]`` for a table of a few entries, as a one-hot sum: no
+    gather, so nothing whose cost could follow the indices."""
+    return (jax.nn.one_hot(idx, table.shape[0], dtype=table.dtype)
+            * table).sum(-1)
+
+
+@jax.custom_vjp
+def _spread(src, idx, mask, back_idx, back_mask):
+    """``out[m] = src[idx[m]] * mask[m]``: rows of ``src [N, C]`` into a
+    buffer of M rows. Every source row lands in at most k buffer rows, the
+    ones ``back_idx [N, k]`` names where ``back_mask`` is set, so the
+    transpose is ``_collect`` (a gather too), not a scatter-add."""
+    del back_idx, back_mask
+    return jnp.where(mask[:, None], src[idx], 0).astype(src.dtype)
+
+
+@jax.custom_vjp
+def _collect(buf, back_idx, back_mask, idx, mask):
+    """``out[n] = sum_j buf[back_idx[n, j]] * back_mask[n, j]``: the k
+    buffer rows of a source row, summed by reshape. Transpose: ``_spread``."""
+    del idx, mask
+    n, k = back_idx.shape
+    rows = buf[back_idx.reshape(n * k)].reshape(n, k, buf.shape[-1])
+    return jnp.where(back_mask[..., None], rows, 0).sum(1).astype(buf.dtype)
+
+
+def _spread_fwd(src, idx, mask, back_idx, back_mask):
+    return _spread(src, idx, mask, back_idx, back_mask), (
+        idx, mask, back_idx, back_mask)
+
+
+def _spread_bwd(res, g):
+    idx, mask, back_idx, back_mask = res
+    return _collect(g, back_idx, back_mask, idx, mask), None, None, None, None
+
+
+def _collect_fwd(buf, back_idx, back_mask, idx, mask):
+    return _collect(buf, back_idx, back_mask, idx, mask), (
+        idx, mask, back_idx, back_mask)
+
+
+def _collect_bwd(res, g):
+    idx, mask, back_idx, back_mask = res
+    return _spread(g, idx, mask, back_idx, back_mask), None, None, None, None
+
+
+_spread.defvjp(_spread_fwd, _spread_bwd)
+_collect.defvjp(_collect_fwd, _collect_bwd)
+
+
+def share_layout(sel: jnp.ndarray, n_experts: int, held: tuple[int, ...],
+                 local_rows: int, row_tile: int) -> dict:
+    """Where each held assignment of ``sel [T, k]`` (expert ids) sits in a
+    buffer of ``local_rows + len(held) * row_tile`` rows, and back.
+
+    Held assignments are ordered by expert, then by position (token, then
+    choice); the first ``local_rows`` of that order are kept and the tail is
+    dropped — whatever the per-expert imbalance, only the share's total can
+    drop a row. Each expert's kept rows start on a row tile and every expert
+    owns at least one tile, so a tile belongs to one expert; the extra
+    ``len(held)`` tiles are what that alignment can cost at worst. All of it
+    is sorts, comparisons and prefix sums over static shapes.
+    """
+    t, k = sel.shape
+    a, h = t * k, len(held)
+    local_of = [h] * n_experts
+    for i, e in enumerate(held):
+        local_of[e] = i
+    loc = _pick(jnp.asarray(local_of, jnp.int32), sel.reshape(a))  # h = absent
+    order = jnp.argsort(loc, stable=True).astype(jnp.int32)   # rank -> assignment
+    rank = jnp.argsort(order).astype(jnp.int32)               # assignment -> rank
+    n = (loc[:, None] == jnp.arange(h)[None, :]).sum(0).astype(jnp.int32)
+    start = jnp.cumsum(n) - n
+    kept_n = jnp.clip(local_rows - start, 0, n)
+    aligned = jnp.maximum(row_tile, -(-kept_n // row_tile) * row_tile)
+    a_start = jnp.cumsum(aligned) - aligned
+    p = local_rows + h * row_tile
+    tiles = p // row_tile
+    tile_start = jnp.arange(tiles, dtype=jnp.int32) * row_tile
+    tile_group = jnp.minimum(
+        (tile_start[:, None] >= (a_start + aligned)[None, :]).sum(1), h - 1
+    ).astype(jnp.int32)
+    # buffer row -> assignment
+    e_r = jnp.repeat(tile_group, row_tile)
+    off_r = jnp.arange(p, dtype=jnp.int32) - _pick(a_start, e_r)
+    valid = off_r < _pick(kept_n, e_r)
+    a_r = order[jnp.clip(_pick(start, e_r) + off_r, 0, a - 1)]
+    # assignment -> buffer row
+    e_a = jnp.minimum(loc, h - 1)
+    off_a = rank - _pick(start, e_a)
+    kept = (loc < h) & (off_a < _pick(kept_n, e_a))
+    # an assignment with no row points somewhere harmless, spread over the
+    # buffer so that the access pattern does not depend on how many there are
+    dest = jnp.where(kept, _pick(a_start, e_a) + off_a,
+                     jnp.arange(a, dtype=jnp.int32) % p)
+    return {"tile_group": tile_group, "row_assignment": a_r, "row_valid": valid,
+            "dest": dest.reshape(t, k), "kept": kept.reshape(t, k),
+            "rows_held": kept_n.sum(), "rows_dropped": (n - kept_n).sum(),
+            "expert_rows_max": n.max()}
+
+
+class ExpertShare(nn.Module):
+    """The part of a routed-expert layer that one chip of an expert-parallel
+    deployment computes: it routes over all ``n_routed_experts``, holds the
+    experts in ``held``, and returns what those give (plus the shared
+    expert, which every chip computes alike). Assignments to absent experts
+    are left out; nothing stands in for the other chips or their exchange.
+
+    Sigmoid scores in float32, top-k of ``score + bias`` (the bias,
+    ``e_score_correction_bias``, lives in ``batch_stats``: no gradient moves
+    it; each training step moves it by ``bias_update_rate`` towards the
+    experts that saw fewer tokens than the mean), weights the chosen scores
+    normalised to sum ``routed_scaling_factor``.
+
+    The device work is a function of the shapes: held assignments are
+    sorted into one buffer (``share_layout``), all of whose row tiles are
+    multiplied by ``ops.pallas_grouped_matmul`` whether they hold rows or
+    zeros; dispatch and combine are row gathers both ways. ``batch_stats``
+    also accumulates the counters ``rows_held``, ``rows_dropped``,
+    ``expert_rows_max`` and ``steps`` (int32: exact for 2**31 rows, which
+    float32 is not past 2**24).
+    """
+
+    d_model: int
+    d_ff: int
+    n_routed_experts: int
+    top_k: int
+    held: tuple[int, ...]
+    local_rows: int
+    n_shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
+    bias_update_rate: float = 1e-3
+    dtype: Any = jnp.bfloat16
+    row_tile: int = 256
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+        from tpu_sandbox.ops.pallas_grouped_matmul import grouped_matmul
+
+        lead, c = x.shape[:-1], x.shape[-1]
+        x = x.reshape(-1, c).astype(self.dtype)
+        t, k, e, h = x.shape[0], self.top_k, self.n_routed_experts, len(self.held)
+        if self.local_rows % self.row_tile:
+            raise ValueError(f"local_rows {self.local_rows} is not a multiple "
+                             f"of the row tile {self.row_tile}")
+        init = nn.initializers.lecun_normal()
+        bias = self.variable("batch_stats", "e_score_correction_bias",
+                             jnp.zeros, (e,), jnp.float32)
+        counters = {name: self.variable("batch_stats", name, jnp.zeros, (),
+                                        jnp.int32)
+                    for name in ("rows_held", "rows_dropped",
+                                 "expert_rows_max", "steps")}
+
+        with jax.named_scope("router"):
+            w_r = self.param("router", init, (c, e), jnp.float32)
+            scores = router_scores(x, w_r)                          # [T, E]
+            _, sel = jax.lax.top_k(
+                scores + jax.lax.stop_gradient(bias.value), k)      # [T, k]
+            chosen = jax.nn.one_hot(sel, e, dtype=jnp.float32)      # [T, k, E]
+            s_sel = (chosen * scores[:, None, :]).sum(-1)
+            weights = (s_sel / (s_sel.sum(-1, keepdims=True) + 1e-20)
+                       * self.routed_scaling_factor)
+            counts = chosen.sum((0, 1))                             # [E]
+
+        with jax.named_scope("dispatch"):
+            lay = share_layout(sel, e, tuple(self.held), self.local_rows,
+                               self.row_tile)
+            tok_r = lay["row_assignment"] // k
+            rows = _spread(x, tok_r, lay["row_valid"], lay["dest"], lay["kept"])
+            w_rows = _spread(
+                weights.reshape(t * k, 1), lay["row_assignment"],
+                lay["row_valid"], lay["dest"].reshape(t * k, 1),
+                lay["kept"].reshape(t * k, 1))                      # [P, 1]
+
+        with jax.named_scope("experts"):
+            shape = (h, c, self.d_ff)
+            w_gate = self.param("w_gate", init, shape, jnp.float32)
+            w_up = self.param("w_up", init, shape, jnp.float32)
+            w_down = self.param("w_down", init, (h, self.d_ff, c), jnp.float32)
+            group = lay["tile_group"]
+            gate = grouped_matmul(rows, w_gate.astype(self.dtype), group,
+                                  self.row_tile)
+            up = grouped_matmul(rows, w_up.astype(self.dtype), group,
+                                self.row_tile)
+            out = grouped_matmul((nn.silu(gate) * up).astype(self.dtype),
+                                 w_down.astype(self.dtype), group,
+                                 self.row_tile)
+
+        with jax.named_scope("combine"):
+            out = (out.astype(jnp.float32) * w_rows).astype(self.dtype)
+            y = _collect(out, lay["dest"], lay["kept"], tok_r,
+                         lay["row_valid"])
+
+        if self.n_shared_experts:
+            with jax.named_scope("shared"):
+                width = self.d_ff * self.n_shared_experts
+                dense = functools.partial(nn.Dense, use_bias=False,
+                                          dtype=self.dtype)
+                g = dense(width, name="shared_gate")(x)
+                u = dense(width, name="shared_up")(x)
+                y = y + dense(c, name="shared_down")(nn.silu(g) * u)
+
+        if not self.is_initializing() and self.is_mutable_collection(
+                "batch_stats"):
+            bias.value = bias.value + self.bias_update_rate * jnp.sign(
+                counts.mean() - counts)
+            counters["rows_held"].value += lay["rows_held"]
+            counters["rows_dropped"].value += lay["rows_dropped"]
+            counters["expert_rows_max"].value = jnp.maximum(
+                counters["expert_rows_max"].value, lay["expert_rows_max"])
+            counters["steps"].value += 1
+        self.sow("intermediates", "sel", sel)
+        return y.reshape(*lead, c)

@@ -188,6 +188,7 @@ class PjitEngine:
         image_size: tuple[int, int] | None = None,
         task: str = "image",
         aux_weight: float = 0.01,
+        mtp_weight: float = 0.0,
         zero_axis: str | None = None,
         fsdp_axis: str | None = None,
         donate: bool = True,
@@ -217,6 +218,10 @@ class PjitEngine:
         # one expert (VERDICT r01 weak #8). 0.01 is the Switch paper's alpha;
         # models that sow nothing are unaffected.
         self.aux_weight = aux_weight
+        # Weight on the cross-entropy of sown "mtp_logits" (a multi-token-
+        # prediction module's logits for the token after the next); the
+        # model's own constant, handed over by whoever builds both.
+        self.mtp_weight = mtp_weight
         if fsdp_axis is not None:
             if fsdp_axis not in mesh.axis_names:
                 raise ValueError(
@@ -317,21 +322,33 @@ class PjitEngine:
         model, tx, image_size = self.model, self.tx, self.image_size
 
         if self.task == "lm":
-            aux_weight = self.aux_weight
+            aux_weight, mtp_weight = self.aux_weight, self.mtp_weight
 
             def loss_fn(params, batch_stats, tokens, targets):
+                variables = {"params": params}
+                if batch_stats:  # state no gradient moves (a router's bias)
+                    variables["batch_stats"] = batch_stats
                 logits, sown = model.apply(
-                    {"params": params}, tokens, mutable=["aux_loss"]
+                    variables, tokens,
+                    mutable=["aux_loss", "mtp_logits", "batch_stats"]
                 )
-                with jax.named_scope("loss"):
-                    loss = cross_entropy_loss(
+
+                def ce(logits, targets):
+                    return cross_entropy_loss(
                         logits.reshape(-1, logits.shape[-1]),
-                        targets.reshape(-1)
-                    )
+                        targets.reshape(-1))
+
+                with jax.named_scope("loss"):
+                    loss = ce(logits, targets)
                     aux = jax.tree.leaves(sown.get("aux_loss", {}))
                     if aux:  # mean over layers: alpha independent of depth
                         loss = loss + aux_weight * sum(aux) / len(aux)
-                return loss, batch_stats
+                    # multi-token prediction: position i's extra logits are
+                    # held to the target of position i + 1
+                    for extra in jax.tree.leaves(sown.get("mtp_logits", {})):
+                        loss = loss + mtp_weight * ce(
+                            extra[:, :-1], targets[:, 1:])
+                return loss, sown.get("batch_stats", batch_stats)
 
         else:
 
