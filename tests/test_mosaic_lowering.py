@@ -76,6 +76,60 @@ def test_flash_lse_and_partial_bwd_lower_for_tpu():
     _lower_tpu(partial_bwd, q, k, v)
 
 
+CELL_SHAPES = [
+    # name, B, S, H, D (q.k), Dv, scale: the two LM cells' attention in bf16
+    ("gpt2m_s1024_64_64", 1, 1024, 2, 64, 64, None),
+    ("xing4_s4096_192_128", 1, 4096, 2, 192, 128, 192 ** -0.5 * 2.00474),
+]
+
+
+@pytest.mark.parametrize("name,b,s,h,d,dv,scale", CELL_SHAPES,
+                         ids=[c[0] for c in CELL_SHAPES])
+def test_flash_attention_at_the_cells_shapes_lowers_for_tpu(
+        name, b, s, h, d, dv, scale):
+    """Forward and backward at the tiles the rule picks for the cells'
+    own shapes (PR 28): far larger blocks than the 128 x 128 the cases
+    above were written for, index maps that read scalar prefetch, and a
+    ``vmem_limit_bytes``."""
+    from tpu_sandbox.ops.pallas_attention import _pad_len, choose_tiles
+
+    sp = _pad_len(s)
+    for kernel in ("fwd", "dkv", "dq"):
+        bq, bk = choose_tiles(kernel, sp, sp, -(-d // 128) * 128,
+                              -(-dv // 128) * 128, 2)
+        assert bq >= 512 and bk >= 512, (kernel, bq, bk)
+    q, k = (jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16),) * 2
+    v = jax.ShapeDtypeStruct((b, s, h, dv), jnp.bfloat16)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, scale=scale, interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    _lower_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
+
+
+@pytest.mark.parametrize("blocks", [{}, {"block_q": 128, "block_k": 128}],
+                         ids=["rule_tiles", "the_rings_128"])
+def test_flash_lse_and_partial_bwd_with_traced_offsets_lower_for_tpu(blocks):
+    """The ring's calls as the ring makes them: the offsets are traced
+    scalars (``idx * s_loc``), which reach the index maps' clamp as scalar
+    prefetch; forward-with-lse and the per-hop backward, at the ring's own
+    explicit blocks and at the rule's."""
+    q, k, v = (jax.ShapeDtypeStruct((1, 1024, 2, 64), jnp.bfloat16),) * 3
+
+    def hop(q, k, v, q_off, kv_off):
+        out, lse = flash_attention_lse(q, k, v, interpret=False,
+                                       q_offset=q_off, kv_offset=kv_off,
+                                       **blocks)
+        fn = make_flash_bwd_lse(q, out.astype(q.dtype), q, lse,
+                                q_offset=q_off, interpret=False, **blocks)
+        dq, dk, dv = fn(k, v, kv_off)
+        return out.sum() + lse.sum() + dq.sum() + dk.sum() + dv.sum()
+
+    off = jax.ShapeDtypeStruct((), jnp.int32)
+    _lower_tpu(hop, q, k, v, off, off)
+
+
 def test_pallas_ce_lowers_for_tpu():
     rng = np.random.default_rng(2)
     logits = jnp.asarray(rng.normal(size=(64, 32000)), jnp.float32)

@@ -69,3 +69,42 @@ def test_plain_attention_takes_a_scale():
     q, k, v, _ = qkv(16, 16, s=8)
     np.testing.assert_allclose(causal_attention(q, k, v),
                                causal_attention(q, k, v, scale=0.25), rtol=1e-6)
+
+
+# -- PR 28: the latent shape under the tile rule ------------------------------
+
+YARN_SCALE = 192 ** -0.5 * 2.00474
+
+
+@pytest.mark.parametrize("s", [1400, 1100], ids=["eleven_tiles", "nine_tiles"])
+def test_rule_tiles_at_the_latent_head_sizes(s):
+    """No blocks given, an S whose lane count leaves the rule small tiles
+    (11 x 128: only 128 divides; 9 x 128: 384 does), so that the default
+    path itself walks interior, diagonal and padded-key tiles at 192 / 128
+    with the model's own scale; forward and backward."""
+    from tpu_sandbox.ops import pallas_attention as pa
+
+    sp = pa._pad_len(s)
+    tiles = {k: pa.choose_tiles(k, sp, sp, 256, 128, 4) for k in pa._KERNELS}
+    assert all(1 < sp // bq and sp % bq == 0 and bq == bk
+               for bq, bk in tiles.values()), tiles
+    q, k, v, g = qkv(192, 128, s=s, h=1)
+    got = jax.vjp(lambda q, k, v: flash_attention(
+        q, k, v, scale=YARN_SCALE), q, k, v)
+    want = jax.vjp(lambda q, k, v: causal_attention(
+        q, k, v, scale=YARN_SCALE), q, k, v)
+    np.testing.assert_allclose(got[0], want[0], rtol=2e-5, atol=2e-5)
+    for a, b in zip(got[1](g), want[1](g)):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "dkv", "dq"])
+def test_the_cell_shape_is_a_few_large_steps(kernel):
+    """At the Xing4 cell's attention (2 x 32 heads, S 4096, 192 -> 256 /
+    128, bf16) the rule's grid is at most a sixteenth of the 65,536 steps
+    that 128 x 128 tiles made, and the tiles fit the budget with room."""
+    from tpu_sandbox.ops import pallas_attention as pa
+
+    bq, bk = pa.choose_tiles(kernel, 4096, 4096, 256, 128, 2)
+    assert 2 * 32 * (4096 // bq) * (4096 // bk) <= 65536 // 16
+    assert pa._vmem_bytes(kernel, bq, bk, 256, 128, 2) <= pa._VMEM_BUDGET
