@@ -50,7 +50,7 @@ KERNEL_SCOPES = {
 
 #: First-step loss band around ln(10): ten classes, lecun-normal head over
 #: ~18M post-BN features gives logits of unit scale, so a 5-image mean CE
-#: sits within a factor of two of ln 10 (bench.annotate_loss's gate). Later
+#: sits within a factor of two of ln 10. Later
 #: losses need only be finite: the reference recipe (SGD 1e-4 on this head)
 #: diverges at this size in torch too (BASELINE.md "Loss dynamics at 3000^2").
 FIRST_LOSS_BAND = (0.5 * math.log(10), 2.0 * math.log(10))

@@ -6,8 +6,7 @@ trace, a shed_storm and a gateway kill mid-load — then the full audit
 (zero lost, exactly-one verdict per rid, alert claims, byte-identical
 audit across two same-seed runs). The full fault matrix (every action
 family, multiple seeds, prefix probes) lives slow-marked in
-test_chaos_integration.py; the real-process version is
-``bench.py --metric chaos``.
+test_chaos_integration.py.
 """
 
 import contextlib

@@ -5,8 +5,7 @@ family the in-process harness can execute (gateway kill, replica shed
 storm, replica stall), against a 3-gateway / 3-replica stub fleet. Each
 must end with zero lost requests and a clean claim audit, and after the
 wreckage a prefix probe checks failover didn't degrade the door to
-blind load balancing. The real-process twin with TLS on the wire is
-``bench.py --metric chaos``.
+blind load balancing.
 """
 
 import contextlib
@@ -411,33 +410,3 @@ def test_agent_campaign_kill_and_partition_zero_loss(tmp_path, seed):
         # leader charged the gang bounce to the restart budget
         assert launcher.respawns >= 1
         assert restarts >= 1
-
-
-def test_bench_chaos_cli_prints_one_json_line():
-    """`bench.py --metric chaos --quick` end to end in a fresh
-    interpreter: real gateway processes over TLS, a real SIGKILL, the
-    claim audit and the tracediff gate. Quick mode is too small for the
-    latency numbers to mean anything, so only the invariants are
-    asserted; BENCH_r13.json holds a committed full run."""
-    import json
-    import os
-    import subprocess
-    import sys
-    from pathlib import Path
-
-    repo = Path(__file__).resolve().parents[1]
-    proc = subprocess.run(
-        [sys.executable, str(repo / "bench.py"),
-         "--metric", "chaos", "--quick"],
-        capture_output=True, text=True, timeout=540, cwd=str(repo),
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
-    )
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    out = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert out["metric"] == "chaos"
-    assert out["all_campaigns_green"] is True
-    assert out["sigkill_zero_loss"] is True
-    assert out["audit_replay_identical"] is True
-    assert out["tls_plaintext_refused"] is True
-    assert out["tracediff_gate_ok"] is True
-    assert out["sigkill_campaign"]["failovers"] >= 1

@@ -1,7 +1,7 @@
 """ConvNetS2D == ConvNet: the space-to-depth plan is the same function.
 
 The s2d model exists purely as an execution plan (models/convnet_s2d.py);
-these tests pin the contract that lets bench.py and the entry scripts swap
+these tests pin the contract that lets the entry scripts swap
 it in for the reference-parity ConvNet: identical parameter tree, identical
 forward, identical gradients, identical batch-stats evolution.
 """

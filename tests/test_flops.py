@@ -1,6 +1,5 @@
-"""FLOP model / MFU accounting tests (utils/flops.py) — the bench's
-plausibility cross-check must itself be correct, since it gates what
-numbers get published (BASELINE.md 'the r01 anomaly, explained')."""
+"""FLOP model / MFU accounting tests (utils/flops.py): the plausibility
+cross-check must itself be correct."""
 
 import pytest
 
@@ -32,7 +31,7 @@ def test_convnet_flops_at_3000_matches_verdict_analysis():
 
 
 def test_convnet_flops_agrees_with_xla_cost_analysis():
-    """The independent cross-check bench.py runs in production: XLA's own
+    """The independent cross-check: XLA's own
     HLO FLOP count for one train step vs the analytic model (XLA also
     counts the resize/BN arithmetic, so it sits slightly above)."""
     import jax

@@ -10,8 +10,8 @@ with in-process stub-step replicas. This file closes the remaining gaps:
 - the gateway's own process entrypoint (``python -m
   tpu_sandbox.gateway.server``), hello auth over the printed port, and a
   clean SIGTERM shutdown;
-- the full ``bench.py --metric gateway --quick`` CLI in a fresh
-  interpreter (the tier-1 smoke calls bench_gateway in-process).
+- two real gateway processes behind TLS, the connected one SIGKILLed
+  mid-load: the client fails over and no request is lost.
 
 Real subprocesses + cold jax compiles: slow-marked, out of tier-1.
 """
@@ -174,23 +174,64 @@ def test_gateway_process_entrypoint_serves_and_shuts_down():
         server.stop()
 
 
-def test_bench_gateway_cli_prints_one_json_line():
-    """`bench.py --metric gateway --quick` end to end in a fresh
-    interpreter. Quick mode is too small for the perf claims to be
-    meaningful, so only their presence and the accounting invariants are
-    asserted; BENCH_r08.json holds a committed full run."""
-    proc = subprocess.run(
-        [sys.executable, str(REPO / "bench.py"),
-         "--metric", "gateway", "--quick"],
-        capture_output=True, text=True, timeout=300, cwd=str(REPO),
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
-    )
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    out = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert out["metric"] == "gateway"
-    assert out["every_request_verdicted"] is True
-    assert "prefix_beats_random_p99" in out
-    assert "feasible_goodput_holds" in out
-    for arm in ("routing_prefix", "routing_random",
-                "admission_feasible", "admission_occupancy"):
-        assert out[arm]["verdict_audit_ok"] is True
+def test_gateway_process_sigkilled_mid_load_over_tls_loses_nothing():
+    """Two real gateway processes behind TLS, stub-step replicas in this
+    process, and the gateway the client is connected to SIGKILLed
+    half-way through the submits: the client fails over to the survivor
+    and every request still ends in exactly one ``ok`` verdict."""
+    from tests.test_gateway import TLSDIR, _pumping, _wait_for_report, _worker
+    from tpu_sandbox.gateway.client import GatewayClient
+    from tpu_sandbox.gateway.wire import make_client_ssl_context
+    from tpu_sandbox.runtime.kvstore import KVClient, KVServer
+    from tpu_sandbox.serve.replica import k_done
+
+    server = KVServer()
+    kv = KVClient(port=server.port)
+    clones = [kv.clone(), kv.clone()]
+    workers = [_worker(c, tag=f"w{i}") for i, c in enumerate(clones)]
+    procs, endpoints = {}, []
+    try:
+        for gid in ("gw0", "gw1"):
+            proc = subprocess.Popen(
+                [sys.executable, "-m", "tpu_sandbox.gateway",
+                 "--kv-port", str(server.port), "--gateway-id", gid,
+                 "--token", "sesame", "--admission", "none",
+                 "--tls-cert", os.path.join(TLSDIR, "server.pem"),
+                 "--tls-key", os.path.join(TLSDIR, "server.key")],
+                env=_replica_env(server.port), cwd=str(REPO),
+                stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
+            line = proc.stdout.readline()
+            assert "listening on" in line and "tls=on" in line, line
+            port = int(line.split("listening on ")[1]
+                       .split()[0].rsplit(":", 1)[1])
+            procs[port] = proc
+            endpoints.append(("127.0.0.1", port))
+        with _pumping(*workers):
+            for w in workers:
+                _wait_for_report(kv, w.tag)
+            with GatewayClient(
+                    endpoints=endpoints, token="sesame", backoff_base=0.02,
+                    tls=make_client_ssl_context(
+                        os.path.join(TLSDIR, "ca.pem"))) as client:
+                rids = [f"k{i}" for i in range(24)]
+                for i, rid in enumerate(rids):
+                    if i == len(rids) // 2:
+                        procs[client.endpoint[1]].send_signal(signal.SIGKILL)
+                    assert client.submit(rid, [1 + i % 5, 2, 3], 3) is True
+                verdicts = {rid: client.result(rid, timeout=60.0)
+                            for rid in rids}
+                assert client.stats.failovers >= 1
+        assert all(v["verdict"] == "ok" for v in verdicts.values())
+        assert all(kv.try_get(k_done(rid)) == b"1" for rid in rids)
+    finally:
+        for proc in procs.values():
+            if proc.poll() is None:
+                proc.kill()
+            proc.wait(timeout=10)
+            proc.stdout.close()
+        for w in workers:
+            w.engine.drain_to_requests()
+        for c in clones:
+            c.close()
+        kv.close()
+        server.stop()

@@ -52,6 +52,8 @@ from tpu_sandbox.mpmd.transport import (  # noqa: E402
 )
 from tpu_sandbox.runtime.staging import stream_load_npz  # noqa: E402
 
+from tests.test_obs import traced  # noqa: E402, F401 (fixture)
+
 CFG = TransformerConfig(vocab_size=64, d_model=32, n_heads=2, n_layers=4,
                         d_ff=64, max_len=128)
 
@@ -360,6 +362,33 @@ def test_zb_h1_grad_parity_vs_fused_backward():
                          candidates=(2, 4, 8))
     assert plan["kind"] in ("1f1b", "zb_h1")
     assert plan["microbatches"] in (2, 4, 8)
+
+
+def test_online_bubble_gauge_agrees_with_the_trace(traced):
+    """A stage worker reports its bubble twice: as the per-step figure
+    behind the ``mpmd.bubble_fraction`` gauge, and as ``stage:op`` /
+    ``stage:step`` spans that ``critpath.bubble_fractions`` reduces
+    offline. Both describe the same run, so they agree; steady state
+    only (step 0 compiles), per-stage medians so that one descheduled
+    thread between the two clock reads cannot decide it."""
+    import statistics
+
+    from tpu_sandbox.obs import collect, critpath, get_recorder
+
+    pipe, _ = _train("1f1b", LocalTransport(), [2, 1, 1], steps=4)
+    get_recorder().flush()
+    offline: dict[int, list[float]] = {}
+    for row in critpath.bubble_fractions(
+            collect.load_merged(traced))["per_step"]:
+        if row["step"] >= 1:
+            offline.setdefault(row["stage"], []).append(row["bubble"])
+    assert sorted(offline) == [0, 1, 2]
+    for stage, worker in enumerate(pipe.workers):
+        online = statistics.median(
+            b for step, b in worker.bubble_by_step.items() if step >= 1)
+        assert 0.0 <= online < 1.0
+        assert statistics.median(offline[stage]) == pytest.approx(
+            online, abs=0.05)
 
 
 @pytest.mark.slow

@@ -405,7 +405,7 @@ def test_priority_preemption_checkpoints_and_resumes(agent_script):
         assert jobs["low"]["seq"] == low_seq
         ev_low = job_events(sched.kv, "low")
         ev_high = job_events(sched.kv, "high")
-        # the bench.py receipts, in causal order on the scheduler's clock
+        # the event stamps, in causal order on the scheduler's clock
         assert ev_low["admitted"] <= ev_low["preempt_sent"] \
             <= ev_low["preempted"] <= ev_low["readmitted"]
         assert ev_high["admitted"] >= ev_low["preempt_sent"]

@@ -1,8 +1,8 @@
 """Serving stack, fast: paged KV allocator units, prefix sharing, the
 bitwise decode-vs-forward parity contract, engine-vs-reference greedy
 outputs (continuous AND static, including under preemption pressure),
-the in-process replica protocol (drain/requeue, cross-worker completion,
-lease-expiry scavenge), and the chipless `bench.py --metric serve` smoke.
+and the in-process replica protocol (drain/requeue, cross-worker
+completion, lease-expiry scavenge).
 
 The parity reference is the one-shot ``TransformerLM`` forward evaluated
 at the cache's ``max_context`` padding — the same k-axis length the
@@ -437,23 +437,3 @@ def test_sampled_decode_interrupted_mid_decode_replays_bitwise(params, step):
     finally:
         kv.close()
         server.stop()
-
-
-# -- bench smoke ------------------------------------------------------------
-
-
-def test_bench_serve_quick_smoke():
-    """`bench_serve(quick=True)` is chipless and must report the SLO
-    fields and reference-identical outputs across the two scheduling
-    policies. In-process on purpose: a subprocess pays ~2s of fresh jax
-    startup for no extra coverage (the CLI path is exercised in the slow
-    test_serve_integration.py)."""
-    from bench import bench_serve
-
-    out = bench_serve(quick=True)
-    assert out["metric"] == "serve"
-    assert out["outputs_match"] is True
-    for side in ("continuous", "static"):
-        for field in ("tokens_per_sec", "p50_ttft_ms", "p99_ttft_ms",
-                      "p50_itl_ms", "p99_itl_ms"):
-            assert out[side][field] >= 0, (side, field)

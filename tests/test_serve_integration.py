@@ -347,47 +347,6 @@ def test_overload_plus_agent_kill_yields_exactly_one_verdict_each(tmp_path):
         server.stop()
 
 
-def test_bench_serve_cli_prints_one_json_line():
-    """The `bench.py --metric serve --quick` CLI path end to end in a
-    fresh interpreter (the tier-1 smoke calls bench_serve in-process)."""
-    import subprocess
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    proc = subprocess.run(
-        [sys.executable, os.path.join(root, "bench.py"),
-         "--metric", "serve", "--quick"],
-        capture_output=True, text=True, timeout=300, cwd=root,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
-    )
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    out = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert out["metric"] == "serve"
-    assert out["outputs_match"] is True
-
-
-def test_bench_serve_slo_cli_prints_one_json_line():
-    """`bench.py --metric serve_slo --quick` end to end: the calibrated
-    overload comparison runs and reports its guardrail claims. Quick mode
-    is too small for the claims to be meaningful, so only their presence
-    and the accounting invariant are asserted here; BENCH_r06.json holds
-    a committed full run."""
-    import subprocess
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    proc = subprocess.run(
-        [sys.executable, os.path.join(root, "bench.py"),
-         "--metric", "serve_slo", "--quick"],
-        capture_output=True, text=True, timeout=300, cwd=root,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
-    )
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    out = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert out["metric"] == "serve_slo"
-    assert out["every_request_verdicted"] is True
-    g = out["guarded_overload"]
-    assert g["completed"] + g["shed"] == out["requests"]
-
-
 if __name__ == "__main__":
     if "--serve-agent" in sys.argv:
         argv = [a for a in sys.argv[1:] if a != "--serve-agent"]

@@ -3,9 +3,8 @@
 Everything here runs the real engine/replica/client/autoscaler code paths
 with a *stub* decode step (next token = last token + 1 mod vocab) — no jax
 compiles, so the whole file stays inside the tier-1 budget, the pattern
-test_scheduler.py uses for the cluster layer. The real-model SLO paths are
-covered by the slow-marked overload bench (bench.py --metric serve_slo)
-and the chaos matrix in test_serve_slo_integration.py.
+test_scheduler.py uses for the cluster layer. The real-model paths under
+faults are the slow-marked test_serve_integration.py.
 """
 
 import json
@@ -140,6 +139,31 @@ def test_no_result_ever_lands_past_deadline():
     eng._pick_token = slow_pick
     eng.step()
     assert eng.shed["rf"].reason == "deadline" and "rf" not in eng.results
+
+
+def test_overload_gives_every_request_exactly_one_verdict():
+    """A seeded burst at twice what the engine drains, through a bounded
+    queue with per-request deadlines: whatever is shed (at the queue, on
+    its deadline while waiting, mid-flight) or completed, each request
+    ends as a result or a SHED verdict, never both, never neither."""
+    rng = np.random.default_rng(6)
+    clock = _Clock()
+    eng = _engine(clock, max_waiting=4)
+    n = 48
+    for i in range(n):
+        eng.submit(_req(f"r{i}", n=int(rng.integers(2, 9)),
+                        arrival=clock.t, deadline=clock.t + 6.0))
+        if i % 2:  # two arrivals a step: twice the service rate
+            clock.advance(1.0)
+            eng.step()
+    while not eng.idle:
+        clock.advance(1.0)
+        eng.step()
+    assert set(eng.results) | set(eng.shed) == {f"r{i}" for i in range(n)}
+    assert not set(eng.results) & set(eng.shed)  # completed + shed == n
+    # the overload was real, and both guardrails took part
+    assert {v.reason for v in eng.shed.values()} == {"queue_full", "deadline"}
+    assert eng.results
 
 
 def test_load_report_signals():

@@ -18,7 +18,7 @@ Usage:
   python tools/aot_v5e.py --capacity --plan s2d           # bisect max batch
 
 Numbers printed here are COMPILER estimates, labeled as such; measured
-truth comes only from a run on the chip (chip_smoke.py, bench.py).
+truth comes only from a run on the chip (chip_smoke.py, benchmark/run.py).
 """
 
 from __future__ import annotations

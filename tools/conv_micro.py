@@ -5,8 +5,8 @@ Round-3 motive: the first on-chip run of the fused-conv plan measured
 compute floor (BASELINE.md "The 10x target, argued") — the Pallas convs
 are executing near ~21 TF/s where the shape analysis predicted ~110.
 This tool separates WHICH kernel (conv1/conv2 x fwd/bwd, Pallas vs the
-XLA lax.conv it replaced) eats the step, with the same fetch-synced
-differential timing as bench.py, so the optimization targets the
+XLA lax.conv it replaced) eats the step, with the fetch-synced
+differential timing of utils/profiling.py, so the optimization targets the
 measured hot spot instead of the estimate.
 
 Usage (chip): python tools/conv_micro.py [--batch 16] [--ops conv1_fwd,...]
@@ -123,8 +123,8 @@ def main() -> None:
             "spread_frac": t.get("spread_frac"),
         }
         if spc <= 0:
-            # same rule as bench.py: a non-positive differential is timing
-            # jitter, not a measurement — never rank kernels by this row
+            # a non-positive differential is timing jitter, not a
+            # measurement — never rank kernels by this row
             rec["degraded"] = "non-positive differential; noise, not a time"
         print(json.dumps(rec), flush=True)
 

@@ -4,8 +4,8 @@ Diagnoses WHERE the headline step time goes on the real chip (the r02
 question: honest timing said ~0.41 s/step = 1.2% MFU, ~20x above the
 bandwidth floor). Each stage runs as its own jitted fori_loop whose
 iterations are data-chained through a scalar tap (`x0 + tap*eps`), so XLA
-can neither hoist nor CSE the op, and timing is the same fetch-synced
-differential as bench.py (utils/profiling.py::measure_per_step).
+can neither hoist nor CSE the op, and timing is the fetch-synced
+differential (utils/profiling.py::measure_per_step).
 
 Known suspect (from the compiler's allocator dump): activations shaped
 [B, 3000, 3000, 16] are tiled T(8,128) with C=16 in the 128-lane minor dim

@@ -24,7 +24,7 @@ Chipless: the driver builds a REAL multi-chip v5e topology
 to schedule) via jax.experimental.topologies, AOT-compiles the bucketed
 DataParallel step, and analyzes the result. Single-process like the other
 AOT tools: do not run two at once. Estimates of schedule structure, not
-measured step time; the bench owns measured truth.
+measured step time; the benchmark (benchmark/run.py) owns measured truth.
 
 Usage:
   python tools/hlo_schedule.py                       # compile + analyze
@@ -54,7 +54,7 @@ from hlo_traffic import (  # noqa: E402
 
 # aot_v5e (and with it libtpu topologies) stays lazy in the driver below:
 # schedule_report() must be importable on CPU-only boxes — the tier-1
-# fixture test and bench.py run it against text.
+# fixture test runs it against text.
 
 #: Opcodes that count as "compute a collective can hide under". Fusions
 #: cover the elementwise/reduce bulk XLA packs around the dots; dots and

@@ -21,7 +21,8 @@ directly here.
 
 Chipless (uses the local libtpu via jax.experimental.topologies, like
 tools/aot_v5e.py — single-process: do not run two AOT tools at once).
-Estimates, not measurements; the bench owns measured truth.
+Estimates, not measurements; the benchmark (benchmark/run.py) owns measured
+truth.
 
 Usage: python tools/hlo_traffic.py [--plan s2d] [--batch 16] [--top 25]
        python tools/hlo_traffic.py --head --batch 5   (the fc head alone)
@@ -44,8 +45,8 @@ sys.path.insert(0, os.path.dirname(_HERE))  # import tpu_sandbox from the repo
 
 # aot_v5e (and with it libtpu topologies) is imported lazily in main():
 # the pure-text analyzers below (shape_bytes / collective_bytes) must be
-# importable on CPU-only boxes — bench.py's grad-compress traffic metric
-# runs them against a CPU SPMD compile.
+# importable on CPU-only boxes — tests/test_grad_compress.py runs them
+# against a CPU SPMD compile.
 
 _SHAPE = re.compile(r"(\w+)\[([\d,]*)\](?:\{([^}]*)\})?")
 _BITS = {

@@ -3,11 +3,11 @@
 
 Compares two runs segment-by-segment (gateway route, queue wait,
 prefill, decode, publish, …) and exits nonzero when a segment regressed
-significantly — the perf gate a bench or CI job puts after its workload.
+significantly — the perf gate a CI job puts after its workload.
 
     python tools/tracediff.py BASELINE CANDIDATE
         Each argument is either a critpath profile JSON (written by
-        ``tracecat --critpath FILE`` or the bench archive hook) or a raw
+        ``tracecat --critpath FILE``) or a raw
         trace directory, which is analyzed on the fly.
 
     python tools/tracediff.py A B --threshold 0.10 --min-ms 0.5

@@ -154,7 +154,7 @@ def append_event(kv, event: dict) -> int:
 
 
 def deploy_events(kv) -> list[dict]:
-    """Every deployment decision, in order — the bench/test timeline."""
+    """Every deployment decision, in order — the timeline tests read."""
     out = []
     for n in range(int(kv.try_get(K_EVENT_TAIL) or b"0")):
         raw = kv.try_get(k_event(n))

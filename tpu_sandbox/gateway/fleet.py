@@ -65,7 +65,7 @@ class FleetSpec:
     #: entry can ever match
     block_size: int = 8
     #: calibrated per-replica service rate (requests/s) feeding the
-    #: feasibility estimate; measure with a closed-loop run (bench does)
+    #: feasibility estimate; measure with a closed-loop run
     service_rate_rps: float = 10.0
     #: occupancy-mode door bound (requests known queued on the replica)
     occupancy_bound: int = 8

@@ -191,8 +191,8 @@ class Gateway:
         self._requested_port = port
         self._token = token
         self.admission = admission
-        # 'prefix' is the product; 'random' is the control arm the bench
-        # measures the TTFT win against (uniform over fresh views)
+        # 'prefix' is the product; 'random' is its control arm (uniform
+        # over fresh views)
         self.policy = policy
         self._rng = random.Random(policy_seed)
         self.max_report_age_s = max_report_age_s

@@ -3,19 +3,6 @@ from tpu_sandbox.models.convnet_s2d import ConvNetS2D  # noqa: F401
 from tpu_sandbox.models.convnet_s2d_t import ConvNetS2DT  # noqa: F401
 
 
-def resolves_to_s2d(image_size, plan: str = "auto") -> bool:
-    """Single home for the auto-plan rule: does this (image_size, plan)
-    request run a space-to-depth execution plan (NHWC or transposed)?
-    Callers that label or annotate results by plan (bench sweep's kernel
-    race, the degraded line's AOT estimate block) must use this rather
-    than re-deriving the rule."""
-    h, w = (image_size, image_size) if isinstance(image_size, int) else image_size
-    return plan != "plain" and (
-        plan in ("s2d", "s2dt") or (plan == "auto" and h % 4 == 0
-                                    and w % 4 == 0)
-    )
-
-
 def resolve_plan(image_size, plan: str = "auto") -> str:
     """Concrete plan for a request: 's2dt' | 's2d' | 'plain'.
 
@@ -24,10 +11,11 @@ def resolve_plan(image_size, plan: str = "auto") -> str:
     COMPILE (TPU, or chipless AOT via TPU_SANDBOX_FORCE_COMPILED_KERNELS),
     the NHWC s2d plan where they would run interpreted (CPU tests), and
     the plain ConvNet when the image is not 4-divisible."""
-    if not resolves_to_s2d(image_size, plan):
-        return "plain"
     if plan in ("s2d", "s2dt"):
         return plan
+    h, w = (image_size, image_size) if isinstance(image_size, int) else image_size
+    if plan != "auto" or h % 4 or w % 4:
+        return "plain"
     from tpu_sandbox.ops.pallas_common import default_interpret
 
     return "s2dt" if not default_interpret(None) else "s2d"
@@ -53,9 +41,9 @@ def pick_convnet(image_size, *, plan: str = "auto", **kwargs):
 
     def drop_s2dt_only(kw):
         # s2dt-only toggles (sparse_conv1, fused_conv1_bwd) are
-        # meaningless — and unknown — to the other plans; drop them so a
-        # plan-ladder rung like dict(fused_conv1_bwd=False) still works
-        # when 'auto' resolves elsewhere (e.g. s2d on CPU)
+        # meaningless — and unknown — to the other plans; drop them so
+        # the same kwargs still work when 'auto' resolves elsewhere
+        # (e.g. s2d on CPU)
         return {k: v for k, v in kw.items()
                 if k not in ("sparse_conv1", "fused_conv1_bwd")}
 

@@ -7,7 +7,7 @@ batch-stats updates agree to float tolerance (tests/test_convnet_s2d_t.py)
 — and the parameter/batch_stats tree is bit-compatible with both, so
 checkpoints, TrainState, and every engine accept any of the three.
 
-Why a third plan: on-chip micro-benchmarks (measured/conv_micro_r03.jsonl)
+Why a third plan: round 3's on-chip micro-benchmarks (tools/conv_micro.py)
 showed the NHWC s2d Pallas convs running at 19-27 TF/s — below the XLA
 convs they replaced — because with channels on the 128-lane minor dim the
 [W, 9C] im2col tile build wastes 7/8 of every VPU op at C=16 and the
@@ -16,7 +16,7 @@ and W on lanes (ops/pallas_conv_t.py) made the tile build tile-aligned
 sublane stacking: conv1 fwd 24.6 -> 15.3 ms, conv1 fwd+BN-stats
 29.1 -> 15.3 ms (the stats fusion became free), conv2 bwd
 57.6 -> 27.3-41.1 ms at bs=16 (the range spans the two recorded r03
-sweeps — 25-50% run-to-run spread, see conv_micro_r03_t.jsonl), with the
+sweeps — 25-50% run-to-run spread), with the
 fused tail pair (ops/pallas_bn_tail_t.py) keeping the BN/ReLU/pool chain
 at one HBM pass per direction.
 
@@ -122,7 +122,7 @@ class _ConvT(nn.Module):
             # unchanged) and calls the composite itself
             return kernel.astype(self.dtype), bias.astype(self.dtype)
         # env var read at TRACE time: set it before the process first
-        # traces the step (each bench/test invocation is its own process
+        # traces the step (each run is its own process
         # under the one-chip-process discipline); flipping it after a
         # jitted step compiled is a no-op — the jit cache key ignores
         # env. In-process A/B goes through the `sparse` field instead
@@ -295,7 +295,7 @@ class ConvNetS2DT(nn.Module):
         The full-size [N, H, W] image never materializes — in the r03
         step that intermediate cost two whole-image relayout copies
         (~55 ms/step at bs=16, the largest single residue in the 199 ms
-        step; measured/hlo_cycles_s2dt_b16_r04.json). Feed the result to
+        step by round 4's AOT estimate). Feed the result to
         ``__call__``, which detects the pre-s2d shape."""
         H, W = image_size
         assert H % 4 == 0 and W % 4 == 0, (H, W)
@@ -306,7 +306,7 @@ class ConvNetS2DT(nn.Module):
         x = images.astype(jnp.float32)
         u = jnp.einsum("nij,wbj->nibw", x, aw4)          # [N, h0, 4, W/4]
         # The 5D->4D (a,b)->16 merge costs one whole-tensor retiling
-        # copy (~6 ms est at bs=16, copy.67 in measured/hlo_cycles; real
+        # copy (~6 ms est at bs=16, copy.67 in round 4's AOT estimate; real
         # bytes ~0.6 GB). A per-a-slice + channel-concat variant was
         # AOT-raced in r05 and came out est-neutral (47.8 vs 48.0 ms:
         # the concat just splits the same relayout into four slice

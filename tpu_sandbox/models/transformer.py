@@ -49,8 +49,8 @@ class TransformerConfig:
     # remat policy: "full" recomputes everything (max memory savings);
     # "dots" = jax.checkpoint_policies.checkpoint_dots — matmul outputs are
     # SAVED and only cheap elementwise work is recomputed, so the backward
-    # pays no extra MXU FLOPs (~25% step-time win at the bench config for a
-    # modest memory give-back). Ignored when remat=False.
+    # pays no extra MXU FLOPs, for a modest memory give-back. Ignored when
+    # remat=False.
     remat_policy: str = "full"
     # emit logits in fp32 (the safe default for any consumer). False
     # skips the cast and returns compute-dtype logits — at b16/s2048/

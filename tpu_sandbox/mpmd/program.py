@@ -1,9 +1,9 @@
 """Per-stage MPMD programs — separately compiled, bitwise-matching the
 SPMD pipeline.
 
-Each stage compiles ONLY its own program (the AOT receipt in
-tools/aot_mpmd.py shows stage 0's executable carries the embedding table
-and no head, the last stage's the reverse): forward for its layer slice,
+Each stage compiles ONLY its own program (stage 0's executable carries
+the embedding table and no head, the last stage's the reverse): forward
+for its layer slice,
 a vjp-based backward fed by the downstream stage's shipped cotangent, and
 a stage-local optimizer apply. The math is lifted from
 ``parallel/pipeline.py`` (same ``Block.apply`` scan, same fp32 layernorm,
@@ -310,38 +310,3 @@ class StageProgram:
 
     def init_opt_state(self, params):
         return self.place(self.tx.init(params))
-
-    def lower_train_programs(self, params, sample_x, sample_targets=None,
-                             *, zb: bool = False):
-        """AOT-lower this stage's programs (fwd and, where they exist,
-        bwd/loss_grad) without executing — the hook aot_mpmd.py and the
-        graftlint HLO pass share. With ``zb`` the split ZB-H1 backward
-        pair (grad-input / grad-weight) is lowered alongside, so the AOT
-        receipt shows what each half's executable actually carries."""
-        out = {}
-        if self.is_last:
-            out["loss_grad"] = self.loss_grad.lower(
-                params, sample_x, sample_targets)
-            if zb:
-                out["loss_bwd_input"] = self.loss_bwd_input.lower(
-                    params, sample_x, sample_targets)
-                _, _, stash = jax.eval_shape(
-                    self.loss_bwd_input, params, sample_x, sample_targets)
-                out["loss_bwd_weight"] = self.loss_bwd_weight.lower(
-                    params, sample_targets, stash)
-        else:
-            out["fwd"] = self.fwd.lower(params, sample_x)
-            g = jax.eval_shape(self.fwd, params, sample_x)
-            out["bwd"] = self.bwd.lower(params, sample_x, g)
-            if zb:
-                if self.is_first:
-                    out["bwd_weight"] = self.bwd_weight_chain.lower(
-                        params, sample_x, g)
-                else:
-                    out["bwd_input"] = self.bwd_input.lower(
-                        params, sample_x, g)
-                    _, stash = jax.eval_shape(
-                        self.bwd_input, params, sample_x, g)
-                    out["bwd_weight"] = self.bwd_weight.lower(
-                        params, stash)
-        return out

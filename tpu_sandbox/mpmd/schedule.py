@@ -207,8 +207,8 @@ def autotune_plan(op_costs: dict[int, dict[str, float]], *, n_stages: int,
     (e.g. the driver's recorded ``stage:op`` medians); candidate M
     rescales them by ``measured_microbatches / M`` — per-op work is
     linear in microbatch size at fixed global batch. Returns the winning
-    plan plus every candidate's prediction, so the bench receipt shows
-    the whole frontier, not just the argmin."""
+    plan plus every candidate's prediction: the whole frontier, not just
+    the argmin."""
     if not candidates:
         raise ValueError("no microbatch candidates")
     rows = []

@@ -147,7 +147,7 @@ def unpack_arrays(meta: dict, payload) -> list[np.ndarray]:
 
 @dataclass
 class TransportStats:
-    """Wire accounting for the bench receipt. Latencies are whole-op wall
+    """Wire accounting. Latencies are whole-op wall
     times (staging + chunk puts / blocking wait + reassembly)."""
 
     puts: int = 0

@@ -32,8 +32,8 @@ MPMD runs get the same treatment at stage granularity:
 :func:`bubble_fractions` derives per-stage, per-step pipeline bubble
 from the ``stage:op`` / ``stage:step`` spans that
 :class:`tpu_sandbox.mpmd.driver.StageWorker` emits, independently of the
-online ``mpmd.bubble_fraction`` gauge the worker publishes — the bench
-cross-checks the two against the analytic ``(S-1)/(M+S-1)``.
+online ``mpmd.bubble_fraction`` gauge the worker publishes
+(tests/test_mpmd_fastfabric.py holds the two to each other).
 
 :func:`publish_profile` pushes a profile's segment shares through the
 tsdb ring (static gauge names, segment as a label — GL-O402/O403) so
@@ -83,10 +83,6 @@ GAP_SEGMENTS = {
 #: process-level spans that stall resident requests without being part
 #: of any request's causal chain — matched into gaps by process key
 STALL_SPANS = {"swap": "swap_pause"}
-
-#: the coverage contract: at most 5% of a request's wall may stay
-#: unattributed for the request to count as fully explained
-COVERAGE_TARGET = 0.95
 
 
 def _segment_of(name: str) -> str | None:

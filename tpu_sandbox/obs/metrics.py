@@ -173,7 +173,7 @@ class MetricsRegistry:
         }
 
     def reset(self) -> None:
-        """Drop every metric (tests / bench arm isolation)."""
+        """Drop every metric (test isolation)."""
         with self._lock:
             self._counters.clear()
             self._gauges.clear()

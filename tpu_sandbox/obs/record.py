@@ -405,8 +405,8 @@ def get_recorder() -> Recorder:
 
 def reset_recorder() -> None:
     """Close and forget the global recorder so the next
-    :func:`get_recorder` re-reads the environment (tests / the obs
-    bench flipping tracing on and off inside one process)."""
+    :func:`get_recorder` re-reads the environment (tests flipping
+    tracing on and off inside one process)."""
     global _RECORDER
     with _RECORDER_LOCK:
         if _RECORDER is not None:

@@ -70,9 +70,8 @@ class TimeSeriesFlusher:
     """Flush one process's registry into the durable ring.
 
     Call :meth:`flush` on whatever cadence the process already has (the
-    replica worker rides its load-report interval; the bench rides the
-    step loop). ``clock`` is injectable so tests can drive bucket
-    boundaries with a stub clock.
+    replica worker rides its load-report interval). ``clock`` is
+    injectable so tests can drive bucket boundaries with a stub clock.
     """
 
     def __init__(self, kv, proc: str, *, bucket_s: float = 1.0,

@@ -26,7 +26,7 @@ def cross_entropy_loss(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
     b16/s2048/v32768 to exactly that materialization (convert + reduce
     over a 4.3 GB f32 buffer). Off-TPU (CPU tests) and at small C the
     plain optax path runs — same math, pinned against each other by
-    bench --metric pallas and tests/test_pallas_ce-style checks."""
+    tests/test_aux.py and tests/test_mosaic_lowering.py."""
     if logits.ndim == 2 and logits.shape[-1] >= _FUSED_CE_MIN_CLASSES:
         from tpu_sandbox.ops.pallas_common import default_interpret
 

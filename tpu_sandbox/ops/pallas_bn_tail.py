@@ -189,7 +189,7 @@ def _grid_rows(h: int, w: int, c: int) -> int:
 def unfused_reference(y, gamma, beta, co: int, blk: int, eps: float = 1e-5):
     """The unfused tail exactly as ConvNetS2D computes it in train mode:
     (pooled, mu, var). Single home for the contract the kernels are checked
-    against (tests/test_pallas_bn_tail.py and bench.py --metric pallas)."""
+    against (tests/test_pallas_bn_tail.py)."""
     from tpu_sandbox.models.convnet_s2d import block_max_pool
 
     *lead, c = y.shape

@@ -147,7 +147,7 @@ def _grid_rows(h: int, w: int, c: int) -> int:
 
 def unfused_reference_t(y, gamma, beta, co: int, blk: int, eps: float = 1e-5):
     """The unfused transposed tail exactly as ConvNetS2DT computes it in
-    train mode: (pooled, mu, var). Contract for tests and bench."""
+    train mode: (pooled, mu, var). Contract for tests."""
     from tpu_sandbox.models.convnet_s2d_t import block_max_pool_t
 
     *lead, c, w = y.shape

@@ -34,10 +34,10 @@ loaded laptop and a quiet CI box. What is NOT deterministic — which
 replica executed a rid, how many scavenges raced — stays out of the
 audit bytes and in the human-facing report instead.
 
-The campaign drives gateways and agents through injected hooks (a test
-kills an in-process ``Gateway``; the bench SIGKILLs a real gateway
-process) — the orchestrator owns sequencing and auditing, never process
-management.
+The campaign drives gateways and agents through injected hooks (one test
+kills an in-process ``Gateway``; the slow integration tests SIGKILL real
+agent processes) — the orchestrator owns sequencing and auditing, never
+process management.
 """
 
 from __future__ import annotations

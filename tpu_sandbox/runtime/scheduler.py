@@ -40,8 +40,8 @@ KV schema (cluster level, outside every job namespace)::
     sched/jobs/<id>/verdict       copy of the job's final job/done record
     sched/jobs/<id>/event/<name>  wall-clock stamps (submitted, admitted,
                                   readmitted, preempt_sent, preempted, ...)
-                                  — receipts for bench --metric cluster;
-                                  never compared against a local clock
+                                  — never compared against a local
+                                  clock
 
 plus, per job, everything ``host_agent.py`` documents — under
 ``job/<id>/`` instead of bare.
@@ -241,7 +241,7 @@ def cancel_job(kv: KVClient, job_id: str) -> None:
 
 
 def job_events(kv: KVClient, job_id: str) -> dict[str, float]:
-    """The job's wall-clock event stamps (bench receipts). Differences
+    """The job's wall-clock event stamps. Differences
     between two stamps are meaningful — they come from the scheduler's
     clock — but never mix them with the caller's own clock."""
     out = {}
@@ -902,7 +902,7 @@ class ClusterScheduler:
 
     def _stamp_event(self, job_id: str, name: str) -> None:
         """One job-lifecycle stamp, twice: the durable wall-clock KV key
-        (bench receipts, resume detection) and a flight-recorder instant
+        (resume detection) and a flight-recorder instant
         (the merged timeline)."""
         self.kv.set(k_event(job_id, name), f"{time.time():.6f}")
         get_recorder().instant(f"job:{name}", args={"job": job_id})

@@ -9,7 +9,7 @@ Layers (bottom up):
   device page buffers; replay-exact seeded sampling (``sample_token``).
 - ``engine``  — continuous-batching engine: admits/evicts sequences at
   decode-step granularity, preempts-to-requeue under block pressure, plus a
-  static-batch baseline for the bench comparison. SLO guardrails live here:
+  static-batch baseline as its control. SLO guardrails live here:
   per-request deadlines, a bounded admission queue with shed-on-overload,
   and the load-report backpressure signals.
 - ``replica`` — replica processes behind the KV-backed request queue:

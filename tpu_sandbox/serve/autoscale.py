@@ -54,7 +54,7 @@ def k_event(n: int) -> str:
 
 
 def autoscale_events(kv) -> list[dict]:
-    """Every autoscale decision, in order — the bench/test timeline."""
+    """Every autoscale decision, in order — the timeline tests read."""
     out = []
     for n in range(int(kv.try_get(K_EVENT_TAIL) or b"0")):
         raw = kv.try_get(k_event(n))

@@ -27,8 +27,7 @@ Bitwise discipline (the parity tests in tests/test_serve.py hold these):
 
 Static shapes everywhere: prefill is compiled once per bucket length,
 decode once per (max_batch, page geometry). The page buffers are donated
-through both steps — the AOT receipt in tools/aot_serve.py shows XLA
-aliasing them input->output.
+through both steps.
 """
 
 from __future__ import annotations

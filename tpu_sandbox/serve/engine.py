@@ -15,7 +15,7 @@ paged allocator, and advances the whole replica one decode step at a time:
 
 ``StaticEngine`` is the control: admit a full batch, decode until *all* of
 it finishes, then admit the next batch. Same compiled steps, same
-allocator — the bench compares scheduling policy only.
+allocator — the two differ in scheduling policy only.
 
 Decoding is greedy argmax over fp32 logits by default — deterministic,
 which is what makes requeue/replay and the replica zero-loss story exact

@@ -2,8 +2,8 @@
 preflight, and a torch-replica twin for loss-curve experiments.
 
 ``numerics_preflight`` compares the execution plan under test with the
-plain ``ConvNet`` on the device it runs on; ``chip_smoke.py`` and
-``bench.py`` both fail on its verdict.
+plain ``ConvNet`` on the device it runs on; ``chip_smoke.py`` fails on
+its verdict.
 
 The reference architecture is torch (mnist_onegpu.py:11-31); this framework
 re-implements it in flax (models/convnet.py). To demonstrate end-to-end
