@@ -108,6 +108,41 @@ def test_flash_attention_at_the_cells_shapes_lowers_for_tpu(
     _lower_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
 
 
+MHC_SHAPES = [
+    # name, n, C, B, S, dtype: the Xing4 cell's streams, and a float32 shape
+    # (the products then stay at HIGHEST, the row groups are 8 rows)
+    ("xing4_cell_bf16", 4, 3584, 2, 4096, jnp.bfloat16),
+    ("float32", 4, 512, 2, 384, jnp.float32),
+]
+
+
+@pytest.mark.parametrize("name,n,c,b,s,dtype", MHC_SHAPES,
+                         ids=[m[0] for m in MHC_SHAPES])
+def test_mhc_kernels_lower_for_tpu(name, n, c, b, s, dtype):
+    """``ops/pallas_mhc.py``: ``pre`` and ``post`` forward and backward (four
+    kernels) at the tiles the rule picks, with the lane rolls that add and
+    fill the slots, products contracted over the tokens, a block held in
+    one buffer and an aliased cotangent."""
+    from tpu_sandbox.ops import pallas_mhc
+
+    k = n * n + 2 * n
+    x = jax.ShapeDtypeStruct((n, b, s, c), dtype)
+    phi = jax.ShapeDtypeStruct((n, c, k), jnp.float32)
+    alpha = jax.ShapeDtypeStruct((), jnp.float32)
+    bias = jax.ShapeDtypeStruct((n,), jnp.float32)
+
+    def loss(x, phi, alpha, bias):
+        u, proj, kept = pallas_mhc.pre(x, phi, alpha, bias, eps=1e-6,
+                                       dtype=dtype, interpret=False)
+        h_res = jax.nn.sigmoid(proj[2 * n:]).reshape(n, n, b, s)
+        out = pallas_mhc.post(kept, u, h_res, proj[n:2 * n], interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3))).trace(
+        x, phi, alpha, bias).lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 4
+
+
 @pytest.mark.parametrize("blocks", [{}, {"block_q": 128, "block_k": 128}],
                          ids=["rule_tiles", "the_rings_128"])
 def test_flash_lse_and_partial_bwd_with_traced_offsets_lower_for_tpu(blocks):

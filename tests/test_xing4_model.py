@@ -79,7 +79,7 @@ def test_hyper_connection_starts_as_a_plain_residual():
     mhc = xing4.HyperConnection(cfg)
     streams = jax.random.normal(jax.random.key(1), (4, B, S, 64))
     variables = mhc.init(jax.random.key(0), streams, method="pre")
-    u, (h_res, h_post) = mhc.apply(variables, streams, method="pre")
+    u, _, (h_res, h_post) = mhc.apply(variables, streams, method="pre")
     # H_pre = 1/n, H_post = 1, H_res ~ I up to the small alpha x~ Phi term
     np.testing.assert_allclose(u, streams.mean(0), atol=5e-2)
     np.testing.assert_allclose(h_post, 1.0, atol=5e-2)
@@ -133,12 +133,13 @@ def test_config_reads_the_published_keys_and_the_share():
 # --- the model against the reference ---
 
 @functools.cache
-def system_and_reference(dtype, mtp: bool, flash: bool = True):
+def system_and_reference(dtype, mtp: bool, flash: bool = True,
+                         hidden: int = 64):
     # the published 20 Sinkhorn iterations in the main float32 case; fewer
     # and no recomputation where the subject is the MTP module or bf16
     # (every unrolled iteration is compiled four times a block)
     full = dtype == jnp.float32 and not mtp
-    config = tiny(num_nextn_predict_layers=int(mtp),
+    config = tiny(num_nextn_predict_layers=int(mtp), hidden_size=hidden,
                   hc_sinkhorn_iters=20 if full else 4)
     cfg = xing4.Xing4Config.from_dict(config, tokens_per_step=B * S,
                                       dtype=dtype, remat=full, flash=flash)
@@ -176,10 +177,13 @@ def system_and_reference(dtype, mtp: bool, flash: bool = True):
 SYMMETRIC = ("block0/mhc_attn/", "mtp_block/mhc_attn/")
 
 
-@pytest.mark.parametrize("mtp", [False, True], ids=["main", "with_mtp"])
-def test_model_matches_the_reference_in_float32(mtp):
+# at width 128 the hyper-connections run ``ops/pallas_mhc.py``'s kernels
+# (interpreted here); at 64, no lane multiple, their ``jnp`` fallback
+@pytest.mark.parametrize("mtp,hidden", [(False, 64), (True, 64), (False, 128)],
+                         ids=["main", "with_mtp", "main_kernels"])
+def test_model_matches_the_reference_in_float32(mtp, hidden):
     (loss, logits, grads), (ref_loss, ref_logits, ref_grads) = (
-        system_and_reference(jnp.float32, mtp))
+        system_and_reference(jnp.float32, mtp, hidden=hidden))
     assert abs(float(loss) - float(ref_loss)) < 1e-5
     assert ref.rms_rel(logits, ref_logits) < 1e-5
     assert ("mtp_proj/kernel" in grads) == mtp
@@ -233,16 +237,25 @@ def _forgets_the_normalisations(logits, iters, eps):
 xing4_sinkhorn = xing4.sinkhorn
 
 
-@pytest.mark.parametrize("fault", [None, "sinkhorn_backward"])
-def test_check_point_conditions_the_mhc_gradients(fault, monkeypatch):
+@pytest.mark.parametrize("fault,hidden", [
+    pytest.param(None, 64, id="None"),
+    pytest.param("sinkhorn_backward", 64, id="sinkhorn_backward"),
+    pytest.param(None, 128, id="None-kernels"),
+    pytest.param("sinkhorn_backward", 128, id="sinkhorn_backward-kernels")])
+def test_check_point_conditions_the_mhc_gradients(fault, hidden, monkeypatch):
     """At ``ref.off_start``'s point the gradients of Phi_res, alpha_res and
     Phi_pre agree with the reference like the others (float32: 1e-3), so a
     limit on them means something; and a Sinkhorn whose backward pass is
     wrong breaks ``ref.compare``'s limit on them, with the forward pass
-    (logits, loss) untouched."""
+    (logits, loss) untouched. At width 128 it is the mHC kernels, with
+    Sinkhorn between them, that the reference and the fault are held to."""
+    from tests.test_pallas_mhc import choices, new_choices
+
+    before = choices()
     if fault:
         monkeypatch.setattr(xing4, "sinkhorn", _forgets_the_normalisations)
-    config = tiny(num_nextn_predict_layers=0, hc_sinkhorn_iters=6)
+    config = tiny(num_nextn_predict_layers=0, hc_sinkhorn_iters=6,
+                  hidden_size=hidden)
     cfg = xing4.Xing4Config.from_dict(config, tokens_per_step=B * S,
                                       dtype=jnp.float32, remat=False, flash=False)
     model = xing4.Xing4LM(cfg)
@@ -265,6 +278,7 @@ def test_check_point_conditions_the_mhc_gradients(fault, monkeypatch):
 
     (loss, (logits, sown)), grads = jax.jit(
         jax.value_and_grad(system, has_aux=True))(params)
+    assert ("fallback" in new_choices(before)) == (hidden == 64)
     wanted = [k for k in flat(params) if k.startswith("block1/mhc_attn/")]
     ref_cfg = {**config, "held": list(cfg.held), "local_rows": cfg.local_rows}
     ref_loss, ref_logits, ref_chosen, ref_grads = ref.loss_and_grads(

@@ -19,7 +19,10 @@ float32: ``x~ = RMSNorm_nC(vec X)`` (no learned scale);
 ``H_post = 2 sigmoid(a_post x~ Phi_post + b_post)``,
 ``H_res = Sinkhorn(clip(a_res mat(x~ Phi_res) + B_res))``;
 ``u = sum_i H_pre[i] X[i]``; ``y = F(RMSNorm(u))``;
-``X'[i] = sum_j H_res[i, j] X[j] + H_post[i] y``.
+``X'[i] = sum_j H_res[i, j] X[j] + H_post[i] y``. The passes that walk the
+streams (norm, projection, both mixes, and their backward) are
+``ops/pallas_mhc.py``'s: fused kernels where the shape tiles, ``jnp`` where
+it does not; the coefficient arithmetic on ``[n*n + 2n, B, S]`` is here.
 
 *MLA.* Low-rank query (``q_lora_rank``) and key/value (``kv_lora_rank``)
 paths with RMSNorm on the latents, per-head ``[nope | rope]`` queries and
@@ -45,10 +48,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from tpu_sandbox.ops import pallas_mhc
 from tpu_sandbox.ops.attention import causal_attention
 from tpu_sandbox.parallel.expert import ExpertShare
-
-_HIGHEST = jax.lax.Precision.HIGHEST
 
 # what the published config does not settle (the configuration file's
 # ``assumed``: ``router_bias_update``, ``mtp``, ``mhc_init``)
@@ -235,33 +237,28 @@ class HyperConnection(nn.Module):
             * (1.0 - jnp.eye(n, dtype=jnp.float32)), (n, n))
 
     def pre(self, streams):
+        """-> the sub-layer's input ``u``, the streams for ``post`` (as they
+        came; ``pallas_mhc.pre`` says why ``post`` reads this copy) and the
+        coefficients ``(h_res, h_post)``."""
         cfg = self.config
         n = cfg.hc_mult
-        x = streams.astype(jnp.float32)
-        inv = jax.lax.rsqrt(jnp.mean(jnp.square(x), (0, 3)) + cfg.hc_eps)  # [B,S]
         phi = jnp.concatenate([self.phi_pre, self.phi_post, self.phi_res], -1)
-        # [n*n + 2n, B, S]: the coefficient index leads, tokens fill the lanes
-        proj = jnp.einsum("nbsc,nck->kbs", x, phi, precision=_HIGHEST) * inv
-        h_pre = jax.nn.sigmoid(
-            self.alpha_pre * proj[:n] + self.b_pre[:, None, None])
+        # proj [n*n + 2n, B, S], float32: x~ Phi, the coefficient index
+        # leads, tokens fill the lanes
+        u, proj, streams = pallas_mhc.pre(
+            streams, phi, self.alpha_pre, self.b_pre, eps=cfg.hc_eps,
+            dtype=cfg.dtype)
         h_post = 2.0 * jax.nn.sigmoid(
             self.alpha_post * proj[n:2 * n] + self.b_post[:, None, None])
         h_res = sinkhorn(
-            jnp.clip(self.alpha_res * proj[2 * n:].reshape(n, n, *inv.shape)
+            jnp.clip(self.alpha_res * proj[2 * n:].reshape(n, n, *proj.shape[1:])
                      + self.b_res[:, :, None, None],
                      cfg.mhc_h_res_clamp_min, cfg.mhc_h_res_clamp_max),
             cfg.hc_sinkhorn_iters, cfg.hc_eps)
-        u = sum(h_pre[i][..., None] * x[i] for i in range(n))
-        return u.astype(cfg.dtype), (h_res, h_post)
+        return u, streams, (h_res, h_post)
 
     def post(self, streams, y, coefficients):
-        h_res, h_post = coefficients
-        n = self.config.hc_mult
-        x, y = streams.astype(jnp.float32), y.astype(jnp.float32)
-        return jnp.stack([
-            sum(h_res[i, j][..., None] * x[j] for j in range(n))
-            + h_post[i][..., None] * y for i in range(n)
-        ]).astype(streams.dtype)
+        return pallas_mhc.post(streams, y, *coefficients)
 
 
 class LatentAttention(nn.Module):
@@ -334,11 +331,11 @@ class Block(nn.Module):
         cfg = self.config
         norm = functools.partial(RMSNorm, cfg.rms_norm_eps, cfg.dtype)
         mhc = HyperConnection(cfg, name="mhc_attn")
-        u, coefficients = mhc.pre(streams)
+        u, streams, coefficients = mhc.pre(streams)
         y = LatentAttention(cfg, name="mla")(norm(name="attn_norm")(u))
         streams = mhc.post(streams, y, coefficients)
         mhc = HyperConnection(cfg, name="mhc_ffn")
-        u, coefficients = mhc.pre(streams)
+        u, streams, coefficients = mhc.pre(streams)
         ffn = (GatedMlp(cfg, name="mlp") if self.dense
                else expert_share(cfg, "moe"))
         return mhc.post(streams, ffn(norm(name="ffn_norm")(u)), coefficients)
