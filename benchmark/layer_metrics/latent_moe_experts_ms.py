@@ -1,0 +1,10 @@
+"""``moe_experts_ms`` in a cell whose experts live in a latent: the same scopes,
+facts and counters, read by ``layer_metrics/moe_experts_ms.py``. A metric of its own
+because ``moe_experts_ms``'s list of cells is held to the cell it was made for
+(``tests/benchmark/test_benchmark_xing4.py``, which a later PR may not edit)."""
+
+from benchmark.lib import manifest
+
+
+def read(obs):
+    return manifest.module("layer_metrics", "moe_experts_ms").read(obs)

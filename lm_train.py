@@ -45,12 +45,34 @@ def make_batches(vocab: int, batch: int, seq_len: int, steps: int, seed: int):
         yield tokens, targets
 
 
+def _xing4(config: dict, **how):
+    from tpu_sandbox.models import xing4
+
+    return (xing4.Xing4LM(xing4.Xing4Config.from_dict(config, **how)),
+            xing4.MTP_LOSS_WEIGHT)
+
+
+def _nemotron_h(config: dict, **how):
+    from tpu_sandbox.models import nemotron_h
+
+    return (nemotron_h.NemotronHLM(
+        nemotron_h.NemotronHConfig.from_dict(config, **how)),
+        nemotron_h.MTP_LOSS_WEIGHT)
+
+
+#: ``--model`` name -> builder of the models described by a ``--config`` file
+#: of published keys: ``(config, tokens_per_step=, dtype=, remat=, flash=)
+#: -> (model, weight of the MTP loss)``. ``gpt2`` is ``TransformerLM`` from
+#: the size flags, under every parallelism.
+CONFIG_MODELS = {"xing4": _xing4, "nemotron_h": _nemotron_h}
+
+
 def build(args, devices):
     """Model, optimizer, state (on the mesh) and engine for ``args`` (a
     namespace of ``build_parser``) over ``devices``: everything ``train``
     needs before its loop, and what the benchmark's runner drives, so that
     a cell measures this construction and not a copy of it. ``--model
-    xing4 --config <json>`` builds ``models.xing4.Xing4LM`` from the
+    <name> --config <json>`` builds a model of ``CONFIG_MODELS`` from the
     published keys of the file (dp only); the size flags then do not apply.
     A caller that has read the file already sets ``args.config`` to its
     content.
@@ -141,31 +163,28 @@ def build(args, devices):
             "grad collectives"
         )
     model = None  # the sp / pp engines build theirs from ``cfg``
-    if args.model == "xing4":
+    if args.model in CONFIG_MODELS:
         if p != "dp" or not args.config:
-            raise SystemExit("--model xing4 needs --config <json> and "
-                             "--parallelism dp")
+            raise SystemExit(f"--model {args.model} needs --config <json> "
+                             "and --parallelism dp")
         import json
         import types
-
-        from tpu_sandbox.models import xing4
 
         config = args.config
         if not isinstance(config, dict):
             with open(config) as f:
                 config = json.load(f)
-        model = xing4.Xing4LM(xing4.Xing4Config.from_dict(
+        model, mtp_weight = CONFIG_MODELS[args.model](
             config, tokens_per_step=args.batch * args.seq_len, dtype=dtype,
-            remat=args.remat, flash=args.flash))
+            remat=args.remat, flash=args.flash)
         mesh = make_mesh({"data": n}, devices=devices)
-        # ``create`` runs ``init`` op by op, and this model has hundreds of
-        # distinct operations: hand it the init as one compiled program.
+        # ``create`` runs ``init`` op by op, and these models have hundreds
+        # of distinct operations: hand it the init as one compiled program.
         # Parameter shapes do not depend on the length: a short sample.
         state = TrainState.create(
             types.SimpleNamespace(init=jax.jit(model.init)), rng,
             sample[:, :min(args.seq_len, 128)], tx)
-        eng = PjitEngine(model, tx, mesh, task="lm",
-                         mtp_weight=xing4.MTP_LOSS_WEIGHT)
+        eng = PjitEngine(model, tx, mesh, task="lm", mtp_weight=mtp_weight)
     elif p == "dp":
         mesh = make_mesh({"data": n}, devices=devices)
         model = TransformerLM(cfg, attention_fn=attention_fn)
@@ -271,7 +290,8 @@ def train(args):
 
     model, _, state, eng = build(args, devices)
     p = args.parallelism
-    vocab = model.config.vocab_size if args.model == "xing4" else args.vocab
+    vocab = (model.config.vocab_size if args.model in CONFIG_MODELS
+             else args.vocab)
     start = datetime.datetime.now()
     losses = []
     spans = LoopSpans()  # the same four spans as Trainer's loop
@@ -302,12 +322,13 @@ def build_parser() -> argparse.ArgumentParser:
     parser.add_argument("--parallelism",
                         choices=["dp", "tp", "sp", "pp", "pp_sp", "ep", "3d"],
                         default="dp")
-    parser.add_argument("--model", choices=["gpt2", "xing4"], default="gpt2",
-                        help="gpt2: TransformerLM from the size flags; "
-                             "xing4: models.xing4 from --config")
+    parser.add_argument("--model", choices=["gpt2", *CONFIG_MODELS],
+                        default="gpt2",
+                        help="gpt2: TransformerLM from the size flags; the "
+                             "others: the model of that name from --config")
     parser.add_argument("--config", default=None,
-                        help="xing4 only: the published config.json keys "
-                             "(benchmark/configs/xing4.0-29b-a4b.json)")
+                        help="the published config.json keys of a --model "
+                             "other than gpt2 (benchmark/configs/*.json)")
     parser.add_argument("--seed", type=int, default=0,
                         help="key of the weights' init")
     parser.add_argument("--dp", type=int, default=1,

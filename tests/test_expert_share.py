@@ -227,3 +227,135 @@ def test_bias_and_counters_do_not_move_without_a_mutable_collection():
     assert float(mutated["batch_stats"]["steps"]) == 1.0
     with pytest.raises(ValueError, match="row tile"):
         share((0, 1), local_rows=12).init(jax.random.key(0), x)
+
+
+# --- what PR 31 added: two-product experts, a router on another input, and
+# --- a router that builds nothing of tokens x choices x experts elements
+
+def test_relu2_experts_match_a_dense_loop():
+    """``kind="relu2"``: two products and no gate, for the routed experts
+    and for the shared expert alike, against a loop over the experts."""
+    x, _, _ = whole_layer()
+    held = tuple(range(E))
+    layer = ExpertShare(d_model=C, d_ff=F, n_routed_experts=E, top_k=K,
+                        held=held, local_rows=T * K, n_shared_experts=2,
+                        routed_scaling_factor=2.0, dtype=jnp.float32,
+                        row_tile=8, kind="relu2")
+    variables = layer.init(jax.random.key(1), x)
+    p = variables["params"]
+    assert "w_gate" not in p and "shared_gate" not in p
+    assert p["shared_up"]["kernel"].shape == (C, 2 * F)
+    _, sel, w = ref.route({"router": p["router"]}, x, REF_CFG)
+    want = jnp.square(jax.nn.relu(x @ p["shared_up"]["kernel"])
+                      ) @ p["shared_down"]["kernel"]
+    for e in range(E):
+        weight = jnp.where(sel == e, w, 0.0).sum(-1)
+        want = want + weight[:, None] * (
+            jnp.square(jax.nn.relu(x @ p["w_up"][e])) @ p["w_down"][e])
+    np.testing.assert_allclose(layer.apply(variables, x), want, atol=5e-5)
+    with pytest.raises(ValueError, match="unknown expert kind"):
+        ExpertShare(d_model=C, d_ff=F, n_routed_experts=E, top_k=K, held=held,
+                    local_rows=T * K, kind="gelu").init(jax.random.key(0), x)
+
+
+def test_the_router_may_score_another_input_than_the_experts_read():
+    """``routed(x, route_on=u)``: experts in a latent of width C, scores from
+    the full width 2 C, as a model that wraps the share calls it; against the
+    benchmark's plain reference of such a layer."""
+    from benchmark.reference import nemotron_h as latent_ref
+    from tpu_sandbox.models.nemotron_h import LatentMoE
+
+    u = jax.random.normal(jax.random.key(3), (T, 2 * C))
+    held = (1, 4, 7, 12)
+    layer = LatentMoE(d_model=C, d_ff=F, n_routed_experts=E, top_k=K,
+                      held=held, local_rows=T * K, kind="relu2",
+                      shared_width=3 * F, routed_scaling_factor=2.0,
+                      dtype=jnp.float32, row_tile=8)
+    variables = layer.init(jax.random.key(1), u)
+    p = variables["params"]
+    assert p["router"].shape == (2 * C, E) and p["w_up"].shape == (4, C, F)
+    assert p["shared_up"]["kernel"].shape == (2 * C, 3 * F)
+    want, _ = latent_ref.latent_moe(
+        dict(p), u, {**REF_CFG, "held": list(held), "local_rows": T * K})
+    np.testing.assert_allclose(layer.apply(variables, u), want, atol=5e-5)
+
+
+def _shapes_of(jaxpr):
+    """Every array shape a jaxpr builds, its sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        for var in eqn.outvars:
+            yield getattr(var.aval, "shape", ())
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _shapes_of(sub)
+
+
+def test_nothing_of_tokens_x_choices_x_experts_elements_is_built():
+    """At T 512, k 22, E 512 (Nemotron's router at a sixteenth of its
+    tokens) no array of the step's jaxpr, forward or backward, has T k E
+    elements: the chosen scores and the counts are sums of ``[T, E]``
+    comparisons, a choice at a time (a one-hot of the choices was 369 MB a
+    layer)."""
+    t, k, e = 512, 22, 512
+    layer = ExpertShare(d_model=C, d_ff=F, n_routed_experts=e, top_k=k,
+                        held=tuple(range(8)), local_rows=512, dtype=jnp.float32,
+                        kind="relu2")
+    x = jax.ShapeDtypeStruct((t, C), jnp.float32)
+    variables = jax.eval_shape(layer.init, jax.random.key(0), x)
+
+    def step(variables, x):
+        def loss(params, x):
+            y, mutated = layer.apply({**variables, "params": params}, x,
+                                     mutable=["batch_stats"])
+            return jnp.square(y).sum(), mutated
+        return jax.grad(loss, (0, 1), has_aux=True)(variables["params"], x)
+
+    sizes = [int(np.prod(s)) for s in _shapes_of(
+        jax.make_jaxpr(step)(variables, x).jaxpr)]
+    assert sizes and max(sizes) < t * k * e
+    assert max(sizes) >= t * e              # the scores themselves
+
+
+def test_gathered_scores_and_counts_are_bitwise_what_the_one_hot_gave():
+    """Xing4's router (top-4 of 64): the chosen scores, their gradient, the
+    per-expert counts and the layout's local expert index, bit for bit what
+    the one-hot formulation computed before (its expressions are here)."""
+    from tpu_sandbox.parallel.expert import _chosen, _members, _pick
+
+    t, k, e = 256, 4, 64
+    scores = jax.nn.sigmoid(jax.random.normal(jax.random.key(0), (t, e)))
+    _, sel = jax.lax.top_k(scores, k)
+    g = jax.random.normal(jax.random.key(1), (t, k))
+
+    def before(scores):
+        chosen = jax.nn.one_hot(sel, e, dtype=jnp.float32)
+        return (chosen * scores[:, None, :]).sum(-1)
+
+    np.testing.assert_array_equal(_chosen(scores, sel), before(scores))
+    np.testing.assert_array_equal(
+        jax.grad(lambda s: (_chosen(s, sel) * g).sum())(scores),
+        jax.grad(lambda s: (before(s) * g).sum())(scores))
+    np.testing.assert_array_equal(
+        _members(sel, e).sum(0),
+        jax.nn.one_hot(sel, e, dtype=jnp.float32).sum((0, 1)))
+    held = (3, 9, 17, 40)
+    local_of = [len(held)] * e
+    for i, expert in enumerate(held):
+        local_of[expert] = i
+    lay = share_layout(sel, e, held, 64, 8)
+    loc = _pick(jnp.asarray(local_of, jnp.int32), sel.reshape(-1))
+    np.testing.assert_array_equal(
+        np.asarray(lay["kept"]).reshape(-1) <= (np.asarray(loc) < len(held)),
+        True)
+    assert int(lay["rows_held"] + lay["rows_dropped"]) == int(
+        (loc < len(held)).sum())
+
+
+def test_share_rows_is_the_buffer_both_configs_size():
+    from tpu_sandbox.parallel.expert import share_rows
+
+    assert share_rows(8192, 4, 8, 64, 2, 256) == 8192      # Xing4's cell
+    assert share_rows(8192, 22, 8, 512, 2, 256) == 5632    # Nemotron's
+    assert share_rows(32, 2, 4, 8, 2, 256) == 256          # rounded up to a tile

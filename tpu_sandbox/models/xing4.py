@@ -50,7 +50,7 @@ import jax.numpy as jnp
 
 from tpu_sandbox.ops import pallas_mhc
 from tpu_sandbox.ops.attention import causal_attention
-from tpu_sandbox.parallel.expert import ExpertShare
+from tpu_sandbox.parallel.expert import ExpertShare, share_rows
 
 # what the published config does not settle (the configuration file's
 # ``assumed``: ``router_bias_update``, ``mtp``, ``mhc_init``)
@@ -118,11 +118,9 @@ class Xing4Config:
             raise ValueError("only sigmoid scores with n_group = topk_group = 1")
         e = dep.get("routed_experts_total", config["n_routed_experts"])
         held = tuple(dep.get("held", range(e)))
-        k = config["num_experts_per_tok"]
-        mean_rows = tokens_per_step * k * len(held) / e
-        tile = ExpertShare.row_tile
-        rows = int(math.ceil(dep.get("local_rows_factor", 2) * mean_rows / tile)
-                   ) * tile
+        rows = share_rows(tokens_per_step, config["num_experts_per_tok"],
+                          len(held), e, dep.get("local_rows_factor", 2),
+                          ExpertShare.row_tile)
         return cls(
             **{key: config[key] for key in cls.__dataclass_fields__
                if key in config}

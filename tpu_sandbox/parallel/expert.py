@@ -27,6 +27,7 @@ objective with ``aux_weight``.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any
 
 import flax.linen as nn
@@ -117,6 +118,53 @@ def router_scores(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
         precision=jax.lax.Precision.HIGHEST))
 
 
+def share_rows(tokens: int, top_k: int, n_held: int, n_experts: int,
+               factor: float, row_tile: int) -> int:
+    """R, the rows of a share's static buffer: ``factor`` times the mean
+    number of assignments the ``n_held`` experts of ``n_experts`` see in a
+    step of ``tokens`` tokens, rounded up to the row tile."""
+    mean_rows = tokens * top_k * n_held / n_experts
+    return int(math.ceil(factor * mean_rows / row_tile)) * row_tile
+
+
+def _members(sel: jnp.ndarray, n_experts: int, values=None) -> jnp.ndarray:
+    """``[T, E]``: where expert e is among ``sel[t]`` (distinct ids), 1 or
+    ``values[t, j]`` of the choice j that names it, else 0. One ``[T, E]``
+    select a choice, summed: no ``[T, k, E]`` array, no gather, no scatter."""
+    ids = jnp.arange(n_experts, dtype=sel.dtype)
+    out = 0
+    for j in range(sel.shape[1]):
+        hit = sel[:, j:j + 1] == ids
+        out = out + (hit.astype(jnp.float32) if values is None
+                     else jnp.where(hit, values[:, j:j + 1], 0))
+    return out
+
+
+@jax.custom_vjp
+def _chosen(scores: jnp.ndarray, sel: jnp.ndarray) -> jnp.ndarray:
+    """``scores[t, sel[t, j]]`` as ``[T, k]``: one masked row sum a choice (a
+    gather of single numbers costs the chip more than these passes over
+    ``[T, E]``), and the transpose puts each choice's cotangent back the
+    same way (``_members``)."""
+    ids = jnp.arange(scores.shape[-1], dtype=sel.dtype)
+    return jnp.stack([jnp.where(sel[:, j:j + 1] == ids, scores, 0).sum(-1)
+                      for j in range(sel.shape[1])], -1)
+
+
+def _chosen_fwd(scores, sel):
+    # the scores ride along for their width alone; the sigmoid's own
+    # backward pass keeps them anyway
+    return _chosen(scores, sel), (sel, scores)
+
+
+def _chosen_bwd(res, g):
+    sel, scores = res
+    return _members(sel, scores.shape[-1], g), None
+
+
+_chosen.defvjp(_chosen_fwd, _chosen_bwd)
+
+
 def _pick(table: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     """``table[idx]`` for a table of a few entries, as a one-hot sum: no
     gather, so nothing whose cost could follow the indices."""
@@ -183,10 +231,10 @@ def share_layout(sel: jnp.ndarray, n_experts: int, held: tuple[int, ...],
     """
     t, k = sel.shape
     a, h = t * k, len(held)
-    local_of = [h] * n_experts
+    flat = sel.reshape(a)
+    loc = jnp.full((a,), h, jnp.int32)                              # h = absent
     for i, e in enumerate(held):
-        local_of[e] = i
-    loc = _pick(jnp.asarray(local_of, jnp.int32), sel.reshape(a))  # h = absent
+        loc = jnp.where(flat == e, i, loc)
     order = jnp.argsort(loc, stable=True).astype(jnp.int32)   # rank -> assignment
     rank = jnp.argsort(order).astype(jnp.int32)               # assignment -> rank
     n = (loc[:, None] == jnp.arange(h)[None, :]).sum(0).astype(jnp.int32)
@@ -222,9 +270,9 @@ def share_layout(sel: jnp.ndarray, n_experts: int, held: tuple[int, ...],
 class ExpertShare(nn.Module):
     """The part of a routed-expert layer that one chip of an expert-parallel
     deployment computes: it routes over all ``n_routed_experts``, holds the
-    experts in ``held``, and returns what those give (plus the shared
-    expert, which every chip computes alike). Assignments to absent experts
-    are left out; nothing stands in for the other chips or their exchange.
+    experts in ``held``, and returns what those give. Assignments to absent
+    experts are left out; nothing stands in for the other chips or their
+    exchange.
 
     Sigmoid scores in float32, top-k of ``score + bias`` (the bias,
     ``e_score_correction_bias``, lives in ``batch_stats``: no gradient moves
@@ -232,11 +280,24 @@ class ExpertShare(nn.Module):
     experts that saw fewer tokens than the mean), weights the chosen scores
     normalised to sum ``routed_scaling_factor``.
 
+    What a model may choose: the experts' ``kind`` (``gated_silu``: three
+    products, ``down(silu(gate x) * up x)``; ``relu2``: two,
+    ``down(relu(up x) ** 2)``); a shared expert of the same kind and of
+    width ``d_ff * n_shared_experts`` that every chip computes alike
+    (``n_shared_experts`` 0: none here; a model whose shared expert has
+    another width or input owns it); and, through ``routed``, an input for
+    the router other than the experts' own (``route_on``: experts in a
+    latent, scores from the full width). A model that wraps the routed part
+    in layers of its own subclasses this module and calls ``routed`` from
+    its ``__call__``, so that they share one scope.
+
     The device work is a function of the shapes: held assignments are
     sorted into one buffer (``share_layout``), all of whose row tiles are
     multiplied by ``ops.pallas_grouped_matmul`` whether they hold rows or
-    zeros; dispatch and combine are row gathers both ways. ``batch_stats``
-    also accumulates the counters ``rows_held``, ``rows_dropped``,
+    zeros; dispatch and combine are row gathers both ways; the router's
+    chosen scores and counts are masked sums over ``[T, E]``, so nothing of
+    tokens x choices x experts elements is built. ``batch_stats`` also
+    accumulates the counters ``rows_held``, ``rows_dropped``,
     ``expert_rows_max`` and ``steps`` (int32: exact for 2**31 rows, which
     float32 is not past 2**24).
     """
@@ -252,13 +313,42 @@ class ExpertShare(nn.Module):
     bias_update_rate: float = 1e-3
     dtype: Any = jnp.bfloat16
     row_tile: int = 256
+    kind: str = "gated_silu"
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
-        from tpu_sandbox.ops.pallas_grouped_matmul import grouped_matmul
-
         lead, c = x.shape[:-1], x.shape[-1]
         x = x.reshape(-1, c).astype(self.dtype)
+        y = self.routed(x)
+        if self.n_shared_experts:
+            with jax.named_scope("shared"):
+                y = y + self.dense_expert(
+                    x, self.d_ff * self.n_shared_experts, c, "shared")
+        return y.reshape(*lead, c)
+
+    @nn.nowrap
+    def dense_expert(self, x: jnp.ndarray, width: int, out: int, prefix: str):
+        """One expert of this layer's ``kind`` as plain dense layers
+        ``{prefix}_gate`` (gated only), ``{prefix}_up``, ``{prefix}_down``:
+        ``x [T, C_in]`` -> ``[T, out]``."""
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=self.dtype)
+        u = dense(width, name=f"{prefix}_up")(x)
+        if self.kind == "gated_silu":
+            hidden = nn.silu(dense(width, name=f"{prefix}_gate")(x)) * u
+        else:
+            hidden = jnp.square(nn.relu(u))
+        return dense(out, name=f"{prefix}_down")(hidden)
+
+    @nn.nowrap
+    def routed(self, x: jnp.ndarray, route_on: jnp.ndarray | None = None):
+        """What the held experts give for ``x [T, d_model]``, the router
+        scoring ``route_on [T, C_r]`` (``x`` if None). Moves the bias and
+        the counters where ``batch_stats`` is mutable; sows ``sel``."""
+        from tpu_sandbox.ops.pallas_grouped_matmul import grouped_matmul
+
+        if self.kind not in ("gated_silu", "relu2"):
+            raise ValueError(f"unknown expert kind {self.kind!r}")
+        c = x.shape[-1]
         t, k, e, h = x.shape[0], self.top_k, self.n_routed_experts, len(self.held)
         if self.local_rows % self.row_tile:
             raise ValueError(f"local_rows {self.local_rows} is not a multiple "
@@ -272,15 +362,15 @@ class ExpertShare(nn.Module):
                                  "expert_rows_max", "steps")}
 
         with jax.named_scope("router"):
-            w_r = self.param("router", init, (c, e), jnp.float32)
-            scores = router_scores(x, w_r)                          # [T, E]
+            scored = x if route_on is None else route_on
+            w_r = self.param("router", init, (scored.shape[-1], e), jnp.float32)
+            scores = router_scores(scored, w_r)                     # [T, E]
             _, sel = jax.lax.top_k(
                 scores + jax.lax.stop_gradient(bias.value), k)      # [T, k]
-            chosen = jax.nn.one_hot(sel, e, dtype=jnp.float32)      # [T, k, E]
-            s_sel = (chosen * scores[:, None, :]).sum(-1)
+            s_sel = _chosen(scores, sel)
             weights = (s_sel / (s_sel.sum(-1, keepdims=True) + 1e-20)
                        * self.routed_scaling_factor)
-            counts = chosen.sum((0, 1))                             # [E]
+            counts = _members(sel, e).sum(0)                        # [E]
 
         with jax.named_scope("dispatch"):
             lay = share_layout(sel, e, tuple(self.held), self.local_rows,
@@ -293,32 +383,26 @@ class ExpertShare(nn.Module):
                 lay["kept"].reshape(t * k, 1))                      # [P, 1]
 
         with jax.named_scope("experts"):
-            shape = (h, c, self.d_ff)
-            w_gate = self.param("w_gate", init, shape, jnp.float32)
-            w_up = self.param("w_up", init, shape, jnp.float32)
-            w_down = self.param("w_down", init, (h, self.d_ff, c), jnp.float32)
             group = lay["tile_group"]
-            gate = grouped_matmul(rows, w_gate.astype(self.dtype), group,
-                                  self.row_tile)
-            up = grouped_matmul(rows, w_up.astype(self.dtype), group,
-                                self.row_tile)
-            out = grouped_matmul((nn.silu(gate) * up).astype(self.dtype),
-                                 w_down.astype(self.dtype), group,
-                                 self.row_tile)
+
+            def product(name, rows, shape):
+                w = self.param(name, init, (h, *shape), jnp.float32)
+                return grouped_matmul(rows, w.astype(self.dtype), group,
+                                      self.row_tile)
+
+            if self.kind == "gated_silu":
+                gate = product("w_gate", rows, (c, self.d_ff))
+                up = product("w_up", rows, (c, self.d_ff))
+                hidden = nn.silu(gate) * up
+            else:
+                hidden = jnp.square(nn.relu(
+                    product("w_up", rows, (c, self.d_ff))))
+            out = product("w_down", hidden.astype(self.dtype), (self.d_ff, c))
 
         with jax.named_scope("combine"):
             out = (out.astype(jnp.float32) * w_rows).astype(self.dtype)
             y = _collect(out, lay["dest"], lay["kept"], tok_r,
                          lay["row_valid"])
-
-        if self.n_shared_experts:
-            with jax.named_scope("shared"):
-                width = self.d_ff * self.n_shared_experts
-                dense = functools.partial(nn.Dense, use_bias=False,
-                                          dtype=self.dtype)
-                g = dense(width, name="shared_gate")(x)
-                u = dense(width, name="shared_up")(x)
-                y = y + dense(c, name="shared_down")(nn.silu(g) * u)
 
         if not self.is_initializing() and self.is_mutable_collection(
                 "batch_stats"):
@@ -330,4 +414,4 @@ class ExpertShare(nn.Module):
                 counters["expert_rows_max"].value, lay["expert_rows_max"])
             counters["steps"].value += 1
         self.sow("intermediates", "sel", sel)
-        return y.reshape(*lead, c)
+        return y
