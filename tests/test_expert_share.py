@@ -17,7 +17,8 @@ sys.path.insert(0, str(ROOT))
 
 from benchmark.reference import xing4 as ref  # noqa: E402
 from tpu_sandbox.ops.pallas_grouped_matmul import grouped_matmul  # noqa: E402
-from tpu_sandbox.parallel.expert import ExpertShare, share_layout  # noqa: E402
+from tpu_sandbox.parallel.expert import (  # noqa: E402
+    ExpertShare, _collect, _spread, share_layout)
 
 C, F, E, K, T = 32, 16, 16, 4, 96
 
@@ -58,16 +59,19 @@ REF_CFG = {"num_experts_per_tok": K, "routed_scaling_factor": 2.0,
            "n_shared_experts": 1}
 
 
-def test_the_shares_add_up_to_the_whole_layer():
-    """Held = 0-1, 2-3, ..., 14-15: the eight shares' routed parts plus the
-    shared expert counted once are the uncut reference's whole layer."""
+@pytest.mark.parametrize("n_held", [2, 4, 8])
+def test_the_shares_add_up_to_the_whole_layer(n_held):
+    """Held = 0-1, 2-3, ..., 14-15 (fewer experts than a token chooses: a
+    slot of the table a held expert), 0-3, ... (as many), 0-7, 8-15 (more: a
+    slot a choice): the shares' routed parts plus the shared expert counted
+    once are the uncut reference's whole layer."""
     x, layer, variables = whole_layer()
     want, _ = ref.expert_share(
         reference_params(variables), x,
         {**REF_CFG, "held": list(range(E)), "local_rows": T * K})
     total = 0.0
-    for first in range(0, E, 2):
-        held = (first, first + 1)
+    for first in range(0, E, n_held):
+        held = tuple(range(first, first + n_held))
         part = share(held, local_rows=T * K, shared=0).apply(
             cut(variables, held), x)
         ref_part, _ = ref.expert_share(
@@ -90,13 +94,15 @@ def skewed(x, variables, expert, gain=50.0):
         **variables["batch_stats"], "e_score_correction_bias": bias}}
 
 
+@pytest.mark.parametrize("held", [(3, 5), (3, 5, 8, 9, 11)],
+                         ids=["held<k", "k<held"])
 @pytest.mark.parametrize("local_rows,dropped", [(T * 2, 0), (T, 0), (T - 24, 24)])
-def test_one_expert_takes_every_row_and_only_the_total_drops(local_rows, dropped):
-    """All T tokens choose expert 3; the share holds 3 and 5. Per-expert
-    imbalance drops nothing while the share's total is at most R; above R
-    the tail (order: expert, then position) goes, as in the reference."""
+def test_one_expert_takes_every_row_and_only_the_total_drops(
+        local_rows, dropped, held):
+    """All T tokens choose expert 3; the share holds 3 and 5 (or three more).
+    Per-expert imbalance drops nothing while the share's total is at most R;
+    above R the tail (order: expert, then token) goes, as in the reference."""
     x, _, variables = whole_layer()
-    held = (3, 5)
     v = cut(skewed(x, variables, 3), held)
     layer = share(held, local_rows=local_rows)
     y, mutated = layer.apply(v, x, mutable=["batch_stats", "intermediates"])
@@ -115,35 +121,97 @@ def test_one_expert_takes_every_row_and_only_the_total_drops(local_rows, dropped
     np.testing.assert_allclose(y, want, atol=5e-5)
     kept = np.asarray(ref.kept_assignments(jnp.asarray(sel), held, local_rows))
     assert kept.sum() == min(total, local_rows)
-    if total > local_rows:  # the tail: expert 5's rows go first, then 3's last
-        assert not kept[sel == 5].any() or kept[sel == 3].all()
+    if total > local_rows:  # the tail: the last expert's rows go first
+        assert not kept[sel == held[-1]].any() or kept[sel == 3].all()
+
+
+def distinct_choices(seed, t=T, k=K, e=E):
+    """What the router gives: the top-k of a token's scores, distinct ids."""
+    return jax.lax.top_k(jax.random.normal(jax.random.key(seed), (t, e)), k)[1]
+
+
+def kept_by_choice(lay, sel, held):
+    """The table's ``kept [T, m]`` as ``[T, k]`` of the choices: a slot is
+    a choice (m = k) or a held expert (m = len(held))."""
+    kept = np.asarray(lay["kept"])
+    if kept.shape[1] == sel.shape[1] <= len(held):
+        return kept
+    hit = np.asarray(sel)[:, :, None] == np.asarray(held)
+    return (hit & kept[:, None, :]).any(-1)
+
+
+HELD = {"held<k": (4, 12), "held=k": (1, 4, 7, 12),
+        "k<held": (1, 4, 7, 9, 12, 13)}
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2])
 @pytest.mark.parametrize("local_rows", [32, 64, 512])
-def test_layout_invariants(seed, local_rows):
-    held, tile = (1, 4, 7, 12), 8
-    sel = jax.random.randint(jax.random.key(seed), (T, K), 0, E)
-    lay = jax.tree.map(np.asarray, share_layout(sel, E, held, local_rows, tile))
+@pytest.mark.parametrize("regime", list(HELD))
+def test_layout_invariants(seed, local_rows, regime):
+    held, tile = HELD[regime], 8
+    sel = distinct_choices(seed)
+    lay = jax.tree.map(np.asarray, share_layout(sel, held, local_rows, tile))
     p = local_rows + len(held) * tile
     group = lay["tile_group"]
     assert group.shape == (p // tile,) and (np.diff(group) >= 0).all()
     assert set(group) == set(range(len(held)))        # every expert owns a tile
     valid, kept = lay["row_valid"], lay["kept"]
+    m = min(K, len(held))           # what a token can hold here, and no wider
+    assert kept.shape == lay["dest"].shape == (T, m)
     assert valid.sum() == kept.sum() == lay["rows_held"] <= local_rows
     total = int(np.isin(np.asarray(sel), held).sum())
     assert lay["rows_held"] + lay["rows_dropped"] == total
     assert lay["rows_held"] == min(total, local_rows)
-    # a valid row holds an assignment to the expert its tile belongs to, the
-    # assignment points back at the row, and no two share one
+    # a valid row holds a slot of a token that chose the expert its tile
+    # belongs to, the slot points back at the row, and no two share one
     rows = np.flatnonzero(valid)
-    assignment = lay["row_assignment"][rows]
-    flat_sel = np.asarray(sel).reshape(-1)
-    assert (np.asarray(held)[group[rows // tile]] == flat_sel[assignment]).all()
-    assert (lay["dest"].reshape(-1)[assignment] == rows).all()
-    assert len(set(assignment)) == len(assignment)
+    slot = lay["row_assignment"][rows]
+    chosen = np.asarray(sel)[slot // m]
+    assert (chosen == np.asarray(held)[group[rows // tile]][:, None]).any(1).all()
+    assert (lay["dest"].reshape(-1)[slot] == rows).all()
+    assert kept.reshape(-1)[slot].all() and len(set(slot)) == len(slot)
+    # rows of one expert hold its tokens in order
+    for g in range(len(held)):
+        mine = rows[group[rows // tile] == g]
+        assert (np.diff(lay["row_assignment"][mine] // m) > 0).all()
     np.testing.assert_array_equal(
-        kept, np.asarray(ref.kept_assignments(sel, held, local_rows)))
+        kept_by_choice(lay, sel, held),
+        np.asarray(ref.kept_assignments(sel, held, local_rows)))
+
+
+def dense_map(lay, t):
+    """``M [T, P]``: 1 where buffer row r holds a slot of token t."""
+    valid = np.asarray(lay["row_valid"])
+    m = lay["dest"].shape[1]
+    dense = np.zeros((t, valid.shape[0]), np.float32)
+    rows = np.flatnonzero(valid)
+    dense[np.asarray(lay["row_assignment"])[rows] // m, rows] = 1.0
+    return dense
+
+
+@pytest.mark.parametrize("regime", list(HELD))
+def test_collect_and_spread_are_each_others_transpose(regime):
+    """Against the dense map of a random valid layout, rows dropped and
+    all: ``_collect`` is ``M @ buf`` through the table, ``_spread`` is
+    ``M.T @ src`` through the rows' tokens, and each one's ``jax.vjp`` is
+    the other."""
+    held, tile, local_rows = HELD[regime], 8, 32
+    lay = share_layout(distinct_choices(5), held, local_rows, tile)
+    assert int(lay["rows_dropped"]) > 0
+    m = lay["dest"].shape[1]
+    tok_r = lay["row_assignment"] // m
+    args = (lay["dest"], lay["kept"], tok_r, lay["row_valid"])
+    dense = dense_map(lay, T)
+    buf = jax.random.normal(jax.random.key(1), (dense.shape[1], C))
+    src = jax.random.normal(jax.random.key(2), (T, C))
+    collected, back = jax.vjp(lambda b: _collect(b, *args), buf)
+    np.testing.assert_allclose(collected, dense @ np.asarray(buf), atol=1e-5)
+    spread, forth = jax.vjp(
+        lambda s: _spread(s, tok_r, lay["row_valid"], lay["dest"], lay["kept"]),
+        src)
+    np.testing.assert_array_equal(spread, dense.T @ np.asarray(src))
+    np.testing.assert_array_equal(back(src)[0], spread)
+    np.testing.assert_array_equal(forth(buf)[0], collected)
 
 
 @pytest.mark.parametrize("k_dim,n_dim", [(32, 16), (16, 32)])
@@ -183,9 +251,8 @@ def test_empty_tiles_cost_a_product_and_give_zeros():
         x[tile:2 * tile].sum(0)[:, None], (16, 16)), rtol=1e-5, atol=1e-5)
 
 
-def lowered_step_text():
+def lowered_step_text(held=(0, 1, 2, 3)):
     x, _, variables = whole_layer()
-    held = (0, 1, 2, 3)
     layer = share(held, local_rows=64)
     v = cut(variables, held)
 
@@ -216,6 +283,57 @@ def test_compiled_share_has_no_colliding_scatter_and_no_data_dependent_loop():
     assert "ragged" not in text and "dynamic-reshape" not in text
 
 
+def gathers_and_sorts(text):
+    """Of a compiled program's text: (dtype, rows, numbers a row) of every
+    gather, and the keys every sort orders along its dimension."""
+    gathers, sorts = [], []
+    for line in text.splitlines():
+        found = re.search(r"= (\w+)\[([\d,]+)\]\S* gather\(", line)
+        if found:
+            dims = [int(d) for d in found.group(2).split(",")]
+            gathers.append((found.group(1), dims[0], int(np.prod(dims[1:]))))
+        found = re.search(r"= \(?\w+\[([\d,]+)\].* sort\(.*dimensions=\{(\d)\}",
+                          line)
+        if found:
+            sorts.append(int(found.group(1).split(",")[int(found.group(2))]))
+    return gathers, sorts
+
+
+@pytest.mark.parametrize("regime", list(HELD))
+def test_compiled_share_is_sized_by_what_a_token_can_hold_here(regime):
+    """Forward and backward: the widest gather fetches T min(k, h) rows (a
+    collect) and the rest one a buffer row; the layout's one sort has as
+    many keys; nothing has T k of either where fewer experts are held than
+    a token chooses. And the weights travel as rows of a token's m slot
+    weights: the only single numbers gathered are the layout's integers."""
+    held = HELD[regime]
+    m, p = min(K, len(held)), 64 + len(held) * 8
+    gathers, sorts = gathers_and_sorts(lowered_step_text(held))
+    assert {rows for _, rows, _ in gathers} == {T * m, p}
+    assert sorted(sorts) == [E, T * m]              # the top-k, the layout
+    widths = {(dtype, width) for dtype, _, width in gathers}
+    assert widths == {("f32", C), ("f32", m), ("s32", 1)}
+    assert all(rows == p for _, rows, width in gathers if width == 1)
+
+
+@pytest.mark.parametrize("regime", list(HELD))
+def test_a_traced_share_counts_its_table(regime):
+    """``moe.share_table``: one count a traced ``routed`` call site, with
+    the table's width and the buffer it serves."""
+    from tpu_sandbox.obs import get_registry
+
+    held = HELD[regime]
+    x, _, variables = whole_layer()
+    counter = get_registry().counter("moe.share_table", labels={
+        "tokens": T, "top_k": K, "held": len(held),
+        "width": min(K, len(held)), "buffer_rows": 64 + 8 * len(held), "c": C,
+        "collect": "gather"})
+    before = counter.value
+    layer = share(held, local_rows=64)
+    jax.jit(layer.apply)(cut(variables, held), x)
+    assert counter.value == before + 1
+
+
 def test_bias_and_counters_do_not_move_without_a_mutable_collection():
     x, layer, variables = whole_layer()
     y = layer.apply(variables, x)
@@ -232,11 +350,12 @@ def test_bias_and_counters_do_not_move_without_a_mutable_collection():
 # --- what PR 31 added: two-product experts, a router on another input, and
 # --- a router that builds nothing of tokens x choices x experts elements
 
-def test_relu2_experts_match_a_dense_loop():
+@pytest.mark.parametrize("held", [tuple(range(E)), (1, 4)],
+                         ids=["k<held", "held<k"])
+def test_relu2_experts_match_a_dense_loop(held):
     """``kind="relu2"``: two products and no gate, for the routed experts
-    and for the shared expert alike, against a loop over the experts."""
+    and for the shared expert alike, against a loop over the held experts."""
     x, _, _ = whole_layer()
-    held = tuple(range(E))
     layer = ExpertShare(d_model=C, d_ff=F, n_routed_experts=E, top_k=K,
                         held=held, local_rows=T * K, n_shared_experts=2,
                         routed_scaling_factor=2.0, dtype=jnp.float32,
@@ -248,10 +367,10 @@ def test_relu2_experts_match_a_dense_loop():
     _, sel, w = ref.route({"router": p["router"]}, x, REF_CFG)
     want = jnp.square(jax.nn.relu(x @ p["shared_up"]["kernel"])
                       ) @ p["shared_down"]["kernel"]
-    for e in range(E):
+    for i, e in enumerate(held):
         weight = jnp.where(sel == e, w, 0.0).sum(-1)
         want = want + weight[:, None] * (
-            jnp.square(jax.nn.relu(x @ p["w_up"][e])) @ p["w_down"][e])
+            jnp.square(jax.nn.relu(x @ p["w_up"][i])) @ p["w_down"][i])
     np.testing.assert_allclose(layer.apply(variables, x), want, atol=5e-5)
     with pytest.raises(ValueError, match="unknown expert kind"):
         ExpertShare(d_model=C, d_ff=F, n_routed_experts=E, top_k=K, held=held,
@@ -344,7 +463,7 @@ def test_gathered_scores_and_counts_are_bitwise_what_the_one_hot_gave():
     local_of = [len(held)] * e
     for i, expert in enumerate(held):
         local_of[expert] = i
-    lay = share_layout(sel, e, held, 64, 8)
+    lay = share_layout(sel, held, 64, 8)
     loc = _pick(jnp.asarray(local_of, jnp.int32), sel.reshape(-1))
     np.testing.assert_array_equal(
         np.asarray(lay["kept"]).reshape(-1) <= (np.asarray(loc) < len(held)),

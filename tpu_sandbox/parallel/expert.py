@@ -165,6 +165,15 @@ def _chosen_bwd(res, g):
 _chosen.defvjp(_chosen_fwd, _chosen_bwd)
 
 
+def _count_table(tokens: int, top_k: int, held: int, width: int,
+                 buffer_rows: int, c: int) -> None:
+    from tpu_sandbox.obs import get_registry
+
+    get_registry().counter("moe.share_table", labels={
+        "tokens": tokens, "top_k": top_k, "held": held, "width": width,
+        "buffer_rows": buffer_rows, "c": c, "collect": "gather"}).inc()
+
+
 def _pick(table: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     """``table[idx]`` for a table of a few entries, as a one-hot sum: no
     gather, so nothing whose cost could follow the indices."""
@@ -174,9 +183,9 @@ def _pick(table: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
 
 @jax.custom_vjp
 def _spread(src, idx, mask, back_idx, back_mask):
-    """``out[m] = src[idx[m]] * mask[m]``: rows of ``src [N, C]`` into a
-    buffer of M rows. Every source row lands in at most k buffer rows, the
-    ones ``back_idx [N, k]`` names where ``back_mask`` is set, so the
+    """``out[r] = src[idx[r]] * mask[r]``: rows of ``src [N, C]`` into a
+    buffer of P rows. Every source row lands in at most m buffer rows, the
+    ones ``back_idx [N, m]`` names where ``back_mask`` is set, so the
     transpose is ``_collect`` (a gather too), not a scatter-add."""
     del back_idx, back_mask
     return jnp.where(mask[:, None], src[idx], 0).astype(src.dtype)
@@ -184,12 +193,15 @@ def _spread(src, idx, mask, back_idx, back_mask):
 
 @jax.custom_vjp
 def _collect(buf, back_idx, back_mask, idx, mask):
-    """``out[n] = sum_j buf[back_idx[n, j]] * back_mask[n, j]``: the k
-    buffer rows of a source row, summed by reshape. Transpose: ``_spread``."""
+    """``out[n] = sum_j buf[back_idx[n, j]] * back_mask[n, j]``: the m
+    buffer rows of a source row, gathered slot by slot (``[m, N, C]``: as
+    ``[N, m, C]`` the rows want laying out anew before they can be summed,
+    and at m 4, C 3584 that copy cost more than the gather), added in
+    float32 and rounded once. Transpose: ``_spread``."""
     del idx, mask
-    n, k = back_idx.shape
-    rows = buf[back_idx.reshape(n * k)].reshape(n, k, buf.shape[-1])
-    return jnp.where(back_mask[..., None], rows, 0).sum(1).astype(buf.dtype)
+    n, m = back_idx.shape
+    rows = buf[back_idx.T.reshape(m * n)].reshape(m, n, buf.shape[-1])
+    return jnp.where(back_mask.T[..., None], rows, 0).sum(0).astype(buf.dtype)
 
 
 def _spread_fwd(src, idx, mask, back_idx, back_mask):
@@ -216,28 +228,54 @@ _spread.defvjp(_spread_fwd, _spread_bwd)
 _collect.defvjp(_collect_fwd, _collect_bwd)
 
 
-def share_layout(sel: jnp.ndarray, n_experts: int, held: tuple[int, ...],
-                 local_rows: int, row_tile: int) -> dict:
-    """Where each held assignment of ``sel [T, k]`` (expert ids) sits in a
-    buffer of ``local_rows + len(held) * row_tile`` rows, and back.
+def _held_hits(sel: jnp.ndarray, held: tuple[int, ...]):
+    """``[T, k, h]``: where choice j of token t names held expert i, and
+    the axis a slot of the share's table sums it over: the table is as wide
+    as what a token can hold here, ``min(k, h)`` — a slot a choice where a
+    token chooses no more experts than are held, else a slot a held expert
+    (``top_k`` gives a token distinct experts, so it names each at most
+    once)."""
+    hit = sel[:, :, None] == jnp.asarray(held, sel.dtype)
+    return hit, (2 if sel.shape[1] <= len(held) else 1)
 
-    Held assignments are ordered by expert, then by position (token, then
-    choice); the first ``local_rows`` of that order are kept and the tail is
-    dropped — whatever the per-expert imbalance, only the share's total can
-    drop a row. Each expert's kept rows start on a row tile and every expert
-    owns at least one tile, so a tile belongs to one expert; the extra
-    ``len(held)`` tiles are what that alignment can cost at worst. All of it
-    is sorts, comparisons and prefix sums over static shapes.
+
+def _slot_values(sel: jnp.ndarray, held: tuple[int, ...], values: jnp.ndarray):
+    """``values [T, k]`` of a token's choices as ``[T, min(k, h)]`` of its
+    slots (0 where a slot holds nothing): a masked sum, like ``_chosen``,
+    and so is its transpose."""
+    hit, axis = _held_hits(sel, held)
+    return jnp.where(hit, values[:, :, None], 0).sum(axis)
+
+
+def share_layout(sel: jnp.ndarray, held: tuple[int, ...], local_rows: int,
+                 row_tile: int) -> dict:
+    """Where each held assignment of ``sel [T, k]`` (what ``top_k`` gives:
+    distinct expert ids a token) sits in a buffer of ``local_rows +
+    len(held) * row_tile`` rows, and back: a table ``[T, m]`` of a token's
+    slots (``_held_hits``), m = min(k, len(held)).
+
+    Held assignments are ordered by expert, then by token; the first
+    ``local_rows`` of that order are kept and the tail is dropped —
+    whatever the per-expert imbalance, only the share's total can drop a
+    row. Each expert's kept rows start on a row tile and every expert owns
+    at least one tile, so a tile belongs to one expert; the extra
+    ``len(held)`` tiles are what that alignment can cost at worst. An
+    assignment's rank inside its expert is the count of that expert's
+    tokens before it, so nothing is as large as tokens x choices but the
+    comparisons that fill the table; the way back from a buffer row to its
+    slot is one sort of the table's T m keys. All of it is comparisons,
+    prefix sums and that sort over static shapes.
     """
-    t, k = sel.shape
-    a, h = t * k, len(held)
-    flat = sel.reshape(a)
-    loc = jnp.full((a,), h, jnp.int32)                              # h = absent
-    for i, e in enumerate(held):
-        loc = jnp.where(flat == e, i, loc)
-    order = jnp.argsort(loc, stable=True).astype(jnp.int32)   # rank -> assignment
-    rank = jnp.argsort(order).astype(jnp.int32)               # assignment -> rank
-    n = (loc[:, None] == jnp.arange(h)[None, :]).sum(0).astype(jnp.int32)
+    t, h = sel.shape[0], len(held)
+    hit, axis = _held_hits(sel, held)
+    ids = jnp.arange(h, dtype=jnp.int32)
+    loc = jnp.where(hit.any(axis), jnp.where(hit, ids, 0).sum(axis), h)  # h = absent
+    m = loc.shape[1]
+    if (h + 1) * t * m >= 2 ** 31:
+        raise ValueError(f"{t} tokens x {m} slots x {h} experts pass int32")
+    mine = loc[:, :, None] == ids                                   # [T, m, h]
+    count = jnp.cumsum(mine.any(1).astype(jnp.int32), 0)            # [T, h]
+    n = count[-1]
     start = jnp.cumsum(n) - n
     kept_n = jnp.clip(local_rows - start, 0, n)
     aligned = jnp.maximum(row_tile, -(-kept_n // row_tile) * row_tile)
@@ -248,21 +286,22 @@ def share_layout(sel: jnp.ndarray, n_experts: int, held: tuple[int, ...],
     tile_group = jnp.minimum(
         (tile_start[:, None] >= (a_start + aligned)[None, :]).sum(1), h - 1
     ).astype(jnp.int32)
-    # buffer row -> assignment
+    # buffer row -> slot: the slots by (expert, token), absent ones last
+    slot = jnp.arange(t * m, dtype=jnp.int32).reshape(t, m)
+    order = jnp.sort((loc * (t * m) + slot).reshape(t * m)) % (t * m)
     e_r = jnp.repeat(tile_group, row_tile)
     off_r = jnp.arange(p, dtype=jnp.int32) - _pick(a_start, e_r)
     valid = off_r < _pick(kept_n, e_r)
-    a_r = order[jnp.clip(_pick(start, e_r) + off_r, 0, a - 1)]
-    # assignment -> buffer row
+    a_r = order[jnp.clip(_pick(start, e_r) + off_r, 0, t * m - 1)]
+    # slot -> buffer row
+    off_a = jnp.where(mine, count[:, None, :] - 1, 0).sum(2)        # [T, m]
     e_a = jnp.minimum(loc, h - 1)
-    off_a = rank - _pick(start, e_a)
     kept = (loc < h) & (off_a < _pick(kept_n, e_a))
-    # an assignment with no row points somewhere harmless, spread over the
-    # buffer so that the access pattern does not depend on how many there are
-    dest = jnp.where(kept, _pick(a_start, e_a) + off_a,
-                     jnp.arange(a, dtype=jnp.int32) % p)
+    # a slot with no row points somewhere harmless, spread over the buffer
+    # so that the access pattern does not depend on how many there are
+    dest = jnp.where(kept, _pick(a_start, e_a) + off_a, slot % p)
     return {"tile_group": tile_group, "row_assignment": a_r, "row_valid": valid,
-            "dest": dest.reshape(t, k), "kept": kept.reshape(t, k),
+            "dest": dest, "kept": kept,
             "rows_held": kept_n.sum(), "rows_dropped": (n - kept_n).sum(),
             "expert_rows_max": n.max()}
 
@@ -291,10 +330,15 @@ class ExpertShare(nn.Module):
     in layers of its own subclasses this module and calls ``routed`` from
     its ``__call__``, so that they share one scope.
 
-    The device work is a function of the shapes: held assignments are
-    sorted into one buffer (``share_layout``), all of whose row tiles are
-    multiplied by ``ops.pallas_grouped_matmul`` whether they hold rows or
-    zeros; dispatch and combine are row gathers both ways; the router's
+    The device work is a function of the shapes: held assignments are laid
+    out by expert, then token, in one buffer (``share_layout``), all of
+    whose row tiles are multiplied by ``ops.pallas_grouped_matmul`` whether
+    they hold rows or zeros. The share's own bookkeeping is a table of
+    m = min(top_k, held) slots a token, what a token can hold here, not
+    its top_k choices: dispatch gathers a row a buffer row, combine m rows
+    a token, each the other's transpose, and a row's weight travels as a
+    row of its token's m slot weights through the same pair (the registry
+    counts the table a traced call site, ``moe.share_table``). The router's
     chosen scores and counts are masked sums over ``[T, E]``, so nothing of
     tokens x choices x experts elements is built. ``batch_stats`` also
     accumulates the counters ``rows_held``, ``rows_dropped``,
@@ -373,14 +417,20 @@ class ExpertShare(nn.Module):
             counts = _members(sel, e).sum(0)                        # [E]
 
         with jax.named_scope("dispatch"):
-            lay = share_layout(sel, e, tuple(self.held), self.local_rows,
-                               self.row_tile)
-            tok_r = lay["row_assignment"] // k
-            rows = _spread(x, tok_r, lay["row_valid"], lay["dest"], lay["kept"])
-            w_rows = _spread(
-                weights.reshape(t * k, 1), lay["row_assignment"],
-                lay["row_valid"], lay["dest"].reshape(t * k, 1),
-                lay["kept"].reshape(t * k, 1))                      # [P, 1]
+            held = tuple(self.held)
+            lay = share_layout(sel, held, self.local_rows, self.row_tile)
+            m = lay["dest"].shape[1]
+            _count_table(t, k, h, m, lay["row_valid"].shape[0], c)
+            tok_r = lay["row_assignment"] // m
+            back = (lay["dest"], lay["kept"])
+            rows = _spread(x, tok_r, lay["row_valid"], *back)
+            # a row's weight: its token's m slot weights come as a row (a
+            # gather of single numbers, and its transpose of T m of them,
+            # cost the chip more), and the row's own slot is picked
+            w_slots = _slot_values(sel, held, weights)              # [T, m]
+            w_rows = (_spread(w_slots, tok_r, lay["row_valid"], *back)
+                      * jax.nn.one_hot(lay["row_assignment"] % m, m)
+                      ).sum(-1, keepdims=True)                      # [P, 1]
 
         with jax.named_scope("experts"):
             group = lay["tile_group"]
@@ -401,8 +451,7 @@ class ExpertShare(nn.Module):
 
         with jax.named_scope("combine"):
             out = (out.astype(jnp.float32) * w_rows).astype(self.dtype)
-            y = _collect(out, lay["dest"], lay["kept"], tok_r,
-                         lay["row_valid"])
+            y = _collect(out, *back, tok_r, lay["row_valid"])
 
         if not self.is_initializing() and self.is_mutable_collection(
                 "batch_stats"):
