@@ -25,8 +25,11 @@ jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_num_cpu_devices", 8)
 jax.config.update("jax_threefry_partitionable", True)
 
+import faulthandler  # noqa: E402
 import gc  # noqa: E402
+import signal  # noqa: E402
 import sys  # noqa: E402
+import tempfile  # noqa: E402
 import threading  # noqa: E402
 import time  # noqa: E402
 
@@ -44,6 +47,63 @@ import pytest  # noqa: E402
 # resize_on_device).
 gc.set_threshold(50_000, 20, 20)
 gc.freeze()  # startup world (jax, numpy, flax) is permanent: never scan it
+
+
+#: every test's limit, set-up and tear-down included. The slowest tier-1 case
+#: takes 25 s on the six-worker run (42 s before PR 33), a module's first
+#: case also builds its module's fixtures, and on a host whose cores were
+#: being stolen one case read 98 s: three times that, and far less than the
+#: suite's own clock, so that a hang reads as one failed test.
+TEST_LIMIT_S = 300.0
+#: behind it, for a hang inside native code that no Python handler can
+#: interrupt: the process dumps its stacks and exits, xdist reports the
+#: worker down with the test's name and goes on with a new one
+HARD_LIMIT_S = 420.0
+
+
+_STDERR = pytest.StashKey()
+
+
+def pytest_configure(config):
+    # capture is suspended here, so this is the run's own stderr, which the
+    # hard limit's dump still reaches while a test's output is captured
+    config.stash[_STDERR] = os.fdopen(os.dup(2), "w")
+
+
+def _over_the_limit(signum, frame):
+    with tempfile.TemporaryFile("w+") as stacks:
+        faulthandler.dump_traceback(file=stacks, all_threads=True)
+        stacks.seek(0)
+        pytest.fail(f"over the limit of {TEST_LIMIT_S:g} s a test; every "
+                    f"thread's stack:\n{stacks.read()}", pytrace=False)
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_protocol(item, nextitem):
+    """One limit for every test (no ``pytest-timeout`` here): an interval
+    timer whose handler fails the test by name with all threads' stacks."""
+    if threading.current_thread() is not threading.main_thread():
+        return (yield)  # no signal reaches another thread
+    signal.signal(signal.SIGALRM, _over_the_limit)
+    signal.setitimer(signal.ITIMER_REAL, TEST_LIMIT_S)
+    faulthandler.dump_traceback_later(HARD_LIMIT_S, exit=True,
+                                      file=item.config.stash[_STDERR])
+    try:
+        return (yield)
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        faulthandler.cancel_dump_traceback_later()
+
+
+@pytest.fixture
+def light_compile():
+    """XLA:CPU without its expensive passes, for a test (or, by
+    ``pytestmark``, a module) whose claim is a tolerance against a
+    reference and never one that is bitwise: the tiny shapes here cost
+    their compilation, which takes half the CPU time this way."""
+    jax.config.update("jax_disable_most_optimizations", True)
+    yield
+    jax.config.update("jax_disable_most_optimizations", False)
 
 
 @pytest.fixture(autouse=True, scope="module")

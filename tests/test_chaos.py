@@ -9,13 +9,11 @@ family, multiple seeds, prefix probes) lives slow-marked in
 test_chaos_integration.py.
 """
 
-import contextlib
-import threading
 import time
 
-import numpy as np
 import pytest
 
+from tests.helpers import StubStep, kv_pair, pumping  # noqa: F401 (kv_pair: fixture)
 from tpu_sandbox.gateway.client import GatewayClient
 from tpu_sandbox.gateway.fleet import FleetSpec
 from tpu_sandbox.gateway.server import Gateway
@@ -33,37 +31,9 @@ CCFG = CacheConfig(num_blocks=24, block_size=4, max_blocks_per_seq=8)
 BLOCK = CCFG.block_size
 
 
-class _StubStep:
-    """DecodeStep stand-in: next token = (last + 1) % vocab, no jax."""
-
-    def __init__(self, buckets=(8, 16), vocab=64):
-        self.buckets = tuple(buckets)
-        self.vocab = vocab
-        self.prefill = {b: self._prefill for b in self.buckets}
-
-    def pick_bucket(self, plen):
-        for b in self.buckets:
-            if plen <= b:
-                return b
-        raise ValueError(f"prompt of {plen} exceeds buckets {self.buckets}")
-
-    def _prefill(self, params, k, v, toks, dest, last):
-        toks = np.asarray(toks)
-        logits = np.zeros((self.vocab,), np.float32)
-        logits[(int(toks[0, int(last)]) + 1) % self.vocab] = 1.0
-        return logits, k, v
-
-    def decode(self, params, k, v, tokens, lengths, tables):
-        tokens = np.asarray(tokens)
-        logits = np.zeros((tokens.shape[0], self.vocab), np.float32)
-        for i in range(tokens.shape[0]):
-            logits[i, (int(tokens[i, 0]) + 1) % self.vocab] = 1.0
-        return logits, k, v
-
-
 def _engine():
     cfg = ServeConfig(model=MCFG, cache=CCFG, max_batch=2, buckets=(8, 16))
-    return ContinuousEngine(None, cfg, step=_StubStep(), clock=time.monotonic)
+    return ContinuousEngine(None, cfg, step=StubStep(), clock=time.monotonic)
 
 
 def _worker(kv, tag):
@@ -71,45 +41,6 @@ def _worker(kv, tag):
 
     return ReplicaWorker(kv, _engine(), tag=tag, lease_ttl=1.0,
                          load_interval=0.02)
-
-
-@contextlib.contextmanager
-def _pumping(*workers):
-    stop = threading.Event()
-
-    def run():
-        while not stop.is_set():
-            for w in workers:
-                w.tick()
-            time.sleep(0.001)
-
-    t = threading.Thread(target=run, name="chaos-pump", daemon=True)
-    t.start()
-    try:
-        yield stop
-    finally:
-        stop.set()
-        t.join(timeout=10.0)
-
-
-@pytest.fixture
-def kv_pair():
-    from tpu_sandbox.runtime.kvstore import KVClient, KVServer
-
-    server = KVServer()
-    kv = KVClient(port=server.port)
-    clones = []
-
-    def clone():
-        c = kv.clone()
-        clones.append(c)
-        return c
-
-    yield server, kv, clone
-    for c in clones:
-        c.close()
-    kv.close()
-    server.stop()
 
 
 # -- schedule expansion: pure + seeded ----------------------------------------
@@ -168,7 +99,7 @@ def _run_smoke_campaign(kv, clone):
     w0, w1 = _worker(clone(), "w0"), _worker(clone(), "w1")
     client = None
     try:
-        with _pumping(w0, w1):
+        with pumping(w0, w1):
             client = GatewayClient(
                 endpoints=[("127.0.0.1", gws["gw0"].port),
                            ("127.0.0.1", gws["gw1"].port)],

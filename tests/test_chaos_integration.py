@@ -8,13 +8,12 @@ wreckage a prefix probe checks failover didn't degrade the door to
 blind load balancing.
 """
 
-import contextlib
 import threading
 import time
 
-import numpy as np
 import pytest
 
+from tests.helpers import StubStep, pumping
 from tpu_sandbox.gateway.client import GatewayClient
 from tpu_sandbox.gateway.fleet import FleetSpec
 from tpu_sandbox.gateway.server import Gateway
@@ -31,57 +30,12 @@ CCFG = CacheConfig(num_blocks=32, block_size=4, max_blocks_per_seq=8)
 BLOCK = CCFG.block_size
 
 
-class _StubStep:
-    def __init__(self, buckets=(8, 16), vocab=64):
-        self.buckets = tuple(buckets)
-        self.vocab = vocab
-        self.prefill = {b: self._prefill for b in self.buckets}
-
-    def pick_bucket(self, plen):
-        for b in self.buckets:
-            if plen <= b:
-                return b
-        raise ValueError(f"prompt of {plen} exceeds buckets {self.buckets}")
-
-    def _prefill(self, params, k, v, toks, dest, last):
-        toks = np.asarray(toks)
-        logits = np.zeros((self.vocab,), np.float32)
-        logits[(int(toks[0, int(last)]) + 1) % self.vocab] = 1.0
-        return logits, k, v
-
-    def decode(self, params, k, v, tokens, lengths, tables):
-        tokens = np.asarray(tokens)
-        logits = np.zeros((tokens.shape[0], self.vocab), np.float32)
-        for i in range(tokens.shape[0]):
-            logits[i, (int(tokens[i, 0]) + 1) % self.vocab] = 1.0
-        return logits, k, v
-
-
 def _worker(kv, tag):
     from tpu_sandbox.serve.replica import ReplicaWorker
 
     cfg = ServeConfig(model=MCFG, cache=CCFG, max_batch=2, buckets=(8, 16))
-    eng = ContinuousEngine(None, cfg, step=_StubStep(), clock=time.monotonic)
+    eng = ContinuousEngine(None, cfg, step=StubStep(), clock=time.monotonic)
     return ReplicaWorker(kv, eng, tag=tag, lease_ttl=1.0, load_interval=0.02)
-
-
-@contextlib.contextmanager
-def _pumping(*workers):
-    stop = threading.Event()
-
-    def run():
-        while not stop.is_set():
-            for w in workers:
-                w.tick()
-            time.sleep(0.001)
-
-    t = threading.Thread(target=run, name="chaos-pump", daemon=True)
-    t.start()
-    try:
-        yield stop
-    finally:
-        stop.set()
-        t.join(timeout=10.0)
 
 
 def _run_matrix_campaign(seed):
@@ -120,7 +74,7 @@ def _run_matrix_campaign(seed):
     workers = [_worker(clone(), f"w{i}") for i in range(3)]
     client = None
     try:
-        with _pumping(*workers):
+        with pumping(*workers):
             client = GatewayClient(
                 endpoints=[("127.0.0.1", gws[g].port)
                            for g in ("gw0", "gw1", "gw2")],

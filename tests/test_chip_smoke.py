@@ -3,7 +3,6 @@ non-zero and print no verdict on the CPU, refuse a kernel kill-switch, and
 fail when the compiled step lacks one of the Pallas kernels."""
 
 import os
-import subprocess
 import sys
 from pathlib import Path
 
@@ -13,15 +12,15 @@ REPO = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(REPO))
 
 import chip_smoke  # noqa: E402
+from tests.helpers import run_child  # noqa: E402
 
 
 def _run(**env):
     clean = {k: v for k, v in os.environ.items()
              if k not in chip_smoke.KILL_SWITCHES}
-    return subprocess.run(
+    return run_child(
         [sys.executable, str(REPO / "chip_smoke.py")], cwd=REPO,
-        env={**clean, "JAX_PLATFORMS": "cpu", **env},
-        capture_output=True, text=True, timeout=120)
+        env={**clean, "JAX_PLATFORMS": "cpu", **env}, timeout=120)
 
 
 def test_exits_nonzero_on_cpu_naming_the_platform():

@@ -10,6 +10,9 @@ import pytest
 from tpu_sandbox.models import ConvNet
 from tpu_sandbox.ops import cross_entropy_loss
 
+# every claim here is a tolerance: conftest's cheaper compile
+pytestmark = pytest.mark.usefixtures("light_compile")
+
 
 def init_model(h=32, w=32):
     model = ConvNet()

@@ -4,7 +4,13 @@ The s2d model exists purely as an execution plan (models/convnet_s2d.py);
 these tests pin the contract that lets the entry scripts swap
 it in for the reference-parity ConvNet: identical parameter tree, identical
 forward, identical gradients, identical batch-stats evolution.
+
+What the transposed plan promises alike takes a ``plan`` (the model's
+class): this module's fixture gives ConvNetS2D, and
+tests/test_convnet_s2d_t.py collects the same tests with ConvNetS2DT.
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -17,9 +23,9 @@ from tpu_sandbox.models.convnet_s2d import ConvNetS2D, scatter_kernel
 from tpu_sandbox.ops.losses import cross_entropy_loss
 
 
-def _models(use_bn=True, dtype=jnp.float32):
-    return (ConvNet(use_bn=use_bn, dtype=dtype),
-            ConvNetS2D(use_bn=use_bn, dtype=dtype))
+@pytest.fixture
+def plan():
+    return ConvNetS2D
 
 
 def _data(n=3, hw=48, seed=0):
@@ -27,6 +33,32 @@ def _data(n=3, hw=48, seed=0):
     x = jnp.asarray(rng.standard_normal((n, hw, hw, 1)), jnp.float32)
     y = jnp.asarray(rng.integers(0, 10, size=(n,)), jnp.int32)
     return x, y
+
+
+# every claim here is a tolerance between two plans of one function
+pytestmark = pytest.mark.usefixtures("light_compile")
+
+
+@functools.cache
+def _shared_init(use_bn=True, n=3, hw=48):
+    """The variables both plans start from: ConvNet's, compiled once."""
+    return jax.jit(ConvNet(use_bn=use_bn).init)(
+        jax.random.key(0), _data(n, hw)[0])
+
+
+def _applied(model, variables, x, **how):
+    """``model.apply`` as one compiled program, not one a primitive."""
+    return jax.jit(functools.partial(model.apply, **how))(variables, x)
+
+
+def _loss_grads_stats(model, x, y):
+    """The jitted ``(params, stats) -> ((loss, new stats), grads)``."""
+    def f(p, stats):
+        logits, upd = model.apply(
+            {"params": p, "batch_stats": stats}, x, train=True,
+            mutable=["batch_stats"])
+        return cross_entropy_loss(logits, y), upd["batch_stats"]
+    return jax.jit(jax.value_and_grad(f, has_aux=True))
 
 
 def test_pick_convnet_plan_switch():
@@ -59,11 +91,11 @@ def test_pick_convnet_plan_switch():
     assert resolve_plan(3001) == "plain"
 
 
-def test_param_trees_compatible():
-    ref, s2d = _models()
+def test_param_trees_compatible(plan):
+    ref, s2d = ConvNet(), plan()
     x, _ = _data()
-    vr = ref.init(jax.random.key(0), x)
-    vs = s2d.init(jax.random.key(0), x)
+    vr = jax.eval_shape(ref.init, jax.random.key(0), x)
+    vs = jax.eval_shape(s2d.init, jax.random.key(0), x)
     ref_shapes = jax.tree.map(jnp.shape, vr)
     s2d_shapes = jax.tree.map(jnp.shape, vs)
     assert ref_shapes == s2d_shapes
@@ -90,17 +122,17 @@ def test_scatter_kernel_reproduces_conv():
 
 
 @pytest.mark.parametrize("use_bn", [True, False])
-def test_forward_matches_convnet(use_bn):
-    ref, s2d = _models(use_bn)
+def test_forward_matches_convnet(plan, use_bn):
+    ref, s2d = ConvNet(use_bn=use_bn), plan(use_bn=use_bn)
     x, _ = _data()
-    variables = ref.init(jax.random.key(0), x)
+    variables = _shared_init(use_bn)
     if use_bn:
-        lr = ref.apply(variables, x, train=True, mutable=["batch_stats"])
-        ls = s2d.apply(variables, x, train=True, mutable=["batch_stats"])
+        lr = _applied(ref, variables, x, train=True, mutable=["batch_stats"])
+        ls = _applied(s2d, variables, x, train=True, mutable=["batch_stats"])
         out_r, out_s = lr[0], ls[0]
     else:
-        out_r = ref.apply(variables, x, train=True)
-        out_s = s2d.apply(variables, x, train=True)
+        out_r = _applied(ref, variables, x, train=True)
+        out_s = _applied(s2d, variables, x, train=True)
     np.testing.assert_allclose(np.asarray(out_s), np.asarray(out_r),
                                atol=2e-4)
     if use_bn:
@@ -112,33 +144,23 @@ def test_forward_matches_convnet(use_bn):
                     atol=1e-5, err_msg=f"{k}/{stat}")
 
 
-def test_eval_mode_uses_running_stats():
-    ref, s2d = _models()
+def test_eval_mode_uses_running_stats(plan):
+    ref, s2d = ConvNet(), plan()
     x, _ = _data()
-    variables = ref.init(jax.random.key(0), x)
-    out_r = ref.apply(variables, x, train=False)
-    out_s = s2d.apply(variables, x, train=False)
+    variables = _shared_init()
+    out_r = _applied(ref, variables, x, train=False)
+    out_s = _applied(s2d, variables, x, train=False)
     np.testing.assert_allclose(np.asarray(out_s), np.asarray(out_r),
                                atol=2e-4)
 
 
-def test_gradients_match_convnet():
-    ref, s2d = _models()
+def test_gradients_match_convnet(plan):
+    ref, s2d = ConvNet(), plan()
     x, y = _data()
-    variables = ref.init(jax.random.key(0), x)
+    variables = _shared_init()
     params, stats = variables["params"], variables["batch_stats"]
-
-    def loss_fn(model):
-        def f(p):
-            logits, _ = model.apply(
-                {"params": p, "batch_stats": stats}, x, train=True,
-                mutable=["batch_stats"],
-            )
-            return cross_entropy_loss(logits, y)
-        return f
-
-    lr, gr = jax.value_and_grad(loss_fn(ref))(params)
-    ls, gs = jax.value_and_grad(loss_fn(s2d))(params)
+    (lr, _), gr = _loss_grads_stats(ref, x, y)(params, stats)
+    (ls, _), gs = _loss_grads_stats(s2d, x, y)(params, stats)
     np.testing.assert_allclose(ls, lr, atol=1e-5)
     flat_r = jax.tree_util.tree_leaves_with_path(gr)
     flat_s = {jax.tree_util.keystr(k): v
@@ -149,32 +171,61 @@ def test_gradients_match_convnet():
             atol=5e-4, err_msg=jax.tree_util.keystr(k))
 
 
-def test_short_training_runs_stay_together():
-    """5 SGD steps from shared init: losses track to float tolerance."""
-    ref, s2d = _models()
+def test_short_training_runs_stay_together(plan):
+    """5 SGD steps from shared init: losses track to float tolerance (the
+    steps compound the one-ulp conv and reduction differences between the
+    plans; compiled as one program a step the drift reads 3e-6)."""
+    ref, s2d = ConvNet(), plan()
     x, y = _data(n=4, hw=32)
     tx = optax.sgd(1e-2)
-    variables = ref.init(jax.random.key(0), x)
+    variables = _shared_init(n=4, hw=32)
 
     def run(model):
+        loss_grads_stats = _loss_grads_stats(model, x, y)
+
+        @jax.jit
+        def step(params, stats, opt):
+            (loss, stats), g = loss_grads_stats(params, stats)
+            updates, opt = tx.update(g, opt, params)
+            return optax.apply_updates(params, updates), stats, opt, loss
+
         params, stats = variables["params"], variables["batch_stats"]
         opt = tx.init(params)
         losses = []
         for _ in range(5):
-            def f(p):
-                logits, upd = model.apply(
-                    {"params": p, "batch_stats": stats}, x, train=True,
-                    mutable=["batch_stats"],
-                )
-                return cross_entropy_loss(logits, y), upd
-            (loss, upd), g = jax.value_and_grad(f, has_aux=True)(params)
-            stats = upd["batch_stats"]
-            updates, opt = tx.update(g, opt, params)
-            params = optax.apply_updates(params, updates)
+            params, stats, opt, loss = step(params, stats, opt)
             losses.append(float(loss))
         return losses
 
     np.testing.assert_allclose(run(s2d), run(ref), rtol=1e-4)
+
+
+@functools.cache
+def _three_steps_under_data_parallel(model, mesh):
+    """Three steps' losses of ``model`` in DataParallel over the mesh's
+    shards, from ConvNet's init (one run of the plain model serves every
+    case it is compared with)."""
+    from tpu_sandbox.data import synthetic_mnist
+    from tpu_sandbox.data.mnist import normalize
+    from tpu_sandbox.parallel import DataParallel
+    from tpu_sandbox.train import TrainState
+
+    images, labels = synthetic_mnist(n=16, seed=0)
+    images, labels = normalize(images), labels.astype("int32")
+    tx = optax.sgd(1e-2)
+    variables = _shared_init(n=1, hw=32)
+    state0 = TrainState(
+        step=jnp.zeros((), jnp.int32), params=variables["params"],
+        batch_stats=variables["batch_stats"],
+        opt_state=tx.init(variables["params"]),
+    )
+    dp = DataParallel(model, tx, mesh, image_size=(32, 32), donate=False)
+    st = dp.shard_state(state0)
+    losses = []
+    for _ in range(3):
+        st, loss = dp.train_step(st, *dp.shard_batch(images, labels))
+        losses.append(np.asarray(loss))
+    return np.stack(losses)
 
 
 @pytest.mark.parametrize(
@@ -188,60 +239,26 @@ def test_s2d_under_data_parallel_matches_plain_model(mesh8, fused_tail,
     init; BN per-replica in both) — with and without the fused Pallas
     tail/conv, since pick_convnet defaults production entry points to
     both fused."""
-    from tpu_sandbox.data import synthetic_mnist
-    from tpu_sandbox.data.mnist import normalize
-    from tpu_sandbox.parallel import DataParallel
-    from tpu_sandbox.train import TrainState
-
-    images, labels = synthetic_mnist(n=16, seed=0)
-    images, labels = normalize(images), labels.astype("int32")
-    tx = optax.sgd(1e-2)
-    ref, _ = _models()
     s2d = ConvNetS2D(fused_tail=fused_tail, fused_conv=fused_conv)
-    variables = ref.init(jax.random.key(0),
-                         jnp.zeros((1, 32, 32, 1), jnp.float32))
-    state0 = TrainState(
-        step=jnp.zeros((), jnp.int32), params=variables["params"],
-        batch_stats=variables["batch_stats"],
-        opt_state=tx.init(variables["params"]),
-    )
-
-    def run(model):
-        dp = DataParallel(model, tx, mesh8, image_size=(32, 32), donate=False)
-        st = dp.shard_state(state0)
-        losses = []
-        for _ in range(3):
-            st, loss = dp.train_step(st, *dp.shard_batch(images, labels))
-            losses.append(np.asarray(loss))
-        return losses
-
     np.testing.assert_allclose(
-        np.stack(run(s2d)), np.stack(run(ref)), rtol=2e-4, atol=2e-4
-    )
+        _three_steps_under_data_parallel(s2d, mesh8),
+        _three_steps_under_data_parallel(ConvNet(), mesh8),
+        rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.parametrize("fused_conv", [False, True])
-def test_fused_tail_matches_unfused_model(fused_conv):
-    """ConvNetS2D(fused_tail=True[, fused_conv=True]) == ConvNetS2D:
-    logits, grads, and BN running stats with shared init."""
+@pytest.mark.parametrize("fused", [{"fused_conv": False}, {"fused_conv": True}],
+                         ids=["False", "True"])
+def test_fused_tail_matches_unfused_model(plan, fused):
+    """The plan with ``fused_tail=True`` (and, for ConvNetS2D, with or
+    without ``fused_conv``) == the plan without: logits, grads, and BN
+    running stats with shared init."""
     x, y = _data(n=2, hw=32, seed=5)
-    plain = ConvNetS2D()
-    fused = ConvNetS2D(fused_tail=True, fused_conv=fused_conv)
-    variables = plain.init(jax.random.key(0), x)
+    plain = plan()
+    fused = plan(fused_tail=True, **fused)
+    variables = jax.jit(plain.init)(jax.random.key(0), x)
     params, stats = variables["params"], variables["batch_stats"]
-
-    def step(model, params, stats):
-        def f(p):
-            logits, upd = model.apply(
-                {"params": p, "batch_stats": stats}, x, train=True,
-                mutable=["batch_stats"],
-            )
-            return cross_entropy_loss(logits, y), upd
-        (loss, upd), g = jax.value_and_grad(f, has_aux=True)(params)
-        return loss, g, upd["batch_stats"]
-
-    lp, gp, sp = step(plain, params, stats)
-    lf, gf, sf = step(fused, params, stats)
+    (lp, sp), gp = _loss_grads_stats(plain, x, y)(params, stats)
+    (lf, sf), gf = _loss_grads_stats(fused, x, y)(params, stats)
     np.testing.assert_allclose(float(lf), float(lp), atol=1e-5)
     for (kp, a), (_, b) in zip(
         jax.tree_util.tree_leaves_with_path(gp),

@@ -1,39 +1,44 @@
 """ConvNetS2DT == ConvNet: the transposed s2d plan is the same function.
 
-Mirror of test_convnet_s2d.py for the [N,H,C,W]-layout plan
-(models/convnet_s2d_t.py): identical parameter tree, forward, gradients,
-and batch-stats evolution as the reference-parity ConvNet, with and
-without the fused tail pair."""
+The tests of test_convnet_s2d.py, collected here with the [N,H,C,W]-layout
+plan (models/convnet_s2d_t.py): identical parameter tree, forward,
+gradients, and batch-stats evolution as the reference-parity ConvNet, with
+and without the fused tail pair; then what only this plan has."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 
+from tests import test_convnet_s2d as s2d
+from tests.test_convnet_s2d import (  # noqa: F401 (collected here too)
+    test_eval_mode_uses_running_stats, test_forward_matches_convnet,
+    test_param_trees_compatible)
 from tpu_sandbox.models import ConvNet
 from tpu_sandbox.models.convnet_s2d_t import ConvNetS2DT
 from tpu_sandbox.ops.losses import cross_entropy_loss
 
 
-def _models(use_bn=True, dtype=jnp.float32, **kw):
-    return (ConvNet(use_bn=use_bn, dtype=dtype),
-            ConvNetS2DT(use_bn=use_bn, dtype=dtype, **kw))
+@pytest.fixture
+def plan():
+    return ConvNetS2DT
 
 
-def _data(n=3, hw=48, seed=0):
-    rng = np.random.default_rng(seed)
-    x = jnp.asarray(rng.standard_normal((n, hw, hw, 1)), jnp.float32)
-    y = jnp.asarray(rng.integers(0, 10, size=(n,)), jnp.int32)
-    return x, y
+@pytest.mark.slow  # tier-1 keeps the test_convnet_s2d.py twin
+def test_gradients_match_convnet(plan):
+    s2d.test_gradients_match_convnet(plan)
 
 
-def test_param_trees_compatible():
-    ref, t = _models()
-    x, _ = _data()
-    vr = ref.init(jax.random.key(0), x)
-    vt = t.init(jax.random.key(0), x)
-    assert jax.tree.map(jnp.shape, vr) == jax.tree.map(jnp.shape, vt)
+@pytest.mark.slow  # tier-1 keeps the test_convnet_s2d.py twin
+def test_fused_tail_matches_unfused_model(plan):
+    """The production fused chain: conv stats fused in-kernel + fused tail
+    pair."""
+    s2d.test_fused_tail_matches_unfused_model(plan, {})
+
+
+@pytest.mark.slow  # tier-1 keeps the test_convnet_s2d.py twin
+def test_short_training_runs_stay_together(plan):
+    s2d.test_short_training_runs_stay_together(plan)
 
 
 def test_space_to_depth_t_is_transposed_space_to_depth():
@@ -58,142 +63,6 @@ def test_block_max_pool_t_is_transposed_block_max_pool():
         np.asarray(block_max_pool_t(y.transpose(0, 1, 3, 2), 4, 3)),
         np.asarray(block_max_pool(y, 4, 3).transpose(0, 1, 3, 2)),
     )
-
-
-@pytest.mark.parametrize("use_bn", [True, False])
-def test_forward_matches_convnet(use_bn):
-    ref, t = _models(use_bn)
-    x, _ = _data()
-    variables = ref.init(jax.random.key(0), x)
-    if use_bn:
-        lr = ref.apply(variables, x, train=True, mutable=["batch_stats"])
-        lt = t.apply(variables, x, train=True, mutable=["batch_stats"])
-        out_r, out_t = lr[0], lt[0]
-    else:
-        out_r = ref.apply(variables, x, train=True)
-        out_t = t.apply(variables, x, train=True)
-    np.testing.assert_allclose(np.asarray(out_t), np.asarray(out_r),
-                               atol=2e-4)
-    if use_bn:
-        for k in ("bn1", "bn2"):
-            for stat in ("mean", "var"):
-                np.testing.assert_allclose(
-                    np.asarray(lt[1]["batch_stats"][k][stat]),
-                    np.asarray(lr[1]["batch_stats"][k][stat]),
-                    atol=1e-5, err_msg=f"{k}/{stat}")
-
-
-def test_eval_mode_uses_running_stats():
-    ref, t = _models()
-    x, _ = _data()
-    variables = ref.init(jax.random.key(0), x)
-    np.testing.assert_allclose(
-        np.asarray(t.apply(variables, x, train=False)),
-        np.asarray(ref.apply(variables, x, train=False)), atol=2e-4)
-
-
-@pytest.mark.slow  # tier-1 keeps the test_convnet_s2d.py twin
-def test_gradients_match_convnet():
-    ref, t = _models()
-    x, y = _data()
-    variables = ref.init(jax.random.key(0), x)
-    params, stats = variables["params"], variables["batch_stats"]
-
-    def loss_fn(model):
-        def f(p):
-            logits, _ = model.apply(
-                {"params": p, "batch_stats": stats}, x, train=True,
-                mutable=["batch_stats"],
-            )
-            return cross_entropy_loss(logits, y)
-        return f
-
-    lr, gr = jax.value_and_grad(loss_fn(ref))(params)
-    lt, gt = jax.value_and_grad(loss_fn(t))(params)
-    np.testing.assert_allclose(lt, lr, atol=1e-5)
-    flat_r = jax.tree_util.tree_leaves_with_path(gr)
-    flat_t = {jax.tree_util.keystr(k): v
-              for k, v in jax.tree_util.tree_leaves_with_path(gt)}
-    for k, v in flat_r:
-        np.testing.assert_allclose(
-            np.asarray(flat_t[jax.tree_util.keystr(k)]), np.asarray(v),
-            atol=5e-4, err_msg=jax.tree_util.keystr(k))
-
-
-@pytest.mark.slow  # tier-1 keeps the test_convnet_s2d.py twin
-def test_fused_tail_matches_unfused_model():
-    """ConvNetS2DT(fused_tail=True) == ConvNetS2DT: logits, grads, BN
-    running stats with shared init (the production fused chain: conv
-    stats fused in-kernel + fused tail pair)."""
-    x, y = _data(n=2, hw=32, seed=5)
-    plain = ConvNetS2DT()
-    fused = ConvNetS2DT(fused_tail=True)
-    variables = plain.init(jax.random.key(0), x)
-    params, stats = variables["params"], variables["batch_stats"]
-
-    def step(model):
-        # jit'd like the production trainer; also ~2x faster than eager
-        # op-by-op dispatch on the CPU backend
-        @jax.jit
-        def go(p):
-            def f(p):
-                logits, upd = model.apply(
-                    {"params": p, "batch_stats": stats}, x, train=True,
-                    mutable=["batch_stats"],
-                )
-                return cross_entropy_loss(logits, y), upd
-            (loss, upd), g = jax.value_and_grad(f, has_aux=True)(p)
-            return loss, g, upd["batch_stats"]
-        return go(params)
-
-    lp, gp, sp = step(plain)
-    lf, gf, sf = step(fused)
-    np.testing.assert_allclose(np.asarray(lf), np.asarray(lp), atol=1e-5)
-    jax.tree.map(lambda a, b: np.testing.assert_allclose(
-        np.asarray(a), np.asarray(b), atol=5e-4), gf, gp)
-    jax.tree.map(lambda a, b: np.testing.assert_allclose(
-        np.asarray(a), np.asarray(b), atol=1e-5), sf, sp)
-
-
-@pytest.mark.slow  # tier-1 keeps the test_convnet_s2d.py twin
-def test_short_training_runs_stay_together():
-    """5 SGD steps from shared init: losses track to float tolerance."""
-    ref, t = _models()
-    x, y = _data(n=4, hw=32)
-    tx = optax.sgd(1e-2)
-    variables = ref.init(jax.random.key(0), x)
-
-    def run(model):
-        # one jit'd SGD step, like the production trainer (and ~5x faster
-        # than eager op-by-op dispatch on the CPU backend)
-        @jax.jit
-        def one(params, stats, opt):
-            def f(p):
-                logits, upd = model.apply(
-                    {"params": p, "batch_stats": stats}, x, train=True,
-                    mutable=["batch_stats"],
-                )
-                return cross_entropy_loss(logits, y), upd
-            (loss, upd), g = jax.value_and_grad(f, has_aux=True)(params)
-            updates, opt = tx.update(g, opt, params)
-            return (optax.apply_updates(params, updates),
-                    upd["batch_stats"], opt, loss)
-
-        params, stats = variables["params"], variables["batch_stats"]
-        opt = tx.init(params)
-        losses = []
-        for _ in range(5):
-            params, stats, opt, loss = one(params, stats, opt)
-            losses.append(float(loss))
-        return losses
-
-    # Five compounding SGD steps amplify the one-ULP conv/reduction
-    # differences between the two plans; CPU XLA's conv reassociation makes
-    # the drift land near 1e-4 (observed max ~1.09e-4 eager, ~3.3e-6 under
-    # jit, ROADMAP "known flake"). Keep the tight bound on TPU, where both
-    # plans lower to the same MXU convs.
-    rtol = 1e-4 if jax.default_backend() == "tpu" else 1e-3
-    np.testing.assert_allclose(run(t), run(ref), rtol=rtol)
 
 
 @pytest.mark.slow  # tier-1 keeps test_data_parallel's fused-input parity

@@ -15,7 +15,7 @@ import pytest
 from tpu_sandbox.obs import critpath, workload
 from tpu_sandbox.obs.collect import load_merged
 
-from tests.test_gateway import kv_pair  # noqa: F401 (fixture)
+from tests.helpers import kv_pair  # noqa: F401 (fixture)
 
 FIXTURES = os.path.join(os.path.dirname(__file__), "fixtures")
 TRACE_SMALL = os.path.join(FIXTURES, "trace_small")

@@ -17,7 +17,6 @@ from tpu_sandbox.data import ShardedBatchLoader, synthetic_mnist
 from tpu_sandbox.data.mnist import normalize
 from tpu_sandbox.models import ConvNet
 from tpu_sandbox.parallel import DataParallel
-from tpu_sandbox.runtime.mesh import make_mesh
 from tpu_sandbox.train import TrainState, make_train_step
 
 
@@ -28,6 +27,7 @@ def setup(use_bn, lr=0.05):
     return model, tx, state
 
 
+@pytest.mark.usefixtures("light_compile")
 def test_dp_matches_single_device_without_bn(mesh8):
     """Same params, same effective batch 16: one DP step over 8 shards ==
     one single-device step (pmean of shard grads == full-batch grad)."""
@@ -131,6 +131,7 @@ def test_sharded_loader_epochs_and_len():
     assert steps[1][0].shape[0] == 16  # padded equal shards even at the tail
 
 
+@pytest.mark.usefixtures("light_compile")
 def test_dp_training_loss_decreases(mesh8):
     from tpu_sandbox.train import Trainer
 
@@ -150,6 +151,7 @@ def test_dp_training_loss_decreases(mesh8):
     assert int(final.step) == 4 * len(loader)
 
 
+@pytest.mark.usefixtures("light_compile")
 def test_zero1_matches_plain_dp(mesh8):
     """ZeRO-1 (sharded optimizer state) is the same math as plain DP: with
     AdamW (stateful, elementwise) the losses and final params agree to
@@ -197,6 +199,7 @@ def test_zero1_matches_plain_dp(mesh8):
     assert not conv_spec or conv_spec[0] is None, conv_spec
 
 
+@pytest.mark.usefixtures("light_compile")
 def test_dp_s2dt_fused_input_matches_plain_resize(mesh8):
     """The full r04 production input path under DataParallel — raw 28x28
     batch -> fused resize+s2d -> ConvNetS2DT (sparse-tap conv1, fused

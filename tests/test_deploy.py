@@ -15,7 +15,7 @@ tsdb, and the hard contracts the ISSUE pins:
   mailbox command) while its orphaned work replays on the pinned version.
 
 Real subprocess fleets + jax weights live in the slow-marked
-test_deploy_integration.py; everything here uses the _StubStep pattern
+test_deploy_integration.py; everything here uses the StubStep pattern
 (next token = last + 1 mod vocab) so the file stays inside tier-1.
 """
 
@@ -26,6 +26,8 @@ from pathlib import Path
 import numpy as np
 import pytest
 
+from tests.helpers import kv_pair  # noqa: F401 (fixture)
+from tests.test_gateway import BLOCK, _engine
 from tpu_sandbox.deploy.controller import DeployConfig, DeployController
 from tpu_sandbox.deploy.registry import (current_target, deploy_events,
                                          k_ro, load_step_params,
@@ -34,75 +36,13 @@ from tpu_sandbox.deploy.registry import (current_target, deploy_events,
 from tpu_sandbox.gateway.fleet import FleetSpec
 from tpu_sandbox.gateway.server import Gateway
 from tpu_sandbox.gateway.client import GatewayClient, RetriesExhausted
-from tpu_sandbox.models.transformer import TransformerConfig
 from tpu_sandbox.obs.health import active_subjects
-from tpu_sandbox.serve.cache import CacheConfig
-from tpu_sandbox.serve.engine import ContinuousEngine, Request, ServeConfig
+from tpu_sandbox.serve.engine import Request
 from tpu_sandbox.serve.replica import (ReplicaWorker, k_cmd, k_done, k_load,
                                        k_pin, k_result, read_load_reports,
                                        read_result, submit_request)
 from tpu_sandbox.train.checkpoint import export_params, verify_step_dir
 from tpu_sandbox.train.trainer import publish_checkpoint
-
-MCFG = TransformerConfig(vocab_size=64, d_model=32, n_heads=4, n_layers=2,
-                         d_ff=64, max_len=128)
-CCFG = CacheConfig(num_blocks=24, block_size=4, max_blocks_per_seq=8)
-BLOCK = CCFG.block_size
-
-
-class _StubStep:
-    """DecodeStep stand-in: next token = (last + 1) % vocab, no jax."""
-
-    def __init__(self, buckets=(8, 16), vocab=64):
-        self.buckets = tuple(buckets)
-        self.vocab = vocab
-        self.prefill = {b: self._prefill for b in self.buckets}
-
-    def pick_bucket(self, plen):
-        for b in self.buckets:
-            if plen <= b:
-                return b
-        raise ValueError(f"prompt of {plen} exceeds buckets {self.buckets}")
-
-    def _prefill(self, params, k, v, toks, dest, last):
-        toks = np.asarray(toks)
-        logits = np.zeros((self.vocab,), np.float32)
-        logits[(int(toks[0, int(last)]) + 1) % self.vocab] = 1.0
-        return logits, k, v
-
-    def decode(self, params, k, v, tokens, lengths, tables):
-        tokens = np.asarray(tokens)
-        logits = np.zeros((tokens.shape[0], self.vocab), np.float32)
-        for i in range(tokens.shape[0]):
-            logits[i, (int(tokens[i, 0]) + 1) % self.vocab] = 1.0
-        return logits, k, v
-
-
-def _engine(**over):
-    cfg = ServeConfig(model=MCFG, cache=CCFG, max_batch=2, buckets=(8, 16),
-                      **over)
-    return ContinuousEngine(None, cfg, step=_StubStep(), clock=time.monotonic)
-
-
-@pytest.fixture
-def kv_pair():
-    from tpu_sandbox.runtime.kvstore import KVClient, KVServer
-
-    server = KVServer()
-    kv = KVClient(port=server.port)
-    clones = []
-
-    def clone():
-        c = kv.clone()
-        clones.append(c)
-        return c
-
-    yield server, kv, clone
-    for c in clones:
-        c.close()
-    kv.close()
-    server.stop()
-
 
 _SENTINEL_LOADER = object()
 

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from tests.helpers import kv_pair  # noqa: F401 (kv_pair: fixture)
 from tpu_sandbox.deploy.controller import DeployConfig, DeployController
 from tpu_sandbox.deploy.registry import (current_target, deploy_events,
                                          registry_versions)
@@ -61,26 +62,6 @@ def step():
 @pytest.fixture(scope="module")
 def fwd(model):
     return jax.jit(lambda params, toks: model.apply({"params": params}, toks))
-
-
-@pytest.fixture
-def kv_pair():
-    from tpu_sandbox.runtime.kvstore import KVClient, KVServer
-
-    server = KVServer()
-    kv = KVClient(port=server.port)
-    clones = []
-
-    def clone():
-        c = kv.clone()
-        clones.append(c)
-        return c
-
-    yield server, kv, clone
-    for c in clones:
-        c.close()
-    kv.close()
-    server.stop()
 
 
 def _params(seed):

@@ -18,6 +18,8 @@ from pathlib import Path
 import numpy as np
 import pytest
 
+from tests.helpers import assert_same_model
+
 pytestmark = pytest.mark.slow
 
 REPO = Path(__file__).resolve().parent.parent
@@ -53,12 +55,6 @@ def final_params(ckpt_dir):
     assert f.exists(), f"missing final checkpoint {f}"
     with np.load(f, allow_pickle=False) as z:
         return {k: z[k].copy() for k in z.files if k.startswith("leaf:")}
-
-
-def assert_same_model(a, b):
-    assert a.keys() == b.keys()
-    for k in a:
-        np.testing.assert_allclose(a[k], b[k], rtol=0, atol=1e-6, err_msg=k)
 
 
 def test_kill_rank_midepoch_restart_resume_loss_parity(tmp_path):

@@ -1,8 +1,10 @@
 """parallel/expert.py::ExpertShare and ops/pallas_grouped_matmul.py at a
-small size on the CPU (kernels interpreted): the share test of the
-model-configs guide, the drop rule, the layout's invariants, the grouped
-product against dense einsums, and the compiled step's HLO."""
+small size on the CPU (kernels interpreted): the drop rule, the layout's
+invariants, the grouped product against dense einsums, and the compiled
+step's HLO. The share test of the model-configs guide is
+tests/test_shares_add_up.py."""
 
+import functools
 import re
 import sys
 from pathlib import Path
@@ -30,11 +32,18 @@ def share(held, local_rows, shared=1, row_tile=8):
                        dtype=jnp.float32, row_tile=row_tile)
 
 
+def applied(layer, variables, x, mutable=False):
+    """``layer.apply`` as one compiled program, not one a primitive."""
+    return jax.jit(lambda v, x: layer.apply(v, x, mutable=mutable))(
+        variables, x)
+
+
+@functools.cache
 def whole_layer():
     """One layer holding all 16 experts, its variables, an input."""
     x = jax.random.normal(jax.random.key(0), (T, C))
     layer = share(range(E), local_rows=T * K)
-    variables = layer.init(jax.random.key(1), x)
+    variables = jax.jit(layer.init)(jax.random.key(1), x)
     bias = 0.05 * jax.random.normal(jax.random.key(2), (E,))
     variables = {"params": variables["params"], "batch_stats": {
         **variables["batch_stats"], "e_score_correction_bias": bias}}
@@ -59,33 +68,6 @@ REF_CFG = {"num_experts_per_tok": K, "routed_scaling_factor": 2.0,
            "n_shared_experts": 1}
 
 
-@pytest.mark.parametrize("n_held", [2, 4, 8])
-def test_the_shares_add_up_to_the_whole_layer(n_held):
-    """Held = 0-1, 2-3, ..., 14-15 (fewer experts than a token chooses: a
-    slot of the table a held expert), 0-3, ... (as many), 0-7, 8-15 (more: a
-    slot a choice): the shares' routed parts plus the shared expert counted
-    once are the uncut reference's whole layer."""
-    x, layer, variables = whole_layer()
-    want, _ = ref.expert_share(
-        reference_params(variables), x,
-        {**REF_CFG, "held": list(range(E)), "local_rows": T * K})
-    total = 0.0
-    for first in range(0, E, n_held):
-        held = tuple(range(first, first + n_held))
-        part = share(held, local_rows=T * K, shared=0).apply(
-            cut(variables, held), x)
-        ref_part, _ = ref.expert_share(
-            reference_params(cut(variables, held)), x,
-            {**REF_CFG, "held": list(held), "local_rows": T * K},
-            with_shared=False)
-        np.testing.assert_allclose(part, ref_part, atol=2e-5)
-        total = total + part
-    shared_only = (layer.apply(variables, x)
-                   - share(range(E), T * K, shared=0).apply(variables, x))
-    np.testing.assert_allclose(total + shared_only, want, atol=5e-5)
-    np.testing.assert_allclose(layer.apply(variables, x), want, atol=5e-5)
-
-
 def skewed(x, variables, expert, gain=50.0):
     """Variables whose router sends every token to ``expert`` first."""
     params = dict(variables["params"])
@@ -94,6 +76,7 @@ def skewed(x, variables, expert, gain=50.0):
         **variables["batch_stats"], "e_score_correction_bias": bias}}
 
 
+@pytest.mark.usefixtures("light_compile")
 @pytest.mark.parametrize("held", [(3, 5), (3, 5, 8, 9, 11)],
                          ids=["held<k", "k<held"])
 @pytest.mark.parametrize("local_rows,dropped", [(T * 2, 0), (T, 0), (T - 24, 24)])
@@ -105,7 +88,7 @@ def test_one_expert_takes_every_row_and_only_the_total_drops(
     x, _, variables = whole_layer()
     v = cut(skewed(x, variables, 3), held)
     layer = share(held, local_rows=local_rows)
-    y, mutated = layer.apply(v, x, mutable=["batch_stats", "intermediates"])
+    y, mutated = applied(layer, v, x, ["batch_stats", "intermediates"])
     sel = np.asarray(mutated["intermediates"]["sel"][0])
     assert (sel == 3).any(-1).all()
     total = int(np.isin(sel, held).sum())
@@ -150,7 +133,8 @@ HELD = {"held<k": (4, 12), "held=k": (1, 4, 7, 12),
 def test_layout_invariants(seed, local_rows, regime):
     held, tile = HELD[regime], 8
     sel = distinct_choices(seed)
-    lay = jax.tree.map(np.asarray, share_layout(sel, held, local_rows, tile))
+    lay = jax.tree.map(np.asarray, jax.jit(share_layout, static_argnums=(
+        1, 2, 3))(sel, held, local_rows, tile))
     p = local_rows + len(held) * tile
     group = lay["tile_group"]
     assert group.shape == (p // tile,) and (np.diff(group) >= 0).all()
@@ -336,8 +320,8 @@ def test_a_traced_share_counts_its_table(regime):
 
 def test_bias_and_counters_do_not_move_without_a_mutable_collection():
     x, layer, variables = whole_layer()
-    y = layer.apply(variables, x)
-    y2, mutated = layer.apply(variables, x, mutable=["batch_stats"])
+    y = applied(layer, variables, x)
+    y2, mutated = applied(layer, variables, x, ["batch_stats"])
     np.testing.assert_allclose(y, y2, atol=0)
     moved = (mutated["batch_stats"]["e_score_correction_bias"]
              - variables["batch_stats"]["e_score_correction_bias"])
@@ -350,6 +334,7 @@ def test_bias_and_counters_do_not_move_without_a_mutable_collection():
 # --- what PR 31 added: two-product experts, a router on another input, and
 # --- a router that builds nothing of tokens x choices x experts elements
 
+@pytest.mark.usefixtures("light_compile")
 @pytest.mark.parametrize("held", [tuple(range(E)), (1, 4)],
                          ids=["k<held", "held<k"])
 def test_relu2_experts_match_a_dense_loop(held):
@@ -360,23 +345,29 @@ def test_relu2_experts_match_a_dense_loop(held):
                         held=held, local_rows=T * K, n_shared_experts=2,
                         routed_scaling_factor=2.0, dtype=jnp.float32,
                         row_tile=8, kind="relu2")
-    variables = layer.init(jax.random.key(1), x)
+    variables = jax.jit(layer.init)(jax.random.key(1), x)
     p = variables["params"]
     assert "w_gate" not in p and "shared_gate" not in p
     assert p["shared_up"]["kernel"].shape == (C, 2 * F)
-    _, sel, w = ref.route({"router": p["router"]}, x, REF_CFG)
-    want = jnp.square(jax.nn.relu(x @ p["shared_up"]["kernel"])
-                      ) @ p["shared_down"]["kernel"]
-    for i, e in enumerate(held):
-        weight = jnp.where(sel == e, w, 0.0).sum(-1)
-        want = want + weight[:, None] * (
-            jnp.square(jax.nn.relu(x @ p["w_up"][i])) @ p["w_down"][i])
-    np.testing.assert_allclose(layer.apply(variables, x), want, atol=5e-5)
+
+    def dense_loop(p):
+        _, sel, w = ref.route({"router": p["router"]}, x, REF_CFG)
+        want = jnp.square(jax.nn.relu(x @ p["shared_up"]["kernel"])
+                          ) @ p["shared_down"]["kernel"]
+        for i, e in enumerate(held):
+            weight = jnp.where(sel == e, w, 0.0).sum(-1)
+            want = want + weight[:, None] * (
+                jnp.square(jax.nn.relu(x @ p["w_up"][i])) @ p["w_down"][i])
+        return want
+
+    np.testing.assert_allclose(applied(layer, variables, x),
+                               jax.jit(dense_loop)(p), atol=5e-5)
     with pytest.raises(ValueError, match="unknown expert kind"):
         ExpertShare(d_model=C, d_ff=F, n_routed_experts=E, top_k=K, held=held,
                     local_rows=T * K, kind="gelu").init(jax.random.key(0), x)
 
 
+@pytest.mark.usefixtures("light_compile")
 def test_the_router_may_score_another_input_than_the_experts_read():
     """``routed(x, route_on=u)``: experts in a latent of width C, scores from
     the full width 2 C, as a model that wraps the share calls it; against the
@@ -390,13 +381,13 @@ def test_the_router_may_score_another_input_than_the_experts_read():
                       held=held, local_rows=T * K, kind="relu2",
                       shared_width=3 * F, routed_scaling_factor=2.0,
                       dtype=jnp.float32, row_tile=8)
-    variables = layer.init(jax.random.key(1), u)
+    variables = jax.jit(layer.init)(jax.random.key(1), u)
     p = variables["params"]
     assert p["router"].shape == (2 * C, E) and p["w_up"].shape == (4, C, F)
     assert p["shared_up"]["kernel"].shape == (2 * C, 3 * F)
     want, _ = latent_ref.latent_moe(
         dict(p), u, {**REF_CFG, "held": list(held), "local_rows": T * K})
-    np.testing.assert_allclose(layer.apply(variables, u), want, atol=5e-5)
+    np.testing.assert_allclose(applied(layer, variables, u), want, atol=5e-5)
 
 
 def _shapes_of(jaxpr):

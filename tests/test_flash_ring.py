@@ -6,21 +6,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from tests.test_ring_attention import qkv, sp_mesh  # noqa: F401 (sp_mesh: fixture)
 from tpu_sandbox.ops.attention import causal_attention
 from tpu_sandbox.parallel.flash_ring import make_flash_ring_attention
 from tpu_sandbox.parallel.ring_attention import make_ring_attention
 from tpu_sandbox.runtime.mesh import make_mesh
 
-
-def qkv(b=2, s=32, h=2, d=8, seed=0):
-    ks = jax.random.split(jax.random.key(seed), 3)
-    shape = (b, s, h, d)
-    return tuple(jax.random.normal(k, shape) for k in ks)
-
-
-@pytest.fixture(scope="module")
-def sp_mesh():
-    return make_mesh({"sp": 8})
+# every claim here is a tolerance: conftest's cheaper compile
+pytestmark = pytest.mark.usefixtures("light_compile")
 
 
 def test_offset_lse_partials_merge_to_reference():

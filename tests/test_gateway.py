@@ -25,11 +25,11 @@ import json
 import os
 import socket
 import struct
-import threading
 import time
 
 import pytest
 
+from tests.helpers import StubStep, kv_pair, pumping  # noqa: F401 (kv_pair: fixture)
 from tpu_sandbox.gateway import routing, wire
 from tpu_sandbox.gateway.fleet import (FleetSpec, fleet_kv, fleet_namespace)
 from tpu_sandbox.gateway.server import (Gateway, k_gateway_hb,
@@ -47,34 +47,6 @@ MCFG = TransformerConfig(vocab_size=64, d_model=32, n_heads=4, n_layers=2,
                          d_ff=64, max_len=128)
 CCFG = CacheConfig(num_blocks=24, block_size=4, max_blocks_per_seq=8)
 BLOCK = CCFG.block_size
-
-
-class _StubStep:
-    """DecodeStep stand-in: next token = (last + 1) % vocab, no jax."""
-
-    def __init__(self, buckets=(8, 16), vocab=64):
-        self.buckets = tuple(buckets)
-        self.vocab = vocab
-        self.prefill = {b: self._prefill for b in self.buckets}
-
-    def pick_bucket(self, plen):
-        for b in self.buckets:
-            if plen <= b:
-                return b
-        raise ValueError(f"prompt of {plen} exceeds buckets {self.buckets}")
-
-    def _prefill(self, params, k, v, toks, dest, last):
-        toks = np.asarray(toks)
-        logits = np.zeros((self.vocab,), np.float32)
-        logits[(int(toks[0, int(last)]) + 1) % self.vocab] = 1.0
-        return logits, k, v
-
-    def decode(self, params, k, v, tokens, lengths, tables):
-        tokens = np.asarray(tokens)
-        logits = np.zeros((tokens.shape[0], self.vocab), np.float32)
-        for i in range(tokens.shape[0]):
-            logits[i, (int(tokens[i, 0]) + 1) % self.vocab] = 1.0
-        return logits, k, v
 
 
 def _view(tag, *, depth_chain=(), **kw):
@@ -195,7 +167,7 @@ def test_engine_load_report_carries_digest():
     eng = ContinuousEngine(
         None, ServeConfig(model=MCFG, cache=CCFG, max_batch=2,
                           buckets=(8, 16)),
-        step=_StubStep(), clock=time.monotonic)
+        step=StubStep(), clock=time.monotonic)
     prompt = list(range(1, 9))
     eng.submit(Request(rid="r0", prompt=prompt, max_new_tokens=2))
     eng.run_until_idle()
@@ -230,27 +202,7 @@ def test_frame_roundtrip_and_hostile_lengths():
 def _engine(**over):
     cfg = ServeConfig(model=MCFG, cache=CCFG, max_batch=2, buckets=(8, 16),
                       **over)
-    return ContinuousEngine(None, cfg, step=_StubStep(), clock=time.monotonic)
-
-
-@pytest.fixture
-def kv_pair():
-    from tpu_sandbox.runtime.kvstore import KVClient, KVServer
-
-    server = KVServer()
-    kv = KVClient(port=server.port)
-    clones = []
-
-    def clone():
-        c = kv.clone()
-        clones.append(c)
-        return c
-
-    yield server, kv, clone
-    for c in clones:
-        c.close()
-    kv.close()
-    server.stop()
+    return ContinuousEngine(None, cfg, step=StubStep(), clock=time.monotonic)
 
 
 def _worker(kv, **over):
@@ -259,27 +211,6 @@ def _worker(kv, **over):
     over.setdefault("lease_ttl", 1.0)
     over.setdefault("load_interval", 0.02)
     return ReplicaWorker(kv, _engine(), **over)
-
-
-@contextlib.contextmanager
-def _pumping(*workers):
-    """Tick workers from one background thread (each worker was built on
-    its own KV clone, so the main thread's client stays unshared)."""
-    stop = threading.Event()
-
-    def run():
-        while not stop.is_set():
-            for w in workers:
-                w.tick()
-            time.sleep(0.001)
-
-    t = threading.Thread(target=run, name="pump", daemon=True)
-    t.start()
-    try:
-        yield stop
-    finally:
-        stop.set()
-        t.join(timeout=10.0)
 
 
 def _gateway(kv, **over):
@@ -310,7 +241,7 @@ def _wait_for_report(kv, tag, timeout=10.0):
 def test_gateway_serves_end_to_end_over_socket(kv_pair):
     _, kv, clone = kv_pair
     w = _worker(clone(), tag="w0")
-    with _gateway(kv) as gw, _pumping(w):
+    with _gateway(kv) as gw, pumping(w):
         _wait_for_report(kv, "w0")
         with GatewayClient(gw.port) as client:
             assert client.submit("r0", [1, 2, 3], 3) is True
@@ -390,7 +321,7 @@ def test_no_fresh_reports_falls_back_to_shared_queue(kv_pair):
             assert kv.get(k_queue(0)) == b"r0"
             assert gw.stats.routed_shared == 1
             w = _worker(clone(), tag="late")
-            with _pumping(w):
+            with pumping(w):
                 got = client.result("r0", timeout=30.0)
     assert got["verdict"] == "ok" and got["tokens"] == [4, 5, 6]
 
@@ -411,7 +342,7 @@ def test_client_retries_shed_through_gateway(kv_pair):
             storm._publish_verdict("r0", {
                 "rid": "r0", "verdict": "SHED", "reason": "fault:shed_storm",
                 "replica": "storm"})
-            with _pumping(w):
+            with pumping(w):
                 got = client.result("r0", timeout=30.0)
     assert got["verdict"] == "ok" and got["tokens"] == [4, 5, 6]
     assert client.stats.retries == 1
@@ -426,7 +357,7 @@ def test_hedge_reroutes_away_from_first_replica(kv_pair):
     # "ghost" advertises the whole chain but will never claim anything
     _fake_report(kv, "ghost", digest=chain)
     w = _worker(clone(), tag="w1")
-    with _gateway(kv) as gw, _pumping(w):
+    with _gateway(kv) as gw, pumping(w):
         _wait_for_report(kv, "w1")
         with GatewayClient(gw.port, hedge_after=0.05) as client:
             assert client.submit("r0", prompt, 3) is True
@@ -442,7 +373,7 @@ def test_multi_fleet_isolation(kv_pair):
               FleetSpec(name="code", block_size=BLOCK)]
     wa = _worker(fleet_kv(clone(), "chat"), tag="wa")
     wb = _worker(fleet_kv(clone(), "code"), tag="wb")
-    with _gateway(kv, fleets=fleets) as gw, _pumping(wa, wb):
+    with _gateway(kv, fleets=fleets) as gw, pumping(wa, wb):
         _wait_for_report(fleet_kv(kv, "chat"), "wa")
         _wait_for_report(fleet_kv(kv, "code"), "wb")
         with GatewayClient(gw.port, fleet="chat") as ca, \
@@ -600,7 +531,7 @@ def test_auth_gate(kv_pair):
 def test_mid_request_disconnect_strands_nothing(kv_pair):
     _, kv, clone = kv_pair
     w = _worker(clone(), tag="w0")
-    with _gateway(kv) as gw, _pumping(w):
+    with _gateway(kv) as gw, pumping(w):
         _wait_for_report(kv, "w0")
         c = GatewayClient(gw.port)
         assert c.submit("r0", [1, 2, 3], 3) is True
@@ -744,7 +675,7 @@ def test_loaded_gateway_accounts_for_every_request(kv_pair, arm, policy,
                         service_rate_rps=2.0 if doors else 1e3,
                         occupancy_bound=2)]
     with _gateway(kv, fleets=fleets, policy=policy, admission=admission,
-                  policy_seed=7) as gw, _pumping(*workers):
+                  policy_seed=7) as gw, pumping(*workers):
         for w in workers:
             _wait_for_report(kv, w.tag)
         with GatewayClient(gw.port, deadline_s=1.0 if doors else None,
@@ -844,7 +775,7 @@ def test_client_fails_over_when_gateway_killed(kv_pair):
     g1 = _gateway(kv, gateway_id="gw1", hb_ttl=0.4)
     client = None
     try:
-        with _pumping(w):
+        with pumping(w):
             _wait_for_report(kv, "w0")
             client = GatewayClient(
                 endpoints=[("127.0.0.1", g0.port), ("127.0.0.1", g1.port)],
@@ -884,6 +815,9 @@ def test_failover_submit_repolls_verdict_never_reexecutes(kv_pair):
             {"rid": "done-rid", "verdict": "ok", "tokens": [9, 9],
              "replica": "w9"}))
         assert kv.add(k_done("done-rid")) == 1
+        # one op served: gw0 holds the connection it is about to drop (a
+        # kill before its accept ran would leave the socket alive)
+        client.gateway_stats()
         g0.kill()  # the client's socket dies with it
         assert client.submit("done-rid", [1, 2, 3], 2) is True
         assert client.stats.failovers >= 1
@@ -905,7 +839,7 @@ def test_tls_round_trip_and_plaintext_refused(kv_pair):
     loop untouched."""
     _, kv, clone = kv_pair
     w = _worker(clone(), tag="w0")
-    with _gateway(kv, tls=_server_ctx(), token="s3cr3t") as g, _pumping(w):
+    with _gateway(kv, tls=_server_ctx(), token="s3cr3t") as g, pumping(w):
         _wait_for_report(kv, "w0")
         with GatewayClient(g.port, token="s3cr3t",
                            tls=_client_ctx()) as client:
@@ -966,7 +900,7 @@ def test_requests_stamped_with_gateway_id(kv_pair):
 
     _, kv, clone = kv_pair
     w = _worker(clone(), tag="w0")
-    with _gateway(kv, gateway_id="gw-stamp") as g, _pumping(w):
+    with _gateway(kv, gateway_id="gw-stamp") as g, pumping(w):
         _wait_for_report(kv, "w0")
         with GatewayClient(g.port) as client:
             assert client.submit("r0", [1, 2, 3], 3) is True

@@ -179,7 +179,8 @@ def test_gateway_process_sigkilled_mid_load_over_tls_loses_nothing():
     process, and the gateway the client is connected to SIGKILLed
     half-way through the submits: the client fails over to the survivor
     and every request still ends in exactly one ``ok`` verdict."""
-    from tests.test_gateway import TLSDIR, _pumping, _wait_for_report, _worker
+    from tests.helpers import pumping
+    from tests.test_gateway import TLSDIR, _wait_for_report, _worker
     from tpu_sandbox.gateway.client import GatewayClient
     from tpu_sandbox.gateway.wire import make_client_ssl_context
     from tpu_sandbox.runtime.kvstore import KVClient, KVServer
@@ -206,7 +207,7 @@ def test_gateway_process_sigkilled_mid_load_over_tls_loses_nothing():
                        .split()[0].rsplit(":", 1)[1])
             procs[port] = proc
             endpoints.append(("127.0.0.1", port))
-        with _pumping(*workers):
+        with pumping(*workers):
             for w in workers:
                 _wait_for_report(kv, w.tag)
             with GatewayClient(

@@ -15,6 +15,7 @@ The correctness bar, per mode:
     ~4x payload reductions the modes exist for.
 """
 
+import functools
 import os
 import sys
 
@@ -37,6 +38,11 @@ from tpu_sandbox.train.checkpoint import ShardedCheckpoint
 WORLD = 8
 
 
+#: for the tests whose claim is a tolerance or a convergence bound
+light = pytest.mark.usefixtures("light_compile")
+
+
+@functools.cache  # nothing donates or mutates what it returns
 def setup(lr=0.05, momentum=0.0, use_bn=False):
     model = ConvNet(use_bn=use_bn)
     tx = optax.sgd(lr, momentum=momentum) if momentum else optax.sgd(lr)
@@ -113,6 +119,7 @@ def test_int8_block_pmean_error_bound(mesh8):
     assert float(np.abs(out[0] - exact).max()) < bound
 
 
+@light
 def test_int8_error_feedback_telescopes(mesh8):
     """Sum over steps of (compressed mean) + final residual/WORLD ==
     sum of exact means, to fp32 roundoff: the residual carries exactly
@@ -127,10 +134,10 @@ def test_int8_error_feedback_telescopes(mesh8):
     def body(v, res):
         return policy.pmean(v[0], "data", WORLD, res[0])
 
-    run = jax.shard_map(
+    run = jax.jit(jax.shard_map(  # one program, not one a primitive a rank
         lambda v, r: tuple(x[None] for x in body(v, r)),
         mesh=mesh8, in_specs=(P("data"), P("data")),
-        out_specs=(P("data"), P("data")), check_vma=False)
+        out_specs=(P("data"), P("data")), check_vma=False))
 
     res = jnp.zeros((WORLD, 19, 53), jnp.float32)
     got = np.zeros((19, 53), np.float64)
@@ -176,6 +183,7 @@ def test_none_mode_bitwise_identical(mesh8):
     assert jax.tree.leaves(s_comp.grad_residual) == []
 
 
+@light
 def test_bf16_mode_tracks_fp32(mesh8):
     model, tx, state, images, labels = setup()
     ref = DataParallel(model, tx, mesh8, donate=False)
@@ -190,6 +198,7 @@ def test_bf16_mode_tracks_fp32(mesh8):
         s_ref.params)
 
 
+@light
 @pytest.mark.parametrize(
     "block",
     [256,

@@ -5,7 +5,6 @@ verdict) without the jax-importing workers of the slow
 test_multihost_elastic_integration module."""
 
 import json
-import subprocess
 import sys
 import threading
 import time

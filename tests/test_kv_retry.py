@@ -67,7 +67,8 @@ def test_connect_retries_until_server_appears():
         assert kv.try_get("hello") == b"world"
         kv.close()
     finally:
-        t.join()
+        t.join(timeout=10)
+        assert not t.is_alive(), "the late server never started"
         started["server"].stop()
 
 
@@ -166,7 +167,8 @@ def test_read_survives_server_restart_on_same_port():
         assert got == b"4"
         assert kv.keys("elastic/") == ["elastic/generation"]
     finally:
-        t.join()
+        t.join(timeout=10)
+        assert not t.is_alive(), "the restarted server never came up"
         second["srv"].stop()
         kv.close()
 

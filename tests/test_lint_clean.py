@@ -18,11 +18,11 @@ Three layers:
 
 import json
 import os
-import subprocess
 import sys
 
 import pytest
 
+from tests.helpers import run_child
 from tpu_sandbox.analysis import (
     BaselineError,
     apply_baseline,
@@ -411,10 +411,9 @@ def _run_graftlint(*extra):
     """graftlint in a subprocess: the AOT tools mutate process env
     (forced compiled Pallas kernels), so pass 2's compile layer must
     never run inside this long-lived pytest process."""
-    proc = subprocess.run(
+    proc = run_child(
         [sys.executable, os.path.join(ROOT, "tools", "graftlint.py"),
-         "--all", "--json", *extra],
-        capture_output=True, text=True, timeout=600, cwd=ROOT,
+         "--json", *extra], timeout=150, cwd=ROOT,
         env={**os.environ, "JAX_PLATFORMS": "cpu"},
     )
     assert proc.returncode == 0, (
@@ -422,21 +421,28 @@ def _run_graftlint(*extra):
     return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
-def test_graftlint_cli_traces_all_steps():
+@pytest.mark.parametrize("steps,traced", [
+    ("dp,zero,pjit,pipeline,dp-int8,dp-overlap,sp",
+     ("dp", "zero", "pjit", "pipeline", "dp-int8", "dp-overlap", "sp")),
+    ("decode,prefill,fsdp,tp,ep",
+     ("decode", "prefill", "prefill-b16", "fsdp", "tp", "ep")),
+    ("mpmd", ("mpmd-s0-fwd", "mpmd-s0-bwd", "mpmd-s1-loss_grad"))],
+    ids=["engines", "serve_and_pjit_layouts", "mpmd"])
+def test_graftlint_cli_traces_all_steps(steps, traced):
     """Tier-1 half of the CLI gate: all three passes, jaxpr-tracing the
     real DP/ZeRO/pjit/pipeline steps — plus the engine-flag variants
     (int8 grad compress, bucketed overlap), SeqParallel, and the serve
-    decode + bucketed-prefill steps — on CPU. The AOT compiles are skipped here (`--no-aot`)
-    to keep tier-1 inside its time budget — the full chipless AOT receipt
-    runs in the slow twin below."""
-    report = _run_graftlint("--no-aot")
+    decode + bucketed-prefill steps — on CPU, a third of the steps a child
+    (together they are `--steps`' default). The AOT compiles are skipped
+    here (`--no-aot`) to keep tier-1 inside its time budget — the full
+    chipless AOT receipt runs in the slow twin below."""
+    first = steps.startswith("dp,")  # the AST passes run once, with these
+    report = _run_graftlint(*(["--all"] if first else ["--pass", "hlo"]),
+                            "--no-aot", "--steps", steps)
     assert report["findings"] == 0
-    assert report["unused_suppressions"] == 0
+    assert not first or report["unused_suppressions"] == 0
     hlo = report["hlo"]
-    for step in ("dp", "zero", "pjit", "pipeline", "dp-int8",
-                 "dp-overlap", "sp", "decode", "prefill", "prefill-b16",
-                 "fsdp", "tp", "ep", "mpmd-s0-fwd", "mpmd-s0-bwd",
-                 "mpmd-s1-loss_grad"):
+    for step in traced:
         assert hlo[step]["status"] == "traced", hlo
 
 
@@ -446,7 +452,7 @@ def test_graftlint_cli_full_run_including_aot():
     chipless v5e topology and verifies donation, overlap scheduling, and
     int8 wire padding. Skips gracefully where the toolchain can't build
     topologies."""
-    report = _run_graftlint()
+    report = _run_graftlint("--all")
     assert report["findings"] == 0
     aot = report["hlo"]["aot"]
     if aot.get("status") == "skipped":

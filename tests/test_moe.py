@@ -8,10 +8,14 @@ import optax
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from tests.test_pipeline import lm_batch
 from tpu_sandbox.models.transformer import TransformerConfig, TransformerLM
 from tpu_sandbox.parallel.expert import MoeMlp
 from tpu_sandbox.parallel.pjit_engine import PjitEngine
 from tpu_sandbox.runtime.mesh import make_mesh
+
+# every claim here is a tolerance: conftest's cheaper compile
+pytestmark = pytest.mark.usefixtures("light_compile")
 
 CFG = TransformerConfig(
     vocab_size=64, d_model=32, n_heads=2, n_layers=1, d_ff=64, max_len=64,
@@ -133,13 +137,6 @@ def test_moe_capacity_overflow_drops_tokens():
 
 def moe_model_ctor():
     return TransformerLM(CFG, mlp_cls=MoeMlp)
-
-
-def lm_batch(b=8, s=16, seed=0):
-    rng = np.random.default_rng(seed)
-    tokens = rng.integers(0, CFG.vocab_size, size=(b, s)).astype(np.int32)
-    targets = ((tokens + 7) % CFG.vocab_size).astype(np.int32)
-    return tokens, targets
 
 
 def test_moe_transformer_trains():

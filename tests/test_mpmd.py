@@ -1,14 +1,15 @@
-"""MPMD pipeline vs the SPMD baseline: bitwise parity, fault recovery,
-and the disaggregated prefill/decode handoff.
+"""MPMD pipeline vs the SPMD baseline: parity to rounding, fault recovery
+to the bit, and the disaggregated prefill/decode handoff.
 
-The parity contract (see mpmd/program.py): trained *parameters* are
-bitwise identical to PipelineParallel on a ``{'data': 1, 'pipe': S}``
-mesh over >= 20 steps; the reported *loss* may differ by ~1 ulp on some
-steps (XLA may regroup the CE-mean reduction across the two
-compilations), so losses are compared to 1e-6. Recovery must land on the
-SAME bits as the unfaulted run with every slot claimed exactly once per
-generation — a microbatch applied twice or dropped shows up here, not in
-a flaky convergence plot.
+The parity contract: the per-stage programs compute PipelineParallel's
+step on a ``{'data': 1, 'pipe': S}`` mesh — the same sums, in two sets of
+compiled programs, so parameters and losses agree to rounding over 21 adam
+steps and not to the bit (the limits and what was measured are at
+``test_mpmd_parity_with_spmd_to_rounding``). Between two runs of the SAME
+programs bitwise is the right claim: recovery must land on the bits of the
+unfaulted MPMD run with every slot claimed exactly once per generation — a
+microbatch applied twice or dropped shows up here, not in a flaky
+convergence plot.
 """
 
 import numpy as np
@@ -18,6 +19,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from tests.helpers import ulps_apart
 from tpu_sandbox.models.transformer import TransformerConfig
 from tpu_sandbox.mpmd import MPMDPipeline
 from tpu_sandbox.parallel.pipeline import PipelineParallel
@@ -27,12 +29,33 @@ CFG = TransformerConfig(vocab_size=64, d_model=32, n_heads=2, n_layers=4,
                         d_ff=64, max_len=64)
 M = 4
 STEPS = 21
+#: parameters within this many ulps of their leaf's largest entry, losses
+#: within this much, of the SPMD run's (measured over six seeds at this
+#: size: 1225 ulps, 3.6e-6; a microbatch's gradient halved: 1e7 ulps, 0.05)
+PARAM_ULPS, LOSS_ATOL = 8192, 2e-5
 
 
 def _batch():
     rng = np.random.default_rng(0)
     tokens = rng.integers(0, 64, size=(8, 16)).astype(np.int32)
     return tokens, ((tokens + 7) % 64).astype(np.int32)
+
+
+def _without_key_bias(params):
+    """The key bias moves every score of a row alike and softmax forgets
+    it: its gradient is zero but for rounding, and adam steps a full
+    learning rate along whatever sign the rounding has. No two programs
+    agree on it, and no loss depends on it."""
+    def drop(path, leaf):
+        on_qkv_bias = jax.tree_util.keystr(path).endswith("['qkv']['bias']")
+        return np.asarray(leaf)[[0, 2]] if on_qkv_bias else leaf
+    return jax.tree_util.tree_map_with_path(drop, params)
+
+
+def _apart_from_spmd(spmd_ref, params, losses):
+    return (ulps_apart(_without_key_bias(params),
+                       _without_key_bias(spmd_ref["params"])),
+            float(np.abs(np.subtract(losses, spmd_ref["losses"])).max()))
 
 
 def _assert_trees_bitwise(ref, got):
@@ -66,17 +89,22 @@ def spmd_ref():
             "losses": losses}
 
 
-def test_mpmd_bitwise_parity_with_spmd(spmd_ref):
-    """Two separate per-stage programs on two single-device meshes,
-    activations/grads over the transport — same bits as the fused SPMD
-    program after 21 adam steps."""
-    tokens, targets = _batch()
+@pytest.fixture(scope="module")
+def mpmd_clean(spmd_ref):
+    """The unfaulted MPMD run: two per-stage programs on two single-device
+    meshes, activations/grads over the transport."""
     pipe = MPMDPipeline(CFG, optax.adam(1e-2), n_stages=2, microbatches=M,
                         devices=jax.devices()[2:4])
     pipe.init_from_flat(spmd_ref["flat"])
-    losses = pipe.train(STEPS, tokens, targets)
-    _assert_trees_bitwise(spmd_ref["params"], pipe.merged_params())
-    np.testing.assert_allclose(losses, spmd_ref["losses"], rtol=0, atol=1e-6)
+    return pipe, pipe.train(STEPS, *_batch())
+
+
+def test_mpmd_parity_with_spmd_to_rounding(spmd_ref, mpmd_clean):
+    """Same step as the fused SPMD program after 21 adam steps, to
+    ``PARAM_ULPS`` and ``LOSS_ATOL`` (the key bias aside)."""
+    pipe, losses = mpmd_clean
+    ulps, loss_gap = _apart_from_spmd(spmd_ref, pipe.merged_params(), losses)
+    assert ulps <= PARAM_ULPS and loss_gap <= LOSS_ATOL, (ulps, loss_gap)
     # each stage ran its own program: the wire actually carried payloads
     s = pipe.transport.stats
     assert s.puts == s.gets > 0 and s.bytes_out == s.bytes_in > 0
@@ -86,11 +114,33 @@ def test_mpmd_bitwise_parity_with_spmd(spmd_ref):
     assert claims and all(v == 1 for v in claims.values())
 
 
-def test_mpmd_stage_kill_recovers_bitwise(spmd_ref, tmp_path):
+def test_mpmd_parity_limits_catch_a_halved_microbatch(spmd_ref, monkeypatch):
+    """The planted fault: every stage counts its last microbatch's gradient
+    half. Both limits of the parity test fail on it by orders."""
+    from tpu_sandbox.mpmd import driver
+
+    whole = driver.accumulate_descending
+
+    def halves_the_last(per_mb):
+        last = max(per_mb)
+        return whole({**per_mb, last: jax.tree.map(
+            lambda g: np.asarray(g) * 0.5, per_mb[last])})
+
+    monkeypatch.setattr(driver, "accumulate_descending", halves_the_last)
+    pipe = MPMDPipeline(CFG, optax.adam(1e-2), n_stages=2, microbatches=M,
+                        devices=jax.devices()[2:4])
+    pipe.init_from_flat(spmd_ref["flat"])
+    losses = pipe.train(STEPS, *_batch())
+    ulps, loss_gap = _apart_from_spmd(spmd_ref, pipe.merged_params(), losses)
+    assert ulps > 100 * PARAM_ULPS and loss_gap > 100 * LOSS_ATOL
+
+
+def test_mpmd_stage_kill_recovers_bitwise(spmd_ref, mpmd_clean, tmp_path):
     """Stage 1 dies mid-step (between two transport ops); the driver
     respawns it at generation 1, it restores its own checkpoint and
-    replays from durable slots. End state: bitwise the unfaulted params,
-    no microbatch lost or double-applied."""
+    replays from durable slots. End state: bitwise the unfaulted MPMD
+    run's params and losses (the same programs ran), within the parity
+    limits of the SPMD run's, no microbatch lost or double-applied."""
     tokens, targets = _batch()
     pipe = MPMDPipeline(CFG, optax.adam(1e-2), n_stages=2, microbatches=M,
                         devices=jax.devices()[4:6], ckpt_root=str(tmp_path),
@@ -98,9 +148,11 @@ def test_mpmd_stage_kill_recovers_bitwise(spmd_ref, tmp_path):
     pipe.init_from_flat(spmd_ref["flat"])
     pipe.workers[1].fail_at = (7, 3)  # step 7, mid-schedule op
     losses = pipe.train(STEPS, tokens, targets, recover=True)
-    _assert_trees_bitwise(spmd_ref["params"], pipe.merged_params())
-    assert len(losses) == STEPS
-    np.testing.assert_allclose(losses, spmd_ref["losses"], rtol=0, atol=1e-6)
+    clean, clean_losses = mpmd_clean
+    _assert_trees_bitwise(clean.merged_params(), pipe.merged_params())
+    assert losses == clean_losses
+    ulps, loss_gap = _apart_from_spmd(spmd_ref, pipe.merged_params(), losses)
+    assert ulps <= PARAM_ULPS and loss_gap <= LOSS_ATOL, (ulps, loss_gap)
     # the relaunch actually happened and replayed under a new generation
     assert pipe.workers[1].generation == 1
     # zero duplicate deliveries across BOTH generations

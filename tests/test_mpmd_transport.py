@@ -11,6 +11,7 @@ bitwise replay parity rests on.
 import numpy as np
 import pytest
 
+from tests.helpers import kv_pair  # noqa: F401 (fixture)
 from tpu_sandbox.mpmd.schedule import (
     bubble_fraction,
     fetch_plan,
@@ -27,22 +28,11 @@ from tpu_sandbox.mpmd.transport import (
 )
 
 
-@pytest.fixture
-def kv_pair():
-    from tpu_sandbox.runtime.kvstore import KVClient, KVServer
-
-    server = KVServer()
-    kv = KVClient(port=server.port)
-    yield server, kv
-    kv.close()
-    server.stop()
-
-
 @pytest.fixture(params=["local", "kv"])
 def transport(request, kv_pair):
     if request.param == "local":
         return LocalTransport()
-    _, kv = kv_pair
+    _, kv, _ = kv_pair
     return KVTransport(kv, prefix="mpmd/pipe0")
 
 
@@ -132,7 +122,7 @@ def test_claim_once_per_generation(transport):
 
 
 def test_kv_transport_chunks_large_payload(kv_pair):
-    _, kv = kv_pair
+    _, kv, _ = kv_pair
     tr = KVTransport(kv, prefix="mpmd/pipe0", chunk_bytes=1024)
     big = np.arange(5000, dtype=np.float64)  # 40000 bytes -> 40 chunks
     assert tr.put("act0", 0, 0, [big]) is True
@@ -147,7 +137,7 @@ def test_kv_transport_finishes_dead_writers_slot(kv_pair):
     """Commit claimed, meta never landed (writer died mid-put): the
     replayer loses the claim but completes the slot with its own
     deterministic bytes."""
-    _, kv = kv_pair
+    _, kv, _ = kv_pair
     tr = KVTransport(kv, prefix="mpmd/pipe0")
     kv.add("mpmd/pipe0/mpmd/slot/act0/0/0/commit", 1)  # the dead writer
     arrays = _arrays()
@@ -157,7 +147,7 @@ def test_kv_transport_finishes_dead_writers_slot(kv_pair):
 
 
 def test_kv_transport_prefix_isolation(kv_pair):
-    _, kv = kv_pair
+    _, kv, _ = kv_pair
     t0 = KVTransport(kv, prefix="mpmd/pipe0")
     t1 = KVTransport(kv, prefix="mpmd/pipe1")
     t0.put("act0", 0, 0, _arrays())
@@ -166,7 +156,7 @@ def test_kv_transport_prefix_isolation(kv_pair):
 
 
 def test_kv_transport_rejects_oversized_chunks(kv_pair):
-    _, kv = kv_pair
+    _, kv, _ = kv_pair
     with pytest.raises(ValueError, match="read cap"):
         KVTransport(kv, chunk_bytes=1 << 20)
 
@@ -215,7 +205,7 @@ def test_bubble_fraction_formula():
 
 
 def test_publish_fetch_plan_roundtrip(kv_pair):
-    _, kv = kv_pair
+    _, kv, _ = kv_pair
     publish_plan(kv, n_stages=2, microbatches=4, steps=10, seed=7,
                  prefix="mpmd/pipe0", extra={"model": {"d_model": 32}})
     plan = fetch_plan(kv, prefix="mpmd/pipe0")

@@ -199,6 +199,9 @@ def test_kvstore_multiprocess_rendezvous():
         results = [q.get(timeout=15) for _ in range(3)]
         for p in procs:
             p.join(timeout=5)
+            if p.is_alive():
+                p.kill()
+                pytest.fail(f"rank {p.name} outlived its rendezvous")
     assert all(got == [0, 1, 2] for _, got in results), results
 
 

@@ -23,8 +23,8 @@ from tpu_sandbox.obs import (ENV_TRACE_DIR, MetricsRegistry, Recorder,
                              reset_recorder)
 from tpu_sandbox.obs.record import ENV_PROC_NAME
 
-from tests.test_gateway import (_gateway, _pumping, _wait_for_report,
-                                _worker, kv_pair)  # noqa: F401 (fixture)
+from tests.helpers import kv_pair, pumping  # noqa: F401 (kv_pair: fixture)
+from tests.test_gateway import _gateway, _wait_for_report, _worker
 
 
 @pytest.fixture
@@ -297,7 +297,7 @@ def test_gateway_metrics_scrape_over_socket(kv_pair, traced):
 
     _, kv, clone = kv_pair
     w = _worker(clone(), tag="w0")
-    with _gateway(kv) as gw, _pumping(w):
+    with _gateway(kv) as gw, pumping(w):
         _wait_for_report(kv, "w0")
         with GatewayClient(gw.port) as client:
             assert client.submit("m0", [1, 2, 3], 2) is True
@@ -330,7 +330,7 @@ def test_trace_completeness_two_replica_fleet(kv_pair, traced):
     _, kv, clone = kv_pair
     w0 = _worker(clone(), tag="w0")
     w1 = _worker(clone(), tag="w1")
-    with _gateway(kv) as gw, _pumping(w0, w1):
+    with _gateway(kv) as gw, pumping(w0, w1):
         _wait_for_report(kv, "w0")
         _wait_for_report(kv, "w1")
         get_recorder().calibrate(kv, rounds=3)

@@ -3,8 +3,8 @@ PrefetchLoader + tools/hlo_schedule.py).
 
 The correctness bar:
   - bucket planning is a pure, total function of (sizes, target, dtypes);
-  - the bucketed sync with overlap ON and grad_compress='none' is
-    BITWISE the monolithic engine — bucketing reorders collectives, never
+  - the bucketed sync with overlap ON and grad_compress='none' is the
+    monolithic engine to a few ulps — bucketing reorders collectives, never
     values (and with overlap off the code path is literally the old one);
   - int8 + per-bucket error feedback still converges like fp32 (the PR-3
     acceptance bound, now with bucket-local residual blocks);
@@ -22,45 +22,23 @@ import threading
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from tests.helpers import ulps_apart
+from tests.test_grad_compress import _run_steps, setup
 from tpu_sandbox.data import synthetic_mnist
 from tpu_sandbox.data.loader import BatchLoader, PrefetchLoader
-from tpu_sandbox.data.mnist import normalize
-from tpu_sandbox.models import ConvNet
 from tpu_sandbox.parallel import (
-    CompressedAllReduce,
     DataParallel,
     PjitEngine,
     plan_buckets,
 )
-from tpu_sandbox.train import TrainState
 
 WORLD = 8
 
 sys.path.insert(0, os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "tools"))
-
-
-def setup(lr=0.05, momentum=0.0):
-    model = ConvNet(use_bn=False)
-    tx = optax.sgd(lr, momentum=momentum) if momentum else optax.sgd(lr)
-    state = TrainState.create(
-        model, jax.random.key(0), jnp.zeros((1, 28, 28, 1)), tx)
-    images, labels = synthetic_mnist(n=16, seed=0)
-    return model, tx, state, normalize(images), labels.astype("int32")
-
-
-def _run_steps(dp, state, images, labels, n_steps):
-    dstate = dp.shard_state(state)
-    di, dl = dp.shard_batch(images, labels)
-    losses = []
-    for _ in range(n_steps):
-        dstate, loss = dp.train_step(dstate, di, dl)
-        losses.append(float(jnp.mean(loss)))
-    return dstate, losses
 
 
 # -- bucket planning --------------------------------------------------------
@@ -97,25 +75,46 @@ def test_plan_buckets_validation():
 # -- DataParallel wiring ----------------------------------------------------
 
 
-def test_overlap_none_bitwise_identical(mesh8):
+class _DropsOneBucket:
+    """The planted fault: the first bucket issued never meets its peers."""
+    needs_residual = False
+
+    def __init__(self):
+        self.issued = 0
+
+    def pmean(self, buf, axis_name, size, residual):
+        self.issued += 1
+        return (buf if self.issued == 1 else jax.lax.pmean(buf, axis_name),
+                residual)
+
+
+@pytest.mark.parametrize("fault", [None, "dropped_bucket"])
+def test_overlap_none_agrees_to_rounding(mesh8, fault, monkeypatch):
     """Bucketed sync with 'none' compression is a plain pmean over each
-    flat bucket — elementwise, so the whole training trajectory must be
-    byte-for-byte the monolithic engine's. bucket_mb is sized so the
-    ~116KB ConvNet grad really splits into several buckets."""
+    flat bucket — the same sums, so the training trajectory is the
+    monolithic engine's to rounding. Not to the bit: they are two compiled
+    programs, and XLA:CPU is free to order the gradients' sums differently
+    in each. Measured over six seeds at this size: parameters 1 ulp of
+    their leaf's largest entry apart after 3 steps, losses 1 ulp; held to
+    4. A bucket that misses its all-reduce is thousands apart. bucket_mb is
+    sized so the ~116KB ConvNet grad really splits into several buckets."""
+    if fault:
+        from tpu_sandbox.parallel import buckets
+        monkeypatch.setattr(buckets, "as_compress_policy",
+                            lambda _: _DropsOneBucket())
     model, tx, state, images, labels = setup(momentum=0.9)
     base = DataParallel(model, tx, mesh8, donate=False)
     over = DataParallel(model, tx, mesh8, donate=False,
                         overlap_grad_sync=True, bucket_mb=0.02)
     s_base, l_base = _run_steps(base, state, images, labels, 3)
     s_over, l_over = _run_steps(over, state, images, labels, 3)
-    assert l_over == l_base
-    jax.tree.map(
-        lambda a, b: np.testing.assert_array_equal(
-            np.asarray(a), np.asarray(b)),
-        s_over.params, s_base.params)
+    apart = max(ulps_apart(s_over.params, s_base.params),
+                ulps_apart(np.float32(l_over), np.float32(l_base)))
+    assert (apart > 1000) if fault else (apart <= 4), apart
     assert s_over.grad_residual is None
 
 
+@pytest.mark.usefixtures("light_compile")
 def test_overlap_int8_ef_convergence(mesh8):
     """PR-3's acceptance bound survives bucketing: int8 with PER-BUCKET
     error-feedback residuals lands on the fp32 final loss (5e-2 relative,
@@ -160,19 +159,26 @@ def test_overlap_zero_composes(mesh8):
         s_zero.params, s_plain.params)
 
 
-def test_bucketed_hlo_splits_the_collective(mesh8):
-    """The compiled step carries one all-reduce PER BUCKET (the barrier
-    chain in sync_buckets keeps the combiner from re-merging them);
-    ~116KB of ConvNet grads at a 0.02MB target is 4 buckets."""
-    from hlo_schedule import build_overlapped_hlo, schedule_report
+def test_bucketed_step_lowers_to_one_collective_a_bucket(mesh8):
+    """Where the program decides them — the lowered module, before any XLA
+    pass — the step carries one all-reduce PER BUCKET, chained by the
+    barriers of sync_buckets; ~116KB of ConvNet grads at a 0.02MB target is
+    4 buckets. What a backend's combiner then makes of them is its own
+    (XLA:CPU merges both forms into one all-reduce); on the TPU it is the
+    receipt of tools/hlo_schedule.py."""
+    from hlo_schedule import lower_overlapped_step, schedule_report
 
-    devs = np.array(jax.devices()[:WORLD])
-    bucketed = schedule_report(build_overlapped_hlo(devs, bucket_mb=0.02))
-    mono = schedule_report(build_overlapped_hlo(devs, overlap=False))
+    def lowered_hlo(**step):
+        lowered = lower_overlapped_step(
+            np.array(jax.devices()[:WORLD]), **step)
+        return lowered.compiler_ir(dialect="hlo").as_hlo_text()
+
+    bucketed_hlo = lowered_hlo(bucket_mb=0.02)
+    bucketed = schedule_report(bucketed_hlo)
+    mono = schedule_report(lowered_hlo(overlap=False))
     assert bucketed["collective_count"] == 4
-    # the monolithic path syncs per leaf (6 ConvNet grads; XLA:CPU runs no
-    # combiner) — on TPU the combiner merges those into ONE all-reduce,
-    # which is exactly what the barrier chain stops it doing to buckets
+    assert bucketed_hlo.count(" opt-barrier(") == 3  # the chain between them
+    # the monolithic path syncs per leaf (6 ConvNet grads)
     assert mono["collective_count"] == 6
     # same payload either way: bucketing splits bytes, never adds any
     assert bucketed["comm_bytes_total"] == mono["comm_bytes_total"]

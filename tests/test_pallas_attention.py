@@ -6,6 +6,8 @@ ops.attention.causal_attention which is itself torch-verified via the
 transformer tests.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -25,6 +27,7 @@ def _rand_qkv(b=2, s=256, h=2, d=32, seed=0):
     )
 
 
+@pytest.mark.usefixtures("light_compile")
 @pytest.mark.parametrize("causal", [True, False])
 def test_forward_matches_reference(causal):
     q, k, v = _rand_qkv()
@@ -34,6 +37,7 @@ def test_forward_matches_reference(causal):
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.usefixtures("light_compile")
 def test_forward_unaligned_seq_and_headdim():
     # S=200 pads to 256, D=24 pads to the 128 lane tile
     q, k, v = _rand_qkv(s=200, d=24, seed=1)
@@ -43,6 +47,7 @@ def test_forward_unaligned_seq_and_headdim():
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.usefixtures("light_compile")
 @pytest.mark.parametrize("causal", [True, False])
 def test_gradients_match_reference(causal):
     q, k, v = _rand_qkv(s=128, d=16, seed=2)
@@ -57,8 +62,8 @@ def test_gradients_match_reference(causal):
     def loss_ref(q, k, v):
         return jnp.sum(causal_attention(q, k, v, causal=causal) * w)
 
-    g_flash = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    g_flash = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
+    g_ref = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
     for gf, gr, name in zip(g_flash, g_ref, "qkv"):
         np.testing.assert_allclose(
             np.asarray(gf), np.asarray(gr), rtol=5e-5, atol=5e-5,
@@ -66,6 +71,7 @@ def test_gradients_match_reference(causal):
         )
 
 
+@pytest.mark.usefixtures("light_compile")
 def test_gradients_unaligned_seq_and_headdim():
     """Backward through the padding path: S=200 pads to 256 (zero-cotangent
     padded rows), D=24 pads to the 128-lane tile."""
@@ -80,8 +86,8 @@ def test_gradients_unaligned_seq_and_headdim():
     def loss_ref(q, k, v):
         return jnp.sum(causal_attention(q, k, v) * w)
 
-    g_flash = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    g_flash = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
+    g_ref = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
     for gf, gr, name in zip(g_flash, g_ref, "qkv"):
         np.testing.assert_allclose(
             np.asarray(gf), np.asarray(gr), rtol=5e-5, atol=5e-5,
@@ -89,6 +95,7 @@ def test_gradients_unaligned_seq_and_headdim():
         )
 
 
+@pytest.mark.usefixtures("light_compile")
 def test_pallas_bwd_matches_jnp_blockwise_bwd():
     """The Pallas backward kernels against the jnp scan backward they
     replaced (kept as the O(S·block) reference implementation)."""
@@ -116,6 +123,7 @@ def test_pallas_bwd_matches_jnp_blockwise_bwd():
         )
 
 
+@pytest.mark.usefixtures("light_compile")
 def test_transformer_with_flash_attention():
     from tpu_sandbox.models.transformer import TransformerConfig, TransformerLM
 
@@ -125,12 +133,12 @@ def test_transformer_with_flash_attention():
     tokens = jnp.asarray(rng.integers(0, 32, size=(2, 128)), jnp.int32)
 
     ref_model = TransformerLM(cfg)
-    variables = ref_model.init(jax.random.key(0), tokens)
-    ref_logits = ref_model.apply(variables, tokens)
+    variables = jax.jit(ref_model.init)(jax.random.key(0), tokens)
+    ref_logits = jax.jit(ref_model.apply)(variables, tokens)
 
     flash_model = TransformerLM(cfg, attention_fn=flash_attention_fn(
         interpret=True))
-    logits = flash_model.apply(variables, tokens)
+    logits = jax.jit(flash_model.apply)(variables, tokens)
     np.testing.assert_allclose(np.asarray(logits), np.asarray(ref_logits),
                                rtol=2e-4, atol=2e-4)
 
@@ -257,8 +265,17 @@ def _qkvg(s, d_qk, d_v, dtype=jnp.float32, b=1, h=2, seed=0):
 
 
 def _grads(attn, q, k, v, g, **kw):
-    out, vjp = jax.vjp(lambda q, k, v: attn(q, k, v, **kw), q, k, v)
-    return (out, *vjp(g.astype(out.dtype)))
+    def out_and_grads(q, k, v, g):  # one compiled program, not one a primitive
+        out, vjp = jax.vjp(lambda q, k, v: attn(q, k, v, **kw), q, k, v)
+        return (out, *vjp(g.astype(out.dtype)))
+    return jax.jit(out_and_grads)(q, k, v, g)
+
+
+@functools.cache
+def _plain_grads(d_qk, d_v, causal):
+    """Plain attention's (out, dq, dk, dv) at ``_qkvg(600, d_qk, d_v)``: one
+    a head size and mask, for every tiling held to it."""
+    return _grads(causal_attention, *_qkvg(600, d_qk, d_v), causal=causal)
 
 
 # S 600 pads to 768 under blocks (128, 256) / (256, 128): six (three) query
@@ -269,6 +286,7 @@ TILINGS = [(128, 256), (256, 128), (256, 256)]
 HEADS = [(64, 64), (192, 128)]
 
 
+@pytest.mark.usefixtures("light_compile")
 @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
 @pytest.mark.parametrize("block_q,block_k", TILINGS)
 @pytest.mark.parametrize("d_qk,d_v", HEADS)
@@ -277,11 +295,12 @@ def test_unequal_tiles_match_plain_attention_fp32(d_qk, d_v, block_q, block_k,
     q, k, v, g = _qkvg(600, d_qk, d_v)
     got = _grads(flash_attention, q, k, v, g, causal=causal, block_q=block_q,
                  block_k=block_k, interpret=True)
-    want = _grads(causal_attention, q, k, v, g, causal=causal)
+    want = _plain_grads(d_qk, d_v, causal)
     for a, b, name in zip(got, want, ("out", "dq", "dk", "dv")):
         np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5, err_msg=name)
 
 
+@pytest.mark.usefixtures("light_compile")
 @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
 @pytest.mark.parametrize("block_q,block_k", TILINGS)
 @pytest.mark.parametrize("d,dv", [(128, 128), (256, 128)])
@@ -336,6 +355,7 @@ def _bf16_case(d_qk, d_v, causal):
     return (q, k, v, g), want
 
 
+@pytest.mark.usefixtures("light_compile")
 @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
 @pytest.mark.parametrize("d_qk,d_v", HEADS)
 def test_bf16_gradients_against_the_float32_reference(d_qk, d_v, causal):
@@ -347,6 +367,7 @@ def test_bf16_gradients_against_the_float32_reference(d_qk, d_v, causal):
         assert _rel_rms(a, w) < BF16_REL_RMS, (name, _rel_rms(a, w))
 
 
+@pytest.mark.usefixtures("light_compile")
 @pytest.mark.parametrize("fault", ["delta_left_out", "ds_not_scaled"])
 def test_a_planted_fault_breaks_the_bf16_tolerance_tenfold(fault, monkeypatch):
     args, want = _bf16_case(64, 64, True)

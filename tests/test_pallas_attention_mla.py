@@ -25,6 +25,7 @@ SHAPES = [(192, 128, 192 ** -0.5 * 2.00474), (64, 64, None), (24, 16, 0.3),
           (128, 256, None)]
 
 
+@pytest.mark.usefixtures("light_compile")
 @pytest.mark.parametrize("d_qk,d_v,scale", SHAPES)
 def test_forward_matches_plain_attention(d_qk, d_v, scale):
     q, k, v, _ = qkv(d_qk, d_v)
@@ -34,6 +35,7 @@ def test_forward_matches_plain_attention(d_qk, d_v, scale):
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.usefixtures("light_compile")
 @pytest.mark.parametrize("d_qk,d_v,scale", SHAPES[:3])
 def test_backward_matches_plain_attention(d_qk, d_v, scale):
     q, k, v, g = qkv(d_qk, d_v)
@@ -76,6 +78,7 @@ def test_plain_attention_takes_a_scale():
 YARN_SCALE = 192 ** -0.5 * 2.00474
 
 
+@pytest.mark.usefixtures("light_compile")
 @pytest.mark.parametrize("s", [1400, 1100], ids=["eleven_tiles", "nine_tiles"])
 def test_rule_tiles_at_the_latent_head_sizes(s):
     """No blocks given, an S whose lane count leaves the rule small tiles

@@ -20,6 +20,9 @@ from tpu_sandbox.ops.pallas_bn_tail_t import (
     unfused_reference_t as ref_chain,
 )
 
+# every claim here is a tolerance: conftest's cheaper compile
+pytestmark = pytest.mark.usefixtures("light_compile")
+
 
 def _data(blk, co, hw, dtype=jnp.float32, seed=0, n=2):
     rng = np.random.default_rng(seed)

@@ -28,6 +28,7 @@ def _case(n=2, hw=32, f1=8, seed=0, dtype=jnp.float32):
     return x, k5, cb, gamma, beta
 
 
+@pytest.mark.usefixtures("light_compile")
 @pytest.mark.parametrize("hw", [32, 4])  # 4: one-block image, all halos
 def test_forward_and_stats_match_unfused(hw):
     x, k5, cb, gamma, beta = _case(hw=hw)
@@ -39,6 +40,7 @@ def test_forward_and_stats_match_unfused(hw):
     np.testing.assert_allclose(np.asarray(var), np.asarray(var_r), atol=1e-5)
 
 
+@pytest.mark.usefixtures("light_compile")
 def test_all_grads_match_unfused():
     x, k5, cb, gamma, beta = _case(seed=1)
     f1 = k5.shape[-1]
@@ -70,6 +72,7 @@ def test_all_grads_match_unfused():
             rtol=0, atol=3e-5 * scale, err_msg=nm)
 
 
+@pytest.mark.usefixtures("light_compile")
 def test_bf16_grads_track_unfused():
     """Production compute dtype: the in-kernel dy is rounded to bf16
     exactly as the HBM tensor would have been, so even in bf16 the two

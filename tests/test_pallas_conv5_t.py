@@ -6,6 +6,7 @@ scatter/gather index adjointness the VJP relies on. Interpret mode
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from tpu_sandbox.models.convnet_s2d_t import space_to_depth_t
 from tpu_sandbox.ops.pallas_conv5_t import (
@@ -15,6 +16,9 @@ from tpu_sandbox.ops.pallas_conv5_t import (
     gather_dk5,
     scatter_k5,
 )
+
+# every claim here is a tolerance: conftest's cheaper compile
+pytestmark = pytest.mark.usefixtures("light_compile")
 
 
 def _case(n=2, hw=32, f1=8, seed=0, dtype=jnp.float32):

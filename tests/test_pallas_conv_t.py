@@ -1,16 +1,20 @@
 """Transposed-layout Pallas conv (ops/pallas_conv_t.py) vs the lax.conv
-reference (interpret on CPU) — same strategy as test_pallas_conv: the
-TPU call path with interpret=True, numerical parity against
-conv3x3_t_reference (transpose -> the exact NHWC conv -> transpose).
-Covers halo rows, W-edge zero columns, block_h fallback, bf16, the full
-custom VJP, the stats variant, and layout round-trip against the NHWC
-kernel on the s2d-scattered shapes ConvNetS2D uses."""
+reference (interpret on CPU) — the tests of test_pallas_conv, collected
+here with this layout's kernels: the TPU call path with interpret=True,
+numerical parity against conv3x3_t_reference (transpose -> the exact NHWC
+conv -> transpose). Covers halo rows, W-edge zero columns, block_h
+fallback, bf16, the full custom VJP, the stats variant, and layout
+round-trip against the NHWC kernel on the s2d-scattered shapes ConvNetS2D
+uses."""
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from tests import test_pallas_conv as nhwc
+from tests.test_pallas_conv import (  # noqa: F401 (collected here too)
+    test_forward_matches_reference, test_grads_match_reference,
+    test_single_row_blocks_and_tiny_width)
 from tpu_sandbox.ops.pallas_conv_t import (
     conv3x3_t,
     conv3x3_t_reference,
@@ -18,116 +22,23 @@ from tpu_sandbox.ops.pallas_conv_t import (
 )
 
 
-def _data(n=2, h=20, w=12, c=16, co=32, dtype=jnp.float32, seed=0):
-    rng = np.random.default_rng(seed)
-    x = jnp.asarray(rng.standard_normal((n, h, c, w)), dtype)
-    k = jnp.asarray(rng.standard_normal((3, 3, c, co)) * 0.1, dtype)
-    b = jnp.asarray(rng.standard_normal((co,)), dtype)
-    return x, k, b
-
-
-@pytest.mark.parametrize(
-    "h,w,c,co,dt,tol",
-    [
-        (20, 12, 16, 32, jnp.float32, 1e-5),
-        (21, 9, 8, 16, jnp.float32, 1e-5),   # h=21 -> block_h fallback 3
-        (20, 12, 16, 32, jnp.bfloat16, 0.03),
-    ],
-)
-def test_forward_matches_reference(h, w, c, co, dt, tol):
-    x, k, b = _data(h=h, w=w, c=c, co=co, dtype=dt)
-    ref = conv3x3_t_reference(x, k, b)
-    out = conv3x3_t(x, k, b, True)
-    assert out.dtype == x.dtype
-    assert out.shape == (x.shape[0], h, co, w)
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32), np.asarray(ref, np.float32),
-        rtol=tol, atol=tol,
-    )
-
-
-def test_single_row_blocks_and_tiny_width():
-    x, k, b = _data(n=1, h=7, w=3, c=4, co=8)
-    np.testing.assert_allclose(
-        np.asarray(conv3x3_t(x, k, b, True)),
-        np.asarray(conv3x3_t_reference(x, k, b)), rtol=1e-5, atol=1e-5,
-    )
-
-
-def test_grads_match_reference():
-    x, k, b = _data()
-    w = jnp.asarray(
-        np.random.default_rng(9).standard_normal((2, 20, 32, 12)),
-        jnp.float32,
-    )
-
-    def loss_kernel(x, k, b):
-        return jnp.sum(conv3x3_t(x, k, b, True) * w)
-
-    def loss_ref(x, k, b):
-        return jnp.sum(conv3x3_t_reference(x, k, b) * w)
-
-    gk = jax.grad(loss_kernel, argnums=(0, 1, 2))(x, k, b)
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(x, k, b)
-    for a, r, name in zip(gk, gr, ("dx", "dw", "db")):
-        np.testing.assert_allclose(
-            np.asarray(a), np.asarray(r), rtol=2e-4, atol=2e-4,
-            err_msg=name,
-        )
+@pytest.fixture
+def layout():
+    return nhwc.Layout(conv3x3_t, conv3x3_t_reference, conv3x3_t_stats, 2)
 
 
 @pytest.mark.slow  # tier-1 keeps test_pallas_conv.py::test_grads_bf16
-def test_grads_bf16():
-    """bf16 grads against the F32-computed truth (the lax.conv reference
-    accumulates in bf16 and is not a valid oracle — see test_pallas_conv
-    ::test_grads_bf16)."""
-    x, k, b = _data(dtype=jnp.bfloat16)
-
-    def tot(f):
-        return lambda x, k, b: jnp.sum(f(x, k, b).astype(jnp.float32))
-
-    gk = jax.grad(tot(lambda x, k, b: conv3x3_t(x, k, b, True)),
-                  argnums=(0, 1, 2))(x, k, b)
-    xf, kf, bf = (jnp.asarray(t, jnp.float32) for t in (x, k, b))
-    gr = jax.grad(tot(conv3x3_t_reference), argnums=(0, 1, 2))(xf, kf, bf)
-    for a, r, name in zip(gk, gr, ("dx", "dw", "db")):
-        assert a.dtype == jnp.bfloat16, name
-        np.testing.assert_allclose(
-            np.asarray(a, np.float32), np.asarray(r),
-            rtol=0.05, atol=0.05, err_msg=name,
-        )
+def test_grads_bf16(layout):
+    nhwc.test_grads_bf16(layout)
 
 
 @pytest.mark.slow  # tier-1 keeps test_pallas_conv.py::test_stats_variant
 @pytest.mark.parametrize("dt", [jnp.float32, jnp.bfloat16])
-def test_stats_variant(dt):
-    """Same y; sum/sumsq equal the reductions of the ROUNDED output over
-    (N, H, W) per channel (channel dim = axis 2 in this layout); grads
-    still flow with stats cotangents zero by contract."""
-    x, k, b = _data(dtype=dt)
-    y, s, ss = conv3x3_t_stats(x, k, b, True)
-    np.testing.assert_array_equal(np.asarray(y),
-                                  np.asarray(conv3x3_t(x, k, b, True)))
-    yf = np.asarray(y, np.float32).transpose(0, 1, 3, 2).reshape(
-        -1, y.shape[2])
-    assert s.shape == (y.shape[2], 1)
-    np.testing.assert_allclose(np.asarray(s)[:, 0], yf.sum(0), rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(ss)[:, 0], (yf * yf).sum(0),
-                               rtol=1e-5)
-
-    def loss(x, k, b):
-        y, s, ss = conv3x3_t_stats(x, k, b, True)
-        return jnp.sum(y.astype(jnp.float32))
-
-    gk = jax.grad(loss, argnums=(0, 1, 2))(x, k, b)
-    gr = jax.grad(
-        lambda x, k, b: jnp.sum(conv3x3_t(x, k, b, True).astype(jnp.float32)),
-        argnums=(0, 1, 2),
-    )(x, k, b)
-    for a, r in zip(gk, gr):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(r))
+def test_stats_variant(layout, dt):
+    nhwc.test_stats_variant(layout, dt)
 
 
+@pytest.mark.usefixtures("light_compile")
 def test_matches_nhwc_kernel_on_s2d_shapes():
     """Transposed kernel == NHWC kernel (modulo layout) on the exact
     s2d-scattered conv1 shapes ConvNetS2D uses, miniature image."""

@@ -7,7 +7,6 @@ tests/test_mosaic_lowering.py, and the compiled head's freedom from
 weight-sized loops in test_compiled_head_has_no_weight_sized_loop."""
 
 import os
-import subprocess
 import sys
 
 import jax
@@ -15,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from tests.helpers import run_child
 from tpu_sandbox.ops.pallas_fc_t import (
     _pick_block_h,
     fc_dgrad_t,
@@ -33,6 +33,10 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 GEOMS = [(3, 8, 16, 32), (2, 4, 32, 750), (9, 6, 8, 48), (5, 3, 4, 10)]
 geoms = pytest.mark.parametrize("geom", GEOMS, ids=lambda g: "x".join(
     map(str, g)))
+
+
+#: for the tests whose claim is a tolerance against the einsum path
+light = pytest.mark.usefixtures("light_compile")
 
 
 def test_block_picker():
@@ -69,6 +73,7 @@ def test_forward_matches_einsum(geom):
         rtol=1e-5, atol=1e-5)
 
 
+@light
 @geoms
 def test_grads_match_einsum_autodiff(geom):
     """All three cotangents (dy via the Pallas kernel, dkernel/dbias via
@@ -81,8 +86,8 @@ def test_grads_match_einsum_autodiff(geom):
     def loss_ref(y, kernel, bias):
         return jnp.sum(_einsum_ref(y, kernel, bias, jnp.float32) ** 2)
 
-    gp = jax.grad(loss_pallas, argnums=(0, 1, 2))(y, kernel, bias)
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(y, kernel, bias)
+    gp = jax.jit(jax.grad(loss_pallas, argnums=(0, 1, 2)))(y, kernel, bias)
+    gr = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(y, kernel, bias)
     for a, b, nm in zip(gp, gr, ("dy", "dkernel", "dbias")):
         scale = float(np.max(np.abs(np.asarray(b)))) or 1.0
         np.testing.assert_allclose(
@@ -90,6 +95,7 @@ def test_grads_match_einsum_autodiff(geom):
             rtol=0, atol=2e-5 * scale, err_msg=nm)
 
 
+@light
 @geoms
 def test_dgrad_kernel_alone(geom):
     """fc_dgrad_t == the broadcast-sum it replaces, incl. bf16 output
@@ -105,6 +111,7 @@ def test_dgrad_kernel_alone(geom):
                                rtol=2e-2, atol=1e-2)
 
 
+@light
 @geoms
 def test_bf16_compute_path(geom):
     """bf16 y (the production compute dtype): fc_t tracks the einsum
@@ -117,12 +124,13 @@ def test_bf16_compute_path(geom):
     def loss_ref(kernel):
         return jnp.sum(_einsum_ref(y, kernel, bias, jnp.bfloat16) ** 2)
 
-    gp = jax.grad(loss_pallas)(kernel)
-    gr = jax.grad(loss_ref)(kernel)
+    gp = jax.jit(jax.grad(loss_pallas))(kernel)
+    gr = jax.jit(jax.grad(loss_ref))(kernel)
     scale = float(np.max(np.abs(np.asarray(gr)))) or 1.0
     assert float(np.max(np.abs(np.asarray(gp - gr)))) / scale < 5e-3
 
 
+@light
 @geoms
 def test_kill_switch_einsum_path(monkeypatch, geom):
     """TPU_SANDBOX_NO_PALLAS_FC=1 must keep working (the emergency
@@ -140,12 +148,15 @@ def test_kill_switch_einsum_path(monkeypatch, geom):
         else:
             monkeypatch.delenv("TPU_SANDBOX_NO_PALLAS_FC", raising=False)
         m = _DenseT(10, jnp.float32)
-        v = m.init(jax.random.key(0), y)
+        v = jax.jit(m.init)(jax.random.key(0), y)
 
-        def f(p):
-            return jnp.sum(m.apply({"params": p}, y) ** 2)
+        def out_and_grads(p):  # one program: the switch is read as it traces
+            out = m.apply({"params": p}, y)
+            return jnp.sum(out ** 2), out
 
-        return m.apply(v, y), jax.grad(f)(v["params"])
+        (_, out), grads = jax.jit(
+            jax.value_and_grad(out_and_grads, has_aux=True))(v["params"])
+        return out, grads
 
     out_p, g_p = run(env=False)
     out_e, g_e = run(env=True)
@@ -173,10 +184,10 @@ def test_compiled_head_has_no_weight_sized_loop(tmp_path):
         while_loops
 
     dump = tmp_path / "head.hlo"
-    run = subprocess.run(
+    run = run_child(
         [sys.executable, os.path.join(_ROOT, "tools", "hlo_traffic.py"),
          "--head", "--batch", "5", "--top", "0", "--dump-hlo", str(dump)],
-        capture_output=True, text=True, timeout=600,
+        timeout=150,
         env={**os.environ, "JAX_PLATFORMS": "cpu", "TPU_LOG_DIR": "disabled"})
     if run.returncode == 3:
         pytest.skip(run.stderr.strip().splitlines()[-1][:300])

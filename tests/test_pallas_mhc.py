@@ -288,10 +288,10 @@ def test_hyper_connection_agrees_with_its_fallback_on_every_parameter(
                * (1.0 + jnp.arange(4.0))[:, None, None, None])
     y = jax.random.normal(jax.random.key(2), (B, S, 128))
     weight = jax.random.normal(jax.random.key(3), streams.shape)
-    params = mix.init(jax.random.key(0), streams, method="pre")["params"]
-    params = jax.tree.map(
+    params = jax.jit(lambda key: jax.tree.map(
         lambda a: a * 5 if a.ndim == 0 else a + 0.3 * jax.random.normal(
-            jax.random.key(a.size), a.shape), params)
+            jax.random.key(a.size), a.shape),
+        mix.init(key, streams, method="pre")["params"]))(jax.random.key(0))
 
     def both(m, streams, y):
         u, kept, coefficients = m.pre(streams)

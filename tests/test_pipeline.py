@@ -1,12 +1,15 @@
 """Pipeline-parallel tests: params split/merge roundtrip, pipelined step ==
 single-device step, and learning over ticks — on a ('data','pipe') mesh."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
 
+from tests.helpers import run_child
 from tpu_sandbox.models.transformer import TransformerConfig, TransformerLM
 from tpu_sandbox.ops.losses import cross_entropy_loss
 from tpu_sandbox.parallel.pipeline import (
@@ -19,6 +22,12 @@ from tpu_sandbox.runtime.mesh import make_mesh
 CFG = TransformerConfig(
     vocab_size=64, d_model=32, n_heads=2, n_layers=4, d_ff=64, max_len=64
 )
+
+
+#: for the tests whose claim is a tolerance against the dense step
+light = pytest.mark.usefixtures("light_compile")
+#: one optimizer object, so that ``dense_step`` is built once a configuration
+SGD = optax.sgd(0.1)
 
 
 def lm_batch(b=8, s=16, seed=0):
@@ -36,7 +45,8 @@ def mesh_dp_pp():
 def test_split_merge_roundtrip():
     model = TransformerLM(CFG)
     tokens, _ = lm_batch()
-    params = model.init(jax.random.key(0), jnp.asarray(tokens))["params"]
+    params = jax.jit(model.init)(
+        jax.random.key(0), jnp.asarray(tokens))["params"]
     pre, stacked, post = split_transformer_params(params, 4)
     assert jax.tree.leaves(stacked)[0].shape[0] == 4
     merged = merge_transformer_params(pre, stacked, post)
@@ -48,6 +58,26 @@ def test_split_merge_roundtrip():
         split_transformer_params(params, 3)
 
 
+@functools.cache
+def dense_step(cfg, tx):
+    """The jitted single-device step ``(params, tokens, targets) -> (loss,
+    updated params)`` of the dense-attention model: one program a
+    configuration, for every pipeline layout held to it."""
+    model = TransformerLM(cfg)
+
+    def loss_of(params, tokens, targets):
+        logits = model.apply({"params": params}, tokens)
+        return cross_entropy_loss(
+            logits.reshape(-1, logits.shape[-1]), targets.reshape(-1))
+
+    def step(params, tokens, targets):
+        loss, grads = jax.value_and_grad(loss_of)(params, tokens, targets)
+        return loss, optax.apply_updates(
+            params, tx.update(grads, tx.init(params), params)[0])
+
+    return jax.jit(step)
+
+
 def assert_matches_dense_reference(pp, cfg, tokens, targets, tx, *,
                                    loss_rtol=1e-5, param_atol=2e-5,
                                    state=None):
@@ -57,22 +87,10 @@ def assert_matches_dense_reference(pp, cfg, tokens, targets, tx, *,
     init (it must be unsharded or shardable by pp.shard_state)."""
     if state is None:
         state = pp.init_state(jax.random.key(0), jnp.asarray(tokens))
-    model = TransformerLM(cfg)  # single-device reference, SAME init params
-    flat_params = pp.merged_params(state)
-
-    def ref_loss(params):
-        logits = model.apply({"params": params}, jnp.asarray(tokens))
-        return cross_entropy_loss(
-            logits.reshape(-1, logits.shape[-1]), jnp.asarray(targets).reshape(-1)
-        )
-
-    ref_loss_val, ref_grads = jax.value_and_grad(ref_loss)(
-        jax.tree.map(jnp.asarray, flat_params)
-    )
-    ref_params = optax.apply_updates(
-        jax.tree.map(jnp.asarray, flat_params),
-        tx.update(ref_grads, tx.init(flat_params), flat_params)[0],
-    )
+    # single-device reference, SAME init params
+    ref_loss_val, ref_params = dense_step(cfg, tx)(
+        jax.tree.map(jnp.asarray, pp.merged_params(state)),
+        jnp.asarray(tokens), jnp.asarray(targets))
 
     new_state, loss = pp.train_step(
         pp.shard_state(state), *pp.shard_batch(tokens, targets)
@@ -86,8 +104,9 @@ def assert_matches_dense_reference(pp, cfg, tokens, targets, tx, *,
     )
 
 
+@light
 def test_pipeline_step_matches_single_device(mesh_dp_pp):
-    tx = optax.sgd(0.1)
+    tx = SGD
     pp = PipelineParallel(CFG, tx, mesh_dp_pp, microbatches=2, donate=False)
     tokens, targets = lm_batch()
     assert_matches_dense_reference(pp, CFG, tokens, targets, tx)
@@ -104,6 +123,7 @@ def test_pipeline_stage_params_are_sharded(mesh_dp_pp):
     assert leaf.shape[0] == 4  # one stage row per pipe rank
 
 
+@light
 def test_pipeline_training_learns(mesh_dp_pp):
     tx = optax.adam(1e-2)
     pp = PipelineParallel(CFG, tx, mesh_dp_pp, microbatches=2, donate=False)
@@ -117,11 +137,12 @@ def test_pipeline_training_learns(mesh_dp_pp):
     assert losses[-1] < losses[0] * 0.6, (losses[0], losses[-1])
 
 
+@light
 def test_pipeline_tp_stages_match_single_device():
     """3-axis data x model x pipe mesh: Megatron TP inside each stage must
     reproduce the single-device step (loss and updated params)."""
     mesh = make_mesh({"data": 2, "model": 2, "pipe": 2})
-    tx = optax.sgd(0.1)
+    tx = SGD
     pp = PipelineParallel(
         CFG, tx, mesh, microbatches=2, model_axis="model", donate=False
     )
@@ -138,6 +159,7 @@ def test_pipeline_tp_stages_match_single_device():
     assert_matches_dense_reference(pp, CFG, tokens, targets, tx, state=state)
 
 
+@light
 @pytest.mark.parametrize("chunks", [2, 4])
 def test_circular_schedule_matches_single_device(chunks):
     """circular_chunks=v: layers round-robin over stages, microbatches ring
@@ -148,7 +170,7 @@ def test_circular_schedule_matches_single_device(chunks):
         d_ff=64, max_len=64,
     )
     mesh = make_mesh({"data": 4, "pipe": 2})
-    tx = optax.sgd(0.1)
+    tx = SGD
     pp = PipelineParallel(cfg, tx, mesh, microbatches=2,
                           circular_chunks=chunks, donate=False)
     assert pp.bubble_fraction() == pytest.approx(1 / (2 * chunks + 1))
@@ -201,6 +223,7 @@ def test_circular_validates():
         )
 
 
+@light
 @pytest.mark.parametrize("model_axis", [None, "model"])
 def test_pipeline_flash_matches_dense_reference(model_axis):
     """VERDICT r02 weak #4: attention_fn plumbs through to plain AND
@@ -212,7 +235,7 @@ def test_pipeline_flash_matches_dense_reference(model_axis):
 
     mesh = (make_mesh({"data": 2, "model": 2, "pipe": 2}) if model_axis
             else make_mesh({"data": 2, "pipe": 4}))
-    tx = optax.sgd(0.1)
+    tx = SGD
     pp = PipelineParallel(
         CFG, tx, mesh, microbatches=2, model_axis=model_axis, donate=False,
         attention_fn=flash_attention_fn(interpret=True),
@@ -222,6 +245,7 @@ def test_pipeline_flash_matches_dense_reference(model_axis):
                                    loss_rtol=1e-4, param_atol=5e-5)
 
 
+@light
 @pytest.mark.parametrize("seq_attn", ["ring", "flash_ring"])
 def test_pipeline_sp_matches_dense_reference(seq_attn):
     """Sequence parallelism INSIDE pipeline stages (dp x pp x sp): ring
@@ -232,7 +256,7 @@ def test_pipeline_sp_matches_dense_reference(seq_attn):
     mesh = make_mesh({"data": 2, "pipe": 2, "sp": 2})
     cfg = TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
                             n_layers=2, d_ff=64, max_len=64)
-    tx = optax.sgd(0.1)
+    tx = SGD
     pp = PipelineParallel(cfg, tx, mesh, microbatches=2, donate=False,
                           seq_axis="sp", seq_attn=seq_attn)
     tokens, targets = lm_batch()
@@ -245,7 +269,6 @@ def test_pipeline_4d_matches_dense_reference():
     (Megatron TP inside stages AND ring attention over the sequence) —
     reproduces the dense single-device step. Needs 16 virtual devices, so
     it runs in a subprocess (the suite's conftest pins 8)."""
-    import subprocess
     import sys
 
     script = """
@@ -260,6 +283,7 @@ try:
     jax.config.update('jax_num_cpu_devices', 16)
 except AttributeError:
     pass  # older jax: the XLA_FLAGS env above already sizes the host platform
+jax.config.update('jax_disable_most_optimizations', True)  # a tolerance claim
 import jax.numpy as jnp, numpy as np, optax
 from tpu_sandbox.models.transformer import TransformerConfig, TransformerLM
 from tpu_sandbox.ops.losses import cross_entropy_loss
@@ -282,11 +306,11 @@ def ref_loss(params):
     logits = model.apply({'params': params}, jnp.asarray(tokens))
     return cross_entropy_loss(logits.reshape(-1, 64),
                               jnp.asarray(targets).reshape(-1))
-ref_val, ref_grads = jax.value_and_grad(ref_loss)(
-    jax.tree.map(jnp.asarray, flat))
-ref_params = optax.apply_updates(
-    jax.tree.map(jnp.asarray, flat),
-    tx.update(ref_grads, tx.init(flat), flat)[0])
+def ref_step(params):
+    ref_val, ref_grads = jax.value_and_grad(ref_loss)(params)
+    return ref_val, optax.apply_updates(
+        params, tx.update(ref_grads, tx.init(params), params)[0])
+ref_val, ref_params = jax.jit(ref_step)(jax.tree.map(jnp.asarray, flat))
 new_state, loss = pp.train_step(
     pp.shard_state(state), *pp.shard_batch(tokens, targets))
 np.testing.assert_allclose(float(loss), float(ref_val), rtol=1e-5)
@@ -296,10 +320,7 @@ jax.tree.map(
     pp.merged_params(new_state), jax.tree.map(np.asarray, ref_params))
 print('4D-OK')
 """
-    proc = subprocess.run(
-        [sys.executable, "-c", script], capture_output=True, text=True,
-        timeout=1200,
-    )
+    proc = run_child([sys.executable, "-c", script], timeout=150)
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert "4D-OK" in proc.stdout
 

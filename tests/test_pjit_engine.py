@@ -19,6 +19,9 @@ from tpu_sandbox.parallel.pjit_engine import param_specs
 from tpu_sandbox.runtime.mesh import make_mesh
 from tpu_sandbox.train import TrainState, make_train_step
 
+# every claim here is a tolerance: conftest's cheaper compile
+pytestmark = pytest.mark.usefixtures("light_compile")
+
 
 def setup(lr=0.05, use_bn=False):
     model = ConvNet(use_bn=use_bn)

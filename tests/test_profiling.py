@@ -4,9 +4,6 @@ fetch-synced host_sync primitive and the differential per-step measurement
 — all on CPU. (Regions are annotated by ``obs.record.Recorder.span``:
 tests/test_program_spans.py.)"""
 
-import math
-import time
-
 import jax.numpy as jnp
 import pytest
 
@@ -64,30 +61,47 @@ def test_host_sync_fetches_a_data_dependent_scalar():
     assert profiling.host_sync(jnp.zeros((2, 3))) == 0.0
 
 
-def test_measure_per_step_cancels_fixed_costs():
+class _FakeClock:
+    """``time`` for ``profiling``: work advances it, nothing else does, so
+    no assertion here depends on the machine's load."""
+
+    def __init__(self):
+        self.now = 100.0
+
+    def perf_counter(self):
+        return self.now
+
+
+def test_measure_per_step_cancels_fixed_costs(monkeypatch):
+    clock = _FakeClock()
+    monkeypatch.setattr(profiling, "time", clock)
     fixed, per_step = 0.004, 0.001
 
     def run_steps(k):
-        time.sleep(fixed + per_step * k)
+        clock.now += fixed + per_step * k
         return jnp.ones((1,))
 
     out = profiling.measure_per_step(run_steps, n=4)
     assert out["n"] == 4
-    assert out["t_2n_sec"] > out["t_n_sec"]
-    # the constant cost cancels: the estimate tracks per_step, not
+    assert out["t_n_sec"] == pytest.approx(fixed + 4 * per_step)
+    assert out["t_2n_sec"] == pytest.approx(fixed + 8 * per_step)
+    # the constant cost cancels: the estimate is per_step, not
     # fixed + per_step
-    assert out["sec_per_step"] == pytest.approx(per_step, rel=0.75)
+    assert out["sec_per_step"] == pytest.approx(per_step)
     assert "differential" in out["timing_method"]
 
 
-def test_measure_per_step_repeated_publishes_spread():
+def test_measure_per_step_repeated_publishes_spread(monkeypatch):
+    clock = _FakeClock()
+    monkeypatch.setattr(profiling, "time", clock)
+    costs = iter([0.001, 0.001, 0.001, 0.002, 0.002, 0.002])  # a step, by call
+
     def run_steps(k):
-        time.sleep(0.001 * k)
+        clock.now += next(costs) * k
         return jnp.ones((1,))
 
     out = profiling.measure_per_step_repeated(run_steps, n=2, repeats=2)
     assert out["repeats"] == 2
-    assert len(out["sec_per_step_samples"]) == 2
-    assert out["sec_per_step"] > 0
-    if out["spread_frac"] is not None:
-        assert out["spread_frac"] >= 0
+    assert out["sec_per_step_samples"] == [0.001, 0.002]
+    assert out["sec_per_step"] == pytest.approx(0.001)  # the least contended
+    assert out["spread_frac"] == 1.0

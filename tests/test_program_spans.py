@@ -7,7 +7,6 @@ that stands for a device's."""
 
 import json
 import os
-import subprocess
 import sys
 import time
 
@@ -17,6 +16,7 @@ import numpy as np
 import optax
 import pytest
 
+from tests.helpers import run_child
 from tpu_sandbox.obs import get_recorder, get_registry, reset_recorder
 from tpu_sandbox.obs.record import Recorder
 
@@ -142,8 +142,8 @@ print("ok")
 """
     env = {k: v for k, v in os.environ.items()
            if k != "TPU_SANDBOX_TRACE_DIR"}
-    proc = subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=env,
-                          capture_output=True, text=True, timeout=60)
+    proc = run_child([sys.executable, "-c", code], cwd=ROOT, env=env,
+                     timeout=60)
     assert proc.returncode == 0 and proc.stdout.strip() == "ok", proc.stderr
 
 
@@ -231,6 +231,7 @@ def test_train_step_spans_the_device_work_and_dispatch_only_the_enqueue(
     assert hist["train.next_batch_s"]["p50"] >= 0.020
 
 
+@pytest.mark.usefixtures("light_compile")
 def test_lm_train_loop_carries_the_same_spans(registry, dark):
     import lm_train
 
@@ -523,8 +524,9 @@ for _ in range(5):
 """
     env = dict(os.environ, TPU_SANDBOX_TRACE_DIR=str(tmp_path),
                JAX_PLATFORMS="cpu")
-    subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=env,
-                   check=True, timeout=120)
+    proc = run_child([sys.executable, "-c", code], cwd=ROOT, env=env,
+                     timeout=120)
+    assert proc.returncode == 0, proc.stderr
     (log,) = os.listdir(tmp_path)
     names = [json.loads(line).get("name") for line in open(tmp_path / log)]
     assert names.count("place:batch") == 5

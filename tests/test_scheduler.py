@@ -618,8 +618,7 @@ def test_vtime_ledger_survives_scheduler_death(agent_script):
                 time.sleep(0.05)
             else:
                 pytest.fail("first scheduler never finished 3 jobs")
-            sched1.kill()
-            sched1.wait()
+            _reap(sched1)
             # the ledger the dead scheduler persisted job-by-job
             persisted = {t: float(kv.get(f"sched/vtime/{t}"))
                          for t in ("alpha", "beta")}
@@ -645,8 +644,7 @@ def test_vtime_ledger_survives_scheduler_death(agent_script):
                     f"2:1 convergence broken across restart: {admitted}"
         finally:
             if sched1.poll() is None:
-                sched1.kill()
-                sched1.wait()
+                _reap(sched1)
             kv.close()
 
 
@@ -757,6 +755,11 @@ def _spawn_scheduler_proc(port, pool):
                             env={**os.environ, "PYTHONPATH": ROOT})
 
 
+def _reap(proc):
+    proc.kill()
+    proc.wait(timeout=10)  # raises if SIGKILL has not taken it by then
+
+
 @pytest.mark.parametrize("kill_order", [
     ("scheduler", "victim_agent"),
     ("victim_agent", "scheduler"),
@@ -788,8 +791,7 @@ def test_scheduler_death_leaves_survivor_unharmed(agent_script, kill_order):
             victim_pid = int(kv.get("job/victim/test/ran/0"))
             for target in kill_order:
                 if target == "scheduler":
-                    sched1.kill()
-                    sched1.wait()
+                    _reap(sched1)
                 else:
                     os.kill(victim_pid, signal.SIGKILL)
                 time.sleep(0.1)
@@ -816,6 +818,5 @@ def test_scheduler_death_leaves_survivor_unharmed(agent_script, kill_order):
             assert kv.keys("job/victim/") == []
         finally:
             if sched1.poll() is None:
-                sched1.kill()
-                sched1.wait()
+                _reap(sched1)
             kv.close()

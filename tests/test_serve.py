@@ -1,14 +1,14 @@
 """Serving stack, fast: paged KV allocator units, prefix sharing, the
-bitwise decode-vs-forward parity contract, engine-vs-reference greedy
+decode-vs-forward parity contract, engine-vs-reference greedy
 outputs (continuous AND static, including under preemption pressure),
 and the in-process replica protocol (drain/requeue, cross-worker
 completion, lease-expiry scavenge).
 
 The parity reference is the one-shot ``TransformerLM`` forward evaluated
 at the cache's ``max_context`` padding — the same k-axis length the
-decode softmax reduces over. Exact-length forwards match bitwise only
-while the context is at or under XLA:CPU's unrolled-reduce threshold
-(16); see serve/decode.py's module docstring for the full discipline.
+decode softmax reduces over; the two agree to a few ulps, not to the bit
+(they are different compiled programs), which is enough for the same
+greedy tokens at every size tested here.
 
 The replica gang under real HostAgents (kill a replica mid-load, lose
 nothing) runs slow in test_serve_integration.py.
@@ -22,6 +22,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from tests.helpers import ulps_apart
 from tpu_sandbox.models.transformer import TransformerConfig, TransformerLM
 from tpu_sandbox.serve import (
     CacheConfig,
@@ -65,8 +66,8 @@ def fwd32(model, params):
 @pytest.fixture(scope="module")
 def greedy(fwd32):
     """Greedy continuation via the padded one-shot forward. One compiled
-    shape total, and bitwise-identical logits to what the serve decode
-    path computes — this IS the unfaulted reference output."""
+    shape total, and the logits the serve decode path computes, to a few
+    ulps — this IS the unfaulted reference output."""
     def _greedy(prompt, max_new):
         toks = list(prompt)
         out = []
@@ -141,12 +142,20 @@ def test_cache_prefix_sharing_refcounts_and_eviction():
     assert cache.stats["evicted_cache_blocks"] >= 1
 
 
-# -- bitwise parity ---------------------------------------------------------
+# -- parity with the one-shot forward ---------------------------------------
 
 
-def test_decode_matches_padded_forward_bitwise_fp32(params, step, fwd32):
-    """Prefill + 24 decode steps, every step's logits bitwise equal to the
-    one-shot forward at max_context padding (fp32, CPU)."""
+@pytest.mark.parametrize("fault", [None, "row_one_off"])
+def test_decode_matches_padded_forward_to_rounding_fp32(
+        params, step, fwd32, fault):
+    """Prefill + 24 decode steps, every step's logits the one-shot forward's
+    at max_context padding (fp32, CPU) to rounding: the prefill, the decode
+    step and the forward are three compiled programs, and XLA:CPU does not
+    promise them one order of summation. Measured over six seeds of weights
+    and prompt at this size: at most 11 ulps of the logits' largest entry
+    (prefill 3.5); held to 32. A step that reads its cache one row off
+    (the planted fault: one step is given its length less one) is millions
+    apart."""
     cache = PagedKVCache(CCFG)
     kp, vp = init_pages(MCFG, CCFG)
     prompt = [5, 17, 3, 42, 9]
@@ -166,7 +175,7 @@ def test_decode_matches_padded_forward_bitwise_fp32(params, step, fwd32):
     alloc.length = len(prompt)
     cur = np.asarray(cur)
     seq = list(prompt)
-    assert np.array_equal(cur, ref_logits(seq)), "prefill logits diverged"
+    apart = [ulps_apart(cur, ref_logits(seq))]
 
     for i in range(24):
         token = int(cur.argmax())
@@ -178,23 +187,19 @@ def test_decode_matches_padded_forward_bitwise_fp32(params, step, fwd32):
         lengths = np.zeros((3,), np.int32)
         tables = np.zeros((3, CCFG.max_blocks_per_seq), np.int32)
         tokens[0, 0] = token
-        lengths[0] = len(seq)
+        lengths[0] = len(seq) - (fault is not None and i == 10)
         tables[0] = cache.block_table(alloc)
         cur, kp, vp = step.decode(
             params, kp, vp, jnp.asarray(tokens), jnp.asarray(lengths),
             jnp.asarray(tables))
         cur = np.asarray(cur)[0]
         alloc.length = len(seq)
-        ref = cur == ref_logits(seq)
-        assert ref.all(), f"decode step {i} (context {len(seq)}) diverged"
-        if len(seq) == 12:
-            # spot-check the documented exact-length equality for n <= 16
-            exact = np.asarray(
-                jax.jit(lambda t: TransformerLM(MCFG).apply(
-                    {"params": params}, t))(
-                    jnp.asarray([seq], jnp.int32)))[0, -1]
-            assert np.array_equal(cur, exact)
+        apart.append(ulps_apart(cur, ref_logits(seq)))
     cache.free(alloc, cache_prefix=False)
+    if fault:
+        assert max(apart[:11]) <= 32 and max(apart[11:]) > 1e5, apart
+    else:
+        assert max(apart) <= 32, apart
 
 
 def test_decode_bf16_cache_stays_close(params, fwd32):

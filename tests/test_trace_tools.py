@@ -12,12 +12,11 @@ import importlib.util
 import json
 import os
 import shutil
-import subprocess
 import sys
 
 import pytest
 
-from tests.test_gateway import kv_pair  # noqa: F401 (fixture)
+from tests.helpers import kv_pair, run_child  # noqa: F401 (kv_pair: fixture)
 from tpu_sandbox.obs import critpath
 from tpu_sandbox.obs.collect import (chain_check, load_dir, load_merged,
                                      read_log, request_waterfall)
@@ -29,9 +28,9 @@ TRACE_SLOW = os.path.join(FIXTURES, "trace_slow")
 
 
 def _run(tool, *argv):
-    return subprocess.run(
+    return run_child(
         [sys.executable, os.path.join(REPO, "tools", tool), *argv],
-        capture_output=True, text=True, timeout=120)
+        timeout=120)
 
 
 # -- tracecat -----------------------------------------------------------------

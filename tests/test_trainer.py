@@ -8,12 +8,16 @@ import jax.numpy as jnp
 import jax.random
 import numpy as np
 import optax
+import pytest
 
 from tpu_sandbox.data import BatchLoader, synthetic_mnist
 from tpu_sandbox.data.mnist import normalize
 from tpu_sandbox.models import ConvNet
 from tpu_sandbox.train import Trainer, TrainState, make_train_step
 from tpu_sandbox.train.trainer import make_eval_step
+
+# every claim here is a tolerance: conftest's cheaper compile
+pytestmark = pytest.mark.usefixtures("light_compile")
 
 
 def make_setup(image_size=None, lr=0.05, n=128):

@@ -14,7 +14,9 @@ from tpu_sandbox.models.transformer import TransformerConfig, TransformerLM
 from tpu_sandbox.ops.losses import cross_entropy_loss
 from tpu_sandbox.parallel.seq_parallel import SeqParallel
 from tpu_sandbox.runtime.mesh import make_mesh
-from tpu_sandbox.train import TrainState
+
+# every claim here is a tolerance: conftest's cheaper compile
+pytestmark = pytest.mark.usefixtures("light_compile")
 
 CFG = TransformerConfig(vocab_size=64, d_model=32, n_heads=2, n_layers=2, d_ff=64,
                         max_len=64)
@@ -54,10 +56,13 @@ def test_sp_step_matches_single_device(mesh_dp_sp):
             logits.reshape(-1, logits.shape[-1]), jnp.asarray(targets).reshape(-1)
         )
 
-    ref_loss_val, ref_grads = jax.value_and_grad(ref_loss)(state.params)
-    ref_params = optax.apply_updates(
-        state.params, tx.update(ref_grads, tx.init(state.params), state.params)[0]
-    )
+    @jax.jit  # one compiled program, not one a primitive
+    def ref_step(params):
+        loss, grads = jax.value_and_grad(ref_loss)(params)
+        return loss, optax.apply_updates(
+            params, tx.update(grads, tx.init(params), params)[0])
+
+    ref_loss_val, ref_params = ref_step(state.params)
 
     sstate = sp.shard_state(state)
     new_state, loss = sp.train_step(sstate, *sp.shard_batch(tokens, targets))
@@ -93,8 +98,8 @@ def test_sp_validates_axes():
 def test_transformer_forward_shapes():
     model = TransformerLM(CFG)
     tokens = jnp.zeros((2, 16), jnp.int32)
-    variables = model.init(jax.random.key(0), tokens)
-    logits = model.apply(variables, tokens)
+    variables = jax.jit(model.init)(jax.random.key(0), tokens)
+    logits = jax.jit(model.apply)(variables, tokens)
     assert logits.shape == (2, 16, CFG.vocab_size)
     assert logits.dtype == jnp.float32
 
@@ -102,10 +107,10 @@ def test_transformer_forward_shapes():
 def test_transformer_is_causal():
     model = TransformerLM(CFG)
     tokens = jnp.asarray(np.random.default_rng(0).integers(0, 64, (1, 16)), jnp.int32)
-    variables = model.init(jax.random.key(0), tokens)
-    base = model.apply(variables, tokens)
-    mutated = tokens.at[:, 10:].set(1)
-    out = model.apply(variables, mutated)
+    variables = jax.jit(model.init)(jax.random.key(0), tokens)
+    apply = jax.jit(model.apply)
+    base = apply(variables, tokens)
+    out = apply(variables, tokens.at[:, 10:].set(1))
     np.testing.assert_allclose(
         np.asarray(base)[:, :10], np.asarray(out)[:, :10], atol=1e-5
     )

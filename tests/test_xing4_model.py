@@ -1,7 +1,8 @@
 """models/xing4.py at a small size on the CPU (hidden 64, 4 streams, 2
 heads, 8 experts, seeded random weights): the float32 parts against hand
-values, the model against the plain float32 reference of the benchmark on
-logits, loss and gradients, the router's bias, and ``lm_train.build``."""
+values, the point of the on-chip check, and the router's bias. The model
+against the plain float32 reference of the benchmark is
+tests/test_lm_models.py, ``lm_train.build`` tests/test_lm_train.py."""
 
 import functools
 import math
@@ -130,100 +131,6 @@ def test_config_reads_the_published_keys_and_the_share():
             tokens_per_step=64)
 
 
-# --- the model against the reference ---
-
-@functools.cache
-def system_and_reference(dtype, mtp: bool, flash: bool = True,
-                         hidden: int = 64):
-    # the published 20 Sinkhorn iterations in the main float32 case; fewer
-    # and no recomputation where the subject is the MTP module or bf16
-    # (every unrolled iteration is compiled four times a block)
-    full = dtype == jnp.float32 and not mtp
-    config = tiny(num_nextn_predict_layers=int(mtp), hidden_size=hidden,
-                  hc_sinkhorn_iters=20 if full else 4)
-    cfg = xing4.Xing4Config.from_dict(config, tokens_per_step=B * S,
-                                      dtype=dtype, remat=full, flash=flash)
-    model = xing4.Xing4LM(cfg)
-    rng = np.random.default_rng(0)
-    tokens = jnp.asarray(rng.integers(0, 256, (B, S)), jnp.int32)
-    targets = jnp.asarray(rng.integers(0, 256, (B, S)), jnp.int32)
-    variables = jax.jit(model.init)(jax.random.key(1), tokens)
-    # off the initial point, so that every alpha, bias and Phi matters
-    params = jax.tree.map(
-        lambda a: a * 5 if a.ndim == 0 else a + 0.05 * jax.random.normal(
-            jax.random.key(a.size), a.shape), variables["params"])
-    stats = variables["batch_stats"]
-
-    def system(p):
-        logits, sown = model.apply(
-            {"params": p, "batch_stats": stats}, tokens,
-            mutable=["mtp_logits", "batch_stats", "intermediates"])
-        loss = cross_entropy_loss(logits.reshape(-1, 256), targets.reshape(-1))
-        for extra in jax.tree.leaves(sown.get("mtp_logits", {})):
-            loss = loss + xing4.MTP_LOSS_WEIGHT * cross_entropy_loss(
-                extra[:, :-1].reshape(-1, 256), targets[:, 1:].reshape(-1))
-        return loss, logits
-
-    (loss, logits), grads = jax.jit(
-        jax.value_and_grad(system, has_aux=True))(params)
-    ref_cfg = {**config, "held": list(cfg.held), "local_rows": cfg.local_rows}
-    ref_loss, ref_logits, _, ref_grads = ref.loss_and_grads(
-        ref.from_program_tree(params, stats), tokens, targets, ref_cfg)
-    return (loss, logits, flat(grads)), (ref_loss, ref_logits, ref_grads)
-
-
-#: gradients that are zero by symmetry where every stream is the same (the
-#: first block's and the MTP block's input mix): rounding over nothing
-SYMMETRIC = ("block0/mhc_attn/", "mtp_block/mhc_attn/")
-
-
-# at width 128 the hyper-connections run ``ops/pallas_mhc.py``'s kernels
-# (interpreted here); at 64, no lane multiple, their ``jnp`` fallback
-@pytest.mark.parametrize("mtp,hidden", [(False, 64), (True, 64), (False, 128)],
-                         ids=["main", "with_mtp", "main_kernels"])
-def test_model_matches_the_reference_in_float32(mtp, hidden):
-    (loss, logits, grads), (ref_loss, ref_logits, ref_grads) = (
-        system_and_reference(jnp.float32, mtp, hidden=hidden))
-    assert abs(float(loss) - float(ref_loss)) < 1e-5
-    assert ref.rms_rel(logits, ref_logits) < 1e-5
-    assert ("mtp_proj/kernel" in grads) == mtp
-    for path, grad in grads.items():
-        scale = float(np.sqrt(np.mean(np.square(ref_grads[path]))))
-        if path.startswith(SYMMETRIC) or scale < 1e-9:
-            continue
-        assert ref.rms_rel(grad, ref_grads[path]) < 2e-3, path
-
-
-@pytest.mark.parametrize("mtp", [False, True], ids=["main", "with_mtp"])
-def test_model_matches_the_reference_in_bf16_within_its_band(mtp):
-    """bf16 through three blocks of width 64 with two flippable choices of
-    eight: logits within 6 %, the loss within 0.03, and the gradients of
-    the wide parameters (thousands of entries, no single choice decides
-    them) within 25 %."""
-    (loss, logits, grads), (ref_loss, ref_logits, ref_grads) = (
-        system_and_reference(jnp.bfloat16, mtp))
-    assert abs(float(loss) - float(ref_loss)) < 3e-2
-    assert ref.rms_rel(logits, ref_logits) < 6e-2
-    for path in ("tok_emb/embedding", "lm_head/kernel",
-                 "block1/mla/kv_b/kernel", "block0/mlp/down/kernel"):
-        assert ref.rms_rel(grads[path], ref_grads[path]) < 0.25, path
-
-
-def test_plain_attention_path_agrees_with_the_flash_path():
-    """Off the chip's path (``flash`` off) the model computes the same
-    logits through ``ops.attention`` with the same scale."""
-    rng = np.random.default_rng(0)
-    tokens = jnp.asarray(rng.integers(0, 256, (B, S)), jnp.int32)
-    out = []
-    for flash in (True, False):
-        model = xing4.Xing4LM(xing4.Xing4Config.from_dict(
-            tiny(num_nextn_predict_layers=0), tokens_per_step=B * S,
-            dtype=jnp.float32, remat=False, flash=flash))
-        variables = jax.jit(model.init)(jax.random.key(1), tokens)
-        out.append(jax.jit(model.apply)(variables, tokens))
-    assert ref.rms_rel(out[0], out[1]) < 1e-5
-
-
 # --- the point of the on-chip check ---
 
 def _forgets_the_normalisations(logits, iters, eps):
@@ -237,23 +144,11 @@ def _forgets_the_normalisations(logits, iters, eps):
 xing4_sinkhorn = xing4.sinkhorn
 
 
-@pytest.mark.parametrize("fault,hidden", [
-    pytest.param(None, 64, id="None"),
-    pytest.param("sinkhorn_backward", 64, id="sinkhorn_backward"),
-    pytest.param(None, 128, id="None-kernels"),
-    pytest.param("sinkhorn_backward", 128, id="sinkhorn_backward-kernels")])
-def test_check_point_conditions_the_mhc_gradients(fault, hidden, monkeypatch):
-    """At ``ref.off_start``'s point the gradients of Phi_res, alpha_res and
-    Phi_pre agree with the reference like the others (float32: 1e-3), so a
-    limit on them means something; and a Sinkhorn whose backward pass is
-    wrong breaks ``ref.compare``'s limit on them, with the forward pass
-    (logits, loss) untouched. At width 128 it is the mHC kernels, with
-    Sinkhorn between them, that the reference and the fault are held to."""
-    from tests.test_pallas_mhc import choices, new_choices
-
-    before = choices()
-    if fault:
-        monkeypatch.setattr(xing4, "sinkhorn", _forgets_the_normalisations)
+@functools.cache
+def check_point(hidden):
+    """What the planted fault and its absence share at a width: the model,
+    its variables at ``ref.off_start``'s point, the batch, and what the
+    reference computes there for the second block's attention mix."""
     config = tiny(num_nextn_predict_layers=0, hc_sinkhorn_iters=6,
                   hidden_size=hidden)
     cfg = xing4.Xing4Config.from_dict(config, tokens_per_step=B * S,
@@ -269,6 +164,34 @@ def test_check_point_conditions_the_mhc_gradients(fault, hidden, monkeypatch):
     params = ref.unflatten({**flat(variables["params"]),
                             **{k: jnp.asarray(v) for k, v in moved.items()}})
     stats = variables["batch_stats"]
+    wanted = [k for k in flat(params) if k.startswith("block1/mhc_attn/")]
+    ref_cfg = {**config, "held": list(cfg.held), "local_rows": cfg.local_rows}
+    ref_loss, ref_logits, ref_chosen, ref_grads = ref.loss_and_grads(
+        ref.from_program_tree(params, stats), tokens, targets, ref_cfg, wanted)
+    return (model, params, stats, tokens, targets), {
+        "logits": ref_logits, "loss": ref_loss, "chosen": ref_chosen,
+        "grads": ref_grads}
+
+
+@pytest.mark.usefixtures("light_compile")
+@pytest.mark.parametrize("fault,hidden", [
+    pytest.param(None, 64, id="None"),
+    pytest.param("sinkhorn_backward", 64, id="sinkhorn_backward"),
+    pytest.param(None, 128, id="None-kernels"),
+    pytest.param("sinkhorn_backward", 128, id="sinkhorn_backward-kernels")])
+def test_check_point_conditions_the_mhc_gradients(fault, hidden, monkeypatch):
+    """At ``ref.off_start``'s point the gradients of Phi_res, alpha_res and
+    Phi_pre agree with the reference like the others (float32: 1e-3), so a
+    limit on them means something; and a Sinkhorn whose backward pass is
+    wrong breaks ``ref.compare``'s limit on them, with the forward pass
+    (logits, loss) untouched. At width 128 it is the mHC kernels, with
+    Sinkhorn between them, that the reference and the fault are held to."""
+    from tests.test_pallas_mhc import choices, new_choices
+
+    (model, params, stats, tokens, targets), reference = check_point(hidden)
+    before = choices()
+    if fault:
+        monkeypatch.setattr(xing4, "sinkhorn", _forgets_the_normalisations)
 
     def system(p):
         logits, sown = model.apply({"params": p, "batch_stats": stats}, tokens,
@@ -279,16 +202,12 @@ def test_check_point_conditions_the_mhc_gradients(fault, hidden, monkeypatch):
     (loss, (logits, sown)), grads = jax.jit(
         jax.value_and_grad(system, has_aux=True))(params)
     assert ("fallback" in new_choices(before)) == (hidden == 64)
-    wanted = [k for k in flat(params) if k.startswith("block1/mhc_attn/")]
-    ref_cfg = {**config, "held": list(cfg.held), "local_rows": cfg.local_rows}
-    ref_loss, ref_logits, ref_chosen, ref_grads = ref.loss_and_grads(
-        ref.from_program_tree(params, stats), tokens, targets, ref_cfg, wanted)
     chosen = [np.asarray(c).reshape(B, S, -1)
               for c in jax.tree.leaves(sown["intermediates"])]
     dev, bad = ref.compare(
         {"logits": logits, "loss": loss, "chosen": chosen, "grads": flat(grads)},
-        {"logits": ref_logits, "loss": ref_loss, "chosen": ref_chosen,
-         "grads": ref_grads})
+        reference)
+    ref_grads = reference["grads"]
     if fault is None:
         assert bad == [] and max(
             v for k, v in dev.items() if k.startswith("grad_rel:")) < 1e-3, dev
@@ -308,9 +227,10 @@ def test_check_point_conditions_the_mhc_gradients(fault, hidden, monkeypatch):
 
 # --- the router's bias ---
 
+@pytest.mark.usefixtures("light_compile")
 def test_router_bias_moves_by_gamma_and_carries_no_gradient():
     cfg = xing4.Xing4Config.from_dict(TINY, tokens_per_step=B * S,
-                                      dtype=jnp.float32, remat=True)
+                                      dtype=jnp.float32)
     model = xing4.Xing4LM(cfg)
     tokens = jnp.asarray(np.random.default_rng(3).integers(0, 256, (B, S)))
     variables = jax.jit(model.init)(jax.random.key(0), tokens)
@@ -330,40 +250,5 @@ def test_router_bias_moves_by_gamma_and_carries_no_gradient():
         assert float(layer["moe"]["rows_held"]) == held
         assert float(layer["moe"]["rows_dropped"]) == 0.0
     # without the collection being mutable (evaluation) nothing moves
-    logits = model.apply(variables, tokens)
+    logits = jax.jit(model.apply)(variables, tokens)
     assert logits.shape == (B, S, 256)
-
-
-# --- the entry script ---
-
-@pytest.mark.parametrize("model", ["gpt2", "xing4"])
-def test_lm_train_build_returns_model_optimizer_state_engine(model, tmp_path):
-    import json
-
-    import lm_train
-
-    flags = ["--force-cpu", "--batch", "2", "--seq-len", "16"]
-    if model == "xing4":
-        path = tmp_path / "tiny.json"
-        path.write_text(json.dumps(TINY))
-        flags += ["--model", "xing4", "--config", str(path), "--remat"]
-    args = lm_train.build_parser().parse_args(flags)
-    built, tx, state, eng = lm_train.build(args, jax.devices()[:1])
-    assert type(built).__name__ == ("Xing4LM" if model == "xing4"
-                                    else "TransformerLM")
-    assert bool(jax.tree.leaves(state.batch_stats)) == (model == "xing4")
-    batch = next(lm_train.make_batches(built.config.vocab_size, 2, 16, 1, 0))
-    new, loss = eng.train_step(state, *eng.shard_batch(*batch))
-    assert np.isfinite(float(loss)) and int(new.step) == 1
-    if model == "xing4":
-        bias = flat(new.batch_stats)["block1/moe/e_score_correction_bias"]
-        assert float(jnp.abs(bias).max()) == pytest.approx(1e-3)
-
-
-def test_xing4_needs_its_config_and_dp():
-    import lm_train
-
-    args = lm_train.build_parser().parse_args(
-        ["--force-cpu", "--model", "xing4"])
-    with pytest.raises(SystemExit, match="--config"):
-        lm_train.build(args, jax.devices()[:1])

@@ -191,16 +191,16 @@ def schedule_report(hlo_text: str) -> dict:
     }
 
 
-def build_overlapped_hlo(devices, *, batch_per_rank: int = 8,
-                         bucket_mb: float = 0.02,
-                         grad_compress: str = "none",
-                         overlap: bool = True,
-                         compiler_options: dict | None = None) -> str:
-    """AOT-compile the DataParallel MNIST step on ``devices`` (topology or
-    real) and return the optimized HLO text. The tiny bucket_mb default is
-    sized to the ~116 KB ConvNet gradient so the step splits into several
-    buckets — the schedule structure under test, not a tuning suggestion
-    (real models keep the 25 MB default)."""
+def lower_overlapped_step(devices, *, batch_per_rank: int = 8,
+                          bucket_mb: float = 0.02,
+                          grad_compress: str = "none",
+                          overlap: bool = True):
+    """Lower the DataParallel MNIST step on ``devices`` (topology or real):
+    the module as the program wrote it, one all-reduce a bucket, before any
+    XLA pass. The tiny bucket_mb default is sized to the ~116 KB ConvNet
+    gradient so the step splits into several buckets — the schedule
+    structure under test, not a tuning suggestion (real models keep the
+    25 MB default)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -233,7 +233,14 @@ def build_overlapped_hlo(devices, *, batch_per_rank: int = 8,
         (world * batch_per_rank, 28, 28, 1), jnp.float32
     )
     labs = jax.ShapeDtypeStruct((world * batch_per_rank,), jnp.int32)
-    lowered = dp.lower_step(state, imgs, labs)
+    return dp.lower_step(state, imgs, labs)
+
+
+def build_overlapped_hlo(devices, *, compiler_options: dict | None = None,
+                         **step) -> str:
+    """AOT-compile ``lower_overlapped_step(devices, **step)`` and return
+    the optimized HLO text."""
+    lowered = lower_overlapped_step(devices, **step)
     try:
         return lowered.compile(
             compiler_options=compiler_options or TPU_OVERLAP_COMPILER_OPTIONS

@@ -1,5 +1,5 @@
-"""Per-stage MPMD programs — separately compiled, bitwise-matching the
-SPMD pipeline.
+"""Per-stage MPMD programs — separately compiled, matching the SPMD
+pipeline to rounding.
 
 Each stage compiles ONLY its own program (stage 0's executable carries
 the embedding table and no head, the last stage's the reverse): forward
@@ -9,9 +9,12 @@ a stage-local optimizer apply. The math is lifted from
 ``parallel/pipeline.py`` (same ``Block.apply`` scan, same fp32 layernorm,
 same ``head_loss/M``), so the only parity question is accumulation order.
 
-Bitwise discipline (held by tests/test_mpmd.py against the real SPMD
+Summation discipline (tests/test_mpmd.py holds the result to the real SPMD
 engine on a ``{'data': 1, 'pipe': S}`` mesh, where psum/pmean are
-identities):
+identities — to a stated number of ulps: the order of the adds below is
+the SPMD program's, but XLA:CPU orders the sums *inside* each compiled
+program as it likes, so two compilations agree to rounding, not to the
+bit; two runs of these same programs, as after a stage's recovery, do):
 
 - The SPMD pipeline differentiates one ``lax.scan`` over ticks; scan's
   transpose accumulates each stage's parameter cotangent in REVERSE tick
@@ -20,13 +23,7 @@ identities):
   ``((0 + g[M-1]) + g[M-2]) + ... + g[0]`` — which reproduces the scan
   transpose add-for-add (``0 + g`` is bitwise ``g``).
 - The loss scalar is accumulated ascending (forward tick order), like
-  the scan carry. Trained *parameters* are bitwise across ≥20 steps for
-  sgd and adam; the reported *loss* can differ from the fused SPMD
-  program by ~1 ulp on some steps — XLA may group the cross-entropy mean
-  reduction differently in the two compilations, and a reduce regrouping
-  changes the forward value but not its gradient (the cotangent of a
-  mean is uniform regardless of grouping). Params are the parity
-  contract; losses are compared to 1e-6.
+  the scan carry.
 - optax's sgd/adam update leaf-wise, so the stage-local apply over a
   stage's param slice matches the SPMD whole-tree update exactly.
   (Global-norm-clipped transforms would couple stages and break this —
