@@ -12,10 +12,12 @@ eye — no compiles, CPU-only.
 import os
 import sys
 
+import pytest
+
 sys.path.insert(0, os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "tools"))
 
-from hlo_schedule import schedule_report  # noqa: E402
+from hlo_schedule import issue_order_report, schedule_report  # noqa: E402
 from hlo_traffic import (  # noqa: E402
     collective_bytes,
     shape_bytes,
@@ -260,3 +262,92 @@ def test_schedule_report_orphan_start_counts_exposed():
     assert len(exposed) == 1
     assert exposed[0]["bytes"] == 100 * 128 * 4
     assert rep["comm_bytes_exposed"] == 100 * 128 * 4
+
+
+# ---------------------------------------------------------------------------
+# issue_order_report: where the dp4 step's large all-reduce is scheduled
+# ---------------------------------------------------------------------------
+
+_FIXTURES = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                         "fixtures", "hlo")
+_BACKWARD = ["fc.3", "bn2.fused.4", "bn2.fused.5", "conv2.4", "conv2.5",
+             "bn1.fused_conv1.6", "bn1.fused_conv1.7"]
+
+
+def _issue_order(name):
+    with open(os.path.join(_FIXTURES, name)) as f:
+        return issue_order_report(f.read())
+
+
+def test_issue_order_of_one_trailing_pmean():
+    """The step as it was scheduled before PR 34: the fc gradient's
+    producer last, its all-reduce one instruction between that and the
+    update, every kernel in front of all three."""
+    r = _issue_order("dp4_step_parent.txt")
+    assert (r["collective"], r["form"]) == ("psum.79", "one instruction")
+    assert r["collective_bytes"] == 18000000 * 16 * 4  # ten classes pad to 16
+    assert (r["gradient"], r["consumer"]) == ("fusion.33",
+                                              "multiply_add_fusion")
+    assert r["kernels_between_issue_and_consumer"] == 0
+    assert r["kernels_under_the_collective"] == 0
+    ops = [row["op"] for row in r["order"]]
+    assert ops[-3:] == ["fusion.33", "psum.79", "multiply_add_fusion"]
+    assert ops.index("all-reduce") < ops.index("psum.79")
+    assert sorted(row["op"] for row in r["order"]
+                  if row["role"] == "bwd kernel") == sorted(_BACKWARD)
+
+
+def test_issue_order_of_the_largest_leaf_summed_first():
+    """The step as the engine's options make the compiler schedule it: an
+    async pair, started behind the gradient's producer and ended in front
+    of the update, each of the seven backward kernels wrapped in a fusion
+    that runs a share of the collective; the other leaves' all-reduce
+    behind the update."""
+    r = _issue_order("dp4_step_largest_first.txt")
+    assert (r["collective"], r["form"]) == ("async-collective-start",
+                                            "async pair")
+    assert r["collective_bytes"] == 18000000 * 16 * 4
+    assert (r["gradient"], r["consumer"]) == ("fusion.34",
+                                              "multiply_add_fusion")
+    assert r["kernels_between_issue_and_consumer"] == 7
+    assert r["kernels_under_the_collective"] == 7
+    rows = r["order"]
+    start = next(i for i, row in enumerate(rows) if row["role"] == "collective")
+    assert rows[start - 1]["role"] == "gradient"
+    assert [row["op"] for row in rows[start + 1:start + 8]] == _BACKWARD
+    assert all(row["role"] == "bwd kernel" for row in rows[start + 1:start + 8])
+    assert [row["role"] for row in rows[start + 8:]] == [
+        "collective done", "consumer", "other collective"]
+
+
+def _with_vmem(text, sizes):
+    """``text`` with a ``backend_config`` as the compiler prints it on each
+    wrapped kernel of ``sizes``: the limit's window first, what the fusion
+    really holds in ``used_scoped_memory_configs``."""
+    out = []
+    for line in text.splitlines():
+        name = line.strip().split(" = ")[0].lstrip("%")
+        if name in sizes:
+            line += (', backend_config={"scoped_memory_configs":[{"memory_'
+                     'space":"1","offset":"16777216","size":"50331648"}],'
+                     '"used_scoped_memory_configs":[{"memory_space":"1",'
+                     f'"offset":"0","size":"{sizes[name]}"}}]}}')
+        out.append(line)
+    return "\n".join(out)
+
+
+@pytest.mark.parametrize("sizes,want", [
+    ({}, 0),                                     # the trimmed fixture itself
+    ({"conv2.5": 42053632, "fc.3": 25731072}, 42053632),
+    # a kernel outside the pair does not count: it holds no collective
+    ({"fc.2": 99999999, "bn2.fused.4": 19640320}, 19640320),
+])
+def test_issue_order_reads_the_vmem_the_wrapped_kernels_hold(sizes, want):
+    with open(os.path.join(_FIXTURES, "dp4_step_largest_first.txt")) as f:
+        r = issue_order_report(_with_vmem(f.read(), sizes))
+    assert r["scoped_vmem_bytes_under_collective"] == want
+    assert r["kernels_under_the_collective"] == 7
+
+
+def test_issue_order_without_a_collective():
+    assert issue_order_report(_LOOP_HLO)["collective"] is None

@@ -9,13 +9,15 @@ reports, per collective:
 - async ``-start``/``-done`` pairs (the GPU-style spelling): how many
   compute ops (dot / convolution / fusion / custom-call) sit strictly
   between start and done — >=1 means the latency hides under compute;
-- synchronous collectives (the TPU spelling: this libtpu never splits
-  collectives into HLO async pairs — overlap happens below HLO, in the
-  TensorCore emitter, when ``xla_tpu_overlap_compute_collective_tc`` is
-  on): whether the op is SCHEDULED before the last backward compute op
-  (metadata ``op_name=".../transpose(..."`` marks backprop). A collective
-  issued while backward work remains is an interleaved issue point — the
-  monolithic sync can only ever sit after the last gradient;
+- synchronous collectives (how this libtpu prints a bucket's all-reduce:
+  one instruction, no HLO async pair): whether the op is SCHEDULED before
+  the last backward compute op (metadata ``op_name=".../transpose(..."``
+  marks backprop). That is an issue point and no more: nothing measured
+  says such an instruction runs under the operations behind it. What was
+  measured to (PERF.md section 6, PR 34) is the other form this libtpu has,
+  an ``async-collective-start`` / ``-done`` pair of fusions with the
+  kernels between them wrapped in ``async_collective_fusion``s, which
+  ``--cell-step`` reads;
 - exposed vs overlapped communication bytes, and the receipt the bucketing
   exists to produce: ``all_reduce_issues_before_last_bwd_compute >= 1``.
 
@@ -26,10 +28,24 @@ DataParallel step, and analyzes the result. Single-process like the other
 AOT tools: do not run two at once. Estimates of schedule structure, not
 measured step time; the benchmark (benchmark/run.py) owns measured truth.
 
+``--cell-step`` compiles the four-chip cell's own step instead (the
+3000^2 ``s2dt`` ConvNet, bf16, batch 5 a chip, SGD, built by
+``DataParallel`` with the options the engine itself hands ``jax.jit``) and
+prints where its largest collective, the fc gradient's all-reduce, stands
+among the backward kernels: ``kernels_between_issue_and_consumer`` is 7
+(``fc``'s own input-gradient kernel and the six of the convolutions)
+where the engine sums that leaf first and compiles with its options, and
+0 where one ``pmean`` trails the step, as before PR 34. 25-45 s.
+
 Usage:
   python tools/hlo_schedule.py                       # compile + analyze
   python tools/hlo_schedule.py --no-overlap          # monolithic baseline
   python tools/hlo_schedule.py --hlo-file dump.txt   # re-analyze a dump
+  python tools/hlo_schedule.py --cell-step           # the dp4 cell's order
+  python tools/hlo_schedule.py --cell-step --image-size 2000 --dtype fp32
+                                    # the same engine at another shape: does
+                                    # it compile, and the VMEM its wrapped
+                                    # kernels hold beside the collective
 """
 
 from __future__ import annotations
@@ -62,17 +78,6 @@ from hlo_traffic import (  # noqa: E402
 _COMPUTE = ("dot", "convolution", "fusion", "custom-call")
 
 _BWD = re.compile(r'op_name="[^"]*transpose\(')
-
-#: Latency-hiding / async-collective knobs for TPU AOT compiles (the
-#: compile-option spelling of MaxText's LIBTPU_INIT_ARGS). Verified to
-#: exist on the local libtpu; the TC-overlap flag is what makes the
-#: sync-form collectives below actually run concurrently with compute.
-TPU_OVERLAP_COMPILER_OPTIONS = {
-    "xla_tpu_enable_latency_hiding_scheduler": "true",
-    "xla_tpu_overlap_compute_collective_tc": "true",
-    "xla_tpu_enable_async_collective_fusion": "true",
-    "xla_enable_async_all_reduce": "true",
-}
 
 
 def schedule_report(hlo_text: str) -> dict:
@@ -191,6 +196,154 @@ def schedule_report(hlo_text: str) -> dict:
     }
 
 
+def _entry_instructions(hlo_text: str) -> list[tuple[str, str, str, str]]:
+    """(name, result shape, opcode, rest of the line) of the ENTRY
+    computation's instructions, in the order printed: the schedule, in a
+    module printed with ``is_scheduled=true``."""
+    ops, inside = [], False
+    for line in hlo_text.splitlines():
+        stripped = line.strip()
+        if stripped.endswith("{") and "->" in stripped:
+            inside = stripped.startswith("ENTRY")
+            continue
+        m = _INST.match(line) if inside else None
+        if m:
+            ops.append(m.groups())
+    return ops
+
+
+_START, _DONE = "async-collective-start", "async-collective-done"
+_USED_VMEM = re.compile(
+    r'"used_scoped_memory_configs":\[\{"memory_space":"1","offset":"\d+",'
+    r'"size":"(\d+)"')
+
+
+def issue_order_report(hlo_text: str) -> dict:
+    """Where the step's largest all-reduce stands among its kernels.
+
+    Pure text analysis of a scheduled module. The largest all-reduce of
+    the ENTRY computation (by result bytes: the fc gradient's, in the
+    ConvNet step) is either one ``all-reduce`` instruction, issued and
+    waited for where it is printed, or what this libtpu makes of it under
+    the engine's options: an ``async-collective-start`` fusion, the
+    kernels it runs under each wrapped in an ``async_collective_fusion``
+    of their own, and an ``async-collective-done``. ``order`` lists the
+    gradient's producer, the collectives, every kernel (a ``custom-call``
+    or such a wrapper; ``bwd`` where the line's ``op_name`` says
+    transpose) and the first reader of the sum, as scheduled;
+    ``kernels_between_issue_and_consumer`` counts the kernels between the
+    issue and that reader, ``kernels_under_the_collective`` those between
+    issue and wait. It is the pair that was measured to run under its
+    kernels (PERF.md section 6, PR 34); for one instruction the second
+    count is 0 wherever it stands. ``scoped_vmem_bytes_under_collective``
+    is the most scoped VMEM one of those wrapped kernels holds, the
+    collective's buffers included: what has to stay under the engine's
+    ``xla_tpu_scoped_vmem_limit_kib`` for the step to compile at all (0
+    where the text carries no ``backend_config``, as the trimmed fixtures
+    do not)."""
+    ops = _entry_instructions(hlo_text)
+
+    def reads(i, name):
+        return name in operand_tokens(ops[i][3])
+
+    def first_reader(start, name):
+        return next((i for i in range(start + 1, len(ops))
+                     if reads(i, name)), None)
+
+    def is_kernel(i):
+        _, _, opcode, rest = ops[i]
+        return opcode == "custom-call" or (
+            opcode == "fusion" and "calls=%async_collective_fusion" in rest)
+
+    at = {name: i for i, (name, *_) in enumerate(ops)}
+    # (issue, wait, bytes): a start and the done of the same number, or an
+    # all-reduce instruction that is both
+    found = [(i, i, shape_bytes(op[1])) for i, op in enumerate(ops)
+             if op[2] == "all-reduce"]
+    for name, i in at.items():
+        done = at.get(_DONE + name[len(_START):])
+        if name.startswith(_START) and done is not None:
+            found.append((i, done, shape_bytes(ops[done][1])))
+    if not found:
+        return {"collective": None, "form": None,
+                "kernels_between_issue_and_consumer": 0, "order": []}
+    issue, wait, nbytes = max(found, key=lambda f: f[2])
+    consumer = first_reader(wait, ops[wait][0])
+    producer = next((i for i in range(issue) if reads(issue, ops[i][0])),
+                    None)
+    end = len(ops) if consumer is None else consumer
+    role = {producer: "gradient", issue: "collective", consumer: "consumer"}
+    if wait != issue:
+        role[wait] = "collective done"
+    order = []
+    for i, (name, _shape, opcode, rest) in enumerate(ops):
+        if i in role:
+            order.append({"op": name, "opcode": opcode, "role": role[i]})
+        elif is_kernel(i):
+            order.append({"op": name, "opcode": opcode, "role":
+                          "bwd kernel" if _BWD.search(rest) else "kernel"})
+        elif opcode == "all-reduce" or name.startswith((_START, _DONE)):
+            order.append({"op": name, "opcode": opcode,
+                          "role": "other collective"})
+    return {
+        "collective": ops[issue][0],
+        "form": "async pair" if wait != issue else "one instruction",
+        "collective_bytes": nbytes,
+        "gradient": None if producer is None else ops[producer][0],
+        "consumer": None if consumer is None else ops[consumer][0],
+        "kernels_between_issue_and_consumer": sum(
+            is_kernel(i) for i in range(issue + 1, end)),
+        "kernels_under_the_collective": sum(
+            is_kernel(i) for i in range(issue + 1, wait)),
+        "scoped_vmem_bytes_under_collective": max(
+            (int(m.group(1)) for i in range(issue + 1, wait) if is_kernel(i)
+             for m in [_USED_VMEM.search(ops[i][3])] if m), default=0),
+        "order": order,
+    }
+
+
+def lower_cell_step(devices, *, image_size: int = 3000,
+                    batch_per_rank: int = 5, plan: str = "s2dt",
+                    dtype: str = "bf16", opt: str = "sgd"):
+    """Lower ``convnet3000_dp4_bs5``'s step on ``devices`` from shapes
+    alone: ``pick_convnet(.., plan="s2dt", dtype=bf16)``, ``optax.sgd``,
+    ``DataParallel`` as ``mnist_distributed.build`` makes it. Compiling the
+    result applies the options the engine gave ``jax.jit``. The other
+    arguments are ``mnist_distributed``'s ``--image-size``, ``--batch-size``,
+    ``--plan``, ``--dtype`` and ``--opt``: the shapes its users can ask
+    the same engine for."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import optax
+    from jax.sharding import Mesh
+
+    from tpu_sandbox.models import pick_convnet
+    from tpu_sandbox.parallel import DataParallel
+    from tpu_sandbox.train import TrainState
+
+    devices = np.array(devices)
+    world = devices.size
+    compute = jnp.bfloat16 if dtype == "bf16" else jnp.float32
+    model = pick_convnet(image_size, plan=plan, dtype=compute)
+    tx = {"sgd": optax.sgd(1e-4), "momentum": optax.sgd(1e-4, momentum=0.9),
+          "adamw": optax.adamw(1e-4)}[opt]
+    state = jax.eval_shape(lambda: TrainState.create(
+        model, jax.random.key(0),
+        jnp.zeros((1, image_size, image_size, 1), compute), tx,
+    ))
+    state = state.replace(batch_stats=jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct((world, *s.shape), s.dtype),
+        state.batch_stats))
+    dp = DataParallel(model, tx, Mesh(devices, ("data",)),
+                      image_size=(image_size, image_size))
+    return dp.lower_step(
+        state,
+        jax.ShapeDtypeStruct((world * batch_per_rank, 28, 28, 1),
+                             jnp.float32),
+        jax.ShapeDtypeStruct((world * batch_per_rank,), jnp.int32))
+
+
 def lower_overlapped_step(devices, *, batch_per_rank: int = 8,
                           bucket_mb: float = 0.02,
                           grad_compress: str = "none",
@@ -240,6 +393,9 @@ def build_overlapped_hlo(devices, *, compiler_options: dict | None = None,
                          **step) -> str:
     """AOT-compile ``lower_overlapped_step(devices, **step)`` and return
     the optimized HLO text."""
+    from tpu_sandbox.parallel.data_parallel import (
+        TPU_OVERLAP_COMPILER_OPTIONS)
+
     lowered = lower_overlapped_step(devices, **step)
     try:
         return lowered.compile(
@@ -258,12 +414,25 @@ def main():
                    help="compile-only TPU topology (needs >1 chip for "
                         "cross-chip collectives to exist)")
     p.add_argument("--chips-per-host", default="2,2,1")
-    p.add_argument("--batch-per-rank", type=int, default=8)
+    p.add_argument("--batch-per-rank", type=int, default=None,
+                   help="default 8, and 5 with --cell-step")
     p.add_argument("--bucket-mb", type=float, default=0.02)
     p.add_argument("--grad-compress", choices=["none", "bf16", "int8"],
                    default="none")
     p.add_argument("--no-overlap", action="store_true",
                    help="monolithic single-all-reduce baseline")
+    p.add_argument("--cell-step", action="store_true",
+                   help="compile convnet3000_dp4_bs5's own step and print "
+                        "where the fc gradient's all-reduce is scheduled")
+    p.add_argument("--image-size", type=int, default=3000,
+                   help="--cell-step: the engine's step at another of "
+                        "mnist_distributed's shapes (with --batch-per-rank, "
+                        "--plan, --dtype, --opt)")
+    p.add_argument("--plan", choices=["s2dt", "s2d", "plain"],
+                   default="s2dt")
+    p.add_argument("--dtype", choices=["bf16", "fp32"], default="bf16")
+    p.add_argument("--opt", choices=["sgd", "momentum", "adamw"],
+                   default="sgd")
     p.add_argument("--hlo-file", default=None,
                    help="re-analyze an existing optimized-HLO dump instead "
                         "of recompiling")
@@ -283,11 +452,17 @@ def main():
             args.topology,
             tuple(int(x) for x in args.chips_per_host.split(",")),
         )
-        text = build_overlapped_hlo(
-            topo.devices, batch_per_rank=args.batch_per_rank,
-            bucket_mb=args.bucket_mb, grad_compress=args.grad_compress,
-            overlap=not args.no_overlap,
-        )
+        if args.cell_step:
+            text = lower_cell_step(
+                topo.devices, image_size=args.image_size,
+                batch_per_rank=args.batch_per_rank or 5, plan=args.plan,
+                dtype=args.dtype, opt=args.opt).compile().as_text()
+        else:
+            text = build_overlapped_hlo(
+                topo.devices, batch_per_rank=args.batch_per_rank or 8,
+                bucket_mb=args.bucket_mb, grad_compress=args.grad_compress,
+                overlap=not args.no_overlap,
+            )
         source = (
             f"chipless {args.topology} AOT compile "
             "(schedule structure, not measured time)"
@@ -295,6 +470,13 @@ def main():
         if args.dump_hlo:
             open(args.dump_hlo, "w").write(text)
 
+    if args.cell_step:
+        report = issue_order_report(text)
+        for row in report.pop("order"):
+            print(f"{row['role']:>16}  {row['opcode']:<16} {row['op']}")
+        report["source"] = source
+        print(json.dumps(report))
+        return
     report = schedule_report(text)
     if not args.detail:
         report.pop("collectives")

@@ -2,15 +2,20 @@
 
 PyTorch DDP's C++ reducer groups gradients into ~25MB buckets and launches
 one async NCCL all-reduce per bucket as soon as the bucket's grads are
-produced, hiding comm under the rest of backward. The XLA analogue is
-structural, not imperative: emit one independent collective per bucket over
-a flat buffer, and the latency-hiding scheduler is free to hoist each
-``all-reduce-start`` to right after the last contributing cotangent and sink
-the matching ``all-reduce-done`` below later backward dots. A single
-monolithic sync (or one collective per leaf, which the all-reduce combiner
-may refuse to merge across dtypes) gives the scheduler strictly less
-freedom; size-targeted flat buckets are the shape it wants
-(``tools/hlo_schedule.py`` is the receipt).
+produced, hiding comm under the rest of backward. Here the buckets are
+structural: one independent collective per bucket over a flat buffer,
+issued in the order the backward pass produces them and chained by
+``optimization_barrier`` so that the all-reduce combiner cannot merge them
+again. What that buys on a TPU has never been measured, and the schedules
+say less than this docstring once claimed: this libtpu prints no
+``all-reduce-start`` / ``-done`` pair for a bucket, only a synchronous
+``all-reduce`` placed among the backward operations
+(``tools/hlo_schedule.py``: 3 of 4 before the last of them, on the MNIST
+ConvNet), and where one leaf carries the step's bytes, as in the 3000^2
+ConvNet, bucketing changes nothing: XLA already takes that leaf's
+all-reduce apart from the small ones and schedules it, with its producer,
+behind the whole backward pass (PERF.md section 6, PR 34; what does move
+it there is ``data_parallel._pmean_largest_first``).
 
 Composition: each bucket goes through one :class:`CompressedAllReduce`
 exchange — ``none`` stays a plain ``lax.pmean`` of the flat buffer
