@@ -76,7 +76,21 @@ def build(args, devices):
     published keys of the file (dp only); the size flags then do not apply.
     A caller that has read the file already sets ``args.config`` to its
     content.
+
+    The whole of it runs under the span ``setup:build``, the root of the
+    launch's record: ``setup:model_init``, ``setup:opt_init`` and
+    ``place:state`` are its children, and every program traced, lowered or
+    compiled on the way is recorded under the innermost of them
+    (``runtime/bootstrap.py``). The token stream is made by ``train``, so
+    there is no ``setup:data`` here.
     """
+    from tpu_sandbox.obs import get_recorder
+
+    with get_recorder().span("setup:build", loop=True):
+        return _build(args, devices)
+
+
+def _build(args, devices):
     import jax
     import jax.numpy as jnp
     import optax
@@ -295,19 +309,22 @@ def train(args):
     start = datetime.datetime.now()
     losses = []
     spans = LoopSpans()  # the same four spans as Trainer's loop
-    for step, (tokens, targets) in enumerate(spans.batches(
-        make_batches(vocab, args.batch, args.seq_len, args.steps, 0)), 1
-    ):
-        with spans.dispatch():
-            state, loss = eng.train_step(
-                state, *eng.shard_batch(tokens, targets))
-        spans.returned(step)
-        if step % args.log_every == 0 or step == args.steps:
-            with spans.sync("log"):
-                loss_v = float(np.ravel(np.asarray(loss))[0])
-            losses.append(loss_v)
-            print(f"[{p}] Step [{step}/{args.steps}], Loss: {loss_v:.4f}",
-                  flush=True)
+    try:
+        for step, (tokens, targets) in enumerate(spans.batches(
+            make_batches(vocab, args.batch, args.seq_len, args.steps, 0)), 1
+        ):
+            with spans.dispatch():
+                state, loss = eng.train_step(
+                    state, *eng.shard_batch(tokens, targets))
+            spans.returned(step)
+            if step % args.log_every == 0 or step == args.steps:
+                with spans.sync("log"):
+                    loss_v = float(np.ravel(np.asarray(loss))[0])
+                losses.append(loss_v)
+                print(f"[{p}] Step [{step}/{args.steps}], Loss: {loss_v:.4f}",
+                      flush=True)
+    finally:
+        spans.ended()
     spans.rec.flush()
     print(f"Training complete in: {datetime.datetime.now() - start}")
     if len(losses) >= 2 and not losses[-1] < losses[0]:

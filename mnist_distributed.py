@@ -78,35 +78,43 @@ def build(args, world_size):
 
     from tpu_sandbox.data import ShardedBatchLoader
     from tpu_sandbox.models import pick_convnet
+    from tpu_sandbox.obs import get_recorder
     from tpu_sandbox.parallel import DataParallel
     from tpu_sandbox.runtime.mesh import make_mesh
     from tpu_sandbox.train import TrainState
     from tpu_sandbox.utils.cli import ensure_devices
 
-    devices = ensure_devices(world_size, force_cpu=args.force_cpu)
-    mesh = make_mesh({"data": world_size}, devices=devices)
+    rec = get_recorder()
+    # ``setup:build`` is the launch's root span (``mnist_onegpu.build``)
+    with rec.span("setup:build", loop=True):
+        devices = ensure_devices(world_size, force_cpu=args.force_cpu)
+        mesh = make_mesh({"data": world_size}, devices=devices)
 
-    rng = jax.random.key(0)  # parity: torch.manual_seed(0), reference :51
-    image_shape = [args.image_size, args.image_size]
-    dtype = jnp.bfloat16 if args.dtype == "bf16" else jnp.float32
-    model = pick_convnet(args.image_size, plan=args.plan,
-                         num_classes=10, dtype=dtype)
-    tx = make_optimizer(args)
+        rng = jax.random.key(0)  # parity: torch.manual_seed(0), reference :51
+        image_shape = [args.image_size, args.image_size]
+        dtype = jnp.bfloat16 if args.dtype == "bf16" else jnp.float32
+        model = pick_convnet(args.image_size, plan=args.plan,
+                             num_classes=10, dtype=dtype)
+        tx = make_optimizer(args)
 
-    images, labels = load_training_arrays(args, world_size)
+        with rec.span("setup:data", hist="setup.data_s", loop=True):
+            images, labels = load_training_arrays(args, world_size)
 
-    # bs per rank (reference :60-61); sampler shards, loader never reshuffles
-    # across epochs (reference quirk: no sampler.set_epoch, SURVEY §2.1 C14)
-    loader = ShardedBatchLoader(
-        images, labels, args.batch_size, world_size, shuffle=True, seed=0
-    )
+            # bs per rank (reference :60-61); sampler shards, loader never
+            # reshuffles across epochs (reference quirk: no
+            # sampler.set_epoch, SURVEY §2.1 C14)
+            loader = ShardedBatchLoader(
+                images, labels, args.batch_size, world_size, shuffle=True,
+                seed=0
+            )
 
-    state = TrainState.create(model, rng, jnp.zeros([1, *image_shape, 1], dtype), tx)
-    dp = DataParallel(model, tx, mesh, image_size=tuple(image_shape),
-                      zero=args.zero, grad_compress=args.grad_compress,
-                      error_feedback=not args.no_error_feedback,
-                      overlap_grad_sync=args.overlap_grad_sync,
-                      bucket_mb=args.bucket_mb)
+        state = TrainState.create(
+            model, rng, jnp.zeros([1, *image_shape, 1], dtype), tx)
+        dp = DataParallel(model, tx, mesh, image_size=tuple(image_shape),
+                          zero=args.zero, grad_compress=args.grad_compress,
+                          error_feedback=not args.no_error_feedback,
+                          overlap_grad_sync=args.overlap_grad_sync,
+                          bucket_mb=args.bucket_mb)
     return dp, state, loader
 
 

@@ -32,6 +32,9 @@ def build(args):
     from tpu_sandbox.models import pick_convnet
     from tpu_sandbox.train import TrainState, make_train_step
 
+    from tpu_sandbox.obs import get_recorder
+
+    rec = get_recorder()
     if args.accum_steps < 1:
         raise SystemExit(f"--accum-steps must be >= 1, got {args.accum_steps}")
     if args.batch_size % args.accum_steps:
@@ -39,45 +42,54 @@ def build(args):
             f"--batch-size {args.batch_size} must be divisible by "
             f"--accum-steps {args.accum_steps}"
         )
-    rng = jax.random.key(0)  # parity: torch.manual_seed(0), reference :35
-    image_shape = [args.image_size, args.image_size]
-    dtype = jnp.bfloat16 if args.dtype == "bf16" else jnp.float32
-    model = pick_convnet(args.image_size, plan=args.plan,
-                         num_classes=10, dtype=dtype)
-    tx = optax.sgd(learning_rate=1e-4)  # reference :49, no momentum
+    # the launch from inside: ``setup:build`` is the root, ``setup:data`` and
+    # ``TrainState.create``'s ``setup:model_init`` / ``setup:opt_init`` are
+    # its children, and every program traced, lowered or compiled on the way
+    # is recorded under the innermost of them (``runtime/bootstrap.py``)
+    with rec.span("setup:build", loop=True):
+        rng = jax.random.key(0)  # parity: torch.manual_seed(0), reference :35
+        image_shape = [args.image_size, args.image_size]
+        dtype = jnp.bfloat16 if args.dtype == "bf16" else jnp.float32
+        model = pick_convnet(args.image_size, plan=args.plan,
+                             num_classes=10, dtype=dtype)
+        tx = optax.sgd(learning_rate=1e-4)  # reference :49, no momentum
 
-    try:
-        images, labels = load_mnist("train", args.data_dir)
-    except FileNotFoundError:
-        print("MNIST IDX files not found; using deterministic synthetic MNIST")
-        images, labels = synthetic_mnist(n=args.synthetic_n, seed=0)
-    if args.limit_steps:
-        images = images[: args.limit_steps * args.batch_size]
-        labels = labels[: args.limit_steps * args.batch_size]
+        with rec.span("setup:data", hist="setup.data_s", loop=True):
+            try:
+                images, labels = load_mnist("train", args.data_dir)
+            except FileNotFoundError:
+                print("MNIST IDX files not found; using deterministic "
+                      "synthetic MNIST")
+                images, labels = synthetic_mnist(n=args.synthetic_n, seed=0)
+            if args.limit_steps:
+                images = images[: args.limit_steps * args.batch_size]
+                labels = labels[: args.limit_steps * args.batch_size]
 
-    # reference :55-59: shuffle=True, num_workers=0. --native-loader swaps in
-    # the C++ worker-pool loader (gather+normalize off the Python thread).
-    # accumulation needs every batch divisible into microbatches: drop the
-    # ragged tail instead of crashing on it at the end of an epoch
-    drop_last = args.accum_steps > 1
-    if args.native_loader:
-        from tpu_sandbox.data.native_loader import NativeBatchLoader
+            # reference :55-59: shuffle=True, num_workers=0. --native-loader
+            # swaps in the C++ worker-pool loader (gather+normalize off the
+            # Python thread). accumulation needs every batch divisible into
+            # microbatches: drop the ragged tail instead of crashing on it
+            # at the end of an epoch
+            drop_last = args.accum_steps > 1
+            if args.native_loader:
+                from tpu_sandbox.data.native_loader import NativeBatchLoader
 
-        loader = NativeBatchLoader(
-            images, labels, args.batch_size, shuffle=True, seed=0, threads=2,
-            drop_last=drop_last,
+                loader = NativeBatchLoader(
+                    images, labels, args.batch_size, shuffle=True, seed=0,
+                    threads=2, drop_last=drop_last,
+                )
+            else:
+                loader = BatchLoader(
+                    normalize(images), labels.astype("int32"),
+                    args.batch_size, shuffle=True, seed=0,
+                    drop_last=drop_last,
+                )
+
+        state = TrainState.create(
+            model, rng, jnp.zeros([1, *image_shape, 1], dtype), tx
         )
-    else:
-        loader = BatchLoader(
-            normalize(images), labels.astype("int32"), args.batch_size,
-            shuffle=True, seed=0, drop_last=drop_last,
-        )
-
-    state = TrainState.create(
-        model, rng, jnp.zeros([1, *image_shape, 1], dtype), tx
-    )
-    step = make_train_step(model, tx, image_size=tuple(image_shape),
-                           accum_steps=args.accum_steps)
+        step = make_train_step(model, tx, image_size=tuple(image_shape),
+                               accum_steps=args.accum_steps)
     return model, state, step, loader
 
 
@@ -184,8 +196,13 @@ def build_parser():
                              "DIR (view in TensorBoard/Perfetto): device ops "
                              "on the /device:TPU:N planes and, on the same "
                              "clock, the program's own spans (train:next_batch"
-                             ", train:dispatch, train:sync, setup:*) on "
-                             "/host:CPU")
+                             ", train:dispatch, train:sync; setup:build with "
+                             "setup:data, setup:model_init, setup:opt_init; "
+                             "trace:kernel at every Pallas call site) on "
+                             "/host:CPU. With TPU_SANDBOX_TRACE_DIR set the "
+                             "same spans, every program's compile:trace / "
+                             "compile:lower / compile:backend and any "
+                             "compile:in_loop go to a JSONL log")
     parser.add_argument("--eval", action="store_true",
                         help="report test-set accuracy after training")
     parser.add_argument("--resume", action="store_true",

@@ -106,6 +106,22 @@ def light_compile():
     jax.config.update("jax_disable_most_optimizations", False)
 
 
+@pytest.fixture
+def launch():
+    """The program's record of its own launch (``runtime/bootstrap.py``: the
+    compile listeners and the program table) over an empty registry, on for
+    one test and off again."""
+    from tpu_sandbox.obs import get_registry
+    from tpu_sandbox.runtime import bootstrap
+
+    get_registry().reset()
+    bootstrap.reset_launch_record()
+    bootstrap._count_cache_events()
+    yield bootstrap
+    bootstrap.reset_launch_record()
+    get_registry().reset()
+
+
 @pytest.fixture(autouse=True, scope="module")
 def _bounded_jit_caches():
     yield

@@ -252,7 +252,6 @@ def test_lm_train_loop_carries_the_same_spans(registry, dark):
     assert hist["place.state_s"]["count"] == 1
     assert hist["setup.model_init_s"]["count"] == 1
     assert hist["setup.opt_init_s"]["count"] == 1
-    assert snap["counters"]["place.batch_bytes"] == 4 * 2 * (2 * 16 * 4)
 
 
 # -- placement, set-up, the compile cache ----------------------------------------
@@ -279,21 +278,20 @@ def test_placement_and_setup_spans_of_data_parallel(registry, dark):
     snap = registry.snapshot()
     assert snap["histograms"]["place.state_s"]["count"] == 1
     assert snap["histograms"]["place.batch_s"]["count"] == 2
-    assert snap["counters"]["place.batch_bytes"] == \
-        2 * (images.nbytes + labels.nbytes)
 
 
 def test_configure_compile_cache_counts_hits_and_misses(
-        registry, tmp_path, monkeypatch):
-    from tpu_sandbox.runtime import bootstrap
-
+        registry, launch, tmp_path, monkeypatch):
+    bootstrap = launch
+    registry.reset()
     assert "compile.cache_misses" not in registry.snapshot()["counters"]
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     bootstrap.configure_compile_cache()
     bootstrap.configure_compile_cache()  # registers its listener once
     # both exist from the call on: a warm run reads 0, not nothing
     assert registry.snapshot()["counters"] == {
-        "compile.cache_hits": 0, "compile.cache_misses": 0}
+        "compile.cache_hits": 0, "compile.cache_misses": 0,
+        "compile.in_loop": 0}
     # what jax reports through jax.monitoring lands in the counters (the
     # names are jax's own: _src/compiler.py, _src/compilation_cache.py)
     from jax._src import monitoring
@@ -303,7 +301,8 @@ def test_configure_compile_cache_counts_hits_and_misses(
     monitoring.record_event("/jax/compilation_cache/cache_hits")
     monitoring.record_event("/jax/compilation_cache/tasks_using_cache")
     assert registry.snapshot()["counters"] == {
-        "compile.cache_hits": 2, "compile.cache_misses": 1}
+        "compile.cache_hits": 2, "compile.cache_misses": 1,
+        "compile.in_loop": 0}
 
 
 # -- scopes inside the compiled steps ----------------------------------------------
@@ -530,3 +529,313 @@ for _ in range(5):
     (log,) = os.listdir(tmp_path)
     names = [json.loads(line).get("name") for line in open(tmp_path / log)]
     assert names.count("place:batch") == 5
+
+
+# -- the launch seen from inside (PR 35) ------------------------------------------
+
+
+def _records(path):
+    return [json.loads(line) for line in open(path)][1:]
+
+
+def test_a_loop_span_records_the_span_it_was_opened_inside(registry, tmp_path):
+    rec = Recorder(str(tmp_path / "t.jsonl"), proc="unit")
+    with rec.span("setup:build", loop=True):
+        assert rec.innermost() == "setup:build"
+        with rec.span("setup:model_init", hist="setup.model_init_s",
+                      loop=True):
+            with rec.span("trace:kernel", loop=True):
+                assert rec.innermost() == "trace:kernel"
+                # what the compile listener asks: kernel sites passed over
+                assert rec.innermost(skip="trace:") == "setup:model_init"
+            rec.complete("compile:trace", time.monotonic() - 0.01, loop=True)
+        # a request span names its parent itself and is no one's child here
+        with rec.span("submit") as root:
+            with rec.span("admit", parent=root.ctx):
+                pass
+    assert rec.innermost() is None
+    rec.close()
+    by_name = {r["name"]: r for r in _records(tmp_path / "t.jsonl")}
+    assert by_name["setup:build"]["parent"] is None
+    assert by_name["setup:model_init"]["parent"] == "setup:build"
+    assert by_name["trace:kernel"]["parent"] == "setup:model_init"
+    assert by_name["compile:trace"]["parent"] == "setup:model_init"
+    assert by_name["submit"]["parent"] is None
+    assert by_name["admit"]["parent"] == by_name["submit"]["span"]
+    # self time = duration less what the children cover
+    build, init = by_name["setup:build"], by_name["setup:model_init"]
+    assert build["ts"] <= init["ts"] and init["dur"] <= build["dur"]
+
+
+@pytest.mark.parametrize("how", ["exception", "out_of_order", "other_thread"])
+def test_the_stack_of_open_spans_is_empty_after(how, dark):
+    rec = dark
+    if how == "exception":
+        with pytest.raises(RuntimeError):
+            with rec.span("setup:build", loop=True):
+                with rec.span("setup:data", loop=True):
+                    raise RuntimeError("the loader failed")
+    elif how == "out_of_order":
+        outer = rec.begin_span("engine:step", loop=True)
+        try:
+            inner = rec.begin_span("engine:admit", loop=True)
+            try:
+                outer.close()  # the outer one first: it leaves by identity
+                assert rec.innermost() == "engine:admit"
+            finally:
+                inner.close()
+        finally:
+            outer.close()      # a second close is a no-op
+    else:
+        import threading
+
+        sp = rec.begin_span("engine:step", loop=True)
+        try:
+            t = threading.Thread(target=sp.close)
+            t.start()
+            t.join(10)
+        finally:
+            sp.close()
+    assert rec.innermost() is None and rec._open_spans() == []
+
+
+def _hist_sums(reg, name):
+    """``under`` label -> (count, sum) of the histogram ``name``."""
+    out = {}
+    for key, h in reg.snapshot()["histograms"].items():
+        if key.startswith(name + "{under="):
+            out[key[len(name) + 7:-1]] = (h["count"], h["sum"])
+    return out
+
+
+def test_a_jit_inside_a_jit_counts_its_trace_seconds_once(
+        registry, dark, launch):
+    """jax reports the inner function's trace before, and within, the
+    outer's. The histograms observe each phase less what lies inside it, so
+    their sum is the outermost interval; the table keeps whole seconds by
+    program."""
+    @jax.jit
+    def launch_inner(x):
+        time.sleep(0.05)          # at trace time
+        return x * 2
+
+    @jax.jit
+    def launch_outer(x):
+        time.sleep(0.02)
+        return launch_inner(x) + launch_inner(x + 1)
+
+    launch_outer.lower(np.ones(3, np.float32))
+    gauges = registry.snapshot()["gauges"]
+    outer = gauges[
+        "compile.program_s{cache=none,phase=trace,program=jit(launch_outer)}"]
+    inner = gauges[
+        "compile.program_s{cache=none,phase=trace,program=jit(launch_inner)}"]
+    assert inner >= 0.05 and outer >= 0.07 and outer >= inner + 0.02
+    traces = _hist_sums(registry, "compile.trace_s")
+    assert set(traces) == {"none"}
+    count, total = traces["none"]
+    assert count >= 3                       # outer, inner twice, the jnp calls
+    assert total == pytest.approx(outer, abs=0.005)   # not outer + inner
+    lowered = _hist_sums(registry, "compile.lower_s")
+    assert lowered["none"][0] == 1          # one module: the outer program's
+    assert "compile.backend_s{under=none}" not in \
+        registry.snapshot()["histograms"]   # lowered, never compiled
+
+
+def test_phases_carry_the_span_they_ran_under(registry, launch, tmp_path,
+                                              monkeypatch):
+    from tpu_sandbox.train import TrainState
+
+    monkeypatch.setenv("TPU_SANDBOX_TRACE_DIR", str(tmp_path))
+    reset_recorder()
+    try:
+        class Tiny:
+            def init(self, rng, x):
+                return {"params": {"w": jax.random.normal(rng, (4, 3)) + x}}
+
+        TrainState.create(Tiny(), jax.random.key(0), jnp.zeros(()),
+                          optax.adam(1e-2))     # two moments: zeros_like
+        jax.jit(lambda v: v * 3 + 1)(np.float32(2))   # under no span
+        get_recorder().flush()
+    finally:
+        reset_recorder()
+    backend = _hist_sums(registry, "compile.backend_s")
+    assert backend["setup:model_init"][0] >= 2     # eager: a program an op
+    assert backend["none"][0] >= 1     # the key, the sample, the lambda
+    assert "setup:opt_init" in _hist_sums(registry, "compile.trace_s")
+    (log,) = [f for f in os.listdir(tmp_path) if f.endswith(".jsonl")]
+    records = _records(tmp_path / log)
+    phases = [r for r in records if r["name"].startswith("compile:")]
+    assert {r["name"] for r in phases} == {
+        "compile:trace", "compile:lower", "compile:backend"}
+    under = {r["args"]["under"] for r in phases}
+    assert under == {"setup:model_init", "setup:opt_init", "none"}
+    for r in phases:   # a loop record's parent is the span it ran under
+        assert (r["parent"] or "none") == r["args"]["under"]
+        assert r["args"]["program"].startswith("jit(")
+        assert "step" not in r["args"]             # no loop runs
+    lam = [r for r in phases if r["args"]["program"] == "jit(<lambda>)"]
+    assert {r["name"] for r in lam} == {
+        "compile:trace", "compile:lower", "compile:backend"}
+    assert all(r["args"]["cache"] == "none" for r in lam)
+
+
+def test_the_program_table_keeps_the_largest_and_a_backend_phase_its_cache(
+        registry, launch):
+    from jax._src import monitoring
+
+    table = launch.PROGRAM_TABLE
+
+    def phase(name, seconds, event="backend_compile_duration"):
+        monitoring.record_event_duration_secs(
+            "/jax/core/compile/" + event, seconds, fun_name=name)
+
+    phase("train_step", 10.0, "jaxpr_trace_duration")
+    monitoring.record_event("/jax/compilation_cache/cache_hits")
+    phase("jit(train_step)", 0.15)               # a load: the event fell in it
+    for i in range(table + 8):                   # a flood of small programs
+        phase(f"jit(op{i})", 0.001 * (i + 1))
+    monitoring.record_event("/jax/compilation_cache/cache_misses")
+    phase("jit(reference)", 5.0)
+    gauges = registry.snapshot()["gauges"]
+    assert gauges["compile.program_s{cache=none,phase=trace,"
+                  "program=jit(train_step)}"] == 10.0
+    assert gauges["compile.program_s{cache=hit,phase=backend,"
+                  "program=jit(train_step)}"] == 0.15
+    assert gauges["compile.program_s{cache=miss,phase=backend,"
+                  "program=jit(reference)}"] == 5.0
+    programs = {key[key.index("program=") + 8:-1] for key in gauges}
+    assert len(programs) == table                # bounded, by program
+    assert "jit(op0)" not in programs            # the smallest left
+    assert f"jit(op{table + 7})" in programs
+
+
+def test_a_compile_inside_the_loop_is_counted_where_it_happens(
+        registry, launch, tmp_path, monkeypatch):
+    from tpu_sandbox.train import Trainer
+
+    monkeypatch.setenv("TPU_SANDBOX_TRACE_DIR", str(tmp_path))
+    reset_recorder()
+    try:
+        @jax.jit
+        def ragged_step(state, images, labels=None):
+            loss = jnp.sum(images)
+            return state.replace(step=state.step + 1), loss
+
+        batches = _batches(3) + [
+            (np.ones((1, 4), np.float32), np.zeros((1,), np.int32))]
+        state = _state()
+        # what a fit dispatches round its loop (it reads state.step, and
+        # waits at its end), compiled before the loop under test
+        Trainer(ragged_step, verbose=False).fit(state, [], 0)
+        jax.block_until_ready(ragged_step(state, *batches[0]))
+        before = registry.snapshot()["counters"]["compile.in_loop"]
+        Trainer(ragged_step, verbose=False).fit(state, batches, 1)
+        assert get_recorder().loop_step is None      # the loop has ended
+        counted = registry.snapshot()["counters"]["compile.in_loop"] - before
+        # after the loop: compiles, and none is counted
+        jax.jit(lambda v: v - 7)(np.float32(1))
+        get_recorder().flush()
+    finally:
+        reset_recorder()
+    assert counted == 1       # the last, ragged batch: one new shape
+    assert registry.snapshot()["counters"]["compile.in_loop"] == before + 1
+    (log,) = [f for f in os.listdir(tmp_path) if f.endswith(".jsonl")]
+    records = _records(tmp_path / log)
+    (instant,) = [r for r in records if r.get("name") == "compile:in_loop"]
+    assert instant["ph"] == "i"
+    assert instant["args"] == {"program": "jit(ragged_step)", "step": 3}
+    stepped = [r for r in records if r["name"].startswith("compile:")
+               and r.get("args", {}).get("step") is not None]
+    assert {r["args"]["program"] for r in stepped if r["ph"] == "X"} >= {
+        "jit(ragged_step)"}
+    assert all(r["args"]["step"] == 3 for r in stepped)
+    assert all(r["args"]["under"] == "train:dispatch" for r in stepped
+               if r["ph"] == "X")
+
+
+def _kernel_hist(reg):
+    """kernel -> sites of ``trace.kernel_s``, over every ``under``."""
+    out = {}
+    for key, h in reg.snapshot()["histograms"].items():
+        if key.startswith("trace.kernel_s{kernel="):
+            kernel = key[len("trace.kernel_s{kernel="):].split(",")[0]
+            out[kernel] = out.get(kernel, 0) + h["count"]
+    return out
+
+
+def test_kernel_site_fires_once_a_site_and_once_a_shape_when_jitted(
+        registry, dark):
+    """Two call sites of one shape: the grouped product's plain kernel
+    traces at both, mHC's jitted call (``_traced_once``) at the first only,
+    and its choice is still counted at both."""
+    from tpu_sandbox.ops import pallas_mhc as mhc
+    from tpu_sandbox.ops.pallas_grouped_matmul import grouped_matmul
+
+    x = jnp.ones((256, 128), jnp.float32)
+    w = jnp.ones((2, 128, 128), jnp.float32)
+    group = jnp.zeros((2,), jnp.int32)
+
+    def two_products(x, w):
+        return grouped_matmul(grouped_matmul(x, w, group, 128), w, group, 128)
+
+    jax.eval_shape(two_products, x, w)
+    assert _kernel_hist(registry) == {"gmm": 2}
+
+    registry.reset()
+    streams = jnp.ones((4, 2, 16, 128), jnp.bfloat16)
+    y = jnp.ones((2, 16, 128), jnp.bfloat16)
+    h_res = jnp.ones((4, 4, 2, 16), jnp.float32)
+    h_post = jnp.ones((4, 2, 16), jnp.float32)
+    # a width no other test of this process gives the jitted call
+    mhc._post_fwd.clear_cache()
+
+    def two_mixes(streams, y):
+        once = mhc.post(streams, y, h_res, h_post)
+        return mhc.post(once, y, h_res, h_post)
+
+    jax.eval_shape(two_mixes, streams, y)
+    assert _kernel_hist(registry) == {"mhc_post_fwd": 1}
+    choices = {k: v for k, v in registry.snapshot()["counters"].items()
+               if k.startswith("mhc.kernel_choice")}
+    assert list(choices.values()) == [2]
+    assert "kernel=post_fwd" in next(iter(choices))
+
+
+def test_the_choice_counters_read_as_before_through_the_helper(
+        registry, dark):
+    """``attn.tile_choice``, ``ssd.chunk_choice`` and ``moe.share_table``
+    keep their names and labels, one count a traced site; a site with a
+    choice opens the same ``trace:kernel`` span as one without
+    (``dp.grad_sync`` marks no kernel: ``tests/test_grad_sync.py``;
+    ``mhc.kernel_choice`` above)."""
+    import functools
+
+    from tpu_sandbox.ops.pallas_attention import flash_attention
+    from tpu_sandbox.ops.ssd import ssd_scan
+
+    q = jnp.ones((1, 128, 2, 64), jnp.float32)
+    jax.eval_shape(jax.grad(lambda q: flash_attention(q, q, q).sum()), q)
+    counters = registry.snapshot()["counters"]
+    tiles = {k: v for k, v in counters.items()
+             if k.startswith("attn.tile_choice")}
+    kinds = {k.split("kernel=")[1].split(",")[0] for k in tiles}
+    assert kinds == {"fwd", "dkv", "dq"}
+    sites = _kernel_hist(registry)
+    assert {k: sites[k] for k in ("flash_fwd", "flash_dkv", "flash_dq")} == {
+        "flash_fwd": sum(v for k, v in tiles.items() if "kernel=fwd" in k),
+        "flash_dkv": 1, "flash_dq": 1}
+    label_keys = {kv.split("=")[0] for k in tiles
+                  for kv in k[k.index("{") + 1:-1].split(",")}
+    assert label_keys == {"kernel", "block_q", "block_k", "s", "d", "dv",
+                          "steps", "steps_with_work"}
+
+    x = jnp.ones((1, 16, 4, 8), jnp.float32)
+    dt = jnp.ones((1, 16, 4), jnp.float32)
+    a = -jnp.ones((4,), jnp.float32)
+    b = jnp.ones((1, 16, 2, 16), jnp.float32)
+    jax.eval_shape(functools.partial(ssd_scan, chunk=8), x, dt, a, b, b)
+    assert registry.snapshot()["counters"][
+        "ssd.chunk_choice{chunk=8,groups=2,head_dim=8,heads=4,impl=jnp,"
+        "state=16,tokens=16}"] == 1
+    assert _kernel_hist(registry)["ssd_scan"] == 1

@@ -151,6 +151,12 @@ class MetricsRegistry:
                 g = self._gauges[key] = Gauge(key)
             return g
 
+    def drop_gauge(self, name: str, labels: dict | None = None) -> None:
+        """Forget one gauge series: for a bounded table whose rows leave
+        (``compile.program_s``), so that its label set stays bounded."""
+        with self._lock:
+            self._gauges.pop(series_key(name, labels), None)
+
     def histogram(self, name: str, reservoir: int = 512,
                   labels: dict | None = None) -> Histogram:
         key = series_key(name, labels)

@@ -3,10 +3,11 @@ to leave on.
 
 One :class:`Recorder` per process, obtained via :func:`get_recorder`. It
 is **disabled unless** ``TPU_SANDBOX_TRACE_DIR`` is set in the
-environment — on a disabled recorder a span costs its histogram observe
-and a profiler flag test (about a microsecond; PERF.md gives the measured
-cost), every other emit a couple of attribute reads, so instrumentation
-stays in the hot paths unconditionally.
+environment — on a disabled recorder a span costs its histogram observe,
+a profiler flag test and a push and pop on its thread's stack of open
+spans (a few microseconds; PERF.md gives the measured cost), every other
+emit a couple of attribute reads, so instrumentation stays in the hot
+paths unconditionally.
 
 Record forms (one JSON object per line, all timestamps are THIS
 process's ``time.monotonic()`` seconds — never wall clock, never another
@@ -46,6 +47,18 @@ GL-O402), recorder enabled or not; (c) writes the JSONL ``X`` record, only
 when enabled. A span names work and ends when that work has ended: a span
 around a device dispatch measures the enqueue and must say so in its name
 (``train:dispatch``); one named for device work ends after a wait.
+
+Who caused a span. A request span names its parent explicitly (a
+:class:`TraceContext`, whose span id is the record's ``parent``). A
+``loop=True`` span has no request to belong to: the recorder keeps, per
+thread, a stack of the spans that are open, and such a span's ``parent``
+in the JSONL is the NAME of the innermost span open on its thread when it
+was opened (``setup:model_init`` under ``setup:build``, a ``compile:trace``
+record under ``setup:model_init``), or null at the top. Self time is a
+span's duration less what its children cover. The same stack answers
+:meth:`Recorder.innermost`, which is how the compile listener
+(``runtime/bootstrap.py``) labels a program's phases with the span they
+ran ``under``; it is kept whether the recorder is enabled or not.
 
 Span discipline: ``with rec.span(name) as sp`` is the sanctioned form;
 ``begin_span`` exists for the rare span that cannot be a ``with`` block
@@ -121,9 +134,10 @@ def _annotation(name: str):
     return ann
 
 
-def _observe(hist: str | None, seconds: float) -> None:
+def _observe(hist: str | None, seconds: float,
+             labels: dict | None = None) -> None:
     if hist is not None:
-        get_registry().histogram(hist).observe(seconds)
+        get_registry().histogram(hist, labels=labels).observe(seconds)
 
 
 class Span:
@@ -132,18 +146,26 @@ class Span:
     passes the parent through."""
 
     __slots__ = ("_rec", "name", "ctx", "parent", "args", "_t0", "_closed",
-                 "_hist", "_ann")
+                 "_hist", "_hist_labels", "_ann", "_under", "_stack")
 
     def __init__(self, rec: "Recorder", name: str,
                  ctx: TraceContext | None, parent: TraceContext | None,
-                 args: dict | None, hist: str | None):
+                 args: dict | None, hist: str | None,
+                 hist_labels: dict | None = None, loop: bool = False):
         self._rec = rec
         self.name = name
         self.ctx = ctx
         self.parent = parent
         self.args = args if args is not None else {}
         self._hist = hist
+        self._hist_labels = hist_labels
         self._closed = False
+        # the opener's stack, kept so that a close from another thread, or
+        # out of order, still takes this span off the stack it is on
+        self._stack = stack = rec._open_spans()
+        self._under = stack[-1].name if loop and parent is None and stack \
+            else None
+        stack.append(self)
         self._ann = _annotation(name)
         self._t0 = time.monotonic()
 
@@ -160,14 +182,23 @@ class Span:
         dur = time.monotonic() - self._t0
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
-        _observe(self._hist, dur)
+        stack = self._stack
+        if stack and stack[-1] is self:
+            stack.pop()
+        else:  # closed out of order: leave by identity, not by position
+            for i in range(len(stack) - 1, -1, -1):
+                if stack[i] is self:
+                    del stack[i]
+                    break
+        _observe(self._hist, dur, self._hist_labels)
         if not self._rec.enabled:
             return
         self._rec._emit({
             "ph": "X", "name": self.name, "ts": self._t0, "dur": dur,
             "trace": None if self.ctx is None else self.ctx.trace_id,
             "span": None if self.ctx is None else self.ctx.span_id,
-            "parent": None if self.parent is None else self.parent.span_id,
+            "parent": self._under if self.parent is None
+            else self.parent.span_id,
             "args": self.args,
         })
 
@@ -203,6 +234,11 @@ class Recorder:
         self._buf: list[dict] = []
         self._lock = threading.Lock()
         self._next_span = 0
+        self._tls = threading.local()
+        #: the optimizer step a training loop has last returned from, while
+        #: one runs (``train/trainer.py::LoopSpans``); None outside a loop.
+        #: The compile listener reads it to count a compile inside the loop
+        self.loop_step: int | None = None
         self._fh = None
         if self.enabled:
             d = os.path.dirname(path)
@@ -270,6 +306,24 @@ class Recorder:
 
     # -- spans / events ------------------------------------------------------
 
+    def _open_spans(self) -> list:
+        """This thread's stack of open spans, innermost last."""
+        try:
+            return self._tls.open
+        except AttributeError:
+            stack = self._tls.open = []
+            return stack
+
+    def innermost(self, skip: str = "") -> str | None:
+        """The name of the innermost span open on this thread, or None;
+        ``skip`` passes over names that start with it (the compile listener
+        skips ``trace:``: a kernel site lies inside a program's trace and is
+        no phase of the launch)."""
+        for sp in reversed(self._open_spans()):
+            if not (skip and sp.name.startswith(skip)):
+                return sp.name
+        return None
+
     def _span_ctx(self, parent: TraceContext | None,
                   loop: bool) -> TraceContext | None:
         if not self.enabled or (loop and parent is None):
@@ -277,16 +331,18 @@ class Recorder:
         return self._child_ctx(parent)
 
     def begin_span(self, name: str, parent=None, args: dict | None = None,
-                   hist: str | None = None, loop: bool = False) -> Span:
+                   hist: str | None = None, loop: bool = False,
+                   hist_labels: dict | None = None) -> Span:
         """Open a span the caller MUST close in a try/finally (GL-O401).
         Prefer ``with rec.span(...)``; use this only when the span's
         lifetime cannot be a lexical block."""
         parent = TraceContext.from_wire(parent)
         return Span(self, name, self._span_ctx(parent, loop), parent, args,
-                    hist)
+                    hist, hist_labels, loop)
 
     def span(self, name: str, parent=None, args: dict | None = None,
-             hist: str | None = None, loop: bool = False) -> Span:
+             hist: str | None = None, loop: bool = False,
+             hist_labels: dict | None = None) -> Span:
         """The sanctioned span form, ``with rec.span(...)``: closes on
         every path, and feeds all three sinks (module docstring).
 
@@ -294,9 +350,12 @@ class Recorder:
         ``loop=True`` marks what belongs to no request: an iteration of a
         training or serving loop, a set-up phase. Such a span carries no
         trace id, so the collector's request chains leave it out, and it
-        sits on the merged timeline by its time alone."""
+        sits on the merged timeline by its time alone; its ``parent`` is
+        the name of the span it was opened inside (module docstring).
+        ``hist_labels`` are the labels of the ``hist`` series: a bounded
+        set, as every registry label (``trace.kernel_s{kernel=...}``)."""
         return self.begin_span(name, parent=parent, args=args, hist=hist,
-                               loop=loop)
+                               loop=loop, hist_labels=hist_labels)
 
     def complete(self, name: str, start_mono: float, parent=None,
                  args: dict | None = None, hist: str | None = None,
@@ -305,18 +364,23 @@ class Recorder:
         process's monotonic clock), ended now. Returns the context
         children should parent to (parent pass-through when disabled).
         Feeds the registry histogram ``hist`` and the JSONL, not the
-        profiler's timeline: an annotation cannot start in the past."""
+        profiler's timeline: an annotation cannot start in the past. A
+        ``loop=True`` record's ``parent`` is the innermost span open now."""
         dur = time.monotonic() - start_mono
         _observe(hist, dur)
         parent = TraceContext.from_wire(parent)
         ctx = self._span_ctx(parent, loop)
         if not self.enabled:
             return ctx
+        if parent is not None:
+            caused_by = parent.span_id
+        else:
+            caused_by = self.innermost() if loop else None
         self._emit({
             "ph": "X", "name": name, "ts": start_mono, "dur": dur,
             "trace": None if ctx is None else ctx.trace_id,
             "span": None if ctx is None else ctx.span_id,
-            "parent": None if parent is None else parent.span_id,
+            "parent": caused_by,
             "args": args or {},
         })
         return ctx

@@ -65,6 +65,7 @@ from tpu_sandbox.ops.pallas_common import (
     LANE as _LANE,
     NEG as _NEG,
     default_interpret,
+    kernel_site,
     round_up as _round_up,
 )
 
@@ -191,21 +192,22 @@ def _tile_census(nq, nk, q_offset, kv_offset, **tile):
 def _plan(kernel, b, h, s, sk, d, dv, block_q, block_k, causal, kv_len,
           q_offset, kv_offset):
     """The static half of a call: its grid ``(nq, nk)``, the keywords its
-    kernel takes, and one count in the always-on registry of which tiles it
-    was built with and how many of its grid steps have work."""
+    kernel takes, and the counter in the always-on registry of which tiles
+    it was built with and how many of its grid steps have work, which the
+    call site counts once (``kernel_site``)."""
     from tpu_sandbox.obs import get_registry
 
     nq, nk = s // block_q, sk // block_k
     tile = dict(causal=causal, block_q=block_q, block_k=block_k,
                 kv_len=kv_len)
     census = _tile_census(nq, nk, q_offset, kv_offset, **tile)
-    get_registry().counter("attn.tile_choice", labels={
+    choice = get_registry().counter("attn.tile_choice", labels={
         "kernel": kernel, "block_q": block_q, "block_k": block_k, "s": s,
         "d": d, "dv": dv, "steps": b * h * nq * nk,
         "steps_with_work": ("traced" if census is None
                             else b * h * sum(census)),
-    }).inc()
-    return nq, nk, dict(tile, census=census, sk=sk)
+    })
+    return nq, nk, dict(tile, census=census, sk=sk), choice
 
 
 def _on_tile(i, j, q_off, kv_off, step, *, census, sk, **tile):
@@ -367,31 +369,32 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, kv_len,
     sk, dv = k.shape[2], v.shape[3]
     block_q, block_k = choose_tiles("fwd", s, sk, d, dv, q.dtype.itemsize,
                                     block_q=block_q, block_k=block_k)
-    nq, nk, tile = _plan("fwd", b, h, s, sk, d, dv, block_q, block_k, causal,
-                         kv_len, q_offset, kv_offset)
+    nq, nk, tile, choice = _plan("fwd", b, h, s, sk, d, dv, block_q, block_k,
+                                 causal, kv_len, q_offset, kv_offset)
     kernel = functools.partial(_fwd_kernel, scale=scale, **tile)
     q_side, kv_side = _block_specs(causal, block_q, block_k, nq, nk, "kv")
-    out, lse = pl.pallas_call(
-        kernel,
-        out_shape=(
-            jax.ShapeDtypeStruct((b, h, s, dv), out_dtype or q.dtype),
-            jax.ShapeDtypeStruct((b, h, s, _LANE), jnp.float32),
-        ),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, h, nq, nk),
-            in_specs=[q_side(d), kv_side(d), kv_side(dv)],
-            out_specs=(q_side(dv), q_side(_LANE)),
-            scratch_shapes=[
-                pltpu.VMEM((block_q, _LANE), jnp.float32),  # running max m
-                pltpu.VMEM((block_q, _LANE), jnp.float32),  # running sum l
-                pltpu.VMEM((block_q, dv), jnp.float32),     # output accumulator
-            ],
-        ),
-        compiler_params=_compiler_params("fwd", block_q, block_k, d, dv,
-                                         q.dtype.itemsize),
-        interpret=interpret,
-    )(*_offsets(q_offset, kv_offset), q, k, v)
+    with kernel_site("flash_fwd", choice):
+        out, lse = pl.pallas_call(
+            kernel,
+            out_shape=(
+                jax.ShapeDtypeStruct((b, h, s, dv), out_dtype or q.dtype),
+                jax.ShapeDtypeStruct((b, h, s, _LANE), jnp.float32),
+            ),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(b, h, nq, nk),
+                in_specs=[q_side(d), kv_side(d), kv_side(dv)],
+                out_specs=(q_side(dv), q_side(_LANE)),
+                scratch_shapes=[
+                    pltpu.VMEM((block_q, _LANE), jnp.float32),  # max m
+                    pltpu.VMEM((block_q, _LANE), jnp.float32),  # sum l
+                    pltpu.VMEM((block_q, dv), jnp.float32),     # output
+                ],
+            ),
+            compiler_params=_compiler_params("fwd", block_q, block_k, d, dv,
+                                             q.dtype.itemsize),
+            interpret=interpret,
+        )(*_offsets(q_offset, kv_offset), q, k, v)
     return out, lse[..., 0]
 
 
@@ -511,27 +514,29 @@ def _flash_bwd(q, k, v, delta, lse, g, scale, causal, block_q, block_k,
         (which lie on the other side), through a float32 scratch each."""
         bq, bk = choose_tiles(name, s, sk, d, dv, itemsize,
                               block_q=block_q, block_k=block_k)
-        nq, nk, tile = _plan(name, b, h, s, sk, d, dv, bq, bk, causal, kv_len,
-                             q_offset, kv_offset)
+        nq, nk, tile, choice = _plan(name, b, h, s, sk, d, dv, bq, bk, causal,
+                                     kv_len, q_offset, kv_offset)
         q_side, kv_side = _block_specs(causal, bq, bk, nq, nk, inner)
         out_side, rows = (kv_side, bk) if inner == "q" else (q_side, bq)
         widths = [x.shape[3] for x in like]
-        return pl.pallas_call(
-            functools.partial(kernel, scale=scale, **tile),
-            out_shape=[jax.ShapeDtypeStruct(x.shape, out_dtype or x.dtype)
-                       for x in like],
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2,
-                grid=(b, h, nk, nq) if inner == "q" else (b, h, nq, nk),
-                in_specs=[q_side(d), kv_side(d), kv_side(dv), q_side(dv),
-                          q_side(_LANE), q_side(_LANE)],
-                out_specs=[out_side(w) for w in widths],
-                scratch_shapes=[pltpu.VMEM((rows, w), jnp.float32)
-                                for w in widths],
-            ),
-            compiler_params=_compiler_params(name, bq, bk, d, dv, itemsize),
-            interpret=interpret,
-        )(*offs, q, k, v, g, lse, delta)
+        with kernel_site("flash_" + name, choice):
+            return pl.pallas_call(
+                functools.partial(kernel, scale=scale, **tile),
+                out_shape=[jax.ShapeDtypeStruct(x.shape, out_dtype or x.dtype)
+                           for x in like],
+                grid_spec=pltpu.PrefetchScalarGridSpec(
+                    num_scalar_prefetch=2,
+                    grid=(b, h, nk, nq) if inner == "q" else (b, h, nq, nk),
+                    in_specs=[q_side(d), kv_side(d), kv_side(dv), q_side(dv),
+                              q_side(_LANE), q_side(_LANE)],
+                    out_specs=[out_side(w) for w in widths],
+                    scratch_shapes=[pltpu.VMEM((rows, w), jnp.float32)
+                                    for w in widths],
+                ),
+                compiler_params=_compiler_params(name, bq, bk, d, dv,
+                                                 itemsize),
+                interpret=interpret,
+            )(*offs, q, k, v, g, lse, delta)
 
     dk, dv_ = call("dkv", _bwd_dkv_kernel, "q", (k, v))
     (dq,) = call("dq", _bwd_dq_kernel, "kv", (q,))

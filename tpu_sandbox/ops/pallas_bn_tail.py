@@ -45,7 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from tpu_sandbox.ops.pallas_common import default_interpret
+from tpu_sandbox.ops.pallas_common import default_interpret, kernel_site
 
 
 def selection_matrix(blk: int, co: int) -> np.ndarray:
@@ -250,20 +250,21 @@ def _forward(y, gamma, beta, co, blk, eps, interpret, ysums=None):
     b_lane = _lane_expand(beta.astype(jnp.float32) - mu * a_co, blk * blk)
     sel = jnp.asarray(selection_matrix(blk, co), jnp.float32)
     hb = _grid_rows(h, w, c)
-    out = pl.pallas_call(
-        functools.partial(_fwd_kernel, co=co, blk=blk),
-        out_shape=jax.ShapeDtypeStruct((n, h, w, sel.shape[1]), y.dtype),
-        grid=(n, h // hb),
-        in_specs=[
-            pl.BlockSpec((1, hb, w, c), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, c), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, c), lambda i, j: (0, 0)),
-            pl.BlockSpec(sel.shape, lambda i, j: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, hb, w, sel.shape[1]),
-                               lambda i, j: (i, j, 0, 0)),
-        interpret=default_interpret(interpret),
-    )(y, a_lane, b_lane, sel)
+    with kernel_site("bn_tail_fwd"):
+        out = pl.pallas_call(
+            functools.partial(_fwd_kernel, co=co, blk=blk),
+            out_shape=jax.ShapeDtypeStruct((n, h, w, sel.shape[1]), y.dtype),
+            grid=(n, h // hb),
+            in_specs=[
+                pl.BlockSpec((1, hb, w, c), lambda i, j: (i, j, 0, 0)),
+                pl.BlockSpec((1, c), lambda i, j: (0, 0)),
+                pl.BlockSpec((1, c), lambda i, j: (0, 0)),
+                pl.BlockSpec(sel.shape, lambda i, j: (0, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, hb, w, sel.shape[1]),
+                                   lambda i, j: (i, j, 0, 0)),
+            interpret=default_interpret(interpret),
+        )(y, a_lane, b_lane, sel)
     return out, mu, var, (a_lane, b_lane, inv)
 
 
@@ -289,30 +290,31 @@ def _vjp_bwd(co, blk, eps, interpret, res, cts):
     def vec():
         return pl.BlockSpec((1, c), lambda i, j: (0, 0))
 
-    s1, s2 = pl.pallas_call(
-        functools.partial(_bwd_reduce_kernel, co=co, blk=blk),
-        out_shape=(jax.ShapeDtypeStruct((1, c), jnp.float32),
-                   jax.ShapeDtypeStruct((1, c), jnp.float32)),
-        grid=(n, h // hb),
-        in_specs=[
-            pl.BlockSpec((1, hb, w, c), lambda i, j: (i, j, 0, 0)),
-            vec(), vec(),
-            pl.BlockSpec((1, hb, w, sel_t.shape[0]),
-                         lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec(sel_t.shape, lambda i, j: (0, 0)),
-            vec(), vec(),
-        ],
-        out_specs=(pl.BlockSpec((1, c), lambda i, j: (0, 0)),
-                   pl.BlockSpec((1, c), lambda i, j: (0, 0))),
-        scratch_shapes=[
-            pltpu.VMEM((1, c), jnp.float32),
-            pltpu.VMEM((1, c), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-        ),
-        interpret=interp,
-    )(y, a_lane, b_lane, g, sel_t, mu_lane, inv_lane)
+    with kernel_site("bn_tail_bwd_reduce"):
+        s1, s2 = pl.pallas_call(
+            functools.partial(_bwd_reduce_kernel, co=co, blk=blk),
+            out_shape=(jax.ShapeDtypeStruct((1, c), jnp.float32),
+                       jax.ShapeDtypeStruct((1, c), jnp.float32)),
+            grid=(n, h // hb),
+            in_specs=[
+                pl.BlockSpec((1, hb, w, c), lambda i, j: (i, j, 0, 0)),
+                vec(), vec(),
+                pl.BlockSpec((1, hb, w, sel_t.shape[0]),
+                             lambda i, j: (i, j, 0, 0)),
+                pl.BlockSpec(sel_t.shape, lambda i, j: (0, 0)),
+                vec(), vec(),
+            ],
+            out_specs=(pl.BlockSpec((1, c), lambda i, j: (0, 0)),
+                       pl.BlockSpec((1, c), lambda i, j: (0, 0))),
+            scratch_shapes=[
+                pltpu.VMEM((1, c), jnp.float32),
+                pltpu.VMEM((1, c), jnp.float32),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary"),
+            ),
+            interpret=interp,
+        )(y, a_lane, b_lane, g, sel_t, mu_lane, inv_lane)
 
     groups = blk * blk
     m_count = n * h * w * groups
@@ -322,22 +324,23 @@ def _vjp_bwd(co, blk, eps, interpret, res, cts):
     c1_lane = _lane_expand(s1_co / m_count, groups)
     c2_lane = _lane_expand(s2_co / m_count, groups)
 
-    dy = pl.pallas_call(
-        functools.partial(_bwd_apply_kernel, co=co, blk=blk),
-        out_shape=jax.ShapeDtypeStruct(y.shape, y.dtype),
-        grid=(n, h // hb),
-        in_specs=[
-            pl.BlockSpec((1, hb, w, c), lambda i, j: (i, j, 0, 0)),
-            vec(), vec(),
-            pl.BlockSpec((1, hb, w, sel_t.shape[0]),
-                         lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec(sel_t.shape, lambda i, j: (0, 0)),
-            vec(), vec(), vec(), vec(), vec(),
-        ],
-        out_specs=pl.BlockSpec((1, hb, w, c), lambda i, j: (i, j, 0, 0)),
-        interpret=interp,
-    )(y, a_lane, b_lane, g, sel_t, mu_lane, inv_lane, gi_lane, c1_lane,
-      c2_lane)
+    with kernel_site("bn_tail_bwd"):
+        dy = pl.pallas_call(
+            functools.partial(_bwd_apply_kernel, co=co, blk=blk),
+            out_shape=jax.ShapeDtypeStruct(y.shape, y.dtype),
+            grid=(n, h // hb),
+            in_specs=[
+                pl.BlockSpec((1, hb, w, c), lambda i, j: (i, j, 0, 0)),
+                vec(), vec(),
+                pl.BlockSpec((1, hb, w, sel_t.shape[0]),
+                             lambda i, j: (i, j, 0, 0)),
+                pl.BlockSpec(sel_t.shape, lambda i, j: (0, 0)),
+                vec(), vec(), vec(), vec(), vec(),
+            ],
+            out_specs=pl.BlockSpec((1, hb, w, c), lambda i, j: (i, j, 0, 0)),
+            interpret=interp,
+        )(y, a_lane, b_lane, g, sel_t, mu_lane, inv_lane, gi_lane, c1_lane,
+          c2_lane)
     dsums = jax.tree.map(jnp.zeros_like, ysums)  # see docstring; None -> None
     return dy, s2_co.astype(gamma.dtype), s1_co.astype(gamma.dtype), dsums
 

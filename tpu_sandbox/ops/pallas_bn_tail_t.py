@@ -32,7 +32,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from tpu_sandbox.ops.pallas_bn_tail import selection_matrix
-from tpu_sandbox.ops.pallas_common import default_interpret
+from tpu_sandbox.ops.pallas_common import default_interpret, kernel_site
 
 
 def _pool_fronts(z, co: int, blk: int):
@@ -211,20 +211,21 @@ def _forward(y, gamma, beta, co, blk, eps, interpret, ysums=None):
     b_col = _col_expand(beta.astype(jnp.float32) - mu * a_co, blk * blk)
     sel_t = jnp.asarray(selection_matrix(blk, co).T, jnp.float32)
     hb = _grid_rows(h, w, c)
-    out = pl.pallas_call(
-        functools.partial(_fwd_kernel, co=co, blk=blk),
-        out_shape=jax.ShapeDtypeStruct((n, h, sel_t.shape[0], w), y.dtype),
-        grid=(n, h // hb),
-        in_specs=[
-            pl.BlockSpec((1, hb, c, w), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((c, 1), lambda i, j: (0, 0)),
-            pl.BlockSpec((c, 1), lambda i, j: (0, 0)),
-            pl.BlockSpec(sel_t.shape, lambda i, j: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, hb, sel_t.shape[0], w),
-                               lambda i, j: (i, j, 0, 0)),
-        interpret=default_interpret(interpret),
-    )(y, a_col, b_col, sel_t)
+    with kernel_site("bn_tail_t_fwd"):
+        out = pl.pallas_call(
+            functools.partial(_fwd_kernel, co=co, blk=blk),
+            out_shape=jax.ShapeDtypeStruct((n, h, sel_t.shape[0], w), y.dtype),
+            grid=(n, h // hb),
+            in_specs=[
+                pl.BlockSpec((1, hb, c, w), lambda i, j: (i, j, 0, 0)),
+                pl.BlockSpec((c, 1), lambda i, j: (0, 0)),
+                pl.BlockSpec((c, 1), lambda i, j: (0, 0)),
+                pl.BlockSpec(sel_t.shape, lambda i, j: (0, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, hb, sel_t.shape[0], w),
+                                   lambda i, j: (i, j, 0, 0)),
+            interpret=default_interpret(interpret),
+        )(y, a_col, b_col, sel_t)
     return out, mu, var, (a_col, b_col, inv)
 
 
@@ -253,30 +254,31 @@ def bwd_reduce(y, g, co, blk, a_col, b_col, mu, inv, interpret):
     def vec():
         return pl.BlockSpec((c, 1), lambda i, j: (0, 0))
 
-    s1, s2 = pl.pallas_call(
-        functools.partial(_bwd_reduce_kernel, co=co, blk=blk),
-        out_shape=(jax.ShapeDtypeStruct((c, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((c, 1), jnp.float32)),
-        grid=(n, h // hb),
-        in_specs=[
-            pl.BlockSpec((1, hb, c, w), lambda i, j: (i, j, 0, 0)),
-            vec(), vec(),
-            pl.BlockSpec((1, hb, sel.shape[1], w),
-                         lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec(sel.shape, lambda i, j: (0, 0)),
-            vec(), vec(),
-        ],
-        out_specs=(pl.BlockSpec((c, 1), lambda i, j: (0, 0)),
-                   pl.BlockSpec((c, 1), lambda i, j: (0, 0))),
-        scratch_shapes=[
-            pltpu.VMEM((c, 1), jnp.float32),
-            pltpu.VMEM((c, 1), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-        ),
-        interpret=interp,
-    )(y, a_col, b_col, g, sel, mu_col, inv_col)
+    with kernel_site("bn_tail_t_bwd_reduce"):
+        s1, s2 = pl.pallas_call(
+            functools.partial(_bwd_reduce_kernel, co=co, blk=blk),
+            out_shape=(jax.ShapeDtypeStruct((c, 1), jnp.float32),
+                       jax.ShapeDtypeStruct((c, 1), jnp.float32)),
+            grid=(n, h // hb),
+            in_specs=[
+                pl.BlockSpec((1, hb, c, w), lambda i, j: (i, j, 0, 0)),
+                vec(), vec(),
+                pl.BlockSpec((1, hb, sel.shape[1], w),
+                             lambda i, j: (i, j, 0, 0)),
+                pl.BlockSpec(sel.shape, lambda i, j: (0, 0)),
+                vec(), vec(),
+            ],
+            out_specs=(pl.BlockSpec((c, 1), lambda i, j: (0, 0)),
+                       pl.BlockSpec((c, 1), lambda i, j: (0, 0))),
+            scratch_shapes=[
+                pltpu.VMEM((c, 1), jnp.float32),
+                pltpu.VMEM((c, 1), jnp.float32),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary"),
+            ),
+            interpret=interp,
+        )(y, a_col, b_col, g, sel, mu_col, inv_col)
     groups = blk * blk
     s1_co = jnp.sum(s1[:, 0].reshape(groups, co), axis=0)
     s2_co = jnp.sum(s2[:, 0].reshape(groups, co), axis=0)
@@ -312,21 +314,22 @@ def _vjp_bwd(co, blk, eps, interpret, res, cts):
     def vec():
         return pl.BlockSpec((c, 1), lambda i, j: (0, 0))
 
-    dy = pl.pallas_call(
-        functools.partial(_bwd_apply_kernel, co=co, blk=blk),
-        out_shape=jax.ShapeDtypeStruct(y.shape, y.dtype),
-        grid=(n, h // hb),
-        in_specs=[
-            pl.BlockSpec((1, hb, c, w), lambda i, j: (i, j, 0, 0)),
-            vec(), vec(),
-            pl.BlockSpec((1, hb, sel.shape[1], w),
-                         lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec(sel.shape, lambda i, j: (0, 0)),
-            vec(), vec(), vec(), vec(), vec(),
-        ],
-        out_specs=pl.BlockSpec((1, hb, c, w), lambda i, j: (i, j, 0, 0)),
-        interpret=interp,
-    )(y, a_col, b_col, g, sel, mu_col, inv_col, gi_col, c1_col, c2_col)
+    with kernel_site("bn_tail_t_bwd"):
+        dy = pl.pallas_call(
+            functools.partial(_bwd_apply_kernel, co=co, blk=blk),
+            out_shape=jax.ShapeDtypeStruct(y.shape, y.dtype),
+            grid=(n, h // hb),
+            in_specs=[
+                pl.BlockSpec((1, hb, c, w), lambda i, j: (i, j, 0, 0)),
+                vec(), vec(),
+                pl.BlockSpec((1, hb, sel.shape[1], w),
+                             lambda i, j: (i, j, 0, 0)),
+                pl.BlockSpec(sel.shape, lambda i, j: (0, 0)),
+                vec(), vec(), vec(), vec(), vec(),
+            ],
+            out_specs=pl.BlockSpec((1, hb, c, w), lambda i, j: (i, j, 0, 0)),
+            interpret=interp,
+        )(y, a_col, b_col, g, sel, mu_col, inv_col, gi_col, c1_col, c2_col)
     dsums = jax.tree.map(jnp.zeros_like, ysums)  # see docstring; None -> None
     return dy, s2_co.astype(gamma.dtype), s1_co.astype(gamma.dtype), dsums
 

@@ -27,6 +27,7 @@ from tpu_sandbox.ops.pallas_common import (
     LANE as _LANE,
     NEG as _NEG,
     default_interpret,
+    kernel_site,
     round_up as _round_up,
 )
 
@@ -91,17 +92,18 @@ def _forward(logits, labels, interpret):
     labels_p = jnp.pad(labels.astype(jnp.int32), (0, np_ - n))[:, None]
 
     grid = (np_ // block_n,)
-    per_row = pl.pallas_call(
-        _ce_kernel,
-        out_shape=jax.ShapeDtypeStruct((np_, 1), jnp.float32),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n, cp), lambda i: (i, 0)),
-            pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
-        interpret=interpret,
-    )(logits_p, labels_p)
+    with kernel_site("ce_fwd"):
+        per_row = pl.pallas_call(
+            _ce_kernel,
+            out_shape=jax.ShapeDtypeStruct((np_, 1), jnp.float32),
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((block_n, cp), lambda i: (i, 0)),
+                pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
+            ],
+            out_specs=pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
+            interpret=interpret,
+        )(logits_p, labels_p)
     return jnp.mean(per_row[:n, 0])
 
 

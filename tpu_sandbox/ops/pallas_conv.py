@@ -50,7 +50,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from tpu_sandbox.ops.pallas_common import default_interpret
+from tpu_sandbox.ops.pallas_common import default_interpret, kernel_site
 
 
 def _pick_block_h(h: int, w: int, c: int, co: int) -> int:
@@ -212,21 +212,22 @@ def _conv_call(x, w, bias, out_dtype, interpret, stats=False):
         out_shape = jax.ShapeDtypeStruct((n, h, wd, co), out_dtype)
         out_specs = pl.BlockSpec((1, bh, wd, co), lambda n, i: (n, i, 0, 0))
         scratch = []
-    return pl.pallas_call(
-        kernel,
-        out_shape=out_shape,
-        grid=(n, nblk),
-        in_specs=_halo_specs(bh, nblk, wd, c) + [
-            pl.BlockSpec((9 * c, co), lambda n, i: (0, 0)),
-            pl.BlockSpec((1, co), lambda n, i: (0, 0)),
-        ],
-        out_specs=out_specs,
-        scratch_shapes=scratch,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-        ),
-        interpret=default_interpret(interpret),
-    )(x, x, x, w.reshape(9 * c, co), bias.reshape(1, co))
+    with kernel_site("conv_fwd"):
+        return pl.pallas_call(
+            kernel,
+            out_shape=out_shape,
+            grid=(n, nblk),
+            in_specs=_halo_specs(bh, nblk, wd, c) + [
+                pl.BlockSpec((9 * c, co), lambda n, i: (0, 0)),
+                pl.BlockSpec((1, co), lambda n, i: (0, 0)),
+            ],
+            out_specs=out_specs,
+            scratch_shapes=scratch,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary"),
+            ),
+            interpret=default_interpret(interpret),
+        )(x, x, x, w.reshape(9 * c, co), bias.reshape(1, co))
 
 
 def _flip_transpose(w):
@@ -258,25 +259,26 @@ def _conv_vjp_bwd(interpret, res, g):
                     x.dtype, interpret)
     bh = _pick_block_h(h, wd, c, co)
     nblk = h // bh
-    dw_flat, db = pl.pallas_call(
-        functools.partial(_wgrad_kernel, bh=bh, nblk=nblk),
-        out_shape=(jax.ShapeDtypeStruct((9 * c, co), jnp.float32),
-                   jax.ShapeDtypeStruct((1, co), jnp.float32)),
-        grid=(n, nblk),
-        in_specs=_halo_specs(bh, nblk, wd, c) + [
-            pl.BlockSpec((1, bh, wd, co), lambda n, i: (n, i, 0, 0)),
-        ],
-        out_specs=(pl.BlockSpec((9 * c, co), lambda n, i: (0, 0)),
-                   pl.BlockSpec((1, co), lambda n, i: (0, 0))),
-        scratch_shapes=[
-            pltpu.VMEM((9 * c, co), jnp.float32),
-            pltpu.VMEM((1, co), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-        ),
-        interpret=default_interpret(interpret),
-    )(x, x, x, g)
+    with kernel_site("conv_wgrad"):
+        dw_flat, db = pl.pallas_call(
+            functools.partial(_wgrad_kernel, bh=bh, nblk=nblk),
+            out_shape=(jax.ShapeDtypeStruct((9 * c, co), jnp.float32),
+                       jax.ShapeDtypeStruct((1, co), jnp.float32)),
+            grid=(n, nblk),
+            in_specs=_halo_specs(bh, nblk, wd, c) + [
+                pl.BlockSpec((1, bh, wd, co), lambda n, i: (n, i, 0, 0)),
+            ],
+            out_specs=(pl.BlockSpec((9 * c, co), lambda n, i: (0, 0)),
+                       pl.BlockSpec((1, co), lambda n, i: (0, 0))),
+            scratch_shapes=[
+                pltpu.VMEM((9 * c, co), jnp.float32),
+                pltpu.VMEM((1, co), jnp.float32),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary"),
+            ),
+            interpret=default_interpret(interpret),
+        )(x, x, x, g)
     dw = dw_flat.reshape(3, 3, c, co).astype(w.dtype)
     return dx, dw, db[0].astype(w.dtype)
 

@@ -52,7 +52,7 @@ from tpu_sandbox.ops.pallas_bn_tail_t import (
     bwd_reduce,
     bwd_scales,
 )
-from tpu_sandbox.ops.pallas_common import default_interpret
+from tpu_sandbox.ops.pallas_common import default_interpret, kernel_site
 from tpu_sandbox.ops.pallas_conv5_t import (
     NT,
     R,
@@ -156,32 +156,33 @@ def _fused_wgrad(x, y1, g_pool, a_col, b_col, sel, mu_col, inv_col,
     def vec():
         return pl.BlockSpec((cbig, 1), lambda n, i: (0, 0))
 
-    dw, db = pl.pallas_call(
-        functools.partial(_wgrad_tail_kernel, bh=bh, nblk=nblk,
-                          co=co, blk=blk, gt=gt),
-        out_shape=(jax.ShapeDtypeStruct(dw_shape, jnp.float32),
-                   jax.ShapeDtypeStruct((cbig, 1), jnp.float32)),
-        grid=(n, nblk),
-        in_specs=_halo_specs(bh, nblk, c16, wd) + [
-            pl.BlockSpec((1, bh, cbig, wd), lambda n, i: (n, i, 0, 0)),
-            pl.BlockSpec((1, bh, cpool, wd), lambda n, i: (n, i, 0, 0)),
-            vec(), vec(),
-            pl.BlockSpec(sel.shape, lambda n, i: (0, 0)),
-            vec(), vec(), vec(), vec(), vec(),
-        ],
-        out_specs=(pl.BlockSpec(dw_shape, lambda n, i: (0, 0)),
-                   pl.BlockSpec((cbig, 1), lambda n, i: (0, 0))),
-        scratch_shapes=[
-            pltpu.VMEM(dw_shape, jnp.float32),
-            pltpu.VMEM((cbig, 1), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=_VMEM_LIMIT,
-        ),
-        interpret=default_interpret(interpret),
-    )(x, x, x, y1, g_pool, a_col, b_col, sel, mu_col, inv_col,
-      gi_col, c1_col, c2_col)
+    with kernel_site("conv1_tail_t_wgrad"):
+        dw, db = pl.pallas_call(
+            functools.partial(_wgrad_tail_kernel, bh=bh, nblk=nblk,
+                              co=co, blk=blk, gt=gt),
+            out_shape=(jax.ShapeDtypeStruct(dw_shape, jnp.float32),
+                       jax.ShapeDtypeStruct((cbig, 1), jnp.float32)),
+            grid=(n, nblk),
+            in_specs=_halo_specs(bh, nblk, c16, wd) + [
+                pl.BlockSpec((1, bh, cbig, wd), lambda n, i: (n, i, 0, 0)),
+                pl.BlockSpec((1, bh, cpool, wd), lambda n, i: (n, i, 0, 0)),
+                vec(), vec(),
+                pl.BlockSpec(sel.shape, lambda n, i: (0, 0)),
+                vec(), vec(), vec(), vec(), vec(),
+            ],
+            out_specs=(pl.BlockSpec(dw_shape, lambda n, i: (0, 0)),
+                       pl.BlockSpec((cbig, 1), lambda n, i: (0, 0))),
+            scratch_shapes=[
+                pltpu.VMEM(dw_shape, jnp.float32),
+                pltpu.VMEM((cbig, 1), jnp.float32),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary"),
+                vmem_limit_bytes=_VMEM_LIMIT,
+            ),
+            interpret=default_interpret(interpret),
+        )(x, x, x, y1, g_pool, a_col, b_col, sel, mu_col, inv_col,
+          gi_col, c1_col, c2_col)
     return (dw.T if gt else dw), db
 
 

@@ -47,7 +47,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from tpu_sandbox.ops.pallas_common import default_interpret
+from tpu_sandbox.ops.pallas_common import default_interpret, kernel_site
 from tpu_sandbox.ops.pallas_conv_t import (
     _VMEM_LIMIT,
     _halo_specs,
@@ -225,22 +225,23 @@ def _conv_call(x, w1, bias_g, out_dtype, interpret, stats=False):
         out_shape = jax.ShapeDtypeStruct((n, h, co, wd), out_dtype)
         out_specs = pl.BlockSpec((1, bh, co, wd), lambda n, i: (n, i, 0, 0))
         scratch = []
-    return pl.pallas_call(
-        kernel,
-        out_shape=out_shape,
-        grid=(n, nblk),
-        in_specs=_halo_specs(bh, nblk, c, wd) + [
-            pl.BlockSpec((co, NT), lambda n, i: (0, 0)),
-            pl.BlockSpec((co, 1), lambda n, i: (0, 0)),
-        ],
-        out_specs=out_specs,
-        scratch_shapes=scratch,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=_VMEM_LIMIT,
-        ),
-        interpret=default_interpret(interpret),
-    )(x, x, x, w1, bias_g)
+    with kernel_site("conv5_t_fwd"):
+        return pl.pallas_call(
+            kernel,
+            out_shape=out_shape,
+            grid=(n, nblk),
+            in_specs=_halo_specs(bh, nblk, c, wd) + [
+                pl.BlockSpec((co, NT), lambda n, i: (0, 0)),
+                pl.BlockSpec((co, 1), lambda n, i: (0, 0)),
+            ],
+            out_specs=out_specs,
+            scratch_shapes=scratch,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary"),
+                vmem_limit_bytes=_VMEM_LIMIT,
+            ),
+            interpret=default_interpret(interpret),
+        )(x, x, x, w1, bias_g)
 
 
 def _prep(k5, bias, dtype):
@@ -316,26 +317,27 @@ def conv1_s2d_t_wgrad(x, g, interpret=None, restage=None):
     bh = _pick_block_h(h, wd, co)
     nblk = h // bh
     dw_shape = (NT, co) if gt else (co, NT)
-    dw, db = pl.pallas_call(
-        functools.partial(_wgrad_kernel, bh=bh, nblk=nblk, gt=gt),
-        out_shape=(jax.ShapeDtypeStruct(dw_shape, jnp.float32),
-                   jax.ShapeDtypeStruct((co, 1), jnp.float32)),
-        grid=(n, nblk),
-        in_specs=_halo_specs(bh, nblk, c, wd) + [
-            pl.BlockSpec((1, bh, co, wd), lambda n, i: (n, i, 0, 0)),
-        ],
-        out_specs=(pl.BlockSpec(dw_shape, lambda n, i: (0, 0)),
-                   pl.BlockSpec((co, 1), lambda n, i: (0, 0))),
-        scratch_shapes=[
-            pltpu.VMEM(dw_shape, jnp.float32),
-            pltpu.VMEM((co, 1), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=_VMEM_LIMIT,
-        ),
-        interpret=default_interpret(interpret),
-    )(x, x, x, g)
+    with kernel_site("conv5_t_wgrad"):
+        dw, db = pl.pallas_call(
+            functools.partial(_wgrad_kernel, bh=bh, nblk=nblk, gt=gt),
+            out_shape=(jax.ShapeDtypeStruct(dw_shape, jnp.float32),
+                       jax.ShapeDtypeStruct((co, 1), jnp.float32)),
+            grid=(n, nblk),
+            in_specs=_halo_specs(bh, nblk, c, wd) + [
+                pl.BlockSpec((1, bh, co, wd), lambda n, i: (n, i, 0, 0)),
+            ],
+            out_specs=(pl.BlockSpec(dw_shape, lambda n, i: (0, 0)),
+                       pl.BlockSpec((co, 1), lambda n, i: (0, 0))),
+            scratch_shapes=[
+                pltpu.VMEM(dw_shape, jnp.float32),
+                pltpu.VMEM((co, 1), jnp.float32),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary"),
+                vmem_limit_bytes=_VMEM_LIMIT,
+            ),
+            interpret=default_interpret(interpret),
+        )(x, x, x, g)
     return (dw.T if gt else dw), db
 
 

@@ -45,7 +45,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from tpu_sandbox.ops.pallas_common import default_interpret
+from tpu_sandbox.ops.pallas_common import default_interpret, kernel_site
 
 
 _VMEM_LIMIT = 100_000_000  # raised from the 16 MB default (v5e: 128 MB)
@@ -238,22 +238,23 @@ def _conv_call(x, w, bias, out_dtype, interpret, stats=False):
         out_shape = jax.ShapeDtypeStruct((n, h, co, wd), out_dtype)
         out_specs = pl.BlockSpec((1, bh, co, wd), lambda n, i: (n, i, 0, 0))
         scratch = []
-    return pl.pallas_call(
-        kernel,
-        out_shape=out_shape,
-        grid=(n, nblk),
-        in_specs=_halo_specs(bh, nblk, c, wd) + [
-            pl.BlockSpec((co, 9 * c), lambda n, i: (0, 0)),
-            pl.BlockSpec((co, 1), lambda n, i: (0, 0)),
-        ],
-        out_specs=out_specs,
-        scratch_shapes=scratch,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=_VMEM_LIMIT,
-        ),
-        interpret=default_interpret(interpret),
-    )(x, x, x, wt, bias.reshape(co, 1))
+    with kernel_site("conv_t_fwd"):
+        return pl.pallas_call(
+            kernel,
+            out_shape=out_shape,
+            grid=(n, nblk),
+            in_specs=_halo_specs(bh, nblk, c, wd) + [
+                pl.BlockSpec((co, 9 * c), lambda n, i: (0, 0)),
+                pl.BlockSpec((co, 1), lambda n, i: (0, 0)),
+            ],
+            out_specs=out_specs,
+            scratch_shapes=scratch,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary"),
+                vmem_limit_bytes=_VMEM_LIMIT,
+            ),
+            interpret=default_interpret(interpret),
+        )(x, x, x, wt, bias.reshape(co, 1))
 
 
 def _flip_transpose(w):
@@ -301,26 +302,27 @@ def conv3x3_t_wgrad(x, g, interpret=None, restage=None):
     bh = _pick_block_h(h, wd, c, co)
     nblk = h // bh
     dw_shape = (9 * c, co) if gt else (co, 9 * c)
-    dw, db = pl.pallas_call(
-        functools.partial(_wgrad_kernel, bh=bh, nblk=nblk, gt=gt),
-        out_shape=(jax.ShapeDtypeStruct(dw_shape, jnp.float32),
-                   jax.ShapeDtypeStruct((co, 1), jnp.float32)),
-        grid=(n, nblk),
-        in_specs=_halo_specs(bh, nblk, c, wd) + [
-            pl.BlockSpec((1, bh, co, wd), lambda n, i: (n, i, 0, 0)),
-        ],
-        out_specs=(pl.BlockSpec(dw_shape, lambda n, i: (0, 0)),
-                   pl.BlockSpec((co, 1), lambda n, i: (0, 0))),
-        scratch_shapes=[
-            pltpu.VMEM(dw_shape, jnp.float32),
-            pltpu.VMEM((co, 1), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=_VMEM_LIMIT,
-        ),
-        interpret=default_interpret(interpret),
-    )(x, x, x, g)
+    with kernel_site("conv_t_wgrad"):
+        dw, db = pl.pallas_call(
+            functools.partial(_wgrad_kernel, bh=bh, nblk=nblk, gt=gt),
+            out_shape=(jax.ShapeDtypeStruct(dw_shape, jnp.float32),
+                       jax.ShapeDtypeStruct((co, 1), jnp.float32)),
+            grid=(n, nblk),
+            in_specs=_halo_specs(bh, nblk, c, wd) + [
+                pl.BlockSpec((1, bh, co, wd), lambda n, i: (n, i, 0, 0)),
+            ],
+            out_specs=(pl.BlockSpec(dw_shape, lambda n, i: (0, 0)),
+                       pl.BlockSpec((co, 1), lambda n, i: (0, 0))),
+            scratch_shapes=[
+                pltpu.VMEM(dw_shape, jnp.float32),
+                pltpu.VMEM((co, 1), jnp.float32),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary"),
+                vmem_limit_bytes=_VMEM_LIMIT,
+            ),
+            interpret=default_interpret(interpret),
+        )(x, x, x, g)
     # caller-facing layout is dwT [CO, 9C] either way; the gt variant's
     # [9C, CO] is a one-off [576, 128]-ish XLA transpose per step (and
     # cancels against the VJP's .T below)

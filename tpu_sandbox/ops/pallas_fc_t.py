@@ -47,7 +47,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from tpu_sandbox.ops.pallas_common import LANE, default_interpret, round_up
+from tpu_sandbox.ops.pallas_common import (
+    LANE,
+    default_interpret,
+    kernel_site,
+    round_up,
+)
 
 _VMEM_LIMIT = 100_000_000
 #: lanes of the flat row handled per accumulation pass of the dgrad kernel
@@ -85,22 +90,23 @@ def fc_flatten_t(y, interpret=None):
     n, h, c, w = y.shape
     bh = _pick_block_h(h, c, w)
     lanes = bh * c * w
-    return pl.pallas_call(
-        functools.partial(_flatten_kernel, n_batch=n, bh=bh, c=c, w=w),
-        out_shape=jax.ShapeDtypeStruct((n, h * c * w), y.dtype),
-        grid=(h // bh,),
-        in_specs=[pl.BlockSpec((n, bh, c, w), lambda i: (0, i, 0, 0))],
-        out_specs=pl.BlockSpec((n, lanes), lambda i: (0, i)),
-        # rows move in y's own dtype (packed bf16 rows and all: 0.90 ms a
-        # step on the chip against 1.24 through f32, PERF.md PR 24); 16 rows
-        # are a whole bf16 tile
-        scratch_shapes=[pltpu.VMEM((round_up(n, 16), lanes), y.dtype)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=_VMEM_LIMIT,
-        ),
-        interpret=default_interpret(interpret),
-    )(y)
+    with kernel_site("fc_t_flatten"):
+        return pl.pallas_call(
+            functools.partial(_flatten_kernel, n_batch=n, bh=bh, c=c, w=w),
+            out_shape=jax.ShapeDtypeStruct((n, h * c * w), y.dtype),
+            grid=(h // bh,),
+            in_specs=[pl.BlockSpec((n, bh, c, w), lambda i: (0, i, 0, 0))],
+            out_specs=pl.BlockSpec((n, lanes), lambda i: (0, i)),
+            # rows move in y's own dtype (packed bf16 rows and all: 0.90 ms a
+            # step on the chip against 1.24 through f32, PERF.md PR 24); 16 rows
+            # are a whole bf16 tile
+            scratch_shapes=[pltpu.VMEM((round_up(n, 16), lanes), y.dtype)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=_VMEM_LIMIT,
+            ),
+            interpret=default_interpret(interpret),
+        )(y)
 
 
 def _dgrad_kernel(g_ref, wt_ref, dy_ref, flat_scr, nat_scr, *,
@@ -138,26 +144,27 @@ def fc_dgrad_t(g, wt, hcw, out_dtype, interpret=None):
     assert wt.shape == (k, h * c * w), (wt.shape, g.shape, hcw)
     bh = _pick_block_h(h, c, w)
     lanes = bh * c * w
-    return pl.pallas_call(
-        functools.partial(_dgrad_kernel, n_batch=n, k_cls=k, bh=bh, c=c,
-                          w=w),
-        out_shape=jax.ShapeDtypeStruct((n, h, c, w), out_dtype),
-        grid=(h // bh,),
-        in_specs=[
-            pl.BlockSpec((n, k), lambda i: (0, 0)),
-            pl.BlockSpec((k, lanes), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((n, bh, c, w), lambda i: (0, i, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((round_up(n, 8), lanes), jnp.float32),
-            pltpu.VMEM((bh, c, w), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=_VMEM_LIMIT,
-        ),
-        interpret=default_interpret(interpret),
-    )(g, wt)
+    with kernel_site("fc_t_dgrad"):
+        return pl.pallas_call(
+            functools.partial(_dgrad_kernel, n_batch=n, k_cls=k, bh=bh, c=c,
+                              w=w),
+            out_shape=jax.ShapeDtypeStruct((n, h, c, w), out_dtype),
+            grid=(h // bh,),
+            in_specs=[
+                pl.BlockSpec((n, k), lambda i: (0, 0)),
+                pl.BlockSpec((k, lanes), lambda i: (0, i)),
+            ],
+            out_specs=pl.BlockSpec((n, bh, c, w), lambda i: (0, i, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((round_up(n, 8), lanes), jnp.float32),
+                pltpu.VMEM((bh, c, w), jnp.float32),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=_VMEM_LIMIT,
+            ),
+            interpret=default_interpret(interpret),
+        )(g, wt)
 
 
 def fc_t(y, kernel2d, bias, dtype, interpret=None):

@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from tpu_sandbox.ops.pallas_common import LANE, default_interpret
+from tpu_sandbox.ops.pallas_common import LANE, default_interpret, kernel_site
 
 
 def _tile(dim: int, cap: int) -> int:
@@ -72,21 +72,22 @@ def _gmm(x, w, tile_group, tm: int, transpose_rhs: bool, interpret):
         w_spec = pl.BlockSpec((1, tn, tk), lambda i, j, kk, g: (g[i], j, kk))
     else:
         w_spec = pl.BlockSpec((1, tk, tn), lambda i, j, kk, g: (g[i], kk, j))
-    return pl.pallas_call(
-        functools.partial(_gmm_kernel, transpose_rhs=transpose_rhs),
-        out_shape=jax.ShapeDtypeStruct((p, n), x.dtype),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(p // tm, n // tn, k // tk),
-            in_specs=[pl.BlockSpec((tm, tk), lambda i, j, kk, g: (i, kk)),
-                      w_spec],
-            out_specs=pl.BlockSpec((tm, tn), lambda i, j, kk, g: (i, j)),
-            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
-        ),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=default_interpret(interpret),
-    )(tile_group, x, w)
+    with kernel_site("gmm"):
+        return pl.pallas_call(
+            functools.partial(_gmm_kernel, transpose_rhs=transpose_rhs),
+            out_shape=jax.ShapeDtypeStruct((p, n), x.dtype),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(p // tm, n // tn, k // tk),
+                in_specs=[pl.BlockSpec((tm, tk), lambda i, j, kk, g: (i, kk)),
+                          w_spec],
+                out_specs=pl.BlockSpec((tm, tn), lambda i, j, kk, g: (i, j)),
+                scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
+            ),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=default_interpret(interpret),
+        )(tile_group, x, w)
 
 
 def _tgmm_kernel(group_ref, first_ref, x_ref, dy_ref, dw_ref, acc):
@@ -111,22 +112,23 @@ def _tgmm(x, dy, tile_group, n_groups: int, tm: int, interpret):
     first = jnp.concatenate([
         jnp.ones((1,), jnp.int32),
         (tile_group[1:] != tile_group[:-1]).astype(jnp.int32)])
-    return pl.pallas_call(
-        _tgmm_kernel,
-        out_shape=jax.ShapeDtypeStruct((n_groups, k, n), x.dtype),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(k // tk, n // tn, p // tm),
-            in_specs=[pl.BlockSpec((tm, tk), lambda a, b, i, g, f: (i, a)),
-                      pl.BlockSpec((tm, tn), lambda a, b, i, g, f: (i, b))],
-            out_specs=pl.BlockSpec((1, tk, tn),
-                                   lambda a, b, i, g, f: (g[i], a, b)),
-            scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)],
-        ),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=default_interpret(interpret),
-    )(tile_group, first, x, dy)
+    with kernel_site("tgmm"):
+        return pl.pallas_call(
+            _tgmm_kernel,
+            out_shape=jax.ShapeDtypeStruct((n_groups, k, n), x.dtype),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(k // tk, n // tn, p // tm),
+                in_specs=[pl.BlockSpec((tm, tk), lambda a, b, i, g, f: (i, a)),
+                          pl.BlockSpec((tm, tn), lambda a, b, i, g, f: (i, b))],
+                out_specs=pl.BlockSpec((1, tk, tn),
+                                       lambda a, b, i, g, f: (g[i], a, b)),
+                scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)],
+            ),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=default_interpret(interpret),
+        )(tile_group, first, x, dy)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))

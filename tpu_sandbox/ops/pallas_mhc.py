@@ -65,7 +65,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from tpu_sandbox.ops.pallas_common import LANE as _LANE, default_interpret
+from tpu_sandbox.ops.pallas_common import (
+    LANE as _LANE,
+    default_interpret,
+    kernel_site,
+)
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 _F32 = jnp.float32
@@ -181,7 +185,9 @@ def _count(kernel: str, x):
     """One count in the always-on registry of what a call site of ``kernel``
     over the streams ``x [n, tokens, C]`` is built with: its tiles, and
     ``passes``, the bf16 products its matrix products take (three where a
-    factor is exact in bf16, six at ``HIGHEST``)."""
+    factor is exact in bf16, six at ``HIGHEST``). Counted at the site,
+    outside the jitted call; the kernel's ``trace:kernel`` span opens inside
+    it, once a shape (``kernel_site``)."""
     from tpu_sandbox.obs import get_registry
 
     n, tokens, c = x.shape
@@ -189,9 +195,10 @@ def _count(kernel: str, x):
     passes = {"pre_fwd": split, "pre_bwd": split + 6}.get(kernel, 0)
     tt, tc = (0, 0) if kernel == "fallback" else choose_tiles(
         kernel, n, c, tokens, x.dtype.itemsize)
-    get_registry().counter("mhc.kernel_choice", labels={
-        "kernel": kernel, "n": n, "c": c, "tokens": tokens,
-        "tile_tokens": tt, "tile_c": tc, "passes": passes}).inc()
+    kernel_site("mhc_" + kernel, get_registry().counter(
+        "mhc.kernel_choice", labels={
+            "kernel": kernel, "n": n, "c": c, "tokens": tokens,
+            "tile_tokens": tt, "tile_c": tc, "passes": passes}))
 
 
 def _traced_once(call):
@@ -337,23 +344,25 @@ def _pre_fwd(x, phi, alpha_pre, b_pre, *, eps, dtype, interpret):
     itemsize = x.dtype.itemsize
     pieces = _pieces(x)
     tt, _ = choose_tiles("pre_fwd", n, c, tokens, itemsize)
-    return _call(
-        functools.partial(_pre_fwd_kernel, n=n, pieces=pieces,
-                          rg=32 // itemsize),
-        grid=(tokens // tt,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, _LANE), lambda t: (0, 0)),
-            pl.BlockSpec((n, tt, c), lambda t: (0, t, 0)),
-            pl.BlockSpec((n, c, _LANE), lambda t: (0, 0, 0),
-                         pipeline_mode=pl.Buffered(1)),
-        ],
-        out_specs=[pl.BlockSpec((tt, c), lambda t: (t, 0)),
-                   pl.BlockSpec((tt, _LANE), lambda t: (t, 0))],
-        out_shape=[jax.ShapeDtypeStruct((tokens, c), dtype),
-                   jax.ShapeDtypeStruct((tokens, _LANE), _F32)],
-        interpret=interpret,
-    )(_scalars(alpha_pre, n, c, eps), _b_row(b_pre), x, _slots(phi, pieces))
+    with kernel_site("mhc_pre_fwd"):
+        return _call(
+            functools.partial(_pre_fwd_kernel, n=n, pieces=pieces,
+                              rg=32 // itemsize),
+            grid=(tokens // tt,),
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec((1, _LANE), lambda t: (0, 0)),
+                pl.BlockSpec((n, tt, c), lambda t: (0, t, 0)),
+                pl.BlockSpec((n, c, _LANE), lambda t: (0, 0, 0),
+                             pipeline_mode=pl.Buffered(1)),
+            ],
+            out_specs=[pl.BlockSpec((tt, c), lambda t: (t, 0)),
+                       pl.BlockSpec((tt, _LANE), lambda t: (t, 0))],
+            out_shape=[jax.ShapeDtypeStruct((tokens, c), dtype),
+                       jax.ShapeDtypeStruct((tokens, _LANE), _F32)],
+            interpret=interpret,
+        )(_scalars(alpha_pre, n, c, eps), _b_row(b_pre), x,
+          _slots(phi, pieces))
 
 
 # --- post, forward and backward ---
@@ -408,12 +417,13 @@ def _post_fwd(x, y, coef, *, interpret):
     itemsize = x.dtype.itemsize
     tt, tc = choose_tiles("post_fwd", n, c, tokens, itemsize)
     wide, row, side = _post_specs(n, tt, tc)
-    return _call(
-        functools.partial(_post_fwd_kernel, n=n, rg=32 // itemsize),
-        grid=(tokens // tt, c // tc), in_specs=[wide, row, side],
-        out_specs=wide, out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        interpret=interpret,
-    )(x, y, coef)
+    with kernel_site("mhc_post_fwd"):
+        return _call(
+            functools.partial(_post_fwd_kernel, n=n, rg=32 // itemsize),
+            grid=(tokens // tt, c // tc), in_specs=[wide, row, side],
+            out_specs=wide, out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+            interpret=interpret,
+        )(x, y, coef)
 
 
 @_traced_once
@@ -422,15 +432,16 @@ def _post_bwd(x, y, g, coef, *, interpret):
     itemsize = x.dtype.itemsize
     tt, tc = choose_tiles("post_bwd", n, c, tokens, itemsize)
     wide, row, side = _post_specs(n, tt, tc)
-    return _call(
-        functools.partial(_post_bwd_kernel, n=n, rg=32 // itemsize),
-        grid=(tokens // tt, c // tc), in_specs=[wide, row, wide, side],
-        out_specs=[wide, row, side],
-        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
-                   jax.ShapeDtypeStruct(y.shape, y.dtype),
-                   jax.ShapeDtypeStruct((tokens, _LANE), _F32)],
-        interpret=interpret,
-    )(x, y, g, coef)
+    with kernel_site("mhc_post_bwd"):
+        return _call(
+            functools.partial(_post_bwd_kernel, n=n, rg=32 // itemsize),
+            grid=(tokens // tt, c // tc), in_specs=[wide, row, wide, side],
+            out_specs=[wide, row, side],
+            out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                       jax.ShapeDtypeStruct(y.shape, y.dtype),
+                       jax.ShapeDtypeStruct((tokens, _LANE), _F32)],
+            interpret=interpret,
+        )(x, y, g, coef)
 
 
 # --- pre, backward ---
@@ -521,27 +532,30 @@ def _pre_bwd(x, phi, alpha_pre, b_pre, stats, du, dproj, dxt, *, eps,
     tt, _ = choose_tiles("pre_bwd", n, c, tokens, itemsize)
     wide = pl.BlockSpec((n, tt, c), lambda t: (0, t, 0))
     side = pl.BlockSpec((tt, _LANE), lambda t: (t, 0))
-    dx, dphit, dz = _call(
-        functools.partial(_pre_bwd_kernel, n=n, pieces=pieces,
-                          rg=32 // itemsize),
-        grid=(tokens // tt,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, _LANE), lambda t: (0, 0)),
-            wide, pl.BlockSpec((tt, c), lambda t: (t, 0)), wide, side, side,
-            pl.BlockSpec((n, 2 * _LANE, c), lambda t: (0, 0, 0),
-                         pipeline_mode=pl.Buffered(1)),
-        ],
-        out_specs=[wide, pl.BlockSpec((n, _LANE, c), lambda t: (0, 0, 0)),
-                   side],
-        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
-                   jax.ShapeDtypeStruct((n, _LANE, c), _F32),
-                   jax.ShapeDtypeStruct((tokens, _LANE), _F32)],
-        interpret=interpret, aliases={4: 0},
-        scratch=[pltpu.VMEM((tt, _LANE), _F32), pltpu.VMEM((tt, _LANE), _F32),
-                 pltpu.VMEM((tt, c), _F32)],
-    )(_scalars(alpha_pre, n, c, eps), _b_row(b_pre), x, du, dxt, stats,
-      _side(dproj), _six_slots(phi))
+    with kernel_site("mhc_pre_bwd"):
+        dx, dphit, dz = _call(
+            functools.partial(_pre_bwd_kernel, n=n, pieces=pieces,
+                              rg=32 // itemsize),
+            grid=(tokens // tt,),
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec((1, _LANE), lambda t: (0, 0)),
+                wide, pl.BlockSpec((tt, c), lambda t: (t, 0)), wide, side,
+                side,
+                pl.BlockSpec((n, 2 * _LANE, c), lambda t: (0, 0, 0),
+                             pipeline_mode=pl.Buffered(1)),
+            ],
+            out_specs=[wide, pl.BlockSpec((n, _LANE, c), lambda t: (0, 0, 0)),
+                       side],
+            out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                       jax.ShapeDtypeStruct((n, _LANE, c), _F32),
+                       jax.ShapeDtypeStruct((tokens, _LANE), _F32)],
+            interpret=interpret, aliases={4: 0},
+            scratch=[pltpu.VMEM((tt, _LANE), _F32),
+                     pltpu.VMEM((tt, _LANE), _F32),
+                     pltpu.VMEM((tt, c), _F32)],
+        )(_scalars(alpha_pre, n, c, eps), _b_row(b_pre), x, du, dxt, stats,
+          _side(dproj), _six_slots(phi))
     dphit = dphit[:, :pieces * _SLOT].reshape(n, pieces, _SLOT, c).sum(1)
     return dx, jnp.swapaxes(dphit[:, :k], 1, 2), dz[:, :n]
 

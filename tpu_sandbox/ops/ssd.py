@@ -28,14 +28,18 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from tpu_sandbox.ops.pallas_common import kernel_site
 
-def _count_choice(impl: str, heads: int, head_dim: int, state: int,
-                  groups: int, chunk: int, tokens: int) -> None:
+
+def _choice(impl: str, heads: int, head_dim: int, state: int,
+            groups: int, chunk: int, tokens: int):
+    """The counter of what a scan call site is built with; the site counts
+    it (``kernel_site``)."""
     from tpu_sandbox.obs import get_registry
 
-    get_registry().counter("ssd.chunk_choice", labels={
+    return get_registry().counter("ssd.chunk_choice", labels={
         "impl": impl, "heads": heads, "head_dim": head_dim, "state": state,
-        "groups": groups, "chunk": chunk, "tokens": tokens}).inc()
+        "groups": groups, "chunk": chunk, "tokens": tokens})
 
 
 def decay_exponents(dt, a, *, chunk: int):
@@ -58,10 +62,12 @@ def ssd_scan(x, dt, a, b, c, *, chunk: int):
         raise ValueError(f"sequence {s} is not a multiple of the chunk {chunk}")
     if h % g:
         raise ValueError(f"{h} heads do not divide into {g} groups")
-    _count_choice("jnp", h, p, n, g, chunk, bsz * s)
     nc, r, dtype = s // chunk, h // g, x.dtype
     f32 = jnp.float32
-    with jax.named_scope("ssd"):
+    # the scan is `jnp` today; its site is recorded as a kernel's is, so
+    # that a later kernel is judged by the same span and count
+    with kernel_site("ssd_scan", _choice("jnp", h, p, n, g, chunk, bsz * s)), \
+            jax.named_scope("ssd"):
         # tokens [B, chunks, Q, G, heads a group, ...]; the decays with the
         # chunk's tokens last, so that the [Q, Q] matrices fill whole tiles
         dt = dt.astype(f32).reshape(bsz, nc, chunk, g, r)

@@ -461,8 +461,6 @@ class DataParallel:
         with get_recorder().span("place:batch",
                                  hist="place.batch_s", loop=True):
             images, labels = jnp.asarray(images), jnp.asarray(labels)
-            get_registry().counter("place.batch_bytes").inc(
-                images.nbytes + labels.nbytes)
             return jax.device_put(images, sh), jax.device_put(labels, sh)
 
     # -- the engine ---------------------------------------------------------
@@ -622,6 +620,9 @@ class DataParallel:
         """AOT-lower the train step without executing it — the hook the
         collective-traffic accounting uses (``.compile().as_text()`` keeps
         the cross-replica collectives with inline operand shapes)."""
-        if self._jitted is None:
-            self._jitted = self._compile_for(state)
-        return self._jitted.lower(state, images, labels)
+        # the step's trace and lower phases are recorded ``under`` this span
+        # (``runtime/bootstrap.py``'s compile listener)
+        with get_recorder().span("compile:lower_step", loop=True):
+            if self._jitted is None:
+                self._jitted = self._compile_for(state)
+            return self._jitted.lower(state, images, labels)

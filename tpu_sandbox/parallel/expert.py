@@ -167,11 +167,17 @@ _chosen.defvjp(_chosen_fwd, _chosen_bwd)
 
 def _count_table(tokens: int, top_k: int, held: int, width: int,
                  buffer_rows: int, c: int) -> None:
+    """One count a traced ``routed`` call site, through the helper every
+    kernel's site counts by. The share's own work is gathers and a sort,
+    no kernel, so no ``trace:kernel`` span opens here; the grouped products
+    it feeds open their own."""
     from tpu_sandbox.obs import get_registry
+    from tpu_sandbox.ops.pallas_common import kernel_site
 
-    get_registry().counter("moe.share_table", labels={
-        "tokens": tokens, "top_k": top_k, "held": held, "width": width,
-        "buffer_rows": buffer_rows, "c": c, "collect": "gather"}).inc()
+    kernel_site("expert_share", get_registry().counter(
+        "moe.share_table", labels={
+            "tokens": tokens, "top_k": top_k, "held": held, "width": width,
+            "buffer_rows": buffer_rows, "c": c, "collect": "gather"}))
 
 
 def _pick(table: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:

@@ -36,7 +36,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tpu_sandbox.obs import get_recorder, get_registry
+from tpu_sandbox.obs import get_recorder
 from tpu_sandbox.ops.losses import cross_entropy_loss
 from tpu_sandbox.parallel.collectives import (
     CompressedAllReduce,
@@ -311,8 +311,6 @@ class PjitEngine:
         with get_recorder().span("place:batch",
                                  hist="place.batch_s", loop=True):
             images, labels = jnp.asarray(images), jnp.asarray(labels)
-            get_registry().counter("place.batch_bytes").inc(
-                images.nbytes + labels.nbytes)
             return (
                 jax.device_put(images, self._sharding(self.input_spec)),
                 jax.device_put(labels, self._sharding(P(self.batch_axis))),
@@ -468,6 +466,9 @@ class PjitEngine:
         """AOT-lower the train step without executing it — same hook as
         ``DataParallel.lower_step`` so the HLO analysis tools (traffic,
         schedule, graftlint pass 2) can treat every engine uniformly."""
-        if self._jitted is None:
-            self._jitted = self._build(state)
-        return self._jitted.lower(state, images, labels)
+        # the step's trace and lower phases are recorded ``under`` this span
+        # (``runtime/bootstrap.py``'s compile listener)
+        with get_recorder().span("compile:lower_step", loop=True):
+            if self._jitted is None:
+                self._jitted = self._build(state)
+            return self._jitted.lower(state, images, labels)

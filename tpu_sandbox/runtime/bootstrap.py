@@ -33,6 +33,8 @@ from __future__ import annotations
 
 import os
 import socket
+import threading
+import time
 from dataclasses import dataclass
 from pathlib import Path
 
@@ -55,12 +57,10 @@ def configure_compile_cache() -> str:
     there). Unset, the cache lives at ``DEFAULT_COMPILE_CACHE``. Entry
     scripts call this first, before anything compiles.
 
-    Also counts what the cache does into the always-on registry:
-    ``compile.cache_hits`` (a program loaded instead of compiled) and
-    ``compile.cache_misses`` (a program compiled and written: jax counts a
-    miss only where it writes the entry, i.e. a compile above its
-    ``jax_persistent_cache_min_compile_time_secs``). Both exist from this
-    call on, so a warm run reads a true 0 misses.
+    Also starts the program's record of its own launch in the always-on
+    registry (``_count_cache_events``): the cache's hits and misses, and
+    every program's trace, lower and compile-or-cache-load by name and by
+    the span it ran under.
     """
     _count_cache_events()
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
@@ -70,29 +70,177 @@ def configure_compile_cache() -> str:
     return str(DEFAULT_COMPILE_CACHE)
 
 
+#: jax's three duration events of a program on its way to the device, by the
+#: phase they clock. Each arrives with ``fun_name=``: the bare function name
+#: for a trace (``train_step``), ``jit(train_step)`` for the other two.
+_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+#: rows of ``compile.program_s``: the programs with the most seconds in all
+PROGRAM_TABLE = 32
+#: intervals a thread keeps to tell an outer phase from those inside it. A
+#: step's trace holds one for every ``jit`` it calls directly (every
+#: ``jnp`` function is one): thousands, of two floats each.
+_OPEN_INTERVALS = 1 << 16
+
 _cache_listener_on = False
+_launch_lock = threading.Lock()
+_launch_tls = threading.local()
+_programs: dict[str, dict] = {}
+
+
+def reset_launch_record() -> None:
+    """Stop listening and forget the program table: for a test, so that no
+    later test of its process finds series it did not make.
+    ``_count_cache_events`` starts the record again."""
+    global _cache_listener_on
+    if _cache_listener_on:
+        jax.monitoring.unregister_event_listener(_on_event)
+        jax.monitoring.unregister_event_duration_listener(_on_duration)
+        _cache_listener_on = False
+    with _launch_lock:
+        _programs.clear()
+
+
+def _self_seconds(start: float, duration: float) -> float:
+    """``duration`` less what the phases inside it cover. jax reports an
+    inner ``jit``'s trace (and an eager operation's three phases) before,
+    and within, the trace of the function that called it: an interval that
+    starts at or after ``start`` is this one's child, and leaves the list as
+    this one joins it, so the list holds outermost intervals only and every
+    second is observed once."""
+    try:
+        open_ = _launch_tls.open
+    except AttributeError:
+        open_ = _launch_tls.open = []
+    inside = 0.0
+    while open_ and open_[-1][0] >= start:
+        inside += open_.pop()[1]
+    open_.append((start, duration))
+    if len(open_) > _OPEN_INTERVALS:
+        del open_[:_OPEN_INTERVALS // 2]
+    return max(duration - inside, 0.0)
+
+
+def _note_program(program: str, phase: str, cache: str,
+                  duration: float) -> None:
+    """``compile.program_s{program, phase, cache}``: seconds in all, for
+    the ``PROGRAM_TABLE`` programs with the most. A row is a program with
+    all its phases, so the 0.15 s cache load of a step that took 10 s to
+    trace stays; the smallest row leaves when a larger program arrives."""
+    from tpu_sandbox.obs import get_registry
+
+    reg = get_registry()
+    with _launch_lock:
+        row = _programs.get(program)
+        if row is None:
+            if len(_programs) >= PROGRAM_TABLE:
+                least = min(_programs, key=lambda p: _programs[p]["total"])
+                if _programs[least]["total"] >= duration:
+                    return
+                for ph, ca in _programs.pop(least)["seconds"]:
+                    reg.drop_gauge("compile.program_s", labels={
+                        "program": least, "phase": ph, "cache": ca})
+            row = _programs[program] = {"total": 0.0, "seconds": {}}
+        row["total"] += duration
+        seconds = row["seconds"][phase, cache] = (
+            row["seconds"].get((phase, cache), 0.0) + duration)
+    reg.gauge("compile.program_s", labels={
+        "program": program, "phase": phase, "cache": cache}).set(seconds)
+
+
+def _on_event(event: str, **kwargs) -> None:
+    from tpu_sandbox.obs import get_registry
+
+    if event == "/jax/compilation_cache/cache_hits":
+        get_registry().counter("compile.cache_hits").inc()
+        _launch_tls.cache = "hit"
+    elif event == "/jax/compilation_cache/cache_misses":
+        get_registry().counter("compile.cache_misses").inc()
+        _launch_tls.cache = "miss"
+
+
+def _on_duration(event: str, duration: float, **kwargs) -> None:
+    from tpu_sandbox.obs import get_recorder, get_registry
+
+    phase = _PHASES.get(event)
+    if phase is None:
+        return
+    reg, rec = get_registry(), get_recorder()
+    start = time.monotonic() - duration
+    seconds = _self_seconds(start, duration)
+    under = rec.innermost(skip="trace:") or "none"
+    labels = {"under": under}
+    cache = "none"
+    if phase == "trace":
+        reg.histogram("compile.trace_s", labels=labels).observe(seconds)
+    elif phase == "lower":
+        reg.histogram("compile.lower_s", labels=labels).observe(seconds)
+    else:
+        reg.histogram("compile.backend_s", labels=labels).observe(seconds)
+        cache = getattr(_launch_tls, "cache", "none")
+        _launch_tls.cache = "none"
+    name = kwargs.get("fun_name", "?")
+    program = name if "(" in name else f"jit({name})"
+    _note_program(program, phase, cache, duration)
+    step = rec.loop_step
+    if step is not None and phase == "backend":
+        reg.counter("compile.in_loop").inc()
+        rec.instant("compile:in_loop",
+                    args={"program": program, "step": step})
+    if rec.enabled:
+        args = {"program": program, "under": under, "cache": cache}
+        if step is not None:
+            args["step"] = step
+        rec.complete(f"compile:{phase}", start, args=args, loop=True)
 
 
 def _count_cache_events() -> None:
-    """Create the two compile-cache counters (so that they read 0, not
-    nothing) and register, once, the ``jax.monitoring`` listener behind
-    them."""
+    """The program's record of its own launch, in the always-on registry;
+    registers, once, the two ``jax.monitoring`` listeners behind it
+    (``_on_event``, ``_on_duration``).
+
+    - ``compile.cache_hits`` (a program loaded instead of compiled) and
+      ``compile.cache_misses`` (a program compiled and written: jax counts
+      a miss only where it writes the entry, i.e. a compile above its
+      ``jax_persistent_cache_min_compile_time_secs``), and
+      ``compile.in_loop``. All three exist from this call on, so a warm run
+      reads a true 0, not nothing.
+    - histograms ``compile.trace_s``, ``compile.lower_s``,
+      ``compile.backend_s`` with the label ``under``: the innermost span
+      open on the thread (``setup:model_init``, ``compile:lower_step``, ...;
+      ``trace:`` kernel sites passed over), or ``none``. Each observes a
+      phase's seconds *less the phases inside it* (``_self_seconds``), so a
+      ``jit`` inside a ``jit`` counts once and the three sums add up to wall
+      time spent.
+    - gauge ``compile.program_s{program, phase, cache}`` (``_note_program``):
+      whole seconds by program, ``program`` as ``jit(<name>)`` for every
+      phase. ``cache`` is ``hit`` or ``miss`` for a backend phase inside
+      which jax's cache event fell (on jax 0.9.0 it arrives before the
+      duration event that encloses it, on the same thread), ``none`` for a
+      compile too short for jax to cache and for the other phases.
+    - with the JSONL on, a retrospective ``compile:<phase>`` record with
+      ``program``, ``under``, ``cache`` and, while a loop runs, ``step``.
+    - a backend phase while a training loop runs (``Recorder.loop_step``)
+      raises ``compile.in_loop`` and writes the instant ``compile:in_loop``
+      with ``program`` and ``step``: a recompile traces and lowers too, but
+      jax reports one trace for every ``jit`` inside the program and one
+      backend phase for the program, so the backend phase is what is
+      counted. Nothing waits, nothing is read from the device.
+    """
     global _cache_listener_on
     from tpu_sandbox.obs import get_registry
 
     get_registry().counter("compile.cache_hits")
     get_registry().counter("compile.cache_misses")
+    get_registry().counter("compile.in_loop")
     if _cache_listener_on:
         return
     _cache_listener_on = True
-
-    def on_event(event: str, **kwargs) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            get_registry().counter("compile.cache_hits").inc()
-        elif event == "/jax/compilation_cache/cache_misses":
-            get_registry().counter("compile.cache_misses").inc()
-
-    jax.monitoring.register_event_listener(on_event)
+    jax.monitoring.register_event_listener(_on_event)
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
 
 
 # Module state: records what init() decided, so entry scripts and tests can

@@ -232,7 +232,13 @@ class LoopSpans:
     is the interval from one return of the step call to the next — the
     loop's cadence, which the device paces once its queue is full (a loop's
     first step has no predecessor and records none). None of them waits for
-    the device where the loop did not already."""
+    the device where the loop did not already.
+
+    From a loop's first ``returned()`` to its ``ended()`` the recorder's
+    ``loop_step`` holds the step last returned from: a program that compiles
+    in between is counted where it happens (``compile.in_loop``, the instant
+    ``compile:in_loop{program, step}``; ``runtime/bootstrap.py``). The first
+    step's own compile lies before the first return and is not one."""
 
     def __init__(self):
         self.rec = get_recorder()
@@ -264,6 +270,11 @@ class LoopSpans:
                               loop=True)
         self._returned = time.monotonic()
         self.steps.inc()
+        self.rec.loop_step = step
+
+    def ended(self) -> None:
+        """The loop has ended, by its last batch or by an exception."""
+        self.rec.loop_step = None
 
     def sync(self, why: str):
         """Around every place the loop does wait for the device."""
@@ -349,6 +360,7 @@ class Trainer:
                 state = self._run_epochs(state, loader, epochs, set_epoch,
                                          total_step, opt_step, mw=mw)
         finally:
+            self._spans.ended()
             if self._saver is not None:
                 # drain in-flight async writes even when the loop raised —
                 # an abandoned background save is an orphaned tmp dir, i.e.
