@@ -77,21 +77,29 @@ def test_flash_lse_and_partial_bwd_lower_for_tpu():
 
 
 CELL_SHAPES = [
-    # name, B, S, H, D (q.k), Dv, scale: the two LM cells' attention in bf16
-    ("gpt2m_s1024_64_64", 1, 1024, 2, 64, 64, None),
-    ("xing4_s4096_192_128", 1, 4096, 2, 192, 128, 192 ** -0.5 * 2.00474),
+    # name, B, S, H, D (q.k), Dv, scale, heads a block (None: the padded
+    # form): the LM cells' attention in bf16, two heads of each, and the
+    # packed form's two cells whole
+    ("gpt2m_s1024_64_64", 1, 1024, 2, 64, 64, None, 2),
+    ("xing4_s4096_192_128", 1, 4096, 2, 192, 128, 192 ** -0.5 * 2.00474,
+     None),
+    ("gpt2m_cell_packed", 8, 1024, 16, 64, 64, None, 2),
+    ("nemotron3s_cell_packed", 1, 8192, 16, 128, 128, None, 1),
 ]
 
 
-@pytest.mark.parametrize("name,b,s,h,d,dv,scale", CELL_SHAPES,
+@pytest.mark.parametrize("name,b,s,h,d,dv,scale,group", CELL_SHAPES,
                          ids=[c[0] for c in CELL_SHAPES])
 def test_flash_attention_at_the_cells_shapes_lowers_for_tpu(
-        name, b, s, h, d, dv, scale):
+        name, b, s, h, d, dv, scale, group):
     """Forward and backward at the tiles the rule picks for the cells'
     own shapes (PR 28): far larger blocks than the 128 x 128 the cases
     above were written for, index maps that read scalar prefetch, and a
-    ``vmem_limit_bytes``."""
-    from tpu_sandbox.ops.pallas_attention import _pad_len, choose_tiles
+    ``vmem_limit_bytes``; in the form the shape rule gives each (packed at
+    64 / 64 and 128 / 128: column blocks of [B, S, H·D], the lane
+    selection of a head in its group; padded at 192 / 128)."""
+    from tpu_sandbox.ops.pallas_attention import (_heads_per_block, _pad_len,
+                                                  choose_tiles)
 
     sp = _pad_len(s)
     for kernel in ("fwd", "dkv", "dq"):
@@ -100,6 +108,7 @@ def test_flash_attention_at_the_cells_shapes_lowers_for_tpu(
         assert bq >= 512 and bk >= 512, (kernel, bq, bk)
     q, k = (jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16),) * 2
     v = jax.ShapeDtypeStruct((b, s, h, dv), jnp.bfloat16)
+    assert _heads_per_block(q, k, v, None, None) == group
 
     def loss(q, k, v):
         out = flash_attention(q, k, v, scale=scale, interpret=False)

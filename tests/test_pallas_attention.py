@@ -235,18 +235,23 @@ def test_only_the_variants_the_grid_needs_are_built(census, want):
     assert built == want
 
 
-def test_tile_choice_is_counted_at_trace_time():
+def _tile_choices():
     from tpu_sandbox.obs import get_registry
 
-    def series():
-        return {k: v for k, v in get_registry().snapshot()["counters"].items()
-                if k.startswith("attn.tile_choice")}
+    return {k: v for k, v in get_registry().snapshot()["counters"].items()
+            if k.startswith("attn.tile_choice")}
 
-    before = series()
+
+def _new_tile_choices(before):
+    return {k: v - before.get(k, 0) for k, v in _tile_choices().items()
+            if v != before.get(k, 0)}
+
+
+def test_tile_choice_is_counted_at_trace_time():
+    before = _tile_choices()
     q, k, v = _rand_qkv(b=1, s=384, h=3, d=32, seed=11)
     jax.grad(lambda q: flash_attention(q, k, v, interpret=True).sum())(q)
-    new = {k: v - before.get(k, 0) for k, v in series().items()
-           if v != before.get(k, 0)}
+    new = _new_tile_choices(before)
     assert len(new) == 3 and set(new.values()) == {1}, new
     for kernel in pa._KERNELS:
         (key,) = [k for k in new if f"kernel={kernel}" in k]
@@ -454,3 +459,131 @@ def test_traced_offsets_without_causality_build_no_mask():
         np.testing.assert_array_equal(a, w)
     np.testing.assert_allclose(got[0], causal_attention(q, k, v, causal=False),
                                rtol=2e-5, atol=2e-5)
+
+
+# -- the packed form: the kernels on [B, S, H·D], whole heads to a block -----
+
+# (H, D): two heads, four heads and one head to a 128-lane block
+PACKED_HEADS = [(4, 64), (8, 32), (2, 128)]
+# S and explicit tiles: the rule's one tile; unequal tiles that divide S
+# (packed); tiles whose lcm pads S 384 to 512 (padded, by the same rule)
+PACKED_TILINGS = [(128, {}, True),
+                  (384, {"block_q": 128, "block_k": 384}, True),
+                  (384, {"block_q": 256, "block_k": 128}, False)]
+
+
+@pytest.mark.usefixtures("light_compile")
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("s,blocks,packed", PACKED_TILINGS,
+                         ids=["s128_rule", "s384_128x384", "s384_256x128"])
+@pytest.mark.parametrize("h,d", PACKED_HEADS)
+def test_packed_form_matches_padded_form_and_plain_attention(
+        h, d, s, blocks, packed, dtype, monkeypatch):
+    """out, dq, dk, dv of the packed form against the padded form on the
+    same call and against plain attention in float32."""
+    args = _qkvg(s, d, d, jnp.dtype(dtype), b=2, h=h, seed=8)
+    assert bool(pa._heads_per_block(*args[:3], blocks.get("block_q"),
+                                    blocks.get("block_k"))) == packed
+    want = _grads(causal_attention,
+                  *(x.astype(jnp.float32) for x in args[:3]), args[3])
+    got = _grads(flash_attention, *args, interpret=True, **blocks)
+    monkeypatch.setattr(pa, "_heads_per_block", lambda *a: None)
+    padded = _grads(flash_attention, *args, interpret=True, **blocks)
+    for a, p, w, name in zip(got, padded, want, ("out", "dq", "dk", "dv")):
+        assert a.dtype == p.dtype == jnp.dtype(dtype)
+        if dtype == "float32":
+            np.testing.assert_allclose(a, w, rtol=2e-5, atol=2e-5,
+                                       err_msg=name)
+            np.testing.assert_allclose(a, p, rtol=2e-5, atol=2e-5,
+                                       err_msg=name)
+        else:
+            assert _rel_rms(a, w) < BF16_REL_RMS, (name, _rel_rms(a, w))
+            assert _rel_rms(a, p) < BF16_REL_RMS, (name, _rel_rms(a, p))
+
+
+@pytest.mark.usefixtures("light_compile")
+def test_a_head_reading_its_neighbours_lanes_breaks_the_bf16_tolerance_tenfold(
+        monkeypatch):
+    """The planted fault of the packed form: of the two heads of a block,
+    each takes the other's lanes of its operands into the products (and
+    writes its own lanes of the results, as before)."""
+    args = _qkvg(256, 64, 64, jnp.bfloat16, h=4, seed=5)
+    want = _grads(causal_attention,
+                  *(x.astype(jnp.float32) for x in args[:3]), args[3])
+    real = pa._own
+    monkeypatch.setattr(pa, "_own", lambda x, lanes: real(x, ~lanes))
+    got = _grads(flash_attention, *args, interpret=True)
+    worst = max(_rel_rms(a, w) for a, w in zip(got, want))
+    assert worst > 10 * BF16_REL_RMS, worst
+
+
+FALLBACKS = [
+    # name, S, H, D (q.k), Dv: shapes the rule keeps on the padded form
+    ("odd_heads_at_64", 256, 3, 64, 64),
+    ("latent_192_128", 256, 2, 192, 128),
+    ("s_200", 200, 4, 64, 64),
+]
+
+
+@pytest.mark.usefixtures("light_compile")
+@pytest.mark.parametrize("name,s,h,d,dv", FALLBACKS,
+                         ids=[c[0] for c in FALLBACKS])
+def test_shapes_outside_the_rule_take_the_padded_form_and_agree(name, s, h, d,
+                                                                dv):
+    args = _qkvg(s, d, dv, h=h, seed=12)
+    assert pa._heads_per_block(*args[:3], None, None) is None
+    before = _tile_choices()
+    got = _grads(flash_attention, *args, interpret=True)
+    new = _new_tile_choices(before)
+    assert len(new) == 3 and all(
+        "layout=padded" in key and "heads_per_block=1" in key
+        and f"d={-(-d // 128) * 128}," in key for key in new), new
+    want = _grads(causal_attention, *args)
+    for a, w, part in zip(got, want, ("out", "dq", "dk", "dv")):
+        np.testing.assert_allclose(a, w, rtol=2e-5, atol=2e-5, err_msg=part)
+
+
+def _equations(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs its equations carry
+    (custom_vjp, pjit, cond), the kernels' bodies left out."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub)
+
+
+def test_no_layout_glue_round_the_calls_at_the_gpt2_cells_shape():
+    """At (8, 1024, 16, 64) bf16, forward and gradient: no pad, transpose
+    or slice equation outside the three kernels, one body a kernel (its
+    products: 2, 4, 3), and the counter's labels say what was built."""
+    q = jax.ShapeDtypeStruct((8, 1024, 16, 64), jnp.bfloat16)
+    before = _tile_choices()
+
+    def out_and_grads(q, k, v, g):
+        out, vjp = jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, interpret=True), q, k, v)
+        return (out, *vjp(g))
+
+    jaxpr = jax.make_jaxpr(out_and_grads)(q, q, q, q).jaxpr
+    eqns = list(_equations(jaxpr))
+    names = [e.primitive.name for e in eqns]
+    assert names.count("pallas_call") == 3, names
+    glue = {"pad", "transpose", "slice", "dynamic_slice", "gather",
+            "concatenate", "broadcast_in_dim"} & set(names)
+    assert not glue, names
+    products = sorted(len(_dot_operand_dtypes(e.params["jaxpr"]))
+                      for e in eqns if e.primitive.name == "pallas_call")
+    assert products == [2, 3, 4], products
+    new = _new_tile_choices(before)
+    assert len(new) == 3 and set(new.values()) == {1}, new
+    for kernel in pa._KERNELS:
+        (key,) = [k for k in new if f"kernel={kernel}" in k]
+        for label in ("layout=packed", "heads_per_block=2", "d=64,", "dv=64",
+                      "block_q=1024", "block_k=1024", "s=1024", "steps=128",
+                      "steps_with_work=128"):
+            assert label in key, (label, key)

@@ -828,7 +828,8 @@ def test_the_choice_counters_read_as_before_through_the_helper(
     label_keys = {kv.split("=")[0] for k in tiles
                   for kv in k[k.index("{") + 1:-1].split(",")}
     assert label_keys == {"kernel", "block_q", "block_k", "s", "d", "dv",
-                          "steps", "steps_with_work"}
+                          "steps", "steps_with_work", "layout",
+                          "heads_per_block"}
 
     x = jnp.ones((1, 16, 4, 8), jnp.float32)
     dt = jnp.ones((1, 16, 4), jnp.float32)

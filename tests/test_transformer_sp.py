@@ -114,3 +114,31 @@ def test_transformer_is_causal():
     np.testing.assert_allclose(
         np.asarray(base)[:, :10], np.asarray(out)[:, :10], atol=1e-5
     )
+
+
+@pytest.mark.parametrize("features,contract,x_shape", [
+    ((3, 4, 8), 1, (2, 16, 32)),      # qkv: [B, S, C] -> [B, S, 3, H·D]
+    ((32,), 2, (2, 16, 4, 8)),        # out: [B, S, H, D] -> [B, S, C]
+], ids=["qkv", "out"])
+def test_heads_dense_holds_dense_generals_parameters_and_numbers(
+        features, contract, x_shape):
+    """``HeadsDense`` is ``nn.DenseGeneral`` with the (heads, head_dim) pair
+    seen as one dimension by the product: the same parameter names, shapes
+    and initial values from the same key, and the same result."""
+    import flax.linen as nn
+
+    from tpu_sandbox.models.transformer import HeadsDense
+
+    x = jax.random.normal(jax.random.key(1), x_shape)
+    axis = tuple(range(-contract, 0))
+    theirs = nn.DenseGeneral(features, axis=axis)
+    ours = HeadsDense(features, contract=contract)
+    want_vars = jax.jit(theirs.init)(jax.random.key(0), x)
+    got_vars = jax.jit(ours.init)(jax.random.key(0), x)
+    jax.tree.map(np.testing.assert_array_equal, got_vars, want_vars)
+    bias = jax.random.normal(jax.random.key(2), features)
+    variables = {"params": {**want_vars["params"], "bias": bias}}
+    want = jax.jit(theirs.apply)(variables, x)
+    got = jax.jit(ours.apply)(variables, x)
+    np.testing.assert_allclose(got.reshape(want.shape), want, rtol=1e-5,
+                               atol=1e-5)

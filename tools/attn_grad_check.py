@@ -1,10 +1,12 @@
 """On the chip: the flash kernels' gradients against float32 attention.
 
 No benchmark cell holds an attention gradient at head size 64 (GPT-2's
-check compares logits and the loss). This reads them directly, at the two
-LM cells' attention shapes in bfloat16 — GPT-2's (S 1024, 64 / 64) and
-Xing4's latent attention (S 4096, 192 / 128, its softmax scale) — on a
-few heads: ``flash_attention``'s out, dq, dk, dv against
+check compares logits and the loss). This reads them directly, at the
+three LM cells' attention shapes in bfloat16 — GPT-2's (S 1024, 64 / 64:
+the packed form, two heads a block), Xing4's latent attention (S 4096,
+192 / 128, its softmax scale: the padded form) and Nemotron's (S 8192,
+128 / 128: packed, one head a block) — on a few heads:
+``flash_attention``'s out, dq, dk, dv against
 ``ops.attention.causal_attention`` on the same (bf16-rounded) inputs in
 float32 at ``highest`` matmul precision. Prints one JSON line a shape
 with the relative RMS deviations (rms(got - want) / rms(want)); bf16's
@@ -20,6 +22,7 @@ on an older checkout of this repository.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -31,10 +34,12 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 SHAPES = {
     "gpt2m_s1024_64_64": (2, 1024, 4, 64, 64, None),
     "xing4_s4096_192_128": (1, 4096, 4, 192, 128, 192 ** -0.5 * 2.00474),
+    "nemotron3s_s8192_128_128": (1, 8192, 4, 128, 128, None),
 }
 TINY = {
     "tiny_64_64": (1, 256, 2, 64, 64, None),
     "tiny_192_128": (1, 384, 2, 192, 128, 0.1),
+    "tiny_128_128": (1, 256, 2, 128, 128, None),
 }
 
 
@@ -55,10 +60,11 @@ def deviations(shape, seed):
         out, vjp = jax.vjp(lambda q, k, v: attn(q, k, v, scale=scale), *xs)
         return (out, *vjp(g.astype(out.dtype)))
 
-    got = jax.jit(lambda: run(flash_attention, q, k, v))()
+    # operands as arguments: closed over, they are constants XLA folds
+    got = jax.jit(functools.partial(run, flash_attention))(q, k, v)
     with jax.default_matmul_precision("highest"):
-        want = jax.jit(lambda: run(
-            causal_attention, *(x.astype(jnp.float32) for x in (q, k, v))))()
+        want = jax.jit(functools.partial(run, causal_attention))(
+            *(x.astype(jnp.float32) for x in (q, k, v)))
 
     def rel_rms(a, b):
         a, b = a.astype(jnp.float32), b.astype(jnp.float32)

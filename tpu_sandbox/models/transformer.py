@@ -17,6 +17,7 @@ static shapes, no data-dependent control flow.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 from typing import Any, Callable
 
@@ -62,6 +63,46 @@ class TransformerConfig:
     fp32_logits: bool = True
 
 
+class HeadsDense(nn.Module):
+    """``nn.DenseGeneral``'s parameters — ``kernel`` ``(*inputs,
+    *features)`` and ``bias`` ``(*features,)``, the same initial values —
+    for a projection to or from heads, whose (heads, head_dim) pair the
+    product sees as one dimension H·D: ``x [..., C] -> [..., 3, H·D]`` for
+    ``features=(3, H, D)``, and ``x [..., H, D] -> [..., C]`` for
+    ``features=(C,)`` with ``contract=2``. The same numbers. But a
+    ``[..., H, 64]`` array of its own is laid out on a TPU with the 64-wide
+    minor dimension padded to a lane tile or moved off the minor place, and
+    copied into the form a kernel reads; as ``[..., H·D]`` the projection's
+    result is what the flash kernels' packed form reads where it lies
+    (``ops/pallas_attention.py``: its reshape ``[B, S, H, D] -> [B, S, H·D]``
+    cancels against the caller's), and under tensor parallelism a kernel
+    sharded by heads stays sharded through the merge, heads being the major
+    of the two."""
+    features: tuple[int, ...]
+    contract: int = 1
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+        inputs, features = x.shape[x.ndim - self.contract:], self.features
+        fan_in = math.prod(inputs)
+
+        def kernel_init(rng, shape, dtype):  # as DenseGeneral: drawn flat
+            return nn.initializers.lecun_normal()(
+                rng, (fan_in, math.prod(features)), dtype).reshape(shape)
+
+        kernel = self.param("kernel", kernel_init, (*inputs, *features),
+                            jnp.float32)
+        bias = self.param("bias", nn.initializers.zeros, features, jnp.float32)
+        x, kernel, bias = nn.dtypes.promote_dtype(x, kernel, bias,
+                                                  dtype=self.dtype)
+        seen = (*features[:-2], math.prod(features[-2:]))
+        x = x.reshape(*x.shape[:x.ndim - self.contract], fan_in)
+        return jax.lax.dot_general(
+            x, kernel.reshape(fan_in, *seen), (((x.ndim - 1,), (0,)), ((), ()))
+        ) + bias.reshape(seen)
+
+
 class SelfAttention(nn.Module):
     config: TransformerConfig
     attention_fn: Callable | None = None  # None -> local causal attention
@@ -69,20 +110,18 @@ class SelfAttention(nn.Module):
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
         cfg = self.config
-        head_dim = cfg.d_model // cfg.n_heads
-        qkv = nn.DenseGeneral(
-            (3, cfg.n_heads, head_dim), dtype=cfg.dtype, name="qkv"
-        )(x)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        heads = (cfg.n_heads, cfg.d_model // cfg.n_heads)
+        qkv = HeadsDense((3, *heads), dtype=cfg.dtype, name="qkv")(x)
+        q, k, v = (qkv[:, :, i].reshape(*x.shape[:2], *heads)
+                   for i in range(3))
         # Serving prefill taps per-layer K/V here. A no-op unless the caller
         # passes mutable=["kv_cache"] (training never does), so the trained
         # step graphs are untouched.
         self.sow("kv_cache", "kv", (k, v), reduce_fn=lambda _, x: x)
         attn = self.attention_fn or (lambda q, k, v: causal_attention(q, k, v))
         out = attn(q, k, v)  # [B, S, H, D]
-        return nn.DenseGeneral(
-            cfg.d_model, axis=(-2, -1), dtype=cfg.dtype, name="out"
-        )(out)
+        return HeadsDense((cfg.d_model,), contract=2, dtype=cfg.dtype,
+                          name="out")(out)
 
 
 class Mlp(nn.Module):

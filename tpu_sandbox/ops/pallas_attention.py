@@ -9,15 +9,45 @@ softmax recurrence, so peak memory is O(block_q · block_k) per core instead
 of O(S²), and the matmuls stay on the MXU in the input dtype with fp32
 accumulation.
 
-Shapes and grid:
-- inputs [B, H, S, D] (callers with [B, S, H, D] use ``flash_attention_fn``,
-  which transposes, pads S to the lane tile and D to the 128-lane tile, and
-  undoes both on the way out); q and k share one head size and v (with the
-  output) may have another (latent attention: 192 for q.k, 128 for v), each
-  padded to its own lane multiple, and the softmax scale is an argument;
-- grid (B, H, S/block_q, S/block_k), kv innermost ("arbitrary" — it carries
-  the softmax state); m/l/acc live in VMEM scratch across kv steps and the
-  output + logsumexp are written on the last kv step.
+Shapes and grid. The kernels read their operands in one of two forms,
+chosen from what the call can see (``_heads_per_block``):
+- packed: q, k, v ``[B, S, H, D]`` are read as ``[B, S, H·D]`` — the
+  projection's own array — in column blocks 128 lanes wide that hold a
+  whole number of heads: two at D 64, four at D 32, one where D is a lane
+  multiple (a block is then D wide). No transpose, no pad and no slice
+  surrounds the calls, forward or backward; and no copy either where the
+  caller's projection holds the heads side by side in its last dimension
+  (``models/transformer.py::HeadsDense``: the two reshapes cancel), since
+  a ``[..., H, 64]`` array of its own has no unpadded 64-minor layout on a
+  TPU and the compiler would move it into one the kernel reads. The rule:
+  self-attention (q, k, v of one shape), S a lane multiple that every
+  explicit block divides, D a lane multiple or a divisor of 128 whose
+  group divides H. A head's products contract over its own lanes only: the
+  operand that enters each contraction has the lanes of the block's other
+  heads zeroed (exact), and a head writes only its own lanes of a result
+  block its group shares;
+- padded: everything else (q.k and v of different head sizes: latent
+  attention's 192 / 128; an S that is no lane multiple; explicit blocks
+  whose lcm pads S; the ring's entry points, whose S and Sk differ). The
+  operands are made ``[B, H, S, D]`` with S zero-padded to the lane tile
+  (to the blocks, where given) and D, Dv each to its 128-lane multiple
+  (``_to_bhsd``, the single home of that convention), and both are undone
+  on the way out.
+In both, the softmax scale is an argument, and
+- the grid is (B, H / G, S/block_q, G, S/block_k): one head a step, the G
+  heads of a packed block one after the other on the block the pipeline
+  already holds, kv innermost ("arbitrary" — it carries the softmax
+  state); m/l/acc live in VMEM scratch across kv steps and the output +
+  logsumexp are written on the last kv step. One kernel body serves every
+  head of a group (the head's lanes are picked by its place in the group,
+  a runtime index), because every ``pallas_call`` site traces its body
+  anew;
+- the row statistics ride lane-broadcast blocks ``[block_q, 128]``: a
+  head's logsumexp over a whole block of ``[B, H, S, 128]`` (padded), or
+  over its own lanes of a block of ``[B, S, (H/G)·128]`` (packed: half the
+  bytes at D 64); the packed backward takes rowsum(do ⊙ out) on the blocks
+  it holds, where the padded form's caller computes it (the ring reuses it
+  across hops).
 
 The tiles are chosen from the shape, not a constant (``choose_tiles``): a
 grid step costs the chip about half a microsecond whatever it holds, so
@@ -26,14 +56,18 @@ padded lengths, up to 1024 on a side, that fit a VMEM budget reckoned from
 the blocks, scratch and float32 tile temporaries it holds (S 1024: one
 tile a head; S 4096: 4 x 4, ten of them with work). ``block_q=`` /
 ``block_k=`` given explicitly are kept (tests, the ring). Each traced call
-counts its choice into the registry (``attn.tile_choice``). Under
-causality no step is spent above the diagonal: the products are skipped
-(a runtime predicate on the offsets) and the index maps, which see the
-offsets as scalar prefetch, stand still on the last block with work, so
-the pipeline fetches nothing; and only the tiles the diagonal crosses or
-that hold padded keys build the mask, interior tiles are plain products
-(a variant no tile of the grid needs is not built at all: at S 1024 each
-kernel is one body, as it was before there were variants).
+counts its choice into the registry (``attn.tile_choice``: the tiles, the
+steps, ``layout`` and ``heads_per_block``). Under causality, on a grid of
+more than one tile a side, no step is spent above the diagonal: the
+products are skipped (a runtime predicate on the offsets) and the index
+maps, which see the offsets as scalar prefetch, stand still on the last
+block with work, so the pipeline fetches nothing; and only the tiles the
+diagonal crosses or that hold padded keys build the mask, interior tiles
+are plain products (a variant no tile of the grid needs is not built at
+all: at S 1024 each kernel is one body, as it was before there were
+variants). A grid of one tile a head (S 1024) computes the masked half of
+that tile: raced on the chip, it still beat four tiles of 512
+(``_TILE_CAP``).
 
 Backward is the standard flash backward recomputation — no O(S²) residual is
 saved, only (q, k, v, out, lse) — and runs as two Pallas kernels (VERDICT
@@ -56,6 +90,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -86,6 +121,54 @@ def _to_bhsd(x, s_target: int, d_target: int):
 def _from_bhsd(x, s: int, d: int):
     """Inverse of _to_bhsd: slice off padding, heads back to dim 2."""
     return jnp.moveaxis(x[:, :, :s, :d], 1, 2)
+
+
+class _Form(NamedTuple):
+    """How a call's operands lie in HBM, read from their shapes.
+
+    Padded (``packed`` False): q, k ``[B, H, S, d]`` and v ``[B, H, S, dv]``
+    as ``_to_bhsd`` makes them, one head a block. Packed: q, k, v
+    ``[B, S, H·D]``, the projection's own array, read in column blocks
+    ``d == dv`` lanes wide that hold ``group`` whole heads (two at D 64, one
+    at D 128). ``b, h, s, sk`` are the batch, the heads and the lengths of
+    queries and keys."""
+    packed: bool
+    group: int
+    b: int
+    h: int
+    s: int
+    sk: int
+    d: int
+    dv: int
+
+
+def _heads_per_block(q, k, v, block_q, block_k) -> int | None:
+    """The heads a 128-lane column block of ``[B, S, H·D]`` holds whole, or
+    None where the call's shapes keep it on the padded form: q, k, v
+    ``[B, S, H, D]`` of one shape (self-attention, one head size), ``S`` a
+    lane multiple that every explicit block divides, and ``D`` a lane
+    multiple (one head a block) or a divisor of the lane tile whose group
+    divides ``H``."""
+    _, s, h, d = q.shape
+    if not (q.shape == k.shape == v.shape
+            and _pad_len(s, block_q, block_k) == s):
+        return None
+    if d % _LANE == 0:
+        return 1
+    group = _LANE // d
+    return group if group * d == _LANE and h % group == 0 else None
+
+
+def _form(q, k, v, heads: int | None) -> _Form:
+    """``heads`` None: the padded form's ``[B, H, S, D]`` operands; the head
+    count of packed ``[B, S, H·D]`` ones otherwise."""
+    if heads is None:
+        b, h, s, d = q.shape
+        return _Form(False, 1, b, h, s, k.shape[2], d, v.shape[3])
+    b, s, hd = q.shape
+    d = hd // heads
+    group = max(1, _LANE // d)
+    return _Form(True, group, b, heads, s, s, group * d, group * d)
 
 
 _KERNELS = ("fwd", "dkv", "dq")
@@ -189,25 +272,31 @@ def _tile_census(nq, nk, q_offset, kv_offset, **tile):
     return sum(f[0] for f in flags), sum(f[1] for f in flags)
 
 
-def _plan(kernel, b, h, s, sk, d, dv, block_q, block_k, causal, kv_len,
-          q_offset, kv_offset):
+def _plan(kernel, form, block_q, block_k, causal, kv_len, q_offset,
+          kv_offset):
     """The static half of a call: its grid ``(nq, nk)``, the keywords its
-    kernel takes, and the counter in the always-on registry of which tiles
-    it was built with and how many of its grid steps have work, which the
-    call site counts once (``kernel_site``)."""
+    kernel takes, and the counter in the always-on registry of which form
+    and tiles it was built with and how many of its grid steps have work,
+    which the call site counts once (``kernel_site``). ``d`` / ``dv`` are
+    the width a head has in its block: the padded one on the padded form,
+    the head's own on the packed form."""
     from tpu_sandbox.obs import get_registry
 
-    nq, nk = s // block_q, sk // block_k
+    nq, nk = form.s // block_q, form.sk // block_k
     tile = dict(causal=causal, block_q=block_q, block_k=block_k,
                 kv_len=kv_len)
     census = _tile_census(nq, nk, q_offset, kv_offset, **tile)
+    heads = form.b * form.h
     choice = get_registry().counter("attn.tile_choice", labels={
-        "kernel": kernel, "block_q": block_q, "block_k": block_k, "s": s,
-        "d": d, "dv": dv, "steps": b * h * nq * nk,
+        "kernel": kernel, "block_q": block_q, "block_k": block_k,
+        "s": form.s, "d": form.d // form.group, "dv": form.dv // form.group,
+        "steps": heads * nq * nk,
         "steps_with_work": ("traced" if census is None
-                            else b * h * sum(census)),
+                            else heads * sum(census)),
+        "layout": "packed" if form.packed else "padded",
+        "heads_per_block": form.group,
     })
-    return nq, nk, dict(tile, census=census, sk=sk), choice
+    return nq, nk, dict(tile, census=census, sk=form.sk, form=form), choice
 
 
 def _on_tile(i, j, q_off, kv_off, step, *, census, sk, **tile):
@@ -242,9 +331,43 @@ def _on_tile(i, j, q_off, kv_off, step, *, census, sk, **tile):
             pl.when(flag)(functools.partial(step, mask))
 
 
+def _head_lanes(g, group: int, shape):
+    """Bool ``shape``: the lanes of a block that belong to head ``g`` of
+    the ``group`` its lanes hold; None where a block is one head's (nothing
+    to select). A lane outside them enters a contraction as zero, which is
+    exact, and is not written by this head."""
+    if group == 1:
+        return None
+    d = shape[-1] // group
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+    return (lane >= g * d) & (lane < (g + 1) * d)
+
+
+def _own(x, lanes):
+    """``x`` with the lanes of the block's other heads zeroed."""
+    return x if lanes is None else jnp.where(lanes, x, jnp.zeros_like(x))
+
+
+def _row_stat(ref, lanes):
+    """[block_q, 1]: the head's row statistic out of a block that carries
+    it lane-broadcast: over all 128 lanes, or over the head's own."""
+    if lanes is None:
+        return ref[...][:, :1]
+    return jnp.max(jnp.where(lanes, ref[...], -jnp.inf), axis=-1,
+                   keepdims=True)
+
+
+def _write_own(ref, x, lanes):
+    """Write the head's lanes of a result block its group shares: the
+    block stays in VMEM over the group's consecutive steps, each head
+    filling its own lanes, and goes back to HBM when its index moves."""
+    ref[...] = x if lanes is None else jnp.where(lanes, x, ref[...])
+
+
 def _fwd_kernel(q_off_ref, kv_off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *, scale: float, **tile):
-    i, j = pl.program_id(2), pl.program_id(3)
+                m_scr, l_scr, acc_scr, *, scale: float, form: _Form, **tile):
+    i, g, j = pl.program_id(2), pl.program_id(3), pl.program_id(4)
+    lanes = _head_lanes(g, form.group, q_ref.shape)
 
     @pl.when(j == 0)
     def _init():
@@ -253,9 +376,9 @@ def _fwd_kernel(q_off_ref, kv_off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     def _step(valid):
-        q = q_ref[0, 0]                      # [block_q, d]
-        k = k_ref[0, 0]                      # [block_k, d]
-        v = v_ref[0, 0]
+        q = _own(q_ref[...], lanes)          # [block_q, d]
+        k = k_ref[...]                       # [block_k, d]
+        v = v_ref[...]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -277,17 +400,18 @@ def _fwd_kernel(q_off_ref, kv_off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     _on_tile(i, j, q_off_ref[0], kv_off_ref[0], _step, **tile)
 
-    @pl.when(j == pl.num_programs(3) - 1)
+    @pl.when(j == pl.num_programs(4) - 1)
     def _emit():
         l = jnp.maximum(l_scr[:, :1], 1e-30)
-        o_ref[0, 0] = (acc_scr[:] / l).astype(o_ref.dtype)
+        _write_own(o_ref, (acc_scr[:] / l).astype(o_ref.dtype), lanes)
         # lane-broadcast row stats: Mosaic requires the last two block dims
         # to be (8k, 128m)-aligned, so lse is carried as [block_q, LANE]
-        # (the official TPU flash kernel's MIN_BLOCK_SIZE convention) and
-        # sliced back to a row outside the kernel
-        lse_ref[0, 0] = jnp.broadcast_to(
-            m_scr[:, :1] + jnp.log(l), lse_ref.shape[2:]
-        ).astype(jnp.float32)
+        # (the official TPU flash kernel's MIN_BLOCK_SIZE convention): a
+        # head's value over the whole block, or over its own lanes of a
+        # block its group shares
+        _write_own(lse_ref, jnp.broadcast_to(
+            m_scr[:, :1] + jnp.log(l), lse_ref.shape
+        ).astype(jnp.float32), lanes)
 
 
 def _last_kv_block(i, q_off_ref, kv_off_ref, block_q, block_k, nk):
@@ -303,34 +427,47 @@ def _first_q_block(j, q_off_ref, kv_off_ref, block_q, block_k, nq):
     return jnp.minimum(jax.lax.div(jnp.maximum(ahead, 0), block_q), nq - 1)
 
 
-def _block_specs(causal, block_q, block_k, nq, nk, inner):
+def _block_specs(packed, causal, block_q, block_k, nq, nk, inner):
     """``(q_side, kv_side)``: BlockSpec factories ``f(width)`` for blocks
-    ``[1, 1, block, width]`` over a grid (B, H, outer, inner) whose
+    ``[block, width]`` over a grid (B, H / G, outer, G, inner) whose
     innermost dimension walks the kv blocks (``inner == "kv"``: forward,
-    dq) or the q blocks (``"q"``: dk/dv). The outer side's index is the
-    grid's own. The inner side's is clamped, under causality, to the
-    blocks that have work: on the steps above the diagonal the index stands
-    still, the pipeline sees the block it already holds and fetches
-    nothing. The offsets arrive as scalar prefetch, so that holds for the
-    ring's traced ones too."""
-    def outer(b, h, x, y, q_off, kv_off):
-        return (b, h, x, 0)
+    dq) or the q blocks (``"q"``: dk/dv), and whose fourth walks the G
+    heads of a group (1 on the padded form). A block lies at ``(b, h, block,
+    0)`` of a padded ``[B, H, S, width]`` array and at ``(b, block, h)`` of
+    a packed ``[B, S, (H / G)·width]`` one: the group's index, the same for
+    each of its heads, so the pipeline fetches a group's block once. The
+    outer side's block is the grid's own. The inner side's is clamped,
+    under causality, to the blocks that have work: on the steps above the
+    diagonal the index stands still, the pipeline sees the block it already
+    holds and fetches nothing. The offsets arrive as scalar prefetch, so
+    that holds for the ring's traced ones too."""
+    def outer(x, y, q_off, kv_off):
+        return x
 
-    def inner_kv(b, h, i, j, q_off, kv_off):
+    def inner_kv(i, j, q_off, kv_off):
         if causal:
             j = jnp.minimum(j, _last_kv_block(i, q_off, kv_off, block_q,
                                               block_k, nk))
-        return (b, h, j, 0)
+        return j
 
-    def inner_q(b, h, j, i, q_off, kv_off):
+    def inner_q(j, i, q_off, kv_off):
         if causal:
             i = jnp.maximum(i, _first_q_block(j, q_off, kv_off, block_q,
                                               block_k, nq))
-        return (b, h, i, 0)
+        return i
 
-    q_map, kv_map = (outer, inner_kv) if inner == "kv" else (inner_q, outer)
-    return (lambda width: pl.BlockSpec((1, 1, block_q, width), q_map),
-            lambda width: pl.BlockSpec((1, 1, block_k, width), kv_map))
+    def side(rows, block):
+        if packed:
+            return lambda width: pl.BlockSpec(
+                (None, rows, width),
+                lambda b, h, x, g, y, *offs: (b, block(x, y, *offs), h))
+        return lambda width: pl.BlockSpec(
+            (None, None, rows, width),
+            lambda b, h, x, g, y, *offs: (b, h, block(x, y, *offs), 0))
+
+    q_block, kv_block = ((outer, inner_kv) if inner == "kv"
+                         else (inner_q, outer))
+    return side(block_q, q_block), side(block_k, kv_block)
 
 
 def _compiler_params(kernel, block_q, block_k, d, dv, itemsize):
@@ -338,7 +475,8 @@ def _compiler_params(kernel, block_q, block_k, d, dv, itemsize):
 
     need = _vmem_bytes(kernel, block_q, block_k, d, dv, itemsize)
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary",
+                             "arbitrary"),
         vmem_limit_bytes=(_VMEM_BUDGET if need > _VMEM_DEFAULT_SCOPE // 2
                           else None),
     )
@@ -349,11 +487,13 @@ def _offsets(q_offset, kv_offset):
 
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, kv_len,
-               q_offset=0, kv_offset=0, out_dtype=None):
+               q_offset=0, kv_offset=0, out_dtype=None, heads=None):
     """q,k [B,H,S,D], v [B,H,S,Dv] (S a multiple of the blocks, D and Dv
     lane-aligned; ``kv_len`` is the true pre-padding length) ->
-    (out [B,H,S,Dv], lse [B,H,S]). A block left ``None`` is the tile
-    rule's (``choose_tiles``).
+    (out [B,H,S,Dv], lse [B,H,S]); with ``heads`` given, q, k, v packed
+    [B,S,H·D] -> (out [B,S,H·D], lse as the kernel wrote it: a head's value
+    over its lanes of [B,S,(H/G)·128], which the packed backward reads as
+    it lies). A block left ``None`` is the tile rule's (``choose_tiles``).
 
     ``q_offset``/``kv_offset`` are *global* positions of the first local
     query/key (python ints or traced scalars — ring attention passes the
@@ -365,24 +505,29 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, kv_len,
     from jax.experimental.pallas import tpu as pltpu
 
     interpret = default_interpret(interpret)
-    b, h, s, d = q.shape
-    sk, dv = k.shape[2], v.shape[3]
-    block_q, block_k = choose_tiles("fwd", s, sk, d, dv, q.dtype.itemsize,
-                                    block_q=block_q, block_k=block_k)
-    nq, nk, tile, choice = _plan("fwd", b, h, s, sk, d, dv, block_q, block_k,
-                                 causal, kv_len, q_offset, kv_offset)
+    form = _form(q, k, v, heads)
+    d, dv = form.d, form.dv
+    block_q, block_k = choose_tiles("fwd", form.s, form.sk, d, dv,
+                                    q.dtype.itemsize, block_q=block_q,
+                                    block_k=block_k)
+    nq, nk, tile, choice = _plan("fwd", form, block_q, block_k, causal,
+                                 kv_len, q_offset, kv_offset)
     kernel = functools.partial(_fwd_kernel, scale=scale, **tile)
-    q_side, kv_side = _block_specs(causal, block_q, block_k, nq, nk, "kv")
+    q_side, kv_side = _block_specs(form.packed, causal, block_q, block_k, nq,
+                                   nk, "kv")
     with kernel_site("flash_fwd", choice):
         out, lse = pl.pallas_call(
             kernel,
             out_shape=(
-                jax.ShapeDtypeStruct((b, h, s, dv), out_dtype or q.dtype),
-                jax.ShapeDtypeStruct((b, h, s, _LANE), jnp.float32),
+                jax.ShapeDtypeStruct((*q.shape[:-1], v.shape[-1]),
+                                     out_dtype or q.dtype),
+                # lse: a lane tile for every block of q's last dimension
+                jax.ShapeDtypeStruct(
+                    (*q.shape[:-1], q.shape[-1] // d * _LANE), jnp.float32),
             ),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
-                grid=(b, h, nq, nk),
+                grid=(form.b, form.h // form.group, nq, form.group, nk),
                 in_specs=[q_side(d), kv_side(d), kv_side(dv)],
                 out_specs=(q_side(dv), q_side(_LANE)),
                 scratch_shapes=[
@@ -395,35 +540,52 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, kv_len,
                                              q.dtype.itemsize),
             interpret=interpret,
         )(*_offsets(q_offset, kv_offset), q, k, v)
-    return out, lse[..., 0]
+    return out, lse if form.packed else lse[..., 0]
 
 
-def _bwd_tile(valid, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, scale):
+def _delta_rows(form, lanes, do_ref, ref):
+    """[block_q, 1]: the head's delta = rowsum(do ⊙ out). The padded form's
+    caller computed it, once (the ring reuses it across hops), and ``ref``
+    carries it lane-broadcast; the packed form hands the kernels ``out``
+    itself, a block like ``do``'s, and the sum over the head's lanes is
+    taken on the blocks the step holds (float32, as the caller's)."""
+    if not form.packed:
+        return _row_stat(ref, lanes)
+    prod = do_ref[...].astype(jnp.float32) * ref[...].astype(jnp.float32)
+    return jnp.sum(_own(prod, lanes), axis=-1, keepdims=True)
+
+
+def _bwd_tile(valid, lanes, q_ref, k_ref, v_ref, do_ref, lse_ref, delta,
+              scale):
     """What both backward kernels recompute on a tile: the operands, each
     in the dtype the MXU is fed (the inputs' own: bfloat16 stays bfloat16,
     float32 stays float32), and the float32 ``p`` and ``ds`` tiles.
-    p = exp(s - lse); ds = p ⊙ (do·vᵀ - delta) · scale."""
+    p = exp(s - lse); ds = p ⊙ (do·vᵀ - delta) · scale. ``q`` and ``do``
+    come back with the lanes of the block's other heads zeroed (``lanes``),
+    so each of the four products they enter is this head's alone."""
     f32 = jnp.float32
     dt = jnp.result_type(q_ref.dtype, k_ref.dtype, v_ref.dtype, do_ref.dtype)
-    q, k = q_ref[0, 0].astype(dt), k_ref[0, 0].astype(dt)
-    v, do = v_ref[0, 0].astype(dt), do_ref[0, 0].astype(dt)
+    q, k = _own(q_ref[...], lanes).astype(dt), k_ref[...].astype(dt)
+    v, do = v_ref[...].astype(dt), _own(do_ref[...], lanes).astype(dt)
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=f32
     ) * scale                                         # [bq, bk]
-    p = jnp.exp(s - lse_ref[0, 0][:, :1])
+    p = jnp.exp(s - _row_stat(lse_ref, lanes))
     if valid is not None:
         p = jnp.where(valid(), p, 0.0)
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())), preferred_element_type=f32
     )
-    ds = p * (dp - delta_ref[0, 0][:, :1]) * scale
+    ds = p * (dp - delta) * scale
     return q, k, do, p, ds
 
 
 def _bwd_dkv_kernel(q_off_ref, kv_off_ref, q_ref, k_ref, v_ref, do_ref,
                     lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr,
-                    *, scale: float, **tile):
-    """dk/dv: grid (B, H, kv blocks, q blocks), q innermost (accumulates).
+                    *, scale: float, form: _Form, **tile):
+    """dk/dv: grid (B, H/G, kv blocks, G, q blocks), q innermost
+    (accumulates); a group's G heads add into one pair of accumulators,
+    each into its own lanes (its ``q`` and ``do`` are zero elsewhere).
 
     Standard flash backward with saved lse: dv += pᵀ·do; dk += dsᵀ·q, with
     ``p`` and ``ds`` rounded to the operands' dtype just before the product
@@ -432,16 +594,18 @@ def _bwd_dkv_kernel(q_off_ref, kv_off_ref, q_ref, k_ref, v_ref, do_ref,
     accumulators — O(block), the VERDICT r01 weak #4 fix (the jnp scan
     backward held [S, block_k] score slabs per step).
     """
-    j, i = pl.program_id(2), pl.program_id(3)
+    j, g, i = pl.program_id(2), pl.program_id(3), pl.program_id(4)
+    lanes = _head_lanes(g, form.group, q_ref.shape)
 
-    @pl.when(i == 0)
+    @pl.when((g == 0) & (i == 0))
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
     def _step(valid):
-        q, _, do, p, ds = _bwd_tile(valid, q_ref, k_ref, v_ref, do_ref,
-                                    lse_ref, delta_ref, scale)
+        delta = _delta_rows(form, lanes, do_ref, delta_ref)
+        q, _, do, p, ds = _bwd_tile(valid, lanes, q_ref, k_ref, v_ref, do_ref,
+                                    lse_ref, delta, scale)
         dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -454,26 +618,29 @@ def _bwd_dkv_kernel(q_off_ref, kv_off_ref, q_ref, k_ref, v_ref, do_ref,
     # q block entirely before the kv block -> nothing flows
     _on_tile(i, j, q_off_ref[0], kv_off_ref[0], _step, **tile)
 
-    @pl.when(i == pl.num_programs(3) - 1)
+    @pl.when((g == form.group - 1) & (i == pl.num_programs(4) - 1))
     def _emit():
-        dk_ref[0, 0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
+        dk_ref[...] = dk_scr[:].astype(dk_ref.dtype)
+        dv_ref[...] = dv_scr[:].astype(dv_ref.dtype)
 
 
 def _bwd_dq_kernel(q_off_ref, kv_off_ref, q_ref, k_ref, v_ref, do_ref,
                    lse_ref, delta_ref, dq_ref, dq_scr,
-                   *, scale: float, **tile):
-    """dq: grid (B, H, q blocks, kv blocks), kv innermost (accumulates).
-    dq += ds·k with the same p/ds tiles as the dk/dv kernel."""
-    i, j = pl.program_id(2), pl.program_id(3)
+                   *, scale: float, form: _Form, **tile):
+    """dq: grid (B, H/G, q blocks, G, kv blocks), kv innermost
+    (accumulates). dq += ds·k with the same p/ds tiles as the dk/dv kernel;
+    a head writes its own lanes of the group's block."""
+    i, g, j = pl.program_id(2), pl.program_id(3), pl.program_id(4)
+    lanes = _head_lanes(g, form.group, q_ref.shape)
 
     @pl.when(j == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
     def _step(valid):
-        _, k, _, _, ds = _bwd_tile(valid, q_ref, k_ref, v_ref, do_ref,
-                                   lse_ref, delta_ref, scale)
+        delta = _delta_rows(form, lanes, do_ref, delta_ref)
+        _, k, _, _, ds = _bwd_tile(valid, lanes, q_ref, k_ref, v_ref, do_ref,
+                                   lse_ref, delta, scale)
         dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -481,44 +648,51 @@ def _bwd_dq_kernel(q_off_ref, kv_off_ref, q_ref, k_ref, v_ref, do_ref,
 
     _on_tile(i, j, q_off_ref[0], kv_off_ref[0], _step, **tile)
 
-    @pl.when(j == pl.num_programs(3) - 1)
+    @pl.when(j == pl.num_programs(4) - 1)
     def _emit():
-        dq_ref[0, 0] = dq_scr[:].astype(dq_ref.dtype)
+        _write_own(dq_ref, dq_scr[:].astype(dq_ref.dtype), lanes)
 
 
 def _flash_bwd(q, k, v, delta, lse, g, scale, causal, block_q, block_k,
-               interpret, kv_len, q_offset=0, kv_offset=0, out_dtype=None):
+               interpret, kv_len, q_offset=0, kv_offset=0, out_dtype=None,
+               heads=None):
     """Pallas backward: (dq, dk, dv), peak memory O(block) per core.
 
     q,k [B,H,S,D], v,g [B,H,S,Dv] (block-padded, lane-aligned), lse [B,H,S] fp32,
     delta = rowsum(g ⊙ out) [B,H,S] precomputed by the caller (once — ring
     callers reuse it across hops). ``out_dtype`` overrides the gradient
     dtype (ring callers pass fp32 so per-hop partials accumulate unrounded).
-    Blocks left ``None`` are the tile rule's, each kernel its own.
+    Blocks left ``None`` are the tile rule's, each kernel its own. With
+    ``heads`` given, q, k, v, g are packed [B,S,H·D], lse lies as the packed
+    forward wrote it and ``delta`` is the forward's ``out``, packed like g
+    (``_delta_rows``).
     """
     from jax.experimental.pallas import tpu as pltpu
 
     interpret = default_interpret(interpret)
-    b, h, s, d = q.shape
-    sk, dv = k.shape[2], v.shape[3]
+    form = _form(q, k, v, heads)
+    d, dv = form.d, form.dv
     itemsize = q.dtype.itemsize
-    # row stats enter lane-broadcast ([B,H,S] -> [B,H,S,LANE]) for the same
-    # Mosaic block-alignment reason the forward emits lse that way
-    lse = jnp.broadcast_to(lse[..., None], (*lse.shape, _LANE))
-    delta = jnp.broadcast_to(delta[..., None], (*delta.shape, _LANE))
+    if not form.packed:
+        # row stats enter lane-broadcast ([B,H,S] -> [B,H,S,LANE]) for the
+        # same Mosaic block-alignment reason the forward emits lse that way
+        lse = jnp.broadcast_to(lse[..., None], (*lse.shape, _LANE))
+        delta = jnp.broadcast_to(delta[..., None], (*delta.shape, _LANE))
     offs = _offsets(q_offset, kv_offset)
 
-    def call(name, kernel, inner, like):
+    def call(name, kernel, inner, like, widths):
         """One backward kernel over a grid whose innermost dimension walks
         the ``inner`` side; it emits a gradient for each array of ``like``
-        (which lie on the other side), through a float32 scratch each."""
-        bq, bk = choose_tiles(name, s, sk, d, dv, itemsize,
+        (which lie on the other side, in blocks ``widths`` wide), through a
+        float32 scratch each."""
+        bq, bk = choose_tiles(name, form.s, form.sk, d, dv, itemsize,
                               block_q=block_q, block_k=block_k)
-        nq, nk, tile, choice = _plan(name, b, h, s, sk, d, dv, bq, bk, causal,
-                                     kv_len, q_offset, kv_offset)
-        q_side, kv_side = _block_specs(causal, bq, bk, nq, nk, inner)
+        nq, nk, tile, choice = _plan(name, form, bq, bk, causal, kv_len,
+                                     q_offset, kv_offset)
+        q_side, kv_side = _block_specs(form.packed, causal, bq, bk, nq, nk,
+                                       inner)
         out_side, rows = (kv_side, bk) if inner == "q" else (q_side, bq)
-        widths = [x.shape[3] for x in like]
+        groups = form.h // form.group
         with kernel_site("flash_" + name, choice):
             return pl.pallas_call(
                 functools.partial(kernel, scale=scale, **tile),
@@ -526,9 +700,11 @@ def _flash_bwd(q, k, v, delta, lse, g, scale, causal, block_q, block_k,
                            for x in like],
                 grid_spec=pltpu.PrefetchScalarGridSpec(
                     num_scalar_prefetch=2,
-                    grid=(b, h, nk, nq) if inner == "q" else (b, h, nq, nk),
+                    grid=((form.b, groups, nk, form.group, nq) if inner == "q"
+                          else (form.b, groups, nq, form.group, nk)),
                     in_specs=[q_side(d), kv_side(d), kv_side(dv), q_side(dv),
-                              q_side(_LANE), q_side(_LANE)],
+                              q_side(_LANE),
+                              q_side(dv if form.packed else _LANE)],
                     out_specs=[out_side(w) for w in widths],
                     scratch_shapes=[pltpu.VMEM((rows, w), jnp.float32)
                                     for w in widths],
@@ -538,8 +714,8 @@ def _flash_bwd(q, k, v, delta, lse, g, scale, causal, block_q, block_k,
                 interpret=interpret,
             )(*offs, q, k, v, g, lse, delta)
 
-    dk, dv_ = call("dkv", _bwd_dkv_kernel, "q", (k, v))
-    (dq,) = call("dq", _bwd_dq_kernel, "kv", (q,))
+    dk, dv_ = call("dkv", _bwd_dkv_kernel, "q", (k, v), (d, dv))
+    (dq,) = call("dq", _bwd_dq_kernel, "kv", (q,), (d,))
     return dq, dk, dv_
 
 
@@ -590,24 +766,28 @@ def _blockwise_bwd(q, k, v, out, lse, g, scale, causal, block_k, kv_len):
     return f(q, k, v, lse, g, delta)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash_core(q, k, v, scale, causal, block_q, block_k, interpret, kv_len):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash_core(q, k, v, scale, causal, block_q, block_k, interpret, kv_len,
+                heads):
     out, _ = _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
-                        kv_len)
+                        kv_len, heads=heads)
     return out
 
 
-def _core_fwd(q, k, v, scale, causal, block_q, block_k, interpret, kv_len):
+def _core_fwd(q, k, v, scale, causal, block_q, block_k, interpret, kv_len,
+              heads):
     out, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
-                          kv_len)
+                          kv_len, heads=heads)
     return out, (q, k, v, out, lse)
 
 
-def _core_bwd(scale, causal, block_q, block_k, interpret, kv_len, res, g):
+def _core_bwd(scale, causal, block_q, block_k, interpret, kv_len, heads, res,
+              g):
     q, k, v, out, lse = res
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    delta = out if heads else jnp.sum(
+        g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     return _flash_bwd(q, k, v, delta, lse, g, scale, causal, block_q, block_k,
-                      interpret, kv_len)
+                      interpret, kv_len, heads=heads)
 
 
 _flash_core.defvjp(_core_fwd, _core_bwd)
@@ -630,22 +810,30 @@ def flash_attention(
     tested) -> [B, S, H, Dv]. ``scale`` multiplies q.k before the softmax;
     None is ``D ** -0.5``.
 
-    Pads S up to the lane tile (to the blocks, where they are given) and
-    D, Dv each up to the 128-lane tile (zero-padded keys are masked inside
-    the kernel; zero-padded q/k lanes add nothing to a score; zero-padded
-    value lanes produce zero output lanes, sliced off). ``block_q`` /
-    ``block_k`` left ``None`` are chosen per kernel from the shapes
-    (``choose_tiles``); given, they hold for all three kernels.
+    Where the shapes allow (``_heads_per_block``: one shape for q, k, v,
+    S a lane multiple, D 64 / 32 / a lane multiple) the kernels read and
+    write ``[B, S, H·D]``, a reshape of what came. Otherwise: pads S up to
+    the lane tile (to the blocks, where they are given) and D, Dv each up
+    to the 128-lane tile (zero-padded keys are masked inside the kernel;
+    zero-padded q/k lanes add nothing to a score; zero-padded value lanes
+    produce zero output lanes, sliced off). ``block_q`` / ``block_k`` left
+    ``None`` are chosen per kernel from the shapes (``choose_tiles``);
+    given, they hold for all three kernels.
     """
     b, s, h, d = q.shape
     dv = v.shape[-1]
     if scale is None:
         scale = 1.0 / float(d) ** 0.5
+    static = (float(scale), causal, block_q, block_k, interpret, s)
+    if _heads_per_block(q, k, v, block_q, block_k):
+        out = _flash_core(*(x.reshape(b, s, h * d) for x in (q, k, v)),
+                          *static, h)
+        return out.reshape(b, s, h, d)
     sp = _pad_len(s, block_q, block_k)
     dp, dvp = _round_up(d, _LANE), _round_up(dv, _LANE)
     out = _flash_core(
         _to_bhsd(q, sp, dp), _to_bhsd(k, sp, dp), _to_bhsd(v, sp, dvp),
-        float(scale), causal, block_q, block_k, interpret, s,
+        *static, None,
     )
     return _from_bhsd(out, s, dv)
 
