@@ -60,11 +60,19 @@ def _nemotron_h(config: dict, **how):
         nemotron_h.MTP_LOSS_WEIGHT)
 
 
+def _olmo_hybrid(config: dict, **how):
+    from tpu_sandbox.models import olmo_hybrid
+
+    return (olmo_hybrid.OlmoHybridLM(
+        olmo_hybrid.OlmoHybridConfig.from_dict(config, **how)), 0.0)
+
+
 #: ``--model`` name -> builder of the models described by a ``--config`` file
 #: of published keys: ``(config, tokens_per_step=, dtype=, remat=, flash=)
 #: -> (model, weight of the MTP loss)``. ``gpt2`` is ``TransformerLM`` from
 #: the size flags, under every parallelism.
-CONFIG_MODELS = {"xing4": _xing4, "nemotron_h": _nemotron_h}
+CONFIG_MODELS = {"xing4": _xing4, "nemotron_h": _nemotron_h,
+                 "olmo_hybrid": _olmo_hybrid}
 
 
 def build(args, devices):

@@ -110,8 +110,9 @@ def _tiny_of(module):
 @pytest.mark.parametrize("model,cls,also", [
     ("gpt2", "TransformerLM", None),
     ("xing4", "Xing4LM", _xing4_built),
-    ("nemotron_h", "NemotronHLM", _nemotron_h_built)],
-    ids=["gpt2", "xing4", "nemotron_h"])
+    ("nemotron_h", "NemotronHLM", _nemotron_h_built),
+    ("olmo_hybrid", "OlmoHybridLM", None)],
+    ids=["gpt2", "xing4", "nemotron_h", "olmo_hybrid"])
 def test_build_returns_model_optimizer_state_engine(model, cls, also, tmp_path):
     """One step through what ``build`` returns, for every ``--model``: the
     loss is finite, the step counted, and a model that holds a share of its

@@ -170,7 +170,7 @@ def gated_group_norm(y, z, scale, groups: int, eps: float):
     return rms_norm(grouped, eps).reshape(gated.shape) * scale
 
 
-def _log_uniform_step(lo: float, hi: float, floor: float):
+def log_uniform_step(lo: float, hi: float, floor: float):
     """``dt_bias`` = softplus^-1 of a step drawn log-uniformly in [lo, hi],
     floored (Mamba-2's usual start)."""
     def init(key, shape, dtype=jnp.float32):
@@ -181,7 +181,7 @@ def _log_uniform_step(lo: float, hi: float, floor: float):
     return init
 
 
-def _log_of_uniform(lo: float, hi: float):
+def log_of_uniform(lo: float, hi: float):
     def init(key, shape, dtype=jnp.float32):
         return jnp.log(jax.random.uniform(key, shape, dtype, lo, hi))
     return init
@@ -211,9 +211,9 @@ class Mamba2Mixer(nn.Module):
                               jnp.float32)
             xbc = nn.silu(causal_conv(xbc, kernel, bias)).astype(cfg.dtype)
         x, b_in, c_in = jnp.split(xbc, [d_in, d_in + g * n], -1)
-        a_log = self.param("A_log", _log_of_uniform(1.0, 16.0), (h,),
+        a_log = self.param("A_log", log_of_uniform(1.0, 16.0), (h,),
                            jnp.float32)
-        dt_bias = self.param("dt_bias", _log_uniform_step(
+        dt_bias = self.param("dt_bias", log_uniform_step(
             cfg.time_step_min, cfg.time_step_max, cfg.time_step_floor), (h,),
             jnp.float32)
         skip = self.param("D", nn.initializers.ones, (h,), jnp.float32)
