@@ -12,6 +12,8 @@ across the shape classes the bench exercises (block-aligned, non-multiple
 sequence lengths, bf16, head_dim below the lane width).
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -150,6 +152,53 @@ def test_mhc_kernels_lower_for_tpu(name, n, c, b, s, dtype):
     text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3))).trace(
         x, phi, alpha, bias).lower(lowering_platforms=("tpu",)).as_text()
     assert text.count("tpu_custom_call") == 4
+
+
+SHORT_CONV_SHAPES = [
+    # name, B, S, the array's width, C, start, bias, operand, result: the
+    # two hybrid cells' calls (a width that is no lane multiple goes through
+    # the pair that reads the tokens on the lanes), a slice read in place
+    # by the row-major pair, and a float32 operand in two sequences
+    ("nemotron3s_xbc_in_in_proj", 1, 8192, 9280, 5120, 4096, True,
+     jnp.bfloat16, jnp.bfloat16),
+    ("olmoh_keys_2880", 1, 8192, 2880, 2880, 0, False, jnp.bfloat16,
+     jnp.float32),
+    ("olmoh_values_5760", 1, 8192, 5760, 5760, 0, False, jnp.bfloat16,
+     jnp.bfloat16),
+    ("a_slice_of_rows", 1, 8192, 10240, 5120, 4096, True,
+     jnp.bfloat16, jnp.bfloat16),
+    ("float32_b2", 2, 384, 512, 512, 0, True, jnp.float32, jnp.float32),
+    ("float32_b2_lanes", 2, 384, 520, 512, 8, True, jnp.float32, jnp.float32),
+]
+
+
+@pytest.mark.parametrize("name,b,s,width,c,start,bias,dtype,result",
+                         SHORT_CONV_SHAPES,
+                         ids=[m[0] for m in SHORT_CONV_SHAPES])
+def test_short_conv_kernels_lower_for_tpu(name, b, s, width, c, start, bias,
+                                          dtype, result):
+    """``ops/pallas_short_conv.py``: forward and backward at the tiles the
+    rule picks: a channel offset in the index map, sublane-shifted
+    (lane-shifted where the tokens fill the lanes) slices of a row group, a
+    halo block whose index is clamped, a result block that stays over the
+    sequence's steps."""
+    from tpu_sandbox.ops import pallas_short_conv
+
+    x = jax.ShapeDtypeStruct((b, s, width), dtype)
+    taps = jax.ShapeDtypeStruct((4, c), jnp.float32)
+    bvec = jax.ShapeDtypeStruct((c,), jnp.float32) if bias else None
+
+    def loss(x, taps, bvec):
+        y = pallas_short_conv.short_conv(
+            x, taps, bvec, start=start, dtype=result, interpret=False)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).trace(
+        x, taps, bvec).lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 2
+    # the pair the cell runs, by the array's width
+    pair = "_kernel_cf" if width % 128 else "_kernel"
+    assert re.findall(r'kernel_name = "_(?:fwd|bwd)(\w+)"', text) == [pair] * 2
 
 
 @pytest.mark.parametrize("blocks", [{}, {"block_q": 128, "block_k": 128}],

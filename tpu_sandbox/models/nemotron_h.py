@@ -53,6 +53,8 @@ import jax.numpy as jnp
 
 from tpu_sandbox.models.xing4 import RMSNorm, rms_norm
 from tpu_sandbox.ops.attention import causal_attention
+# ``causal_conv``: the plain form, here for the tests that compare with it
+from tpu_sandbox.ops.pallas_short_conv import causal_conv, short_conv
 from tpu_sandbox.ops.ssd import ssd_scan
 from tpu_sandbox.parallel.expert import ExpertShare, share_rows
 
@@ -153,15 +155,6 @@ def time_step(dt, dt_bias):
     return jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
 
 
-def causal_conv(x, kernel, bias):
-    """Depthwise causal convolution over ``x [B, S, C]`` with
-    ``kernel [K, C]`` (tap K - 1 reads the current token) as K shifted
-    multiply-adds in float32, plus ``bias``."""
-    k, s = kernel.shape[0], x.shape[1]
-    padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
-    return bias + sum(padded[:, i:i + s] * kernel[i] for i in range(k))
-
-
 def gated_group_norm(y, z, scale, groups: int, eps: float):
     """``RMSNorm_groups(y * silu(z)) * scale`` over ``groups`` equal slices
     of the last axis, in float32: the gate before the norm."""
@@ -201,7 +194,7 @@ class Mamba2Mixer(nn.Module):
         d_in, conv_dim = h * p, h * p + 2 * g * n
         dense = functools.partial(nn.Dense, use_bias=False, dtype=cfg.dtype)
         zxbcdt = dense(d_in + conv_dim + h, name="in_proj")(u)
-        z, xbc, dt = jnp.split(zxbcdt, [d_in, d_in + conv_dim], -1)
+        z, dt = zxbcdt[..., :d_in], zxbcdt[..., d_in + conv_dim:]
         with jax.named_scope("conv"):
             kernel = self.param(
                 "conv_kernel", nn.initializers.variance_scaling(
@@ -209,7 +202,8 @@ class Mamba2Mixer(nn.Module):
                 (cfg.conv_kernel, conv_dim), jnp.float32)
             bias = self.param("conv_bias", nn.initializers.zeros, (conv_dim,),
                               jnp.float32)
-            xbc = nn.silu(causal_conv(xbc, kernel, bias)).astype(cfg.dtype)
+            # xBC where it lies in ``in_proj``'s result: no slice is made
+            xbc = short_conv(zxbcdt, kernel, bias, start=d_in)
         x, b_in, c_in = jnp.split(xbc, [d_in, d_in + g * n], -1)
         a_log = self.param("A_log", log_of_uniform(1.0, 16.0), (h,),
                            jnp.float32)

@@ -47,8 +47,9 @@ import jax
 import jax.numpy as jnp
 
 from tpu_sandbox.models.nemotron_h import (
-    causal_conv, log_of_uniform, log_uniform_step, time_step)
+    log_of_uniform, log_uniform_step, time_step)
 from tpu_sandbox.models.xing4 import RMSNorm, rms_norm
+from tpu_sandbox.ops import pallas_short_conv
 from tpu_sandbox.ops.attention import causal_attention
 from tpu_sandbox.ops.delta_rule import gated_delta_rule
 
@@ -139,17 +140,26 @@ def log_decay(raw, a_log, dt_bias):
     return -jnp.exp(a_log) * time_step(raw, dt_bias)
 
 
-# each keeps its compute-dtype inputs for the backward pass and computes its
-# float32 passes again there (elementwise: cheap), instead of keeping four to
-# six float32 arrays of [tokens, heads x width] a layer
-@functools.partial(jax.checkpoint, static_argnums=(2, 3))
 def short_conv(x, taps, heads: int, unit: float | None):
     """``silu(conv(x))`` by heads, ``x [B, S, heads x width]``, ``taps [K,
     heads x width]``; a head's vector scaled to length ``unit`` if given.
-    Float32 inside; ``[B, S, heads, width]`` in ``x``'s dtype."""
-    y = nn.silu(causal_conv(x, taps, 0.0))
+    The convolution through ``ops/pallas_short_conv.py`` (float32 inside,
+    its own backward pass), its result in float32 where the norm reads it,
+    so that nothing is rounded between the two, and in ``x``'s dtype where
+    nothing does; ``[B, S, heads, width]`` in ``x``'s dtype."""
+    y = pallas_short_conv.short_conv(
+        x, taps, dtype=x.dtype if unit is None else jnp.float32)
     y = y.reshape(*x.shape[:2], heads, -1)
-    return (y if unit is None else l2_normalise(y, unit)).astype(x.dtype)
+    return y if unit is None else unit_heads(y, unit, x.dtype)
+
+
+# each keeps its inputs for the backward pass and computes its float32
+# passes again there (elementwise: cheap), instead of keeping four to six
+# float32 arrays of [tokens, heads x width] a layer
+@functools.partial(jax.checkpoint, static_argnums=(1, 2))
+def unit_heads(y, unit: float, dtype):
+    """``l2_normalise`` of every head's vector, in ``dtype``."""
+    return l2_normalise(y, unit).astype(dtype)
 
 
 @functools.partial(jax.checkpoint, static_argnums=(3,))

@@ -1,15 +1,18 @@
-"""Shared bits for the Pallas TPU kernels (pallas_ce, pallas_attention).
+"""Shared bits for the Pallas TPU kernels.
 
 One home for the tile/padding conventions so the kernels can't drift:
 the 128-lane tile width, the large-negative mask filler (chosen so
 ``exp(filler - max)`` underflows to 0 in fp32), alignment rounding, the
 off-TPU interpret-mode fallback that lets the same call path run
-compiled on TPU and interpreted in CPU tests, and ``kernel_site``, what
-every kernel's call site records of itself at trace time.
+compiled on TPU and interpreted in CPU tests, the tile rules' divisors,
+``traced_once``, which makes a kernel's call a jitted function, and
+``kernel_site``, what every kernel's call site records of itself at trace
+time.
 """
 
 from __future__ import annotations
 
+import inspect
 import os
 
 import jax
@@ -20,6 +23,24 @@ LANE = 128
 
 def round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
+
+
+def divisors(total: int, unit: int, cap: int) -> list[int]:
+    """Multiples of ``unit`` that divide ``total``, largest first, none
+    above ``cap``."""
+    top = min(total, cap) // unit * unit
+    return [t for t in range(top, 0, -unit) if total % t == 0]
+
+
+def traced_once(call):
+    """``call`` (arrays in; what shapes its kernel as keyword-only
+    arguments) under ``jax.jit``: every ``pallas_call`` site traces and
+    lowers its kernel anew, and a model has one site a sub-layer, so the
+    call is a jitted function, which JAX traces and lowers once for the
+    operands' shapes and the keywords and calls from every site."""
+    static = [name for name, p in inspect.signature(call).parameters.items()
+              if p.kind is p.KEYWORD_ONLY]
+    return jax.jit(call, static_argnames=static)
 
 
 def default_interpret(interpret: bool | None) -> bool:
@@ -59,11 +80,11 @@ class kernel_site:
     (``attn.tile_choice``, ``mhc.kernel_choice``, ``ssd.chunk_choice``,
     ``moe.share_table``: made at the site, where its name is a literal,
     GL-O402), counted here, once a site, whether the span is entered or
-    not: a kernel whose call is a jitted function (``pallas_mhc``'s
-    ``_traced_once``) counts at the site, ``kernel_site(name, choice)`` as a
-    plain statement, and opens the span inside the jitted function, where it
-    fires once a shape. The sites counted against the spans fired is what
-    jitting the call saves."""
+    not: a kernel whose call is a jitted function (``traced_once``) counts
+    at the site, ``kernel_site(name, choice)`` as a plain statement, and
+    opens the span inside the jitted function, where it fires once a shape.
+    The sites counted against the spans fired is what jitting the call
+    saves."""
 
     __slots__ = ("kernel", "_span")
 

@@ -57,7 +57,6 @@ a program and not once a site.
 from __future__ import annotations
 
 import functools
-import inspect
 import math
 
 import jax
@@ -68,7 +67,9 @@ from jax.experimental.pallas import tpu as pltpu
 from tpu_sandbox.ops.pallas_common import (
     LANE as _LANE,
     default_interpret,
+    divisors as _divisors,
     kernel_site,
+    traced_once as _traced_once,
 )
 
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -128,13 +129,6 @@ def post_jnp(streams, y, h_res, h_post):
 
 
 # --- tiles ---
-
-def _divisors(total: int, unit: int, cap: int) -> list[int]:
-    """Multiples of ``unit`` that divide ``total``, largest first, none
-    above ``cap``."""
-    top = min(total, cap) // unit * unit
-    return [t for t in range(top, 0, -unit) if total % t == 0]
-
 
 def _vmem_bytes(kernel: str, n: int, c: int, tt: int, tc: int,
                 itemsize: int) -> int:
@@ -199,17 +193,6 @@ def _count(kernel: str, x):
         "mhc.kernel_choice", labels={
             "kernel": kernel, "n": n, "c": c, "tokens": tokens,
             "tile_tokens": tt, "tile_c": tc, "passes": passes}))
-
-
-def _traced_once(call):
-    """``call`` (arrays in; what shapes its kernel as keyword-only
-    arguments) under ``jax.jit``: every ``pallas_call`` site traces and
-    lowers its kernel anew, and a model has one site a sub-layer, so the
-    call is a jitted function, which JAX traces and lowers once for the
-    operands' shapes and the keywords and calls from every site."""
-    static = [name for name, p in inspect.signature(call).parameters.items()
-              if p.kind is p.KEYWORD_ONLY]
-    return jax.jit(call, static_argnames=static)
 
 
 def _call(body, *, grid, in_specs, out_specs, out_shape, interpret,
