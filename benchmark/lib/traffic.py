@@ -2,7 +2,7 @@
 (``benchmark/traffic/<mix>.json``) and a seed; the same seed gives the
 same bytes.
 
-Three kinds of mix, by the file's ``kind``:
+Four kinds of mix, by the file's ``kind``:
 
 ``image_batches``  labelled 28x28 images for an image trainer (the program
                    resizes on the device); ``n_images``, ``batch_per_chip``.
@@ -15,6 +15,10 @@ Three kinds of mix, by the file's ``kind``:
                    prefixes of ``len`` tokens, drawn uniformly; 0 = every
                    prompt unique), ``warmup_s`` of the same mix before the
                    window.
+``decode_replay``  a fixed set of sessions for a server, no arrivals:
+                   ``sessions`` prompts of ``prompt_len`` (as above) and
+                   ``max_new_tokens`` each, every prompt unique;
+                   ``warmup_steps`` unmeasured steps after the prefills.
 
 Every attribute draws from its own stream (``default_rng([seed, k])``), so
 changing one distribution leaves the others' draws where they were.
@@ -24,12 +28,14 @@ from __future__ import annotations
 
 import hashlib
 from dataclasses import dataclass
+from statistics import NormalDist
 
 import numpy as np
 
 _STREAMS = {"labels": 0, "noise": 1, "tokens": 2, "arrivals": 3,
             "prompt_len": 4, "output_len": 5, "prompt_tokens": 6,
-            "prefix_tokens": 7, "prefix_choice": 8}
+            "prefix_tokens": 7, "prefix_choice": 8, "session_order": 9,
+            "session_tokens": 10}
 
 
 def _rng(seed: int, stream: str) -> np.random.Generator:
@@ -120,6 +126,42 @@ def requests(spec: dict, seed: int, seconds: float, vocab: int) -> list[Arrival]
         out.append(Arrival(float(due[i]), f"r{i}", tuple(int(t) for t in body),
                            int(olen[i])))
     return out
+
+
+@dataclass(frozen=True)
+class ReplaySession:
+    rid: str
+    prompt: tuple[int, ...]
+    max_new_tokens: int
+
+
+def _length_quantiles(spec: dict, n: int) -> np.ndarray:
+    """The ``n`` evenly spaced quantiles ((i + 1/2) / n) of a length
+    distribution, clipped as ``_lengths`` clips its draws."""
+    p = (np.arange(n) + 0.5) / n
+    if spec["dist"] == "lognormal":
+        z = np.array([NormalDist().inv_cdf(float(q)) for q in p])
+        values = np.exp(np.log(spec["median"]) + spec["sigma"] * z)
+    elif spec["dist"] == "uniform":
+        values = spec["min"] + p * (spec["max"] + 1 - spec["min"])
+    else:
+        raise ValueError(f"unknown length distribution {spec['dist']!r}")
+    return np.clip(np.floor(values), spec["min"], spec["max"]).astype(np.int64)
+
+
+def decode_replay(spec: dict, seed: int, vocab: int) -> list[ReplaySession]:
+    """``sessions`` sessions to be prefilled in set-up and decoded in the
+    window. Every seed gets the **same set** of prompt lengths (the
+    distribution's evenly spaced quantiles: the work of a step must not
+    swing with the seed) in an order drawn from the seed, and token ids of
+    its own, unique to each session."""
+    n = int(spec["sessions"])
+    lengths = _rng(seed, "session_order").permutation(
+        _length_quantiles(spec["prompt_len"], n))
+    rng = _rng(seed, "session_tokens")
+    return [ReplaySession(f"s{i}", tuple(int(t) for t in rng.integers(
+        1, vocab, size=int(lengths[i]))), int(spec["max_new_tokens"]))
+        for i in range(n)]
 
 
 def digest(obj) -> str:

@@ -179,6 +179,10 @@ def main() -> None:
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(devices),
               "memory_peak_bytes": memory_peak(obs, devices[:cell["chips"]])}
+    # a runner whose reference runs on what the window produced runs it
+    # here: the peak is read, so the reference's memory is not the cell's
+    if hasattr(runner, "verify"):
+        runner.verify(obs, session)
     metrics: dict = {}
     breakdown = None
     if traced:
@@ -210,9 +214,16 @@ def main() -> None:
               "failed": obs.failed, "metrics": metrics, "device": device}
     if breakdown:
         result["breakdown"] = breakdown
+    # each number compared beside its limit: the line's last key, and the
+    # last lines on stderr
+    compared = obs.notes.pop("compared", None)
     result["notes"] = dict(obs.notes, problems=obs.problems,
                            compiles_in_window=obs.compiles_in_window,
                            setup_phases_s=obs.facts)
+    if compared:
+        result["compared"] = compared
+        for name, pair in compared.items():
+            say(f"compared {name}: {pair['value']:.6g} (limit {pair['limit']})")
     print(json.dumps(result), flush=True)
 
 

@@ -116,9 +116,16 @@ def build(config: dict, dep: dict, seed: int, facts: dict):
                        buckets=tuple(dep["prefill_buckets"]),
                        cache_dtype=cache_dtype, eos_token=None)
     t0 = time.perf_counter()
-    params = TransformerLM(mcfg).init(
-        jax.random.key(seed), jnp.zeros((1, 8), jnp.int32))["params"]
+    # the weights in one jitted call from the seed (eagerly, flax's init is
+    # a program an operation: 14 s of set-up at gpt2-medium, PR 40). Its two
+    # small inputs are ready before it starts and die after it has ended, so
+    # that no release is deferred past the call (this alone did not settle
+    # where the allocator puts the pages: PERF.md section 2, the two modes)
+    inputs = jax.block_until_ready(
+        (jax.random.key(seed), jnp.zeros((1, 8), jnp.int32)))
+    params = jax.jit(TransformerLM(mcfg).init)(*inputs)["params"]
     jax.block_until_ready(params)
+    del inputs
     t1 = time.perf_counter()
     facts["init_s"] = t1 - t0
     # build_decode_step lowers and compiles in one call: the split comes

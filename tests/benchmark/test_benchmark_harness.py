@@ -24,6 +24,8 @@ def generate(spec: dict, seed: int):
     if spec["kind"] == "token_batches":
         stream = traffic.token_batches(spec, seed, vocab=50257)
         return [next(stream) for _ in range(3)]
+    if spec["kind"] == "decode_replay":
+        return traffic.decode_replay(spec, seed, vocab=50257)
     return traffic.requests(spec, seed, seconds=5.0, vocab=50257)
 
 
@@ -168,15 +170,16 @@ def test_manifest_validation_catches(tmp_path, breakage, expect):
     assert any(expect in e for e in errors), errors
 
 
-#: What the kept serving cell needs to become a cell (PERF.md section 7,
-#: first row): these entries and no new file. The bounds are placeholders;
-#: the PR that adds the cell measures them.
+#: What the kept open-loop serving cell needs to become a cell (PERF.md
+#: section 7, first row): these entries and no new file. The bounds are
+#: placeholders; the PR that adds the cell measures them. Where the replay
+#: cell (PR 40) already brought a metric, the cell appends itself to it.
 SERVE_CELL = "gpt2m_serve_chat"
 SERVE_END_TO_END = [("serve_tok_per_s", "tokens/s", "higher"),
                     ("ttft_p90_ms", "ms", "lower"),
                     ("itl_p99_ms", "ms", "lower")]
 SERVE_PER_LAYER = [
-    ("decode_step_ms", "ms", "host_clock", "serve engine", "itl_p99_ms"),
+    ("decode_step_p50_ms", "ms", "host_clock", "serve engine", "itl_p99_ms"),
     ("decode_device_ms", "ms", "device_trace", "serve engine", "itl_p99_ms"),
     ("prefill_ms", "ms", "device_trace", "serve engine", "ttft_p90_ms"),
     ("batch_occupancy_pct", "%", "program_counter", "serve scheduler",
@@ -199,10 +202,18 @@ def add_serving_cell(root: Path) -> None:
         m["end_to_end"].append({
             "name": name, "unit": unit, "better": better, "bound": 0.05,
             "source": "host_clock", "workloads": [SERVE_CELL]})
+    have = {x["name"]: x for x in m["end_to_end"] + m["per_layer"]}
+    moved = set()
     for name, unit, source, layer, moves in SERVE_PER_LAYER:
+        if name in have:
+            have[name]["workloads"].append(SERVE_CELL)
+            moved.add(have[name]["moves"])
+            continue
         m["per_layer"].append({
             "name": name, "unit": unit, "better": "lower", "source": source,
             "layer": layer, "moves": moves, "workloads": [SERVE_CELL]})
+    for name in moved:  # a cell reports what its per-layer metrics move
+        have[name]["workloads"].append(SERVE_CELL)
     (root / "BENCHMARK.json").write_text(json.dumps(m))
 
 

@@ -81,15 +81,22 @@ def test_reader_reads_nothing_from_a_program_without_the_record(
 
 
 def test_the_entries_are_appended_with_a_reader_each():
+    """An invariant, not today's contents: later PRs append entries and
+    cells, so the nine appear, in this order, each with its reader, in the
+    cells of PR 35 at least (a later training cell appends itself)."""
     per_layer = manifest.load()["per_layer"]
     names = [m["name"] for m in per_layer]
     mine = list(ALL_FIVE + CONVNET_ONLY)
-    assert names[-len(mine):] == mine      # at the end of the list
-    cells = [w["name"] for w in manifest.load()["workloads"]]
-    for m in per_layer[-len(mine):]:
+    assert [n for n in names if n in mine] == mine    # all, in this order
+    training = ("convnet3000_1chip_bs5", "convnet3000_dp4_bs5",
+                "gpt2m_train_s1024", "xing4_train_s4096",
+                "nemotron3s_train_s8192")                   # PR 35's cells
+    for m in (m for m in per_layer if m["name"] in mine):
         assert m["source"] in ("program_span", "program_counter")
-        want = CONVNET_CELLS if m["name"] in CONVNET_ONLY else tuple(cells)
-        assert tuple(m["workloads"]) == want, m["name"]
+        if m["name"] in CONVNET_ONLY:
+            assert tuple(m["workloads"]) == CONVNET_CELLS, m["name"]
+        else:
+            assert set(training) <= set(m["workloads"]), m["name"]
         assert m["moves"] == ("train_step_ms" if m["name"]
                               == "compiles_in_loop" else "setup_s")
         assert callable(manifest.module("layer_metrics", m["name"]).read)

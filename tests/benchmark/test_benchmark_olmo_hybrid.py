@@ -65,7 +65,8 @@ def test_the_cell_resolves_and_reports_its_metrics():
         assert entries[name]["source"] == "device_trace"
     assert entries["delta_rule_roofline"]["unit"] == "%"
     cells = manifest.load()["workloads"]
-    assert len(cells) == 6 and cells[-1]["name"] == CELL
+    # an invariant, not today's contents: later PRs append their cells
+    assert len(cells) >= 6 and cells[5]["name"] == CELL
     assert sum(w["chips"] == 4 for w in cells) <= max(1, len(cells) // 4)
     assert manifest.validate() == []
 
