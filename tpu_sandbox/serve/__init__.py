@@ -3,10 +3,15 @@
 Layers (bottom up):
 
 - ``cache``   — host-side paged KV-cache allocator: fixed-size blocks, per-
-  sequence block tables, free-list reuse, refcounted prefix sharing.
+  sequence block tables, free-list reuse, refcounted prefix sharing
+  (declined for a model with recurrent layers, whose fixed-size state a
+  decode slot lives beside the pages).
 - ``decode``  — AOT-compiled static-shape prefill (bucketed lengths) and
-  single-token decode step for ``models/transformer.py``, both donating the
-  device page buffers; replay-exact seeded sampling (``sample_token``).
+  single-token decode step, a model family at a time
+  (``models/transformer.py``: pages for every layer; ``models/jamba.py``:
+  pages for the attention layers at the key/value heads and the Mamba
+  layers' slot state), both donating the device buffers; replay-exact
+  seeded sampling (``sample_token``).
 - ``engine``  — continuous-batching engine: admits/evicts sequences at
   decode-step granularity, preempts-to-requeue under block pressure, plus a
   static-batch baseline as its control. SLO guardrails live here:

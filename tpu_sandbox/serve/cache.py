@@ -1,13 +1,24 @@
 """Paged KV-cache allocator: blocks, block tables, free lists, prefix reuse.
 
-The device side is two flat page arrays per model —
+The device side holds two kinds of state, owned and donated through the
+compiled prefill/decode steps (``serve/decode.py`` has the shapes a model
+family at a time):
 
-    k_pages, v_pages : [n_layers, num_blocks, block_size, n_heads, head_dim]
+- **pages** for the attention layers —
 
-— owned and donated through the compiled prefill/decode steps (see
-``serve/decode.py``). Everything in this module is *host* bookkeeping:
-which blocks belong to which sequence, which are free, and which hold a
-shared prompt prefix.
+      k_pages, v_pages : [num_blocks, block_size, n_kv_heads, head_dim]
+
+  a layer (``TransformerLM``: all layers in one buffer, every query head a
+  key/value head of its own; ``JambaLM``: a buffer a layer, one key/value
+  head for twenty query heads) — which this module's blocks index;
+- **slot state** for recurrent layers (``JambaLM``'s Mamba layers: the
+  scan's state and the convolution's last inputs), one fixed-size entry a
+  decode slot. It needs no allocator: the entry is the engine's slot
+  index, it is overwritten whole by the prefill that admits a sequence to
+  the slot, and it is worth nothing once the sequence leaves.
+
+Everything in this module is *host* bookkeeping: which blocks belong to
+which sequence, which are free, and which hold a shared prompt prefix.
 
 Design points:
 
@@ -22,6 +33,14 @@ Design points:
   Cached blocks carry one extra cache reference so they survive their
   owning sequence; under pressure the allocator drops unreferenced cache
   entries (free-list reuse on eviction).
+- **No prefix sharing beside recurrent state** (``recurrent=True``). A
+  shared block holds the keys and values of a prefix, but a recurrent layer
+  has one state a sequence and none to start from at the prefix's end, so
+  the prefill would have to run the prefix anyway. Such a model's
+  allocator never registers or reuses a prefix; an admission whose leading
+  block a live sequence already holds counts as
+  ``serve.prefix_reuse_declined``. (Snapshots of the state at block
+  boundaries would lift this; they are not built.)
 - **Recompute on eviction.** When a sequence is preempted its blocks are
   freed and the request is requeued with its original prompt; decoding is
   greedy and the step functions are bitwise deterministic, so the replay
@@ -35,6 +54,8 @@ from dataclasses import dataclass, field
 from typing import Sequence
 
 import numpy as np
+
+from tpu_sandbox.obs import get_registry
 
 
 @dataclass(frozen=True)
@@ -88,10 +109,11 @@ class SeqAlloc:
 class PagedKVCache:
     """Block allocator + prefix cache. Pure host state (numpy ints only)."""
 
-    def __init__(self, config: CacheConfig):
+    def __init__(self, config: CacheConfig, *, recurrent: bool = False):
         if config.num_blocks < 2:
             raise ValueError("need at least one allocatable block beyond null")
         self.config = config
+        self.recurrent = recurrent
         self._free: list[int] = list(range(config.num_blocks - 1, 0, -1))
         self._refs: dict[int, int] = {}
         # chain hash -> block id, insertion-ordered for FIFO cache eviction
@@ -99,7 +121,7 @@ class PagedKVCache:
         self._seqs: dict[int, SeqAlloc] = {}
         self._next_seq = 0
         self.stats = {"prefix_hits": 0, "prefix_blocks_reused": 0,
-                      "evicted_cache_blocks": 0}
+                      "evicted_cache_blocks": 0, "prefix_reuse_declined": 0}
 
     # -- introspection -------------------------------------------------------
 
@@ -143,6 +165,11 @@ class PagedKVCache:
                 f"{cfg.max_blocks_per_seq}")
         hashes = _chain_hashes(prompt, cfg.block_size)
         shared: list[int] = []
+        if self.recurrent and hashes and any(
+                seq.prompt_hashes[:1] == hashes[:1]
+                for seq in self._seqs.values()):
+            self.stats["prefix_reuse_declined"] += 1
+            get_registry().counter("serve.prefix_reuse_declined").inc()
         for hh in hashes:
             bid = self._prefix.get(hh)
             if bid is None:
@@ -271,6 +298,8 @@ class PagedKVCache:
                 return
 
     def _register_prefix(self, alloc: SeqAlloc) -> None:
+        if self.recurrent:
+            return  # no state to start from at a shared prefix's end
         n_full = len(alloc.prompt_hashes)
         for i in range(n_full):
             hh = alloc.prompt_hashes[i]

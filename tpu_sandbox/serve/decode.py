@@ -1,11 +1,37 @@
-"""AOT-compiled static-shape prefill and single-token decode steps.
+"""AOT-compiled static-shape prefill and single-token decode steps, a
+model family at a time (``build_decode_step`` picks by the configuration's
+type).
 
-Both steps run the *unmodified* ``TransformerLM`` — prefill taps per-layer
-K/V through the model's ``kv_cache`` sow collection, decode injects an
-``attention_fn`` that reads/writes the paged cache — so serving exercises
-exactly the weights and math the training stack produced.
+Both steps run the *unmodified* model — prefill taps per-layer K/V through
+the model's ``kv_cache`` sow collection, decode injects an ``attention_fn``
+that reads/writes the paged cache — so serving exercises exactly the
+weights and math the training stack produced.
 
-Bitwise discipline (the parity tests in tests/test_serve.py hold these):
+Two families, two sets of device state (``DecodeStep.buffers``, donated
+through both programs):
+
+- ``TransformerLM``: ``k_pages``, ``v_pages`` ``[n_layers, num_blocks,
+  block_size, n_heads, head_dim]``, one buffer each for all layers.
+- ``JambaLM`` (``recurrent``): pages for the attention layers only, **a
+  buffer a layer** (``[num_blocks, block_size, n_kv_heads, head_dim]``: the
+  key/value heads, not the query heads — one for twenty in Jamba2-3B), so
+  that one layer's write cannot copy another layer's pages; and beside them
+  the Mamba layers' **slot state** (``models/jamba.py::state_shapes``: the
+  scan's state and the convolution's last inputs of every decode slot, a
+  run of layers stacked as the weights are). Prefill runs a prompt from an
+  empty state and stores the state behind its **last real token** into the
+  slot it is given — bucket padding has ``dt = 0`` and does not move it, the
+  convolution's tail is read at ``last_pos`` — which is also the slot's
+  reset at admission. Decode moves the state of the rows with ``lengths >
+  0`` and leaves the others' bit for bit (empty slots and the rows of
+  another weight version ride every call). Behind the logits both programs
+  give every row's greedy pick (``_greedy_pick``; ``DecodeStep.picks``):
+  the engine takes it for a greedy request and brings the logits to the
+  host only for one that samples; and it can dispatch the next decode call
+  on those picks before it has read them (``DecodeStep.next_tokens``).
+
+Bitwise discipline of the ``TransformerLM`` steps (the parity tests in
+tests/test_serve.py hold these):
 
 - Decode computes single-query attention with the query axis padded to 2:
   at q=1 XLA:CPU switches to a matvec kernel whose output-contraction
@@ -34,7 +60,7 @@ from __future__ import annotations
 
 import itertools
 from dataclasses import dataclass
-from typing import Any, Callable
+from typing import TYPE_CHECKING, Any, Callable
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +68,10 @@ import numpy as np
 
 from tpu_sandbox.models.transformer import TransformerConfig, TransformerLM
 from tpu_sandbox.serve.cache import CacheConfig
+
+if TYPE_CHECKING:  # imported where it is built: a second of imports (the
+    # Pallas kernels' modules) that a TransformerLM replica never needs
+    from tpu_sandbox.models.jamba import JambaConfig
 
 
 def sample_token(logits_row: np.ndarray, *, seed: int, step_index: int,
@@ -72,15 +102,30 @@ def sample_token(logits_row: np.ndarray, *, seed: int, step_index: int,
 class DecodeStep:
     """Compiled step functions plus the geometry they were built for."""
 
-    model_cfg: TransformerConfig
+    model_cfg: TransformerConfig | JambaConfig
     cache_cfg: CacheConfig
     max_batch: int
     buckets: tuple[int, ...]
     cache_dtype: Any
-    # bucket length -> compiled prefill(params, k, v, tokens, dest, last)
+    # bucket length -> compiled prefill(params, *buffers, tokens, dest,
+    # last[, slot]): the slot only where ``recurrent``
     prefill: dict[int, Callable]
-    # compiled decode(params, k, v, tokens, lengths, block_tables)
+    # compiled decode(params, *buffers, tokens, lengths, block_tables)
     decode: Callable
+    # shapes of the device state both programs take and give back, donated:
+    # (k_pages, v_pages) and, where ``recurrent``, the slot state
+    buffers: tuple = ()
+    recurrent: bool = False
+    # both programs give, right behind the logits, every row's greedy pick
+    # as one float32 ``[..., 3]``: the token, its log-probability, the
+    # row's log-sum-exp. A greedy request then needs neither the logits on
+    # the host nor a pass over the vocabulary there; a sampled one still
+    # takes the logits
+    picks: bool = False
+    # compiled next_tokens(picks[B, 3]) -> tokens[B, 1] int32, where
+    # ``picks``: a decode call's input tokens from the picks of the call
+    # before it, without a visit to the host (``engine._decode_ahead``)
+    next_tokens: Callable | None = None
 
     def pick_bucket(self, prompt_len: int) -> int:
         for b in self.buckets:
@@ -209,14 +254,202 @@ def make_decode_fn(model_cfg: TransformerConfig, cache_cfg: CacheConfig,
     return jax.jit(serve_decode, donate_argnums=(1, 2))
 
 
-def build_decode_step(model_cfg: TransformerConfig, cache_cfg: CacheConfig,
-                      *, max_batch: int = 4,
+def init_buffers(step: DecodeStep) -> tuple:
+    """Zeroed device state of ``step``'s shapes (finite everywhere, as
+    ``init_pages``)."""
+    return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), step.buffers)
+
+
+def jamba_buffer_shapes(model_cfg: JambaConfig, cache_cfg: CacheConfig,
+                        max_batch: int, cache_dtype: Any) -> tuple:
+    """``(k_pages, v_pages, state)``: a page buffer an attention layer, at
+    the key/value heads; the Mamba layers' state a decode slot."""
+    from tpu_sandbox.models.jamba import state_shapes
+
+    page = jax.ShapeDtypeStruct(
+        (cache_cfg.num_blocks, cache_cfg.block_size,
+         model_cfg.num_key_value_heads, model_cfg.head_dim), cache_dtype)
+    pages = tuple(page for kind in model_cfg.layer_kinds if kind == "attn")
+    return pages, pages, state_shapes(model_cfg, max_batch)
+
+
+def _store(pages: jnp.ndarray, dest: jnp.ndarray, rows: jnp.ndarray):
+    """One layer's pages ``[blocks, block, H, D]`` with ``rows [n, H, D]``
+    written at the flat slots ``dest [n]``."""
+    nb, bs, H, D = pages.shape
+    return pages.reshape(nb * bs, H, D).at[dest].set(
+        rows.astype(pages.dtype)).reshape(pages.shape)
+
+
+def _greedy_pick(logits: jnp.ndarray):
+    """``logits [..., vocab]`` float32 -> float32 ``[..., 3]``: the token
+    (the first largest logit, as ``numpy.argmax`` on the host picks it, so
+    a replay draws the same token; an id under 2^24 is exact in float32),
+    its log-probability, the row's log-sum-exp. **One** small array: the
+    host waits for the step once, and three waits a step wander more by
+    process than 33.5 MB of logits do. As part of the model's head in a
+    device trace. On the host the same is those logits brought over, an
+    argmax over them and a float64 ``exp`` of every one (62 of 83 ms a step
+    at 128 x 65,536)."""
+    with jax.named_scope("JambaLM/lm_head"):
+        logits = logits.astype(jnp.float32)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        return jnp.stack([jnp.argmax(logits, axis=-1).astype(jnp.float32),
+                          logits.max(axis=-1) - lse, lse], axis=-1)
+
+
+def _next_tokens(picks: jnp.ndarray) -> jnp.ndarray:
+    """``_greedy_pick``'s ``[B, 3]`` -> the tokens ``[B, 1]`` int32 a decode
+    call takes."""
+    return picks[:, :1].astype(jnp.int32)
+
+
+def make_jamba_prefill_fn(model_cfg: JambaConfig):
+    """prefill(params, k_pages, v_pages, state, tokens[1, Lb], dest_idx[Lb],
+    last_pos[], slot[]) -> (next_logits[vocab], their greedy pick
+    (``_greedy_pick``), k_pages, v_pages, state).
+
+    The prompt runs from an empty state; what the Mamba layers hold behind
+    ``last_pos`` replaces slot ``slot``'s state whole. ``dest_idx`` as in
+    ``make_prefill_fn``. Pages and state are donated."""
+    from tpu_sandbox.models.jamba import JambaLM
+
+    model = JambaLM(model_cfg)
+    attn = [i for i, kind in enumerate(model_cfg.layer_kinds)
+            if kind == "attn"]
+
+    def serve_prefill(params, k_pages, v_pages, state, tokens, dest_idx,
+                      last_pos, slot):
+        (logits, last), taps = model.apply(
+            {"params": params}, tokens, last_pos=last_pos,
+            mutable=["kv_cache"])
+        k_pages, v_pages = list(k_pages), list(v_pages)
+        with jax.named_scope("write_kv"):
+            for n, i in enumerate(attn):
+                k, v = taps["kv_cache"][f"block{i}"]["attn"]["kv"]
+                k_pages[n] = _store(k_pages[n], dest_idx, k[0])
+                v_pages[n] = _store(v_pages[n], dest_idx, v[0])
+        with jax.named_scope("write_state"):
+            state = {
+                "conv": tuple(
+                    jax.lax.dynamic_update_slice_in_dim(old, new, slot, 2)
+                    for old, new in zip(state["conv"], last["conv"])),
+                "ssm": tuple(
+                    jax.lax.dynamic_update_slice_in_dim(old, new, slot, 1)
+                    for old, new in zip(state["ssm"], last["ssm"]))}
+        logits = logits[0, 0]
+        return (logits, _greedy_pick(logits), tuple(k_pages),
+                tuple(v_pages), state)
+
+    return jax.jit(serve_prefill, donate_argnums=(1, 2, 3))
+
+
+def make_jamba_decode_fn(model_cfg: JambaConfig, cache_cfg: CacheConfig):
+    """decode(params, k_pages, v_pages, state, tokens[B, 1], lengths[B],
+    block_tables[B, max_blocks]) -> (logits[B, vocab], every row's greedy
+    pick (``_greedy_pick``), k_pages, v_pages, state). ``lengths`` as in ``make_decode_fn``; a row with ``lengths ==
+    0`` writes its key and value to the null block and **keeps its state**
+    (``JambaLM``'s ``live``)."""
+    from tpu_sandbox.models.jamba import JambaLM
+
+    bs = cache_cfg.block_size
+    hq, hkv, hd = (model_cfg.num_attention_heads,
+                   model_cfg.num_key_value_heads, model_cfg.head_dim)
+    max_ctx = cache_cfg.max_context
+
+    def serve_decode(params, k_pages, v_pages, state, tokens, lengths,
+                     block_tables):
+        k_pages, v_pages = list(k_pages), list(v_pages)
+        bsz = tokens.shape[0]
+        pos = jnp.maximum(lengths - 1, 0)                      # [B]
+        dest = (jnp.take_along_axis(
+            block_tables, (pos // bs)[:, None], axis=1)[:, 0] * bs
+            + pos % bs)                                        # [B]
+        kv_mask = (jnp.arange(max_ctx)[None, :] < lengths[:, None])
+        layer = itertools.count()
+
+        def attention_fn(q, k, v):
+            # q [B, 1, Hq, D]; k, v [B, 1, Hkv, D]: the new token
+            i = next(layer)
+            with jax.named_scope("write_kv"):
+                k_pages[i] = _store(k_pages[i], dest, k[:, 0])
+                v_pages[i] = _store(v_pages[i], dest, v[:, 0])
+            with jax.named_scope("gather_ctx"):
+                # whole blocks by the table: [B, blocks, bs, Hkv, D]
+                kc = k_pages[i][block_tables].reshape(
+                    bsz, max_ctx, hkv, hd).astype(q.dtype)
+                vc = v_pages[i][block_tables].reshape(
+                    bsz, max_ctx, hkv, hd).astype(q.dtype)
+            # a key/value head's group of query heads side by side
+            qg = q[:, 0].reshape(bsz, hkv, hq // hkv, hd)
+            scores = jnp.einsum("bhgd,bkhd->bhgk", qg, kc,
+                                preferred_element_type=jnp.float32)
+            scores = scores / jnp.sqrt(jnp.float32(hd))
+            scores = jnp.where(kv_mask[:, None, None, :], scores, -jnp.inf)
+            w = jnp.nan_to_num(jnp.exp(scores - scores.max(-1, keepdims=True)))
+            w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-30)
+            out = jnp.einsum("bhgk,bkhd->bhgd", w.astype(vc.dtype), vc)
+            return out.reshape(bsz, 1, hq, hd)
+
+        model = JambaLM(model_cfg, attention_fn=attention_fn)
+        logits, state = model.apply({"params": params}, tokens, state,
+                                    live=lengths > 0)
+        logits = logits[:, 0]
+        return (logits, _greedy_pick(logits), tuple(k_pages),
+                tuple(v_pages), state)
+
+    return jax.jit(serve_decode, donate_argnums=(1, 2, 3))
+
+
+def build_jamba_decode_step(model_cfg: JambaConfig, cache_cfg: CacheConfig,
+                            *, max_batch: int, buckets: tuple[int, ...],
+                            cache_dtype: Any) -> DecodeStep:
+    """``build_decode_step`` for ``JambaLM``: the same geometry, the slot
+    state beside the pages, a slot index into prefill."""
+    from tpu_sandbox.models.jamba import JambaConfig, JambaLM
+
+    if not isinstance(model_cfg, JambaConfig):
+        raise TypeError(f"no serving family for {type(model_cfg).__name__}")
+    if model_cfg.vocab_size >= 2 ** 24:
+        raise ValueError("the greedy pick carries its token in a float32")
+    params_shape = jax.eval_shape(
+        lambda: JambaLM(model_cfg).init(
+            jax.random.key(0),
+            jnp.zeros((1, buckets[0]), jnp.int32))["params"])
+    held = jamba_buffer_shapes(model_cfg, cache_cfg, max_batch, cache_dtype)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32)
+    prefill = {
+        b: make_jamba_prefill_fn(model_cfg).lower(
+            params_shape, *held, jax.ShapeDtypeStruct((1, b), jnp.int32),
+            jax.ShapeDtypeStruct((b,), jnp.int32), scalar, scalar).compile()
+        for b in buckets}
+    decode = make_jamba_decode_fn(model_cfg, cache_cfg).lower(
+        params_shape, *held,
+        jax.ShapeDtypeStruct((max_batch, 1), jnp.int32),
+        jax.ShapeDtypeStruct((max_batch,), jnp.int32),
+        jax.ShapeDtypeStruct(
+            (max_batch, cache_cfg.max_blocks_per_seq), jnp.int32),
+    ).compile()
+    return DecodeStep(
+        model_cfg=model_cfg, cache_cfg=cache_cfg, max_batch=max_batch,
+        buckets=buckets, cache_dtype=cache_dtype, prefill=prefill,
+        decode=decode, buffers=held, recurrent=True, picks=True,
+        next_tokens=jax.jit(_next_tokens).lower(jax.ShapeDtypeStruct(
+            (max_batch, 3), jnp.float32)).compile())
+
+
+def build_decode_step(model_cfg: TransformerConfig | JambaConfig,
+                      cache_cfg: CacheConfig, *, max_batch: int = 4,
                       buckets: tuple[int, ...] = (16, 32, 64),
                       cache_dtype: Any = jnp.float32) -> DecodeStep:
     """AOT-compile every step function for the given static geometry."""
     buckets = tuple(sorted(b for b in buckets if b <= cache_cfg.max_context))
     if not buckets:
         raise ValueError("no prefill bucket fits max_context")
+    if not isinstance(model_cfg, TransformerConfig):
+        return build_jamba_decode_step(
+            model_cfg, cache_cfg, max_batch=max_batch, buckets=buckets,
+            cache_dtype=cache_dtype)
     params_shape = jax.eval_shape(
         lambda: TransformerLM(model_cfg).init(
             jax.random.key(0),
@@ -245,5 +478,5 @@ def build_decode_step(model_cfg: TransformerConfig, cache_cfg: CacheConfig,
     return DecodeStep(
         model_cfg=model_cfg, cache_cfg=cache_cfg, max_batch=max_batch,
         buckets=buckets, cache_dtype=cache_dtype,
-        prefill=prefill, decode=decode,
+        prefill=prefill, decode=decode, buffers=(kd, vd),
     )

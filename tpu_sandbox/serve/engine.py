@@ -19,7 +19,16 @@ allocator — the two differ in scheduling policy only.
 
 Decoding is greedy argmax over fp32 logits by default — deterministic,
 which is what makes requeue/replay and the replica zero-loss story exact
-rather than probabilistic. Sampled decode (``temperature``/``top_k`` on the
+rather than probabilistic. Where a family's programs give that pick
+themselves (``DecodeStep.picks``) the engine takes it (``_choose``) and the
+logits stay on the device; otherwise, and for every sampled request, the
+choice is made here on the host (``_pick_token``). With the pick on the
+device the next step's call needs nothing of the host's but lengths and
+block tables, which the host knows beforehand: it is **dispatched ahead**
+(``_decode_ahead``), before this step's picks are read, so the device goes
+from one step into the next while the host reads, emits and prepares, and
+a step takes the device's time, not the device's plus the host's. Sampled
+decode (``temperature``/``top_k`` on the
 request) keeps the same guarantee: the sampler key is derived from the
 request seed folded with the decode-step index, so a replayed request
 re-draws identical tokens (see ``serve/decode.py:sample_token``).
@@ -44,8 +53,9 @@ import time
 import weakref
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, Callable
+from typing import TYPE_CHECKING, Any, Callable
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -53,7 +63,10 @@ from tpu_sandbox.models.transformer import TransformerConfig
 from tpu_sandbox.obs import get_recorder, get_registry
 from tpu_sandbox.serve.cache import CacheConfig, PagedKVCache, SeqAlloc
 from tpu_sandbox.serve.decode import (DecodeStep, build_decode_step,
-                                      init_pages, sample_token)
+                                      init_buffers, init_pages, sample_token)
+
+if TYPE_CHECKING:
+    from tpu_sandbox.models.jamba import JambaConfig
 
 # engines with a live decode loop / replica thread, for the conftest leak
 # fixture (mirrors kvstore.live_servers())
@@ -66,7 +79,10 @@ def live_engines() -> list:
 
 @dataclass(frozen=True)
 class ServeConfig:
-    model: TransformerConfig = field(default_factory=TransformerConfig)
+    # the model family's configuration: ``build_decode_step`` picks the
+    # family's step builder by its type
+    model: TransformerConfig | JambaConfig = field(
+        default_factory=TransformerConfig)
     cache: CacheConfig = field(default_factory=CacheConfig)
     max_batch: int = 4
     buckets: tuple[int, ...] = (16, 32, 64)
@@ -132,16 +148,30 @@ class _Slot:
                                         # (the engine clock may be a fake)
 
 
+@dataclass
+class _Ahead:
+    """A decode call dispatched before the picks of the call before it
+    were read (``_decode_ahead``)."""
+    picks: Any                  # its rows' picks, still on the device
+    slots: dict[int, _Slot]     # row -> the slot it decodes for
+    ver: int                    # the weight version it runs on
+
+
 #: "this version is not resident" — distinct from None, which is a valid
 #: params value for stub-step engines that never touch weights
 _MISSING = object()
 
 
-def _token_logprob(logits_row: np.ndarray, token: int) -> float:
-    """Logprob of ``token`` under fp32 ``logits_row`` (stable logsumexp).
+def _token_logprob(logits_row: np.ndarray, token: int,
+                   logsumexp: float | None = None) -> float:
+    """Logprob of ``token`` under fp32 ``logits_row`` (stable logsumexp;
+    ``logsumexp`` where the program gave the row's own: nothing of the
+    vocabulary is then exponentiated here).
     Fed into the ``engine.logprob`` series the canary analysis compares —
     a weight regression shows up as the model scoring its own chosen
     tokens lower, with no reference labels needed."""
+    if logsumexp is not None:
+        return float(logits_row[int(token)]) - float(logsumexp)
     row = np.asarray(logits_row, np.float64)
     m = float(row.max())
     return float(row[int(token)] - m - np.log(np.exp(row - m).sum()))
@@ -165,9 +195,21 @@ class _EngineBase:
         self.step_fns = step or build_decode_step(
             config.model, config.cache, max_batch=config.max_batch,
             buckets=config.buckets, cache_dtype=config.cache_dtype)
-        self.cache = PagedKVCache(config.cache)
-        self.k_pages, self.v_pages = init_pages(
-            config.model, config.cache, config.cache_dtype)
+        # the device state both programs take and give back, donated: the
+        # pages and, for a model with recurrent layers, every slot's state
+        self.recurrent = bool(getattr(self.step_fns, "recurrent", False))
+        self.picks = bool(getattr(self.step_fns, "picks", False))
+        self.cache = PagedKVCache(config.cache, recurrent=self.recurrent)
+        self.state = None
+        if getattr(self.step_fns, "buffers", ()):
+            self.k_pages, self.v_pages, *state = init_buffers(self.step_fns)
+            if state:
+                self.state = state[0]
+                get_registry().gauge("serve.state_bytes").set(sum(
+                    x.nbytes for x in jax.tree.leaves(self.state)))
+        else:  # a stub step: the pages are carried and never read
+            self.k_pages, self.v_pages = init_pages(
+                config.model, config.cache, config.cache_dtype)
         self.clock = clock
         self.waiting: deque[Request] = deque()
         self.slots: list[_Slot | None] = [None] * config.max_batch
@@ -175,6 +217,7 @@ class _EngineBase:
         self.shed: dict[str, ShedRecord] = {}
         self.steps = 0
         self.last_step_at: float | None = None
+        self._ahead: _Ahead | None = None
         _LIVE_ENGINES.add(self)
 
     # -- public --------------------------------------------------------------
@@ -269,6 +312,7 @@ class _EngineBase:
     def drain_to_requests(self) -> list[Request]:
         """Evict everything in flight back to request form (original prompt,
         arrival preserved) — the replica's SIGTERM path."""
+        self._ahead = None  # its tokens go with the slots they were for
         out = []
         for i, slot in enumerate(self.slots):
             if slot is None:
@@ -362,6 +406,34 @@ class _EngineBase:
 
     # -- shared mechanics ----------------------------------------------------
 
+    def _run(self, program: Callable, params, *args):
+        """A compiled step over the donated device state (the pages and,
+        where the model has one, the slot state): its logits, still on the
+        device, and the rows' greedy picks where the program gives them
+        (``DecodeStep.picks``), else None."""
+        held = (self.k_pages, self.v_pages) + (
+            () if self.state is None else (self.state,))
+        logits, *rest = program(params, *held, *args)
+        picks = rest.pop(0) if self.picks else None
+        self.k_pages, self.v_pages, *state = rest
+        if state:
+            self.state = state[0]
+        return logits, picks
+
+    def _choose(self, slot: _Slot, row, pick) -> None:
+        """The slot's next token and its log-probability: the program's own
+        pick (``[token, logprob, logsumexp]`` of the row) for a greedy
+        request, else ``_pick_token`` over the logits ``row`` on the
+        host."""
+        if pick is not None and slot.request.temperature <= 0.0:
+            token, logprob = int(pick[0]), float(pick[1])
+        else:
+            token = self._pick_token(slot, row)
+            logprob = _token_logprob(
+                row, token, None if pick is None else pick[2])
+        slot.logprob_sum += logprob
+        self._emit_token(slot, token)
+
     def _admit_from_waiting(self) -> bool:
         """Admit (or resolve) the queue head. True = the head was consumed
         (admitted, or shed because its pinned version is gone); False = the
@@ -422,12 +494,23 @@ class _EngineBase:
             with rec.span("prefill", parent=admit.ctx,
                           args={"rid": request.rid, "plen": plen},
                           hist="engine.prefill_s"):
-                next_logits, self.k_pages, self.v_pages = \
-                    self.step_fns.prefill[bucket](
-                        params, self.k_pages, self.v_pages,
-                        jnp.asarray(toks), jnp.asarray(dest),
-                        jnp.asarray(plen - 1, jnp.int32))
-                row = np.asarray(next_logits).reshape(-1)
+                # a recurrent model's prefill replaces the slot's state
+                # with the prompt's: the slot's reset at admission (and at
+                # the replay of a preempted request)
+                slot_arg = (jnp.asarray(slot_idx, jnp.int32),) \
+                    if self.recurrent else ()
+                next_logits, pick = self._run(
+                    self.step_fns.prefill[bucket], params,
+                    jnp.asarray(toks), jnp.asarray(dest),
+                    jnp.asarray(plen - 1, jnp.int32), *slot_arg)
+                # the logits come to the host only for a request that
+                # samples from them
+                if pick is not None:
+                    pick = np.asarray(pick)
+                row = None if pick is not None and request.temperature <= 0.0 \
+                    else np.asarray(next_logits).reshape(-1)
+            if self.recurrent:
+                get_registry().counter("serve.state_resets").inc()
             alloc.length = plen
             self.cache.commit_prefix(alloc)
             slot = _Slot(request=request, alloc=alloc,
@@ -436,9 +519,7 @@ class _EngineBase:
         slot.tc = None if admit.ctx is None else admit.ctx.to_wire()
         slot.admitted_mono = time.monotonic()
         self.slots[slot_idx] = slot
-        token = self._pick_token(slot, row)
-        slot.logprob_sum += _token_logprob(row, token)
-        self._emit_token(slot, token)
+        self._choose(slot, row, pick)
         if self._finished(slot):
             self._retire(slot_idx)
 
@@ -533,9 +614,16 @@ class _EngineBase:
         weight swap the batch can hold slots pinned to different versions:
         one decode call runs per resident version, with the other
         versions' rows zeroed out (length 0 masks their reads, table 0
-        scatters their writes to the null block — exactly the treatment
-        empty slots already get), so every sequence decodes every token on
-        the weights it pinned at admit, never a blend."""
+        scatters their writes to the null block, and a recurrent layer
+        leaves such a row's state as it was — exactly the treatment empty
+        slots already get), so every sequence decodes every token on
+        the weights it pinned at admit, never a blend.
+
+        A call dispatched ahead by the step before (``_decode_ahead``) is
+        this step's call for the slots that still hold the row it decoded
+        for them; the rows of slots that left since (retired, shed,
+        preempted) are dropped, and slots it does not hold (admitted since)
+        get a call of their own, the others' rows zeroed."""
         B = self.config.max_batch
         cfg = self.config.cache
         rec = get_recorder()
@@ -545,15 +633,29 @@ class _EngineBase:
         for i in range(B):
             if self.slots[i] is not None and not self._ensure_capacity(i):
                 self._preempt(i)
+        ahead, self._ahead = self._ahead, None
+        covered = {} if ahead is None else {
+            i: s for i, s in ahead.slots.items() if self.slots[i] is s}
         by_ver: dict[int, list[int]] = {}
         for i, slot in enumerate(self.slots):
-            if slot is not None:
+            if slot is not None and i not in covered:
                 by_ver.setdefault(slot.ver, []).append(i)
         get_registry().histogram("engine.occupancy").observe(
             self.active_requests / B)
-        if not by_ver:
+        if not by_ver and not covered:
             return
-        rows: dict[int, np.ndarray] = {}
+        # one call decodes every occupied slot: the next step's can follow
+        # it on the device before its picks are read here
+        alone = len(by_ver) + bool(covered) == 1
+        rows: dict[int, tuple] = {}  # slot -> (logits row, program's pick)
+        if covered:
+            with rec.span("engine:decode_call",
+                          hist="engine.decode_call_s", loop=True):
+                if alone:
+                    self._ahead = self._decode_ahead(ahead.picks, ahead.ver)
+                picks = np.asarray(ahead.picks)
+            for i in covered:
+                rows[i] = (None, picks[i])
         for ver in sorted(by_ver):
             members = by_ver[ver]
             tokens = np.zeros((B, 1), np.int32)
@@ -567,24 +669,100 @@ class _EngineBase:
             # dispatch to logits on the host: device time plus the D2H
             with rec.span("engine:decode_call",
                           hist="engine.decode_call_s", loop=True):
-                logits, self.k_pages, self.v_pages = self.step_fns.decode(
-                    self._params_by_ver[ver], self.k_pages, self.v_pages,
+                logits, picks = self._run(
+                    self.step_fns.decode, self._params_by_ver[ver],
                     jnp.asarray(tokens), jnp.asarray(lengths),
                     jnp.asarray(tables))
-                logits = np.asarray(logits)
+                if alone:
+                    self._ahead = self._decode_ahead(picks, ver)
+                # [B, vocab] logits come to the host only if the program
+                # picks nothing or a member samples from them
+                if picks is None or any(
+                        self.slots[i].request.temperature > 0.0
+                        for i in members):
+                    logits = np.asarray(logits)
+                else:
+                    logits = None
+                if picks is not None:
+                    picks = np.asarray(picks)
             for i in members:
-                rows[i] = logits[i]
+                rows[i] = (None if logits is None else logits[i],
+                           None if picks is None else picks[i])
+        self._emit_rows(rows)
+
+    def _emit_rows(self, rows: dict[int, tuple]) -> None:
+        """A step's tokens: slot -> (logits row, program's pick)."""
         self.steps += 1
         self.last_step_at = self.clock()
-        with rec.span("engine:sample", hist="engine.sample_s", loop=True):
+        with get_recorder().span("engine:sample", hist="engine.sample_s",
+                                 loop=True):
             for i in sorted(rows):
                 slot = self.slots[i]
                 slot.alloc.length = len(slot.tokens)
-                token = self._pick_token(slot, rows[i])
-                slot.logprob_sum += _token_logprob(rows[i], token)
-                self._emit_token(slot, token)
+                self._choose(slot, *rows[i])
                 if self._finished(slot):
                     self._retire(i)
+
+    def _decode_ahead(self, picks, ver: int) -> _Ahead | None:
+        """The **next** step's decode call, dispatched now: ``picks`` are
+        this step's, of the one call that decoded every occupied slot, and
+        are still on the device, where the call takes its tokens from them
+        (``DecodeStep.next_tokens``); its lengths and block tables are what
+        this step's emission will make them. The device then runs on into
+        the next step while the host reads this one's picks, emits them
+        and comes round again.
+
+        None — the next step dispatches as it always did — unless the
+        programs pick on the device and every slot is greedy and on
+        ``ver``; and where a slot would need a block the pool cannot give:
+        preempting is the next step's business. A slot whose last token
+        this step emits by count rides the call as an empty row; one that
+        ends on ``eos_token`` cannot be known here, and its row is dropped
+        by the next step (what the call wrote for it lies behind its
+        sequence's end in blocks it held, and in a slot state the next
+        admission replaces)."""
+        if not self.picks:
+            return None
+        B = self.config.max_batch
+        cfg = self.config.cache
+        members: dict[int, _Slot] = {}
+        for i, slot in enumerate(self.slots):
+            if slot is None or \
+                    len(slot.generated) + 1 >= slot.request.max_new_tokens:
+                continue
+            if slot.ver != ver or slot.request.temperature > 0.0:
+                return None
+            members[i] = slot
+        if not members:
+            return None
+        lengths = np.zeros((B,), np.int32)
+        tables = np.zeros((B, cfg.max_blocks_per_seq), np.int32)
+        for i, slot in members.items():
+            at = len(slot.tokens)  # where the call writes: this step's token
+            if at % cfg.block_size == 0 \
+                    and at // cfg.block_size >= len(slot.alloc.block_ids) \
+                    and not self.cache.grow(slot.alloc):
+                return None
+            lengths[i] = at + 1
+            tables[i] = self.cache.block_table(slot.alloc)
+        _, picks = self._run(
+            self.step_fns.decode, self._params_by_ver[ver],
+            self.step_fns.next_tokens(picks), jnp.asarray(lengths),
+            jnp.asarray(tables))
+        return _Ahead(picks=picks, slots=members, ver=ver)
+
+    def settle(self) -> None:
+        """Resolve the call dispatched ahead, if there is one: its tokens
+        are emitted as a step's, and no call follows. Tokens and device
+        state then agree as they do after a step that dispatched nothing
+        ahead: every token of a sequence but its last has passed through
+        the pages and the slot state."""
+        ahead, self._ahead = self._ahead, None
+        if ahead is None:
+            return
+        picks = np.asarray(ahead.picks)
+        self._emit_rows({i: (None, picks[i]) for i, s in ahead.slots.items()
+                         if self.slots[i] is s})
 
     def _admit_waiting(self) -> None:
         """Fill free slots from the queue head until it blocks."""
