@@ -351,3 +351,56 @@ def test_issue_order_reads_the_vmem_the_wrapped_kernels_hold(sizes, want):
 
 def test_issue_order_without_a_collective():
     assert issue_order_report(_LOOP_HLO)["collective"] is None
+
+
+# ---------------------------------------------------------------------------
+# aot_serve_step.count_page_copies: what moves a serving program's pages
+# ---------------------------------------------------------------------------
+
+# lines of gpt2m_serve_decode_replay's decode program as the v5e compiler
+# wrote it before PR 42 (one stacked buffer for all layers; configs trimmed),
+# and the same places of the program with a buffer a layer
+_STACKED_PAGES_HLO = """\
+ENTRY %main.209 (k_pages.1: bf16[24,4097,16,16,64]) -> (f32[64,50257]) {
+  %k_pages.1 = bf16[24,4097,16,16,64]{4,3,2,1,0:T(8,128)(2,1)} parameter(290), metadata={op_name="k_pages"}
+  %copy.426 = bf16[24,4097,16,16,64]{4,3,2,1,0:T(8,128)(2,1)} copy(%k_pages.1), sharding={replicated}, metadata={op_name="k_pages"}
+  %bitcast.17 = bf16[24,65552,16,64]{3,2,1,0:T(8,128)(2,1)} bitcast(%copy.426)
+  %fusion.51 = bf16[24,65552,16,64]{3,2,1,0:T(8,128)(2,1)} fusion(%bitcast.17, %p.1), kind=kLoop, calls=%fused_scatter
+  %fusion.51.remat_compressed = bf16[24,65552,16,64]{1,3,2,0:T(8,128)(2,1)} copy(%fusion.51), backend_config={"flag_configs":[]}
+  %fusion.51.remat_uncompressed = bf16[24,65552,16,64]{3,2,1,0:T(8,128)(2,1)} copy(%fusion.51.remat_compressed), backend_config={"flag_configs":[]}
+  %gather.8 = bf16[64,1024,16,64]{3,2,1,0:T(8,128)(2,1)} gather(%fusion.51.remat_uncompressed, %ctx.3), offset_dims={2,3}
+  %tuple.9 = (f32[64,50257]{1,0:T(8,128)}, bf16[24,65552,16,64]{3,2,1,0:T(8,128)(2,1)}) tuple(%logits.2, %fusion.51.remat_uncompressed)
+  ROOT %while.1 = (s32[]{:T(128)}, bf16[24,65552,16,64]{3,2,1,0:T(8,128)(2,1)}) while(%tuple.8), condition=%cond, body=%body
+}
+"""
+_PAGES_A_LAYER_HLO = """\
+  %k_pages_0_.1 = bf16[4097,16,1024]{2,1,0:T(8,128)(2,1)} parameter(290), metadata={op_name="k_pages[0]"}
+  %bitcast.17 = bf16[65552,1024]{1,0:T(8,128)(2,1)} bitcast(%k_pages_0_.1)
+  %scatter.1 = bf16[65552,1024]{1,0:T(8,128)(2,1)} scatter(%bitcast.17, %dest.2, %rows.5), to_apply=%assign
+  %gather.8 = bf16[64,64,16,1024]{3,2,1,0:T(8,128)(2,1)} gather(%param_0.2, %transpose.373), offset_dims={2,3}
+  %copy.12 = bf16[64,1024,16,64]{3,2,1,0:T(8,128)(2,1)} copy(%bitcast.40)
+"""
+
+
+def test_page_copies_are_counted_by_name_and_by_the_results_size():
+    from aot_serve_step import count_page_copies
+
+    layer = 4097 * 16 * 16 * 64
+    assert count_page_copies(_STACKED_PAGES_HLO, layer, 24 * layer) == {
+        # the two halves by name; three copies of the pool (the argument's
+        # and both halves); with the scatter's fusion four values of its
+        # size -- the parameter, the bitcast, the tuple and the while pass
+        # buffers on and are no values of their own
+        "remat_compressed": 1, "remat_uncompressed": 1,
+        "page_copies": 3, "pool_sized": 4}
+    # in place: the scatter's result is one layer's pages, and counts as a
+    # copy of them only if the compiler says so; the re-laid gathered
+    # context (64 x 1024 rows) stays under a layer's 65,552 rows
+    assert count_page_copies(_PAGES_A_LAYER_HLO, layer, 24 * layer) == {
+        "remat_compressed": 0, "remat_uncompressed": 0,
+        "page_copies": 0, "pool_sized": 0}
+    # a copy of one layer's pages is found at a layer's size
+    padded = _PAGES_A_LAYER_HLO + (
+        "  %copy.292 = bf16[4097,16,16,64]{0,3,2,1:T(8,128)(2,1)} "
+        "copy(%bitcast.17)\n")
+    assert count_page_copies(padded, layer, 24 * layer)["page_copies"] == 1

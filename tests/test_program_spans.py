@@ -389,8 +389,8 @@ def test_compiled_steps_carry_loss_grad_sync_and_optimizer_scopes(
 def test_serve_programs_have_stable_names_and_scopes():
     from tpu_sandbox.models.transformer import TransformerConfig
     from tpu_sandbox.serve.cache import CacheConfig
-    from tpu_sandbox.serve.decode import (make_decode_fn, make_prefill_fn,
-                                          page_shapes)
+    from tpu_sandbox.serve.decode import (buffer_shapes, make_decode_fn,
+                                          make_prefill_fn)
 
     cfg = TransformerConfig(vocab_size=32, d_model=16, n_heads=2, n_layers=2,
                             d_ff=32, max_len=32)
@@ -399,11 +399,11 @@ def test_serve_programs_have_stable_names_and_scopes():
 
     params = jax.eval_shape(lambda: TransformerLM(cfg).init(
         jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"])
-    kd, vd = page_shapes(cfg, cache, jnp.float32)
+    kd, vd = buffer_shapes(cfg, cache, 2, jnp.float32)
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
-    prefill = make_prefill_fn(cfg, cache).lower(
+    prefill = make_prefill_fn(cfg).lower(
         params, kd, vd, i32(1, 8), i32(8), i32())
-    decode = make_decode_fn(cfg, cache, 2).lower(
+    decode = make_decode_fn(cfg, cache).lower(
         params, kd, vd, i32(2, 1), i32(2), i32(2, 4))
     assert "jit_serve_prefill" in prefill.as_text()
     assert "jit_serve_decode" in decode.as_text()

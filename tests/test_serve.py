@@ -1,5 +1,7 @@
 """Serving stack, fast: paged KV allocator units, prefix sharing, the
-decode-vs-forward parity contract, engine-vs-reference greedy
+decode-vs-forward parity contract, the page store both model families
+share (a buffer an attention layer, updated in place, read from the
+compiled programs' text), engine-vs-reference greedy
 outputs (continuous AND static, including under preemption pressure),
 and the in-process replica protocol (drain/requeue, cross-worker
 completion, lease-expiry scavenge).
@@ -14,6 +16,8 @@ The replica gang under real HostAgents (kill a replica mid-load, lose
 nothing) runs slow in test_serve_integration.py.
 """
 
+import math
+import re
 import time
 
 import numpy as np
@@ -32,7 +36,8 @@ from tpu_sandbox.serve import (
     ServeConfig,
     StaticEngine,
 )
-from tpu_sandbox.serve.decode import build_decode_step, init_pages
+from tpu_sandbox.serve.decode import (Pages, _gather, build_decode_step,
+                                      init_buffers)
 
 MCFG = TransformerConfig(vocab_size=64, d_model=32, n_heads=4, n_layers=2,
                          d_ff=64, max_len=128, dtype=jnp.float32)
@@ -157,7 +162,7 @@ def test_decode_matches_padded_forward_to_rounding_fp32(
     (the planted fault: one step is given its length less one) is millions
     apart."""
     cache = PagedKVCache(CCFG)
-    kp, vp = init_pages(MCFG, CCFG)
+    kp, vp = init_buffers(step)
     prompt = [5, 17, 3, 42, 9]
 
     def ref_logits(seq):
@@ -208,7 +213,7 @@ def test_decode_bf16_cache_stays_close(params, fwd32):
     step16 = build_decode_step(MCFG, CCFG, max_batch=2, buckets=(8,),
                                cache_dtype=jnp.bfloat16)
     cache = PagedKVCache(CCFG)
-    kp, vp = init_pages(MCFG, CCFG, jnp.bfloat16)
+    kp, vp = init_buffers(step16)
     prompt = [11, 2, 33, 4]
     alloc = cache.alloc(prompt, 0)
     toks = np.zeros((1, 8), np.int32)
@@ -241,6 +246,132 @@ def test_decode_bf16_cache_stays_close(params, fwd32):
         ref = np.asarray(fwd32(jnp.asarray(padded)))[0, len(seq) - 1]
         np.testing.assert_allclose(cur, ref, rtol=0.05, atol=0.05)
     cache.free(alloc, cache_prefix=False)
+
+
+# -- the page store, either family -----------------------------------------
+
+POOL = CacheConfig(num_blocks=513, block_size=4, max_blocks_per_seq=8)
+POOL_BUCKETS = (8, 16)
+
+
+@pytest.fixture(scope="module", params=["transformer", "jamba"])
+def family(request):
+    """A compiled step set of each serving family over one pool geometry
+    (bfloat16 pages), and what its page buffers should look like: attention
+    layers, key/value heads, head size."""
+    if request.param == "transformer":
+        cfg, model, layers, heads, head_dim = (
+            MCFG, TransformerLM(MCFG), MCFG.n_layers, MCFG.n_heads,
+            MCFG.d_model // MCFG.n_heads)
+    else:
+        from test_jamba_model import tiny_config
+        from tpu_sandbox.models.jamba import JambaLM
+
+        cfg = tiny_config()
+        model, layers, heads, head_dim = (
+            JambaLM(cfg), cfg.layer_kinds.count("attn"),
+            cfg.num_key_value_heads, cfg.head_dim)
+    weights = jax.eval_shape(lambda: model.init(
+        jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    step = build_decode_step(cfg, POOL, max_batch=3, buckets=POOL_BUCKETS,
+                             cache_dtype=jnp.bfloat16)
+    scfg = ServeConfig(model=cfg, cache=POOL, max_batch=3,
+                       buckets=POOL_BUCKETS, cache_dtype=jnp.bfloat16)
+    return dict(step=step, scfg=scfg, layers=layers, heads=heads,
+                head_dim=head_dim, weights=jax.tree.leaves(weights))
+
+
+def test_buffers_are_one_leaf_an_attention_layer(family):
+    """``DecodeStep.buffers``: K and V each a ``Pages`` of one buffer an
+    attention layer, a token's key/value heads side by side in the minor
+    dimension, in the cache's type; a recurrent family's slot state comes
+    after them."""
+    step, layers = family["step"], family["layers"]
+    k_pages, v_pages, *state = step.buffers
+    assert len(state) == int(step.recurrent)
+    want = (POOL.num_blocks, POOL.block_size,
+            family["heads"] * family["head_dim"])
+    for pages in k_pages, v_pages:
+        assert isinstance(pages, Pages) and len(pages) == layers
+        assert [p.shape for p in pages] == [want] * layers
+        assert {p.dtype for p in pages} == {jnp.dtype(jnp.bfloat16)}
+    # what init_buffers gives is what the programs take: zeros, finite
+    held = init_buffers(step)
+    assert jax.tree.structure(held) == jax.tree.structure(step.buffers)
+    assert not any(np.asarray(x, np.float32).any()
+                   for x in jax.tree.leaves(held[:2]))
+
+
+@pytest.mark.parametrize("program", ["decode", *POOL_BUCKETS])
+def test_programs_update_every_page_leaf_in_place(family, program):
+    """Read from the compiled program's text: every page leaf that goes in
+    is aliased to the page leaf that comes out (the donation is honoured:
+    no second copy of a layer's pages), and no instruction gives a value as
+    large as the K or V pool (a stacked ``[layers, ...]`` buffer threaded
+    through the layers was one, and the chip's compiler copied it twice a
+    layer: PERF.md section 6, PR 42)."""
+    from tools.aot_serve_step import count_page_copies
+
+    step, layers = family["step"], family["layers"]
+    compiled = step.decode if program == "decode" else step.prefill[program]
+    text = compiled.as_text()
+    aliased = {int(param): int(out) for out, param in re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)",
+        text.split("entry_computation_layout")[0])}
+    first_in = len(family["weights"])          # the weights come first
+    first_out = 1 + int(step.picks)            # the logits, the picks
+    for leaf in range(2 * layers):
+        assert aliased.get(first_in + leaf) == first_out + leaf, (
+            leaf, aliased)
+    layer = math.prod(step.buffers[0][0].shape)
+    # the fixture's pool is larger than any weight: a value of its size
+    # could only be the pool
+    assert layers * layer > max(w.size for w in family["weights"])
+    found = count_page_copies(text, layer, layers * layer)
+    assert found["pool_sized"] == 0, found
+    assert found["remat_compressed"] == found["remat_uncompressed"] == 0
+
+
+def test_the_engines_pages_answer_delete_and_flatten_to_their_leaves(family):
+    """What the benchmark's runners do with them after a window."""
+    eng = ContinuousEngine(None, family["scfg"], step=family["step"])
+    leaves = jax.tree.leaves((eng.k_pages, eng.v_pages))
+    assert len(leaves) == 2 * family["layers"]
+    assert all(isinstance(x, jax.Array) for x in leaves)
+    eng.k_pages.delete()
+    eng.v_pages.delete()
+    assert all(x.is_deleted() for x in leaves)
+
+
+def test_a_handoff_round_trips_the_same_rows(family):
+    """Blocks leave a replica as ``[L, n, block, H, D]`` whatever the
+    layout of a layer's buffer (``serve/disagg.py``) and arrive at another
+    replica's block ids bit for bit, where that replica's programs read
+    them; no other block is touched."""
+    step, layers = family["step"], family["layers"]
+    heads, head_dim = family["heads"], family["head_dim"]
+    rng = np.random.default_rng(0)
+    pages = Pages(jnp.asarray(rng.standard_normal(s.shape), s.dtype)
+                  for s in step.buffers[0])
+    ids, home = jnp.asarray([7, 3, 200]), jnp.asarray([1, 2, 5])
+    rows = pages.blocks(ids, heads)
+    assert rows.shape == (layers, 3, POOL.block_size, heads, head_dim)
+    assert rows.dtype == jnp.bfloat16
+    # off a host wire: numpy, as pack_views stages it
+    wire = np.asarray(rows.astype(jnp.float32))
+    there = init_buffers(step)[0].with_blocks(home, wire)
+    assert isinstance(there, Pages)
+    back = there.blocks(home, heads)
+    np.testing.assert_array_equal(np.asarray(back.astype(jnp.float32)), wire)
+    for layer in range(layers):
+        # what a decode step's gather reads at those blocks
+        ctx = _gather(there[layer], home[None], heads)[0]
+        np.testing.assert_array_equal(
+            np.asarray(ctx.astype(jnp.float32)).reshape(wire[layer].shape),
+            wire[layer])
+        rest = np.delete(np.asarray(there[layer].astype(jnp.float32)),
+                         np.asarray(home), axis=0)
+        assert not rest.any()
 
 
 # -- engines vs reference ---------------------------------------------------

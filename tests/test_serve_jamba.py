@@ -234,10 +234,8 @@ def test_the_engine_holds_two_kinds_of_state(served):
     eng = engine(served)
     step = steps[CACHE]
     assert step.recurrent and eng.recurrent
-    k_pages, v_pages, state = step.buffers
-    # a buffer an attention layer, at the one key/value head
-    assert [p.shape for p in k_pages] == [(65, 4, 1, 16)] * 2
-    assert [p.shape for p in v_pages] == [(65, 4, 1, 16)] * 2
+    # the pages are tests/test_serve.py's, either family; beside them:
+    *_, state = step.buffers
     assert [s.shape for s in state["ssm"]] == [
         (1, 3, 16, 128), (2, 3, 16, 128), (1, 3, 16, 128)]
     held = sum(x.nbytes for x in jax.tree.leaves(eng.state))

@@ -320,7 +320,7 @@ def _trace_targets(steps) -> tuple[list[Finding], dict]:
         from tpu_sandbox.models.transformer import TransformerConfig
         from tpu_sandbox.models.transformer import TransformerLM
         from tpu_sandbox.serve.cache import CacheConfig
-        from tpu_sandbox.serve.decode import make_decode_fn, page_shapes
+        from tpu_sandbox.serve.decode import buffer_shapes, make_decode_fn
 
         cfg_d = TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
                                   n_layers=2, d_ff=64, max_len=64)
@@ -329,8 +329,8 @@ def _trace_targets(steps) -> tuple[list[Finding], dict]:
         dparams = jax.eval_shape(
             lambda: TransformerLM(cfg_d).init(
                 jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"])
-        kd, vd = page_shapes(cfg_d, ccfg, jnp.float32)
-        trace("decode", make_decode_fn(cfg_d, ccfg, 2),
+        kd, vd = buffer_shapes(cfg_d, ccfg, 2, jnp.float32)
+        trace("decode", make_decode_fn(cfg_d, ccfg),
               dparams, kd, vd,
               jax.ShapeDtypeStruct((2, 1), jnp.int32),
               jax.ShapeDtypeStruct((2,), jnp.int32),
@@ -339,7 +339,7 @@ def _trace_targets(steps) -> tuple[list[Finding], dict]:
         from tpu_sandbox.models.transformer import TransformerConfig
         from tpu_sandbox.models.transformer import TransformerLM
         from tpu_sandbox.serve.cache import CacheConfig
-        from tpu_sandbox.serve.decode import make_prefill_fn, page_shapes
+        from tpu_sandbox.serve.decode import buffer_shapes, make_prefill_fn
 
         cfg_p = TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
                                   n_layers=2, d_ff=64, max_len=64)
@@ -348,13 +348,13 @@ def _trace_targets(steps) -> tuple[list[Finding], dict]:
         pparams = jax.eval_shape(
             lambda: TransformerLM(cfg_p).init(
                 jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"])
-        kp, vp = page_shapes(cfg_p, pcfg, jnp.float32)
+        kp, vp = buffer_shapes(cfg_p, pcfg, 1, jnp.float32)
         # one trace per bucket length: each bucket is its own static-shape
         # program in the serve AOT set, and padding scatters through the
         # null block have their own upcast/host-transfer surface
         for bucket in (8, 16):
             trace("prefill" if bucket == 8 else f"prefill-b{bucket}",
-                  make_prefill_fn(cfg_p, pcfg),
+                  make_prefill_fn(cfg_p),
                   pparams, kp, vp,
                   jax.ShapeDtypeStruct((1, bucket), jnp.int32),
                   jax.ShapeDtypeStruct((bucket,), jnp.int32),

@@ -7,11 +7,12 @@ Layers (bottom up):
   (declined for a model with recurrent layers, whose fixed-size state a
   decode slot lives beside the pages).
 - ``decode``  — AOT-compiled static-shape prefill (bucketed lengths) and
-  single-token decode step, a model family at a time
-  (``models/transformer.py``: pages for every layer; ``models/jamba.py``:
-  pages for the attention layers at the key/value heads and the Mamba
-  layers' slot state), both donating the device buffers; replay-exact
-  seeded sampling (``sample_token``).
+  single-token decode step, a model family at a time over one page store
+  (``Pages``: a buffer an attention layer at the key/value heads —
+  ``models/transformer.py``: every layer; ``models/jamba.py``: the
+  attention layers, and the Mamba layers' slot state beside them), both
+  donating the device buffers; replay-exact seeded sampling
+  (``sample_token``).
 - ``engine``  — continuous-batching engine: admits/evicts sequences at
   decode-step granularity, preempts-to-requeue under block pressure, plus a
   static-batch baseline as its control. SLO guardrails live here:

@@ -4,13 +4,17 @@ The device side holds two kinds of state, owned and donated through the
 compiled prefill/decode steps (``serve/decode.py`` has the shapes a model
 family at a time):
 
-- **pages** for the attention layers —
+- **pages** for the attention layers, **a buffer a layer** whatever the
+  model family (``decode.Pages``) —
 
-      k_pages, v_pages : [num_blocks, block_size, n_kv_heads, head_dim]
+      k_pages[layer], v_pages[layer] : [num_blocks, block_size,
+                                        n_kv_heads * head_dim]
 
-  a layer (``TransformerLM``: all layers in one buffer, every query head a
-  key/value head of its own; ``JambaLM``: a buffer a layer, one key/value
-  head for twenty query heads) — which this module's blocks index;
+  a token's key/value heads side by side (``TransformerLM``: every layer,
+  every query head a key/value head of its own; ``JambaLM``: the attention
+  layers only, one key/value head for twenty query heads) — which this
+  module's blocks index: block ``b``'s row ``r`` is flat slot
+  ``b * block_size + r`` of every layer's buffer;
 - **slot state** for recurrent layers (``JambaLM``'s Mamba layers: the
   scan's state and the convolution's last inputs), one fixed-size entry a
   decode slot. It needs no allocator: the entry is the engine's slot

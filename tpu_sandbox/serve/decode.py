@@ -7,28 +7,34 @@ the model's ``kv_cache`` sow collection, decode injects an ``attention_fn``
 that reads/writes the paged cache — so serving exercises exactly the
 weights and math the training stack produced.
 
-Two families, two sets of device state (``DecodeStep.buffers``, donated
-through both programs):
+One page store for every family (``DecodeStep.buffers``, donated through both
+programs leaf by leaf): ``k_pages`` and ``v_pages`` are each a ``Pages`` of
+**one buffer an attention layer**, ``[num_blocks, block_size, n_kv_heads *
+head_dim]`` at the key/value heads (``TransformerLM``: every layer, every
+query head a key/value head of its own; ``JambaLM``: the two attention
+layers, one key/value head for twenty query heads). A layer's new rows go
+in through ``_store`` (a scatter into that layer's donated leaf, in place)
+and its context comes out through ``_gather`` (whole blocks by the block
+table), so no value of the whole pool's size exists inside a program and
+one layer's write cannot copy another layer's pages. (Until PR 42
+``TransformerLM`` held one stacked ``[n_layers, ...]`` buffer; its 24
+functional updates a step had the compiler re-lay 3.2 GB out and back 96
+times, 1.57 of the step's 1.70 s on the chip.)
 
-- ``TransformerLM``: ``k_pages``, ``v_pages`` ``[n_layers, num_blocks,
-  block_size, n_heads, head_dim]``, one buffer each for all layers.
-- ``JambaLM`` (``recurrent``): pages for the attention layers only, **a
-  buffer a layer** (``[num_blocks, block_size, n_kv_heads, head_dim]``: the
-  key/value heads, not the query heads — one for twenty in Jamba2-3B), so
-  that one layer's write cannot copy another layer's pages; and beside them
-  the Mamba layers' **slot state** (``models/jamba.py::state_shapes``: the
-  scan's state and the convolution's last inputs of every decode slot, a
-  run of layers stacked as the weights are). Prefill runs a prompt from an
-  empty state and stores the state behind its **last real token** into the
-  slot it is given — bucket padding has ``dt = 0`` and does not move it, the
-  convolution's tail is read at ``last_pos`` — which is also the slot's
-  reset at admission. Decode moves the state of the rows with ``lengths >
-  0`` and leaves the others' bit for bit (empty slots and the rows of
-  another weight version ride every call). Behind the logits both programs
-  give every row's greedy pick (``_greedy_pick``; ``DecodeStep.picks``):
-  the engine takes it for a greedy request and brings the logits to the
-  host only for one that samples; and it can dispatch the next decode call
-  on those picks before it has read them (``DecodeStep.next_tokens``).
+A model with recurrent layers (``JambaLM``, ``recurrent``) holds beside the
+pages the Mamba layers' **slot state** (``models/jamba.py::state_shapes``:
+the scan's state and the convolution's last inputs of every decode slot, a
+run of layers stacked as the weights are). Prefill runs a prompt from an
+empty state and stores the state behind its **last real token** into the
+slot it is given — bucket padding has ``dt = 0`` and does not move it, the
+convolution's tail is read at ``last_pos`` — which is also the slot's
+reset at admission. Decode moves the state of the rows with ``lengths >
+0`` and leaves the others' bit for bit (empty slots and the rows of
+another weight version ride every call). Behind the logits its programs
+give every row's greedy pick (``_greedy_pick``; ``DecodeStep.picks``):
+the engine takes it for a greedy request and brings the logits to the
+host only for one that samples; and it can dispatch the next decode call
+on those picks before it has read them (``DecodeStep.next_tokens``).
 
 Bitwise discipline of the ``TransformerLM`` steps (the parity tests in
 tests/test_serve.py hold these):
@@ -112,8 +118,9 @@ class DecodeStep:
     prefill: dict[int, Callable]
     # compiled decode(params, *buffers, tokens, lengths, block_tables)
     decode: Callable
-    # shapes of the device state both programs take and give back, donated:
-    # (k_pages, v_pages) and, where ``recurrent``, the slot state
+    # shapes of the device state both programs take and give back, donated
+    # (``buffer_shapes``): ``(k_pages, v_pages)``, each a ``Pages`` of one
+    # buffer an attention layer, and, where ``recurrent``, the slot state
     buffers: tuple = ()
     recurrent: bool = False
     # both programs give, right behind the logits, every row's greedy pick
@@ -136,36 +143,119 @@ class DecodeStep:
             f"{self.buckets[-1]}")
 
 
-def page_shapes(model_cfg: TransformerConfig, cache_cfg: CacheConfig,
-                cache_dtype: Any) -> tuple[jax.ShapeDtypeStruct, ...]:
-    head_dim = model_cfg.d_model // model_cfg.n_heads
-    shape = (model_cfg.n_layers, cache_cfg.num_blocks, cache_cfg.block_size,
-             model_cfg.n_heads, head_dim)
-    s = jax.ShapeDtypeStruct(shape, cache_dtype)
-    return s, s
+@jax.tree_util.register_pytree_node_class
+class Pages(tuple):
+    """The K or the V pages of a model: one buffer an attention layer
+    (``page_shapes``). A tuple of them that is a pytree of its own, so that
+    a program takes and gives it back whole and donates it leaf by leaf,
+    and that answers ``delete()`` for whoever holds the pool."""
+
+    def tree_flatten(self):
+        return tuple(self), None
+
+    @classmethod
+    def tree_unflatten(cls, _, layers):
+        return cls(layers)
+
+    def delete(self) -> None:
+        for layer in self:
+            layer.delete()
+
+    def blocks(self, ids: jnp.ndarray, n_kv_heads: int) -> jnp.ndarray:
+        """The blocks ``ids [n]`` of every layer as one array ``[L, n,
+        block, H, D]``: the form pages leave a replica in
+        (``serve/disagg.py``)."""
+        rows = jnp.stack([layer[ids] for layer in self])
+        return rows.reshape(*rows.shape[:3], n_kv_heads, -1)
+
+    def with_blocks(self, ids: jnp.ndarray, rows) -> Pages:
+        """These pages with ``rows [L, n, block, H, D]`` (``blocks``' form)
+        at the blocks ``ids [n]`` of every layer."""
+        return Pages(
+            layer.at[ids].set(jnp.asarray(r, layer.dtype).reshape(
+                len(ids), *layer.shape[1:]))
+            for layer, r in zip(self, rows, strict=True))
 
 
-def init_pages(model_cfg: TransformerConfig, cache_cfg: CacheConfig,
-               cache_dtype: Any = jnp.float32):
-    """Zeroed K and V page buffers (finite everywhere: padding scatters may
-    multiply stale page content by zero weights, which must stay exact)."""
-    ks, vs = page_shapes(model_cfg, cache_cfg, cache_dtype)
-    return jnp.zeros(ks.shape, ks.dtype), jnp.zeros(vs.shape, vs.dtype)
+def page_shapes(cache_cfg: CacheConfig, n_layers: int, n_kv_heads: int,
+                head_dim: int, cache_dtype: Any) -> tuple[Pages, Pages]:
+    """``(k_pages, v_pages)``: a buffer ``[num_blocks, block_size,
+    n_kv_heads * head_dim]`` each of ``n_layers`` attention layers. A
+    token's heads lie side by side in the minor dimension: with 64 there
+    (``[..., 16, 64]``) the chip's compiler keeps a second layout of every
+    buffer, blocks minor, and copies each out and back every step (96
+    copies of 134 MB at gpt2-medium, ``tools/aot_serve_step.py``)."""
+    page = jax.ShapeDtypeStruct(
+        (cache_cfg.num_blocks, cache_cfg.block_size, n_kv_heads * head_dim),
+        cache_dtype)
+    pages = Pages(page for _ in range(n_layers))
+    return pages, pages
 
 
-def _flat(pages: jnp.ndarray) -> jnp.ndarray:
-    L, nb, bs, H, D = pages.shape
-    return pages.reshape(L, nb * bs, H, D)
+def buffer_shapes(model_cfg: TransformerConfig | JambaConfig,
+                  cache_cfg: CacheConfig, max_batch: int,
+                  cache_dtype: Any) -> tuple:
+    """The device state a family's programs hold (``DecodeStep.buffers``):
+    ``(k_pages, v_pages)`` and, for a model with recurrent layers, the
+    Mamba layers' state a decode slot."""
+    if isinstance(model_cfg, TransformerConfig):
+        return page_shapes(
+            cache_cfg, model_cfg.n_layers, model_cfg.n_heads,
+            model_cfg.d_model // model_cfg.n_heads, cache_dtype)
+    from tpu_sandbox.models.jamba import state_shapes
+
+    return (*page_shapes(
+        cache_cfg, model_cfg.layer_kinds.count("attn"),
+        model_cfg.num_key_value_heads, model_cfg.head_dim, cache_dtype),
+        state_shapes(model_cfg, max_batch))
 
 
-def make_prefill_fn(model_cfg: TransformerConfig, cache_cfg: CacheConfig,
-                    cache_dtype: Any = jnp.float32):
+def init_buffers(step: DecodeStep) -> tuple:
+    """Zeroed device state of ``step``'s shapes (finite everywhere: padding
+    scatters may multiply stale page content by zero weights, which must
+    stay exact)."""
+    return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), step.buffers)
+
+
+def _store(pages: jnp.ndarray, dest: jnp.ndarray, rows: jnp.ndarray):
+    """One layer's pages ``[blocks, block, H * D]`` with ``rows [n, H, D]``
+    written at the flat slots ``dest [n]``."""
+    nb, bs, width = pages.shape
+    return pages.reshape(nb * bs, width).at[dest].set(
+        rows.reshape(-1, width).astype(pages.dtype)).reshape(pages.shape)
+
+
+def _gather(pages: jnp.ndarray, block_tables: jnp.ndarray, n_kv_heads: int):
+    """One layer's pages ``[blocks, block, H * D]`` -> every row's context
+    ``[B, max_blocks * block, H, D]`` in sequence order: whole blocks by
+    ``block_tables [B, max_blocks]``."""
+    return pages[block_tables].reshape(
+        block_tables.shape[0], -1, n_kv_heads, pages.shape[-1] // n_kv_heads)
+
+
+def _decode_slots(cache_cfg: CacheConfig, lengths: jnp.ndarray,
+                  block_tables: jnp.ndarray):
+    """Of a decode call's rows: the position of the token being fed
+    (``lengths`` counts it), the flat page slot its key and value go to
+    (the null block's for ``lengths == 0``), and which of the gathered
+    ``max_context`` positions the row attends to."""
+    bs = cache_cfg.block_size
+    pos = jnp.maximum(lengths - 1, 0)                          # [B]
+    dest = (jnp.take_along_axis(
+        block_tables, (pos // bs)[:, None], axis=1)[:, 0] * bs
+        + pos % bs)                                            # [B]
+    kv_mask = (jnp.arange(cache_cfg.max_context)[None, :]
+               < lengths[:, None])                             # [B, max_ctx]
+    return pos, dest, kv_mask
+
+
+def make_prefill_fn(model_cfg: TransformerConfig):
     """prefill(params, k_pages, v_pages, tokens[1, Lb], dest_idx[Lb],
     last_pos[]) -> (next_logits[vocab], k_pages, v_pages).
 
     ``dest_idx`` maps each bucket position to its flat page slot — null
     block (slot 0) for bucket padding and shared-prefix positions, so the
-    scatter never rewrites shared content. Page buffers are donated.
+    scatter never rewrites shared content. The pages are donated.
     """
     model = TransformerLM(model_cfg)
 
@@ -176,22 +266,20 @@ def make_prefill_fn(model_cfg: TransformerConfig, cache_cfg: CacheConfig,
     def serve_prefill(params, k_pages, v_pages, tokens, dest_idx, last_pos):
         logits, taps = model.apply(
             {"params": params}, tokens, mutable=["kv_cache"])
-        fk, fv = _flat(k_pages), _flat(v_pages)
+        k_pages, v_pages = list(k_pages), list(v_pages)
         with jax.named_scope("write_kv"):
             for i in range(model_cfg.n_layers):
                 k, v = taps["kv_cache"][f"block{i}"]["attn"]["kv"]
-                fk = fk.at[i, dest_idx].set(k[0].astype(cache_dtype))
-                fv = fv.at[i, dest_idx].set(v[0].astype(cache_dtype))
+                k_pages[i] = _store(k_pages[i], dest_idx, k[0])
+                v_pages[i] = _store(v_pages[i], dest_idx, v[0])
         next_logits = jax.lax.dynamic_index_in_dim(
             logits[0], last_pos, axis=0, keepdims=False)
-        return (next_logits,
-                fk.reshape(k_pages.shape), fv.reshape(v_pages.shape))
+        return next_logits, Pages(k_pages), Pages(v_pages)
 
     return jax.jit(serve_prefill, donate_argnums=(1, 2))
 
 
-def make_decode_fn(model_cfg: TransformerConfig, cache_cfg: CacheConfig,
-                   max_batch: int, cache_dtype: Any = jnp.float32):
+def make_decode_fn(model_cfg: TransformerConfig, cache_cfg: CacheConfig):
     """decode(params, k_pages, v_pages, tokens[B, 1], lengths[B],
     block_tables[B, max_blocks]) -> (logits[B, vocab], k_pages, v_pages).
 
@@ -201,38 +289,28 @@ def make_decode_fn(model_cfg: TransformerConfig, cache_cfg: CacheConfig,
     ``lengths == 0``: their writes land in the null block and their
     attention weights collapse to zeros.
     """
-    bs = cache_cfg.block_size
     head_dim = model_cfg.d_model // model_cfg.n_heads
-    max_ctx = cache_cfg.max_context
     scale = jnp.sqrt(jnp.asarray(head_dim, jnp.float32))
 
     # ``jit_serve_decode`` in a device trace; inside each ``block{i}/attn``
     # the page write and the context gather carry scopes of their own
     # (``write_kv``, ``gather_ctx``), the rest is the attention math
     def serve_decode(params, k_pages, v_pages, tokens, lengths, block_tables):
-        fk, fv = _flat(k_pages), _flat(v_pages)
-        pos = jnp.maximum(lengths - 1, 0)                      # [B]
-        dest = (jnp.take_along_axis(
-            block_tables, (pos // bs)[:, None], axis=1)[:, 0] * bs
-            + pos % bs)                                        # [B]
-        # flat slot of every block-table position, in sequence order
-        ctx_idx = (block_tables[:, :, None] * bs
-                   + jnp.arange(bs)[None, None, :]).reshape(
-                       tokens.shape[0], max_ctx)               # [B, max_ctx]
-        kv_mask = (jnp.arange(max_ctx)[None, :] < lengths[:, None])
-
+        k_pages, v_pages = list(k_pages), list(v_pages)
+        pos, dest, kv_mask = _decode_slots(cache_cfg, lengths, block_tables)
         layer = itertools.count()
 
         def attention_fn(q, k, v):
             # q/k/v: [B, 1, H, D] — the new token at position lengths-1
-            nonlocal fk, fv
             i = next(layer)
             with jax.named_scope("write_kv"):
-                fk = fk.at[i, dest].set(k[:, 0].astype(cache_dtype))
-                fv = fv.at[i, dest].set(v[:, 0].astype(cache_dtype))
+                k_pages[i] = _store(k_pages[i], dest, k[:, 0])
+                v_pages[i] = _store(v_pages[i], dest, v[:, 0])
             with jax.named_scope("gather_ctx"):
-                kc = fk[i][ctx_idx].astype(q.dtype)            # [B, ctx, H, D]
-                vc = fv[i][ctx_idx].astype(v.dtype)
+                kc = _gather(k_pages[i], block_tables,
+                             model_cfg.n_heads).astype(q.dtype)
+                vc = _gather(v_pages[i], block_tables,
+                             model_cfg.n_heads).astype(v.dtype)
             # query padded to q=2: XLA's q=1 matvec kernel accumulates the
             # output contraction in a different order than the full
             # forward's gemm (~1 ulp); at q>=2 the gemm kernel matches
@@ -248,37 +326,9 @@ def make_decode_fn(model_cfg: TransformerConfig, cache_cfg: CacheConfig,
 
         model = TransformerLM(model_cfg, attention_fn=attention_fn)
         logits = model.apply({"params": params}, tokens, pos[:, None])
-        return (logits[:, 0],
-                fk.reshape(k_pages.shape), fv.reshape(v_pages.shape))
+        return logits[:, 0], Pages(k_pages), Pages(v_pages)
 
     return jax.jit(serve_decode, donate_argnums=(1, 2))
-
-
-def init_buffers(step: DecodeStep) -> tuple:
-    """Zeroed device state of ``step``'s shapes (finite everywhere, as
-    ``init_pages``)."""
-    return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), step.buffers)
-
-
-def jamba_buffer_shapes(model_cfg: JambaConfig, cache_cfg: CacheConfig,
-                        max_batch: int, cache_dtype: Any) -> tuple:
-    """``(k_pages, v_pages, state)``: a page buffer an attention layer, at
-    the key/value heads; the Mamba layers' state a decode slot."""
-    from tpu_sandbox.models.jamba import state_shapes
-
-    page = jax.ShapeDtypeStruct(
-        (cache_cfg.num_blocks, cache_cfg.block_size,
-         model_cfg.num_key_value_heads, model_cfg.head_dim), cache_dtype)
-    pages = tuple(page for kind in model_cfg.layer_kinds if kind == "attn")
-    return pages, pages, state_shapes(model_cfg, max_batch)
-
-
-def _store(pages: jnp.ndarray, dest: jnp.ndarray, rows: jnp.ndarray):
-    """One layer's pages ``[blocks, block, H, D]`` with ``rows [n, H, D]``
-    written at the flat slots ``dest [n]``."""
-    nb, bs, H, D = pages.shape
-    return pages.reshape(nb * bs, H, D).at[dest].set(
-        rows.astype(pages.dtype)).reshape(pages.shape)
 
 
 def _greedy_pick(logits: jnp.ndarray):
@@ -338,8 +388,8 @@ def make_jamba_prefill_fn(model_cfg: JambaConfig):
                     jax.lax.dynamic_update_slice_in_dim(old, new, slot, 1)
                     for old, new in zip(state["ssm"], last["ssm"]))}
         logits = logits[0, 0]
-        return (logits, _greedy_pick(logits), tuple(k_pages),
-                tuple(v_pages), state)
+        return (logits, _greedy_pick(logits), Pages(k_pages),
+                Pages(v_pages), state)
 
     return jax.jit(serve_prefill, donate_argnums=(1, 2, 3))
 
@@ -347,25 +397,19 @@ def make_jamba_prefill_fn(model_cfg: JambaConfig):
 def make_jamba_decode_fn(model_cfg: JambaConfig, cache_cfg: CacheConfig):
     """decode(params, k_pages, v_pages, state, tokens[B, 1], lengths[B],
     block_tables[B, max_blocks]) -> (logits[B, vocab], every row's greedy
-    pick (``_greedy_pick``), k_pages, v_pages, state). ``lengths`` as in ``make_decode_fn``; a row with ``lengths ==
-    0`` writes its key and value to the null block and **keeps its state**
-    (``JambaLM``'s ``live``)."""
+    pick (``_greedy_pick``), k_pages, v_pages, state). ``lengths`` as in
+    ``make_decode_fn``; a row with ``lengths == 0`` writes its key and value
+    to the null block and **keeps its state** (``JambaLM``'s ``live``)."""
     from tpu_sandbox.models.jamba import JambaLM
 
-    bs = cache_cfg.block_size
     hq, hkv, hd = (model_cfg.num_attention_heads,
                    model_cfg.num_key_value_heads, model_cfg.head_dim)
-    max_ctx = cache_cfg.max_context
 
     def serve_decode(params, k_pages, v_pages, state, tokens, lengths,
                      block_tables):
         k_pages, v_pages = list(k_pages), list(v_pages)
         bsz = tokens.shape[0]
-        pos = jnp.maximum(lengths - 1, 0)                      # [B]
-        dest = (jnp.take_along_axis(
-            block_tables, (pos // bs)[:, None], axis=1)[:, 0] * bs
-            + pos % bs)                                        # [B]
-        kv_mask = (jnp.arange(max_ctx)[None, :] < lengths[:, None])
+        _, dest, kv_mask = _decode_slots(cache_cfg, lengths, block_tables)
         layer = itertools.count()
 
         def attention_fn(q, k, v):
@@ -375,11 +419,8 @@ def make_jamba_decode_fn(model_cfg: JambaConfig, cache_cfg: CacheConfig):
                 k_pages[i] = _store(k_pages[i], dest, k[:, 0])
                 v_pages[i] = _store(v_pages[i], dest, v[:, 0])
             with jax.named_scope("gather_ctx"):
-                # whole blocks by the table: [B, blocks, bs, Hkv, D]
-                kc = k_pages[i][block_tables].reshape(
-                    bsz, max_ctx, hkv, hd).astype(q.dtype)
-                vc = v_pages[i][block_tables].reshape(
-                    bsz, max_ctx, hkv, hd).astype(q.dtype)
+                kc = _gather(k_pages[i], block_tables, hkv).astype(q.dtype)
+                vc = _gather(v_pages[i], block_tables, hkv).astype(q.dtype)
             # a key/value head's group of query heads side by side
             qg = q[:, 0].reshape(bsz, hkv, hq // hkv, hd)
             scores = jnp.einsum("bhgd,bkhd->bhgk", qg, kc,
@@ -395,47 +436,55 @@ def make_jamba_decode_fn(model_cfg: JambaConfig, cache_cfg: CacheConfig):
         logits, state = model.apply({"params": params}, tokens, state,
                                     live=lengths > 0)
         logits = logits[:, 0]
-        return (logits, _greedy_pick(logits), tuple(k_pages),
-                tuple(v_pages), state)
+        return (logits, _greedy_pick(logits), Pages(k_pages),
+                Pages(v_pages), state)
 
     return jax.jit(serve_decode, donate_argnums=(1, 2, 3))
 
 
-def build_jamba_decode_step(model_cfg: JambaConfig, cache_cfg: CacheConfig,
-                            *, max_batch: int, buckets: tuple[int, ...],
-                            cache_dtype: Any) -> DecodeStep:
-    """``build_decode_step`` for ``JambaLM``: the same geometry, the slot
-    state beside the pages, a slot index into prefill."""
-    from tpu_sandbox.models.jamba import JambaConfig, JambaLM
+def lower_step(model_cfg: TransformerConfig | JambaConfig,
+               cache_cfg: CacheConfig, *, max_batch: int, cache_dtype: Any,
+               placed: Callable = lambda shapes: shapes):
+    """``(params, held, lower)`` of a family's programs at one geometry: the
+    shapes of the weights and of the device state (``buffer_shapes``), and
+    ``lower(bucket)`` — the prefill program of that bucket length, traced
+    and lowered; the decode program for ``None``. ``placed`` maps every
+    tree of shapes on its way in (``tools/aot_serve_step.py`` gives them a
+    described chip's sharding)."""
+    recurrent = not isinstance(model_cfg, TransformerConfig)
+    if recurrent:
+        from tpu_sandbox.models.jamba import JambaConfig, JambaLM
 
-    if not isinstance(model_cfg, JambaConfig):
-        raise TypeError(f"no serving family for {type(model_cfg).__name__}")
-    if model_cfg.vocab_size >= 2 ** 24:
-        raise ValueError("the greedy pick carries its token in a float32")
-    params_shape = jax.eval_shape(
-        lambda: JambaLM(model_cfg).init(
-            jax.random.key(0),
-            jnp.zeros((1, buckets[0]), jnp.int32))["params"])
-    held = jamba_buffer_shapes(model_cfg, cache_cfg, max_batch, cache_dtype)
-    scalar = jax.ShapeDtypeStruct((), jnp.int32)
-    prefill = {
-        b: make_jamba_prefill_fn(model_cfg).lower(
-            params_shape, *held, jax.ShapeDtypeStruct((1, b), jnp.int32),
-            jax.ShapeDtypeStruct((b,), jnp.int32), scalar, scalar).compile()
-        for b in buckets}
-    decode = make_jamba_decode_fn(model_cfg, cache_cfg).lower(
-        params_shape, *held,
-        jax.ShapeDtypeStruct((max_batch, 1), jnp.int32),
-        jax.ShapeDtypeStruct((max_batch,), jnp.int32),
-        jax.ShapeDtypeStruct(
-            (max_batch, cache_cfg.max_blocks_per_seq), jnp.int32),
-    ).compile()
-    return DecodeStep(
-        model_cfg=model_cfg, cache_cfg=cache_cfg, max_batch=max_batch,
-        buckets=buckets, cache_dtype=cache_dtype, prefill=prefill,
-        decode=decode, buffers=held, recurrent=True, picks=True,
-        next_tokens=jax.jit(_next_tokens).lower(jax.ShapeDtypeStruct(
-            (max_batch, 3), jnp.float32)).compile())
+        if not isinstance(model_cfg, JambaConfig):
+            raise TypeError(
+                f"no serving family for {type(model_cfg).__name__}")
+        model = JambaLM(model_cfg)
+        prefill_fn = make_jamba_prefill_fn(model_cfg)
+        decode_fn = make_jamba_decode_fn(model_cfg, cache_cfg)
+    else:
+        model = TransformerLM(model_cfg)
+        prefill_fn = make_prefill_fn(model_cfg)
+        decode_fn = make_decode_fn(model_cfg, cache_cfg)
+
+    def ints(*shape):
+        return placed(jax.ShapeDtypeStruct(shape, jnp.int32))
+
+    params = placed(jax.eval_shape(
+        lambda: model.init(jax.random.key(0),
+                           jnp.zeros((1, 8), jnp.int32))["params"]))
+    held = placed(buffer_shapes(model_cfg, cache_cfg, max_batch, cache_dtype))
+
+    def lower(bucket: int | None):
+        if bucket is None:
+            return decode_fn.lower(
+                params, *held, ints(max_batch, 1), ints(max_batch),
+                ints(max_batch, cache_cfg.max_blocks_per_seq))
+        # a recurrent family's prefill takes the slot whose state it resets
+        slot = (ints(),) if recurrent else ()
+        return prefill_fn.lower(params, *held, ints(1, bucket), ints(bucket),
+                                ints(), *slot)
+
+    return params, held, lower
 
 
 def build_decode_step(model_cfg: TransformerConfig | JambaConfig,
@@ -446,37 +495,20 @@ def build_decode_step(model_cfg: TransformerConfig | JambaConfig,
     buckets = tuple(sorted(b for b in buckets if b <= cache_cfg.max_context))
     if not buckets:
         raise ValueError("no prefill bucket fits max_context")
-    if not isinstance(model_cfg, TransformerConfig):
-        return build_jamba_decode_step(
-            model_cfg, cache_cfg, max_batch=max_batch, buckets=buckets,
-            cache_dtype=cache_dtype)
-    params_shape = jax.eval_shape(
-        lambda: TransformerLM(model_cfg).init(
-            jax.random.key(0),
-            jnp.zeros((1, buckets[0]), jnp.int32))["params"])
-    kd, vd = page_shapes(model_cfg, cache_cfg, cache_dtype)
-
-    prefill = {}
-    for b in buckets:
-        fn = make_prefill_fn(model_cfg, cache_cfg, cache_dtype)
-        prefill[b] = fn.lower(
-            params_shape, kd, vd,
-            jax.ShapeDtypeStruct((1, b), jnp.int32),
-            jax.ShapeDtypeStruct((b,), jnp.int32),
-            jax.ShapeDtypeStruct((), jnp.int32),
-        ).compile()
-
-    decode = make_decode_fn(
-        model_cfg, cache_cfg, max_batch, cache_dtype).lower(
-        params_shape, kd, vd,
-        jax.ShapeDtypeStruct((max_batch, 1), jnp.int32),
-        jax.ShapeDtypeStruct((max_batch,), jnp.int32),
-        jax.ShapeDtypeStruct(
-            (max_batch, cache_cfg.max_blocks_per_seq), jnp.int32),
-    ).compile()
-
+    _, held, lower = lower_step(model_cfg, cache_cfg, max_batch=max_batch,
+                                cache_dtype=cache_dtype)
+    # slot state beside the pages: that family's programs give every row's
+    # greedy pick, and the tokens of the next call from it
+    recurrent = len(held) > 2
+    next_tokens = None
+    if recurrent:
+        if model_cfg.vocab_size >= 2 ** 24:
+            raise ValueError("the greedy pick carries its token in a float32")
+        next_tokens = jax.jit(_next_tokens).lower(jax.ShapeDtypeStruct(
+            (max_batch, 3), jnp.float32)).compile()
     return DecodeStep(
         model_cfg=model_cfg, cache_cfg=cache_cfg, max_batch=max_batch,
         buckets=buckets, cache_dtype=cache_dtype,
-        prefill=prefill, decode=decode, buffers=(kd, vd),
-    )
+        prefill={b: lower(b).compile() for b in buckets},
+        decode=lower(None).compile(), buffers=held, recurrent=recurrent,
+        picks=recurrent, next_tokens=next_tokens)

@@ -32,7 +32,7 @@ from tpu_sandbox.serve.cache import CacheConfig, PagedKVCache
 from tpu_sandbox.serve.decode import (
     DecodeStep,
     build_decode_step,
-    init_pages,
+    init_buffers,
     sample_token,
 )
 
@@ -75,8 +75,7 @@ class _Replica:
         self.step = step if step is not None else build_decode_step(
             model_cfg, cache_cfg, max_batch=max_batch, buckets=buckets)
         self.cache = PagedKVCache(cache_cfg)
-        self.k_pages, self.v_pages = init_pages(
-            model_cfg, cache_cfg, self.step.cache_dtype)
+        self.k_pages, self.v_pages = init_buffers(self.step)
 
 
 class PrefillReplica(_Replica):
@@ -101,10 +100,11 @@ class PrefillReplica(_Replica):
         # decode side re-homes them at its own ids, contents untouched.
         # The gather happens ON DEVICE: only the request's rows are ever
         # staged (pack_views stages them for host wires; DeviceTransport
-        # ships the device buffers as-is), never the whole page pool.
+        # ships the device buffers as-is), never the whole page pool; the
+        # layers' rows travel as one array [L, n, block, H, D].
         ids = jnp.asarray(alloc.block_ids)
-        k_rows = self.k_pages[:, ids]
-        v_rows = self.v_pages[:, ids]
+        k_rows = self.k_pages.blocks(ids, self.model_cfg.n_heads)
+        v_rows = self.v_pages.blocks(ids, self.model_cfg.n_heads)
         self.transport.put(_edge(req.rid), 0, 0,
                            [k_rows, v_rows, next_logits])
         self.cache.free(alloc)
@@ -131,12 +131,10 @@ class DecodeReplica(_Replica):
             raise RuntimeError("decode cache out of blocks")
         if len(alloc.block_ids) > alloc.n_shared:
             idx = jnp.asarray(alloc.block_ids[alloc.n_shared:])
-            self.k_pages = self.k_pages.at[:, idx].set(
-                jnp.asarray(k_rows[:, alloc.n_shared:],
-                            self.step.cache_dtype))
-            self.v_pages = self.v_pages.at[:, idx].set(
-                jnp.asarray(v_rows[:, alloc.n_shared:],
-                            self.step.cache_dtype))
+            self.k_pages = self.k_pages.with_blocks(
+                idx, k_rows[:, alloc.n_shared:])
+            self.v_pages = self.v_pages.with_blocks(
+                idx, v_rows[:, alloc.n_shared:])
         alloc.length = plen
         self.cache.commit_prefix(alloc)
 

@@ -62,8 +62,8 @@ import numpy as np
 from tpu_sandbox.models.transformer import TransformerConfig
 from tpu_sandbox.obs import get_recorder, get_registry
 from tpu_sandbox.serve.cache import CacheConfig, PagedKVCache, SeqAlloc
-from tpu_sandbox.serve.decode import (DecodeStep, build_decode_step,
-                                      init_buffers, init_pages, sample_token)
+from tpu_sandbox.serve.decode import (DecodeStep, Pages, build_decode_step,
+                                      init_buffers, sample_token)
 
 if TYPE_CHECKING:
     from tpu_sandbox.models.jamba import JambaConfig
@@ -200,16 +200,14 @@ class _EngineBase:
         self.recurrent = bool(getattr(self.step_fns, "recurrent", False))
         self.picks = bool(getattr(self.step_fns, "picks", False))
         self.cache = PagedKVCache(config.cache, recurrent=self.recurrent)
-        self.state = None
-        if getattr(self.step_fns, "buffers", ()):
-            self.k_pages, self.v_pages, *state = init_buffers(self.step_fns)
-            if state:
-                self.state = state[0]
-                get_registry().gauge("serve.state_bytes").set(sum(
-                    x.nbytes for x in jax.tree.leaves(self.state)))
-        else:  # a stub step: the pages are carried and never read
-            self.k_pages, self.v_pages = init_pages(
-                config.model, config.cache, config.cache_dtype)
+        # a stub step (no ``buffers``) carries empty pages and never reads
+        # them
+        self.k_pages, self.v_pages, *state = init_buffers(self.step_fns) \
+            if getattr(self.step_fns, "buffers", ()) else (Pages(), Pages())
+        self.state = state[0] if state else None
+        if state:
+            get_registry().gauge("serve.state_bytes").set(sum(
+                x.nbytes for x in jax.tree.leaves(self.state)))
         self.clock = clock
         self.waiting: deque[Request] = deque()
         self.slots: list[_Slot | None] = [None] * config.max_batch
