@@ -1,6 +1,7 @@
 """What more than one test module uses and no fixture can carry."""
 
 import contextlib
+import re
 import subprocess
 import threading
 import time
@@ -35,6 +36,26 @@ def ulps_apart(got, want):
         return float(np.abs(a - b).max() / np.spacing(np.abs(b).max()))
 
     return max(jax.tree.leaves(jax.tree.map(leaf, got, want)))
+
+
+def counters(prefix: str, since: dict | None = None) -> dict:
+    """The registry's counters whose series key starts with ``prefix`` (a
+    name, with its labels behind it): what each reads, or, given an earlier
+    reading ``since``, what each has gained (those that gained nothing left
+    out)."""
+    from tpu_sandbox.obs import get_registry
+
+    now = {key: n for key, n in get_registry().snapshot()["counters"].items()
+           if key.startswith(prefix)}
+    if since is None:
+        return now
+    return {key: n - since.get(key, 0) for key, n in now.items()
+            if n != since.get(key, 0)}
+
+
+def label(series_key: str, name: str) -> str:
+    """The value of label ``name`` in a registry series key."""
+    return re.search(rf"{name}=([^,}}]+)", series_key).group(1)
 
 
 def assert_same_model(a, b):

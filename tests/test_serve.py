@@ -26,7 +26,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from tests.helpers import ulps_apart
+from tests.helpers import counters, label, ulps_apart
 from tpu_sandbox.models.transformer import TransformerConfig, TransformerLM
 from tpu_sandbox.serve import (
     CacheConfig,
@@ -36,8 +36,9 @@ from tpu_sandbox.serve import (
     ServeConfig,
     StaticEngine,
 )
+from tpu_sandbox.serve import decode as serve_decode
 from tpu_sandbox.serve.decode import (Pages, _gather, build_decode_step,
-                                      init_buffers)
+                                      init_buffers, lower_step)
 
 MCFG = TransformerConfig(vocab_size=64, d_model=32, n_heads=4, n_layers=2,
                          d_ff=64, max_len=128, dtype=jnp.float32)
@@ -441,6 +442,144 @@ def test_preemption_under_block_pressure_replays_identically(params, step,
     for r in reqs:
         assert eng.results[r.rid].tokens == greedy(r.prompt,
                                                    r.max_new_tokens), r.rid
+
+
+# -- the decode programs' attention: the kernel or the jnp form, by shape ----
+
+# a geometry whose page row is two 128-lane tiles and whose block is two
+# float32 sublane tiles (16 KB a page): the rule gives it the kernel
+KMCFG = TransformerConfig(vocab_size=64, d_model=256, n_heads=4, n_layers=2,
+                          d_ff=256, max_len=64, dtype=jnp.float32)
+KCCFG = CacheConfig(num_blocks=10, block_size=16, max_blocks_per_seq=4)
+
+
+CHOICE = "paged_attn.kernel_choice"
+
+
+@pytest.fixture(scope="module")
+def branches():
+    """``KMCFG``'s weights and its step set twice over ``KCCFG``: as the
+    rule builds it (the kernel, interpreted here; two pages a compute step,
+    so a row that fills its table takes two) and with a rule that declines
+    every shape (the ``jnp`` form), and what each build counted."""
+    params = TransformerLM(KMCFG).init(
+        jax.random.key(1), jnp.zeros((1, 8), jnp.int32))["params"]
+    built = {}
+    for branch, knob, value in (
+            ("pallas", "_STEP_TOKENS", 32),
+            ("jnp", "pages_per_step", lambda *shape: None)):
+        before = counters(CHOICE)
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(serve_decode, knob, value)
+            step = build_decode_step(KMCFG, KCCFG, max_batch=3,
+                                     buckets=(32,))
+        built[branch] = step, counters(CHOICE, since=before)
+    return params, built
+
+
+def test_each_branch_counts_its_choice_a_layer(branches):
+    """One count a traced site (a layer) through ``kernel_site``, static
+    labels: the form, the heads, the page geometry, the batch."""
+    for branch, (_, counted) in branches[1].items():
+        (key, n), = counted.items()
+        assert n == KMCFG.n_layers
+        assert label(key, "impl") == branch
+        assert label(key, "pages_per_step") == (
+            "2" if branch == "pallas" else "0")
+        assert (label(key, "kv_heads"), label(key, "group"),
+                label(key, "head_dim"), label(key, "block_size"),
+                label(key, "max_blocks"), label(key, "batch")) == (
+                    "4", "1", "64", "16", "4", "3")
+
+
+def test_the_kernel_branch_serves_what_the_jnp_branch_serves(branches):
+    """Through the engine, three rows that outgrow the pool together (nine
+    allocatable blocks for twelve): the kernel's greedy tokens are the
+    ``jnp`` form's, the chosen tokens' log-probabilities agree to float32
+    rounding, and the request that was preempted and replayed returns what
+    it returns alone in a fresh engine."""
+    params, built = branches
+    scfg = ServeConfig(model=KMCFG, cache=KCCFG, max_batch=3, buckets=(32,))
+    rng = np.random.default_rng(8)
+    prompts = {f"r{i}": [int(t) for t in rng.integers(1, 64, size=18 + i)]
+               for i in range(3)}
+
+    def serve(branch, rids):
+        eng = ContinuousEngine(params, scfg, step=built[branch][0])
+        logprobs, retire = {}, eng._retire
+
+        def keeping(i):
+            slot = eng.slots[i]
+            logprobs[slot.request.rid] = slot.logprob_sum
+            return retire(i)
+
+        eng._retire = keeping
+        for rid in rids:
+            eng.submit(Request(rid=rid, prompt=list(prompts[rid]),
+                               max_new_tokens=64 - len(prompts[rid])))
+        eng.run_until_idle()
+        return ({rid: eng.results[rid].tokens for rid in rids}, logprobs,
+                sum(r.preemptions for r in eng.results.values()))
+
+    tokens, logprobs, preempted = serve("pallas", list(prompts))
+    assert preempted >= 1, "no preemption; shrink the pool"
+    want, want_logprobs, _ = serve("jnp", list(prompts))
+    assert tokens == want
+    for rid in prompts:
+        assert logprobs[rid] == pytest.approx(want_logprobs[rid], abs=1e-3)
+        assert serve("pallas", [rid])[0][rid] == tokens[rid]
+
+
+@pytest.mark.parametrize("branch", ["pallas", "jnp"])
+def test_a_decode_program_keeps_the_scopes_the_benchmark_reads(branches,
+                                                               branch):
+    """``gather_ctx`` and ``write_kv`` inside every ``block{i}/attn`` of the
+    compiled decode program, on either branch: the benchmark's readers sum
+    the device time under both, and a program without one is read as
+    incorrect (``benchmark/lib/readers.py::_scope_sum``)."""
+    names = set(re.findall(r'op_name="([^"]+)"',
+                           branches[1][branch][0].decode.as_text()))
+    for i in range(KMCFG.n_layers):
+        for scope in ("gather_ctx", "write_kv"):
+            assert any(re.search(rf"/block{i}/attn/{scope}(/|$)", name)
+                       for name in names), (i, scope)
+
+
+@pytest.mark.parametrize("width, block, dtype, pages", [
+    (1024, 16, jnp.bfloat16, 16),    # gpt2-medium's pages: 32 KB each
+    (128, 16, jnp.bfloat16, 64),     # Jamba's 4 KB: 1024 positions a step
+    (256, 16, jnp.float32, 32),      # KCCFG's 16 KB (its rows have 4)
+    (2048, 32, jnp.bfloat16, 4),     # 128 KB a page
+    (32, 4, jnp.float32, None),      # MCFG's: no lane multiple
+    (1024, 4, jnp.bfloat16, None),   # a block under the type's sublane tile
+    (1024, 8, jnp.bfloat16, None),
+    (1024, 8, jnp.float32, 16),
+])
+def test_the_shape_rule_reads_the_pages_alone(width, block, dtype, pages):
+    """``pages_per_step``: from a row's width, a block's positions and the
+    cache's type, nothing else."""
+    assert serve_decode.pages_per_step(width, block, dtype, 64) == (
+        pages if pages is None else min(pages, 64))
+    assert serve_decode.pages_per_step(width, block, dtype, 4) == (
+        pages if pages is None else min(pages, 4))
+
+
+@pytest.mark.parametrize("d_model, heads, cache_dtype, impl", [
+    (256, 4, jnp.float32, "pallas"), (256, 4, jnp.bfloat16, "pallas"),
+    (256, 8, jnp.float32, "pallas"),     # heads of 32 side by side
+    (192, 3, jnp.float32, "jnp"),        # 192 lanes
+])
+def test_a_lowered_decode_program_takes_the_branch_its_pages_give(
+        d_model, heads, cache_dtype, impl):
+    """Read from ``paged_attn.kernel_choice`` after tracing alone (no
+    compile): the model's name, a flag or the environment play no part."""
+    cfg = TransformerConfig(vocab_size=64, d_model=d_model, n_heads=heads,
+                            n_layers=3, d_ff=64, max_len=64,
+                            dtype=jnp.float32)
+    before = counters(CHOICE)
+    lower_step(cfg, KCCFG, max_batch=2, cache_dtype=cache_dtype)[2](None)
+    (key, n), = counters(CHOICE, since=before).items()
+    assert (label(key, "impl"), n) == (impl, 3)
 
 
 # -- replica protocol (in-process) -----------------------------------------

@@ -20,10 +20,12 @@ sys.path.insert(0, str(ROOT))
 
 from benchmark.reference import jamba as ref  # noqa: E402
 from test_jamba_model import TINY, tiny_config  # noqa: E402
-from tpu_sandbox.models.jamba import JambaLM  # noqa: E402
+from tests.helpers import counters, label  # noqa: E402
+from tpu_sandbox.models.jamba import JambaConfig, JambaLM  # noqa: E402
 from tpu_sandbox.obs import get_registry  # noqa: E402
 from tpu_sandbox.serve import (CacheConfig, ContinuousEngine,  # noqa: E402
                                PagedKVCache, Request, ServeConfig)
+from tpu_sandbox.serve import decode as serve_decode  # noqa: E402
 from tpu_sandbox.serve.decode import build_decode_step  # noqa: E402
 
 BUCKETS = (8, 16, 32)
@@ -241,6 +243,83 @@ def test_the_engine_holds_two_kinds_of_state(served):
     held = sum(x.nbytes for x in jax.tree.leaves(eng.state))
     assert get_registry().gauge("serve.state_bytes").value == held
     assert held == 3 * 4 * (16 * 128 * 4 + 3 * 128 * 4)
+
+
+# --- the decode program's attention: the kernel or the jnp form, by shape ---
+
+# one key/value head of 128 for two query heads, blocks of 32 float32 rows:
+# the shape rule gives these pages the kernel; five allocatable blocks for
+# three rows of up to two
+WIDE = {**TINY, "hidden_size": 256, "num_attention_heads": 2}
+WIDE_POOL = CacheConfig(num_blocks=6, block_size=32, max_blocks_per_seq=2)
+
+
+@pytest.fixture(scope="module")
+def branches():
+    """``served``'s tuple for ``WIDE`` over ``WIDE_POOL`` twice: as the rule
+    builds it (the kernel, interpreted here, one page a compute step so a
+    full row takes two) and with a rule that declines every shape (the
+    ``jnp`` form), with the ``paged_attn.kernel_choice`` counts of each."""
+    cfg = JambaConfig.from_dict(WIDE, dtype=jnp.float32,
+                                param_dtype=jnp.float32, scan_chunk=4)
+    params = jax.jit(JambaLM(cfg).init)(
+        jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"]
+
+    built = {}
+    for branch, knob, value in (
+            ("pallas", "_STEP_TOKENS", 32),
+            ("jnp", "pages_per_step", lambda *shape: None)):
+        before = counters("paged_attn.kernel_choice")
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(serve_decode, knob, value)
+            step = build_decode_step(cfg, WIDE_POOL, max_batch=3,
+                                     buckets=BUCKETS)
+        counted = {label(key, "impl"): n for key, n in counters(
+            "paged_attn.kernel_choice", since=before).items()}
+        built[branch] = (cfg, params, {WIDE_POOL: step}, None), counted
+    return built
+
+
+def test_the_shape_rule_gives_wide_pages_the_kernel(branches):
+    """One count an attention layer, of the form the rule took."""
+    for branch, (_, counted) in branches.items():
+        assert counted == {branch: 2}
+
+
+def test_the_kernel_branch_serves_what_the_jnp_branch_serves(branches):
+    """Three rows that outgrow the pool together: the kernel's tokens are
+    the ``jnp`` form's, the logits every token was chosen from agree to
+    float32 rounding, and a request preempted and replayed returns what it
+    returns alone."""
+    prompts = {f"p{i}": prompt(20 + 3 * i, seed=5) for i in range(3)}
+    eng = engine(branches["pallas"][0], WIDE_POOL)
+    got = serve(eng, prompts, new=30)
+    assert sum(r.preemptions for r in eng.results.values()) >= 1
+    want = serve(engine(branches["jnp"][0], WIDE_POOL), prompts, new=30)
+    for rid, p in prompts.items():
+        assert got[rid][0] == want[rid][0]
+        np.testing.assert_allclose(got[rid][1][-30:], want[rid][1][-30:],
+                                   rtol=1e-4, atol=1e-4)
+        alone = serve(engine(branches["pallas"][0], WIDE_POOL), {rid: p},
+                      new=30)[rid]
+        assert got[rid][0] == alone[0]
+
+
+@pytest.mark.parametrize("branch", ["pallas", "jnp"])
+def test_a_decode_program_keeps_the_scopes_the_benchmark_reads(branches,
+                                                               branch):
+    """``gather_ctx`` and ``write_kv`` inside both attention layers of the
+    compiled decode program, on either branch (``benchmark/lib/readers.py::
+    _scope_sum`` reads a program without one as incorrect)."""
+    import re
+
+    cfg, _, steps, _ = branches[branch][0]
+    names = set(re.findall(r'op_name="([^"]+)"',
+                           steps[WIDE_POOL].decode.as_text()))
+    for i, kind in enumerate(cfg.layer_kinds):
+        for scope in ("gather_ctx", "write_kv") if kind == "attn" else ():
+            assert any(re.search(rf"/block{i}/attn/{scope}(/|$)", name)
+                       for name in names), (i, scope)
 
 
 def life(served, script, cache=CACHE, steps=60, **config):

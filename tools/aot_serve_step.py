@@ -2,13 +2,17 @@
 and every prefill bucket that ``serve.build_decode_step`` would build for a
 ``*_serve_decode_replay`` cell (either family), for one described v5e chip
 with the local libtpu, and print the compiler's memory count of each beside
-what the engine keeps live (weights, pages, slot state), and how many
+what the engine keeps live (weights, pages, slot state), how many
 instructions of the compiled program copy a layer's pages or more
-(``count_page_copies``). What decides, before any chip time is spent, whether
-the cell fits the chip's 15.75 GB, whether the page buffers are laid out
-unpadded (the arguments beside the live bytes), and whether a step moves the
-pool or only the rows it touches (``tools/aot_lm_step.py`` does the first for
-a training step).
+(``count_page_copies``), how many Pallas kernels it calls (``pallas_calls``:
+the decode program's paged attention, one a layer where the shape rule of
+``serve/decode.py::pages_per_step`` takes the pages, and which form each
+site took, ``paged_attn``), its temporaries and its serialized size. What
+decides, before any chip time is spent, whether the cell fits the chip's
+15.75 GB, whether the page buffers are laid out unpadded (the arguments
+beside the live bytes), whether a step moves the pool or only the rows it
+touches, and whether the kernel engaged (``tools/aot_lm_step.py`` does the
+first for a training step).
 
     python tools/aot_serve_step.py --workload gpt2m_serve_decode_replay \\
         [--programs decode 512] [--hlo DIR]
@@ -23,6 +27,7 @@ import argparse
 import json
 import math
 import os
+import re
 import sys
 import time
 
@@ -129,10 +134,25 @@ def main() -> None:
                        + live["slot_state_gb"])
     print(json.dumps({"workload": args.workload, **live}), flush=True)
 
+    def paged_attn_sites() -> dict:
+        """``paged_attn.kernel_choice`` so far, by form."""
+        from tpu_sandbox.obs import get_registry
+
+        sites: dict = {}
+        for key, n in get_registry().snapshot()["counters"].items():
+            if key.startswith("paged_attn.kernel_choice"):
+                impl = re.search(r"impl=(\w+)", key).group(1)
+                sites[impl] = sites.get(impl, 0) + n
+        return sites
+
     wanted = args.programs or ["decode", *map(str, scfg.buckets)]
     for name in wanted:
         t0 = time.perf_counter()
+        before = paged_attn_sites()
         compiled = lower(None if name == "decode" else int(name)).compile()
+        sites = {impl: n - before.get(impl, 0)
+                 for impl, n in paged_attn_sites().items()
+                 if n != before.get(impl, 0)}
         mem = compiled.memory_analysis()
         total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
                  + mem.output_size_in_bytes - mem.alias_size_in_bytes)
@@ -157,6 +177,10 @@ def main() -> None:
                 "total_gb": total / 1e9, "chip_gb": 15.75},
             "moves_of_pages": count_page_copies(
                 text, k_pages[0].size, k_pages[0].size * len(k_pages)),
+            # the Mosaic kernels the program calls, and the form every
+            # attention site of this program's trace took
+            "pallas_calls": text.count('custom_call_target="tpu_custom_call"'),
+            "paged_attn": sites,
             "program_text_mb": len(text) / 1e6,
             "executable_mb": executable_mb,
             "compile_s": round(time.perf_counter() - t0, 1)}), flush=True)

@@ -13,13 +13,29 @@ programs leaf by leaf): ``k_pages`` and ``v_pages`` are each a ``Pages`` of
 head_dim]`` at the key/value heads (``TransformerLM``: every layer, every
 query head a key/value head of its own; ``JambaLM``: the two attention
 layers, one key/value head for twenty query heads). A layer's new rows go
-in through ``_store`` (a scatter into that layer's donated leaf, in place)
-and its context comes out through ``_gather`` (whole blocks by the block
-table), so no value of the whole pool's size exists inside a program and
-one layer's write cannot copy another layer's pages. (Until PR 42
+in through ``_store`` (a scatter into that layer's donated leaf, in place),
+so no value of the whole pool's size exists inside a program and one
+layer's write cannot copy another layer's pages. (Until PR 42
 ``TransformerLM`` held one stacked ``[n_layers, ...]`` buffer; its 24
 functional updates a step had the compiler re-lay 3.2 GB out and back 96
 times, 1.57 of the step's 1.70 s on the chip.)
+
+A decode step's attention reads the context where it lies (``_attend``,
+PR 43): behind ``_store``, so that the new token's own key and value are
+read from the pages like every other, one Pallas kernel a layer
+(``ops/pallas_paged_attention.py``) takes the layer's K and V pages as they
+are, copies each row's live blocks through the block table into VMEM,
+several pages a compute step, and returns the new token's output: bytes
+follow the live tokens, a row with ``lengths == 0`` reads nothing and gives
+zeros, and what lies behind a length never reaches an output. **Which form
+a program takes follows from the pages' shape and type alone**
+(``pages_per_step``): pages the kernel cannot tile, or too small for a
+kernel of page copies to beat one XLA gather, take the ``jnp`` form
+(``_attend_jnp``: whole blocks by the table at ``max_context`` through
+``_gather``, two products, a masked softmax). Either way the read of the
+context stands under a ``gather_ctx`` scope inside ``block{i}/attn``, the
+write under ``write_kv``, and every site counts its form
+(``paged_attn.kernel_choice``).
 
 A model with recurrent layers (``JambaLM``, ``recurrent``) holds beside the
 pages the Mamba layers' **slot state** (``models/jamba.py::state_shapes``:
@@ -36,26 +52,29 @@ the engine takes it for a greedy request and brings the logits to the
 host only for one that samples; and it can dispatch the next decode call
 on those picks before it has read them (``DecodeStep.next_tokens``).
 
-Bitwise discipline of the ``TransformerLM`` steps (the parity tests in
-tests/test_serve.py hold these):
+What the steps hold to (tests/test_serve.py, tests/test_serve_jamba.py,
+tests/test_paged_attention.py):
 
-- Decode computes single-query attention with the query axis padded to 2:
-  at q=1 XLA:CPU switches to a matvec kernel whose output-contraction
-  accumulation order differs from the full forward's gemm by ~1 ulp; at
-  q>=2 the gemm kernel is used and row outputs are bitwise identical
-  regardless of row count.
-- The softmax *sum* reduce is grouping-stable only between equal (or
-  vector-aligned) k-axis lengths: reducing 17 real weights over a k=17
-  axis and over a zero-tailed k=32 axis rounds differently (~1 ulp) once
-  the length exceeds the unrolled-reduce threshold (16 on XLA:CPU). So
-  the bitwise reference for a decode step at context length n is the
-  one-shot forward evaluated at the cache's ``max_context`` padding —
-  the same k-axis length decode reduces over. While n <= 16 the
-  exact-length one-shot matches too, and power-of-two bucket lengths are
-  mutually bitwise (prefill at bucket 8 == forward at 32, etc.).
-- All other per-position ops (Dense, LayerNorm, embeds, the score
-  einsum's d-contraction, the length-masked max) are row-independent or
-  exactly associative and bitwise at any slice.
+- **Replay: same program, same bits.** A row's logits are a function of
+  its own tokens, block table and length: not of its slot, of the rows
+  beside it, or of which physical blocks the allocator gave. On the
+  ``jnp`` form every row reduces over the same ``max_context`` positions;
+  in the kernel the chunking follows the length alone. So a request
+  preempted and requeued, or replayed on a peer after a replica's death,
+  recomputes the logits it had and draws the same tokens, greedy or sampled
+  (``sample_token``).
+- **Against the one-shot forward: to rounding.** Decode and the full
+  forward are different compiled programs (another reduction order, an
+  online softmax in the kernel, scores accumulated in float32 where the
+  forward rounds them to the compute type), so their logits agree to a few
+  float32 ulps with a float32 cache
+  (``test_decode_matches_padded_forward_to_rounding_fp32``) and to the
+  cache's rounding with a bfloat16 one; nothing here is bitwise against
+  another program, on XLA:CPU or anywhere else.
+- **On the chip the contract is the benchmark's**:
+  ``benchmark/reference/gpt2.py::compare_served`` (and ``jamba.py``'s) hold
+  the served logits and tokens to limits of their own against a float32
+  reference, in every run.
 
 Static shapes everywhere: prefill is compiled once per bucket length,
 decode once per (max_batch, page geometry). The page buffers are donated
@@ -236,17 +255,104 @@ def _gather(pages: jnp.ndarray, block_tables: jnp.ndarray, n_kv_heads: int):
 def _decode_slots(cache_cfg: CacheConfig, lengths: jnp.ndarray,
                   block_tables: jnp.ndarray):
     """Of a decode call's rows: the position of the token being fed
-    (``lengths`` counts it), the flat page slot its key and value go to
-    (the null block's for ``lengths == 0``), and which of the gathered
-    ``max_context`` positions the row attends to."""
+    (``lengths`` counts it) and the flat page slot its key and value go to
+    (the null block's for ``lengths == 0``)."""
     bs = cache_cfg.block_size
     pos = jnp.maximum(lengths - 1, 0)                          # [B]
     dest = (jnp.take_along_axis(
         block_tables, (pos // bs)[:, None], axis=1)[:, 0] * bs
         + pos % bs)                                            # [B]
-    kv_mask = (jnp.arange(cache_cfg.max_context)[None, :]
-               < lengths[:, None])                             # [B, max_ctx]
-    return pos, dest, kv_mask
+    return pos, dest
+
+
+# what one compute step of the kernel copies of K (and as much of V), and
+# the positions it may hold: sixteen 32 KB pages at gpt2-medium, sixty-four
+# 4 KB pages at Jamba (PERF.md section 6, PR 43: the kernel alone on the
+# chip read 390 / 518 / 589 / 611 GB/s of live keys and values at 4 / 8 /
+# 16 / 32 pages a step of GPT-2's, and 113 / 132 / 142 at 16 / 32 / 64 of
+# Jamba's)
+_STEP_BYTES = 512 * 1024
+_STEP_TOKENS = 1024
+
+
+def pages_per_step(width: int, block_size: int, cache_dtype: Any,
+                   max_blocks: int) -> int | None:
+    """The shape rule of the decode programs' attention: how many pages a
+    compute step of ``ops/pallas_paged_attention.py`` copies at this page
+    geometry, or None where the ``jnp`` form (``_attend_jnp``) reads the
+    context. From the pages alone -- a row's width ``Hkv * D``, the block's
+    positions, the cache's type -- never from the model's name or a flag.
+    The kernel copies whole pages into VMEM tiles, so it takes a row that
+    is a multiple of the 128 lanes and a block that is a multiple of the
+    type's sublane tile (8 rows of 32 bits, 16 of bfloat16); the tiny
+    models of the tests are neither, and the CPU suite does not run them
+    through the interpreter. The smallest page that fits is 4 KB, Jamba's
+    (one head of 128, 16 positions, bfloat16), and there the kernel of
+    small copies still beat XLA's one gather a layer by 22 % of the whole
+    step (``jamba2_serve_decode_replay``, PERF.md section 6, PR 43), so the
+    rule has no floor on a page's bytes."""
+    itemsize = jnp.dtype(cache_dtype).itemsize
+    if width % 128 or block_size % (32 // itemsize):
+        return None
+    return max(1, min(_STEP_BYTES // (block_size * width * itemsize),
+                      _STEP_TOKENS // block_size, max_blocks))
+
+
+def _attend_jnp(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
+                block_tables: jnp.ndarray, lengths: jnp.ndarray,
+                n_kv_heads: int) -> jnp.ndarray:
+    """``_attend`` in plain ``jnp``: every row's context gathered whole at
+    ``max_context`` (``gather_ctx``), the products and the softmax over it,
+    the positions behind ``lengths`` masked."""
+    bsz, hq, hd = q.shape
+    with jax.named_scope("gather_ctx"):
+        kc = _gather(k_pages, block_tables, n_kv_heads).astype(q.dtype)
+        vc = _gather(v_pages, block_tables, n_kv_heads).astype(q.dtype)
+    # a key/value head's group of query heads side by side
+    qg = q.reshape(bsz, n_kv_heads, hq // n_kv_heads, hd)
+    scores = jnp.einsum("bhgd,bkhd->bhgk", qg, kc,
+                        preferred_element_type=jnp.float32)
+    scores = scores / jnp.sqrt(jnp.float32(hd))
+    kv_mask = jnp.arange(kc.shape[1])[None, :] < lengths[:, None]
+    scores = jnp.where(kv_mask[:, None, None, :], scores, -jnp.inf)
+    w = jnp.nan_to_num(jnp.exp(scores - scores.max(-1, keepdims=True)))
+    w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-30)
+    out = jnp.einsum("bhgk,bkhd->bhgd", w.astype(vc.dtype), vc)
+    return out.reshape(bsz, hq, hd)
+
+
+def _attend(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
+            block_tables: jnp.ndarray, lengths: jnp.ndarray,
+            n_kv_heads: int) -> jnp.ndarray:
+    """The new token's attention output ``[B, Hq, D]`` of ``q [B, Hq, D]``
+    over the first ``lengths[b]`` positions of one layer's pages, its own
+    among them (``_store`` ran): the paged-attention kernel where
+    ``pages_per_step`` takes the pages' shape, which reads them where they
+    lie, else ``_attend_jnp``. ``lengths == 0`` gives zeros. Either way the
+    read of the context through the table stands under ``gather_ctx`` -- the
+    kernel whole, the ``jnp`` form's gathers -- and the site counts which
+    it took (``paged_attn.kernel_choice``)."""
+    from tpu_sandbox.obs import get_registry
+    from tpu_sandbox.ops.pallas_common import kernel_site
+
+    _, block_size, width = k_pages.shape
+    pages = pages_per_step(width, block_size, k_pages.dtype,
+                           block_tables.shape[1])
+    kernel_site("paged_attn", get_registry().counter(
+        "paged_attn.kernel_choice", labels={
+            "impl": "jnp" if pages is None else "pallas",
+            "kv_heads": n_kv_heads, "group": q.shape[1] // n_kv_heads,
+            "head_dim": q.shape[2], "block_size": block_size,
+            "pages_per_step": pages or 0,
+            "max_blocks": block_tables.shape[1], "batch": q.shape[0]}))
+    if pages is None:
+        return _attend_jnp(q, k_pages, v_pages, block_tables, lengths,
+                           n_kv_heads)
+    from tpu_sandbox.ops.pallas_paged_attention import paged_attention
+
+    with jax.named_scope("gather_ctx"):
+        return paged_attention(q, k_pages, v_pages, block_tables, lengths,
+                               pages_per_step=pages)
 
 
 def make_prefill_fn(model_cfg: TransformerConfig):
@@ -289,15 +395,12 @@ def make_decode_fn(model_cfg: TransformerConfig, cache_cfg: CacheConfig):
     ``lengths == 0``: their writes land in the null block and their
     attention weights collapse to zeros.
     """
-    head_dim = model_cfg.d_model // model_cfg.n_heads
-    scale = jnp.sqrt(jnp.asarray(head_dim, jnp.float32))
-
     # ``jit_serve_decode`` in a device trace; inside each ``block{i}/attn``
-    # the page write and the context gather carry scopes of their own
-    # (``write_kv``, ``gather_ctx``), the rest is the attention math
+    # the page write and the read of the context carry scopes of their own
+    # (``write_kv``, ``gather_ctx``: ``_attend``)
     def serve_decode(params, k_pages, v_pages, tokens, lengths, block_tables):
         k_pages, v_pages = list(k_pages), list(v_pages)
-        pos, dest, kv_mask = _decode_slots(cache_cfg, lengths, block_tables)
+        pos, dest = _decode_slots(cache_cfg, lengths, block_tables)
         layer = itertools.count()
 
         def attention_fn(q, k, v):
@@ -306,23 +409,8 @@ def make_decode_fn(model_cfg: TransformerConfig, cache_cfg: CacheConfig):
             with jax.named_scope("write_kv"):
                 k_pages[i] = _store(k_pages[i], dest, k[:, 0])
                 v_pages[i] = _store(v_pages[i], dest, v[:, 0])
-            with jax.named_scope("gather_ctx"):
-                kc = _gather(k_pages[i], block_tables,
-                             model_cfg.n_heads).astype(q.dtype)
-                vc = _gather(v_pages[i], block_tables,
-                             model_cfg.n_heads).astype(v.dtype)
-            # query padded to q=2: XLA's q=1 matvec kernel accumulates the
-            # output contraction in a different order than the full
-            # forward's gemm (~1 ulp); at q>=2 the gemm kernel matches
-            # bitwise (see module docstring / tests/test_serve.py)
-            q2 = jnp.concatenate([q, q], axis=1)               # [B, 2, H, D]
-            scores = jnp.einsum("bqhd,bkhd->bhqk", q2, kc).astype(jnp.float32)
-            scores = scores / scale
-            scores = jnp.where(kv_mask[:, None, None, :], scores, -jnp.inf)
-            w = jnp.nan_to_num(jnp.exp(scores - scores.max(-1, keepdims=True)))
-            w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-30)
-            out = jnp.einsum("bhqk,bkhd->bqhd", w.astype(vc.dtype), vc)
-            return out[:, :1]
+            return _attend(q[:, 0], k_pages[i], v_pages[i], block_tables,
+                           lengths, model_cfg.n_heads)[:, None]
 
         model = TransformerLM(model_cfg, attention_fn=attention_fn)
         logits = model.apply({"params": params}, tokens, pos[:, None])
@@ -402,14 +490,10 @@ def make_jamba_decode_fn(model_cfg: JambaConfig, cache_cfg: CacheConfig):
     to the null block and **keeps its state** (``JambaLM``'s ``live``)."""
     from tpu_sandbox.models.jamba import JambaLM
 
-    hq, hkv, hd = (model_cfg.num_attention_heads,
-                   model_cfg.num_key_value_heads, model_cfg.head_dim)
-
     def serve_decode(params, k_pages, v_pages, state, tokens, lengths,
                      block_tables):
         k_pages, v_pages = list(k_pages), list(v_pages)
-        bsz = tokens.shape[0]
-        _, dest, kv_mask = _decode_slots(cache_cfg, lengths, block_tables)
+        _, dest = _decode_slots(cache_cfg, lengths, block_tables)
         layer = itertools.count()
 
         def attention_fn(q, k, v):
@@ -418,19 +502,8 @@ def make_jamba_decode_fn(model_cfg: JambaConfig, cache_cfg: CacheConfig):
             with jax.named_scope("write_kv"):
                 k_pages[i] = _store(k_pages[i], dest, k[:, 0])
                 v_pages[i] = _store(v_pages[i], dest, v[:, 0])
-            with jax.named_scope("gather_ctx"):
-                kc = _gather(k_pages[i], block_tables, hkv).astype(q.dtype)
-                vc = _gather(v_pages[i], block_tables, hkv).astype(q.dtype)
-            # a key/value head's group of query heads side by side
-            qg = q[:, 0].reshape(bsz, hkv, hq // hkv, hd)
-            scores = jnp.einsum("bhgd,bkhd->bhgk", qg, kc,
-                                preferred_element_type=jnp.float32)
-            scores = scores / jnp.sqrt(jnp.float32(hd))
-            scores = jnp.where(kv_mask[:, None, None, :], scores, -jnp.inf)
-            w = jnp.nan_to_num(jnp.exp(scores - scores.max(-1, keepdims=True)))
-            w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-30)
-            out = jnp.einsum("bhgk,bkhd->bhgd", w.astype(vc.dtype), vc)
-            return out.reshape(bsz, 1, hq, hd)
+            return _attend(q[:, 0], k_pages[i], v_pages[i], block_tables,
+                           lengths, model_cfg.num_key_value_heads)[:, None]
 
         model = JambaLM(model_cfg, attention_fn=attention_fn)
         logits, state = model.apply({"params": params}, tokens, state,
