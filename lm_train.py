@@ -29,8 +29,6 @@ Examples::
 
 import argparse
 
-from tpu_sandbox.utils.cli import add_grad_compress_cli, add_overlap_cli
-
 
 def make_batches(vocab: int, batch: int, seq_len: int, steps: int, seed: int):
     """Deterministic synthetic LM stream: targets = (tokens + k) % vocab with
@@ -176,14 +174,6 @@ def _build(args, devices):
     sample = jnp.zeros((1, args.seq_len), jnp.int32)
 
     p = args.parallelism
-    if (args.grad_compress != "none" or args.overlap_grad_sync) and p != "dp":
-        # the compressed/bucketed sync intercepts grads as they cross the
-        # batch axis; under tp/sp/pp/ep XLA owns the collective placement
-        raise SystemExit(
-            f"--grad-compress/--overlap-grad-sync only compose with "
-            f"--parallelism dp (got {p!r}): other plans let XLA place the "
-            "grad collectives"
-        )
     model = None  # the sp / pp engines build theirs from ``cfg``
     if args.model in CONFIG_MODELS:
         if p != "dp" or not args.config:
@@ -211,10 +201,7 @@ def _build(args, devices):
         mesh = make_mesh({"data": n}, devices=devices)
         model = TransformerLM(cfg, attention_fn=attention_fn)
         state = TrainState.create(model, rng, sample, tx)
-        eng = PjitEngine(model, tx, mesh, task="lm",
-                         grad_compress=args.grad_compress,
-                         overlap_grad_sync=args.overlap_grad_sync,
-                         bucket_mb=args.bucket_mb)
+        eng = PjitEngine(model, tx, mesh, task="lm")
     elif p == "tp":
         if args.dp < 1 or n % args.dp:
             raise SystemExit(f"--dp {args.dp} must be >= 1 and divide {n} devices")
@@ -398,11 +385,6 @@ def build_parser() -> argparse.ArgumentParser:
     parser.add_argument("--remat", action="store_true",
                         help="jax.checkpoint each block (memory for FLOPs)")
     parser.add_argument("--force-cpu", action="store_true")
-    # dp only; no --no-error-feedback here — PjitEngine's compressed sync
-    # is stateless (no residual to carry), unlike DataParallel's
-    add_grad_compress_cli(parser, error_feedback=False)
-    # dp only likewise; no --prefetch (synthetic in-memory stream)
-    add_overlap_cli(parser, prefetch=False)
     return parser
 
 

@@ -24,7 +24,7 @@ from tpu_sandbox.utils.cli import (
     add_checkpoint_cli,
     add_elastic_cli,
     add_grad_compress_cli,
-    add_overlap_cli,
+    add_prefetch_cli,
 )
 
 IMAGE_SHAPE = [3000, 3000]
@@ -112,9 +112,7 @@ def build(args, world_size):
             model, rng, jnp.zeros([1, *image_shape, 1], dtype), tx)
         dp = DataParallel(model, tx, mesh, image_size=tuple(image_shape),
                           zero=args.zero, grad_compress=args.grad_compress,
-                          error_feedback=not args.no_error_feedback,
-                          overlap_grad_sync=args.overlap_grad_sync,
-                          bucket_mb=args.bucket_mb)
+                          error_feedback=not args.no_error_feedback)
     return dp, state, loader
 
 
@@ -230,9 +228,7 @@ def train_multiprocess_worker(args, world_size):
 
     dp = DataParallel(model, tx, mesh, image_size=tuple(image_shape),
                       zero=args.zero, grad_compress=args.grad_compress,
-                      error_feedback=not args.no_error_feedback,
-                      overlap_grad_sync=args.overlap_grad_sync,
-                      bucket_mb=args.bucket_mb)
+                      error_feedback=not args.no_error_feedback)
     dstate = dp.shard_state(state)
     trainer = Trainer(dp.train_step, log_every=args.log_every, log_rank=0,
                       verbose=rank == 0)
@@ -348,9 +344,7 @@ def train_elastic_worker(args, world_size):
     dp = DataParallel(model, tx, mesh, image_size=tuple(image_shape),
                       zero=args.zero, donate=False,
                       grad_compress=args.grad_compress,
-                      error_feedback=not args.no_error_feedback,
-                      overlap_grad_sync=args.overlap_grad_sync,
-                      bucket_mb=args.bucket_mb)
+                      error_feedback=not args.no_error_feedback)
 
     # per-boundary preemption vote: OR this rank's flag across the world
     # through a real collective, so every rank reaches the same stop
@@ -452,10 +446,6 @@ def _elastic_passthrough(args):
         passthrough += ["--grad-compress", args.grad_compress]
     if args.no_error_feedback:
         passthrough += ["--no-error-feedback"]
-    if args.overlap_grad_sync:
-        passthrough += ["--overlap-grad-sync"]
-    if args.bucket_mb != 25.0:
-        passthrough += ["--bucket-mb", str(args.bucket_mb)]
     if args.prefetch:
         passthrough += ["--prefetch"]
     return passthrough
@@ -708,10 +698,6 @@ def spawn_multiprocess(args, world_size):
         passthrough += ["--grad-compress", args.grad_compress]
     if args.no_error_feedback:
         passthrough += ["--no-error-feedback"]
-    if args.overlap_grad_sync:
-        passthrough += ["--overlap-grad-sync"]
-    if args.bucket_mb != 25.0:
-        passthrough += ["--bucket-mb", str(args.bucket_mb)]
     if args.prefetch:
         passthrough += ["--prefetch"]
     procs = [
@@ -803,7 +789,7 @@ def build_parser():
     parser.add_argument("--dtype", choices=["bf16", "fp32"], default="bf16")
     add_checkpoint_cli(parser)
     add_grad_compress_cli(parser)
-    add_overlap_cli(parser)
+    add_prefetch_cli(parser)
     parser.add_argument("--force-cpu", action="store_true",
                         help="use virtual CPU devices even if an accelerator is present")
     parser.add_argument("--multiprocess", action="store_true",

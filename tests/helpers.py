@@ -26,14 +26,18 @@ def run_child(argv, *, timeout, **popen):
 
 
 def ulps_apart(got, want):
-    """The largest difference between two float32 pytrees, each leaf's in
-    units in the last place of that leaf's largest magnitude: what two
+    """The largest difference between two float pytrees, each leaf's in
+    units in the last place (of ``want``'s type: float32, or bfloat16's 65536
+    times coarser one) of that leaf's largest magnitude: what two
     compiled programs that sum the same terms in another order differ by."""
     import jax
+    import jax.numpy as jnp
 
     def leaf(a, b):
+        coarser = 2.0 ** (23 - jnp.finfo(np.asarray(b).dtype).nmant)
         a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-        return float(np.abs(a - b).max() / np.spacing(np.abs(b).max()))
+        return float(np.abs(a - b).max()
+                     / (np.spacing(np.abs(b).max()) * coarser))
 
     return max(jax.tree.leaves(jax.tree.map(leaf, got, want)))
 

@@ -1,6 +1,5 @@
 """Compressed gradient synchronization (parallel/collectives.py
-CompressedAllReduce + the engine wiring in parallel/data_parallel.py and
-parallel/pjit_engine.py).
+CompressedAllReduce + the engine wiring in parallel/data_parallel.py).
 
 The correctness bar, per mode:
   - 'none' must be BYTE-IDENTICAL to the pre-compression path — the
@@ -31,7 +30,6 @@ from tpu_sandbox.data.mnist import normalize
 from tpu_sandbox.models import ConvNet
 from tpu_sandbox.parallel import CompressedAllReduce, DataParallel, PjitEngine
 from tpu_sandbox.parallel.collectives import as_compress_policy, world_group
-from tpu_sandbox.runtime.mesh import make_mesh
 from tpu_sandbox.train import TrainState
 from tpu_sandbox.train.checkpoint import ShardedCheckpoint
 
@@ -357,38 +355,24 @@ def test_hlo_collective_bytes_drop_under_int8(mesh8):
 # -- PjitEngine wiring ------------------------------------------------------
 
 
-def test_pjit_engine_compressed_modes(mesh8):
+def test_pjit_engine_carries_a_residual_it_is_handed(mesh8):
+    """``PjitEngine`` compresses nothing and feeds nothing back, but a state
+    that an int8 ``DataParallel`` run left (one residual a rank) goes
+    through its step with the residual as it came, and the step is the
+    one a state without residual takes."""
     model, tx, state, images, labels = setup()
-    ref = PjitEngine(model, tx, mesh8, donate=False)
-    sstate = ref.shard_state(state)
-    _, l_ref = ref.train_step(sstate, *ref.shard_batch(images, labels))
-    for mode, rtol in (("none", 0.0), ("bf16", 2e-2), ("int8", 2e-2)):
-        eng = PjitEngine(model, tx, mesh8, donate=False, grad_compress=mode)
-        sstate = eng.shard_state(state)
-        _, loss = eng.train_step(sstate, *eng.shard_batch(images, labels))
-        if mode == "none":
-            assert float(loss) == float(l_ref)
-        else:
-            np.testing.assert_allclose(float(loss), float(l_ref), rtol=rtol)
-
-
-def test_pjit_engine_compression_restrictions(mesh8):
-    """The pjit path's compression is deliberately restricted to its
-    plain-DP configuration; every unsupported combination fails loud at
-    construction or first build, never silently uncompressed."""
-    model, tx, state, images, labels = setup()
-    with pytest.raises(ValueError, match="rules"):
-        PjitEngine(model, tx, mesh8, donate=False, grad_compress="int8",
-                   rules=[("fc/kernel", P(None, "model"))])
-    mesh2 = make_mesh({"data": 4, "fsdp": 2})
-    with pytest.raises(ValueError, match="fsdp"):
-        PjitEngine(model, tx, mesh2, donate=False, grad_compress="bf16",
-                   fsdp_axis="fsdp")
-    bn_model = ConvNet(use_bn=True)
-    bn_state = TrainState.create(
-        bn_model, jax.random.key(0), jnp.zeros((1, 28, 28, 1)), optax.sgd(0.05))
-    eng = PjitEngine(bn_model, optax.sgd(0.05), mesh8, donate=False,
-                     grad_compress="int8")
-    with pytest.raises(ValueError, match="batch"):
-        sstate = eng.shard_state(bn_state)
-        eng.train_step(sstate, *eng.shard_batch(images, labels))
+    dp = DataParallel(model, tx, mesh8, donate=False, grad_compress="int8")
+    left, _ = _run_steps(dp, state, images, labels, 1)
+    assert any(float(jnp.abs(r).max()) > 0
+               for r in jax.tree.leaves(left.grad_residual))
+    eng = PjitEngine(model, tx, mesh8, donate=False)
+    handed = jax.tree.map(np.asarray, left)
+    new, loss = eng.train_step(
+        eng.shard_state(handed), *eng.shard_batch(images, labels))
+    jax.tree.map(np.testing.assert_array_equal,
+                 new.grad_residual, handed.grad_residual)
+    bare = handed.replace(grad_residual=None)
+    eng = PjitEngine(model, tx, mesh8, donate=False)  # a step a structure
+    _, l_bare = eng.train_step(
+        eng.shard_state(bare), *eng.shard_batch(images, labels))
+    assert float(loss) == float(l_bare)

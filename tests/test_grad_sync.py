@@ -1,12 +1,13 @@
 """DataParallel's plain sync sums its largest leaf first
 (parallel/data_parallel.py::_pmean_largest_first).
 
-What a CPU can hold: the mathematics is one trailing pmean's; the lowered
-module has the large leaf's all-reduce in front of one barrier that ties it
-to the other leaves, still unsummed, with its division and the others'
-all-reduce behind; every other program (the one-chip step, the model's
-gradient outside any mesh, the ZeRO, compressed and bucketed steps) is as
-it was; the counter names the leaf. Whether the chip then runs that
+What a CPU can hold: the mathematics is one trailing pmean's, over the
+ConvNet's gradients in the engine's step and over trees of other shapes;
+the lowered module has the large leaf's all-reduce in front of one barrier
+that ties it to the other leaves, still unsummed, with its division and the
+others' all-reduce behind; every other program (the one-chip step, the
+model's gradient outside any mesh, the ZeRO and compressed steps) is as it
+was; the counter names the leaf. Whether the chip then runs that
 collective under the convolutions' backward kernels is PERF.md section 6's
 (PR 34), and what the compiler schedules tools/hlo_schedule.py's
 (tests/test_hlo_tools.py)."""
@@ -20,15 +21,20 @@ import numpy as np
 import optax
 import pytest
 from jax import lax
+from jax.sharding import Mesh, PartitionSpec as P
 
-from tests.helpers import ulps_apart
+from tests.helpers import counters, label, ulps_apart
 from tests.test_grad_compress import setup as plain_setup
 from tpu_sandbox.data import synthetic_mnist
 from tpu_sandbox.data.mnist import normalize
 from tpu_sandbox.models.convnet_s2d_t import ConvNetS2DT
 from tpu_sandbox.obs import get_registry
 from tpu_sandbox.ops.losses import cross_entropy_loss
-from tpu_sandbox.parallel import DataParallel, data_parallel
+from tpu_sandbox.parallel import (
+    CompressedAllReduce,
+    DataParallel,
+    data_parallel,
+)
 from tpu_sandbox.train import TrainState, make_train_step
 
 HW = 32                      # the s2dt model's image: fc kernel [2048, 10]
@@ -82,30 +88,98 @@ class _SumsNothing:
         return (lambda x, axis: x) if name == "psum" else getattr(lax, name)
 
 
-@pytest.mark.parametrize("fault", [None, "sums_nothing"])
-def test_largest_first_agrees_with_one_pmean(mesh8, fault, monkeypatch):
-    """The same float32 sum of eight gradients, then / 8, then SGD with
-    momentum: only the place of one collective in the program differs, so
-    parameters and every rank's loss after 3 steps are one trailing
-    pmean's to rounding. Not to the bit, as in test_overlap.py: two
-    compiled programs, and XLA:CPU may order the sums round the collective
-    differently in each (measured: 0 ulps of each leaf's largest entry;
-    held to 4). A large leaf that is never summed is thousands apart."""
+def _nested(f32):
+    return {"block": {"attn": (f32(4), f32(32, 8)), "mlp": {"w": f32(8, 8)}},
+            "head": f32(10)}
+
+
+#: name -> (one rank's gradients, from a maker of float32 arrays; the leaf
+#: summed first; the axis's size). The largest leaf is the one with most
+#: ELEMENTS, the first of them in flatten order where several tie.
+TREES = {
+    "one_leaf": (lambda f32: {"w": f32(6, 5)}, "w", 8),
+    "same_size": (lambda f32: {"a": f32(3, 4), "b": f32(4, 3), "c": f32(12)},
+                  "a", 8),
+    "mixed_dtypes": (lambda f32: {"bias": f32(8).astype(jnp.bfloat16),
+                                  "emb": f32(64, 8).astype(jnp.bfloat16),
+                                  "scale": f32(300)}, "emb", 8),
+    "nested": (_nested, "block/attn/1", 8),
+    "axis_of_one": (_nested, "block/attn/1", 1),
+}
+
+
+def _means_of_tree(make, size):
+    """``_pmean_largest_first`` and one ``lax.pmean`` (``lax``'s own,
+    whatever the module's is patched to) of ``size`` ranks' seeded
+    gradients, each as one compiled program over a mesh of ``size``."""
+    keys = iter(jax.random.split(jax.random.key(0), 16))
+    grads = make(lambda *shape: jax.random.normal(next(keys), (size, *shape)))
+    mesh = Mesh(np.array(jax.devices()[:size]), ("data",))
+
+    def mean(fn):
+        return jax.jit(jax.shard_map(
+            lambda g: fn(jax.tree.map(lambda x: x[0], g)), mesh=mesh,
+            in_specs=P("data"), out_specs=P(), check_vma=False))(grads)
+
+    return (mean(lambda g: data_parallel._pmean_largest_first(
+                g, "data", size)),
+            mean(lambda g: lax.pmean(g, "data")))
+
+
+def _apart_after_three_steps(mesh8):
+    """Through the engine, SGD with momentum behind the mean: how far
+    parameters and every rank's loss after 3 steps are from those of an
+    engine whose sync is one ``lax.pmean`` (``lax``'s own)."""
     model, tx, state, images, labels = plain_setup(momentum=0.9, use_bn=True)
-    with monkeypatch.context() as mp:
+    with pytest.MonkeyPatch.context() as mp:
         mp.setattr(data_parallel, "_pmean_largest_first",
                    lambda grads, axis, size: lax.pmean(grads, axis))
         want_params, want_losses = _three_steps(
             DataParallel(model, tx, mesh8, donate=False),
             state, images, labels)
-    if fault:
-        monkeypatch.setattr(data_parallel, "lax", _SumsNothing())
     params, losses = _three_steps(
         DataParallel(model, tx, mesh8, donate=False), state, images, labels)
     assert losses.shape == (3, 8)
-    apart = max(ulps_apart(params, want_params),
-                ulps_apart(losses, want_losses))
-    assert (apart > 1000) if fault else (apart <= 4), apart
+    return max(ulps_apart(params, want_params),
+               ulps_apart(losses, want_losses))
+
+
+@pytest.mark.parametrize("fault", [None, "sums_nothing"])
+@pytest.mark.parametrize("grads", ["convnet_step", *TREES])
+def test_largest_first_agrees_with_one_pmean(mesh8, grads, fault, monkeypatch):
+    """The same float32 sum of eight gradients, then / 8: only the place of
+    one collective in the program differs, so the mean is one trailing
+    pmean's to rounding. Not to the bit: two compiled programs, and XLA:CPU
+    may order the sums round the collective differently in each (measured:
+    0 ulps of each leaf's largest entry; held to 4). A large leaf that is
+    never summed is thousands apart (hundreds of bfloat16's places), but on
+    an axis of one, where its sum is itself.
+
+    ``convnet_step``: through the engine, SGD with momentum behind the
+    mean, parameters and every rank's loss after 3 steps. The others:
+    the function alone over trees the ConvNet's is not -- one leaf (nothing
+    behind the barrier), leaves of one size (the first is taken, every
+    time), bfloat16 beside float32 (each leaf keeps its type), containers
+    in containers with the largest in the middle (every leaf back in its
+    place), one rank -- and the counter names the leaf that went first."""
+    if fault:
+        monkeypatch.setattr(data_parallel, "lax", _SumsNothing())
+    if grads == "convnet_step":
+        apart, axis_size = _apart_after_three_steps(mesh8), 8
+    else:
+        make, first, axis_size = TREES[grads]
+        before = counters("dp.grad_sync{")
+        got, want = _means_of_tree(make, axis_size)
+        assert jax.tree.structure(got) == jax.tree.structure(want)
+        assert (jax.tree.map(lambda x: (x.shape, x.dtype), got)
+                == jax.tree.map(lambda x: (x.shape, x.dtype), want))
+        apart = ulps_apart(got, want)
+        issued = {label(key, "issued"): label(key, "leaf")
+                  for key in counters("dp.grad_sync{", since=before)}
+        n_others = len(jax.tree.leaves(want)) - 1
+        assert issued == {"backward": first, "step_end": f"other_{n_others}"}
+    # bfloat16 has 256 places a binade: a leaf never summed is 100s off
+    assert (apart > 100) if fault and axis_size > 1 else (apart <= 4), apart
 
 
 def test_large_leaf_is_summed_in_front_of_one_barrier(mesh8):
@@ -179,19 +253,35 @@ def test_outside_data_parallel_nothing_is_traced(program):
 
 
 @pytest.mark.parametrize("path", [
-    {"zero": True}, {"grad_compress": "bf16"}, {"overlap_grad_sync": True}],
-    ids=["zero", "bf16", "bucketed"])
+    {"zero": True}, {"grad_compress": "bf16"}, {"grad_compress": "int8"},
+    {"zero": True, "grad_compress": "int8"}],
+    ids=["zero", "bf16", "int8", "zero_int8"])
 def test_other_sync_paths_keep_their_own(mesh8, path):
-    """ZeRO, compressed and bucketed steps sync every leaf their own way:
-    nothing is counted, and no barrier stands in the module but the
-    bucketed path's own chain between its buckets."""
+    """ZeRO and compressed steps sync every leaf their own way: nothing is
+    counted, and no barrier stands in the module."""
     before = _sync_counts()
     model, tx, state, images, labels = plain_setup()
-    dp = DataParallel(model, tx, mesh8, donate=False, bucket_mb=0.02, **path)
+    dp = DataParallel(model, tx, mesh8, donate=False, **path)
     text = _lowered_text(dp, state, images, labels)
+    assert re.search(  # the step does sync
+        r"stablehlo\.(all_reduce|reduce_scatter|all_to_all|all_gather)", text)
     assert _sync_counts() == before
-    n_barriers = text.count("stablehlo.optimization_barrier")
-    assert n_barriers == (3 if "overlap_grad_sync" in path else 0)
+    assert "optimization_barrier" not in text
+
+
+def test_a_policy_of_none_is_the_plain_step(mesh8):
+    """``grad_compress`` given as a policy object that compresses nothing
+    is the plain path, as the string and the default are: the module
+    lowered is the same text, barrier and all."""
+    model, tx, state, images, labels = plain_setup()
+    texts = {_lowered_text(DataParallel(model, tx, mesh8, donate=False, **kw),
+                           state, images, labels)
+             for kw in ({}, {"grad_compress": "none"},
+                        {"grad_compress": CompressedAllReduce(mode="none")},
+                        {"grad_compress": CompressedAllReduce(
+                            mode="none", error_feedback=False)})}
+    assert len(texts) == 1
+    assert texts.pop().count("stablehlo.optimization_barrier") == 1
 
 
 def test_tpu_options_go_to_tpu_meshes_only(mesh8):

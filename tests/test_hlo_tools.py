@@ -4,9 +4,9 @@ The `-done` opcode bug class: async collectives appear twice in scheduled
 HLO (`all-reduce-start` + `all-reduce-done`); counting both doubles the
 traffic number, counting neither drops it. These tests pin the parsing
 contracts of ``hlo_traffic.collective_bytes`` (per-opcode bucketing) and
-``hlo_schedule.schedule_report`` (monolithic baseline vs overlapped
-schedule) against hand-written modules where every byte is computable by
-eye — no compiles, CPU-only.
+``hlo_schedule.issue_order_report`` (where the step's largest all-reduce
+stands among its kernels) against hand-written modules where every byte is
+computable by eye — no compiles, CPU-only.
 """
 
 import os
@@ -17,7 +17,7 @@ import pytest
 sys.path.insert(0, os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "tools"))
 
-from hlo_schedule import issue_order_report, schedule_report  # noqa: E402
+from hlo_schedule import issue_order_report  # noqa: E402
 from hlo_traffic import (  # noqa: E402
     collective_bytes,
     shape_bytes,
@@ -178,90 +178,6 @@ ENTRY %m (p0: f32[8]) -> f32[8] {
   ROOT %c = f32[8] copy(f32[8] %p0)
 }
 """) == {"total": 0, "by_opcode": {}}
-
-
-# ---------------------------------------------------------------------------
-# schedule_report: monolithic baseline vs overlapped schedule
-# ---------------------------------------------------------------------------
-
-_MONO_HLO = """\
-HloModule train_step, is_scheduled=true
-
-%add.clone (x.1: f32[], y.1: f32[]) -> f32[] {
-  %x.1 = f32[] parameter(0)
-  %y.1 = f32[] parameter(1)
-  ROOT %add.9 = f32[] add(f32[] %x.1, f32[] %y.1)
-}
-
-ENTRY %main.42 (p0: f32[256,128]) -> f32[256,128] {
-  %p0 = f32[256,128] parameter(0)
-  %dot.fwd = f32[256,128] dot(f32[256,128] %p0, f32[256,128] %p0), metadata={op_name="jit(train_step)/jvp(loss)/dot_general"}
-  %fusion.bwd = f32[256,128] fusion(f32[256,128] %dot.fwd), kind=kLoop, metadata={op_name="jit(train_step)/transpose(jvp(loss))/mul"}
-  ROOT %all-reduce.0 = f32[256,128] all-reduce(f32[256,128] %fusion.bwd), replica_groups={{0,1,2,3}}, to_apply=%add.clone
-}
-"""
-
-_OVERLAP_HLO = """\
-HloModule train_step, is_scheduled=true
-
-ENTRY %main.42 (p0: f32[256,128]) -> f32[256,128] {
-  %p0 = f32[256,128] parameter(0)
-  %dot.fwd = f32[256,128] dot(f32[256,128] %p0, f32[256,128] %p0), metadata={op_name="jit(train_step)/jvp(loss)/dot_general"}
-  %ar-start.0 = f32[100,128] all-reduce-start(f32[100,128] %dot.fwd), to_apply=%add.clone
-  %fusion.bwd1 = f32[256,128] fusion(f32[256,128] %dot.fwd), kind=kLoop, metadata={op_name="jit(train_step)/transpose(jvp(loss))/mul"}
-  %ar-done.0 = f32[100,128] all-reduce-done(f32[100,128] %ar-start.0)
-  %all-reduce.1 = f32[50,128] all-reduce(f32[50,128] %fusion.bwd1), to_apply=%add.clone
-  ROOT %fusion.bwd2 = f32[256,128] fusion(f32[256,128] %fusion.bwd1), kind=kLoop, metadata={op_name="jit(train_step)/transpose(jvp(loss))/add"}
-}
-"""
-
-
-def test_schedule_report_monolithic_baseline():
-    """The shape the bucketing exists to kill: one all-reduce scheduled
-    after the last backward compute op — fully exposed."""
-    rep = schedule_report(_MONO_HLO)
-    assert rep["collective_count"] == 1
-    assert rep["sync_collectives"] == 1
-    assert rep["all_reduce_issues_before_last_bwd_compute"] == 0
-    assert rep["comm_bytes_exposed"] == 256 * 128 * 4
-    assert rep["comm_bytes_overlapped"] == 0
-    assert rep["exposed_comm_fraction"] == 1.0
-    assert rep["last_bwd_compute_op"] == "fusion.bwd"
-
-
-def test_schedule_report_resolves_operands_printed_by_name():
-    rep = schedule_report(_MONO_HLO.replace("f32[256,128] %", "%")
-                          .replace("f32[] %", "%"))
-    assert rep["comm_bytes_exposed"] == 256 * 128 * 4
-
-
-def test_schedule_report_overlapped_schedule():
-    """Async pair with compute between start/done + a sync collective
-    issued before the last backward op: everything overlaps."""
-    rep = schedule_report(_OVERLAP_HLO)
-    assert rep["collective_count"] == 2
-    assert rep["async_pairs"] == 1
-    assert rep["sync_collectives"] == 1
-    # both the -start and the sync form issue before fusion.bwd2
-    assert rep["all_reduce_issues_before_last_bwd_compute"] == 2
-    pair = [c for c in rep["collectives"] if c["form"] == "async"][0]
-    assert pair["compute_ops_between"] == 1 and pair["overlapped"]
-    assert rep["comm_bytes_exposed"] == 0
-    assert rep["exposed_comm_fraction"] == 0.0
-
-
-def test_schedule_report_orphan_start_counts_exposed():
-    """A -start whose -done never appears must count as exposed bytes,
-    not vanish (the dual of the -done double-count bug)."""
-    orphan = _OVERLAP_HLO.replace(
-        "  %ar-done.0 = f32[100,128] all-reduce-done"
-        "(f32[100,128] %ar-start.0)\n", "")
-    rep = schedule_report(orphan)
-    assert rep["collective_count"] == 2
-    exposed = [c for c in rep["collectives"] if not c["overlapped"]]
-    assert len(exposed) == 1
-    assert exposed[0]["bytes"] == 100 * 128 * 4
-    assert rep["comm_bytes_exposed"] == 100 * 128 * 4
 
 
 # ---------------------------------------------------------------------------

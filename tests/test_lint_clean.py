@@ -39,7 +39,6 @@ from tpu_sandbox.analysis.hlo_pass import (
     lint_hlo_text,
     lint_int8_padding,
     lint_jaxpr,
-    lint_schedule,
 )
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -319,22 +318,6 @@ def test_host_transfer_rule_h203():
                          "fix") == []
 
 
-def test_schedule_rule_h204():
-    sys.path.insert(0, os.path.join(ROOT, "tools"))
-    from hlo_schedule import schedule_report
-
-    from tests.test_hlo_tools import _MONO_HLO, _OVERLAP_HLO
-
-    mono = schedule_report(_MONO_HLO)
-    fired = lint_schedule(mono, "dp-overlap", overlap=True)
-    assert [f.rule for f in fired] == ["GL-H204"]
-    # same schedule without overlap requested: the monolithic baseline is
-    # legitimate, not a finding
-    assert lint_schedule(mono, "dp", overlap=False) == []
-    assert lint_schedule(
-        schedule_report(_OVERLAP_HLO), "dp-overlap", overlap=True) == []
-
-
 def test_int8_padding_rule_h205():
     # 30 tiny leaves: block/axis alignment zeros dwarf the payload
     fired, wire = lint_int8_padding([16] * 30, 8, label="fix")
@@ -422,16 +405,16 @@ def _run_graftlint(*extra):
 
 
 @pytest.mark.parametrize("steps,traced", [
-    ("dp,zero,pjit,pipeline,dp-int8,dp-overlap,sp",
-     ("dp", "zero", "pjit", "pipeline", "dp-int8", "dp-overlap", "sp")),
+    ("dp,zero,pjit,pipeline,dp-int8,sp",
+     ("dp", "zero", "pjit", "pipeline", "dp-int8", "sp")),
     ("decode,prefill,fsdp,tp,ep",
      ("decode", "prefill", "prefill-b16", "fsdp", "tp", "ep")),
     ("mpmd", ("mpmd-s0-fwd", "mpmd-s0-bwd", "mpmd-s1-loss_grad"))],
     ids=["engines", "serve_and_pjit_layouts", "mpmd"])
 def test_graftlint_cli_traces_all_steps(steps, traced):
     """Tier-1 half of the CLI gate: all three passes, jaxpr-tracing the
-    real DP/ZeRO/pjit/pipeline steps — plus the engine-flag variants
-    (int8 grad compress, bucketed overlap), SeqParallel, and the serve
+    real DP/ZeRO/pjit/pipeline steps — plus the engine-flag variant
+    (int8 grad compress), SeqParallel, and the serve
     decode + bucketed-prefill steps — on CPU, a third of the steps a child
     (together they are `--steps`' default). The AOT compiles are skipped
     here (`--no-aot`) to keep tier-1 inside its time budget — the full
@@ -449,8 +432,7 @@ def test_graftlint_cli_traces_all_steps(steps, traced):
 @pytest.mark.slow
 def test_graftlint_cli_full_run_including_aot():
     """Pass 2 end-to-end: AOT-compiles the DP/ZeRO steps against the
-    chipless v5e topology and verifies donation, overlap scheduling, and
-    int8 wire padding. Skips gracefully where the toolchain can't build
+    chipless v5e topology and verifies donation and int8 wire padding. Skips gracefully where the toolchain can't build
     topologies."""
     report = _run_graftlint("--all")
     assert report["findings"] == 0
@@ -460,4 +442,3 @@ def test_graftlint_cli_full_run_including_aot():
     # the acceptance receipt: donation status for the DP and ZeRO steps
     assert aot["dp"]["donation"] == "verified", aot
     assert aot["zero"]["donation"] == "verified", aot
-    assert aot["overlap_schedule"]["issues_before_last_bwd"] >= 1, aot

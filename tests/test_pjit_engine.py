@@ -4,6 +4,9 @@ The correctness bar mirrors test_data_parallel: sharded training must equal
 single-device training on the same effective batch (BN-free model), and the
 tensor-sharded head must actually be sharded (not silently replicated)."""
 
+import types
+
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,6 +18,7 @@ from tpu_sandbox.data import synthetic_mnist
 from tpu_sandbox.data.mnist import normalize
 from tpu_sandbox.models import ConvNet
 from tpu_sandbox.parallel import PjitEngine
+from tpu_sandbox.parallel.expert import ExpertShare
 from tpu_sandbox.parallel.pjit_engine import param_specs
 from tpu_sandbox.runtime.mesh import make_mesh
 from tpu_sandbox.train import TrainState, make_train_step
@@ -200,3 +204,82 @@ def test_fsdp_axis_shards_params(mesh8):
     ck = st_fsdp.params["conv1"]["kernel"].sharding.spec
     assert not ck or ck[0] is None, ck
     _assert_params_equal(st_rep.params, st_fsdp.params)
+
+
+# -- the one step, every sharding mode, both tasks ---------------------------
+
+
+class _RoutedLM(nn.Module):
+    """Embedding, one routed-expert layer, head: the smallest LM whose
+    ``batch_stats`` hold a router's bias and counters."""
+
+    @nn.compact
+    def __call__(self, tokens):
+        x = nn.Embed(64, 32, name="embed")(tokens)
+        x = x + ExpertShare(
+            d_model=32, d_ff=16, n_routed_experts=8, top_k=2,
+            held=tuple(range(8)), local_rows=2 * tokens.size,
+            n_shared_experts=1, dtype=jnp.float32, row_tile=8, name="moe")(x)
+        return nn.Dense(64, name="head")(x)
+
+
+def _lm_setup():
+    model = _RoutedLM()
+    tx = optax.sgd(0.05)
+    tokens = jax.random.randint(jax.random.key(1), (8, 16), 0, 64)
+    state = TrainState.create(
+        types.SimpleNamespace(init=jax.jit(model.init)), jax.random.key(0),
+        tokens[:1], tx)
+    return model, tx, state, tokens, (tokens + 1) % 64
+
+
+#: mode -> (the mesh's axes, the engine's options); a rule that finds no
+#: parameter of a model shards nothing there
+MODES = {
+    "dp": ({"data": 8}, {}),
+    "zero_axis": ({"data": 8}, {"zero_axis": "data"}),
+    "fsdp_axis": ({"data": 8}, {"fsdp_axis": "data"}),
+    "tp_rule": ({"data": 2, "model": 4},
+                {"rules": [("fc/kernel", P("model", None)),
+                           ("head/kernel", P(None, "model")),
+                           ("moe/shared_up/kernel", P(None, "model"))]}),
+}
+
+
+@pytest.mark.parametrize("task", ["image", "lm"])
+@pytest.mark.parametrize("mode", MODES)
+def test_one_step_returns_the_stats_in_every_mode(mode, task):
+    """``PjitEngine`` has one step, and it hands the model's ``batch_stats``
+    back moved -- BatchNorm's running moments, a router's bias and its
+    counters -- however state and batch are laid out: under jit the
+    batch axis is global, so loss and statistics are the one-device
+    step's to rounding (``make_train_step`` for the image task; the same
+    engine on a mesh of one device for the LM, which has no other)."""
+    axes, kw = MODES[mode]
+    if task == "image":
+        model, tx, state, *batch = setup(use_bn=True)
+        want_state, want_loss = make_train_step(model, tx, donate=False)(
+            state, *map(jnp.asarray, batch))
+    else:
+        model, tx, state, *batch = _lm_setup()
+        one_device = make_mesh({"data": 1}, devices=jax.devices()[:1])
+        one = PjitEngine(model, tx, one_device, task="lm", donate=False)
+        want_state, want_loss = one.train_step(
+            one.shard_state(state), *one.shard_batch(*batch))
+    eng = PjitEngine(model, tx, make_mesh(axes), task=task, donate=False,
+                     **kw)
+    new_state, loss = eng.train_step(
+        eng.shard_state(state), *eng.shard_batch(*batch))
+    np.testing.assert_allclose(float(loss), float(want_loss), rtol=1e-5)
+    stats, was = new_state.batch_stats, state.batch_stats
+    assert jax.tree.structure(stats) == jax.tree.structure(was)
+    assert jax.tree.leaves(stats)
+    moved = jax.tree.map(lambda a, b: bool((a != b).any()), stats, was)
+    if task == "lm":
+        assert int(stats["moe"]["steps"]) == 1
+        moved["moe"].pop("rows_dropped")  # nothing dropped: local_rows is T k
+    assert all(jax.tree.leaves(moved)), moved
+    jax.tree.map(
+        lambda a, b: np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6),
+        stats, want_state.batch_stats)

@@ -335,8 +335,9 @@ def _convnet_state():
     return model, tx, state
 
 
-@pytest.mark.parametrize("engine", ["one_chip", "data_parallel", "pjit_lm",
-                                    "pjit_lm_compressed"])
+@pytest.mark.parametrize("engine", [
+    "one_chip", "data_parallel", "data_parallel_zero",
+    "data_parallel_compressed", "pjit_lm"])
 def test_compiled_steps_carry_loss_grad_sync_and_optimizer_scopes(
         engine, registry, dark):
     images = jnp.zeros((4, 28, 28, 1))
@@ -347,11 +348,14 @@ def test_compiled_steps_carry_loss_grad_sync_and_optimizer_scopes(
         model, tx, state = _convnet_state()
         lowered = make_train_step(model, tx).lower(state, images, labels)
         want = {"loss", "optimizer"}
-    elif engine == "data_parallel":
+    elif engine.startswith("data_parallel"):
         from tpu_sandbox.parallel import DataParallel
 
+        # each of the engine's three sync forms stands under ``grad_sync``
         model, tx, state = _convnet_state()
-        dp = DataParallel(model, tx, _mesh2())
+        dp = DataParallel(
+            model, tx, _mesh2(), zero=engine.endswith("_zero"),
+            grad_compress="bf16" if engine.endswith("_compressed") else "none")
         lowered = dp.lower_step(dp.shard_state(state),
                                 *dp.shard_batch(images, labels))
         want = {"loss", "grad_sync", "optimizer"}
@@ -366,14 +370,12 @@ def test_compiled_steps_carry_loss_grad_sync_and_optimizer_scopes(
         model, tx = TransformerLM(cfg), optax.adam(1e-3)
         tokens = jnp.zeros((2, 8), jnp.int32)
         state = TrainState.create(model, jax.random.key(0), tokens[:1], tx)
-        compressed = engine == "pjit_lm_compressed"
-        eng = PjitEngine(model, tx, _mesh2(), task="lm",
-                         grad_compress="bf16" if compressed else "none")
+        eng = PjitEngine(model, tx, _mesh2(), task="lm")
         lowered = eng.lower_step(eng.shard_state(state),
                                  *eng.shard_batch(tokens, tokens))
-        # without compression XLA inserts the gradient all-reduce itself:
-        # there is no gradient mean in the program to scope
-        want = {"loss", "optimizer"} | ({"grad_sync"} if compressed else set())
+        # XLA inserts the gradient all-reduce itself: there is no gradient
+        # mean in the program to scope
+        want = {"loss", "optimizer"}
     names = _op_names(lowered)
     for scope in want:
         assert _has_scope(names, scope), (engine, scope)

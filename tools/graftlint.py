@@ -73,7 +73,7 @@ def main(argv=None) -> int:
                    help="pass 2 without the chipless AOT compiles "
                         "(donation reported as skipped)")
     p.add_argument("--steps",
-                   default="dp,zero,pjit,pipeline,dp-int8,dp-overlap,"
+                   default="dp,zero,pjit,pipeline,dp-int8,"
                            "sp,decode,prefill,fsdp,tp,ep,mpmd",
                    help="pass 2 step functions to trace")
     args = p.parse_args(argv)

@@ -6,8 +6,8 @@ the CLI and ``analysis/baseline.toml`` for the ratchet):
 - Pass 1 (:mod:`.collective_pass`) — AST collective-consistency: the
   SPMD-divergence deadlock class (rules GL-C1xx).
 - Pass 2 (:mod:`.hlo_pass`) — jaxpr + chipless AOT HLO lint of the real
-  step functions: donation, upcasts, host transfers, overlap schedule,
-  int8 padding (rules GL-H2xx).
+  step functions: donation, upcasts, host transfers, int8 padding
+  (rules GL-H2xx).
 - Pass 3 (:mod:`.control_pass`) — control-plane AST lint over
   ``runtime/``: claim scoping, clock-skew stamp math, thread hygiene,
   leader-section blocking reads (rules GL-R3xx).

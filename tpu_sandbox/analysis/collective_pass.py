@@ -11,8 +11,8 @@ Mechanics (pure ``ast``, no imports of the scanned code):
 - Collective call sites are recognized by *name*: ``lax.pmean``/``psum``/
   ``psum_scatter``/``all_gather``/``ppermute``/``all_to_all``, the
   :class:`~tpu_sandbox.parallel.collectives.CollectiveGroup` method
-  surface, and the bucketed/compressed sync entry points
-  (``sync_buckets``, ``pmean_tree``, ``int8_block_pmean``).
+  surface, and the compressed sync entry points
+  (``pmean_tree``, ``int8_block_pmean``).
 - Rank-likeness of a condition is a token scan of the test expression:
   identifiers/attributes such as ``rank``, ``process_index``, ``coords``,
   or calls to ``lax.axis_index`` / ``jax.process_index``.
@@ -32,13 +32,13 @@ import os
 from tpu_sandbox.analysis.findings import Finding, make_finding
 
 #: Call names that ARE collectives (jax.lax spellings + this repo's
-#: CollectiveGroup methods + the bucketed/compressed sync entry points).
+#: CollectiveGroup methods + the compressed sync entry points).
 COLLECTIVE_NAMES = frozenset({
     "pmean", "psum", "psum_scatter", "pmax", "pmin",
     "all_gather", "ppermute", "all_to_all", "pshuffle",
     "all_reduce", "reduce_scatter", "broadcast", "shift",
     "compressed_all_reduce",
-    "sync_buckets", "pmean_tree", "int8_block_pmean",
+    "pmean_tree", "int8_block_pmean",
 })
 
 #: Identifier / attribute tokens that mark a condition as rank-derived.
@@ -56,7 +56,7 @@ _EXCLUDE_DIRS = {
 
 def _call_name(func: ast.AST) -> str | None:
     """Trailing name of a call target: ``lax.pmean`` -> 'pmean',
-    ``group.all_reduce`` -> 'all_reduce', ``sync_buckets`` -> itself."""
+    ``group.all_reduce`` -> 'all_reduce', ``pmean_tree`` -> itself."""
     if isinstance(func, ast.Attribute):
         return func.attr
     if isinstance(func, ast.Name):

@@ -72,16 +72,10 @@ RULES: dict[str, tuple[str, str]] = {
         "callbacks/infeed/outfeed serialize the step on host round-trips; "
         "move the host work outside the jit or behind io_callback batching",
     ),
-    "GL-H204": (
-        "grad-sync collectives all scheduled after the last backward op",
-        "overlap_grad_sync is on but XLA issued no all-reduce before the "
-        "last backward compute: nothing can hide under compute — check "
-        "bucket_mb and the latency-hiding compiler flags",
-    ),
     "GL-H205": (
         "int8 block padding waste above threshold",
         "block/axis alignment padding dominates the int8 wire payload; "
-        "lower CompressedAllReduce.block or fuse small leaves into buckets",
+        "lower CompressedAllReduce.block",
     ),
     # -- Pass 3: control-plane lint ------------------------------------------
     "GL-R301": (

@@ -3,18 +3,17 @@
 Two layers, split so the cheap one is always available:
 
 - **Pure functions** (``lint_jaxpr``, ``lint_hlo_text``,
-  ``lint_schedule``, ``lint_int8_padding``) take already-built artifacts
+  ``lint_int8_padding``) take already-built artifacts
   and emit findings. They import nothing heavy — the fixture tests drive
   them directly.
 - **The driver** (:func:`run_hlo_pass`) builds the real engines chipless
   and feeds them through: it traces ``DataParallel`` (plain, ZeRO, and
-  the int8-grad-compress / bucketed-overlap flag variants),
+  the int8-grad-compress flag variant),
   ``PjitEngine``, ``PipelineParallel``, ``SeqParallel``, and the serve
   decode + bucketed-prefill steps to jaxprs on CPU
   devices, then AOT-compiles the DP/ZeRO steps against a multi-chip v5e
   topology (``tools/aot_v5e.make_topology``) to verify input donation
-  from XLA's own ``memory_analysis`` and to check the overlapped
-  grad-sync schedule via ``tools/hlo_schedule.schedule_report``.
+  from XLA's own ``memory_analysis``.
 
 The driver mutates process env (``make_topology`` forces compiled
 Pallas kernels) — run it in a dedicated process (the ``graftlint`` CLI),
@@ -167,25 +166,6 @@ def lint_donation(label: str, *, donate_requested: bool, alias_bytes: int,
     return [], entry
 
 
-def lint_schedule(report: dict, label: str, *, overlap: bool) -> list[Finding]:
-    """GL-H204 from a ``tools/hlo_schedule.schedule_report`` dict: overlap
-    was requested but every grad all-reduce issues after the last backward
-    compute op — nothing can hide under compute."""
-    if not overlap:
-        return []
-    issues = report.get("all_reduce_issues_before_last_bwd_compute", 0)
-    n_coll = report.get("collective_count", 0)
-    if n_coll and not issues:
-        return [make_finding(
-            "GL-H204", f"<step:{label}>", 0,
-            f"overlap_grad_sync requested but 0 of {n_coll} collectives "
-            "issue before the last backward compute op",
-            snippet=f"all_reduce_issues_before_last_bwd_compute=0 "
-                    f"collective_count={n_coll}",
-        )]
-    return []
-
-
 def lint_int8_padding(leaf_sizes, size: int, *, block: int = 256,
                       label: str = "dp",
                       threshold: float = INT8_OVERHEAD_THRESHOLD,
@@ -290,15 +270,12 @@ def _trace_targets(steps) -> tuple[list[Finding], dict]:
         )
         toks = jax.ShapeDtypeStruct((4, 64), jnp.int32)
         trace("pipeline", pp._compile_for(pstate), pstate, toks, toks)
-    # engine-flag variants: the same DP step graph is a different graph
-    # under grad compression / bucketed overlap, and each has had its own
-    # regression history — lint them as first-class steps
+    # engine-flag variant: the same DP step graph is a different graph
+    # under grad compression, with its own regression history — lint it
+    # as a first-class step
     if "dp-int8" in steps:
         dpc = DataParallel(model, tx, mesh, grad_compress="int8")
         trace("dp-int8", dpc._compile_for(state), state, imgs, labs)
-    if "dp-overlap" in steps:
-        dpo = DataParallel(model, tx, mesh, overlap_grad_sync=True)
-        trace("dp-overlap", dpo._compile_for(state), state, imgs, labs)
     if "sp" in steps:
         from tpu_sandbox.models.transformer import TransformerConfig
         from tpu_sandbox.models.transformer import TransformerLM
@@ -434,12 +411,11 @@ def _trace_targets(steps) -> tuple[list[Finding], dict]:
     return findings, report
 
 
-def _aot_targets(steps, *, topology: str, chips, overlap_check: bool,
+def _aot_targets(steps, *, topology: str, chips,
                  int8_check: bool) -> tuple[list[Finding], dict]:
-    """Donation + schedule + padding lint against a chipless v5e topology."""
+    """Donation + padding lint against a chipless v5e topology."""
     _tools_on_path()
     from aot_v5e import make_topology
-    from hlo_schedule import build_overlapped_hlo, schedule_report
 
     import jax
     import jax.numpy as jnp
@@ -487,17 +463,6 @@ def _aot_targets(steps, *, topology: str, chips, overlap_check: bool,
     if "zero" in steps:
         check_donation("zero", DataParallel(model, tx, mesh, zero=True))
 
-    if overlap_check:
-        text = build_overlapped_hlo(devices, bucket_mb=0.02, overlap=True)
-        sched = schedule_report(text)
-        findings.extend(lint_schedule(sched, "dp-overlap", overlap=True))
-        report["overlap_schedule"] = {
-            "collective_count": sched["collective_count"],
-            "issues_before_last_bwd":
-                sched["all_reduce_issues_before_last_bwd_compute"],
-            "exposed_comm_fraction": sched["exposed_comm_fraction"],
-        }
-
     if int8_check:
         leaf_sizes = [
             int(np.prod(l.shape)) for l in jax.tree.leaves(state.params)
@@ -510,12 +475,11 @@ def _aot_targets(steps, *, topology: str, chips, overlap_check: bool,
 
 def run_hlo_pass(
     *,
-    steps=("dp", "zero", "pjit", "pipeline", "dp-int8", "dp-overlap",
+    steps=("dp", "zero", "pjit", "pipeline", "dp-int8",
            "sp", "decode", "prefill", "fsdp", "tp", "ep", "mpmd"),
     aot: bool = True,
     topology: str = "v5e:2x2x1",
     chips=(2, 2, 1),
-    overlap_check: bool = True,
     int8_check: bool = True,
 ) -> tuple[list[Finding], dict]:
     """Full Pass 2. Returns ``(findings, report)``; ``report`` carries the
@@ -527,7 +491,7 @@ def run_hlo_pass(
         try:
             aot_findings, aot_report = _aot_targets(
                 steps, topology=topology, chips=chips,
-                overlap_check=overlap_check, int8_check=int8_check,
+                int8_check=int8_check,
             )
             findings.extend(aot_findings)
             report["aot"] = aot_report

@@ -1,4 +1,3 @@
-from tpu_sandbox.parallel.buckets import plan_buckets, sync_buckets  # noqa: F401
 from tpu_sandbox.parallel.collectives import (  # noqa: F401
     CollectiveGroup,
     CompressedAllReduce,

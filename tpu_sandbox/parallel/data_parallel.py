@@ -123,14 +123,13 @@ class DataParallel:
         state = dp.shard_state(state)               # replicate params, split BN
         state, loss = dp.train_step(state, images, labels)   # global batch
 
-    On a TPU mesh the plain step (no ``zero``, ``grad_compress`` or
-    ``overlap_grad_sync``) is compiled with
-    ``TPU_OVERLAP_COMPILER_OPTIONS``, so that its largest gradient's
-    all-reduce runs under the backward kernels. That raises the compiler's
-    scoped VMEM limit to 48 MiB and needs every Pallas kernel of the
-    backward pass to fit it beside the collective's 16 MiB; a model whose
-    kernels do not fails to compile (the constant's comment says how to
-    check a shape without a chip).
+    On a TPU mesh the plain step (no ``zero`` or ``grad_compress``) is
+    compiled with ``TPU_OVERLAP_COMPILER_OPTIONS``, so that its largest
+    gradient's all-reduce runs under the backward kernels. That raises the
+    compiler's scoped VMEM limit to 48 MiB and needs every Pallas kernel of
+    the backward pass to fit it beside the collective's 16 MiB; a model
+    whose kernels do not fails to compile (the constant's comment says how
+    to check a shape without a chip).
     """
 
     def __init__(
@@ -146,8 +145,6 @@ class DataParallel:
         donate: bool = True,
         grad_compress: str | CompressedAllReduce = "none",
         error_feedback: bool = True,
-        overlap_grad_sync: bool = False,
-        bucket_mb: float = 25.0,
     ):
         """``zero=True`` is ZeRO-1 (optimizer-state sharding): optimizer
         state lives sharded over the data axis (dim 0, leaves whose leading
@@ -177,18 +174,8 @@ class DataParallel:
         compressed mean replaces BOTH the psum_scatter and pmean branches:
         wire compression is kept, but the scatter-only half-volume trick is
         traded away (each rank slices its block from the full compressed
-        mean).
-
-        ``overlap_grad_sync`` buckets the gradient sync (DDP's reducer):
-        grads are grouped into ``bucket_mb``-targeted flat buffers
-        (parallel/buckets.py) and each bucket is one independent collective
-        (what the TPU compiler makes of them has not been measured: see
-        that module's docstring). Composes
-        with every ``grad_compress`` mode (buckets quantize as units, with
-        per-bucket error-feedback residuals that still checkpoint
-        leaf-shaped) and with ``zero`` (full bucketed mean, then each rank
-        slices its block — same trade as compression). Off by default:
-        overlap off + ``grad_compress='none'`` is the plain path."""
+        mean). ``grad_compress='none'`` without ``zero`` is the plain
+        path."""
         if axis not in mesh.axis_names:
             raise ValueError(f"axis {axis!r} not in mesh axes {mesh.axis_names}")
         self.model = model
@@ -206,14 +193,9 @@ class DataParallel:
                 mode=str(grad_compress) if grad_compress else "none",
                 error_feedback=error_feedback,
             )
-        if bucket_mb <= 0:
-            raise ValueError(f"bucket_mb must be positive, got {bucket_mb}")
-        self.overlap = bool(overlap_grad_sync)
-        self.bucket_bytes = int(bucket_mb * 2 ** 20)
-        # the plain sync: one float32 pmean of the gradients, no bucket, no
+        # the plain sync: one float32 pmean of the gradients, no
         # compression, no sharded update
-        self._plain_sync = not (zero or self.overlap
-                                or self.compress.mode != "none")
+        self._plain_sync = not (zero or self.compress.mode != "none")
         self._build(donate)
 
     def _dim0_sharded(self, leaf) -> bool:
@@ -470,7 +452,6 @@ class DataParallel:
         image_size, average_loss = self.image_size, self.average_loss
         zero, size, dim0_sharded = self.zero, self.size, self._dim0_sharded
         compress = self.compress
-        overlap, bucket_bytes = self.overlap, self.bucket_bytes
         plain = self._plain_sync
 
         def loss_fn(params, batch_stats, images, labels):
@@ -494,28 +475,20 @@ class DataParallel:
                 state.params, local_stats, images, labels
             )
             new_residual = state.grad_residual
-            if overlap or compress.mode != "none":
+            if compress.mode != "none":
                 # Sync happens ONCE here for every leaf; the branches below
                 # then consume already-mean'd grads. (Under ZeRO this
                 # supersedes the psum_scatter half-volume trick — the wire
-                # carries the bucketed/compressed payload instead.)
+                # carries the compressed payload instead.)
                 local_res = (
                     jax.tree.map(lambda x: x[0], state.grad_residual)
                     if compress.needs_residual
                     else None
                 )
                 with jax.named_scope("grad_sync"):
-                    if overlap:
-                        from tpu_sandbox.parallel.buckets import sync_buckets
-
-                        grads, new_res = sync_buckets(
-                            grads, axis, size, compress, residuals=local_res,
-                            bucket_bytes=bucket_bytes,
-                        )
-                    else:
-                        grads, new_res = compress.pmean_tree(
-                            grads, axis, size, local_res
-                        )
+                    grads, new_res = compress.pmean_tree(
+                        grads, axis, size, local_res
+                    )
                 if compress.needs_residual:
                     new_residual = jax.tree.map(lambda x: x[None], new_res)
             if zero:
@@ -536,8 +509,8 @@ class DataParallel:
                 params_blk = jax.tree.map(
                     lambda p, s: blk(p) if s else p, state.params, sharded
                 )
-                if overlap or compress.mode != "none":
-                    # already mean'd by the bucketed/compressed sync above —
+                if compress.mode != "none":
+                    # already mean'd by the compressed sync above —
                     # each rank just slices its own block
                     grads_blk = jax.tree.map(
                         lambda g, s: blk(g) if s else g, grads, sharded

@@ -28,7 +28,6 @@ alternative. Pick per experiment.
 from __future__ import annotations
 
 import re
-from functools import partial
 from typing import Callable, Sequence
 
 import jax
@@ -38,10 +37,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tpu_sandbox.obs import get_recorder
 from tpu_sandbox.ops.losses import cross_entropy_loss
-from tpu_sandbox.parallel.collectives import (
-    CompressedAllReduce,
-    as_compress_policy,
-)
 from tpu_sandbox.train.state import TrainState
 
 Rule = tuple[str, P]
@@ -159,9 +154,9 @@ def state_specs(state: TrainState, rules: Sequence[Rule],
         params=pspecs,
         batch_stats=jax.tree.map(lambda _: P(), state.batch_stats),
         opt_state=jax.tree_util.tree_map_with_path(opt_spec, state.opt_state),
-        # this engine never threads an error-feedback residual (grad
-        # compression here is stateless); mirror the (normally empty) node
-        # so pytree structures line up whatever state is handed in
+        # this engine syncs no compressed gradient and threads no
+        # error-feedback residual; it mirrors the (normally empty) node
+        # so a state that came from ``DataParallel`` keeps its structure
         grad_residual=jax.tree.map(lambda _: P(), state.grad_residual),
     )
 
@@ -192,9 +187,6 @@ class PjitEngine:
         zero_axis: str | None = None,
         fsdp_axis: str | None = None,
         donate: bool = True,
-        grad_compress: str | CompressedAllReduce = "none",
-        overlap_grad_sync: bool = False,
-        bucket_mb: float = 25.0,
     ):
         if task not in ("image", "lm"):
             raise ValueError(f"task must be 'image' or 'lm', got {task!r}")
@@ -243,39 +235,6 @@ class PjitEngine:
         self.zero_axis = zero_axis
         self.fsdp_axis = fsdp_axis
         self.donate = donate
-        # Compressed/bucketed grad sync needs the gradients to cross
-        # exactly ONE mesh axis (the batch axis) in a known place, so it is
-        # spelled as an explicit shard_map wrapped around the grad
-        # computation. That only composes with pure data parallelism: under
-        # TP rules / FSDP / spatial input specs, XLA owns where the
-        # collectives go and we cannot intercept them. zero_axis is fine
-        # (the sharding mismatch is between replicated grads and sharded
-        # moments, downstream of the sync). Stateless here: no
-        # error-feedback residual — use DataParallel for int8 + error
-        # feedback.
-        self.grad_compress = as_compress_policy(grad_compress)
-        if bucket_mb <= 0:
-            raise ValueError(f"bucket_mb must be positive, got {bucket_mb}")
-        self.overlap = bool(overlap_grad_sync)
-        self.bucket_bytes = int(bucket_mb * 2 ** 20)
-        if self.grad_compress.mode != "none" or self.overlap:
-            what = ("grad_compress" if self.grad_compress.mode != "none"
-                    else "overlap_grad_sync")
-            if self.rules:
-                raise ValueError(
-                    f"{what} composes only with pure data parallelism; "
-                    f"drop the TP rules or disable {what}"
-                )
-            if self.fsdp_axis is not None:
-                raise ValueError(
-                    f"{what} does not compose with fsdp_axis (FSDP's "
-                    "reduce-scatter is compiler-inserted)"
-                )
-            if self.input_spec != P(self.batch_axis):
-                raise ValueError(
-                    f"{what} needs input_spec == P({self.batch_axis!r}) "
-                    f"(batch-only sharding), got {self.input_spec}"
-                )
         self._jitted: Callable | None = None
 
     def _state_specs(self, state: TrainState) -> TrainState:
@@ -361,88 +320,26 @@ class PjitEngine:
                     loss = cross_entropy_loss(logits, labels)
                 return loss, mutated.get("batch_stats", {})
 
-        compress = self.grad_compress
-        overlap, bucket_bytes = self.overlap, self.bucket_bytes
-        if compress.mode != "none" or overlap:
-            if jax.tree.leaves(state.batch_stats):
-                raise ValueError(
-                    "grad_compress/overlap_grad_sync under PjitEngine "
-                    "requires a BN-free model: batch stats mutate per data "
-                    "shard inside the grad shard_map and cannot be returned "
-                    "replicated. Use DataParallel (per-replica BN) instead."
-                )
-            from jax import lax
-
-            axis = self.batch_axis
-            size = self.mesh.shape[axis]
-
-            def grads_body(params, images, labels):
-                (loss, _), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True
-                )(params, {}, images, labels)
-                with jax.named_scope("grad_sync"):
-                    if overlap:
-                        from tpu_sandbox.parallel.buckets import sync_buckets
-
-                        grads, _ = sync_buckets(
-                            grads, axis, size, compress,
-                            bucket_bytes=bucket_bytes,
-                        )
-                    else:
-                        grads, _ = compress.pmean_tree(
-                            grads, axis, size, None)
-                return lax.pmean(loss, axis), grads
-
-            grads_fn = jax.shard_map(
-                grads_body,
-                mesh=self.mesh,
-                in_specs=(P(), P(axis), P(axis)),
-                out_specs=(P(), P()),
-                check_vma=False,  # grads are replicated by the compressed
-                # pmean; the static analysis can't see through it
+        def step(state: TrainState, images, labels):
+            if image_size is not None and self.task == "image":
+                from tpu_sandbox.train import prepare_inputs
+                images = prepare_inputs(model, images, image_size)
+            (loss, new_stats), grads = jax.value_and_grad(
+                loss_fn, has_aux=True
+            )(state.params, state.batch_stats, images, labels)
+            with jax.named_scope("optimizer"):
+                updates, new_opt = tx.update(
+                    grads, state.opt_state, state.params)
+                new_params = optax.apply_updates(state.params, updates)
+            return (
+                state.replace(
+                    step=state.step + 1,
+                    params=new_params,
+                    batch_stats=new_stats,
+                    opt_state=new_opt,
+                ),
+                loss,
             )
-
-            def step(state: TrainState, images, labels):
-                if image_size is not None and self.task == "image":
-                    from tpu_sandbox.train import prepare_inputs
-                    images = prepare_inputs(model, images, image_size)
-                loss, grads = grads_fn(state.params, images, labels)
-                with jax.named_scope("optimizer"):
-                    updates, new_opt = tx.update(
-                        grads, state.opt_state, state.params
-                    )
-                    new_params = optax.apply_updates(state.params, updates)
-                return (
-                    state.replace(
-                        step=state.step + 1,
-                        params=new_params,
-                        opt_state=new_opt,
-                    ),
-                    loss,
-                )
-
-        else:
-
-            def step(state: TrainState, images, labels):
-                if image_size is not None and self.task == "image":
-                    from tpu_sandbox.train import prepare_inputs
-                    images = prepare_inputs(model, images, image_size)
-                (loss, new_stats), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True
-                )(state.params, state.batch_stats, images, labels)
-                with jax.named_scope("optimizer"):
-                    updates, new_opt = tx.update(
-                        grads, state.opt_state, state.params)
-                    new_params = optax.apply_updates(state.params, updates)
-                return (
-                    state.replace(
-                        step=state.step + 1,
-                        params=new_params,
-                        batch_stats=new_stats,
-                        opt_state=new_opt,
-                    ),
-                    loss,
-                )
 
         specs = self._state_specs(state)
         to_sh = lambda tree: jax.tree.map(self._sharding, tree)  # noqa: E731

@@ -756,7 +756,7 @@ def train_resumable(
     :class:`~tpu_sandbox.data.loader.PrefetchLoader`. The prefetcher's
     determinism contract (same batches, same order, delegated
     ``set_epoch``) keeps the (epoch, offset) checkpoint metadata exact, so
-    resume parity is unchanged — tested in tests/test_overlap.py.
+    resume parity is unchanged — tested in tests/test_prefetch.py.
     """
     loader = _maybe_prefetch(loader, prefetch)
     steps_per_epoch = len(loader)
