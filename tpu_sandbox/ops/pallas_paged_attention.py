@@ -26,6 +26,15 @@ types (bf16 on the MXU where both are), scores, running maximum, sum and
 output accumulate in float32 across a row's chunks (online softmax), scale
 ``1 / sqrt(D)``.
 
+**A latent cache** (``v_pages`` None, ``v_dim``; ``serve/decode.py``'s
+third family): the pages hold one row a position, shared by all the query
+heads -- ``[c | k_pe | padding]`` of MLA's absorbed form -- and the values
+are **the first ``v_dim`` lanes of the same row**. One buffer is copied, the
+scores read the row whole (the queries carry zeros over the padding), the
+second product reads its first ``v_dim`` lanes in VMEM, and the output is
+``[B, Hq, v_dim]``. The scale is the caller's (``1 / sqrt(192)`` where the
+row is 576 wide).
+
 What lies behind ``lengths[b]`` never reaches the output, whatever it is:
 those scores are replaced before the maximum, and the values' rows there
 are zeroed in VMEM before the second product (a weight of 0 times a NaN is
@@ -55,9 +64,15 @@ from tpu_sandbox.ops.pallas_common import (
 _F32 = jnp.float32
 
 
-def _kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
-            k_buf, v_buf, sems, state, *, pages: int, max_blocks: int,
-            hkv: int, group: int, precision):
+def _kernel(tables_ref, lengths_ref, q_ref, k_hbm, *rest, pages: int,
+            max_blocks: int, hkv: int, group: int, precision, scale: float,
+            v_dim: int | None):
+    # a latent cache has no V pages: the values are lanes of the K row
+    if v_dim is None:
+        v_hbm, o_ref, k_buf, v_buf, sems, state = rest
+    else:
+        v_hbm = v_buf = None
+        o_ref, k_buf, sems, state = rest
     b, rows = pl.program_id(0), pl.num_programs(0)
     _, bs, width = k_hbm.shape
     hd = width // hkv
@@ -76,8 +91,9 @@ def _kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
             at = pl.ds(pl.multiple_of(j * bs, bs), bs)
             act(pltpu.make_async_copy(
                 k_hbm.at[block], k_buf.at[slot, at], sems.at[0, slot]))
-            act(pltpu.make_async_copy(
-                v_hbm.at[block], v_buf.at[slot, at], sems.at[1, slot]))
+            if v_hbm is not None:
+                act(pltpu.make_async_copy(
+                    v_hbm.at[block], v_buf.at[slot, at], sems.at[1, slot]))
             return carry
 
         jax.lax.fori_loop(0, live, page, 0)
@@ -114,7 +130,9 @@ def _kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
                       0.0)
             for g in range(group)], axis=0).astype(qg.dtype)  # [G * Hkv, W]
     heads = group * hkv
-    scale = 1.0 / (hd ** 0.5)
+    out_width = width if v_dim is None else v_dim
+    # the buffer the values are read from, and zeroed behind the length in
+    values = k_buf if v_dim is not None else v_buf
 
     def chunk(i, carry):
         m, l, acc = carry
@@ -145,10 +163,11 @@ def _kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
         @pl.when(left < tokens)
         def _():
             held = jax.lax.broadcasted_iota(jnp.int32, (tokens, 1), 0) < left
-            v_buf[slot] = jnp.where(held, v_buf[slot], 0)
+            values[slot] = jnp.where(held, values[slot], 0)
 
         k = k_buf[slot].astype(qbd.dtype)                   # [T, W]
-        v = v_buf[slot].astype(qbd.dtype)
+        v = (k[:, :v_dim] if v_dim is not None
+             else v_buf[slot].astype(qbd.dtype))
         s = jax.lax.dot_general(
             qbd, k, (((1,), (1,)), ((), ())), precision=precision,
             preferred_element_type=_F32) * scale            # [heads, T]
@@ -166,7 +185,7 @@ def _kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
     m, l, acc = jax.lax.fori_loop(
         0, n_chunks, chunk,
         (jnp.full((heads, 1), NEG, _F32), jnp.zeros((heads, 1), _F32),
-         jnp.zeros((heads, width), _F32)))
+         jnp.zeros((heads, out_width), _F32)))
     out = acc / jnp.maximum(l, 1e-30)
     if own is not None:       # each row's own head's lanes, heads side by side
         out = jnp.concatenate([
@@ -178,36 +197,45 @@ def _kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 @traced_once
 def _paged_attn(q, k_pages, v_pages, block_tables, lengths, *,
-                pages_per_step, interpret):
+                pages_per_step, interpret, scale=None, v_dim=None):
     bsz, hq, hd = q.shape
     _, bs, width = k_pages.shape
-    hkv = width // hd
+    latent = v_pages is None
+    # a latent row is every query head's: one "head" as wide as the row
+    hkv = 1 if latent else width // hd
     group = hq // hkv
     dtype = jnp.promote_types(q.dtype, k_pages.dtype)
-    # a key/value head's group of query heads in rows, the heads in lanes
-    qg = (q.reshape(bsz, hkv, group, hd).swapaxes(1, 2)
-          .reshape(bsz, group, width).astype(dtype))
+    if latent:  # zeros over the row's padding lanes
+        qg = jnp.pad(q, ((0, 0), (0, 0), (0, width - hd))).astype(dtype)
+    else:
+        # a key/value head's group of query heads in rows, the heads in
+        # lanes
+        qg = (q.reshape(bsz, hkv, group, hd).swapaxes(1, 2)
+              .reshape(bsz, group, width).astype(dtype))
+    out_width = v_dim if latent else width
     tokens = pages_per_step * bs
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    kv_buffer = pltpu.VMEM((2, tokens, width), k_pages.dtype)
     with kernel_site("paged_attn"):
         out = pl.pallas_call(
             functools.partial(
                 _kernel, pages=pages_per_step,
                 max_blocks=block_tables.shape[1], hkv=hkv, group=group,
                 precision=(jax.lax.Precision.HIGHEST if dtype == _F32
-                           else None)),
-            out_shape=jax.ShapeDtypeStruct((bsz, group, width), q.dtype),
+                           else None),
+                scale=(hd ** -0.5 if scale is None else scale),
+                v_dim=v_dim if latent else None),
+            out_shape=jax.ShapeDtypeStruct((bsz, group, out_width), q.dtype),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2, grid=(bsz,),
                 in_specs=[
                     pl.BlockSpec((1, group, width),
                                  lambda b, tables, lens: (b, 0, 0)),
-                    pl.BlockSpec(memory_space=pl.ANY),
-                    pl.BlockSpec(memory_space=pl.ANY)],
-                out_specs=pl.BlockSpec((1, group, width),
+                    hbm, *(() if latent else (hbm,))],
+                out_specs=pl.BlockSpec((1, group, out_width),
                                        lambda b, tables, lens: (b, 0, 0)),
                 scratch_shapes=[
-                    pltpu.VMEM((2, tokens, width), k_pages.dtype),
-                    pltpu.VMEM((2, tokens, width), v_pages.dtype),
+                    kv_buffer, *(() if latent else (kv_buffer,)),
                     pltpu.SemaphoreType.DMA((2, 2)),
                     pltpu.SMEM((2,), jnp.int32)]),
             # a row hands the next one its first chunk's copies
@@ -215,20 +243,32 @@ def _paged_attn(q, k_pages, v_pages, block_tables, lengths, *,
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,
         )(block_tables.reshape(-1).astype(jnp.int32),
-          lengths.astype(jnp.int32), qg, k_pages, v_pages)
+          lengths.astype(jnp.int32), qg, k_pages,
+          *(() if latent else (v_pages,)))
+    if latent:
+        return out
     return (out.reshape(bsz, group, hkv, hd).swapaxes(1, 2)
             .reshape(bsz, hq, hd))
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
-                    pages_per_step: int, interpret: bool | None = None):
+                    pages_per_step: int, interpret: bool | None = None,
+                    scale: float | None = None, v_dim: int | None = None):
     """The attention output ``[B, Hq, D]`` (``q``'s type) of ``q [B, Hq,
     D]`` over each row's first ``lengths[b]`` cached positions: ``k_pages``,
     ``v_pages`` ``[num_blocks, block_size, Hkv * D]`` through
     ``block_tables [B, max_blocks]``, ``pages_per_step`` pages a compute
     step (module docstring). The width ``Hkv * D`` is a multiple of the 128
     lanes and ``block_size`` of the cache type's sublane tile
-    (``serve/decode.py::pages_per_step`` holds the rule)."""
+    (``serve/decode.py::pages_per_step`` holds the rule). ``scale``: ``1 /
+    sqrt(D)`` where None.
+
+    ``v_pages`` None: a latent cache. ``q [B, Hq, d]`` with ``d`` at most
+    the pages' width, every query head against the same row, the values the
+    row's first ``v_dim`` lanes: ``[B, Hq, v_dim]``."""
+    if v_pages is None and not v_dim:
+        raise ValueError("a latent cache's values need their width (v_dim)")
     return _paged_attn(q, k_pages, v_pages, block_tables, lengths,
                        pages_per_step=pages_per_step,
-                       interpret=default_interpret(interpret))
+                       interpret=default_interpret(interpret), scale=scale,
+                       v_dim=v_dim)

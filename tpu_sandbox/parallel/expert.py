@@ -110,12 +110,17 @@ class MoeMlp(nn.Module):
 
 # --- a share of the experts, with device work that follows shapes alone ---
 
-def router_scores(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
-    """Sigmoid router scores ``[T, E]`` in float32 at full matmul precision
-    (on a TPU a float32 product is otherwise rounded to bf16 passes)."""
-    return jax.nn.sigmoid(jnp.dot(
-        x.astype(jnp.float32), w.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
+def router_scores(x: jnp.ndarray, w: jnp.ndarray,
+                  rule: str = "sigmoid") -> jnp.ndarray:
+    """Router scores ``[T, E]`` in float32 at full matmul precision (on a
+    TPU a float32 product is otherwise rounded to bf16 passes): the
+    sigmoid of every output, or (``rule`` ``softmax``) the softmax over
+    all of them."""
+    logits = jnp.dot(x.astype(jnp.float32), w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    if rule == "softmax":
+        return jax.nn.softmax(logits, axis=-1)
+    return jax.nn.sigmoid(logits)
 
 
 def share_rows(tokens: int, top_k: int, n_held: int, n_experts: int,
@@ -125,6 +130,20 @@ def share_rows(tokens: int, top_k: int, n_held: int, n_experts: int,
     step of ``tokens`` tokens, rounded up to the row tile."""
     mean_rows = tokens * top_k * n_held / n_experts
     return int(math.ceil(factor * mean_rows / row_tile)) * row_tile
+
+
+def share_row_tile(tokens: int, top_k: int, n_held: int, n_experts: int,
+                   factor: float, cap: int = 256) -> int:
+    """The row tile of a share whose calls differ in size (a served model:
+    a prompt of thousands of tokens, then a decode step of one a session):
+    the power of two that holds all of R (``share_rows``), between the
+    bfloat16 sublane tile 16 and ``cap``, the tile of a training step.
+    Every tile of the buffer is multiplied by an expert's whole matrices
+    (``ops/pallas_grouped_matmul.py``), each held expert owns one tile at
+    least, and what R adds are tiles more: where R is one tile, a step
+    reads each held expert's matrices once and one expert's once more."""
+    rows = max(1.0, factor * tokens * top_k * n_held / n_experts)
+    return int(min(cap, max(16, 2 ** math.ceil(math.log2(rows)))))
 
 
 def _members(sel: jnp.ndarray, n_experts: int, values=None) -> jnp.ndarray:
@@ -165,8 +184,9 @@ def _chosen_bwd(res, g):
 _chosen.defvjp(_chosen_fwd, _chosen_bwd)
 
 
-def _count_table(tokens: int, top_k: int, held: int, width: int,
-                 buffer_rows: int, c: int) -> None:
+def count_share_table(tokens: int, top_k: int, held: int, width: int,
+                      buffer_rows: int, c: int,
+                      collect: str = "gather") -> None:
     """One count a traced ``routed`` call site, through the helper every
     kernel's site counts by. The share's own work is gathers and a sort,
     no kernel, so no ``trace:kernel`` span opens here; the grouped products
@@ -177,7 +197,7 @@ def _count_table(tokens: int, top_k: int, held: int, width: int,
     kernel_site("expert_share", get_registry().counter(
         "moe.share_table", labels={
             "tokens": tokens, "top_k": top_k, "held": held, "width": width,
-            "buffer_rows": buffer_rows, "c": c, "collect": "gather"}))
+            "buffer_rows": buffer_rows, "c": c, "collect": collect}))
 
 
 def _pick(table: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
@@ -319,11 +339,29 @@ class ExpertShare(nn.Module):
     experts are left out; nothing stands in for the other chips or their
     exchange.
 
-    Sigmoid scores in float32, top-k of ``score + bias`` (the bias,
-    ``e_score_correction_bias``, lives in ``batch_stats``: no gradient moves
-    it; each training step moves it by ``bias_update_rate`` towards the
-    experts that saw fewer tokens than the mean), weights the chosen scores
-    normalised to sum ``routed_scaling_factor``.
+    Scores in float32 by one of two rules (``score_rule``), top-k of
+    ``score + bias`` either way (the bias, ``e_score_correction_bias``,
+    lives in ``batch_stats``: no gradient moves it; each training step
+    moves it by ``bias_update_rate`` towards the experts that saw fewer
+    tokens than the mean; 0 leaves it, as a served model's):
+
+    - ``sigmoid_norm`` (Xing4, Nemotron): the sigmoid of every output, the
+      weights the chosen scores normalised to sum ``routed_scaling_factor``;
+    - ``softmax`` (LongCat-Flash): the softmax over all outputs, the
+      weights the chosen probabilities times ``routed_scaling_factor``,
+      **not** normalised over the choices.
+
+    **Zero-compute experts** (``n_zero_experts``): the router scores
+    ``n_routed_experts + n_zero_experts`` outputs, and a choice that falls
+    on one of the last ``n_zero_experts`` adds ``weight * x`` (the identity
+    expert) and takes no row of the buffer. Every chip computes that term
+    alike for its own tokens, like a shared expert: it is whole here, and
+    counted once where shares are summed. ``batch_stats`` then also counts
+    ``real_choices`` and ``zero_choices``.
+
+    The matrices (experts, shared expert) are held in ``param_dtype``
+    (float32 where a step trains them; bfloat16 where a served model holds
+    nothing else); the router's stays float32 for its float32 product.
 
     What a model may choose: the experts' ``kind`` (``gated_silu``: three
     products, ``down(silu(gate x) * up x)``; ``relu2``: two,
@@ -364,6 +402,9 @@ class ExpertShare(nn.Module):
     dtype: Any = jnp.bfloat16
     row_tile: int = 256
     kind: str = "gated_silu"
+    score_rule: str = "sigmoid_norm"
+    n_zero_experts: int = 0
+    param_dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
@@ -381,7 +422,8 @@ class ExpertShare(nn.Module):
         """One expert of this layer's ``kind`` as plain dense layers
         ``{prefix}_gate`` (gated only), ``{prefix}_up``, ``{prefix}_down``:
         ``x [T, C_in]`` -> ``[T, out]``."""
-        dense = functools.partial(nn.Dense, use_bias=False, dtype=self.dtype)
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=self.dtype,
+                                  param_dtype=self.param_dtype)
         u = dense(width, name=f"{prefix}_up")(x)
         if self.kind == "gated_silu":
             hidden = nn.silu(dense(width, name=f"{prefix}_gate")(x)) * u
@@ -394,12 +436,14 @@ class ExpertShare(nn.Module):
         """What the held experts give for ``x [T, d_model]``, the router
         scoring ``route_on [T, C_r]`` (``x`` if None). Moves the bias and
         the counters where ``batch_stats`` is mutable; sows ``sel``."""
-        from tpu_sandbox.ops.pallas_grouped_matmul import grouped_matmul
-
         if self.kind not in ("gated_silu", "relu2"):
             raise ValueError(f"unknown expert kind {self.kind!r}")
-        c = x.shape[-1]
-        t, k, e, h = x.shape[0], self.top_k, self.n_routed_experts, len(self.held)
+        if self.score_rule not in ("sigmoid_norm", "softmax"):
+            raise ValueError(f"unknown score rule {self.score_rule!r}")
+        t, k = x.shape[0], self.top_k
+        # the router's outputs: the routed experts, then the zero experts
+        n_real, zeros = self.n_routed_experts, self.n_zero_experts
+        e = n_real + zeros
         if self.local_rows % self.row_tile:
             raise ValueError(f"local_rows {self.local_rows} is not a multiple "
                              f"of the row tile {self.row_tile}")
@@ -409,24 +453,66 @@ class ExpertShare(nn.Module):
         counters = {name: self.variable("batch_stats", name, jnp.zeros, (),
                                         jnp.int32)
                     for name in ("rows_held", "rows_dropped",
-                                 "expert_rows_max", "steps")}
+                                 "expert_rows_max", "steps")
+                    + (("real_choices", "zero_choices") if zeros else ())}
 
         with jax.named_scope("router"):
             scored = x if route_on is None else route_on
             w_r = self.param("router", init, (scored.shape[-1], e), jnp.float32)
-            scores = router_scores(scored, w_r)                     # [T, E]
+            softmax = self.score_rule == "softmax"
+            scores = router_scores(scored, w_r,
+                                   "softmax" if softmax else "sigmoid")
             _, sel = jax.lax.top_k(
                 scores + jax.lax.stop_gradient(bias.value), k)      # [T, k]
             s_sel = _chosen(scores, sel)
-            weights = (s_sel / (s_sel.sum(-1, keepdims=True) + 1e-20)
-                       * self.routed_scaling_factor)
+            if softmax:
+                weights = s_sel * self.routed_scaling_factor
+            else:
+                weights = (s_sel / (s_sel.sum(-1, keepdims=True) + 1e-20)
+                           * self.routed_scaling_factor)
             counts = _members(sel, e).sum(0)                        # [E]
 
+        y, lay = self._held(x, sel, weights, init)
+
+        on_zero = sel >= n_real                                     # [T, k]
+        if zeros:
+            with jax.named_scope("zero"):
+                w_zero = jnp.where(on_zero, weights, 0).sum(-1, keepdims=True)
+                y = (y.astype(jnp.float32)
+                     + w_zero * x.astype(jnp.float32)).astype(self.dtype)
+
+        if not self.is_initializing() and self.is_mutable_collection(
+                "batch_stats"):
+            bias.value = bias.value + self.bias_update_rate * jnp.sign(
+                counts.mean() - counts)
+            counters["rows_held"].value += lay["rows_held"]
+            counters["rows_dropped"].value += lay["rows_dropped"]
+            counters["expert_rows_max"].value = jnp.maximum(
+                counters["expert_rows_max"].value, lay["expert_rows_max"])
+            counters["steps"].value += 1
+            if zeros:
+                n_zero = on_zero.sum().astype(jnp.int32)
+                counters["zero_choices"].value += n_zero
+                counters["real_choices"].value += t * k - n_zero
+        self.sow("intermediates", "sel", sel)
+        return y
+
+    @nn.nowrap
+    def _held(self, x, sel, weights, init):
+        """What the held experts give the tokens that chose them, over the
+        share's static row buffer: ``(y, counts)``, the counts ``rows_held``,
+        ``rows_dropped`` and ``expert_rows_max`` of ``share_layout``. (A
+        model whose call cannot live with a static buffer overrides this:
+        ``models/longcat_flash.py::PromptShare``.)"""
+        from tpu_sandbox.ops.pallas_grouped_matmul import grouped_matmul
+
+        c = x.shape[-1]
+        t, k, h = x.shape[0], self.top_k, len(self.held)
         with jax.named_scope("dispatch"):
             held = tuple(self.held)
             lay = share_layout(sel, held, self.local_rows, self.row_tile)
             m = lay["dest"].shape[1]
-            _count_table(t, k, h, m, lay["row_valid"].shape[0], c)
+            count_share_table(t, k, h, m, lay["row_valid"].shape[0], c)
             tok_r = lay["row_assignment"] // m
             back = (lay["dest"], lay["kept"])
             rows = _spread(x, tok_r, lay["row_valid"], *back)
@@ -442,7 +528,7 @@ class ExpertShare(nn.Module):
             group = lay["tile_group"]
 
             def product(name, rows, shape):
-                w = self.param(name, init, (h, *shape), jnp.float32)
+                w = self.param(name, init, (h, *shape), self.param_dtype)
                 return grouped_matmul(rows, w.astype(self.dtype), group,
                                       self.row_tile)
 
@@ -458,15 +544,4 @@ class ExpertShare(nn.Module):
         with jax.named_scope("combine"):
             out = (out.astype(jnp.float32) * w_rows).astype(self.dtype)
             y = _collect(out, *back, tok_r, lay["row_valid"])
-
-        if not self.is_initializing() and self.is_mutable_collection(
-                "batch_stats"):
-            bias.value = bias.value + self.bias_update_rate * jnp.sign(
-                counts.mean() - counts)
-            counters["rows_held"].value += lay["rows_held"]
-            counters["rows_dropped"].value += lay["rows_dropped"]
-            counters["expert_rows_max"].value = jnp.maximum(
-                counters["expert_rows_max"].value, lay["expert_rows_max"])
-            counters["steps"].value += 1
-        self.sow("intermediates", "sel", sel)
-        return y
+        return y, lay

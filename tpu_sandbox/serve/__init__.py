@@ -10,8 +10,10 @@ Layers (bottom up):
   single-token decode step, a model family at a time over one page store
   (``Pages``: a buffer an attention layer at the key/value heads —
   ``models/transformer.py``: every layer; ``models/jamba.py``: the
-  attention layers, and the Mamba layers' slot state beside them), both
-  donating the device buffers; replay-exact seeded sampling
+  attention layers, and the Mamba layers' slot state beside them;
+  ``models/longcat_flash.py``: a latent row a position an attention
+  sub-layer, no separate V, and the expert shares' counters beside them),
+  both donating the device buffers; replay-exact seeded sampling
   (``sample_token``).
 - ``engine``  — continuous-batching engine: admits/evicts sequences at
   decode-step granularity, preempts-to-requeue under block pressure, plus a

@@ -1,6 +1,6 @@
 """AOT-compiled static-shape prefill and single-token decode steps, a
 model family at a time (``build_decode_step`` picks by the configuration's
-type).
+type; three families: ``TransformerLM``, ``JambaLM``, ``LongcatFlashLM``).
 
 Both steps run the *unmodified* model — prefill taps per-layer K/V through
 the model's ``kv_cache`` sow collection, decode injects an ``attention_fn``
@@ -37,6 +37,23 @@ context stands under a ``gather_ctx`` scope inside ``block{i}/attn``, the
 write under ``write_kv``, and every site counts its form
 (``paged_attn.kernel_choice``).
 
+The third family (``LongcatFlashLM``: latent attention, rotary positions,
+routed experts) holds **a latent page buffer an attention sub-layer**
+(``page_shapes(..., latent=True)``: one row ``[c | k_pe]`` of
+``kv_lora_rank + qk_rope_head_dim`` = 576 values a position in 640 lanes, no
+heads, no separate V: ``v_pages`` is empty; two leaves a double layer).
+Prefill runs the prompt from position 0, attends in the *expanded* form and
+stores each sub-layer's rows (behind norm, scale and rotation); decode
+rotates the new token at ``lengths - 1``, writes its row and attends in the
+*absorbed* form over the pages (``_attend_latent``: the same shape rule,
+the same kernel with the values read out of the key row's first 512 lanes,
+the same scopes and counter, and ``mla.cache_layout``). The third donated
+buffer is the expert shares' device counters (``rows_held``,
+``rows_dropped``, ``real_choices``, ``zero_choices``, ``steps`` a layer: no
+transfer a step; whoever wants them reads them once), the router's bias
+rides with the weights (``params = {"params", "router_bias"}``), and the
+programs give the greedy pick as Jamba's do.
+
 A model with recurrent layers (``JambaLM``, ``recurrent``) holds beside the
 pages the Mamba layers' **slot state** (``models/jamba.py::state_shapes``:
 the scan's state and the convolution's last inputs of every decode slot, a
@@ -53,7 +70,7 @@ host only for one that samples; and it can dispatch the next decode call
 on those picks before it has read them (``DecodeStep.next_tokens``).
 
 What the steps hold to (tests/test_serve.py, tests/test_serve_jamba.py,
-tests/test_paged_attention.py):
+tests/test_serve_longcat.py, tests/test_paged_attention.py):
 
 - **Replay: same program, same bits.** A row's logits are a function of
   its own tokens, block table and length: not of its slot, of the rows
@@ -72,7 +89,8 @@ tests/test_paged_attention.py):
   cache's rounding with a bfloat16 one; nothing here is bitwise against
   another program, on XLA:CPU or anywhere else.
 - **On the chip the contract is the benchmark's**:
-  ``benchmark/reference/gpt2.py::compare_served`` (and ``jamba.py``'s) hold
+  ``benchmark/reference/gpt2.py::compare_served`` (``jamba.py``'s,
+  ``longcat_flash.py``'s) hold
   the served logits and tokens to limits of their own against a float32
   reference, in every run.
 
@@ -97,6 +115,7 @@ from tpu_sandbox.serve.cache import CacheConfig
 if TYPE_CHECKING:  # imported where it is built: a second of imports (the
     # Pallas kernels' modules) that a TransformerLM replica never needs
     from tpu_sandbox.models.jamba import JambaConfig
+    from tpu_sandbox.models.longcat_flash import LongcatFlashConfig
 
 
 def sample_token(logits_row: np.ndarray, *, seed: int, step_index: int,
@@ -197,18 +216,28 @@ class Pages(tuple):
 
 
 def page_shapes(cache_cfg: CacheConfig, n_layers: int, n_kv_heads: int,
-                head_dim: int, cache_dtype: Any) -> tuple[Pages, Pages]:
+                head_dim: int, cache_dtype: Any, *,
+                latent: bool = False) -> tuple[Pages, Pages]:
     """``(k_pages, v_pages)``: a buffer ``[num_blocks, block_size,
     n_kv_heads * head_dim]`` each of ``n_layers`` attention layers. A
     token's heads lie side by side in the minor dimension: with 64 there
     (``[..., 16, 64]``) the chip's compiler keeps a second layout of every
     buffer, blocks minor, and copies each out and back every step (96
-    copies of 134 MB at gpt2-medium, ``tools/aot_serve_step.py``)."""
+    copies of 134 MB at gpt2-medium, ``tools/aot_serve_step.py``).
+
+    ``latent``: the second geometry. A position leaves **one row** of
+    ``head_dim`` values (MLA's ``[c | k_pe]``: 576), no heads and no
+    separate V -- ``v_pages`` is empty, the values are lanes of the same
+    row -- padded to whole 128-lane tiles (640): the chip lays a minor
+    dimension out in whole tiles anyway, and a row that says so is one the
+    paged kernel can copy (``mla.cache_layout`` counts what that wastes)."""
+    if latent:
+        head_dim += -head_dim % 128
     page = jax.ShapeDtypeStruct(
         (cache_cfg.num_blocks, cache_cfg.block_size, n_kv_heads * head_dim),
         cache_dtype)
     pages = Pages(page for _ in range(n_layers))
-    return pages, pages
+    return pages, (Pages() if latent else pages)
 
 
 def buffer_shapes(model_cfg: TransformerConfig | JambaConfig,
@@ -221,6 +250,14 @@ def buffer_shapes(model_cfg: TransformerConfig | JambaConfig,
         return page_shapes(
             cache_cfg, model_cfg.n_layers, model_cfg.n_heads,
             model_cfg.d_model // model_cfg.n_heads, cache_dtype)
+    if _family(model_cfg) == "longcat":
+        from tpu_sandbox.models.longcat_flash import counter_shapes
+
+        # a latent page buffer an attention sub-layer, two a double layer;
+        # beside them the expert shares' counters
+        return (*page_shapes(cache_cfg, 2 * model_cfg.num_layers, 1,
+                             model_cfg.latent_dim, cache_dtype, latent=True),
+                counter_shapes(model_cfg))
     from tpu_sandbox.models.jamba import state_shapes
 
     return (*page_shapes(
@@ -294,8 +331,14 @@ def pages_per_step(width: int, block_size: int, cache_dtype: Any,
     itemsize = jnp.dtype(cache_dtype).itemsize
     if width % 128 or block_size % (32 // itemsize):
         return None
-    return max(1, min(_STEP_BYTES // (block_size * width * itemsize),
-                      _STEP_TOKENS // block_size, max_blocks))
+    pages = max(1, min(_STEP_BYTES // (block_size * width * itemsize),
+                       _STEP_TOKENS // block_size, max_blocks))
+    # the scores of a step are [heads, positions]: whole 128-lane tiles of
+    # positions where a step holds more than one (25 pages of a latent
+    # row's 640 lanes become 24)
+    if pages * block_size > 128 and block_size <= 128:
+        pages -= pages % (128 // block_size)
+    return pages
 
 
 def _attend_jnp(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
@@ -344,7 +387,8 @@ def _attend(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
             "kv_heads": n_kv_heads, "group": q.shape[1] // n_kv_heads,
             "head_dim": q.shape[2], "block_size": block_size,
             "pages_per_step": pages or 0,
-            "max_blocks": block_tables.shape[1], "batch": q.shape[0]}))
+            "max_blocks": block_tables.shape[1], "batch": q.shape[0],
+            "qk_dim": q.shape[2], "v_dim": q.shape[2]}))
     if pages is None:
         return _attend_jnp(q, k_pages, v_pages, block_tables, lengths,
                            n_kv_heads)
@@ -353,6 +397,56 @@ def _attend(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     with jax.named_scope("gather_ctx"):
         return paged_attention(q, k_pages, v_pages, block_tables, lengths,
                                pages_per_step=pages)
+
+
+def _attend_latent(q: jnp.ndarray, pages: jnp.ndarray,
+                   block_tables: jnp.ndarray, lengths: jnp.ndarray, *,
+                   qk_dim: int, v_dim: int, scale: float) -> jnp.ndarray:
+    """``_attend`` over a latent page buffer ``[blocks, block, lanes]``:
+    the absorbed queries ``q [B, Hq, qk_dim]`` (``[q~ | q_pe]``) against
+    each row's first ``lengths[b]`` cached rows ``[c | k_pe | padding]``,
+    the values their first ``v_dim`` lanes: ``[B, Hq, v_dim]``. The same
+    rule on the pages' shape and type picks the form (the kernel with
+    ``v_pages`` None, or the gathered rows through
+    ``models/longcat_flash.py::absorbed_attention``), under the same
+    ``gather_ctx`` scope, counted by the same counter (``kv_heads`` 1,
+    ``group`` the query heads) and by ``mla.cache_layout``."""
+    from tpu_sandbox.obs import get_registry
+    from tpu_sandbox.ops.pallas_common import kernel_site
+
+    _, block_size, lanes = pages.shape
+    step = pages_per_step(lanes, block_size, pages.dtype,
+                          block_tables.shape[1])
+    kernel_site("paged_attn", get_registry().counter(
+        "paged_attn.kernel_choice", labels={
+            "impl": "jnp" if step is None else "pallas",
+            "kv_heads": 1, "group": q.shape[1], "head_dim": lanes,
+            "block_size": block_size, "pages_per_step": step or 0,
+            "max_blocks": block_tables.shape[1], "batch": q.shape[0],
+            "qk_dim": qk_dim, "v_dim": v_dim}))
+    kernel_site("latent_cache", get_registry().counter(
+        "mla.cache_layout", labels={
+            "latent": v_dim, "rope": qk_dim - v_dim, "lanes": lanes,
+            "pad_lanes": lanes - qk_dim, "block_size": block_size}))
+    if step is None:
+        from tpu_sandbox.models.longcat_flash import absorbed_attention
+
+        with jax.named_scope("gather_ctx"):
+            rows = pages[block_tables].reshape(
+                block_tables.shape[0], -1, lanes)[..., :qk_dim]
+        return absorbed_attention(q, rows, lengths, v_dim=v_dim, scale=scale)
+    from tpu_sandbox.ops.pallas_paged_attention import paged_attention
+
+    with jax.named_scope("gather_ctx"):
+        return paged_attention(q, pages, None, block_tables, lengths,
+                               pages_per_step=step, scale=scale, v_dim=v_dim)
+
+
+def _store_latent(pages: jnp.ndarray, dest: jnp.ndarray, rows: jnp.ndarray):
+    """``_store`` of latent rows ``[n, qk_dim]``, zeros over the pages'
+    padding lanes."""
+    return _store(pages, dest, jnp.pad(
+        rows, ((0, 0), (0, pages.shape[-1] - rows.shape[-1]))))
 
 
 def make_prefill_fn(model_cfg: TransformerConfig):
@@ -419,7 +513,7 @@ def make_decode_fn(model_cfg: TransformerConfig, cache_cfg: CacheConfig):
     return jax.jit(serve_decode, donate_argnums=(1, 2))
 
 
-def _greedy_pick(logits: jnp.ndarray):
+def _greedy_pick(logits: jnp.ndarray, model: str = "JambaLM"):
     """``logits [..., vocab]`` float32 -> float32 ``[..., 3]``: the token
     (the first largest logit, as ``numpy.argmax`` on the host picks it, so
     a replay draws the same token; an id under 2^24 is exact in float32),
@@ -428,8 +522,9 @@ def _greedy_pick(logits: jnp.ndarray):
     process than 33.5 MB of logits do. As part of the model's head in a
     device trace. On the host the same is those logits brought over, an
     argmax over them and a float64 ``exp`` of every one (62 of 83 ms a step
-    at 128 x 65,536)."""
-    with jax.named_scope("JambaLM/lm_head"):
+    at 128 x 65,536). ``model``: the module the family's scopes start
+    with."""
+    with jax.named_scope(f"{model}/lm_head"):
         logits = logits.astype(jnp.float32)
         lse = jax.nn.logsumexp(logits, axis=-1)
         return jnp.stack([jnp.argmax(logits, axis=-1).astype(jnp.float32),
@@ -515,6 +610,104 @@ def make_jamba_decode_fn(model_cfg: JambaConfig, cache_cfg: CacheConfig):
     return jax.jit(serve_decode, donate_argnums=(1, 2, 3))
 
 
+def _longcat_variables(params: dict, counters: dict) -> dict:
+    """The model's variables from what the engine holds: the weights with
+    the router's bias a layer (``params``) and the shares' counters, the
+    third donated buffer."""
+    from tpu_sandbox.models.longcat_flash import join_stats
+
+    return {"params": params["params"],
+            "batch_stats": join_stats(params["router_bias"], counters)}
+
+
+def _longcat_counters(mutated: dict) -> dict:
+    from tpu_sandbox.models.longcat_flash import split_stats
+
+    return split_stats(mutated["batch_stats"])[1]
+
+
+def make_longcat_prefill_fn(model_cfg: LongcatFlashConfig):
+    """prefill(params, pages, (), counters, tokens[1, Lb], dest_idx[Lb],
+    last_pos[]) -> (next_logits[vocab], their greedy pick, pages, (),
+    counters). ``params`` is ``{"params", "router_bias"}``. The prompt runs
+    from position 0 and attends to itself in the expanded form; every
+    sub-layer's latent rows (``[c | k_pe]`` behind norm, scale and
+    rotation) go to its page buffer at ``dest_idx`` (as
+    ``make_prefill_fn``). Pages and counters are donated."""
+    from tpu_sandbox.models.longcat_flash import LongcatFlashLM
+
+    model = LongcatFlashLM(model_cfg)
+
+    def serve_prefill(params, pages, no_v, counters, tokens, dest_idx,
+                      last_pos):
+        logits, taps = model.apply(
+            _longcat_variables(params, counters), tokens, last_pos=last_pos,
+            mutable=["kv_cache", "batch_stats"])
+        pages = list(pages)
+        with jax.named_scope("write_kv"):
+            for i in range(model_cfg.num_layers):
+                for j in (0, 1):
+                    rows = taps["kv_cache"][f"block{i}"][f"mla{j}"]["latent"]
+                    pages[2 * i + j] = _store_latent(
+                        pages[2 * i + j], dest_idx, rows[0])
+        logits = logits[0, 0]
+        return (logits, _greedy_pick(logits, "LongcatFlashLM"), Pages(pages),
+                no_v, _longcat_counters(taps))
+
+    return jax.jit(serve_prefill, donate_argnums=(1, 2, 3))
+
+
+def make_longcat_decode_fn(model_cfg: LongcatFlashConfig,
+                           cache_cfg: CacheConfig):
+    """decode(params, pages, (), counters, tokens[B, 1], lengths[B],
+    block_tables[B, max_blocks]) -> (logits[B, vocab], every row's greedy
+    pick, pages, (), counters). ``lengths`` as in ``make_decode_fn``: the
+    token's **rotary position is ``lengths - 1``**, its latent row goes to
+    the null block where ``lengths == 0``, and every sub-layer attends in
+    the absorbed form (``_attend_latent``)."""
+    from tpu_sandbox.models.longcat_flash import LongcatFlashLM
+
+    scale = model_cfg.qk_head_dim ** -0.5
+
+    def serve_decode(params, pages, no_v, counters, tokens, lengths,
+                     block_tables):
+        pages = list(pages)
+        pos, dest = _decode_slots(cache_cfg, lengths, block_tables)
+        layer = itertools.count()
+
+        def attention_fn(q, row):
+            # q [B, H, 576] absorbed; row [B, 576]: the new token's latent
+            i = next(layer)
+            with jax.named_scope("write_kv"):
+                pages[i] = _store_latent(pages[i], dest, row)
+            return _attend_latent(
+                q, pages[i], block_tables, lengths,
+                qk_dim=model_cfg.latent_dim, v_dim=model_cfg.kv_lora_rank,
+                scale=scale)
+
+        model = LongcatFlashLM(model_cfg, attention_fn=attention_fn)
+        logits, mutated = model.apply(
+            _longcat_variables(params, counters), tokens, pos[:, None],
+            mutable=["batch_stats"])
+        logits = logits[:, 0]
+        return (logits, _greedy_pick(logits, "LongcatFlashLM"), Pages(pages),
+                no_v, _longcat_counters(mutated))
+
+    return jax.jit(serve_decode, donate_argnums=(1, 2, 3))
+
+
+def _family(model_cfg) -> str:
+    """The family of programs a configuration's type names."""
+    if isinstance(model_cfg, TransformerConfig):
+        return "transformer"
+    name = type(model_cfg).__name__
+    if name == "JambaConfig":
+        return "jamba"
+    if name == "LongcatFlashConfig":
+        return "longcat"
+    raise TypeError(f"no serving family for {name}")
+
+
 def lower_step(model_cfg: TransformerConfig | JambaConfig,
                cache_cfg: CacheConfig, *, max_batch: int, cache_dtype: Any,
                placed: Callable = lambda shapes: shapes):
@@ -524,16 +717,20 @@ def lower_step(model_cfg: TransformerConfig | JambaConfig,
     and lowered; the decode program for ``None``. ``placed`` maps every
     tree of shapes on its way in (``tools/aot_serve_step.py`` gives them a
     described chip's sharding)."""
-    recurrent = not isinstance(model_cfg, TransformerConfig)
-    if recurrent:
-        from tpu_sandbox.models.jamba import JambaConfig, JambaLM
+    family = _family(model_cfg)
+    recurrent = family == "jamba"
+    if family == "jamba":
+        from tpu_sandbox.models.jamba import JambaLM
 
-        if not isinstance(model_cfg, JambaConfig):
-            raise TypeError(
-                f"no serving family for {type(model_cfg).__name__}")
         model = JambaLM(model_cfg)
         prefill_fn = make_jamba_prefill_fn(model_cfg)
         decode_fn = make_jamba_decode_fn(model_cfg, cache_cfg)
+    elif family == "longcat":
+        from tpu_sandbox.models.longcat_flash import LongcatFlashLM
+
+        model = LongcatFlashLM(model_cfg)
+        prefill_fn = make_longcat_prefill_fn(model_cfg)
+        decode_fn = make_longcat_decode_fn(model_cfg, cache_cfg)
     else:
         model = TransformerLM(model_cfg)
         prefill_fn = make_prefill_fn(model_cfg)
@@ -542,9 +739,17 @@ def lower_step(model_cfg: TransformerConfig | JambaConfig,
     def ints(*shape):
         return placed(jax.ShapeDtypeStruct(shape, jnp.int32))
 
-    params = placed(jax.eval_shape(
-        lambda: model.init(jax.random.key(0),
-                           jnp.zeros((1, 8), jnp.int32))["params"]))
+    variables = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
+    params = variables["params"]
+    if family == "longcat":
+        from tpu_sandbox.models.longcat_flash import split_stats
+
+        # what the engine holds as this family's weights: the router's
+        # bias a layer rides with them (``_longcat_variables``)
+        params = {"params": params,
+                  "router_bias": split_stats(variables["batch_stats"])[0]}
+    params = placed(params)
     held = placed(buffer_shapes(model_cfg, cache_cfg, max_batch, cache_dtype))
 
     def lower(bucket: int | None):
@@ -570,11 +775,13 @@ def build_decode_step(model_cfg: TransformerConfig | JambaConfig,
         raise ValueError("no prefill bucket fits max_context")
     _, held, lower = lower_step(model_cfg, cache_cfg, max_batch=max_batch,
                                 cache_dtype=cache_dtype)
-    # slot state beside the pages: that family's programs give every row's
-    # greedy pick, and the tokens of the next call from it
-    recurrent = len(held) > 2
+    # the families that came after the first give every row's greedy pick,
+    # and the tokens of the next call from it; Jamba's holds slot state
+    # beside the pages (a slot into prefill, no prefix reuse)
+    family = _family(model_cfg)
+    picks = family != "transformer"
     next_tokens = None
-    if recurrent:
+    if picks:
         if model_cfg.vocab_size >= 2 ** 24:
             raise ValueError("the greedy pick carries its token in a float32")
         next_tokens = jax.jit(_next_tokens).lower(jax.ShapeDtypeStruct(
@@ -583,5 +790,5 @@ def build_decode_step(model_cfg: TransformerConfig | JambaConfig,
         model_cfg=model_cfg, cache_cfg=cache_cfg, max_batch=max_batch,
         buckets=buckets, cache_dtype=cache_dtype,
         prefill={b: lower(b).compile() for b in buckets},
-        decode=lower(None).compile(), buffers=held, recurrent=recurrent,
-        picks=recurrent, next_tokens=next_tokens)
+        decode=lower(None).compile(), buffers=held,
+        recurrent=family == "jamba", picks=picks, next_tokens=next_tokens)

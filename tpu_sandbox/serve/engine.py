@@ -67,6 +67,7 @@ from tpu_sandbox.serve.decode import (DecodeStep, Pages, build_decode_step,
 
 if TYPE_CHECKING:
     from tpu_sandbox.models.jamba import JambaConfig
+    from tpu_sandbox.models.longcat_flash import LongcatFlashConfig
 
 # engines with a live decode loop / replica thread, for the conftest leak
 # fixture (mirrors kvstore.live_servers())
@@ -81,7 +82,7 @@ def live_engines() -> list:
 class ServeConfig:
     # the model family's configuration: ``build_decode_step`` picks the
     # family's step builder by its type
-    model: TransformerConfig | JambaConfig = field(
+    model: TransformerConfig | JambaConfig | LongcatFlashConfig = field(
         default_factory=TransformerConfig)
     cache: CacheConfig = field(default_factory=CacheConfig)
     max_batch: int = 4
@@ -196,7 +197,9 @@ class _EngineBase:
             config.model, config.cache, max_batch=config.max_batch,
             buckets=config.buckets, cache_dtype=config.cache_dtype)
         # the device state both programs take and give back, donated: the
-        # pages and, for a model with recurrent layers, every slot's state
+        # pages and a third buffer where the family has one (a model with
+        # recurrent layers: every slot's state; one with routed experts:
+        # the shares' counters)
         self.recurrent = bool(getattr(self.step_fns, "recurrent", False))
         self.picks = bool(getattr(self.step_fns, "picks", False))
         self.cache = PagedKVCache(config.cache, recurrent=self.recurrent)
@@ -205,9 +208,13 @@ class _EngineBase:
         self.k_pages, self.v_pages, *state = init_buffers(self.step_fns) \
             if getattr(self.step_fns, "buffers", ()) else (Pages(), Pages())
         self.state = state[0] if state else None
-        if state:
+        if self.recurrent:
             get_registry().gauge("serve.state_bytes").set(sum(
                 x.nbytes for x in jax.tree.leaves(self.state)))
+        if self.k_pages and not self.v_pages:  # a latent cache: one row a
+            # position, no separate V (``serve/decode.py::page_shapes``)
+            get_registry().gauge("serve.latent_bytes").set(sum(
+                x.nbytes for x in self.k_pages))
         self.clock = clock
         self.waiting: deque[Request] = deque()
         self.slots: list[_Slot | None] = [None] * config.max_batch
