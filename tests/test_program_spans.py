@@ -423,7 +423,8 @@ def test_serve_programs_have_stable_names_and_scopes():
 
 class _CountingSteps:
     """The engine's compiled programs, with every call and every fetch of a
-    result counted: the logits come back as objects that note when
+    result counted: the logits and the rows' greedy picks (what the engine
+    fetches for a greedy request) come back as objects that note when
     ``np.asarray`` reads them."""
 
     class _Logits:
@@ -443,11 +444,17 @@ class _CountingSteps:
         self.decode = self._wrap("decode", real.decode)
         self.pick_bucket = real.pick_bucket
 
+    def next_tokens(self, picks):
+        """The call dispatched ahead takes its tokens from the picks on the
+        device: no fetch."""
+        return self.real.next_tokens(picks.array)
+
     def _wrap(self, kind, fn):
         def call(*args):
             self.events.append(kind)
-            logits, k, v = fn(*args)
-            return self._Logits(self, logits), k, v
+            logits, picks, k, v = fn(*args)
+            return (self._Logits(self, logits), self._Logits(self, picks),
+                    k, v)
         return call
 
 

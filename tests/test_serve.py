@@ -26,7 +26,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from tests.helpers import counters, label, ulps_apart
+import test_serve_jamba as lives
+from tests.helpers import StubStep, counters, label, ulps_apart
 from tpu_sandbox.models.transformer import TransformerConfig, TransformerLM
 from tpu_sandbox.serve import (
     CacheConfig,
@@ -39,6 +40,7 @@ from tpu_sandbox.serve import (
 from tpu_sandbox.serve import decode as serve_decode
 from tpu_sandbox.serve.decode import (Pages, _gather, build_decode_step,
                                       init_buffers, lower_step)
+from tpu_sandbox.serve.engine import _token_logprob
 
 MCFG = TransformerConfig(vocab_size=64, d_model=32, n_heads=4, n_layers=2,
                          d_ff=64, max_len=128, dtype=jnp.float32)
@@ -175,7 +177,7 @@ def test_decode_matches_padded_forward_to_rounding_fp32(
     toks = np.zeros((1, 8), np.int32)
     toks[0, :len(prompt)] = prompt
     dest = cache.dest_indices(alloc, 8).astype(np.int32)
-    cur, kp, vp = step.prefill[8](
+    cur, _, kp, vp = step.prefill[8](
         params, kp, vp, jnp.asarray(toks), jnp.asarray(dest),
         jnp.asarray(len(prompt) - 1, jnp.int32))
     alloc.length = len(prompt)
@@ -195,7 +197,7 @@ def test_decode_matches_padded_forward_to_rounding_fp32(
         tokens[0, 0] = token
         lengths[0] = len(seq) - (fault is not None and i == 10)
         tables[0] = cache.block_table(alloc)
-        cur, kp, vp = step.decode(
+        cur, _, kp, vp = step.decode(
             params, kp, vp, jnp.asarray(tokens), jnp.asarray(lengths),
             jnp.asarray(tables))
         cur = np.asarray(cur)[0]
@@ -220,7 +222,7 @@ def test_decode_bf16_cache_stays_close(params, fwd32):
     toks = np.zeros((1, 8), np.int32)
     toks[0, :len(prompt)] = prompt
     dest = cache.dest_indices(alloc, 8).astype(np.int32)
-    cur, kp, vp = step16.prefill[8](
+    cur, _, kp, vp = step16.prefill[8](
         params, kp, vp, jnp.asarray(toks), jnp.asarray(dest),
         jnp.asarray(len(prompt) - 1, jnp.int32))
     alloc.length = len(prompt)
@@ -237,7 +239,7 @@ def test_decode_bf16_cache_stays_close(params, fwd32):
         tokens[0, 0] = token
         lengths[0] = len(seq)
         tables[0] = cache.block_table(alloc)
-        cur, kp, vp = step16.decode(
+        cur, _, kp, vp = step16.decode(
             params, kp, vp, jnp.asarray(tokens), jnp.asarray(lengths),
             jnp.asarray(tables))
         cur = np.asarray(cur)[0]
@@ -442,6 +444,209 @@ def test_preemption_under_block_pressure_replays_identically(params, step,
     for r in reqs:
         assert eng.results[r.rid].tokens == greedy(r.prompt,
                                                    r.max_new_tokens), r.rid
+
+
+# -- the greedy pick on the device, the next call dispatched ahead ----------
+
+# tests/test_serve_jamba.py has the bodies, written against a ``served``
+# tuple of any family (configuration, weights, a step set a pool, -): here
+# they run over ``TransformerLM``, whose allocator shares prefixes beside
+# the call ahead where a recurrent family's declines to
+LMCFG = TransformerConfig(vocab_size=256, d_model=32, n_heads=4, n_layers=2,
+                          d_ff=64, max_len=64, dtype=jnp.float32)
+AHEAD = "engine.decode_ahead"
+
+
+@pytest.fixture(scope="module")
+def served():
+    weights = [TransformerLM(LMCFG).init(
+        jax.random.key(seed), jnp.zeros((1, 8), jnp.int32))["params"]
+        for seed in (0, 1)]
+    steps = {cache: build_decode_step(LMCFG, cache, max_batch=3,
+                                      buckets=lives.BUCKETS)
+             for cache in (lives.CACHE, lives.SMALL_POOL)}
+    return LMCFG, weights[0], steps, weights[1]
+
+
+def outcomes(since: dict) -> dict:
+    """``engine.decode_ahead``'s gains by outcome."""
+    return {label(key, "outcome"): n
+            for key, n in counters(AHEAD, since=since).items()}
+
+
+def test_a_greedy_request_is_picked_by_the_program_a_sampled_one_on_the_host(
+        served):
+    """A greedy request never brings a row of logits to ``_pick_token``; a
+    sampled one does for every token. Either way the token is what the same
+    program's logits give and its log-probability the float64 one of those
+    logits (the program's: ``max - logsumexp`` in float32)."""
+    p = lives.prompt(11, seed=4)
+    for rid, sampling, picked_on_host in (
+            ("g", {}, 0), ("s", {"temperature": 0.8, "seed": 3}, 6)):
+        eng = lives.engine(served)
+        pick, emit = eng._pick_token, eng._emit_token
+        on_host, chosen = [], []
+
+        def counting(slot, row):
+            on_host.append(slot.request.rid)
+            return pick(slot, row)
+
+        def keeping(slot, token):
+            chosen.append((token, slot.logprob_sum))
+            return emit(slot, token)
+
+        eng._pick_token, eng._emit_token = counting, keeping
+        tokens, rows = lives.serve(eng, {rid: p}, **sampling)[rid]
+        assert on_host == [rid] * picked_on_host
+        assert tokens == [t for t, _ in chosen] and len(tokens) == 6
+        if not sampling:
+            assert tokens == [int(r.argmax()) for r in rows]
+        sums = [0.0] + [total for _, total in chosen]
+        for row, token, before, after in zip(rows, tokens, sums, sums[1:]):
+            assert after - before == pytest.approx(
+                _token_logprob(row, token), abs=1e-5)     # the float64 one
+    again = lives.serve(lives.engine(served), {"s": p}, temperature=0.8,
+                        seed=3)["s"]
+    assert again[0] == tokens           # a replay draws the same tokens
+
+
+@pytest.mark.parametrize("name", list(lives.LIVES))
+def test_a_call_dispatched_ahead_changes_no_token_and_no_step(served, name):
+    lives.test_a_call_dispatched_ahead_changes_no_token_and_no_step(
+        served, name)
+
+
+def test_a_call_dispatched_ahead_under_block_pressure(served):
+    since = counters(AHEAD)
+    lives.test_a_call_dispatched_ahead_under_block_pressure(served)
+    assert outcomes(since)["no_blocks"] >= 1
+
+
+def test_settle_resolves_the_call_dispatched_ahead(served):
+    lives.test_settle_resolves_the_call_dispatched_ahead(served)
+    # and the pages are those of an engine that waited for every step
+    # (but the null block's: an empty row writes there whatever token it
+    # was given)
+    ahead, plain = lives.engine(served), lives.engine(served, ahead=False)
+    for eng in ahead, plain:
+        for request in lives.requests([9, 4], 12):
+            eng.submit(request)
+    for _ in range(3):
+        ahead.step()
+        plain.step()
+    plain.step()
+    ahead.settle()
+    for a, b in zip(jax.tree.leaves((ahead.k_pages, ahead.v_pages)),
+                    jax.tree.leaves((plain.k_pages, plain.v_pages))):
+        np.testing.assert_array_equal(np.asarray(a)[1:], np.asarray(b)[1:])
+    for eng in ahead, plain:
+        eng.drain_to_requests()
+
+
+def test_requests_that_share_a_prefix_decode_ahead(served):
+    """What this family has and a recurrent one declines: the second
+    request reuses the first one's full prompt blocks, both ride the calls
+    dispatched ahead, and each is served what it is served alone."""
+    shared = lives.prompt(12, seed=3)
+    script = {0: [Request(rid="first", prompt=shared + [7, 8],
+                          max_new_tokens=9),
+                  Request(rid="second", prompt=shared + [9],
+                          max_new_tokens=7)]}
+    _, ended, eng, ahead = lives.life(served, script)
+    assert eng.cache.stats["prefix_hits"] == 1
+    assert eng.cache.stats["prefix_blocks_reused"] == 3
+    assert ahead >= 6
+    for request in script[0]:
+        _, alone, _, _ = lives.life(served, {0: [Request(
+            rid=request.rid, prompt=list(request.prompt),
+            max_new_tokens=request.max_new_tokens)]}, ahead=False)
+        assert ended[request.rid][0] == alone[request.rid][0]
+        assert ended[request.rid][1] == pytest.approx(alone[request.rid][1],
+                                                      abs=1e-5)
+
+
+def test_a_row_dropped_at_eos_token_has_its_slot_refilled(served):
+    """A sequence that ends on ``eos_token`` is known to have ended only
+    after the next call went out with its row: the row is dropped, the
+    request that waited takes the slot with a call of its own (counted
+    ``admitted``), and is served what it is served alone."""
+    eos = lives.eos_of(served)
+    eng = lives.engine(served, eos_token=eos)
+    for request in lives.requests([9, 4, 14, 6], 9):
+        eng.submit(request)
+    since, dropped = counters(AHEAD), []
+    while not eng.idle:
+        flying = dict(eng._ahead.slots) if eng._ahead else {}
+        eng.step()
+        dropped += [(i, s.request.rid) for i, s in flying.items()
+                    if eng.slots[i] is not s]
+        if dropped and "q6" not in eng.results:   # refilled the step it left
+            assert eng.slots[dropped[0][0]].request.rid == "q6"
+    assert dropped[0][1] == "q9"
+    assert eng.results["q9"].tokens[-1] == eos
+    assert len(eng.results["q9"].tokens) < 9
+    assert outcomes(since)["admitted"] >= 1
+    _, alone, _, _ = lives.life(served, {0: lives.requests([6], 9)},
+                                ahead=False, eos_token=eos)
+    assert eng.results["q6"].tokens == alone["q6"][0]
+
+
+def test_a_swap_between_two_steps_declines_the_call_ahead(served):
+    """Slots on two weight versions take a call a version, and nothing is
+    dispatched ahead of such a step (``versions``); every request finishes
+    on the weights it pinned at admission."""
+    _, old, _, new = served
+    eng = lives.engine(served)
+    before_swap, after_swap = lives.requests([9, 4], 10), \
+        lives.requests([7], 6)
+    for request in before_swap:
+        eng.submit(request)
+    eng.step()
+    eng.step()
+    assert eng._ahead is not None       # in flight over the swap
+    eng.swap_params(new, version=1)
+    eng.submit(after_swap[0])
+    since = counters(AHEAD)
+    eng.step()
+    assert outcomes(since) == {"versions": 1} and eng._ahead is None
+    eng.run_until_idle()
+    assert outcomes(since)["dispatched"] >= 1       # q7 alone, once they left
+    assert {rid: r.ver for rid, r in eng.results.items()} \
+        == {"q9": 0, "q4": 0, "q7": 1}
+    for weights, version, asked in ((old, 0, before_swap),
+                                    (new, 1, after_swap)):
+        for request in asked:
+            alone = lives.engine((LMCFG, weights, served[2], None))
+            alone.submit(Request(rid=request.rid, prompt=request.prompt,
+                                 max_new_tokens=request.max_new_tokens))
+            alone.run_until_idle()
+            assert eng.results[request.rid].tokens \
+                == alone.results[request.rid].tokens, (request.rid, version)
+
+
+@pytest.mark.parametrize("batch, want", [
+    ("greedy", {"dispatched": 6, "no_rows": 1}),
+    ("one member samples", {"sampled": 6, "no_rows": 1}),
+    ("a stub step", {"no_picks": 7}),
+])
+def test_every_step_that_decodes_counts_what_came_of_the_call_ahead(
+        served, batch, want):
+    """``engine.decode_ahead{outcome}``: one count a step that decoded
+    (seven: the prefills gave the first of eight tokens; in the last every
+    slot is on its last token, which a stub step never comes to ask)."""
+    if batch == "a stub step":
+        eng = ContinuousEngine(None, _scfg(), step=StubStep())
+    else:
+        eng = lives.engine(served)
+    sampling = {"temperature": 0.7, "seed": 2} \
+        if batch == "one member samples" else {}
+    for request in lives.requests([9, 4], 8) \
+            + lives.requests([12], 8, **sampling):
+        request.prompt = [t % 64 for t in request.prompt]   # the stub's
+        eng.submit(request)
+    since = counters(AHEAD)
+    eng.run_until_idle()
+    assert outcomes(since) == want and eng.steps == 7
 
 
 # -- the decode programs' attention: the kernel or the jnp form, by shape ----

@@ -51,8 +51,7 @@ the same scopes and counter, and ``mla.cache_layout``). The third donated
 buffer is the expert shares' device counters (``rows_held``,
 ``rows_dropped``, ``real_choices``, ``zero_choices``, ``steps`` a layer: no
 transfer a step; whoever wants them reads them once), the router's bias
-rides with the weights (``params = {"params", "router_bias"}``), and the
-programs give the greedy pick as Jamba's do.
+rides with the weights (``params = {"params", "router_bias"}``).
 
 A model with recurrent layers (``JambaLM``, ``recurrent``) holds beside the
 pages the Mamba layers' **slot state** (``models/jamba.py::state_shapes``:
@@ -63,11 +62,14 @@ slot it is given — bucket padding has ``dt = 0`` and does not move it, the
 convolution's tail is read at ``last_pos`` — which is also the slot's
 reset at admission. Decode moves the state of the rows with ``lengths >
 0`` and leaves the others' bit for bit (empty slots and the rows of
-another weight version ride every call). Behind the logits its programs
-give every row's greedy pick (``_greedy_pick``; ``DecodeStep.picks``):
-the engine takes it for a greedy request and brings the logits to the
-host only for one that samples; and it can dispatch the next decode call
-on those picks before it has read them (``DecodeStep.next_tokens``).
+another weight version ride every call).
+
+**Every family's programs pick on the device.** Behind the logits each of
+the six gives every row's greedy pick (``_greedy_pick``;
+``DecodeStep.picks``): the engine takes it for a greedy request and brings
+the logits to the host only for one that samples; and it can dispatch the
+next decode call on those picks before it has read them
+(``DecodeStep.next_tokens``).
 
 What the steps hold to (tests/test_serve.py, tests/test_serve_jamba.py,
 tests/test_serve_longcat.py, tests/test_paged_attention.py):
@@ -165,11 +167,13 @@ class DecodeStep:
     # as one float32 ``[..., 3]``: the token, its log-probability, the
     # row's log-sum-exp. A greedy request then needs neither the logits on
     # the host nor a pass over the vocabulary there; a sampled one still
-    # takes the logits
+    # takes the logits. True of every family ``build_decode_step`` builds:
+    # a field only because the tests' program-less stub steps have none
+    # (``getattr(step, "picks", False)``) and give logits alone
     picks: bool = False
-    # compiled next_tokens(picks[B, 3]) -> tokens[B, 1] int32, where
-    # ``picks``: a decode call's input tokens from the picks of the call
-    # before it, without a visit to the host (``engine._decode_ahead``)
+    # compiled next_tokens(picks[B, 3]) -> tokens[B, 1] int32: a decode
+    # call's input tokens from the picks of the call before it, without a
+    # visit to the host (``engine._decode_ahead``); None only in a stub
     next_tokens: Callable | None = None
 
     def pick_bucket(self, prompt_len: int) -> int:
@@ -451,7 +455,8 @@ def _store_latent(pages: jnp.ndarray, dest: jnp.ndarray, rows: jnp.ndarray):
 
 def make_prefill_fn(model_cfg: TransformerConfig):
     """prefill(params, k_pages, v_pages, tokens[1, Lb], dest_idx[Lb],
-    last_pos[]) -> (next_logits[vocab], k_pages, v_pages).
+    last_pos[]) -> (next_logits[vocab], their greedy pick
+    (``_greedy_pick``), k_pages, v_pages).
 
     ``dest_idx`` maps each bucket position to its flat page slot — null
     block (slot 0) for bucket padding and shared-prefix positions, so the
@@ -474,14 +479,16 @@ def make_prefill_fn(model_cfg: TransformerConfig):
                 v_pages[i] = _store(v_pages[i], dest_idx, v[0])
         next_logits = jax.lax.dynamic_index_in_dim(
             logits[0], last_pos, axis=0, keepdims=False)
-        return next_logits, Pages(k_pages), Pages(v_pages)
+        return (next_logits, _greedy_pick(next_logits, "TransformerLM"),
+                Pages(k_pages), Pages(v_pages))
 
     return jax.jit(serve_prefill, donate_argnums=(1, 2))
 
 
 def make_decode_fn(model_cfg: TransformerConfig, cache_cfg: CacheConfig):
     """decode(params, k_pages, v_pages, tokens[B, 1], lengths[B],
-    block_tables[B, max_blocks]) -> (logits[B, vocab], k_pages, v_pages).
+    block_tables[B, max_blocks]) -> (logits[B, vocab], every row's greedy
+    pick (``_greedy_pick``), k_pages, v_pages).
 
     ``lengths[b]`` counts tokens *including* the one being fed, so its
     position is ``lengths[b] - 1`` and attention covers kv positions
@@ -507,13 +514,14 @@ def make_decode_fn(model_cfg: TransformerConfig, cache_cfg: CacheConfig):
                            lengths, model_cfg.n_heads)[:, None]
 
         model = TransformerLM(model_cfg, attention_fn=attention_fn)
-        logits = model.apply({"params": params}, tokens, pos[:, None])
-        return logits[:, 0], Pages(k_pages), Pages(v_pages)
+        logits = model.apply({"params": params}, tokens, pos[:, None])[:, 0]
+        return (logits, _greedy_pick(logits, "TransformerLM"),
+                Pages(k_pages), Pages(v_pages))
 
     return jax.jit(serve_decode, donate_argnums=(1, 2))
 
 
-def _greedy_pick(logits: jnp.ndarray, model: str = "JambaLM"):
+def _greedy_pick(logits: jnp.ndarray, model: str):
     """``logits [..., vocab]`` float32 -> float32 ``[..., 3]``: the token
     (the first largest logit, as ``numpy.argmax`` on the host picks it, so
     a replay draws the same token; an id under 2^24 is exact in float32),
@@ -571,7 +579,7 @@ def make_jamba_prefill_fn(model_cfg: JambaConfig):
                     jax.lax.dynamic_update_slice_in_dim(old, new, slot, 1)
                     for old, new in zip(state["ssm"], last["ssm"]))}
         logits = logits[0, 0]
-        return (logits, _greedy_pick(logits), Pages(k_pages),
+        return (logits, _greedy_pick(logits, "JambaLM"), Pages(k_pages),
                 Pages(v_pages), state)
 
     return jax.jit(serve_prefill, donate_argnums=(1, 2, 3))
@@ -604,7 +612,7 @@ def make_jamba_decode_fn(model_cfg: JambaConfig, cache_cfg: CacheConfig):
         logits, state = model.apply({"params": params}, tokens, state,
                                     live=lengths > 0)
         logits = logits[:, 0]
-        return (logits, _greedy_pick(logits), Pages(k_pages),
+        return (logits, _greedy_pick(logits, "JambaLM"), Pages(k_pages),
                 Pages(v_pages), state)
 
     return jax.jit(serve_decode, donate_argnums=(1, 2, 3))
@@ -775,20 +783,17 @@ def build_decode_step(model_cfg: TransformerConfig | JambaConfig,
         raise ValueError("no prefill bucket fits max_context")
     _, held, lower = lower_step(model_cfg, cache_cfg, max_batch=max_batch,
                                 cache_dtype=cache_dtype)
-    # the families that came after the first give every row's greedy pick,
-    # and the tokens of the next call from it; Jamba's holds slot state
-    # beside the pages (a slot into prefill, no prefix reuse)
-    family = _family(model_cfg)
-    picks = family != "transformer"
-    next_tokens = None
-    if picks:
-        if model_cfg.vocab_size >= 2 ** 24:
-            raise ValueError("the greedy pick carries its token in a float32")
-        next_tokens = jax.jit(_next_tokens).lower(jax.ShapeDtypeStruct(
-            (max_batch, 3), jnp.float32)).compile()
+    # every family's programs give each row's greedy pick, and the tokens
+    # of the next call from it; Jamba's holds slot state beside the pages
+    # (a slot into prefill, no prefix reuse)
+    if model_cfg.vocab_size >= 2 ** 24:
+        raise ValueError("the greedy pick carries its token in a float32")
+    next_tokens = jax.jit(_next_tokens).lower(jax.ShapeDtypeStruct(
+        (max_batch, 3), jnp.float32)).compile()
     return DecodeStep(
         model_cfg=model_cfg, cache_cfg=cache_cfg, max_batch=max_batch,
         buckets=buckets, cache_dtype=cache_dtype,
         prefill={b: lower(b).compile() for b in buckets},
         decode=lower(None).compile(), buffers=held,
-        recurrent=family == "jamba", picks=picks, next_tokens=next_tokens)
+        recurrent=_family(model_cfg) == "jamba", picks=True,
+        next_tokens=next_tokens)

@@ -90,10 +90,13 @@ class PrefillReplica(_Replica):
         toks = np.zeros((1, bucket), np.int32)
         toks[0, :plen] = req.prompt
         dest = self.cache.dest_indices(alloc, bucket).astype(np.int32)
-        next_logits, self.k_pages, self.v_pages = self.step.prefill[bucket](
-            self.params, self.k_pages, self.v_pages,
-            jnp.asarray(toks), jnp.asarray(dest),
-            jnp.asarray(plen - 1, jnp.int32))
+        # the program's own greedy pick stays unread: the decode side
+        # chooses from the shipped logits (``_pick_token``)
+        next_logits, _, self.k_pages, self.v_pages = \
+            self.step.prefill[bucket](
+                self.params, self.k_pages, self.v_pages,
+                jnp.asarray(toks), jnp.asarray(dest),
+                jnp.asarray(plen - 1, jnp.int32))
         alloc.length = plen
         self.cache.commit_prefix(alloc)
         # exactly this request's block rows, in block-table order — the
@@ -158,7 +161,7 @@ class DecodeReplica(_Replica):
             tables = np.zeros((B, self.cache_cfg.max_blocks_per_seq),
                               np.int32)
             tables[0] = self.cache.block_table(alloc)
-            logits, self.k_pages, self.v_pages = self.step.decode(
+            logits, _, self.k_pages, self.v_pages = self.step.decode(
                 self.params, self.k_pages, self.v_pages,
                 jnp.asarray(toks), jnp.asarray(lengths),
                 jnp.asarray(tables))

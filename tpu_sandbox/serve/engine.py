@@ -19,15 +19,17 @@ allocator — the two differ in scheduling policy only.
 
 Decoding is greedy argmax over fp32 logits by default — deterministic,
 which is what makes requeue/replay and the replica zero-loss story exact
-rather than probabilistic. Where a family's programs give that pick
-themselves (``DecodeStep.picks``) the engine takes it (``_choose``) and the
-logits stay on the device; otherwise, and for every sampled request, the
-choice is made here on the host (``_pick_token``). With the pick on the
-device the next step's call needs nothing of the host's but lengths and
-block tables, which the host knows beforehand: it is **dispatched ahead**
-(``_decode_ahead``), before this step's picks are read, so the device goes
-from one step into the next while the host reads, emits and prepares, and
-a step takes the device's time, not the device's plus the host's. Sampled
+rather than probabilistic. Every family's programs give that pick
+themselves (``DecodeStep.picks``): the engine takes it (``_choose``) and the logits stay on the device; for a
+sampled request, and under a program-less stub step that gives logits
+alone, the choice is made here on the host (``_pick_token``). With the pick
+on the device the next step's call needs nothing of the host's but lengths
+and block tables, which the host knows beforehand: it is **dispatched
+ahead** (``_decode_ahead``), before this step's picks are read, so the
+device goes from one step into the next while the host reads, emits and
+prepares, and a step takes the device's time, not the device's plus the
+host's. Whether a step did, or what it saw in its input that kept it from
+it, is counted once a step (``engine.decode_ahead{outcome}``). Sampled
 decode (``temperature``/``top_k`` on the
 request) keeps the same guarantee: the sampler key is derived from the
 request seed folded with the decode-step index, so a replayed request
@@ -652,6 +654,10 @@ class _EngineBase:
         # one call decodes every occupied slot: the next step's can follow
         # it on the device before its picks are read here
         alone = len(by_ver) + bool(covered) == 1
+        if not alone:  # several calls this step: none follows them ahead
+            versions = set(by_ver) | ({ahead.ver} if covered else set())
+            self._ahead_outcome(
+                "versions" if len(versions) > 1 else "admitted")
         rows: dict[int, tuple] = {}  # slot -> (logits row, program's pick)
         if covered:
             with rec.span("engine:decode_call",
@@ -720,14 +726,15 @@ class _EngineBase:
         None — the next step dispatches as it always did — unless the
         programs pick on the device and every slot is greedy and on
         ``ver``; and where a slot would need a block the pool cannot give:
-        preempting is the next step's business. A slot whose last token
+        preempting is the next step's business. Either way the step counts
+        what came of it (``_ahead_outcome``). A slot whose last token
         this step emits by count rides the call as an empty row; one that
         ends on ``eos_token`` cannot be known here, and its row is dropped
         by the next step (what the call wrote for it lies behind its
         sequence's end in blocks it held, and in a slot state the next
         admission replaces)."""
         if not self.picks:
-            return None
+            return self._ahead_outcome("no_picks")
         B = self.config.max_batch
         cfg = self.config.cache
         members: dict[int, _Slot] = {}
@@ -735,11 +742,13 @@ class _EngineBase:
             if slot is None or \
                     len(slot.generated) + 1 >= slot.request.max_new_tokens:
                 continue
-            if slot.ver != ver or slot.request.temperature > 0.0:
-                return None
+            if slot.ver != ver:
+                return self._ahead_outcome("versions")
+            if slot.request.temperature > 0.0:
+                return self._ahead_outcome("sampled")
             members[i] = slot
         if not members:
-            return None
+            return self._ahead_outcome("no_rows")
         lengths = np.zeros((B,), np.int32)
         tables = np.zeros((B, cfg.max_blocks_per_seq), np.int32)
         for i, slot in members.items():
@@ -747,14 +756,28 @@ class _EngineBase:
             if at % cfg.block_size == 0 \
                     and at // cfg.block_size >= len(slot.alloc.block_ids) \
                     and not self.cache.grow(slot.alloc):
-                return None
+                return self._ahead_outcome("no_blocks")
             lengths[i] = at + 1
             tables[i] = self.cache.block_table(slot.alloc)
         _, picks = self._run(
             self.step_fns.decode, self._params_by_ver[ver],
             self.step_fns.next_tokens(picks), jnp.asarray(lengths),
             jnp.asarray(tables))
-        return _Ahead(picks=picks, slots=members, ver=ver)
+        return self._ahead_outcome(
+            "dispatched", _Ahead(picks=picks, slots=members, ver=ver))
+
+    @staticmethod
+    def _ahead_outcome(outcome: str, ahead: _Ahead | None = None):
+        """Count what a step that decoded made of the call ahead, once:
+        ``dispatched``, or what it saw that kept it from it -- ``sampled``
+        (a member samples), ``versions`` (slots on more than one weight
+        version), ``admitted`` (slots admitted since the call before took a
+        call of their own), ``no_blocks`` (the pool gives none: the next
+        step preempts), ``no_rows`` (every slot on its last token),
+        ``no_picks`` (a stub step)."""
+        get_registry().counter("engine.decode_ahead",
+                               labels={"outcome": outcome}).inc()
+        return ahead
 
     def settle(self) -> None:
         """Resolve the call dispatched ahead, if there is one: its tokens
