@@ -39,6 +39,18 @@ def rule_inputs(s, *, h=3, dk=8, dv=16, b=2, beta_shift=0.0, seed=0):
             jax.random.normal(ks[5], (b, s, h, dv)))
 
 
+def inverse_choices(since=None):
+    """labels -> count of ``delta_rule.inverse_choice``, less ``since``."""
+    from tpu_sandbox.obs import get_registry
+
+    since = since or {}
+    now = {key[key.index("{") + 1:-1]: count for key, count in
+           get_registry().snapshot()["counters"].items()
+           if key.startswith("delta_rule.inverse_choice")}
+    return {labels: count - since.get(labels, 0)
+            for labels, count in now.items() if count != since.get(labels, 0)}
+
+
 def out_and_grads(rule):
     """One compiled program a side, not one a primitive."""
     def run(*args, weight):
@@ -52,7 +64,8 @@ def out_and_grads(rule):
 @pytest.mark.parametrize("s,chunk,beta_shift", [
     (16, 16, 0.0),       # one chunk: no state crosses a boundary
     (256, 4, 0.0),       # S = 64 C: the loop over the chunks does the work
-    (128, 64, 0.0),      # the cell's chunk
+    (128, 64, 0.0),      # the cell's chunk: the inverse is the kernel's
+    (256, 32, 0.0),      # eight chunks a kernel block
     (64, 8, 3.0),        # beta in (1, 2): the transition flips the key
     (64, 8, -3.0)])      # beta under 1 everywhere
 def test_chunked_rule_is_the_recurrence_forward_and_backward(s, chunk, beta_shift):
@@ -62,11 +75,18 @@ def test_chunked_rule_is_the_recurrence_forward_and_backward(s, chunk, beta_shif
         assert beta.min() < 0.5 and beta.max() > 1.5
     else:
         assert ((beta > 1.0).mean() > 0.9) == (beta_shift > 0)
+    inverse_sites = inverse_choices()
     with jax.default_matmul_precision("highest"):
         (_, got_out), got = out_and_grads(functools.partial(
             gated_delta_rule, chunk=chunk))(*args, weight=weight)
         (_, want_out), want = out_and_grads(ref.delta_recurrence)(
             *args, weight=weight)
+    # which inverse ran follows the shape: the kernel pair from a chunk of
+    # 16 and two chunks a head, forward and backward one site each
+    impl = "pallas" if chunk >= 16 and s // chunk >= 2 else "jnp"
+    assert {",".join(labels.split(",")[:2]): count for labels, count in
+            inverse_choices(since=inverse_sites).items()} == {
+        f"impl={impl},kernel=fwd": 1, f"impl={impl},kernel=bwd": 1}
     np.testing.assert_allclose(got_out, want_out, rtol=2e-5, atol=2e-5)
     for name, a, b in zip(("q", "k", "v", "g", "beta"), got, want):
         assert ref.rms_rel(a, b) < 2e-5, name

@@ -201,6 +201,31 @@ def test_short_conv_kernels_lower_for_tpu(name, b, s, width, c, start, bias,
     assert re.findall(r'kernel_name = "_(?:fwd|bwd)(\w+)"', text) == [pair] * 2
 
 
+@pytest.mark.parametrize("shape", [(1, 30, 128, 64, 64), (1, 30, 4, 64, 64)],
+                         ids=["olmoh_step", "olmoh_float32_check"])
+def test_tri_inverse_kernels_lower_for_tpu(shape):
+    """``ops/pallas_tri_inverse.py``: the delta rule's triangular inverse
+    and its cotangent at the tile the rule picks, the leading axes as the
+    rule hands them over (squeezed in the ``BlockSpec``): a lane gather in
+    two dimensions on a 64-lane value, sublane-tile row slices, batched bf16
+    products of float32's pieces, and two float32 products at ``HIGHEST``,
+    one of them contracting the rows of both operands."""
+    from tpu_sandbox.ops import pallas_tri_inverse as ti
+
+    tile = ti.choose_tile(shape)
+    a = jax.ShapeDtypeStruct(shape, jnp.float32)
+
+    def both(a, dt):
+        t = ti.tri_inverse_fwd(a, tile=tile, interpret=False)
+        return t, ti.tri_inverse_bwd(t, dt, tile=tile, interpret=False)
+
+    text = jax.jit(both).trace(a, a).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert re.findall(r'kernel_name = "(\w+)"', text) == [
+        "tri_inverse_fwd", "tri_inverse_bwd"]
+
+
 @pytest.mark.parametrize("blocks", [{}, {"block_q": 128, "block_k": 128}],
                          ids=["rule_tiles", "the_rings_128"])
 def test_flash_lse_and_partial_bwd_with_traced_offsets_lower_for_tpu(blocks):

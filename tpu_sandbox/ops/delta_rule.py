@@ -37,7 +37,18 @@ of ``[[L11, 0], [A21, L22]]`` is ``[[T11, 0], [-T22 A21 T11, T22]]``, from
 blocks of one row up, ``log2 C`` levels of two batched products each, all
 float32 at ``highest`` precision; no sequential solve, and nothing grows.
 Its backward pass is a ``custom_vjp`` (``dA = -T^T dT T^T``, two products,
-only ``T`` kept); everything else is differentiated by JAX.
+only ``T`` kept); everything else is differentiated by JAX. In ``jnp``
+(``_doubling``: the fallback and the tests' oracle) every level's products
+go through HBM, 26 float32 passes over ``[3840, 64, 64]`` a layer and step
+at the cell's shape; where the shape allows, inverse and cotangent are one
+Pallas kernel each (``ops/pallas_tri_inverse.py``: blocks of chunks held
+in VMEM, forward substitution inside the small diagonal blocks on the VPU
+and the doubling levels above them on the MXU, still float32 throughout),
+and ``A`` is read once and ``T`` written once. Which runs follows the shape
+alone (``pallas_tri_inverse.choose_tile``: a chunk that is a power of two
+from 16 to 128 and an even tile of chunks that divides a head's), counted
+once a site in ``delta_rule.inverse_choice``; ``INVERSE`` below names the
+method, which both share.
 
 ``g``, ``b``, the cumulative sums, every decay, ``A``, ``T`` and the state
 are float32; the operands of the large products are the compute dtype with
@@ -47,10 +58,13 @@ what it was built with (``delta_rule.chunk_choice``).
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
-from tpu_sandbox.ops.pallas_common import kernel_site
+from tpu_sandbox.ops import pallas_tri_inverse
+from tpu_sandbox.ops.pallas_common import default_interpret, kernel_site
 
 INVERSE = "block_doubling"
 
@@ -85,28 +99,64 @@ def _doubling(a):
     return inv
 
 
+def _inverse_site(kernel: str, shape) -> int | None:
+    """Counts a site of the inverse (``kernel``: ``fwd``) or of its
+    cotangent (``bwd``) with what it is built with, and returns the
+    kernel's tile: the chunks of a VMEM block, or None where the shape goes
+    to ``jnp``."""
+    from tpu_sandbox.obs import get_registry
+
+    tile = pallas_tri_inverse.choose_tile(shape)
+    kernel_site("tri_inverse_" + kernel, get_registry().counter(
+        "delta_rule.inverse_choice", labels={
+            "impl": "jnp" if tile is None else "pallas", "n": shape[-1],
+            "matrices": math.prod(shape[:-2]), "tile": tile or 0,
+            "kernel": kernel}))
+    return tile
+
+
+def _inverse(a):
+    tile = _inverse_site("fwd", a.shape)
+    if tile is None:
+        return _doubling(a)
+    with jax.named_scope("tri_inverse"):
+        return pallas_tri_inverse.tri_inverse_fwd(
+            a.astype(jnp.float32), tile=tile,
+            interpret=default_interpret(None))
+
+
 @jax.custom_vjp
 def unit_lower_inverse(a):
     """``(I + a)^-1`` for ``a [..., n, n]`` strictly lower triangular (what
     lies on or above the diagonal is not read), ``n`` a power of two, in
-    float32 by block doubling. Every level is two products of whole
-    ``[n, n]`` matrices (the blocks are picked by a mask, not by a reshape:
-    an array whose last axes are a block of 1 or 2 is padded to a whole
-    tile on the chip, a thousandfold). Its cotangent is ``-T^T dT T^T``
-    below the diagonal: only ``T`` is kept for the backward pass."""
-    return _doubling(a)
+    float32: one Pallas kernel over blocks of chunks held in VMEM
+    (``ops/pallas_tri_inverse.py``) where the shape allows
+    (``pallas_tri_inverse.choose_tile``), else ``_doubling``, block doubling
+    in ``jnp``: every level two products of whole ``[n, n]`` matrices (the
+    blocks are picked by a mask, not by a reshape: an array whose last axes
+    are a block of 1 or 2 is padded to a whole tile on the chip, a
+    thousandfold). Its cotangent is ``-T^T dT T^T`` below the diagonal, by
+    the second kernel or two ``jnp`` products: only ``T`` is kept for the
+    backward pass."""
+    return _inverse(a)
 
 
 def _inverse_fwd(a):
-    inv = _doubling(a)
+    inv = _inverse(a)
     return inv, inv
 
 
 def _inverse_bwd(inv, d_inv):
-    n = inv.shape[-1]
-    d_a = -jnp.einsum("...ji,...jk,...lk->...il", inv, d_inv, inv,
-                      precision="highest")
-    return (jnp.where(jnp.tril(jnp.ones((n, n), bool), -1), d_a, 0.0),)
+    tile = _inverse_site("bwd", inv.shape)
+    if tile is None:
+        n = inv.shape[-1]
+        d_a = -jnp.einsum("...ji,...jk,...lk->...il", inv, d_inv, inv,
+                          precision="highest")
+        return (jnp.where(jnp.tril(jnp.ones((n, n), bool), -1), d_a, 0.0),)
+    with jax.named_scope("tri_inverse"):
+        return (pallas_tri_inverse.tri_inverse_bwd(
+            inv, d_inv.astype(jnp.float32), tile=tile,
+            interpret=default_interpret(None)),)
 
 
 unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
