@@ -1,6 +1,6 @@
 """Chipless rehearsal of a serving cell's programs: compile the decode step
 and every prefill bucket that ``serve.build_decode_step`` would build for a
-``*_serve_decode_replay`` cell (any of the three families), for one described v5e chip
+``*_serve_decode_replay`` cell (any of the four families), for one described v5e chip
 with the local libtpu, and print the compiler's memory count of each beside
 what the engine keeps live (weights, pages, slot state), how many
 instructions of the compiled program copy a layer's pages or more
@@ -184,8 +184,10 @@ def main() -> None:
                 "temporaries_gb": mem.temp_size_in_bytes / 1e9,
                 "aliased_gb": mem.alias_size_in_bytes / 1e9,
                 "total_gb": total / 1e9, "chip_gb": 15.75},
+            # (the smallest layer's: a window layer's pool is the smaller)
             "moves_of_pages": count_page_copies(
-                text, k_pages[0].size, k_pages[0].size * len(k_pages)),
+                text, min(p.size for p in k_pages),
+                sum(p.size for p in k_pages)),
             # the Mosaic kernels the program calls, and the form every
             # attention site of this program's trace took
             "pallas_calls": text.count('custom_call_target="tpu_custom_call"'),

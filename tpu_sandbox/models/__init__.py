@@ -2,15 +2,17 @@
 (``pick_convnet``), ``transformer.TransformerLM`` (GPT-2's shape),
 ``xing4`` (mHC streams, latent attention, an expert share), ``nemotron_h``
 (Mamba-2, GQA, experts in a latent), ``olmo_hybrid`` (Gated DeltaNet and
-full attention). **Served, three families** (``serve/decode.py`` picks a
+full attention). **Served, four families** (``serve/decode.py`` picks a
 family's programs by the configuration's type): ``transformer.TransformerLM``
 (learned positions, a key/value page buffer a layer), ``jamba.JambaLM``
-(Mamba-1 with multi-query attention: recurrent slot state beside the pages)
-and ``longcat_flash.LongcatFlashLM`` (shortcut-connected double layers:
-latent attention over a latent paged cache, rotary positions, routed and
-zero-compute experts). ``latent`` holds what the latent-attention models
-share (RMSNorm, RoPE, the low-rank query and key/value paths, the gated
-MLP)."""
+(Mamba-1 with multi-query attention: recurrent slot state beside the pages),
+``longcat_flash.LongcatFlashLM`` (shortcut-connected double layers: latent
+attention over a latent paged cache, rotary positions, routed and
+zero-compute experts) and ``laguna.LagunaLM`` (full and window attention
+layers of different head counts over pages of two kinds, two rotary rules,
+a per-head output gate, routed experts with a shared one). ``latent`` holds
+what several of them share (RMSNorm, RoPE and YaRN's frequencies, the
+low-rank query and key/value paths, the gated MLP)."""
 
 from tpu_sandbox.models.convnet import ConvNet  # noqa: F401
 from tpu_sandbox.models.convnet_s2d import ConvNetS2D  # noqa: F401

@@ -1,6 +1,7 @@
 """What the models with latent attention share (``models/xing4.py``,
-trained; ``models/longcat_flash.py``, served): RMSNorm, rotary positions,
-the low-rank query and key/value paths of MLA, and the gated MLP. One place,
+trained; ``models/longcat_flash.py``, served), and ``models/laguna.py`` of
+it what is not latent: RMSNorm, rotary positions and YaRN's frequencies, the
+low-rank query and key/value paths of MLA, and the gated MLP. One place,
 so that a change for one model is a change for the other, and the benchmark
 holds both.
 
@@ -14,6 +15,7 @@ the lines itself.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any
 
 import flax.linen as nn
@@ -54,6 +56,28 @@ def apply_rope(x, inv_freq, cos_sin_scale: float = 1.0, positions=None):
     x = x.astype(jnp.float32)
     a, b = x[..., : d // 2], x[..., d // 2:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, beta_fast: float,
+                  beta_slow: float, original_max: int):
+    """YaRN's blended inverse frequencies ``[dim / 2]`` (python floats in,
+    a float32 array out): below ``low`` the published frequency, above
+    ``high`` the interpolated one (divided by ``factor``), a linear ramp
+    between. Returns ``(inv_freq, low, high)``."""
+    def correction_dim(rotations):
+        return dim * math.log(original_max / (rotations * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    extra = 1.0 / theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return extra / factor * ramp + extra * (1.0 - ramp), low, high
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
 def _parts(dtype, param_dtype, eps):

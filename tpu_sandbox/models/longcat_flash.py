@@ -45,8 +45,8 @@ rule (weights ``routed_scaling_factor * p``, not normalised), the zero
 experts' term whole on every chip, and this chip's ``held`` experts; its
 row buffer and row tile follow the tokens of a decode call (``share_rows``,
 ``share_row_tile``); a whole sequence runs every held expert over all its
-tokens (``PromptShare``: a prompt's positions choose alike, and no row
-may drop).
+tokens (``parallel.expert.PromptShare``: a prompt's positions choose alike,
+and no row may drop).
 
 Scopes in a device trace: ``block{i}/mla{0,1}/{q_a, q_b, kv_a, rope, kv_b |
 absorb, unabsorb, o}``, ``block{i}/mlp{0,1}/{gate, up, down}``,
@@ -67,7 +67,7 @@ import jax.numpy as jnp
 from tpu_sandbox.models.latent import (GatedMlp, RMSNorm, apply_rope,
                                        low_rank_kv, low_rank_queries)
 from tpu_sandbox.ops.attention import causal_attention
-from tpu_sandbox.parallel.expert import (ExpertShare, count_share_table,
+from tpu_sandbox.parallel.expert import (ExpertShare, PromptShare,
                                          share_row_tile, share_rows)
 
 _F32 = jnp.float32
@@ -260,51 +260,6 @@ class LatentAttention(nn.Module):
         return nn.DenseGeneral(c, axis=(-2, -1), use_bias=False,
                                dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                                name="o")(out)
-
-
-class PromptShare(ExpertShare):
-    """The share of a whole sequence (the full forward, a prompt): no
-    buffer. Every held expert's products run over all T tokens, one expert
-    after another, and a token that did not choose an expert gets its
-    output at weight 0: T x held rows of work where the buffer does R, and
-    **no row can drop**. Under random weights the positions of a long
-    prompt come to share most of their hidden state and choose the same
-    experts (one held expert was given 3443 of a prompt's 6144 tokens, a
-    prompt 1.3 held rows a token where an even router gives 0.25; my chip
-    runs, PR 45); a buffer that shapes alone keep from dropping holds
-    ``min(top_k, held)`` rows a token, 12 T here, which is this form's 16 T
-    with a dispatch in front. Same parameters, scopes and counters as the
-    buffered share (``rows_dropped`` 0); ``moe.share_table`` counts it with
-    ``collect=dense``."""
-
-    @nn.nowrap
-    def _held(self, x, sel, weights, init):
-        c, h = x.shape[-1], len(self.held)
-        count_share_table(x.shape[0], self.top_k, h, 0, 0, c, "dense")
-        with jax.named_scope("dispatch"):
-            held = jnp.asarray(self.held, sel.dtype)
-            hit = sel[:, :, None] == held                           # [T, k, h]
-            w_held = jnp.where(hit, weights[:, :, None], 0).sum(1)  # [T, h]
-            n = hit.any(1).sum(0)                                   # [h]
-        with jax.named_scope("experts"):
-            stacks = [self.param(name, init, shape, self.param_dtype)
-                      for name, shape in (("w_gate", (h, c, self.d_ff)),
-                                          ("w_up", (h, c, self.d_ff)),
-                                          ("w_down", (h, self.d_ff, c)))]
-
-            def one(acc, expert):
-                gate, up, down, w_e = expert
-                hidden = (nn.silu(jnp.dot(x, gate.astype(self.dtype)))
-                          * jnp.dot(x, up.astype(self.dtype)))
-                out = jnp.dot(hidden.astype(self.dtype),
-                              down.astype(self.dtype))
-                return acc + w_e[:, None] * out.astype(_F32), None
-
-            y, _ = jax.lax.scan(one, jnp.zeros(x.shape, _F32),
-                                (*stacks, w_held.T))
-        return y.astype(self.dtype), {
-            "rows_held": n.sum(), "rows_dropped": jnp.zeros((), jnp.int32),
-            "expert_rows_max": n.max()}
 
 
 def expert_share(cfg: LongcatFlashConfig, tokens: int, name: str | None,

@@ -50,7 +50,8 @@ import jax.numpy as jnp
 
 from tpu_sandbox.models.latent import (  # noqa: F401  (RMSNorm, rms_norm:
     # the other models import them from here)
-    GatedMlp, RMSNorm, apply_rope, low_rank_kv, low_rank_queries, rms_norm)
+    GatedMlp, RMSNorm, apply_rope, low_rank_kv, low_rank_queries, rms_norm,
+    yarn_inv_freq, yarn_mscale)
 from tpu_sandbox.ops import pallas_mhc
 from tpu_sandbox.ops.attention import causal_attention
 from tpu_sandbox.parallel.expert import ExpertShare, share_rows
@@ -155,28 +156,6 @@ def sinkhorn(logits, iters: int, eps: float):
     # a loop of ``iters`` steps, not ``iters`` copies of the step: unrolled,
     # the copies are most of the compiled train step's instructions
     return jax.lax.scan(once, m, None, length=iters)[0]
-
-
-def yarn_inv_freq(dim: int, theta: float, factor: float, beta_fast: float,
-                  beta_slow: float, original_max: int):
-    """YaRN's blended inverse frequencies ``[dim / 2]`` (python floats in,
-    a float32 array out): below ``low`` the published frequency, above
-    ``high`` the interpolated one (divided by ``factor``), a linear ramp
-    between. Returns ``(inv_freq, low, high)``."""
-    def correction_dim(rotations):
-        return dim * math.log(original_max / (rotations * 2 * math.pi)) / (
-            2 * math.log(theta))
-
-    low = max(math.floor(correction_dim(beta_fast)), 0)
-    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
-    extra = 1.0 / theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
-    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
-                    / max(high - low, 1e-3), 0.0, 1.0)
-    return extra / factor * ramp + extra * (1.0 - ramp), low, high
-
-
-def yarn_mscale(factor: float, mscale: float) -> float:
-    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
 # --- modules ---

@@ -244,7 +244,8 @@ def _pad_len(s: int, *blocks: int | None) -> int:
     return _round_up(max(s, unit), unit)
 
 
-def _tile_flags(i, j, q_off, kv_off, *, causal, block_q, block_k, kv_len):
+def _tile_flags(i, j, q_off, kv_off, *, causal, block_q, block_k, kv_len,
+                window=None):
     """``(interior, masked)`` for the tile of q block ``i`` and kv block
     ``j`` at GLOBAL offsets ``q_off`` / ``kv_off``: interior tiles lie
     wholly under the diagonal and hold no padded key (plain products);
@@ -252,14 +253,25 @@ def _tile_flags(i, j, q_off, kv_off, *, causal, block_q, block_k, kv_len):
     that is neither lies above the diagonal and has no work. Python ints in,
     python bools out (the census of a grid, at trace time); traced scalars
     in, traced bools out (the kernel's own predicates, so the ring's
-    rotating source is covered)."""
+    rotating source is covered).
+
+    ``window`` (causal only): a band. Query ``q`` sees the keys ``q -
+    window < k <= q``: a tile wholly behind every query's window has no
+    work either, and one that the band's lower edge crosses is masked."""
     k_end = (j + 1) * block_k
     unpadded, padded = k_end <= kv_len, k_end > kv_len
     if not causal:
         return unpadded, padded
     q_lo, k_hi = q_off + i * block_q, kv_off + k_end - 1
     run = k_hi - block_k + 1 <= q_lo + block_q - 1
-    return run & (k_hi <= q_lo) & unpadded, run & ((k_hi > q_lo) | padded)
+    if window is None:
+        return run & (k_hi <= q_lo) & unpadded, run & ((k_hi > q_lo) | padded)
+    # the last query's window starts at q_hi - window + 1
+    edge = q_lo + block_q - 1 - window
+    run = run & (k_hi > q_lo - window)
+    inside, crossed = k_hi - block_k + 1 > edge, k_hi - block_k + 1 <= edge
+    return (run & (k_hi <= q_lo) & inside & unpadded,
+            run & ((k_hi > q_lo) | crossed | padded))
 
 
 def _tile_census(nq, nk, q_offset, kv_offset, **tile):
@@ -273,7 +285,7 @@ def _tile_census(nq, nk, q_offset, kv_offset, **tile):
 
 
 def _plan(kernel, form, block_q, block_k, causal, kv_len, q_offset,
-          kv_offset):
+          kv_offset, window=None):
     """The static half of a call: its grid ``(nq, nk)``, the keywords its
     kernel takes, and the counter in the always-on registry of which form
     and tiles it was built with and how many of its grid steps have work,
@@ -284,7 +296,7 @@ def _plan(kernel, form, block_q, block_k, causal, kv_len, q_offset,
 
     nq, nk = form.s // block_q, form.sk // block_k
     tile = dict(causal=causal, block_q=block_q, block_k=block_k,
-                kv_len=kv_len)
+                kv_len=kv_len, window=window)
     census = _tile_census(nq, nk, q_offset, kv_offset, **tile)
     heads = form.b * form.h
     choice = get_registry().counter("attn.tile_choice", labels={
@@ -295,7 +307,7 @@ def _plan(kernel, form, block_q, block_k, causal, kv_len, q_offset,
                             else heads * sum(census)),
         "layout": "packed" if form.packed else "padded",
         "heads_per_block": form.group,
-    })
+    } | ({} if window is None else {"window": window}))
     return nq, nk, dict(tile, census=census, sk=form.sk, form=form), choice
 
 
@@ -318,6 +330,9 @@ def _on_tile(i, j, q_off, kv_off, step, *, census, sk, **tile):
             q_pos = q_off + i * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
             below = q_pos >= kv_off + k_pos
+            if tile.get("window") is not None:
+                below = jnp.logical_and(
+                    below, q_pos - tile["window"] < kv_off + k_pos)
             ok = below if ok is None else jnp.logical_and(ok, below)
         return ok
 
@@ -427,7 +442,15 @@ def _first_q_block(j, q_off_ref, kv_off_ref, block_q, block_k, nq):
     return jnp.minimum(jax.lax.div(jnp.maximum(ahead, 0), block_q), nq - 1)
 
 
-def _block_specs(packed, causal, block_q, block_k, nq, nk, inner):
+def _first_kv_block(i, q_off_ref, kv_off_ref, block_q, block_k, window):
+    """The first kv block that the window of q block ``i``'s first query
+    reaches."""
+    behind = q_off_ref[0] + i * block_q - window + 1 - kv_off_ref[0]
+    return jax.lax.div(jnp.maximum(behind, 0), block_k)
+
+
+def _block_specs(packed, causal, block_q, block_k, nq, nk, inner,
+                 window=None):
     """``(q_side, kv_side)``: BlockSpec factories ``f(width)`` for blocks
     ``[block, width]`` over a grid (B, H / G, outer, G, inner) whose
     innermost dimension walks the kv blocks (``inner == "kv"``: forward,
@@ -440,7 +463,9 @@ def _block_specs(packed, causal, block_q, block_k, nq, nk, inner):
     under causality, to the blocks that have work: on the steps above the
     diagonal the index stands still, the pipeline sees the block it already
     holds and fetches nothing. The offsets arrive as scalar prefetch, so
-    that holds for the ring's traced ones too."""
+    that holds for the ring's traced ones too. Under a ``window`` (the
+    forward pass alone) the kv index stands still likewise on the first
+    block a q block's window reaches, through the blocks behind it."""
     def outer(x, y, q_off, kv_off):
         return x
 
@@ -448,6 +473,9 @@ def _block_specs(packed, causal, block_q, block_k, nq, nk, inner):
         if causal:
             j = jnp.minimum(j, _last_kv_block(i, q_off, kv_off, block_q,
                                               block_k, nk))
+            if window is not None:
+                j = jnp.maximum(j, _first_kv_block(i, q_off, kv_off, block_q,
+                                                   block_k, window))
         return j
 
     def inner_q(j, i, q_off, kv_off):
@@ -487,7 +515,8 @@ def _offsets(q_offset, kv_offset):
 
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, kv_len,
-               q_offset=0, kv_offset=0, out_dtype=None, heads=None):
+               q_offset=0, kv_offset=0, out_dtype=None, heads=None,
+               window=None):
     """q,k [B,H,S,D], v [B,H,S,Dv] (S a multiple of the blocks, D and Dv
     lane-aligned; ``kv_len`` is the true pre-padding length) ->
     (out [B,H,S,Dv], lse [B,H,S]); with ``heads`` given, q, k, v packed
@@ -500,7 +529,8 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, kv_len,
     rotating source offset); the causal block skip stays active either way
     because the kernel predicates on the runtime offsets. ``out_dtype``
     defaults to q's dtype; partial-attention callers pass fp32 so the
-    cross-block merge never sees a rounded partial.
+    cross-block merge never sees a rounded partial. ``window``: the
+    band of ``_tile_flags``.
     """
     from jax.experimental.pallas import tpu as pltpu
 
@@ -511,10 +541,10 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, kv_len,
                                     q.dtype.itemsize, block_q=block_q,
                                     block_k=block_k)
     nq, nk, tile, choice = _plan("fwd", form, block_q, block_k, causal,
-                                 kv_len, q_offset, kv_offset)
+                                 kv_len, q_offset, kv_offset, window)
     kernel = functools.partial(_fwd_kernel, scale=scale, **tile)
     q_side, kv_side = _block_specs(form.packed, causal, block_q, block_k, nq,
-                                   nk, "kv")
+                                   nk, "kv", window)
     with kernel_site("flash_fwd", choice):
         out, lse = pl.pallas_call(
             kernel,
@@ -803,6 +833,7 @@ def flash_attention(
     block_q: int | None = None,
     block_k: int | None = None,
     interpret: bool | None = None,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """Flash attention over q, k [B, S, H, D] and v [B, S, H, Dv] (the
     layout used by models.transformer.SelfAttention and
@@ -819,19 +850,31 @@ def flash_attention(
     produce zero output lanes, sliced off). ``block_q`` / ``block_k`` left
     ``None`` are chosen per kernel from the shapes (``choose_tiles``);
     given, they hold for all three kernels.
+
+    ``window`` (with ``causal``): query ``i`` attends to the keys ``i -
+    window < j <= i``, a band; key tiles wholly behind it are skipped, not
+    masked. **Forward only** (a served prompt's window layers): the two
+    backward kernels know no band yet.
     """
     b, s, h, d = q.shape
     dv = v.shape[-1]
     if scale is None:
         scale = 1.0 / float(d) ** 0.5
     static = (float(scale), causal, block_q, block_k, interpret, s)
+    if window is None:
+        core = _flash_core
+    elif not causal or window < 1:
+        raise ValueError(f"a window of {window} keys, causal {causal}")
+    else:
+        def core(q, k, v, *static):
+            *static, heads = static
+            return _flash_fwd(q, k, v, *static, heads=heads, window=window)[0]
     if _heads_per_block(q, k, v, block_q, block_k):
-        out = _flash_core(*(x.reshape(b, s, h * d) for x in (q, k, v)),
-                          *static, h)
+        out = core(*(x.reshape(b, s, h * d) for x in (q, k, v)), *static, h)
         return out.reshape(b, s, h, d)
     sp = _pad_len(s, block_q, block_k)
     dp, dvp = _round_up(d, _LANE), _round_up(dv, _LANE)
-    out = _flash_core(
+    out = core(
         _to_bhsd(q, sp, dp), _to_bhsd(k, sp, dp), _to_bhsd(v, sp, dvp),
         *static, None,
     )

@@ -42,6 +42,16 @@ a NaN). A row's result is a function of its own query, table and length:
 the chunking follows the length alone, so a row gives the same bits in any
 slot, beside any batch, from any physical blocks.
 
+**A window** (``window``; ``serve/decode.py``'s fourth family): a row
+attends to its last ``min(lengths[b], window)`` positions alone, and its
+table is a **ring** (``serve/cache.py``): the block that holds position
+``p`` is entry ``(p // block_size) % max_blocks`` of the row's table. The
+walk starts at the block that holds ``max(0, lengths[b] - window)`` and
+copies no block before it, so a window layer's bytes follow the window, not
+the sequence; the positions of that first block that lie before the window
+are the sequence's own older rows, and their scores are replaced like those
+behind the length.
+
 Interpreted off-TPU, like ops.pallas_attention.
 """
 
@@ -66,7 +76,7 @@ _F32 = jnp.float32
 
 def _kernel(tables_ref, lengths_ref, q_ref, k_hbm, *rest, pages: int,
             max_blocks: int, hkv: int, group: int, precision, scale: float,
-            v_dim: int | None):
+            v_dim: int | None, window: int | None = None):
     # a latent cache has no V pages: the values are lanes of the K row
     if v_dim is None:
         v_hbm, o_ref, k_buf, v_buf, sems, state = rest
@@ -78,16 +88,30 @@ def _kernel(tables_ref, lengths_ref, q_ref, k_hbm, *rest, pages: int,
     hd = width // hkv
     tokens = pages * bs
     length = lengths_ref[b]
-    n_chunks = pl.cdiv(length, tokens)
+
+    def base(row):
+        """The first block of a windowed ``row``'s walk: the one that holds
+        the first position of its window."""
+        return jnp.maximum(lengths_ref[row] - window, 0) // bs
+
+    # the positions the walk covers, from its first block's start
+    span = length if window is None else length - base(b) * bs
+    n_chunks = pl.cdiv(span, tokens)
 
     def copies(row, chunk, slot, act):
         """``act`` on the copy of every page of ``row``'s ``chunk`` that its
         length reaches, K's and V's, into buffer ``slot``."""
         first = chunk * pages
+        if window is not None:
+            first = base(row) + first
         live = jnp.minimum(pl.cdiv(lengths_ref[row], bs) - first, pages)
 
         def page(j, carry):
-            block = tables_ref[row * max_blocks + first + j]
+            if window is None:
+                block = tables_ref[row * max_blocks + first + j]
+            else:   # the table is a ring
+                block = tables_ref[row * max_blocks
+                                   + (first + j) % max_blocks]
             at = pl.ds(pl.multiple_of(j * bs, bs), bs)
             act(pltpu.make_async_copy(
                 k_hbm.at[block], k_buf.at[slot, at], sems.at[0, slot]))
@@ -158,7 +182,7 @@ def _kernel(tables_ref, lengths_ref, q_ref, k_hbm, *rest, pages: int,
             state[1] = (nxt < rows).astype(jnp.int32)
 
         copies(b, i, slot, lambda copy: copy.wait())
-        left = length - i * tokens          # positions of this chunk in use
+        left = span - i * tokens            # positions of this chunk in use
 
         @pl.when(left < tokens)
         def _():
@@ -171,8 +195,12 @@ def _kernel(tables_ref, lengths_ref, q_ref, k_hbm, *rest, pages: int,
         s = jax.lax.dot_general(
             qbd, k, (((1,), (1,)), ((), ())), precision=precision,
             preferred_element_type=_F32) * scale            # [heads, T]
-        s = jnp.where(
-            jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) < left, s, NEG)
+        at = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        seen = at < left
+        if window is not None:   # the first block's rows before the window
+            seen = jnp.logical_and(
+                seen, at >= length - window - base(b) * bs - i * tokens)
+        s = jnp.where(seen, s, NEG)
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new)
@@ -197,7 +225,8 @@ def _kernel(tables_ref, lengths_ref, q_ref, k_hbm, *rest, pages: int,
 
 @traced_once
 def _paged_attn(q, k_pages, v_pages, block_tables, lengths, *,
-                pages_per_step, interpret, scale=None, v_dim=None):
+                pages_per_step, interpret, scale=None, v_dim=None,
+                window=None):
     bsz, hq, hd = q.shape
     _, bs, width = k_pages.shape
     latent = v_pages is None
@@ -224,7 +253,7 @@ def _paged_attn(q, k_pages, v_pages, block_tables, lengths, *,
                 precision=(jax.lax.Precision.HIGHEST if dtype == _F32
                            else None),
                 scale=(hd ** -0.5 if scale is None else scale),
-                v_dim=v_dim if latent else None),
+                v_dim=v_dim if latent else None, window=window),
             out_shape=jax.ShapeDtypeStruct((bsz, group, out_width), q.dtype),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2, grid=(bsz,),
@@ -253,7 +282,8 @@ def _paged_attn(q, k_pages, v_pages, block_tables, lengths, *,
 
 def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
                     pages_per_step: int, interpret: bool | None = None,
-                    scale: float | None = None, v_dim: int | None = None):
+                    scale: float | None = None, v_dim: int | None = None,
+                    window: int | None = None):
     """The attention output ``[B, Hq, D]`` (``q``'s type) of ``q [B, Hq,
     D]`` over each row's first ``lengths[b]`` cached positions: ``k_pages``,
     ``v_pages`` ``[num_blocks, block_size, Hkv * D]`` through
@@ -265,10 +295,18 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
 
     ``v_pages`` None: a latent cache. ``q [B, Hq, d]`` with ``d`` at most
     the pages' width, every query head against the same row, the values the
-    row's first ``v_dim`` lanes: ``[B, Hq, v_dim]``."""
+    row's first ``v_dim`` lanes: ``[B, Hq, v_dim]``.
+
+    ``window``: each row over its last ``min(lengths[b], window)`` positions
+    alone, ``block_tables`` a ring (module docstring) of at least
+    ``ceil(window / block_size) + 1`` blocks a row."""
     if v_pages is None and not v_dim:
         raise ValueError("a latent cache's values need their width (v_dim)")
+    if window is not None and block_tables.shape[1] \
+            < -(-window // k_pages.shape[1]) + 1:
+        raise ValueError(f"a ring of {block_tables.shape[1]} blocks of "
+                         f"{k_pages.shape[1]} cannot hold a window of {window}")
     return _paged_attn(q, k_pages, v_pages, block_tables, lengths,
                        pages_per_step=pages_per_step,
                        interpret=default_interpret(interpret), scale=scale,
-                       v_dim=v_dim)
+                       v_dim=v_dim, window=window)

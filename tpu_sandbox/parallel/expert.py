@@ -36,6 +36,8 @@ import jax.numpy as jnp
 
 from tpu_sandbox.models.transformer import TransformerConfig
 
+_F32 = jnp.float32
+
 
 class MoeMlp(nn.Module):
     """Drop-in MLP replacement for models.transformer.Block (mlp_cls)."""
@@ -503,7 +505,7 @@ class ExpertShare(nn.Module):
         share's static row buffer: ``(y, counts)``, the counts ``rows_held``,
         ``rows_dropped`` and ``expert_rows_max`` of ``share_layout``. (A
         model whose call cannot live with a static buffer overrides this:
-        ``models/longcat_flash.py::PromptShare``.)"""
+        ``PromptShare``, below.)"""
         from tpu_sandbox.ops.pallas_grouped_matmul import grouped_matmul
 
         c = x.shape[-1]
@@ -545,3 +547,48 @@ class ExpertShare(nn.Module):
             out = (out.astype(jnp.float32) * w_rows).astype(self.dtype)
             y = _collect(out, *back, tok_r, lay["row_valid"])
         return y, lay
+
+
+class PromptShare(ExpertShare):
+    """The share of a whole sequence (the full forward, a prompt): no
+    buffer. Every held expert's products run over all T tokens, one expert
+    after another, and a token that did not choose an expert gets its
+    output at weight 0: T x held rows of work where the buffer does R, and
+    **no row can drop**. Under random weights the positions of a long
+    prompt come to share most of their hidden state and choose the same
+    experts (one held expert was given 3443 of a prompt's 6144 tokens, a
+    prompt 1.3 held rows a token where an even router gives 0.25; my chip
+    runs, PR 45); a buffer that shapes alone keep from dropping holds
+    ``min(top_k, held)`` rows a token, 12 T here, which is this form's 16 T
+    with a dispatch in front. Same parameters, scopes and counters as the
+    buffered share (``rows_dropped`` 0); ``moe.share_table`` counts it with
+    ``collect=dense``."""
+
+    @nn.nowrap
+    def _held(self, x, sel, weights, init):
+        c, h = x.shape[-1], len(self.held)
+        count_share_table(x.shape[0], self.top_k, h, 0, 0, c, "dense")
+        with jax.named_scope("dispatch"):
+            held = jnp.asarray(self.held, sel.dtype)
+            hit = sel[:, :, None] == held                           # [T, k, h]
+            w_held = jnp.where(hit, weights[:, :, None], 0).sum(1)  # [T, h]
+            n = hit.any(1).sum(0)                                   # [h]
+        with jax.named_scope("experts"):
+            stacks = [self.param(name, init, shape, self.param_dtype)
+                      for name, shape in (("w_gate", (h, c, self.d_ff)),
+                                          ("w_up", (h, c, self.d_ff)),
+                                          ("w_down", (h, self.d_ff, c)))]
+
+            def one(acc, expert):
+                gate, up, down, w_e = expert
+                hidden = (nn.silu(jnp.dot(x, gate.astype(self.dtype)))
+                          * jnp.dot(x, up.astype(self.dtype)))
+                out = jnp.dot(hidden.astype(self.dtype),
+                              down.astype(self.dtype))
+                return acc + w_e[:, None] * out.astype(_F32), None
+
+            y, _ = jax.lax.scan(one, jnp.zeros(x.shape, _F32),
+                                (*stacks, w_held.T))
+        return y.astype(self.dtype), {
+            "rows_held": n.sum(), "rows_dropped": jnp.zeros((), jnp.int32),
+            "expert_rows_max": n.max()}

@@ -45,6 +45,22 @@ Design points:
   block a live sequence already holds counts as
   ``serve.prefix_reuse_declined``. (Snapshots of the state at block
   boundaries would lift this; they are not built.)
+- **Two kinds of block behind one allocator** (``CacheConfig.window``; a
+  model whose layers are *full* or *window* attention, ``models/laguna.py``).
+  The window layers' pages are a pool of their own (``window_blocks``, its
+  block 0 null like the other's), and a sequence's blocks there are a
+  **ring**: position ``p`` lives in entry ``(p // block_size) %
+  ring_blocks`` of ``SeqAlloc.window_ids``, ``ring_blocks = ceil(window /
+  block_size) + 1`` being the blocks a window can touch. A sequence owns
+  ``ceil(n / block_size)`` full blocks and ``min`` of that and
+  ``ring_blocks`` window blocks, however long it grows: past the ring a
+  new block of positions overwrites the one the window has left
+  (``cache.window_blocks_recycled``). Admission, ``grow``, ``free`` and
+  preemption move both kinds together or not at all. A prompt longer than
+  the window writes only its last window's rows there
+  (``window_dest_indices``). **No prefix sharing beside window layers**
+  either, for the recurrent layers' reason: their rows behind a shared
+  prefix's end are gone.
 - **Recompute on eviction.** When a sequence is preempted its blocks are
   freed and the request is requeued with its original prompt; decoding is
   greedy and the step functions are bitwise deterministic, so the replay
@@ -67,10 +83,18 @@ class CacheConfig:
     num_blocks: int = 64          # includes the reserved null block 0
     block_size: int = 8           # positions per block
     max_blocks_per_seq: int = 8   # block-table width == max context / block_size
+    window: int = 0               # positions a window layer attends to; 0: none
+    window_blocks: int = 0        # the window layers' pool, its null block too
 
     @property
     def max_context(self) -> int:
         return self.max_blocks_per_seq * self.block_size
+
+    @property
+    def ring_blocks(self) -> int:
+        """The window blocks a sequence can own: what ``window`` positions
+        can touch, wherever they start in a block."""
+        return -(-self.window // self.block_size) + 1 if self.window else 0
 
 
 def _chain_hashes(tokens: Sequence[int], block_size: int) -> list[bytes]:
@@ -108,6 +132,8 @@ class SeqAlloc:
     n_shared: int                  # leading block_ids reused from the prefix cache
     prompt_hashes: list[bytes]     # chain hashes of the prompt's full blocks
     length: int = 0                # tokens currently stored
+    # the window pool's blocks, a ring: position p in entry (p // bs) % ring
+    window_ids: list[int] = field(default_factory=list)
 
 
 class PagedKVCache:
@@ -116,16 +142,24 @@ class PagedKVCache:
     def __init__(self, config: CacheConfig, *, recurrent: bool = False):
         if config.num_blocks < 2:
             raise ValueError("need at least one allocatable block beyond null")
+        if config.window and config.window_blocks < 2:
+            raise ValueError("window layers need a pool of their own "
+                             "(window_blocks)")
         self.config = config
-        self.recurrent = recurrent
+        # neither recurrent state nor a window's rows can be resumed at a
+        # shared prefix's end
+        self.recurrent = recurrent or bool(config.window)
         self._free: list[int] = list(range(config.num_blocks - 1, 0, -1))
+        self._window_free: list[int] = list(
+            range(config.window_blocks - 1, 0, -1))
         self._refs: dict[int, int] = {}
         # chain hash -> block id, insertion-ordered for FIFO cache eviction
         self._prefix: dict[bytes, int] = {}
         self._seqs: dict[int, SeqAlloc] = {}
         self._next_seq = 0
         self.stats = {"prefix_hits": 0, "prefix_blocks_reused": 0,
-                      "evicted_cache_blocks": 0, "prefix_reuse_declined": 0}
+                      "evicted_cache_blocks": 0, "prefix_reuse_declined": 0,
+                      "window_blocks_seq_max": 0}
 
     # -- introspection -------------------------------------------------------
 
@@ -147,14 +181,21 @@ class PagedKVCache:
         entries = list(self._prefix)[-top_k:]
         return [h[:DIGEST_BYTES].hex() for h in entries]
 
+    @property
+    def free_window_blocks(self) -> int:
+        return len(self._window_free)
+
     def blocks_needed(self, prompt: Sequence[int], max_new: int) -> int:
+        """Full blocks; a sequence's window blocks are ``min`` of these and
+        the ring."""
         total = len(prompt) + max_new
         return -(-total // self.config.block_size)
 
     def can_admit(self, prompt: Sequence[int], max_new: int) -> bool:
         need = self.blocks_needed(prompt, max_new)
         shared = self._count_shared(prompt)
-        return need - shared <= len(self._free) + self._reclaimable()
+        return need - shared <= len(self._free) + self._reclaimable() \
+            and min(need, self.config.ring_blocks) <= len(self._window_free)
 
     # -- allocation ----------------------------------------------------------
 
@@ -180,8 +221,9 @@ class PagedKVCache:
                 break
             shared.append(bid)
         # blocks we are about to pin as shared are not reclaimable fuel
+        need_window = min(need, cfg.ring_blocks)
         if need - len(shared) > len(self._free) + self._reclaimable(
-                exclude=set(shared)):
+                exclude=set(shared)) or need_window > len(self._window_free):
             return None
         for bid in shared:
             self._refs[bid] += 1
@@ -195,9 +237,11 @@ class PagedKVCache:
             n_shared=len(shared),
             prompt_hashes=hashes,
             length=0,
+            window_ids=[self._window_free.pop() for _ in range(need_window)],
         )
         self._next_seq += 1
         self._seqs[alloc.seq_id] = alloc
+        self._count_blocks(alloc)
         return alloc
 
     def commit_prefix(self, alloc: SeqAlloc) -> None:
@@ -215,16 +259,30 @@ class PagedKVCache:
             self._register_prefix(alloc)
         for bid in alloc.block_ids:
             self._decref(bid)
+        self._window_free.extend(alloc.window_ids)
+        alloc.window_ids = []
+        self._count_blocks()
 
     def grow(self, alloc: SeqAlloc) -> bool:
-        """Append one block when decode crosses a block boundary. True on
-        success; False means block pressure (caller preempts-to-requeue)."""
+        """Append one block when decode crosses a block boundary: a full
+        block and, beside window layers, the ring's next entry (a window
+        block more until the ring is whole, then the one the window has
+        left, overwritten). True on success; False means block pressure in
+        either pool (caller preempts-to-requeue), and nothing was taken."""
         if len(alloc.block_ids) >= self.config.max_blocks_per_seq:
+            return False
+        ring_grows = len(alloc.window_ids) < self.config.ring_blocks
+        if ring_grows and not self._window_free:
             return False
         try:
             alloc.block_ids.append(self._take_free())
         except MemoryError:
             return False
+        if ring_grows:
+            alloc.window_ids.append(self._window_free.pop())
+        elif self.config.window:
+            get_registry().counter("cache.window_blocks_recycled").inc()
+        self._count_blocks(alloc)
         return True
 
     def flush_prefix_cache(self) -> int:
@@ -257,14 +315,35 @@ class PagedKVCache:
         prefix blocks and bucket padding are redirected to the null block
         (flat slots [0, bs)) so prefill never rewrites shared content.
         """
+        bs = self.config.block_size
+        idx = np.zeros(bucket_len, np.int64)
+        p = np.arange(min(bucket_len, len(alloc.block_ids) * bs))
+        own = p // bs >= alloc.n_shared  # shared prefix: the null block
+        idx[p[own]] = np.asarray(alloc.block_ids, np.int64)[p[own] // bs] \
+            * bs + p[own] % bs
+        return idx
+
+    def window_table(self, alloc: SeqAlloc) -> np.ndarray:
+        """The ring as a decode call takes it: ``[ring_blocks]`` int32,
+        null-block padded; position p in entry ``(p // bs) % ring_blocks``."""
+        row = np.zeros(self.config.ring_blocks, np.int32)
+        row[: len(alloc.window_ids)] = alloc.window_ids
+        return row
+
+    def window_dest_indices(self, alloc: SeqAlloc, bucket_len: int,
+                            prompt_len: int) -> np.ndarray:
+        """``dest_indices`` into the window layers' pool: the prompt's
+        positions from the block that holds the first of its last
+        ``window`` on, through the ring; everything before them, and the
+        bucket's padding, to the null block (padding behind the prompt
+        would land on ring entries that hold the window)."""
         cfg = self.config
         bs = cfg.block_size
         idx = np.zeros(bucket_len, np.int64)
-        for p in range(min(bucket_len, len(alloc.block_ids) * bs)):
-            b = p // bs
-            if b < alloc.n_shared:
-                continue  # shared prefix: leave pointed at null block
-            idx[p] = alloc.block_ids[b] * bs + p % bs
+        first = max(prompt_len - cfg.window, 0) // bs * bs
+        p = np.arange(first, min(prompt_len, bucket_len))
+        idx[p] = np.asarray(alloc.window_ids, np.int64)[
+            p // bs % cfg.ring_blocks] * bs + p % bs
         return idx
 
     # -- internals -----------------------------------------------------------
@@ -314,6 +393,25 @@ class PagedKVCache:
             bid = alloc.block_ids[i]
             self._prefix[hh] = bid
             self._refs[bid] += 1  # the cache's own reference
+
+    def _count_blocks(self, alloc: SeqAlloc | None = None) -> None:
+        """The pools' gauges (``cache.blocks{kind, state}``) and, beside
+        window layers, the most window blocks a sequence has owned."""
+        reg = get_registry()
+        cfg = self.config
+        for kind, free, total in (
+                ("full", len(self._free), cfg.num_blocks - 1),
+                ("window", len(self._window_free), cfg.window_blocks - 1)):
+            if total > 0:
+                reg.gauge("cache.blocks", labels={
+                    "kind": kind, "state": "free"}).set(free)
+                reg.gauge("cache.blocks", labels={
+                    "kind": kind, "state": "held"}).set(total - free)
+        if alloc is not None and cfg.window:
+            self.stats["window_blocks_seq_max"] = max(
+                self.stats["window_blocks_seq_max"], len(alloc.window_ids))
+            reg.gauge("cache.window_blocks_seq_max").set(
+                self.stats["window_blocks_seq_max"])
 
     def _decref(self, bid: int) -> None:
         self._refs[bid] -= 1

@@ -1,6 +1,7 @@
 """AOT-compiled static-shape prefill and single-token decode steps, a
 model family at a time (``build_decode_step`` picks by the configuration's
-type; three families: ``TransformerLM``, ``JambaLM``, ``LongcatFlashLM``).
+type; four families: ``TransformerLM``, ``JambaLM``, ``LongcatFlashLM``,
+``LagunaLM``).
 
 Both steps run the *unmodified* model — prefill taps per-layer K/V through
 the model's ``kv_cache`` sow collection, decode injects an ``attention_fn``
@@ -52,6 +53,20 @@ buffer is the expert shares' device counters (``rows_held``,
 ``rows_dropped``, ``real_choices``, ``zero_choices``, ``steps`` a layer: no
 transfer a step; whoever wants them reads them once), the router's bias
 rides with the weights (``params = {"params", "router_bias"}``).
+
+The fourth family (``LagunaLM``: grouped-query attention whose layers are
+*full* or *window*, a per-head gate, routed experts) is the first written
+against **layer kinds**: ``page_shapes(..., kinds=...)`` gives a layer the
+pages of its kind -- K/V rows of one width, a full layer's from the cache's
+``num_blocks``, a window layer's from its ``window_blocks`` -- and both
+programs take **two tables**: prefill a second ``dest_idx`` (a prompt's last
+window through the ring, ``cache.window_dest_indices``; a window layer
+stores the bucket's last ``ring_blocks`` blocks' rows alone), decode the
+rings ``[B, ring_blocks]`` beside the block tables. A window layer's read
+(``_attend(..., window=, kind=)``) starts at the block that holds the
+first position of the window; the two reads stand under
+``gather_ctx/full`` and ``gather_ctx/window``. The third donated buffer is
+the shares' counters, as LongCat's.
 
 A model with recurrent layers (``JambaLM``, ``recurrent``) holds beside the
 pages the Mamba layers' **slot state** (``models/jamba.py::state_shapes``:
@@ -117,6 +132,7 @@ from tpu_sandbox.serve.cache import CacheConfig
 if TYPE_CHECKING:  # imported where it is built: a second of imports (the
     # Pallas kernels' modules) that a TransformerLM replica never needs
     from tpu_sandbox.models.jamba import JambaConfig
+    from tpu_sandbox.models.laguna import LagunaConfig
     from tpu_sandbox.models.longcat_flash import LongcatFlashConfig
 
 
@@ -154,9 +170,11 @@ class DecodeStep:
     buckets: tuple[int, ...]
     cache_dtype: Any
     # bucket length -> compiled prefill(params, *buffers, tokens, dest,
-    # last[, slot]): the slot only where ``recurrent``
+    # last[, slot | window_dest]): the slot only where ``recurrent``, the
+    # second destinations only beside window layers (``cache_cfg.window``)
     prefill: dict[int, Callable]
-    # compiled decode(params, *buffers, tokens, lengths, block_tables)
+    # compiled decode(params, *buffers, tokens, lengths, block_tables[,
+    # window_tables])
     decode: Callable
     # shapes of the device state both programs take and give back, donated
     # (``buffer_shapes``): ``(k_pages, v_pages)``, each a ``Pages`` of one
@@ -221,7 +239,8 @@ class Pages(tuple):
 
 def page_shapes(cache_cfg: CacheConfig, n_layers: int, n_kv_heads: int,
                 head_dim: int, cache_dtype: Any, *,
-                latent: bool = False) -> tuple[Pages, Pages]:
+                latent: bool = False,
+                kinds: tuple[str, ...] | None = None) -> tuple[Pages, Pages]:
     """``(k_pages, v_pages)``: a buffer ``[num_blocks, block_size,
     n_kv_heads * head_dim]`` each of ``n_layers`` attention layers. A
     token's heads lie side by side in the minor dimension: with 64 there
@@ -234,13 +253,17 @@ def page_shapes(cache_cfg: CacheConfig, n_layers: int, n_kv_heads: int,
     separate V -- ``v_pages`` is empty, the values are lanes of the same
     row -- padded to whole 128-lane tiles (640): the chip lays a minor
     dimension out in whole tiles anyway, and a row that says so is one the
-    paged kernel can copy (``mla.cache_layout`` counts what that wastes)."""
+    paged kernel can copy (``mla.cache_layout`` counts what that wastes).
+
+    ``kinds`` (a layer: ``full`` | ``window``): a window layer's buffer has
+    the blocks of the cache's window pool (``window_blocks``), rows of the
+    same width."""
     if latent:
         head_dim += -head_dim % 128
-    page = jax.ShapeDtypeStruct(
-        (cache_cfg.num_blocks, cache_cfg.block_size, n_kv_heads * head_dim),
-        cache_dtype)
-    pages = Pages(page for _ in range(n_layers))
+    pages = Pages(jax.ShapeDtypeStruct(
+        (cache_cfg.window_blocks if kind == "window" else cache_cfg.num_blocks,
+         cache_cfg.block_size, n_kv_heads * head_dim), cache_dtype)
+        for kind in (kinds or ("full",) * n_layers))
     return pages, (Pages() if latent else pages)
 
 
@@ -262,6 +285,14 @@ def buffer_shapes(model_cfg: TransformerConfig | JambaConfig,
         return (*page_shapes(cache_cfg, 2 * model_cfg.num_layers, 1,
                              model_cfg.latent_dim, cache_dtype, latent=True),
                 counter_shapes(model_cfg))
+    if _family(model_cfg) == "laguna":
+        from tpu_sandbox.models.laguna import counter_shapes
+
+        # pages by the layer's kind; beside them the shares' counters
+        return (*page_shapes(
+            cache_cfg, model_cfg.num_hidden_layers,
+            model_cfg.num_key_value_heads, model_cfg.head_dim, cache_dtype,
+            kinds=model_cfg.layer_kinds), counter_shapes(model_cfg))
     from tpu_sandbox.models.jamba import state_shapes
 
     return (*page_shapes(
@@ -345,14 +376,30 @@ def pages_per_step(width: int, block_size: int, cache_dtype: Any,
     return pages
 
 
+def _ring_seen(block_tables: jnp.ndarray, lengths: jnp.ndarray,
+               block_size: int, window: int) -> jnp.ndarray:
+    """``[B, ring * block]`` bool: which rows of a gathered ring (entry r
+    holds the newest block b of the sequence with ``b % ring == r``) lie in
+    the row's window ``[lengths - window, lengths)``."""
+    ring = block_tables.shape[1]
+    last = (lengths[:, None] - 1) // block_size                  # [B, 1]
+    block = last - (last - jnp.arange(ring)[None, :]) % ring     # [B, ring]
+    pos = (block[:, :, None] * block_size
+           + jnp.arange(block_size)).reshape(lengths.shape[0], -1)
+    return ((pos >= jnp.maximum(lengths[:, None] - window, 0))
+            & (pos < lengths[:, None]))
+
+
 def _attend_jnp(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
                 block_tables: jnp.ndarray, lengths: jnp.ndarray,
-                n_kv_heads: int) -> jnp.ndarray:
+                n_kv_heads: int, window: int | None = None,
+                scope: str = "gather_ctx") -> jnp.ndarray:
     """``_attend`` in plain ``jnp``: every row's context gathered whole at
     ``max_context`` (``gather_ctx``), the products and the softmax over it,
-    the positions behind ``lengths`` masked."""
+    the positions behind ``lengths`` masked (``window``: the table a ring,
+    the positions outside the window masked, ``_ring_seen``)."""
     bsz, hq, hd = q.shape
-    with jax.named_scope("gather_ctx"):
+    with jax.named_scope(scope):
         kc = _gather(k_pages, block_tables, n_kv_heads).astype(q.dtype)
         vc = _gather(v_pages, block_tables, n_kv_heads).astype(q.dtype)
     # a key/value head's group of query heads side by side
@@ -360,7 +407,10 @@ def _attend_jnp(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     scores = jnp.einsum("bhgd,bkhd->bhgk", qg, kc,
                         preferred_element_type=jnp.float32)
     scores = scores / jnp.sqrt(jnp.float32(hd))
-    kv_mask = jnp.arange(kc.shape[1])[None, :] < lengths[:, None]
+    if window is None:
+        kv_mask = jnp.arange(kc.shape[1])[None, :] < lengths[:, None]
+    else:
+        kv_mask = _ring_seen(block_tables, lengths, k_pages.shape[1], window)
     scores = jnp.where(kv_mask[:, None, None, :], scores, -jnp.inf)
     w = jnp.nan_to_num(jnp.exp(scores - scores.max(-1, keepdims=True)))
     w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-30)
@@ -370,7 +420,8 @@ def _attend_jnp(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
 
 def _attend(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
             block_tables: jnp.ndarray, lengths: jnp.ndarray,
-            n_kv_heads: int) -> jnp.ndarray:
+            n_kv_heads: int, *, window: int | None = None,
+            kind: str | None = None) -> jnp.ndarray:
     """The new token's attention output ``[B, Hq, D]`` of ``q [B, Hq, D]``
     over the first ``lengths[b]`` positions of one layer's pages, its own
     among them (``_store`` ran): the paged-attention kernel where
@@ -378,7 +429,12 @@ def _attend(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     lie, else ``_attend_jnp``. ``lengths == 0`` gives zeros. Either way the
     read of the context through the table stands under ``gather_ctx`` -- the
     kernel whole, the ``jnp`` form's gathers -- and the site counts which
-    it took (``paged_attn.kernel_choice``)."""
+    it took (``paged_attn.kernel_choice``).
+
+    A model written against layer kinds names the layer's ``kind`` (the
+    scope is then ``gather_ctx/<kind>``) and, for a window layer, its
+    ``window``: ``block_tables`` is the rows' rings, and the read covers
+    the last ``min(lengths, window)`` positions alone."""
     from tpu_sandbox.obs import get_registry
     from tpu_sandbox.ops.pallas_common import kernel_site
 
@@ -392,15 +448,17 @@ def _attend(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
             "head_dim": q.shape[2], "block_size": block_size,
             "pages_per_step": pages or 0,
             "max_blocks": block_tables.shape[1], "batch": q.shape[0],
-            "qk_dim": q.shape[2], "v_dim": q.shape[2]}))
+            "qk_dim": q.shape[2], "v_dim": q.shape[2]}
+        | ({} if kind is None else {"kind": kind, "window": window or 0})))
+    scope = "gather_ctx" if kind is None else f"gather_ctx/{kind}"
     if pages is None:
         return _attend_jnp(q, k_pages, v_pages, block_tables, lengths,
-                           n_kv_heads)
+                           n_kv_heads, window, scope)
     from tpu_sandbox.ops.pallas_paged_attention import paged_attention
 
-    with jax.named_scope("gather_ctx"):
+    with jax.named_scope(scope):
         return paged_attention(q, k_pages, v_pages, block_tables, lengths,
-                               pages_per_step=pages)
+                               pages_per_step=pages, window=window)
 
 
 def _attend_latent(q: jnp.ndarray, pages: jnp.ndarray,
@@ -618,8 +676,9 @@ def make_jamba_decode_fn(model_cfg: JambaConfig, cache_cfg: CacheConfig):
     return jax.jit(serve_decode, donate_argnums=(1, 2, 3))
 
 
-def _longcat_variables(params: dict, counters: dict) -> dict:
-    """The model's variables from what the engine holds: the weights with
+def _share_variables(params: dict, counters: dict) -> dict:
+    """A model with expert shares (LongCat's, Laguna's): its variables from
+    what the engine holds: the weights with
     the router's bias a layer (``params``) and the shares' counters, the
     third donated buffer."""
     from tpu_sandbox.models.longcat_flash import join_stats
@@ -628,7 +687,7 @@ def _longcat_variables(params: dict, counters: dict) -> dict:
             "batch_stats": join_stats(params["router_bias"], counters)}
 
 
-def _longcat_counters(mutated: dict) -> dict:
+def _share_counters(mutated: dict) -> dict:
     from tpu_sandbox.models.longcat_flash import split_stats
 
     return split_stats(mutated["batch_stats"])[1]
@@ -649,7 +708,7 @@ def make_longcat_prefill_fn(model_cfg: LongcatFlashConfig):
     def serve_prefill(params, pages, no_v, counters, tokens, dest_idx,
                       last_pos):
         logits, taps = model.apply(
-            _longcat_variables(params, counters), tokens, last_pos=last_pos,
+            _share_variables(params, counters), tokens, last_pos=last_pos,
             mutable=["kv_cache", "batch_stats"])
         pages = list(pages)
         with jax.named_scope("write_kv"):
@@ -660,7 +719,7 @@ def make_longcat_prefill_fn(model_cfg: LongcatFlashConfig):
                         pages[2 * i + j], dest_idx, rows[0])
         logits = logits[0, 0]
         return (logits, _greedy_pick(logits, "LongcatFlashLM"), Pages(pages),
-                no_v, _longcat_counters(taps))
+                no_v, _share_counters(taps))
 
     return jax.jit(serve_prefill, donate_argnums=(1, 2, 3))
 
@@ -695,11 +754,104 @@ def make_longcat_decode_fn(model_cfg: LongcatFlashConfig,
 
         model = LongcatFlashLM(model_cfg, attention_fn=attention_fn)
         logits, mutated = model.apply(
-            _longcat_variables(params, counters), tokens, pos[:, None],
+            _share_variables(params, counters), tokens, pos[:, None],
             mutable=["batch_stats"])
         logits = logits[:, 0]
         return (logits, _greedy_pick(logits, "LongcatFlashLM"), Pages(pages),
-                no_v, _longcat_counters(mutated))
+                no_v, _share_counters(mutated))
+
+    return jax.jit(serve_decode, donate_argnums=(1, 2, 3))
+
+
+def make_laguna_prefill_fn(model_cfg: LagunaConfig, cache_cfg: CacheConfig):
+    """prefill(params, k_pages, v_pages, counters, tokens[1, Lb],
+    dest_idx[Lb], last_pos[], window_dest[Lb]) -> (next_logits[vocab], their
+    greedy pick, k_pages, v_pages, counters). ``params`` is ``{"params",
+    "router_bias"}``. The prompt runs from position 0; a full layer's keys
+    and values go to its pages at ``dest_idx`` (as ``make_prefill_fn``), a
+    window layer's at ``window_dest`` -- of which only the ``ring_blocks``
+    blocks' worth of rows up to ``last_pos`` are stored at all: whatever
+    lies before them is aimed at the null block. Pages and counters are
+    donated."""
+    from tpu_sandbox.models.laguna import WINDOW, LagunaLM
+
+    tail = cache_cfg.ring_blocks * cache_cfg.block_size
+
+    def serve_prefill(params, k_pages, v_pages, counters, tokens, dest_idx,
+                      last_pos, window_dest):
+        k_pages, v_pages = list(k_pages), list(v_pages)
+        bucket = tokens.shape[1]
+        rows = min(tail, bucket)
+        start = jnp.clip(last_pos + 1 - rows, 0, bucket - rows)
+        layer = itertools.count()
+
+        def kv_fn(k, v, out):
+            # k, v [1, Lb, Hkv, D]: stored as the layer gives them and tied
+            # to the layer's output, or the compiler keeps every layer's to
+            # the program's end and stores there
+            i = next(layer)
+            dest = dest_idx
+            if model_cfg.layer_kinds[i] == WINDOW:
+                k, v = (jax.lax.dynamic_slice_in_dim(x, start, rows, 1)
+                        for x in (k, v))
+                dest = jax.lax.dynamic_slice_in_dim(window_dest, start, rows)
+            with jax.named_scope("write_kv"):
+                k_pages[i], v_pages[i], out = jax.lax.optimization_barrier((
+                    _store(k_pages[i], dest, k[0]),
+                    _store(v_pages[i], dest, v[0]), out))
+            return out
+
+        logits, mutated = LagunaLM(model_cfg, kv_fn=kv_fn).apply(
+            _share_variables(params, counters), tokens, last_pos=last_pos,
+            mutable=["batch_stats"])
+        logits = logits[0, 0]
+        return (logits, _greedy_pick(logits, "LagunaLM"), Pages(k_pages),
+                Pages(v_pages), _share_counters(mutated))
+
+    return jax.jit(serve_prefill, donate_argnums=(1, 2, 3))
+
+
+def make_laguna_decode_fn(model_cfg: LagunaConfig, cache_cfg: CacheConfig):
+    """decode(params, k_pages, v_pages, counters, tokens[B, 1], lengths[B],
+    block_tables[B, max_blocks], window_tables[B, ring_blocks]) ->
+    (logits[B, vocab], every row's greedy pick, k_pages, v_pages,
+    counters). ``lengths`` as in ``make_decode_fn``; the token's rotary
+    position is ``lengths - 1``; a full layer writes and reads through
+    ``block_tables``, a window layer through the ring (position p in entry
+    ``(p // block_size) % ring_blocks``) over its window alone."""
+    from tpu_sandbox.models.laguna import LagunaLM
+
+    def serve_decode(params, k_pages, v_pages, counters, tokens, lengths,
+                     block_tables, window_tables):
+        k_pages, v_pages = list(k_pages), list(v_pages)
+        bs, ring = cache_cfg.block_size, cache_cfg.ring_blocks
+        pos, dest = _decode_slots(cache_cfg, lengths, block_tables)
+        window_dest = jnp.take_along_axis(
+            window_tables, (pos // bs % ring)[:, None], axis=1)[:, 0] * bs \
+            + pos % bs
+        layer = itertools.count()
+
+        def attention_fn(q, k, v):
+            # q [B, Hq, D]; k, v [B, Hkv, D]: the new token
+            i = next(layer)
+            kind = model_cfg.layer_kinds[i]
+            window = model_cfg.window(kind)
+            at, tables = ((dest, block_tables) if window is None
+                          else (window_dest, window_tables))
+            with jax.named_scope("write_kv"):
+                k_pages[i] = _store(k_pages[i], at, k)
+                v_pages[i] = _store(v_pages[i], at, v)
+            return _attend(q, k_pages[i], v_pages[i], tables, lengths,
+                           model_cfg.num_key_value_heads, window=window,
+                           kind=kind)
+
+        model = LagunaLM(model_cfg, attention_fn=attention_fn)
+        logits, mutated = model.apply(
+            _share_variables(params, counters), tokens, pos[:, None],
+            mutable=["batch_stats"])
+        logits = logits[:, 0]
+        return (logits, _greedy_pick(logits, "LagunaLM"), Pages(k_pages),
+                Pages(v_pages), _share_counters(mutated))
 
     return jax.jit(serve_decode, donate_argnums=(1, 2, 3))
 
@@ -713,6 +865,8 @@ def _family(model_cfg) -> str:
         return "jamba"
     if name == "LongcatFlashConfig":
         return "longcat"
+    if name == "LagunaConfig":
+        return "laguna"
     raise TypeError(f"no serving family for {name}")
 
 
@@ -739,6 +893,16 @@ def lower_step(model_cfg: TransformerConfig | JambaConfig,
         model = LongcatFlashLM(model_cfg)
         prefill_fn = make_longcat_prefill_fn(model_cfg)
         decode_fn = make_longcat_decode_fn(model_cfg, cache_cfg)
+    elif family == "laguna":
+        from tpu_sandbox.models.laguna import LagunaLM
+
+        if cache_cfg.window != model_cfg.sliding_window:
+            raise ValueError(
+                f"the cache's window {cache_cfg.window} is not the model's "
+                f"{model_cfg.sliding_window}")
+        model = LagunaLM(model_cfg)
+        prefill_fn = make_laguna_prefill_fn(model_cfg, cache_cfg)
+        decode_fn = make_laguna_decode_fn(model_cfg, cache_cfg)
     else:
         model = TransformerLM(model_cfg)
         prefill_fn = make_prefill_fn(model_cfg)
@@ -750,11 +914,11 @@ def lower_step(model_cfg: TransformerConfig | JambaConfig,
     variables = jax.eval_shape(
         lambda: model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
     params = variables["params"]
-    if family == "longcat":
+    if family in ("longcat", "laguna"):
         from tpu_sandbox.models.longcat_flash import split_stats
 
         # what the engine holds as this family's weights: the router's
-        # bias a layer rides with them (``_longcat_variables``)
+        # bias a layer rides with them (``_share_variables``)
         params = {"params": params,
                   "router_bias": split_stats(variables["batch_stats"])[0]}
     params = placed(params)
@@ -762,13 +926,17 @@ def lower_step(model_cfg: TransformerConfig | JambaConfig,
 
     def lower(bucket: int | None):
         if bucket is None:
+            rings = (ints(max_batch, cache_cfg.ring_blocks),) \
+                if cache_cfg.window else ()
             return decode_fn.lower(
                 params, *held, ints(max_batch, 1), ints(max_batch),
-                ints(max_batch, cache_cfg.max_blocks_per_seq))
-        # a recurrent family's prefill takes the slot whose state it resets
-        slot = (ints(),) if recurrent else ()
+                ints(max_batch, cache_cfg.max_blocks_per_seq), *rings)
+        # a recurrent family's prefill takes the slot whose state it resets,
+        # one with window layers their destinations
+        more = (ints(),) if recurrent else \
+            (ints(bucket),) if cache_cfg.window else ()
         return prefill_fn.lower(params, *held, ints(1, bucket), ints(bucket),
-                                ints(), *slot)
+                                ints(), *more)
 
     return params, held, lower
 

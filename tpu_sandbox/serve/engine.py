@@ -69,6 +69,7 @@ from tpu_sandbox.serve.decode import (DecodeStep, Pages, build_decode_step,
 
 if TYPE_CHECKING:
     from tpu_sandbox.models.jamba import JambaConfig
+    from tpu_sandbox.models.laguna import LagunaConfig
     from tpu_sandbox.models.longcat_flash import LongcatFlashConfig
 
 # engines with a live decode loop / replica thread, for the conftest leak
@@ -84,7 +85,8 @@ def live_engines() -> list:
 class ServeConfig:
     # the model family's configuration: ``build_decode_step`` picks the
     # family's step builder by its type
-    model: TransformerConfig | JambaConfig | LongcatFlashConfig = field(
+    model: TransformerConfig | JambaConfig | LongcatFlashConfig \
+        | LagunaConfig = field(
         default_factory=TransformerConfig)
     cache: CacheConfig = field(default_factory=CacheConfig)
     max_batch: int = 4
@@ -498,6 +500,10 @@ class _EngineBase:
             toks = np.zeros((1, bucket), np.int32)
             toks[0, :plen] = request.prompt
             dest = self.cache.dest_indices(alloc, bucket).astype(np.int32)
+            # beside window layers: where they store the prompt's last window
+            more = (jnp.asarray(self.cache.window_dest_indices(
+                alloc, bucket, plen).astype(np.int32)),) \
+                if self.config.cache.window else ()
             with rec.span("prefill", parent=admit.ctx,
                           args={"rid": request.rid, "plen": plen},
                           hist="engine.prefill_s"):
@@ -509,7 +515,7 @@ class _EngineBase:
                 next_logits, pick = self._run(
                     self.step_fns.prefill[bucket], params,
                     jnp.asarray(toks), jnp.asarray(dest),
-                    jnp.asarray(plen - 1, jnp.int32), *slot_arg)
+                    jnp.asarray(plen - 1, jnp.int32), *slot_arg, *more)
                 # the logits come to the host only for a request that
                 # samples from them
                 if pick is not None:
@@ -632,7 +638,6 @@ class _EngineBase:
         preempted) are dropped, and slots it does not hold (admitted since)
         get a call of their own, the others' rows zeroed."""
         B = self.config.max_batch
-        cfg = self.config.cache
         rec = get_recorder()
         # resolve capacity for every slot first: growing one slot may
         # preempt another that was already swept, so the batch is built
@@ -671,19 +676,17 @@ class _EngineBase:
             members = by_ver[ver]
             tokens = np.zeros((B, 1), np.int32)
             lengths = np.zeros((B,), np.int32)
-            tables = np.zeros((B, cfg.max_blocks_per_seq), np.int32)
             for i in members:
                 slot = self.slots[i]
                 tokens[i, 0] = slot.tokens[-1]
                 lengths[i] = len(slot.tokens)
-                tables[i] = self.cache.block_table(slot.alloc)
             # dispatch to logits on the host: device time plus the D2H
             with rec.span("engine:decode_call",
                           hist="engine.decode_call_s", loop=True):
                 logits, picks = self._run(
                     self.step_fns.decode, self._params_by_ver[ver],
                     jnp.asarray(tokens), jnp.asarray(lengths),
-                    jnp.asarray(tables))
+                    *self._tables({i: self.slots[i] for i in members}))
                 if alone:
                     self._ahead = self._decode_ahead(picks, ver)
                 # [B, vocab] logits come to the host only if the program
@@ -750,7 +753,6 @@ class _EngineBase:
         if not members:
             return self._ahead_outcome("no_rows")
         lengths = np.zeros((B,), np.int32)
-        tables = np.zeros((B, cfg.max_blocks_per_seq), np.int32)
         for i, slot in members.items():
             at = len(slot.tokens)  # where the call writes: this step's token
             if at % cfg.block_size == 0 \
@@ -758,13 +760,28 @@ class _EngineBase:
                     and not self.cache.grow(slot.alloc):
                 return self._ahead_outcome("no_blocks")
             lengths[i] = at + 1
-            tables[i] = self.cache.block_table(slot.alloc)
         _, picks = self._run(
             self.step_fns.decode, self._params_by_ver[ver],
             self.step_fns.next_tokens(picks), jnp.asarray(lengths),
-            jnp.asarray(tables))
+            *self._tables(members))
         return self._ahead_outcome(
             "dispatched", _Ahead(picks=picks, slots=members, ver=ver))
+
+    def _tables(self, rows: dict[int, _Slot]) -> tuple:
+        """The tables of a decode call that decodes ``rows`` (row -> slot;
+        the null block for every other row): the block tables ``[B,
+        max_blocks]`` and, beside window layers, the rings ``[B,
+        ring_blocks]`` (``cache.window_table``)."""
+        cfg = self.config.cache
+        B = self.config.max_batch
+        tables = np.zeros((B, cfg.max_blocks_per_seq), np.int32)
+        rings = np.zeros((B, cfg.ring_blocks), np.int32)
+        for i, slot in rows.items():
+            tables[i] = self.cache.block_table(slot.alloc)
+            if cfg.window:
+                rings[i] = self.cache.window_table(slot.alloc)
+        return (jnp.asarray(tables),) + (
+            (jnp.asarray(rings),) if cfg.window else ())
 
     @staticmethod
     def _ahead_outcome(outcome: str, ahead: _Ahead | None = None):
