@@ -379,8 +379,10 @@ def build_parser() -> argparse.ArgumentParser:
                         help="use the Pallas flash-attention kernel")
     parser.add_argument("--remat-policy", choices=["full", "dots"],
                         default="full",
-                        help="remat=full recomputes whole blocks; dots "
-                             "saves matmul outputs (checkpoint_dots) so "
+                        help="remat=full recomputes whole blocks (with "
+                             "--flash the forward kernel runs twice); dots "
+                             "saves matmul outputs (checkpoint_dots) and "
+                             "the flash kernel's output and logsumexp, so "
                              "backward pays no extra MXU FLOPs")
     parser.add_argument("--remat", action="store_true",
                         help="jax.checkpoint each block (memory for FLOPs)")

@@ -7,7 +7,9 @@ Built from the published ``config.json`` keys under their published names
 (``NemotronHConfig.from_dict``); how many heads, groups, experts and
 vocabulary rows are held here are plain numbers of the configuration file.
 bf16 compute over fp32 parameters, a bf16 residual, every block under
-``nn.remat`` when ``remat``. The equations
+``nn.remat`` when ``remat``, which keeps the flash kernel's output and
+logsumexp (``ops/pallas_attention.py::FLASH_RESIDUALS``) and recomputes
+everything else of a block. The equations
 (``benchmark/configs/nemotron-3-super-120b-a12b.json`` lists what the
 published config does not settle, under ``assumed``):
 
@@ -53,6 +55,8 @@ import jax.numpy as jnp
 
 from tpu_sandbox.models.xing4 import RMSNorm, rms_norm
 from tpu_sandbox.ops.attention import causal_attention
+from tpu_sandbox.ops.pallas_attention import (
+    remat_saving, save_flash_residuals)
 # ``causal_conv``: the plain form, here for the tests that compare with it
 from tpu_sandbox.ops.pallas_short_conv import causal_conv, short_conv
 from tpu_sandbox.ops.ssd import ssd_scan
@@ -313,7 +317,9 @@ class NemotronHLM(nn.Module):
         norm_f = norm(name="norm_f")
         head = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
                         name="lm_head")
-        block_cls = nn.remat(Block) if cfg.remat else Block
+        block_cls = (remat_saving(
+            nn.remat(Block, policy=save_flash_residuals()), "nemotron_h")
+            if cfg.remat else Block)
 
         emb = embed(tokens)
         h = emb

@@ -9,7 +9,10 @@ residual. With ``remat`` every block is recomputed in its backward pass, in
 two halves (the mixer with its norm and residual, the MLP with its): the
 recomputation of one region, but the peak holds one half's intermediates,
 not both -- at 8192 tokens a whole Gated DeltaNet block's do not fit beside
-this model's state on a 16 GB chip. The equations
+this model's state on a 16 GB chip. All that a half keeps from its forward
+pass is the flash kernel's output and logsumexp
+(``ops/pallas_attention.py::FLASH_RESIDUALS``), so that kernel runs once a
+full-attention layer. The equations
 (``benchmark/configs/olmo-hybrid-7b.json`` lists what the published config
 does not settle, under ``assumed``), ``x [T, hidden]``, no bias anywhere:
 
@@ -51,6 +54,8 @@ from tpu_sandbox.models.nemotron_h import (
 from tpu_sandbox.models.xing4 import RMSNorm, rms_norm
 from tpu_sandbox.ops import pallas_short_conv
 from tpu_sandbox.ops.attention import causal_attention
+from tpu_sandbox.ops.pallas_attention import (
+    remat_saving, save_flash_residuals)
 from tpu_sandbox.ops.delta_rule import gated_delta_rule
 
 KINDS = {"linear_attention": "gdn", "full_attention": "attn"}
@@ -257,7 +262,8 @@ class Mlp(nn.Module):
 
 class Block(nn.Module):
     """Two halves, each a module that returns its residual sum
-    (``post_norm`` is its norm) and each under ``nn.remat``."""
+    (``post_norm`` is its norm) and each under an ``nn.remat`` that keeps
+    the flash kernel's two results."""
 
     config: OlmoHybridConfig
     kind: str
@@ -265,7 +271,13 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x):
         cfg = self.config
-        half = nn.remat if cfg.remat else (lambda cls: cls)
+
+        def half(cls):
+            if not cfg.remat:
+                return cls
+            return remat_saving(
+                nn.remat(cls, policy=save_flash_residuals()), "olmo_hybrid")
+
         name = KINDS[self.kind]
         mixer = GatedDeltaNet if name == "gdn" else Attention
         h = half(mixer)(cfg, name=name)(x)

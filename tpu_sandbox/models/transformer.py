@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 
 from tpu_sandbox.ops.attention import causal_attention
+from tpu_sandbox.ops.pallas_attention import (
+    FLASH_RESIDUALS, remat_saving, save_flash_residuals)
 
 
 @dataclass(frozen=True)
@@ -47,10 +49,14 @@ class TransformerConfig:
     # trades recompute FLOPs for O(n_layers) less activation memory — the
     # TPU-first long-context memory lever (HBM, not sequence sharding)
     remat: bool = False
-    # remat policy: "full" recomputes everything (max memory savings);
+    # remat policy: "full" recomputes everything (max memory savings; a
+    # flash attention_fn's forward kernel runs a second time in backward);
     # "dots" = jax.checkpoint_policies.checkpoint_dots — matmul outputs are
-    # SAVED and only cheap elementwise work is recomputed, so the backward
-    # pays no extra MXU FLOPs, for a modest memory give-back. Ignored when
+    # SAVED, and with them attention's product where a kernel makes it:
+    # the flash kernel's output and logsumexp (ops/pallas_attention.py::
+    # FLASH_RESIDUALS, 50 MB a layer at 8 x 1024 tokens of 1024) — and
+    # only cheap elementwise work is recomputed, so the backward pays no
+    # extra MXU FLOPs, for a modest memory give-back. Ignored when
     # remat=False.
     remat_policy: str = "full"
     # emit logits in fp32 (the safe default for any consumer). False
@@ -174,9 +180,13 @@ class TransformerLM(nn.Module):
             positions
         )
         if cfg.remat:
-            policy = (jax.checkpoint_policies.checkpoint_dots
-                      if cfg.remat_policy == "dots" else None)
-            block_cls = nn.remat(Block, policy=policy)
+            dots = cfg.remat_policy == "dots"
+            policy = (save_flash_residuals(
+                also=jax.checkpoint_policies.checkpoint_dots) if dots
+                else None)
+            block_cls = remat_saving(nn.remat(Block, policy=policy),
+                                     "transformer",
+                                     FLASH_RESIDUALS if dots else ())
         else:
             block_cls = Block
         for i in range(cfg.n_layers):

@@ -5,7 +5,9 @@ this chip holds a share.
 A model described by layer kinds, built from the published ``config.json``
 keys (``Xing4Config.from_dict``); ``TransformerLM`` stays GPT-2's. bf16
 compute over fp32 parameters; every block under ``nn.remat`` when
-``remat``. The equations (``benchmark/configs/xing4.0-29b-a4b.json`` lists
+``remat``, which keeps the flash kernel's output and logsumexp
+(``ops/pallas_attention.py::FLASH_RESIDUALS``) and recomputes everything
+else of a block. The equations (``benchmark/configs/xing4.0-29b-a4b.json`` lists
 what the published config does not settle, under ``assumed``):
 
 *Streams.* The residual is ``n = hc_mult`` streams of width C, held as
@@ -54,6 +56,8 @@ from tpu_sandbox.models.latent import (  # noqa: F401  (RMSNorm, rms_norm:
     yarn_inv_freq, yarn_mscale)
 from tpu_sandbox.ops import pallas_mhc
 from tpu_sandbox.ops.attention import causal_attention
+from tpu_sandbox.ops.pallas_attention import (
+    remat_saving, save_flash_residuals)
 from tpu_sandbox.parallel.expert import ExpertShare, share_rows
 
 # what the published config does not settle (the configuration file's
@@ -297,7 +301,9 @@ class Xing4LM(nn.Module):
         norm_f = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="norm_f")
         head = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
                         name="lm_head")
-        block_cls = nn.remat(Block) if cfg.remat else Block
+        block_cls = (remat_saving(
+            nn.remat(Block, policy=save_flash_residuals()), "xing4")
+            if cfg.remat else Block)
 
         def enter(x):
             return jnp.broadcast_to(x[None], (n, *x.shape))

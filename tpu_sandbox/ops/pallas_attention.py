@@ -70,7 +70,14 @@ that tile: raced on the chip, it still beat four tiles of 512
 (``_TILE_CAP``).
 
 Backward is the standard flash backward recomputation — no O(S²) residual is
-saved, only (q, k, v, out, lse) — and runs as two Pallas kernels (VERDICT
+saved, only (q, k, v, out, lse). ``out`` and ``lse``, which only the forward
+kernel can make, carry checkpoint names (``FLASH_RESIDUALS``): a ``remat``
+whose policy keeps those names (``save_flash_residuals``: every block of the
+four LM models, and ``TransformerLM``'s ``"dots"``) holds the two arrays
+from its forward pass and runs the forward kernel once a layer; a
+policy-less ``remat`` (``TransformerLM``'s ``"full"``, the pipeline's tick)
+runs it again in its backward pass, and outside a ``remat`` the names mean
+nothing. The backward runs as two Pallas kernels (VERDICT
 r01 weak #4: the first version scanned kv blocks in jnp, holding
 [S, block_k] score slabs): a dk/dv kernel with q blocks innermost and a dq
 kernel with kv blocks innermost, both accumulating in VMEM scratch with the
@@ -94,6 +101,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 from tpu_sandbox.ops.pallas_common import (
@@ -804,10 +812,45 @@ def _flash_core(q, k, v, scale, causal, block_q, block_k, interpret, kv_len,
     return out
 
 
+#: The checkpoint names of what only the forward kernel can make: its
+#: output and its logsumexp, as the differentiated call keeps them.
+FLASH_RESIDUALS = ("flash_out", "flash_lse")
+
+
+def save_flash_residuals(also=None):
+    """The ``remat`` policy that keeps ``FLASH_RESIDUALS`` (and what the
+    policy ``also`` keeps): the backward pass of a block under it reads
+    the forward kernel's two results where a policy-less ``remat`` runs
+    the kernel a second time. q, k and v carry no name: they are
+    projections' results, recomputed (or kept by ``also``) like any other."""
+    names = jax.checkpoint_policies.save_only_these_names(*FLASH_RESIDUALS)
+    if also is None:
+        return names
+    return jax.checkpoint_policies.save_from_both_policies(also, names)
+
+
+def remat_saving(remat_cls, model: str, names=FLASH_RESIDUALS):
+    """``remat_cls`` -- a block's class as ``model`` has put it under
+    ``nn.remat`` -- as a callable that builds it and counts
+    ``remat.saved{model, names}`` in the always-on registry, once a block
+    built: ``names`` are the checkpoint names that ``remat`` keeps."""
+    from tpu_sandbox.obs import get_registry
+
+    saved = get_registry().counter("remat.saved", labels={
+        "model": model, "names": "+".join(names) or "none"})
+
+    def build(*args, **kwargs):
+        saved.inc()
+        return remat_cls(*args, **kwargs)
+
+    return build
+
+
 def _core_fwd(q, k, v, scale, causal, block_q, block_k, interpret, kv_len,
               heads):
     out, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
                           kv_len, heads=heads)
+    out, lse = map(checkpoint_name, (out, lse), FLASH_RESIDUALS)
     return out, (q, k, v, out, lse)
 
 
