@@ -503,9 +503,21 @@ def test_engine_spans_end_after_the_logits_are_fetched(registry, tmp_path,
     assert hist["engine.prefill_s"]["min"] >= 0.005
     assert hist["engine.step_s"]["count"] == eng.steps
     assert hist["engine.sample_s"]["count"] == decode_calls
-    assert hist["engine.occupancy"]["count"] == eng.steps
-    assert 0.0 < hist["engine.occupancy"]["max"] <= 1.0
-    assert snap["counters"]["engine.tokens"] == 3 * 4
+    # a decode call is two phases, which the step log keeps: the dispatch
+    # ends before the fetch, and the wait holds it (``_CountingSteps``
+    # sleeps inside ``__array__``)
+    records = list(eng.step_log.steps)
+    assert sum(r.calls for r in records) == decode_calls
+    assert min(r.wait_s for r in records) >= 0.005
+    assert sum(r.dispatch_s + r.wait_s for r in records) \
+        <= hist["engine.decode_call_s"]["sum"]
+    assert max(r.dispatch_s for r in records) \
+        < hist["engine.decode_call_s"]["max"]
+    assert hist["engine.admit_s"]["count"] == hist["engine.step_s"]["count"]
+    assert "engine.occupancy" not in hist
+    assert [r.rows for r in records].count(0) == 0
+    assert sum(r.rows for r in eng.step_log.steps) == 3 * 3  # a prefill's
+    assert snap["counters"]["engine.tokens"] == 3 * 4   # first token counts
     # the JSONL: a request's prefill span holds the fetch too, inside admit
     (log,) = [f for f in os.listdir(tmp_path) if f.endswith(".jsonl")]
     spans = [json.loads(line) for line in open(tmp_path / log)]

@@ -29,7 +29,9 @@ KV-sequencer calibration, and renders one of:
         scavenge requeues, in order, across every process.
 
 With no mode flag it prints a summary: processes, record counts,
-dropped (torn/corrupt) lines, trace chains and their integrity.
+dropped (torn/corrupt) lines, trace chains and their integrity, and every
+``engine:stall`` instant (a serving step that took 1.5 x its neighbours:
+the phase that held it, by how much, on or off the CPU).
 """
 
 from __future__ import annotations
@@ -131,6 +133,13 @@ def main(argv=None) -> int:
         ok = sum(1 for recs in chains.values()
                  if collect.chain_check(recs)["connected"])
         print(f"{len(chains)} traces, {ok} fully connected")
+        stalls = [r for r in merged
+                  if r.get("ph") == "i" and r.get("name") == "engine:stall"]
+        if stalls:
+            from tpu_sandbox.serve.steplog import format_stall
+            for r in stalls:
+                print(f"  [{r.get('pkey', '?')}] engine:stall  "
+                      f"{format_stall(r.get('args') or {})}")
     return 0
 
 

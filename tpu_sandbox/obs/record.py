@@ -146,7 +146,7 @@ class Span:
     passes the parent through."""
 
     __slots__ = ("_rec", "name", "ctx", "parent", "args", "_t0", "_closed",
-                 "_hist", "_hist_labels", "_ann", "_under", "_stack")
+                 "_hist", "_hist_labels", "_ann", "_under", "_stack", "dur")
 
     def __init__(self, rec: "Recorder", name: str,
                  ctx: TraceContext | None, parent: TraceContext | None,
@@ -160,6 +160,9 @@ class Span:
         self._hist = hist
         self._hist_labels = hist_labels
         self._closed = False
+        #: seconds from open to close, once closed: for an opener that keeps
+        #: its own record of the span
+        self.dur = 0.0
         # the opener's stack, kept so that a close from another thread, or
         # out of order, still takes this span off the stack it is on
         self._stack = stack = rec._open_spans()
@@ -179,7 +182,7 @@ class Span:
         if self._closed:
             return
         self._closed = True
-        dur = time.monotonic() - self._t0
+        dur = self.dur = time.monotonic() - self._t0
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
         stack = self._stack
@@ -239,6 +242,11 @@ class Recorder:
         #: one runs (``train/trainer.py::LoopSpans``); None outside a loop.
         #: The compile listener reads it to count a compile inside the loop
         self.loop_step: int | None = None
+        #: backend compiles the compile listener has seen, and writes of the
+        #: buffer to the file: plain totals, read without a lock, for a loop
+        #: that asks whether either fell inside one of its iterations
+        self.compiles = 0
+        self.flushes = 0
         self._fh = None
         if self.enabled:
             d = os.path.dirname(path)
@@ -273,6 +281,7 @@ class Recorder:
         self._buf.clear()
         self._fh.write(lines)
         self._fh.flush()
+        self.flushes += 1
 
     def flush(self) -> None:
         with self._lock:

@@ -180,6 +180,7 @@ def _on_duration(event: str, duration: float, **kwargs) -> None:
         reg.histogram("compile.lower_s", labels=labels).observe(seconds)
     else:
         reg.histogram("compile.backend_s", labels=labels).observe(seconds)
+        rec.compiles += 1
         cache = getattr(_launch_tls, "cache", "none")
         _launch_tls.cache = "none"
     name = kwargs.get("fun_name", "?")

@@ -35,6 +35,16 @@ request) keeps the same guarantee: the sampler key is derived from the
 request seed folded with the decode-step index, so a replayed request
 re-draws identical tokens (see ``serve/decode.py:sample_token``).
 
+A step tells its own host side. Six phases tile the span ``engine:step``
+(``engine:shed``, ``engine:admit``, ``engine:grow``, then a decode call's
+``engine:dispatch`` -- prepare and enqueue, waits for nothing -- and
+``engine:wait`` -- the blocking read of its result -- and ``engine:sample``),
+and ``step_log`` (``serve/steplog.py``) keeps the last steps in memory, one
+record each: the phases' seconds, wall and thread-CPU time, the collector's
+runs; a step of 1.5 x its neighbours is a **stall**, counted and kept with
+the phase that held it. ``load_report()`` says what they add up to
+(``host_ms``, ``wait_ms``, ``stalls``).
+
 SLO guardrails live here too:
 
 - requests may carry an absolute **deadline** (engine clock); waiting or
@@ -66,6 +76,7 @@ from tpu_sandbox.obs import get_recorder, get_registry
 from tpu_sandbox.serve.cache import CacheConfig, PagedKVCache, SeqAlloc
 from tpu_sandbox.serve.decode import (DecodeStep, Pages, build_decode_step,
                                       init_buffers, sample_token)
+from tpu_sandbox.serve.steplog import StepLog
 
 if TYPE_CHECKING:
     from tpu_sandbox.models.jamba import JambaConfig
@@ -79,6 +90,13 @@ _LIVE_ENGINES: "weakref.WeakSet" = weakref.WeakSet()
 
 def live_engines() -> list:
     return [e for e in _LIVE_ENGINES if e.active_requests or e.waiting]
+
+
+def engines() -> list:
+    """Every engine of this process that is still referenced, busy or not:
+    how a reader finds an engine's ``step_log`` after the engine was
+    drained."""
+    return list(_LIVE_ENGINES)
 
 
 @dataclass(frozen=True)
@@ -227,6 +245,9 @@ class _EngineBase:
         self.steps = 0
         self.last_step_at: float | None = None
         self._ahead: _Ahead | None = None
+        #: the last steps, one record each, and the stalls among them
+        #: (``serve/steplog.py``)
+        self.step_log = StepLog()
         _LIVE_ENGINES.add(self)
 
     # -- public --------------------------------------------------------------
@@ -411,6 +432,9 @@ class _EngineBase:
             # a silently-dropping recorder must be visible at the top
             # level of every load report, not buried in a nested dict
             "dropped_events": rec_stats["dropped"],
+            # the host's own share of a step, its slack behind the device,
+            # and the steps that stalled: ``host_ms``, ``wait_ms``, ``stalls``
+            **self.step_log.report(),
         }
 
     # -- shared mechanics ----------------------------------------------------
@@ -533,6 +557,7 @@ class _EngineBase:
         slot.admitted_mono = time.monotonic()
         self.slots[slot_idx] = slot
         self._choose(slot, row, pick)
+        get_registry().counter("engine.tokens").inc()
         if self._finished(slot):
             self._retire(slot_idx)
 
@@ -550,7 +575,6 @@ class _EngineBase:
                             temperature=req.temperature, top_k=req.top_k)
 
     def _emit_token(self, slot: _Slot, token: int) -> None:
-        get_registry().counter("engine.tokens").inc()
         now = self.clock()
         if slot.first_token_at is None:
             slot.first_token_at = now
@@ -639,12 +663,15 @@ class _EngineBase:
         get a call of their own, the others' rows zeroed."""
         B = self.config.max_batch
         rec = get_recorder()
+        log = self.step_log
         # resolve capacity for every slot first: growing one slot may
         # preempt another that was already swept, so the batch is built
         # only from the survivors
-        for i in range(B):
-            if self.slots[i] is not None and not self._ensure_capacity(i):
-                self._preempt(i)
+        with rec.span("engine:grow", loop=True) as sp:
+            for i in range(B):
+                if self.slots[i] is not None and not self._ensure_capacity(i):
+                    self._preempt(i)
+        log.grow_s = sp.dur
         ahead, self._ahead = self._ahead, None
         covered = {} if ahead is None else {
             i: s for i, s in ahead.slots.items() if self.slots[i] is s}
@@ -652,8 +679,6 @@ class _EngineBase:
         for i, slot in enumerate(self.slots):
             if slot is not None and i not in covered:
                 by_ver.setdefault(slot.ver, []).append(i)
-        get_registry().histogram("engine.occupancy").observe(
-            self.active_requests / B)
         if not by_ver and not covered:
             return
         # one call decodes every occupied slot: the next step's can follow
@@ -664,58 +689,78 @@ class _EngineBase:
             self._ahead_outcome(
                 "versions" if len(versions) > 1 else "admitted")
         rows: dict[int, tuple] = {}  # slot -> (logits row, program's pick)
+        # a decode call is two phases: ``engine:dispatch`` prepares and
+        # enqueues (this step's call, and the next step's ahead of it) and
+        # waits for nothing; ``engine:wait`` is the blocking read
         if covered:
             with rec.span("engine:decode_call",
                           hist="engine.decode_call_s", loop=True):
                 if alone:
-                    self._ahead = self._decode_ahead(ahead.picks, ahead.ver)
-                picks = np.asarray(ahead.picks)
+                    with rec.span("engine:dispatch", loop=True) as sp:
+                        self._ahead = self._decode_ahead(
+                            ahead.picks, ahead.ver)
+                    log.dispatch_s += sp.dur
+                _, picks = self._fetch(None, ahead.picks)
             for i in covered:
                 rows[i] = (None, picks[i])
         for ver in sorted(by_ver):
             members = by_ver[ver]
-            tokens = np.zeros((B, 1), np.int32)
-            lengths = np.zeros((B,), np.int32)
-            for i in members:
-                slot = self.slots[i]
-                tokens[i, 0] = slot.tokens[-1]
-                lengths[i] = len(slot.tokens)
             # dispatch to logits on the host: device time plus the D2H
             with rec.span("engine:decode_call",
                           hist="engine.decode_call_s", loop=True):
-                logits, picks = self._run(
-                    self.step_fns.decode, self._params_by_ver[ver],
-                    jnp.asarray(tokens), jnp.asarray(lengths),
-                    *self._tables({i: self.slots[i] for i in members}))
-                if alone:
-                    self._ahead = self._decode_ahead(picks, ver)
+                with rec.span("engine:dispatch", loop=True) as sp:
+                    tokens = np.zeros((B, 1), np.int32)
+                    lengths = np.zeros((B,), np.int32)
+                    for i in members:
+                        slot = self.slots[i]
+                        tokens[i, 0] = slot.tokens[-1]
+                        lengths[i] = len(slot.tokens)
+                    logits, picks = self._run(
+                        self.step_fns.decode, self._params_by_ver[ver],
+                        jnp.asarray(tokens), jnp.asarray(lengths),
+                        *self._tables({i: self.slots[i] for i in members}))
+                    if alone:
+                        self._ahead = self._decode_ahead(picks, ver)
+                log.dispatch_s += sp.dur
                 # [B, vocab] logits come to the host only if the program
                 # picks nothing or a member samples from them
-                if picks is None or any(
+                if picks is not None and not any(
                         self.slots[i].request.temperature > 0.0
                         for i in members):
-                    logits = np.asarray(logits)
-                else:
                     logits = None
-                if picks is not None:
-                    picks = np.asarray(picks)
+                logits, picks = self._fetch(logits, picks)
             for i in members:
                 rows[i] = (None if logits is None else logits[i],
                            None if picks is None else picks[i])
         self._emit_rows(rows)
+
+    def _fetch(self, logits, picks) -> tuple:
+        """The blocking read of a call's results (``None`` stays ``None``):
+        the phase ``engine:wait``."""
+        with get_recorder().span("engine:wait", loop=True) as sp:
+            if logits is not None:
+                logits = np.asarray(logits)
+            if picks is not None:
+                picks = np.asarray(picks)
+        self.step_log.wait_s += sp.dur
+        self.step_log.calls += 1
+        return logits, picks
 
     def _emit_rows(self, rows: dict[int, tuple]) -> None:
         """A step's tokens: slot -> (logits row, program's pick)."""
         self.steps += 1
         self.last_step_at = self.clock()
         with get_recorder().span("engine:sample", hist="engine.sample_s",
-                                 loop=True):
+                                 loop=True) as sp:
             for i in sorted(rows):
                 slot = self.slots[i]
                 slot.alloc.length = len(slot.tokens)
                 self._choose(slot, *rows[i])
                 if self._finished(slot):
                     self._retire(i)
+            get_registry().counter("engine.tokens").inc(len(rows))
+        self.step_log.sample_s += sp.dur
+        self.step_log.rows += len(rows)
 
     def _decode_ahead(self, picks, ver: int) -> _Ahead | None:
         """The **next** step's decode call, dispatched now: ``picks`` are
@@ -812,32 +857,48 @@ class _EngineBase:
     def _admit_waiting(self) -> None:
         """Fill free slots from the queue head until it blocks."""
         with get_recorder().span("engine:admit",
-                                 hist="engine.admit_s", loop=True):
+                                 hist="engine.admit_s", loop=True) as sp:
             while self.waiting:
                 if not self._admit_from_waiting():
                     break
+        self.step_log.admit_s = sp.dur
+
+    def _admits(self) -> bool:
+        """Whether this step fills free slots before it decodes: the two
+        engines' one difference."""
+        raise NotImplementedError
+
+    def step(self) -> None:
+        """One step of the replica: shed, admit, decode. Its phases tile
+        the span ``engine:step`` (``engine:shed``, ``engine:admit``,
+        ``engine:grow``, then ``engine:dispatch`` and ``engine:wait`` a
+        decode call, ``engine:sample``), and the step log keeps the record
+        (``serve/steplog.py``)."""
+        rec = get_recorder()
+        log = self.step_log
+        log.begin()
+        with rec.span("engine:step", hist="engine.step_s",
+                      loop=True) as step:
+            with rec.span("engine:shed", loop=True) as sp:
+                self.shed_expired()
+            log.shed_s = sp.dur
+            if self._admits():
+                self._admit_waiting()
+            self._decode_active()
+        log.end(step.dur)
 
 
 class ContinuousEngine(_EngineBase):
     """Admit/evict at decode-step granularity — freed slots refill before
     the next step, nothing waits for a batch to finish."""
 
-    def step(self) -> None:
-        with get_recorder().span("engine:step",
-                                 hist="engine.step_s", loop=True):
-            self.shed_expired()
-            self._admit_waiting()
-            self._decode_active()
+    def _admits(self) -> bool:
+        return True
 
 
 class StaticEngine(_EngineBase):
     """Batch-barrier control: fill the batch once, then decode until every
     member finishes before admitting again."""
 
-    def step(self) -> None:
-        with get_recorder().span("engine:step",
-                                 hist="engine.step_s", loop=True):
-            self.shed_expired()
-            if self.active_requests == 0:
-                self._admit_waiting()
-            self._decode_active()
+    def _admits(self) -> bool:
+        return self.active_requests == 0
