@@ -691,6 +691,10 @@ def test_each_branch_counts_its_choice_a_layer(branches):
         assert label(key, "impl") == branch
         assert label(key, "pages_per_step") == (
             "2" if branch == "pallas" else "0")
+        # a full step copies a page of K and of V and waits once a buffer
+        assert (label(key, "copies_per_step"),
+                label(key, "waits_per_step")) == (
+                    ("4", "2") if branch == "pallas" else ("0", "0"))
         assert (label(key, "kv_heads"), label(key, "group"),
                 label(key, "head_dim"), label(key, "block_size"),
                 label(key, "max_blocks"), label(key, "batch")) == (

@@ -334,6 +334,10 @@ def test_each_branch_counts_its_choice_and_the_caches_layout(branches):
                     branch, "1", "4", "128", "104", "128")
         assert label(key, "pages_per_step") == (
             "2" if branch == "pallas" else "0")
+        # a full step of the one latent buffer: a copy a page, one wait
+        assert (label(key, "copies_per_step"),
+                label(key, "waits_per_step")) == (
+                    ("2", "1") if branch == "pallas" else ("0", "0"))
         (key, n), = layout.items()
         assert n == 2 * cfg.num_layers
         assert (label(key, "latent"), label(key, "rope"), label(key, "lanes"),

@@ -338,11 +338,15 @@ def _decode_slots(cache_cfg: CacheConfig, lengths: jnp.ndarray,
 
 
 # what one compute step of the kernel copies of K (and as much of V), and
-# the positions it may hold: sixteen 32 KB pages at gpt2-medium, sixty-four
-# 4 KB pages at Jamba (PERF.md section 6, PR 43: the kernel alone on the
-# chip read 390 / 518 / 589 / 611 GB/s of live keys and values at 4 / 8 /
-# 16 / 32 pages a step of GPT-2's, and 113 / 132 / 142 at 16 / 32 / 64 of
-# Jamba's)
+# the positions it may hold: sixteen 32 KB pages at gpt2-medium and Laguna,
+# sixty-four 4 KB pages at Jamba, twenty-four 20 KB latent pages at LongCat
+# (PERF.md section 6. PR 43: the kernel alone on the chip read 390 / 518 /
+# 589 / 611 GB/s of live keys and values at 4 / 8 / 16 / 32 pages a step of
+# GPT-2's, and 113 / 132 / 142 at 16 / 32 / 64 of Jamba's. PR 51's race
+# split a call three ways: at 32 KB pages the step is its transfer and the
+# products hide under it; at 4 and 20 KB it was the core's issue and wait of
+# each copy, 22-33 ns, *plus* the products, and a longer step would not have
+# bought those back -- the kernel's bookkeeping did, and the rule stayed)
 _STEP_BYTES = 512 * 1024
 _STEP_TOKENS = 1024
 
@@ -374,6 +378,17 @@ def pages_per_step(width: int, block_size: int, cache_dtype: Any,
     if pages * block_size > 128 and block_size <= 128:
         pages -= pages % (128 // block_size)
     return pages
+
+
+def _step_labels(pages: int | None, buffers: int) -> dict:
+    """What a compute step whose pages are all live costs the kernel's
+    scalar side, for ``paged_attn.kernel_choice``: the copies it issues (a
+    page of each of ``buffers``: K and V, or the one latent buffer) and the
+    waits it makes (one a buffer: a DMA semaphore counts bytes). A row's
+    last, partial step issues and waits page by page. 0 and 0 for the
+    ``jnp`` form."""
+    return {"copies_per_step": (pages or 0) * buffers,
+            "waits_per_step": buffers if pages else 0}
 
 
 def _ring_seen(block_tables: jnp.ndarray, lengths: jnp.ndarray,
@@ -449,6 +464,7 @@ def _attend(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
             "pages_per_step": pages or 0,
             "max_blocks": block_tables.shape[1], "batch": q.shape[0],
             "qk_dim": q.shape[2], "v_dim": q.shape[2]}
+        | _step_labels(pages, buffers=2)
         | ({} if kind is None else {"kind": kind, "window": window or 0})))
     scope = "gather_ctx" if kind is None else f"gather_ctx/{kind}"
     if pages is None:
@@ -485,7 +501,8 @@ def _attend_latent(q: jnp.ndarray, pages: jnp.ndarray,
             "kv_heads": 1, "group": q.shape[1], "head_dim": lanes,
             "block_size": block_size, "pages_per_step": step or 0,
             "max_blocks": block_tables.shape[1], "batch": q.shape[0],
-            "qk_dim": qk_dim, "v_dim": v_dim}))
+            "qk_dim": qk_dim, "v_dim": v_dim}
+        | _step_labels(step, buffers=1)))
     kernel_site("latent_cache", get_registry().counter(
         "mla.cache_layout", labels={
             "latent": v_dim, "rope": qk_dim - v_dim, "lanes": lanes,
